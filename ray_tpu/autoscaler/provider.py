@@ -76,7 +76,7 @@ class FakeSliceProvider(NodeProvider):
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
         proc = subprocess.Popen(
-            [sys.executable, "-S", "-m", "ray_tpu.core.node_agent",
+            [sys.executable, "-m", "ray_tpu.core.node_agent",
              "--address", f"{self._addr[0]}:{self._addr[1]}",
              "--num-cpus", str(res.pop("CPU", 1.0)),
              "--resources", json.dumps(res),

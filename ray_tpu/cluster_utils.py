@@ -59,7 +59,7 @@ class Cluster:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
         proc = subprocess.Popen(
-            [sys.executable, "-S", "-m", "ray_tpu.core.node_agent",
+            [sys.executable, "-m", "ray_tpu.core.node_agent",
              "--address", f"{addr[0]}:{addr[1]}",
              "--num-cpus", str(res.pop("CPU")),
              "--resources", json.dumps(res),

@@ -34,6 +34,7 @@ from .ids import NodeId, ObjectId, WorkerId
 from .object_store import (make_store, SegmentReader, pull_chunks,
                            read_store_chunk)
 from .rpc import RpcChannel, RpcServer, cluster_token, connect
+from .worker_env import worker_env
 
 
 def _outbound_ip_toward(addr) -> str:
@@ -168,7 +169,8 @@ class NodeAgent:
     def _handle_head_command(self, method: str, payload):
         if method == "start_worker":
             self._start_worker(payload["worker_id"],
-                               container=payload.get("container"))
+                               container=payload.get("container"),
+                               chip=bool(payload.get("chip")))
             return True
         if method == "push_task":
             ch = self._channels.get(payload["worker_id"])
@@ -342,12 +344,11 @@ class NodeAgent:
     # ---- worker lifecycle ----------------------------------------------------
 
     def _start_worker(self, worker_id: WorkerId,
-                      container: dict | None = None) -> None:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
-        env["RTPU_AUTHKEY"] = cluster_token().hex()  # env, never argv
+                      container: dict | None = None,
+                      chip: bool = False) -> None:
+        env = worker_env(chip, cluster_token().hex())
         cmd = [
-            sys.executable, "-S", "-m", "ray_tpu.core.worker_main",
+            sys.executable, "-m", "ray_tpu.core.worker_main",
             "--address", self._sock_path,
             "--worker-id", worker_id.hex(),
             "--node-id", self.node_id.hex(),

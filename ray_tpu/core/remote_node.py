@@ -13,6 +13,7 @@ duplex TCP channel; bulk object bytes move as chunked reads
 """
 from __future__ import annotations
 
+import math
 import threading
 import time
 from typing import Dict, Optional
@@ -22,6 +23,7 @@ from .node import Node, WorkerHandle
 from .object_store import SegmentReader, pull_chunks
 from .resources import ResourceSet
 from .rpc import RpcChannel
+from .worker_env import is_chip
 
 
 class RemoteStoreProxy:
@@ -122,6 +124,8 @@ class RemoteNode(Node):
         self._lease_queue = {}  # (demand, pg, env) sig -> deque (Node's shape)
         self._bundles = {}
         self._starting_count = 0
+        self._chip_holders = set()  # Node's one-process-per-chip ledger
+        self._chip_slots = math.ceil(self.total_resources.get("TPU", 0))
         self._prefetch_depth = max(1, int(config.worker_task_prefetch))
         self._launch_failures = {}  # Node's launch-strike breaker state
         self.alive = True
@@ -145,12 +149,15 @@ class RemoteNode(Node):
         worker_id = WorkerId.from_random()
         handle = WorkerHandle(worker_id=worker_id, proc=None,  # type: ignore
                               started_at=time.monotonic())
+        chip = is_chip(env_hash)
         if env_hash is not None:
-            handle.env_hash = env_hash  # container workers: dedicated
+            handle.env_hash = env_hash  # container/chip workers: dedicated
         with self._lock:  # reentrant: callers may already hold it
             self._workers[worker_id] = handle
             self._starting_count += 1
-        msg = {"worker_id": worker_id}
+            if chip:
+                self._chip_holders.add(worker_id)
+        msg = {"worker_id": worker_id, "chip": chip}
         if container is not None:
             # the agent launches inside the container on ITS host via
             # its configured launcher (same contract as the local Node)
@@ -180,6 +187,7 @@ class RemoteNode(Node):
     def on_remote_worker_exit(self, worker_id: WorkerId,
                               error: str = None) -> None:
         fail_req = None
+        self._chip_released(worker_id)
         with self._lock:
             handle = self._workers.get(worker_id)
             if handle is None:

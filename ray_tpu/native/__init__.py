@@ -3,9 +3,10 @@
 The image has no pybind11; the native pieces export a C ABI and build
 on first import with the system g++ into a content-hashed cached .so
 (so a source edit rebuilds, and N processes race benignly via atomic
-rename). `load_store_lib()` returns None when no compiler is present —
-callers fall back to the pure-Python implementations, which remain the
-semantics reference.
+rename). The sources are committed and nothing built is: a fresh copy of
+the tree builds again. `load_store_lib()` returns None when no compiler
+is present — callers fall back to the pure-Python implementations, which
+remain the semantics reference — and says so on stderr.
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 from typing import Optional
@@ -29,6 +31,15 @@ def _cache_dir() -> str:
     return d
 
 
+def _fell_back(what: str, err: BaseException) -> None:
+    detail = getattr(err, "stderr", b"") or str(err)
+    if isinstance(detail, bytes):
+        detail = detail.decode(errors="replace")
+    print(f"ray_tpu.native: {what} did not build or load "
+          f"({type(err).__name__}: {detail.strip()[-300:]}); using the "
+          f"pure-Python implementation", file=sys.stderr, flush=True)
+
+
 def _build() -> Optional[str]:
     with open(_SRC, "rb") as f:
         src = f.read()
@@ -43,11 +54,12 @@ def _build() -> Optional[str]:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, out)  # atomic: concurrent builders race benignly
         return out
-    except Exception:
+    except Exception as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        _fell_back("store.cpp", e)
         return None
 
 
@@ -82,11 +94,12 @@ def load_wirefast():
                 subprocess.run(cmd, check=True, capture_output=True,
                                timeout=120)
                 os.replace(tmp, out)
-            except Exception:
+            except Exception as e:
                 try:
                     os.unlink(tmp)
                 except OSError:
                     pass
+                _fell_back("wirefast.c", e)
                 return None
         try:
             import importlib.machinery
@@ -98,7 +111,8 @@ def load_wirefast():
                 "_rtpu_wirefast", out, loader=loader)
             _wire_mod = importlib.util.module_from_spec(spec)
             loader.exec_module(_wire_mod)
-        except Exception:
+        except Exception as e:
+            _fell_back("wirefast.c", e)
             _wire_mod = None
         return _wire_mod
 
@@ -117,7 +131,8 @@ def load_store_lib() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            _fell_back("store.cpp", e)
             return None
         u64, p = ctypes.c_uint64, ctypes.c_void_p
         lib.rtpu_store_open.restype = p

@@ -143,8 +143,6 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
     num_kb = seq_k // block_k
     from jax.experimental.pallas import tpu as pltpu
 
-    from ..jax_compat import tpu_compiler_params as _compiler_params
-
     if num_kb == 1:
         kernel = functools.partial(
             _fwd_single_kernel, sm_scale=sm_scale, causal=causal,
@@ -169,7 +167,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
                 # residual-stacking copies; this layout pads 8x only.
                 jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
             ],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=_use_interpret(),
             cost_estimate=pl.CostEstimate(
@@ -209,7 +207,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
         # batch*head and q-block grid dims are independent — marking them
         # parallel lets Mosaic pipeline the next block's DMA under compute;
         # only the K dim (scratch carry) is sequential
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(),
         cost_estimate=pl.CostEstimate(
@@ -393,8 +391,6 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
     interp = _use_interpret()
     from jax.experimental.pallas import tpu as pltpu
 
-    from ..jax_compat import tpu_compiler_params as _compiler_params
-
     if num_kb == 1:
         # single K block: one fused pass computes s/p once and emits
         # dq + dk + dv together (the two-pass scheme below recomputes the
@@ -420,7 +416,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interp,
             cost_estimate=pl.CostEstimate(
@@ -445,7 +441,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
         cost_estimate=pl.CostEstimate(
@@ -474,7 +470,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
         cost_estimate=pl.CostEstimate(

@@ -97,7 +97,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
                            sm_scale: Optional[float] = None):
     """Convenience wrapper: shard_map ring_attention over `mesh` with
     sequence on `axis_name`, batch on dp/fsdp, heads on tp."""
-    from ..jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(("dp", "fsdp"), axis_name, "tp", None)

@@ -103,16 +103,21 @@ def build_mesh(spec: Union[MeshSpec, Dict[str, int], None] = None,
         return build_multislice_mesh(spec, devices, axis_names)
     degrees = spec.resolve(len(devices)) if isinstance(spec, MeshSpec) else dict(spec)
     shape = tuple(degrees[a] for a in axis_names)
-    try:
+    return Mesh(_device_array(shape, devices), axis_names)
+
+
+def _device_array(shape: Tuple[int, ...],
+                  devices: Sequence[jax.Device]) -> np.ndarray:
+    """Devices laid out as ``shape``. On a TPU the physical topology
+    decides the order, and a layout it cannot give raises: a silent
+    reshape would put collectives on the wrong links. CPU devices have
+    no topology, so there a plain reshape is the layout."""
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
-        if devices[0].platform == "tpu":
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=devices, allow_split_physical_axes=True)
-        else:
-            raise ValueError  # virtual devices: plain reshape is fine
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, axis_names)
+
+        return mesh_utils.create_device_mesh(
+            shape, devices=devices, allow_split_physical_axes=True)
+    return np.asarray(devices).reshape(shape)
 
 
 def group_devices_by_slice(devices: Sequence[jax.Device],
@@ -145,19 +150,8 @@ def build_multislice_mesh(spec: MeshSpec,
     degrees = spec.resolve(len(devices))
     inner_shape = tuple(degrees[a] for a in axis_names)
     groups = group_devices_by_slice(devices, spec.slices)
-    per_slice = []
-    for g in groups:
-        try:
-            from jax.experimental import mesh_utils
-            if g[0].platform == "tpu":
-                arr = mesh_utils.create_device_mesh(
-                    inner_shape, devices=g, allow_split_physical_axes=True)
-            else:
-                raise ValueError
-        except Exception:
-            arr = np.asarray(g).reshape(inner_shape)
-        per_slice.append(arr)
-    dev_array = np.stack(per_slice, axis=0)
+    dev_array = np.stack([_device_array(inner_shape, g) for g in groups],
+                         axis=0)
     return Mesh(dev_array, ("slice", *axis_names))
 
 

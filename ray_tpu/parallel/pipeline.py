@@ -183,9 +183,7 @@ def pipeline_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
         # initial carries must be marked pp-varying: the ticks fill them
         # with per-stage values, and scan requires carry types to be stable
         def _vary(x):
-            from ..jax_compat import pvary
-
-            return pvary(x, (pp_axis,))
+            return jax.lax.pcast(x, (pp_axis,), to="varying")
         state = _vary(jnp.zeros_like(x_loc[0]))
         ybuf = _vary(jnp.zeros_like(x_loc))
 
@@ -212,11 +210,9 @@ def pipeline_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return ybuf
 
     param_specs = jax.tree.map(lambda _: P(pp_axis), stage_params)
-    from ..jax_compat import shard_map
-
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(param_specs, P()), out_specs=P(),
-                   axis_names=frozenset({pp_axis}))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(param_specs, P()), out_specs=P(),
+                       axis_names=frozenset({pp_axis}))
     return fn(stage_params, x_mb)
 
 

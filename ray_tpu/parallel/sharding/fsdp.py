@@ -29,7 +29,7 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 from ..zero import TreeSpec, flatten_tree, tree_bytes, unflatten_tree
-from .lower import lower_shard_map
+from .lower import lower_jit, lower_shard_map
 from .owner import MeshOwner
 
 __all__ = ["FsdpPlane", "FsdpParams"]
@@ -192,20 +192,15 @@ class FsdpPlane:
             shapes)
 
     def _gather_prog(self, size: int, dtype):
-        import jax
         from jax.sharding import PartitionSpec as P
 
         key = ("gather", size, str(dtype))
         if key not in self._progs:
-            axis = self.axis
-
-            def _gather_local(p_shard):
-                return jax.lax.all_gather(p_shard, axis, tiled=True)
-
-            self._progs[key] = lower_shard_map(
-                _gather_local, self.owner,
-                in_specs=(P(axis),), out_specs=P(),
-                axis_names=frozenset({axis}))
+            # a reshard, not a manual collective: the partitioner places
+            # the all-gather and the result is replicated by construction
+            self._progs[key] = lower_jit(
+                lambda flat: flat, self.owner,
+                in_specs=(P(self.axis),), out_specs=P())
         return self._progs[key]
 
     def _init_prog(self, size: int, dtype):

@@ -20,7 +20,8 @@ from __future__ import annotations
 
 from typing import Callable, Optional, Sequence, Union
 
-from ...jax_compat import shard_map
+from jax import shard_map
+
 from .owner import MeshOwner
 
 

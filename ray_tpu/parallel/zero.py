@@ -396,9 +396,8 @@ def make_zero_update_spmd(tx, mesh, axis: str = "dp",
     import jax
     import jax.numpy as jnp
     import optax
-    from jax.sharding import PartitionSpec as P
-
-    from ..jax_compat import shard_map
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from . import quant as _quant
 
@@ -466,15 +465,23 @@ def make_zero_update_spmd(tx, mesh, axis: str = "dp",
             p_shard = jax.lax.dynamic_slice(p_local, (idx * chunk,),
                                             (chunk,))
             updates, new_opt = tx.update(g_shard, opt_local, p_shard)
-            new_shard = optax.apply_updates(p_shard, updates)
-            new_flat = jax.lax.all_gather(new_shard, axis, tiled=True)
-            return new_flat, new_opt
+            return optax.apply_updates(p_shard, updates), new_opt
 
         ospecs = _opt_specs(chunk, dtype)
-        prog = jax.jit(shard_map(_upd_local, mesh=mesh,
-                                 in_specs=(P(), P(axis), ospecs),
-                                 out_specs=(P(), ospecs),
-                                 axis_names=frozenset({axis})))
+        mapped = shard_map(_upd_local, mesh=mesh,
+                           in_specs=(P(), P(axis), ospecs),
+                           out_specs=(P(axis), ospecs),
+                           axis_names=frozenset({axis}))
+
+        def _upd(p_flat, g_stacked, opt):
+            # the body returns each rank's updated chunk; resharding the
+            # assembled vector to P() is the all-gather, placed by the
+            # partitioner, so the result is replicated by construction
+            new_flat, new_opt = mapped(p_flat, g_stacked, opt)
+            return jax.lax.with_sharding_constraint(
+                new_flat, NamedSharding(mesh, P())), new_opt
+
+        prog = jax.jit(_upd)
         _progs[key] = prog
         return prog
 

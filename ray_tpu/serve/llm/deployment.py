@@ -35,11 +35,15 @@ from typing import Any, Dict, Optional, Tuple
 from .engine import EngineConfig, LLMEngine
 
 
-def build_model(model: Any = "gpt-tiny", seed: int = 0) -> Tuple[Any, Any]:
+def build_model(model: Any = "gpt-tiny", seed: int = 0,
+                tp: int = 1) -> Tuple[Any, Any]:
     """-> (model, params). `model` is a registry name ("gpt-tiny",
     "llama-tiny", "gpt2-small", "llama2-7b"), or a dict
     {"family": "gpt"|"llama", **config_kwargs} for explicit sizing.
-    Params initialize from `seed` so disaggregated stages agree."""
+    Params initialize from `seed` so disaggregated stages agree. With
+    ``tp > 1`` they are born sharded on the tp mesh the engine will
+    build over the same devices: a model that only fits across chips
+    (llama2-7b is 27 GB of float32) never sits whole on the first."""
     import jax
     import jax.numpy as jnp
 
@@ -70,8 +74,14 @@ def build_model(model: Any = "gpt-tiny", seed: int = 0) -> Tuple[Any, Any]:
         m = Llama(cfg)
     else:
         raise ValueError(f"unknown model family {family!r}")
-    params = jax.jit(m.init)(jax.random.PRNGKey(seed))
-    return m, params
+    if tp > 1:
+        from ...parallel.sharding import MeshOwner, sharded_init
+
+        owner = MeshOwner.tp_mesh(tp, name="llm-init")
+        init = sharded_init(m.init, owner, owner.layout.param_specs(m))
+    else:
+        init = jax.jit(m.init)
+    return m, init(jax.random.PRNGKey(seed))
 
 
 class LLMServer:
@@ -81,8 +91,8 @@ class LLMServer:
     def __init__(self, model: Any = "gpt-tiny",
                  engine_config: Optional[Dict[str, Any]] = None,
                  seed: int = 0, name: str = ""):
-        m, params = build_model(model, seed=seed)
         cfg = EngineConfig(**(engine_config or {}))
+        m, params = build_model(model, seed=seed, tp=cfg.tp)
         self.engine = LLMEngine(m, params, cfg, name=name or "serve")
         self.engine.start()
 

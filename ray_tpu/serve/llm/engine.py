@@ -266,14 +266,16 @@ class LLMEngine:
         self.tp = int(cfg.tp)
         self.owner = None
         self.pool = BlockPool(cfg.num_blocks, shards=self.tp)
-        self._cache = model.init_paged_cache(cfg.num_blocks, cfg.block_size)
         self._cache_sharding = None
-        if self.tp > 1:
+        if self.tp == 1:
+            self._cache = model.init_paged_cache(cfg.num_blocks,
+                                                 cfg.block_size)
+        else:
             # sharded execution layer (docs/SHARDING.md): one mesh per
             # replica; params shard per SpecLayout family (heads/FFN/
             # vocab on tp), the KV pool block-shards per chip, and the
             # host-side scheduler stays unchanged
-            from ...parallel.sharding import MeshOwner
+            from ...parallel.sharding import MeshOwner, sharded_init
 
             self.owner = MeshOwner.tp_mesh(self.tp,
                                            name=f"llm-{self.name}")
@@ -281,11 +283,13 @@ class LLMEngine:
             self.params = params = {
                 n: jax.device_put(v, self.owner.sharding(pspecs[n]))
                 for n, v in params.items()}
-            self._cache_sharding = self.owner.sharding(
-                self.owner.layout.kv_cache_blocks())
-            self._cache = {
-                k: jax.device_put(v, self._cache_sharding)
-                for k, v in self._cache.items()}
+            kvspec = self.owner.layout.kv_cache_blocks()
+            self._cache_sharding = self.owner.sharding(kvspec)
+            # born block-sharded: the whole pool never sits on one chip
+            self._cache = sharded_init(
+                lambda: model.init_paged_cache(cfg.num_blocks,
+                                               cfg.block_size),
+                self.owner, {"k": kvspec, "v": kvspec})()
         self._lock = threading.RLock()
         self._waiting: "collections.deque[Request]" = collections.deque()
         self._running: List[_Sequence] = []

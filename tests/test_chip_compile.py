@@ -1,0 +1,139 @@
+"""The main path's programs compile for the chip: a DESCRIBED TPU v5e, not an
+attached one (on-chip-measurement guide, section 2). Nothing runs, so these
+say nothing about results or speed; they catch what the chip's compiler
+refuses (tiling, VMEM, HBM) before a chip run has to.
+
+The topology is described only inside the module-scoped fixture below, so
+importing this file loads nothing, and only the xdist worker that runs it
+takes libtpu. The shapes are chip_smoke.py's: GPT-2 small at full width,
+S=1024, the train batch chosen there and the serve engine's programs.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 15.75e9  # what the v5e compiler reports as its HBM capacity
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile is written to the persistent cache but can
+    # never be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The kernels decide interpret mode from jax.default_backend(), which
+    is the CPU here: steer them to the compiled path for these tests."""
+    for mod in ("ray_tpu.ops.flash_attention", "ray_tpu.ops.fused"):
+        monkeypatch.setattr(importlib.import_module(mod), "_use_interpret",
+                            lambda: False)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _fits(compiled) -> float:
+    m = compiled.memory_analysis()
+    resident = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes)
+    assert resident < HBM_BYTES
+    return resident
+
+
+def test_flash_attention_fwd_bwd_gpt2_small(one_chip, compiled_kernels):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    # forward kernel + the fused single-block backward kernel
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("n,d,f", [(8192, 768, 3072), (8192, 768, 2304)])
+def test_fused_entry_exit_kernels(one_chip, compiled_kernels, n, d, f):
+    from ray_tpu.ops.fused import ln_matmul, matmul_residual
+
+    bf = jnp.bfloat16
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, bf, sharding=one_chip)  # noqa: E731
+    text = jax.jit(ln_matmul).lower(
+        sds(n, d), sds(d), sds(d), sds(d, f), sds(f)).compile().as_text()
+    assert "tpu_custom_call" in text
+    text = jax.jit(matmul_residual).lower(
+        sds(n, f), sds(f, d), sds(d), sds(n, d)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
+    """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
+    B=8, S=1024, params and optimizer state donated."""
+    import optax
+
+    from ray_tpu.models import GPT, GPTConfig
+
+    model = GPT(GPTConfig.small(dtype=jnp.bfloat16, use_flash=True))
+    tx = optax.adamw(3e-4, weight_decay=0.1)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tx.init, params)
+
+    def step(params, opt_state, tokens, targets):
+        loss, grads = jax.value_and_grad(model.loss)(params, tokens, targets)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return loss, optax.apply_updates(params, updates), opt_state
+
+    tok = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        _on(one_chip, params), _on(one_chip, opt), tok, tok).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # params f32 + two adam moments, nothing else resident across steps
+    assert 1.4e9 < _fits(compiled) < 1.6e9
+
+
+def test_gpt2_small_engine_programs(one_chip):
+    """The serve engine's prefill (512 bucket) and decode (8 slots)
+    programs at gpt2-small width over chip_smoke.py's KV pool. This path
+    holds no Pallas kernel: paged prefill calls mha_reference and paged
+    attention is plain jnp."""
+    from ray_tpu.models import GPT, GPTConfig
+
+    model = GPT(GPTConfig.small())
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: model.init_paged_cache(320, 16)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    prefill = jax.jit(model.paged_prefill).lower(
+        params, cache, i32(1, 512), i32(), i32(40)).compile()
+    assert "tpu_custom_call" not in prefill.as_text()
+    _fits(prefill)
+    decode = jax.jit(model.paged_decode_step).lower(
+        params, cache, i32(8), i32(8), i32(8, 40),
+        jax.ShapeDtypeStruct((8,), jnp.bool_, sharding=one_chip)).compile()
+    _fits(decode)

@@ -272,3 +272,82 @@ class TestLauncher:
         # state file removed
         assert not os.path.exists(
             os.path.join(L.STATE_DIR, "launchtest.json"))
+
+
+# ---- one process per chip (core/worker_env.py) ------------------------------
+
+def test_only_a_tpu_lease_can_reach_the_chip(monkeypatch):
+    """With nothing pinning the platform from outside, a worker whose
+    lease holds no TPU is born pinned to the CPU; one whose lease holds
+    TPU inherits the parent's (unpinned) choice and the compile cache."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    ray_tpu.init(num_cpus=2, num_tpus=1)
+    try:
+        @ray_tpu.remote(num_cpus=1)
+        def env_of():
+            import os
+            return (os.environ.get("JAX_PLATFORMS"),
+                    os.environ.get("JAX_COMPILATION_CACHE_DIR"), os.getpid())
+
+        host = ray_tpu.get(env_of.remote(), timeout=60)
+        chip = ray_tpu.get(env_of.options(num_tpus=1).remote(), timeout=60)
+        assert host[0] == "cpu"
+        assert chip[0] is None
+        assert chip[1] and chip[1].endswith(".jax_cache")
+        assert host[2] != chip[2]
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_no_tpu_resource_no_chip_worker(monkeypatch):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ray_tpu.init(num_cpus=2)
+    try:
+        @ray_tpu.remote
+        class A:
+            def platform(self):
+                import os
+                return os.environ.get("JAX_PLATFORMS")
+
+        @ray_tpu.remote(num_cpus=1)
+        def platform():
+            import os
+            return os.environ.get("JAX_PLATFORMS")
+
+        assert ray_tpu.get(platform.remote(), timeout=60) == "cpu"
+        assert ray_tpu.get(A.remote().platform.remote(), timeout=60) == "cpu"
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_two_tpu_actors_never_live_at_once():
+    """num_tpus=1: the second actor's process is started only after the
+    first one's process has exited, whatever order kill and create race in."""
+    ray_tpu.init(num_cpus=4, num_tpus=1)
+    try:
+        @ray_tpu.remote(num_tpus=1, num_cpus=1)
+        class Holder:
+            def __init__(self, other_pid=None):
+                import os
+                self.other_alive = (other_pid is not None
+                                    and os.path.exists(f"/proc/{other_pid}"))
+
+            def pid(self):
+                import os
+                return os.getpid()
+
+            def saw_other_alive(self):
+                return self.other_alive
+
+        a = Holder.remote()
+        a_pid = ray_tpu.get(a.pid.remote(), timeout=60)
+        b = Holder.remote(a_pid)
+        ready, _ = ray_tpu.wait([b.pid.remote()], timeout=1.0)
+        assert not ready  # the chip is taken: b waits
+        ray_tpu.kill(a)
+        b_pid = ray_tpu.get(b.pid.remote(), timeout=60)
+        assert b_pid != a_pid
+        assert ray_tpu.get(b.saw_other_alive.remote(), timeout=60) is False
+    finally:
+        ray_tpu.shutdown()
