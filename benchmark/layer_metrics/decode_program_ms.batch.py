@@ -1,0 +1,7 @@
+"""Median device time of one execution of the decode program."""
+from benchmark.layer_metrics._common import decode_program_ms as read  # noqa: F401
+
+LAYER = "models"
+UNIT = "ms"
+MOVES = "serve_tokens_per_s"
+SOURCE = "device_trace"
