@@ -1,0 +1,12 @@
+"""Median per decode step of the scheduler's span rtpu.llm.decode.sample:
+argmax over the batch's logits and the emit of one token per sequence."""
+from benchmark.layer_metrics._program import decode_span_ms
+
+LAYER = "engine"
+UNIT = "ms"
+MOVES = "serve_tokens_per_s"
+SOURCE = "program_span"
+
+
+def read(view):
+    return decode_span_ms(view, "sample")

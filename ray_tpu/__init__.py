@@ -83,38 +83,43 @@ def init(num_cpus: Optional[float] = None,
 
             existing.default_runtime_env = _renv_mod.validate(runtime_env)
         return existing
-    res: Dict[str, float] = dict(resources or {})
-    res.setdefault("CPU", float(num_cpus if num_cpus is not None
-                                else (os.cpu_count() or 1)))
-    if num_tpus is not None:
-        res["TPU"] = float(num_tpus)
-    if object_store_memory is not None:
-        res["object_store_memory"] = float(object_store_memory)
-    rt = DriverRuntime(resources=res, num_nodes=num_nodes,
-                       config=Config(system_config), namespace=namespace)
-    if int(rt.config.metrics_export_port):
-        # opt-in Prometheus exposition at a fixed port (config/env
-        # RTPU_METRICS_EXPORT_PORT); ephemeral-port serving remains
-        # available any time via metrics.start_metrics_server()
-        from .util import metrics as _metrics_mod
+    from .perf.recorder import get_recorder
 
-        global _metrics_server_from_init
-        was_running = _metrics_mod._server is not None
-        try:
-            _metrics_mod.start_metrics_server(
-                port=int(rt.config.metrics_export_port))
-            # only own the lifecycle when init() actually bound it — a
-            # user-started server must survive ray_tpu.shutdown()
-            _metrics_server_from_init = not was_running
-        except OSError:
-            pass  # port taken: init must not fail over observability
-    if runtime_env:
-        # job-level default: merged under every task/actor env (ref:
-        # job_config.py runtime_env; validated now so errors hit at init)
-        from .core import runtime_env as _renv_mod
+    # start-up spans end in THIS process's ring (docs/OBSERVABILITY.md):
+    # rtpu.core.init here, its children .gcs and .node in DriverRuntime
+    with get_recorder().span("rtpu.core.init", pin=True):
+        res: Dict[str, float] = dict(resources or {})
+        res.setdefault("CPU", float(num_cpus if num_cpus is not None
+                                    else (os.cpu_count() or 1)))
+        if num_tpus is not None:
+            res["TPU"] = float(num_tpus)
+        if object_store_memory is not None:
+            res["object_store_memory"] = float(object_store_memory)
+        rt = DriverRuntime(resources=res, num_nodes=num_nodes,
+                           config=Config(system_config), namespace=namespace)
+        if int(rt.config.metrics_export_port):
+            # opt-in Prometheus exposition at a fixed port (config/env
+            # RTPU_METRICS_EXPORT_PORT); ephemeral-port serving remains
+            # available any time via metrics.start_metrics_server()
+            from .util import metrics as _metrics_mod
 
-        rt.default_runtime_env = _renv_mod.validate(runtime_env)
-    _runtime_mod.set_runtime(rt)
+            global _metrics_server_from_init
+            was_running = _metrics_mod._server is not None
+            try:
+                _metrics_mod.start_metrics_server(
+                    port=int(rt.config.metrics_export_port))
+                # only own the lifecycle when init() actually bound it — a
+                # user-started server must survive ray_tpu.shutdown()
+                _metrics_server_from_init = not was_running
+            except OSError:
+                pass  # port taken: init must not fail over observability
+        if runtime_env:
+            # job-level default: merged under every task/actor env (ref:
+            # job_config.py runtime_env; validated now so errors hit at init)
+            from .core import runtime_env as _renv_mod
+
+            rt.default_runtime_env = _renv_mod.validate(runtime_env)
+        _runtime_mod.set_runtime(rt)
     return rt
 
 

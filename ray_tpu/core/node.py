@@ -36,6 +36,9 @@ from .resources import ResourceSet, normalize, res_add, res_ge, res_sub
 from .rpc import RpcChannel, RpcServer, cluster_token
 from .task_spec import TaskSpec, TaskType
 from .worker_env import is_chip, lease_env_hash, worker_env
+from ..perf.recorder import get_recorder as _get_recorder
+
+_FLREC = _get_recorder()
 
 
 @dataclass
@@ -64,6 +67,8 @@ class WorkerHandle:
     # peer-facing direct-call socket this worker listens on (direct
     # dispatch; resolve_actor hands it to callers — docs/DISPATCH.md)
     direct_addr: Optional[str] = None
+    # open rtpu.core.worker_spawn span: process asked for -> registered
+    spawn_span: Optional[tuple] = None
 
 
 @dataclass
@@ -473,9 +478,13 @@ class Node:
 
             cmd = container_command(self.config.container_launcher,
                                     container, cmd)
+        spawn_span = _FLREC.begin(
+            "rtpu.core.worker_spawn", worker_id.hex()[:8], {"chip": chip},
+            pin=True)
         proc = subprocess.Popen(cmd, env=env)
         handle = WorkerHandle(worker_id=worker_id, proc=proc, pid=proc.pid,
-                              started_at=time.monotonic())
+                              started_at=time.monotonic(),
+                              spawn_span=spawn_span)
         if env_hash is not None:
             handle.env_hash = env_hash  # container and chip workers:
             # dedicated from birth (the env can't be applied to a host
@@ -511,6 +520,13 @@ class Node:
                                       pid=payload.get("pid", 0))
                 self._workers[worker_id] = handle
             handle.channel = channel
+            if handle.spawn_span is not None:
+                # the worker's own stamps (process start, worker_main
+                # entered, imports done: wall clock) ride its message
+                _FLREC.end(handle.spawn_span,
+                           {"pid": payload.get("pid", handle.pid),
+                            "stamps": payload.get("stamps")})
+                handle.spawn_span = None
             handle.pid = payload.get("pid", handle.pid)
             handle.direct_addr = payload.get("direct_addr")
             handle.state = "idle"

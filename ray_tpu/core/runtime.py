@@ -255,8 +255,9 @@ class DriverRuntime:
         self.session_dir = session_dir or os.path.join(
             "/tmp/ray_tpu", f"session_{int(time.time() * 1000)}_{os.getpid()}")
         os.makedirs(self.session_dir, exist_ok=True)
-        self.gcs = Gcs(storage_path=self.config.gcs_storage_path,
-                       config=self.config)
+        with _FLREC.span("rtpu.core.init.gcs", pin=True):
+            self.gcs = Gcs(storage_path=self.config.gcs_storage_path,
+                           config=self.config)
         self.gcs.register_job(JobInfo(job_id=self.job_id, driver_pid=os.getpid()))
         self.gcs.schedule_actor_cb = self._restart_actor
         self.gcs.pubsub.subscribe("actor", self._on_actor_state)
@@ -318,7 +319,8 @@ class DriverRuntime:
                          name="pg-placer").start()
         default_res = resources or {"CPU": float(os.cpu_count() or 1)}
         for i in range(num_nodes):
-            self.add_node(dict(default_res))
+            with _FLREC.span("rtpu.core.init.node", str(i), pin=True):
+                self.add_node(dict(default_res))
         self.head_node_id = next(iter(self.nodes), None)
         # refs the driver receives INSIDE fetched values (borrows) must be
         # counted like refs it created via make_ref
@@ -1591,7 +1593,7 @@ class DriverRuntime:
             if spec.num_returns == STREAMING_RETURNS:
                 self._generator_finish(spec.task_id, error=error)
             if spec.task_type == TaskType.ACTOR_CREATION_TASK:
-                self._on_actor_creation_failed(spec, node_id, worker)
+                self._on_actor_creation_failed(spec, node_id, worker, error)
         else:
             results = payload.get("results") or []
             borrowed = payload.get("borrowed") or []
@@ -1725,11 +1727,18 @@ class DriverRuntime:
         self._flush_actor_queue(spec.actor_id)
 
     def _on_actor_creation_failed(self, spec: TaskSpec, node_id: NodeId,
-                                  worker: WorkerHandle) -> None:
+                                  worker: WorkerHandle,
+                                  error: bytes) -> None:
+        # the constructor's own traceback is the death cause: whoever
+        # calls the actor next reads why it never came up
+        cause = "creation task failed"
+        try:
+            cause += f":\n{serialization.loads(error)}"
+        except Exception:  # noqa: BLE001 - the cause is best effort
+            pass
         self.gcs.set_actor_state(spec.actor_id, ActorState.DEAD,
-                                 death_cause="creation task failed")
-        self._drain_actor_queue_with_error(spec.actor_id,
-                                           "actor creation failed")
+                                 death_cause=cause)
+        self._drain_actor_queue_with_error(spec.actor_id, cause)
         # the dedicated worker holds a lease; tear it down so resources return
         node = self.nodes.get(node_id)
         if node is not None:
@@ -1754,7 +1763,10 @@ class DriverRuntime:
             # direct in-flights first: their routed resubmission hits the
             # DEAD record and surfaces the typed ActorDiedError
             self._recover_direct_inflight(actor_id)
-            self._drain_actor_queue_with_error(actor_id, "actor is dead")
+            info = self.gcs.get_actor(actor_id)
+            self._drain_actor_queue_with_error(
+                actor_id, (info.death_cause if info else "")
+                or "actor is dead")
         elif state == ActorState.RESTARTING:
             # re-queue un-answered direct calls through the head; they run
             # on the new incarnation in head-lane order

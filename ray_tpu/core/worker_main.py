@@ -15,6 +15,10 @@ channel to the node — the worker never blocks its RPC reader.
 """
 from __future__ import annotations
 
+# entered: after the interpreter's start and ``import ray_tpu`` (this is
+# ``python -m ray_tpu.core.worker_main``), before this module's imports
+_T_ENTERED = __import__("time").time()
+
 import argparse
 import asyncio
 import inspect
@@ -828,7 +832,22 @@ def _make_cancelled_error(spec: TaskSpec):
     return TaskCancelledError(f"Task {spec.description} was cancelled")
 
 
+def _process_start_wall() -> Optional[float]:
+    """Wall-clock time at which the kernel started this process, to a
+    clock tick (Linux: now less the process's age, which is the time
+    since boot less the start tick of /proc/self/stat), else None."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+        return time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 def main() -> None:
+    t_imports_done = time.time()
     parser = argparse.ArgumentParser()
     parser.add_argument("--address", required=True)
     parser.add_argument("--worker-id", required=True)
@@ -880,10 +899,15 @@ def main() -> None:
         channel.on_close(lambda: (_dump(), os._exit(0)))
     else:
         channel.on_close(lambda: os._exit(0))
-    resp = channel.call("register", {"worker_id": worker_id,
-                                     "pid": os.getpid(),
-                                     "direct_addr": wp.direct_addr},
-                        timeout=30)
+    resp = channel.call("register", {
+        "worker_id": worker_id, "pid": os.getpid(),
+        "direct_addr": wp.direct_addr,
+        # where this process's start-up went, for the node's
+        # rtpu.core.worker_spawn span (wall clock, seconds)
+        "stamps": {"process_start": _process_start_wall(),
+                   "main_entered": _T_ENTERED,
+                   "imports_done": t_imports_done,
+                   "register_sent": time.time()}}, timeout=30)
     if isinstance(resp, dict) and resp.get("forward_logs"):
         # tee prints into the attributed log plane (and still to the
         # local console); remote nodes additionally get driver mirroring

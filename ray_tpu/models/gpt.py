@@ -191,58 +191,66 @@ class GPT:
         drop = c.dropout > 0.0 and key is not None
         if drop:
             k_attn, k_mlp = jax.random.split(key)
-        if c.fused_entry_exit:
-            from ..ops.fused import ln_matmul
+        fused_exit = c.fused_entry_exit and not drop
+        # the two halves of a block are named scopes (metadata only):
+        # a device trace charges every operation, forward, backward and
+        # recomputed, to the scope in its op_name
+        with jax.named_scope("attn"):
+            if c.fused_entry_exit:
+                from ..ops.fused import ln_matmul, matmul_residual
 
-            qkv = ln_matmul(
-                x.reshape(B * S, D), lp["ln1_g"], lp["ln1_b"],
-                lp["w_qkv"].astype(c.dtype),
-                lp["b_qkv"].astype(c.dtype)).reshape(B, S, 3 * D)
-        else:
-            h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
-            qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
-                + lp["b_qkv"].astype(c.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, S, H, hd)
-        k = k.reshape(B, S, H, hd)
-        v = v.reshape(B, S, H, hd)
-        if c.seq_axis is not None:
-            attn = ring_attention(q, k, v, axis_name=c.seq_axis, causal=True)
-        elif c.use_flash:
-            attn = flash_attention(q, k, v, causal=True,
-                                   block_q=c.flash_block_q,
-                                   block_k=c.flash_block_k)
-        else:
-            from ..ops import mha_reference
+                qkv = ln_matmul(
+                    x.reshape(B * S, D), lp["ln1_g"], lp["ln1_b"],
+                    lp["w_qkv"].astype(c.dtype),
+                    lp["b_qkv"].astype(c.dtype)).reshape(B, S, 3 * D)
+            else:
+                h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
+                qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
+                    + lp["b_qkv"].astype(c.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, S, H, hd)
+            k = k.reshape(B, S, H, hd)
+            v = v.reshape(B, S, H, hd)
+            if c.seq_axis is not None:
+                attn = ring_attention(q, k, v, axis_name=c.seq_axis,
+                                      causal=True)
+            elif c.use_flash:
+                attn = flash_attention(q, k, v, causal=True,
+                                       block_q=c.flash_block_q,
+                                       block_k=c.flash_block_k)
+            else:
+                from ..ops import mha_reference
 
-            attn = mha_reference(q, k, v, causal=True)
-        attn = attn.reshape(B, S, D)
-        if c.fused_entry_exit and not drop:
-            from ..ops.fused import ln_matmul, matmul_residual
-
-            x = matmul_residual(attn.reshape(B * S, D),
-                                lp["w_proj"].astype(c.dtype),
-                                lp["b_proj"].astype(c.dtype),
-                                x.reshape(B * S, D)).reshape(B, S, D)
-            h = ln_matmul(x.reshape(B * S, D), lp["ln2_g"], lp["ln2_b"],
-                          lp["w_fc"].astype(c.dtype),
-                          lp["b_fc"].astype(c.dtype))
-            h = gelu(h)
-            x = matmul_residual(h, lp["w_out"].astype(c.dtype),
-                                lp["b_out"].astype(c.dtype),
-                                x.reshape(B * S, D)).reshape(B, S, D)
-            return x
-        proj = (attn @ lp["w_proj"].astype(c.dtype)) + lp["b_proj"].astype(c.dtype)
-        if drop:
-            proj = self._dropout(proj, k_attn)
-        x = x + proj
-        h = layernorm(x, lp["ln2_g"], lp["ln2_b"])
-        h = gelu((h @ lp["w_fc"].astype(c.dtype)) + lp["b_fc"].astype(c.dtype))
-        out = (h @ lp["w_out"].astype(c.dtype)) + lp["b_out"].astype(c.dtype)
-        if drop:
-            out = self._dropout(out, k_mlp)
-        x = x + out
-        return x
+                attn = mha_reference(q, k, v, causal=True)
+            attn = attn.reshape(B, S, D)
+            if fused_exit:
+                x = matmul_residual(attn.reshape(B * S, D),
+                                    lp["w_proj"].astype(c.dtype),
+                                    lp["b_proj"].astype(c.dtype),
+                                    x.reshape(B * S, D)).reshape(B, S, D)
+            else:
+                proj = (attn @ lp["w_proj"].astype(c.dtype)) \
+                    + lp["b_proj"].astype(c.dtype)
+                if drop:
+                    proj = self._dropout(proj, k_attn)
+                x = x + proj
+        with jax.named_scope("mlp"):
+            if fused_exit:
+                h = ln_matmul(x.reshape(B * S, D), lp["ln2_g"], lp["ln2_b"],
+                              lp["w_fc"].astype(c.dtype),
+                              lp["b_fc"].astype(c.dtype))
+                h = gelu(h)
+                return matmul_residual(h, lp["w_out"].astype(c.dtype),
+                                       lp["b_out"].astype(c.dtype),
+                                       x.reshape(B * S, D)).reshape(B, S, D)
+            h = layernorm(x, lp["ln2_g"], lp["ln2_b"])
+            h = gelu((h @ lp["w_fc"].astype(c.dtype))
+                     + lp["b_fc"].astype(c.dtype))
+            out = (h @ lp["w_out"].astype(c.dtype)) \
+                + lp["b_out"].astype(c.dtype)
+            if drop:
+                out = self._dropout(out, k_mlp)
+            return x + out
 
     @staticmethod
     def _remat_policy():
@@ -267,15 +275,18 @@ class GPT:
         c = self.config
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
-        return wte.astype(c.dtype)[tokens] + wpe.astype(c.dtype)[positions]
+        with jax.named_scope("embed"):
+            return wte.astype(c.dtype)[tokens] \
+                + wpe.astype(c.dtype)[positions]
 
     def _lm_head(self, head_w: jax.Array, x: jax.Array) -> jax.Array:
         """Tied LM head in bf16 on the MXU fast path, f32 accumulation —
         a f32xf32 matmul here runs at 1/4 MXU rate and doubles HBM
         traffic on the [B,S,V] logits. Single definition for all paths."""
-        return jnp.einsum("bsd,vd->bsv", x,
-                          head_w.astype(self.config.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bsd,vd->bsv", x,
+                              head_w.astype(self.config.dtype),
+                              preferred_element_type=jnp.float32)
 
     def apply(self, params: Dict[str, jax.Array], tokens: jax.Array,
               positions: Optional[jax.Array] = None,
@@ -287,7 +298,8 @@ class GPT:
     def loss(self, params: Dict[str, jax.Array], tokens: jax.Array,
              targets: jax.Array, rng: Optional[jax.Array] = None) -> jax.Array:
         logits = self.apply(params, tokens, rng=rng)
-        return cross_entropy_loss(logits, targets)
+        with jax.named_scope("loss"):
+            return cross_entropy_loss(logits, targets)
 
     def loss_chunked(self, params: Dict[str, jax.Array], tokens: jax.Array,
                      targets: jax.Array, rng: Optional[jax.Array] = None,
@@ -319,12 +331,14 @@ class GPT:
                            policy=jax.checkpoint_policies.nothing_saveable)
         def chunk_nll(carry, xt_tg):
             xc, tc = xt_tg
-            logits = jnp.einsum("td,vd->tv", xc, wte,
-                                preferred_element_type=jnp.float32)
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, tc[:, None], axis=-1)[:, 0]
-            return carry + jnp.sum(lse - gold), None
+            with jax.named_scope("lm_head"):
+                logits = jnp.einsum("td,vd->tv", xc, wte,
+                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("loss"):
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, tc[:, None], axis=-1)[:, 0]
+                return carry + jnp.sum(lse - gold), None
 
         total, _ = jax.lax.scan(chunk_nll, jnp.float32(0.0), (xt, tg))
         return total / T
@@ -400,10 +414,12 @@ class GPT:
 
     def _paged_mlp(self, x: jax.Array, lp: Dict[str, jax.Array]) -> jax.Array:
         c = self.config
-        h = layernorm(x, lp["ln2_g"], lp["ln2_b"])
-        h = gelu((h @ lp["w_fc"].astype(c.dtype)) + lp["b_fc"].astype(c.dtype))
-        return x + (h @ lp["w_out"].astype(c.dtype)) \
-            + lp["b_out"].astype(c.dtype)
+        with jax.named_scope("mlp"):
+            h = layernorm(x, lp["ln2_g"], lp["ln2_b"])
+            h = gelu((h @ lp["w_fc"].astype(c.dtype))
+                     + lp["b_fc"].astype(c.dtype))
+            return x + (h @ lp["w_out"].astype(c.dtype)) \
+                + lp["b_out"].astype(c.dtype)
 
     def paged_prefill(self, params: Dict[str, jax.Array],
                       cache: Dict[str, jax.Array], tokens: jax.Array,
@@ -424,25 +440,38 @@ class GPT:
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = self._paged_layer_params(params, li)
-            h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
-            qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
-                + lp["b_qkv"].astype(c.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(1, S, H, hd)
-            k = k.reshape(1, S, H, hd)
-            v = v.reshape(1, S, H, hd)
-            attn = mha_reference(q, k, v, causal=True)
-            new_k.append(paged_write_prefill(kc[li], block_row, k[0], length))
-            new_v.append(paged_write_prefill(vc[li], block_row, v[0], length))
-            x = x + attn.reshape(1, S, H * hd) @ lp["w_proj"].astype(c.dtype) \
-                + lp["b_proj"].astype(c.dtype)
+            with jax.named_scope("attn"):
+                h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
+                qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
+                    + lp["b_qkv"].astype(c.dtype)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(1, S, H, hd)
+                k = k.reshape(1, S, H, hd)
+                v = v.reshape(1, S, H, hd)
+                with jax.named_scope("paged_attn"):
+                    attn = mha_reference(q, k, v, causal=True)
+                with jax.named_scope("kv_write"):
+                    new_k.append(paged_write_prefill(kc[li], block_row,
+                                                     k[0], length))
+                    new_v.append(paged_write_prefill(vc[li], block_row,
+                                                     v[0], length))
+                x = x + attn.reshape(1, S, H * hd) \
+                    @ lp["w_proj"].astype(c.dtype) \
+                    + lp["b_proj"].astype(c.dtype)
             x = self._paged_mlp(x, lp)
-        x = layernorm(x, params["lnf_g"], params["lnf_b"])
-        last = jax.lax.dynamic_index_in_dim(
-            x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False)
-        logits = jnp.einsum("d,vd->v", last.astype(jnp.float32),
-                            params["wte"].astype(jnp.float32))
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        return self._paged_head(params, x[0], length), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+
+    def _paged_head(self, params: Dict[str, jax.Array], x: jax.Array,
+                    length: jax.Array) -> jax.Array:
+        """Final norm and float32 logits of the last real token of one
+        prefilled sequence: x [S, D] -> [V]."""
+        with jax.named_scope("lm_head"):
+            x = layernorm(x, params["lnf_g"], params["lnf_b"])
+            last = jax.lax.dynamic_index_in_dim(
+                x, jnp.maximum(length - 1, 0), axis=0, keepdims=False)
+            return jnp.einsum("d,vd->v", last.astype(jnp.float32),
+                              params["wte"].astype(jnp.float32))
 
     def paged_prefill_extend(self, params: Dict[str, jax.Array],
                              cache: Dict[str, jax.Array],
@@ -468,27 +497,30 @@ class GPT:
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = self._paged_layer_params(params, li)
-            h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
-            qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
-                + lp["b_qkv"].astype(c.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            kl = paged_write_prefill(kc[li], block_row,
-                                     k.reshape(S, H, hd), length, start)
-            vl = paged_write_prefill(vc[li], block_row,
-                                     v.reshape(S, H, hd), length, start)
-            new_k.append(kl)
-            new_v.append(vl)
-            attn = paged_attention_prefill(q.reshape(S, H, hd), kl, vl,
-                                           block_row, start, length)
-            x = x + attn.reshape(1, S, H * hd) @ lp["w_proj"].astype(c.dtype) \
-                + lp["b_proj"].astype(c.dtype)
+            with jax.named_scope("attn"):
+                h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
+                qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
+                    + lp["b_qkv"].astype(c.dtype)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                with jax.named_scope("kv_write"):
+                    kl = paged_write_prefill(kc[li], block_row,
+                                             k.reshape(S, H, hd), length,
+                                             start)
+                    vl = paged_write_prefill(vc[li], block_row,
+                                             v.reshape(S, H, hd), length,
+                                             start)
+                new_k.append(kl)
+                new_v.append(vl)
+                with jax.named_scope("paged_attn"):
+                    attn = paged_attention_prefill(
+                        q.reshape(S, H, hd), kl, vl, block_row, start,
+                        length)
+                x = x + attn.reshape(1, S, H * hd) \
+                    @ lp["w_proj"].astype(c.dtype) \
+                    + lp["b_proj"].astype(c.dtype)
             x = self._paged_mlp(x, lp)
-        x = layernorm(x, params["lnf_g"], params["lnf_b"])
-        last = jax.lax.dynamic_index_in_dim(
-            x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False)
-        logits = jnp.einsum("d,vd->v", last.astype(jnp.float32),
-                            params["wte"].astype(jnp.float32))
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        return self._paged_head(params, x[0], length), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     def paged_decode_step(self, params: Dict[str, jax.Array],
                           cache: Dict[str, jax.Array], tokens: jax.Array,
@@ -512,24 +544,29 @@ class GPT:
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = self._paged_layer_params(params, li)
-            h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
-            qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
-                + lp["b_qkv"].astype(c.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            kl = paged_write_step(kc[li], block_rows, positions,
-                                  k.reshape(B, H, hd), active)
-            vl = paged_write_step(vc[li], block_rows, positions,
-                                  v.reshape(B, H, hd), active)
-            new_k.append(kl)
-            new_v.append(vl)
-            attn = paged_attention_decode(q.reshape(B, H, hd), kl, vl,
-                                          block_rows, lengths)
-            x = x + attn.reshape(B, H * hd) @ lp["w_proj"].astype(c.dtype) \
-                + lp["b_proj"].astype(c.dtype)
+            with jax.named_scope("attn"):
+                h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
+                qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
+                    + lp["b_qkv"].astype(c.dtype)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                with jax.named_scope("kv_write"):
+                    kl = paged_write_step(kc[li], block_rows, positions,
+                                          k.reshape(B, H, hd), active)
+                    vl = paged_write_step(vc[li], block_rows, positions,
+                                          v.reshape(B, H, hd), active)
+                new_k.append(kl)
+                new_v.append(vl)
+                with jax.named_scope("paged_attn"):
+                    attn = paged_attention_decode(
+                        q.reshape(B, H, hd), kl, vl, block_rows, lengths)
+                x = x + attn.reshape(B, H * hd) \
+                    @ lp["w_proj"].astype(c.dtype) \
+                    + lp["b_proj"].astype(c.dtype)
             x = self._paged_mlp(x, lp)
-        x = layernorm(x, params["lnf_g"], params["lnf_b"])
-        logits = jnp.einsum("bd,vd->bv", x.astype(jnp.float32),
-                            params["wte"].astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            x = layernorm(x, params["lnf_g"], params["lnf_b"])
+            logits = jnp.einsum("bd,vd->bv", x.astype(jnp.float32),
+                                params["wte"].astype(jnp.float32))
         return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     # ---- pipeline-stage slicing (train/pipeline_cgraph.py) -----------------
@@ -578,7 +615,8 @@ class GPT:
             for i in range(c.n_layer):
                 lp = {k: v[i] for k, v in layer_params.items()}
                 x = blk(x, lp, rng)
-        return layernorm(x, params["lnf_g"], params["lnf_b"])
+        with jax.named_scope("lm_head"):     # the final norm goes with it
+            return layernorm(x, params["lnf_g"], params["lnf_b"])
 
 
 # ---------------------------------------------------------------------------

@@ -139,33 +139,31 @@ class Llama:
         c = self.config
         B, S, D = x.shape
         H, KH, hd = c.n_head, c.n_kv_head, c.head_dim
-        h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
-        q = (h @ lp["w_q"].astype(c.dtype)).reshape(B, S, H, hd)
-        k = (h @ lp["w_k"].astype(c.dtype)).reshape(B, S, KH, hd)
-        v = (h @ lp["w_v"].astype(c.dtype)).reshape(B, S, KH, hd)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        if KH != H:  # GQA: broadcast kv heads to query heads
-            rep = H // KH
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        if c.use_flash:
-            attn = flash_attention(q, k, v, causal=True)
-        else:
-            attn = mha_reference(q, k, v, causal=True)
-        x = x + attn.reshape(B, S, H * hd) @ lp["w_o"].astype(c.dtype)
-        h = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
-        gate = jax.nn.silu(h @ lp["w_gate"].astype(c.dtype))
-        up = h @ lp["w_up"].astype(c.dtype)
-        x = x + (gate * up) @ lp["w_down"].astype(c.dtype)
-        return x
+        # named scopes as in models/gpt.py: embed, attn, mlp, lm_head, loss
+        with jax.named_scope("attn"):
+            h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
+            q = (h @ lp["w_q"].astype(c.dtype)).reshape(B, S, H, hd)
+            k = (h @ lp["w_k"].astype(c.dtype)).reshape(B, S, KH, hd)
+            v = (h @ lp["w_v"].astype(c.dtype)).reshape(B, S, KH, hd)
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+            if KH != H:  # GQA: broadcast kv heads to query heads
+                rep = H // KH
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+            if c.use_flash:
+                attn = flash_attention(q, k, v, causal=True)
+            else:
+                attn = mha_reference(q, k, v, causal=True)
+            x = x + attn.reshape(B, S, H * hd) @ lp["w_o"].astype(c.dtype)
+        return self._mlp(x, lp)
 
     def apply(self, params, tokens, positions=None):
         c = self.config
         B, S = tokens.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        x = params["wte"].astype(c.dtype)[tokens]
+        x = self._embed(params, tokens)
         cos, sin = rope_cache(c.max_seq, c.head_dim, c.rope_base)
         lp_names = [n for n, a in self.logical_axes().items()
                     if a[0] is None and len(a) > 1 and n not in ("out_norm",)]
@@ -177,12 +175,23 @@ class Llama:
         if c.remat:
             block_fn = jax.checkpoint(block_fn)
         x, _ = jax.lax.scan(block_fn, x, layer_params)
-        x = rmsnorm(x, params["out_norm"], c.rms_eps)
-        return jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
-                          params["lm_head"].astype(jnp.float32))
+        return self._head(params, x, "bsd,vd->bsv")
+
+    def _embed(self, params, tokens):
+        with jax.named_scope("embed"):
+            return params["wte"].astype(self.config.dtype)[tokens]
+
+    def _head(self, params, x, einsum: str):
+        """Final norm and float32 logits, for every entry point."""
+        with jax.named_scope("lm_head"):
+            x = rmsnorm(x, params["out_norm"], self.config.rms_eps)
+            return jnp.einsum(einsum, x.astype(jnp.float32),
+                              params["lm_head"].astype(jnp.float32))
 
     def loss(self, params, tokens, targets):
-        return cross_entropy_loss(self.apply(params, tokens), targets)
+        logits = self.apply(params, tokens)
+        with jax.named_scope("loss"):
+            return cross_entropy_loss(logits, targets)
 
     # ---- paged-KV serving path (ray_tpu.serve.llm) ------------------------
 
@@ -197,12 +206,13 @@ class Llama:
     _PAGED_LP = ("attn_norm", "w_q", "w_k", "w_v", "w_o", "mlp_norm",
                  "w_gate", "w_up", "w_down")
 
-    def _paged_mlp(self, x, lp):
+    def _mlp(self, x, lp):
         c = self.config
-        h = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
-        gate = jax.nn.silu(h @ lp["w_gate"].astype(c.dtype))
-        up = h @ lp["w_up"].astype(c.dtype)
-        return x + (gate * up) @ lp["w_down"].astype(c.dtype)
+        with jax.named_scope("mlp"):
+            h = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
+            gate = jax.nn.silu(h @ lp["w_gate"].astype(c.dtype))
+            up = h @ lp["w_up"].astype(c.dtype)
+            return x + (gate * up) @ lp["w_down"].astype(c.dtype)
 
     def paged_prefill(self, params, cache, tokens, length, block_row):
         """Prompt pass at a static bucket shape (see GPT.paged_prefill —
@@ -213,33 +223,42 @@ class Llama:
         c = self.config
         S = tokens.shape[1]
         H, KH, hd = c.n_head, c.n_kv_head, c.head_dim
-        x = params["wte"].astype(c.dtype)[tokens]              # [1, S, D]
+        x = self._embed(params, tokens)                        # [1, S, D]
         cos, sin = rope_cache(c.max_seq, hd, c.rope_base)
         kc, vc = cache["k"], cache["v"]
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = {n: params[n][li] for n in self._PAGED_LP}
-            h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
-            q = (h @ lp["w_q"].astype(c.dtype)).reshape(1, S, H, hd)
-            k = (h @ lp["w_k"].astype(c.dtype)).reshape(1, S, KH, hd)
-            v = (h @ lp["w_v"].astype(c.dtype)).reshape(1, S, KH, hd)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            new_k.append(paged_write_prefill(kc[li], block_row, k[0], length))
-            new_v.append(paged_write_prefill(vc[li], block_row, v[0], length))
-            if KH != H:
-                rep = H // KH
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            attn = mha_reference(q, k, v, causal=True)
-            x = x + attn.reshape(1, S, H * hd) @ lp["w_o"].astype(c.dtype)
-            x = self._paged_mlp(x, lp)
-        x = rmsnorm(x, params["out_norm"], c.rms_eps)
+            with jax.named_scope("attn"):
+                h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
+                q = (h @ lp["w_q"].astype(c.dtype)).reshape(1, S, H, hd)
+                k = (h @ lp["w_k"].astype(c.dtype)).reshape(1, S, KH, hd)
+                v = (h @ lp["w_v"].astype(c.dtype)).reshape(1, S, KH, hd)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+                with jax.named_scope("kv_write"):
+                    new_k.append(paged_write_prefill(kc[li], block_row,
+                                                     k[0], length))
+                    new_v.append(paged_write_prefill(vc[li], block_row,
+                                                     v[0], length))
+                if KH != H:
+                    rep = H // KH
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                with jax.named_scope("paged_attn"):
+                    attn = mha_reference(q, k, v, causal=True)
+                x = x + attn.reshape(1, S, H * hd) \
+                    @ lp["w_o"].astype(c.dtype)
+            x = self._mlp(x, lp)
+        return self._last_logits(params, x[0], length), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+
+    def _last_logits(self, params, x, length):
+        """x [S, D] of one prefilled sequence -> logits [V] of its last
+        real token."""
         last = jax.lax.dynamic_index_in_dim(
-            x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False)
-        logits = jnp.einsum("d,vd->v", last.astype(jnp.float32),
-                            params["lm_head"].astype(jnp.float32))
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+            x, jnp.maximum(length - 1, 0), axis=0, keepdims=False)
+        return self._head(params, last, "d,vd->v")
 
     def paged_prefill_extend(self, params, cache, tokens, start, length,
                              block_row):
@@ -253,35 +272,35 @@ class Llama:
         c = self.config
         S = tokens.shape[1]
         H, KH, hd = c.n_head, c.n_kv_head, c.head_dim
-        x = params["wte"].astype(c.dtype)[tokens]              # [1, S, D]
+        x = self._embed(params, tokens)                        # [1, S, D]
         cos, sin = rope_cache(c.max_seq, hd, c.rope_base)
         positions = (start + jnp.arange(S))[None]              # [1, S]
         kc, vc = cache["k"], cache["v"]
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = {n: params[n][li] for n in self._PAGED_LP}
-            h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
-            q = (h @ lp["w_q"].astype(c.dtype)).reshape(1, S, H, hd)
-            k = (h @ lp["w_k"].astype(c.dtype)).reshape(1, S, KH, hd)
-            v = (h @ lp["w_v"].astype(c.dtype)).reshape(1, S, KH, hd)
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            kl = paged_write_prefill(kc[li], block_row, k[0], length,
-                                     start)
-            vl = paged_write_prefill(vc[li], block_row, v[0], length,
-                                     start)
-            new_k.append(kl)
-            new_v.append(vl)
-            attn = paged_attention_prefill(q[0], kl, vl, block_row,
-                                           start, length)
-            x = x + attn.reshape(1, S, H * hd) @ lp["w_o"].astype(c.dtype)
-            x = self._paged_mlp(x, lp)
-        x = rmsnorm(x, params["out_norm"], c.rms_eps)
-        last = jax.lax.dynamic_index_in_dim(
-            x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False)
-        logits = jnp.einsum("d,vd->v", last.astype(jnp.float32),
-                            params["lm_head"].astype(jnp.float32))
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+            with jax.named_scope("attn"):
+                h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
+                q = (h @ lp["w_q"].astype(c.dtype)).reshape(1, S, H, hd)
+                k = (h @ lp["w_k"].astype(c.dtype)).reshape(1, S, KH, hd)
+                v = (h @ lp["w_v"].astype(c.dtype)).reshape(1, S, KH, hd)
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
+                with jax.named_scope("kv_write"):
+                    kl = paged_write_prefill(kc[li], block_row, k[0],
+                                             length, start)
+                    vl = paged_write_prefill(vc[li], block_row, v[0],
+                                             length, start)
+                new_k.append(kl)
+                new_v.append(vl)
+                with jax.named_scope("paged_attn"):
+                    attn = paged_attention_prefill(q[0], kl, vl, block_row,
+                                                   start, length)
+                x = x + attn.reshape(1, S, H * hd) \
+                    @ lp["w_o"].astype(c.dtype)
+            x = self._mlp(x, lp)
+        return self._last_logits(params, x[0], length), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     def paged_decode_step(self, params, cache, tokens, positions,
                           block_rows, active):
@@ -292,33 +311,34 @@ class Llama:
         c = self.config
         B = tokens.shape[0]
         H, KH, hd = c.n_head, c.n_kv_head, c.head_dim
-        x = params["wte"].astype(c.dtype)[tokens]              # [B, D]
+        x = self._embed(params, tokens)                        # [B, D]
         cos, sin = rope_cache(c.max_seq, hd, c.rope_base)
         kc, vc = cache["k"], cache["v"]
         lengths = positions + 1
         new_k, new_v = [], []
         for li in range(c.n_layer):
             lp = {n: params[n][li] for n in self._PAGED_LP}
-            h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
-            q = (h @ lp["w_q"].astype(c.dtype)).reshape(B, 1, H, hd)
-            k = (h @ lp["w_k"].astype(c.dtype)).reshape(B, 1, KH, hd)
-            v = (h @ lp["w_v"].astype(c.dtype)).reshape(B, 1, KH, hd)
-            q = apply_rope(q, cos, sin, positions[:, None])
-            k = apply_rope(k, cos, sin, positions[:, None])
-            kl = paged_write_step(kc[li], block_rows, positions,
-                                  k[:, 0], active)
-            vl = paged_write_step(vc[li], block_rows, positions,
-                                  v[:, 0], active)
-            new_k.append(kl)
-            new_v.append(vl)
-            attn = paged_attention_decode(q[:, 0], kl, vl, block_rows,
-                                          lengths)
-            x = x + attn.reshape(B, H * hd) @ lp["w_o"].astype(c.dtype)
-            x = self._paged_mlp(x, lp)
-        x = rmsnorm(x, params["out_norm"], c.rms_eps)
-        logits = jnp.einsum("bd,vd->bv", x.astype(jnp.float32),
-                            params["lm_head"].astype(jnp.float32))
-        return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+            with jax.named_scope("attn"):
+                h = rmsnorm(x, lp["attn_norm"], c.rms_eps)
+                q = (h @ lp["w_q"].astype(c.dtype)).reshape(B, 1, H, hd)
+                k = (h @ lp["w_k"].astype(c.dtype)).reshape(B, 1, KH, hd)
+                v = (h @ lp["w_v"].astype(c.dtype)).reshape(B, 1, KH, hd)
+                q = apply_rope(q, cos, sin, positions[:, None])
+                k = apply_rope(k, cos, sin, positions[:, None])
+                with jax.named_scope("kv_write"):
+                    kl = paged_write_step(kc[li], block_rows, positions,
+                                          k[:, 0], active)
+                    vl = paged_write_step(vc[li], block_rows, positions,
+                                          v[:, 0], active)
+                new_k.append(kl)
+                new_v.append(vl)
+                with jax.named_scope("paged_attn"):
+                    attn = paged_attention_decode(q[:, 0], kl, vl,
+                                                  block_rows, lengths)
+                x = x + attn.reshape(B, H * hd) @ lp["w_o"].astype(c.dtype)
+            x = self._mlp(x, lp)
+        return self._head(params, x, "bd,vd->bv"), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
 
     # ---- decode path (Serve) ----------------------------------------------
 

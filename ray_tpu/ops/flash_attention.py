@@ -31,6 +31,18 @@ from .attention import mha_reference
 
 _NEG_INF = -1e30
 
+# The names of the five kernels, as a device trace and the compiled HLO
+# show them (``name=`` on ``pl.pallas_call``; each stays a custom call to
+# ``tpu_custom_call``). Part of the measurement: pinned in
+# tests/test_tracing_names.py.
+KERNEL_NAMES = {
+    "fwd_single": "flash_fwd_single",   # forward, all of K/V in one block
+    "fwd": "flash_fwd",                 # forward, K/V streamed in blocks
+    "bwd_fused": "flash_bwd_fused",     # dq, dk, dv in one pass
+    "bwd_dq": "flash_bwd_dq",           # dq, one pass over K blocks
+    "bwd_dkv": "flash_bwd_dkv",         # dk and dv, one pass over Q blocks
+}
+
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -169,6 +181,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
+            name=KERNEL_NAMES["fwd_single"],
             interpret=_use_interpret(),
             cost_estimate=pl.CostEstimate(
                 flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
@@ -209,6 +222,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
         # only the K dim (scratch carry) is sequential
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=KERNEL_NAMES["fwd"],
         interpret=_use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
@@ -418,6 +432,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            name=KERNEL_NAMES["bwd_fused"],
             interpret=interp,
             cost_estimate=pl.CostEstimate(
                 flops=10 * bh * seq_q * seq_k * d // (2 if causal else 1),
@@ -443,6 +458,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=KERNEL_NAMES["bwd_dq"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
@@ -472,6 +488,7 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=KERNEL_NAMES["bwd_dkv"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
             flops=8 * bh * seq_q * seq_k * d // (2 if causal else 1),

@@ -14,12 +14,36 @@ tests), and then `run()` broadcasts a callable for SPMD execution.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, List, Optional, Sequence
 
 import ray_tpu
 from ray_tpu.core.placement_group import placement_group, remove_placement_group
 
+from ..perf.recorder import get_recorder
 from .mesh import MeshSpec
+
+
+class MeshReady(int):
+    """What ``setup_mesh`` answers: the worker's device count (every
+    caller that wants only that keeps working) with the seconds the
+    worker spent bringing jax up and building the mesh and the wall-clock
+    time at which the call reached it, so the driver's
+    ``rtpu.train.setup_mesh`` span can say what it waited for (before
+    ``entered``: the lease, the worker's process, the actor's
+    construction) without another call."""
+
+    def __new__(cls, devices: int, jax_start_s: float = 0.0,
+                mesh_s: float = 0.0, entered: float = 0.0):
+        self = super().__new__(cls, devices)
+        self.jax_start_s = float(jax_start_s)
+        self.mesh_s = float(mesh_s)
+        self.entered = float(entered)
+        return self
+
+    def __reduce__(self):
+        return (MeshReady, (int(self), self.jax_start_s, self.mesh_s,
+                            self.entered))
 
 
 class MeshWorkerMixin:
@@ -34,8 +58,30 @@ class MeshWorkerMixin:
     def setup_mesh(self, process_id: int, num_processes: int,
                    coordinator: Optional[str], spec_kwargs: dict,
                    devices_per_process: Optional[int] = None) -> int:
-        import jax
+        """-> the number of devices of this worker's mesh, as a
+        :class:`MeshReady`: an int that also says where the seconds of
+        the call went (jax start-up, building the mesh)."""
+        rec = get_recorder()
+        entered = time.time()
+        with rec.span("rtpu.train.jax_start") as jax_start:
+            import jax
 
+            devs = self._jax_devices(jax, process_id, num_processes,
+                                     coordinator, devices_per_process)
+        with rec.span("rtpu.train.mesh") as mesh:
+            from .sharding import MeshOwner
+
+            self._mesh_devices = devs
+            self._owner = MeshOwner(MeshSpec(**spec_kwargs), devices=devs,
+                                    name=f"gang-p{process_id}")
+            self._mesh = self._owner.mesh
+        return MeshReady(len(devs), jax_start.dur, mesh.dur, entered)
+
+    def _jax_devices(self, jax, process_id: int, num_processes: int,
+                     coordinator: Optional[str],
+                     devices_per_process: Optional[int]) -> list:
+        """Imports done, this is jax's own start-up: the backend comes up
+        (on a chip worker: the TPU runtime) at ``jax.devices()``."""
         self._process_id = process_id
         self._num_processes = num_processes
         if num_processes > 1 and coordinator:
@@ -49,13 +95,7 @@ class MeshWorkerMixin:
         if devices_per_process is not None:
             lo = process_id * devices_per_process
             devs = devs[lo:lo + devices_per_process]
-        from .sharding import MeshOwner
-
-        self._mesh_devices = devs
-        self._owner = MeshOwner(MeshSpec(**spec_kwargs), devices=devs,
-                                name=f"gang-p{process_id}")
-        self._mesh = self._owner.mesh
-        return len(devs)
+        return devs
 
     @property
     def mesh(self):

@@ -177,13 +177,15 @@ class StepReport:
                     "args": {"method": op.get("method", "")}})
         for ev in self.events:
             # recorder begin/end pairs were already folded into ops by
-            # the profiler; whatever remains renders as instants
+            # the profiler; spans render as slices, the rest as instants
+            slice_ = {"ph": "X", "dur": max(0.1, round(ev["dur"] * 1e6, 1)),
+                      "tid": "spans"} if "dur" in ev \
+                else {"ph": "i", "s": "p", "tid": "events"}
             out.append({
                 "name": f"{ev.get('kind', 'event')} {ev.get('label', '')}"
                         .strip(),
-                "cat": "flightrec", "ph": "i", "s": "p",
-                "ts": us(ev.get("ts", t0)), "pid": pid,
-                "tid": "events", "args": ev.get("data") or {}})
+                "cat": "flightrec", "ts": us(ev.get("ts", t0)), "pid": pid,
+                "args": ev.get("data") or {}, **slice_})
         # per-step phase lanes (llm) / aggregate lanes (pipeline)
         cursor = 0.0
         for name, ms in sorted(self.phases.items()):

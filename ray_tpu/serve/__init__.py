@@ -140,6 +140,10 @@ def _wait_healthy(controller, name: str, timeout: float) -> None:
         st = ray_tpu.get(controller.status.remote(), timeout=30).get(name)
         if st and st["status"] == "HEALTHY":
             return
+        if st and st.get("constructor_error"):
+            raise RuntimeError(
+                f"deployment {name}: a replica's constructor raised\n"
+                f"{st['constructor_error']}")
         time.sleep(0.1)
     raise TimeoutError(f"deployment {name} not healthy after {timeout}s")
 

@@ -16,6 +16,7 @@ import uuid
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.exceptions import ActorDiedError
 
 from .config import HEALTHY, UNHEALTHY, UPDATING, DeploymentConfig
 
@@ -59,6 +60,9 @@ class _DeploymentState:
                        if config.autoscaling else config.num_replicas)
         self._last_scale = 0.0
         self.deleted = False
+        # traceback of a replica constructor that raised, until a replica
+        # of this version comes up or the deployment is redeployed
+        self.constructor_error = ""
 
 
 class ServeController:
@@ -99,6 +103,7 @@ class ServeController:
             if code_changed:
                 st.version += 1         # triggers rolling replacement
                 st.status = UPDATING
+                st.constructor_error = ""
             return True
 
     def delete(self, name: str) -> bool:
@@ -213,7 +218,8 @@ class ServeController:
                        "draining": sum(1 for r in st.replicas
                                        if r.draining),
                        "cache_blocks_resident": sum(
-                           r.prefix_blocks_resident for r in st.replicas)}
+                           r.prefix_blocks_resident for r in st.replicas),
+                       "constructor_error": st.constructor_error}
                 for name, st in self._deployments.items() if not st.deleted
             }
 
@@ -326,6 +332,7 @@ class ServeController:
                           and r.version == version)
             if healthy >= st.target and not old:
                 st.status = HEALTHY
+                st.constructor_error = ""
             elif not st.replicas:
                 st.status = UNHEALTHY
             else:
@@ -353,6 +360,17 @@ class ServeController:
                 r.cache_hit_rate = float(info.get("cache_hit_rate", 0.0))
                 r.prefix_blocks_resident = int(
                     info.get("prefix_blocks_resident", 0))
+            except ActorDiedError as e:
+                # a replica that died before it ever answered: its
+                # constructor raised (the death cause holds its
+                # traceback). Waiting out the grace below would take
+                # three health-check timeouts to say the same
+                with self._lock:
+                    if r.starting and r.version == st.version:
+                        st.constructor_error = str(e)
+                    if r in st.replicas:
+                        st.replicas.remove(r)
+                self._kill(r, st.config.graceful_shutdown_timeout_s)
             except Exception:
                 grace = st.config.health_check_timeout_s * 3
                 if r.starting and time.monotonic() - r.started_at < grace:

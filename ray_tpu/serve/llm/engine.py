@@ -69,6 +69,11 @@ _H_TTFT = _metrics.Histogram(
     "ray_tpu_llm_ttft_seconds",
     "time to first token (submission -> first emit, queue wait included)",
     tag_keys=("engine",))
+_H_INTAKE = _metrics.Histogram(
+    "ray_tpu_llm_intake_wait_seconds",
+    "time a request waited for the engine's lock at intake (submission "
+    "-> queued), before any queueing or prefill",
+    boundaries=_metrics.FAST_BOUNDARIES, tag_keys=("engine",))
 _H_TPOT = _metrics.Histogram(
     "ray_tpu_llm_tpot_seconds",
     "time per output token during decode (inter-token latency)",
@@ -207,7 +212,11 @@ class Request:
     # while the engine's latency math stays on perf_counter.
     trace_ctx: Optional[tuple] = None
     submitted_wall: float = 0.0
-    queued_wall: float = 0.0           # last enqueue (submit or preempt)
+    # last enqueue (submit or preempt), stamped once the engine's lock
+    # is HELD: submitted_wall -> queued_wall is the wait for the lock
+    # (span llm.intake_wait), queued_wall -> first token is queue and
+    # prefill (span llm.admit)
+    queued_wall: float = 0.0
     cache_hit_tokens: int = 0
     cache_miss_tokens: int = 0
 
@@ -233,6 +242,115 @@ class _Sequence:
         # what the prefix cache indexes at retire/preempt time
         self.tokens: List[int] = list(tokens if tokens is not None
                                       else req.prompt)
+
+
+# The four device programs of an engine. Their names are part of the
+# measurement: a profiler trace shows a jitted program as
+# ``jit_<__name__>``, and the benchmark's readers find ``jit__decode``,
+# ``jit__prefill``, ``jit__extend`` and ``jit__cow`` by name
+# (tests/test_tracing_names.py pins them on both lowering paths).
+
+def _engine_programs(model) -> Dict[str, Any]:
+    def _decode(params, kc, vc, tokens, positions, rows, active):
+        logits, cache = model.paged_decode_step(
+            params, {"k": kc, "v": vc}, tokens, positions, rows, active)
+        return logits, cache["k"], cache["v"]
+
+    def _prefill(params, kc, vc, tokens, length, block_row):
+        logits, cache = model.paged_prefill(
+            params, {"k": kc, "v": vc}, tokens, length, block_row)
+        return logits, cache["k"], cache["v"]
+
+    def _extend(params, kc, vc, tokens, start, length, block_row):
+        logits, cache = model.paged_prefill_extend(
+            params, {"k": kc, "v": vc}, tokens, start, length,
+            block_row)
+        return logits, cache["k"], cache["v"]
+
+    def _cow(kc, vc, src, dst):
+        # duplicate one pool block (copy-on-write divergence point):
+        # block axis is axis 1 of the [L, N, Bs, KH, hd] cache
+        return (kc.at[:, dst].set(kc[:, src]),
+                vc.at[:, dst].set(vc[:, src]))
+
+    programs = {"_decode": _decode, "_prefill": _prefill,
+                "_extend": _extend, "_cow": _cow}
+    for name, fn in programs.items():
+        fn.__name__ = fn.__qualname__ = name      # pinned, see above
+    return programs
+
+
+_PHASE_OF = (("rtpu.llm.prefill.", "prefill"), ("rtpu.llm.extend.", "prefill"),
+             ("rtpu.llm.decode.", "decode"), ("rtpu.llm.retire", "retire"),
+             ("rtpu.llm.admit", "admit"), ("rtpu.llm.step", "admit"))
+
+
+def step_phases(events: List[dict]) -> Tuple[List[float], Dict[str, float]]:
+    """One engine's flight-recorder events (ring order) -> (wall ms of
+    each ``rtpu.llm.step`` span, ms by phase over those steps). A span is
+    charged its SELF time, its duration less that of the spans it caused
+    (``parent``), so the phases sum to the steps' wall time: ``admit`` is
+    scheduling net of the prefills and retires it triggered plus the
+    step's own bookkeeping, ``prefill`` the prefill and extend programs
+    (dispatch and wait), ``decode`` the four decode spans net of retires,
+    ``retire`` the retires. Spans outside a step (a lock wait, a retire
+    from ``_fail_all``) have no parent and are left out."""
+    step_ms: List[float] = []
+    phases = {"admit": 0.0, "prefill": 0.0, "decode": 0.0, "retire": 0.0}
+    children: Dict[str, list] = {}   # parent kind -> [(start, s)] closed
+    pending: List[tuple] = []        # (start, phase, own s) since a step
+    for ev in events:
+        if "dur" not in ev or not ev["kind"].startswith("rtpu.llm."):
+            continue
+        kind, ts, dur = ev["kind"], ev["ts"], ev["dur"]
+        is_step = kind == "rtpu.llm.step"
+        if not (is_step or ev["parent"].startswith("rtpu.llm.")):
+            continue
+        # a span closes after its children, so they are waiting for it;
+        # one that began before it belongs to a step that left no event
+        own = max(0.0, dur - sum(d for t, d in children.pop(kind, ())
+                                 if t >= ts))
+        phase = next((ph for prefix, ph in _PHASE_OF
+                      if kind.startswith(prefix)), None)
+        if is_step:
+            step_ms.append(round(dur * 1e3, 4))
+            phases[phase] += own
+            for t, ph, o in pending:
+                if t >= ts and ph is not None:
+                    phases[ph] += o
+            pending.clear()
+        else:
+            children.setdefault(ev["parent"], []).append((ts, dur))
+            pending.append((ts, phase, own))
+    return step_ms, {k: round(v * 1e3, 3) for k, v in phases.items()}
+
+
+class _LockWait:
+    """``with engine._locked("intake")``: takes the engine's lock on the
+    CALLER's thread inside a ``rtpu.llm.lock_wait.<who>`` span (from
+    asking for the lock to having it) and counts the wait, under the
+    lock, into ``stats()``. -> the seconds waited."""
+
+    __slots__ = ("_eng", "_who", "_kind")
+
+    def __init__(self, eng: "LLMEngine", who: str):
+        self._eng, self._who = eng, who
+        self._kind = "rtpu.llm.lock_wait." + who
+
+    def __enter__(self) -> float:
+        eng = self._eng
+        with _FLREC.span(self._kind, eng.name) as sp:
+            # released in __exit__: this object IS the `with` block
+            # graftcheck: disable=GC006,GC030
+            eng._lock.acquire()
+        c = eng._lock_waits[self._who]
+        c[0] += 1
+        c[1] += sp.dur
+        c[2] = max(c[2], sp.dur)
+        return sp.dur
+
+    def __exit__(self, *exc) -> None:
+        self._eng._lock.release()
 
 
 class LLMEngine:
@@ -298,10 +416,19 @@ class LLMEngine:
         self._stop = threading.Event()
         self._total_generated = 0
         self._total_preemptions = 0
-        # cumulative scheduler-phase seconds; profile() diffs across a
-        # window, so these only ever grow
-        self._phase_s = {"admit": 0.0, "prefill": 0.0, "decode": 0.0,
-                         "retire": 0.0}
+        # counters for stats(); every one is written under the lock
+        self._decode_steps = 0
+        self._prefill_calls: Dict[int, int] = {b: 0 for b in buckets}
+        self._extend_calls = 0
+        self._cow_copies = 0
+        # [count, seconds, longest] of waits for the lock, by who asked:
+        # "intake" (add_request/add_prefilled) and "observer" (stats,
+        # queue_depth, cache_stats, kv_bytes_per_chip)
+        self._lock_waits = {"intake": [0, 0.0, 0.0],
+                            "observer": [0, 0.0, 0.0]}
+        self._loop_lock_held_s = 0.0   # seconds step() held the lock
+        self._span_prefill = {b: f"rtpu.llm.prefill.b{b}" for b in buckets}
+        self._span_extend = {b: f"rtpu.llm.extend.b{b}" for b in buckets}
         self._prof: Optional[Dict[str, list]] = None
         self._peak_blocks = 0
         self._peak_per_chip: List[int] = [0] * self.tp
@@ -318,28 +445,9 @@ class LLMEngine:
         # shape, so decode compiles once and prefill (and the suffix
         # extend variant) once per bucket — the buckets BOUND the
         # program count
-        def _decode(params, kc, vc, tokens, positions, rows, active):
-            logits, cache = model.paged_decode_step(
-                params, {"k": kc, "v": vc}, tokens, positions, rows, active)
-            return logits, cache["k"], cache["v"]
-
-        def _prefill(params, kc, vc, tokens, length, block_row):
-            logits, cache = model.paged_prefill(
-                params, {"k": kc, "v": vc}, tokens, length, block_row)
-            return logits, cache["k"], cache["v"]
-
-        def _extend(params, kc, vc, tokens, start, length, block_row):
-            logits, cache = model.paged_prefill_extend(
-                params, {"k": kc, "v": vc}, tokens, start, length,
-                block_row)
-            return logits, cache["k"], cache["v"]
-
-        def _cow(kc, vc, src, dst):
-            # duplicate one pool block (copy-on-write divergence point):
-            # block axis is axis 1 of the [L, N, Bs, KH, hd] cache
-            return (kc.at[:, dst].set(kc[:, src]),
-                    vc.at[:, dst].set(vc[:, src]))
-
+        progs = _engine_programs(model)
+        _decode, _prefill = progs["_decode"], progs["_prefill"]
+        _extend, _cow = progs["_extend"], progs["_cow"]
         if self.owner is None:
             self._decode_fn = jax.jit(_decode)
             self._prefill_fn = jax.jit(_prefill)
@@ -398,17 +506,33 @@ class LLMEngine:
             # the replica activates the request's context around the
             # user-callable invocation, which reaches here synchronously
             trace_ctx = _tracing.current_context()
-        now_wall = time.time()
         req = Request(rid, prompt, int(max_tokens),
                       self.config.eos_id if eos_id == "__default__"
                       else eos_id,
                       stream, time.perf_counter(),
                       trace_ctx=tuple(trace_ctx) if trace_ctx else None,
-                      submitted_wall=now_wall, queued_wall=now_wall)
-        with self._lock:
+                      submitted_wall=time.time())
+        with self._locked("intake") as waited:
+            self._queued(req, waited)
             self._waiting.append(req)
             self._update_gauges()
         return stream
+
+    def _locked(self, who: str) -> _LockWait:
+        return _LockWait(self, who)
+
+    def _queued(self, req: Request, waited: float) -> None:
+        """Under the lock, at intake: the request is in the engine from
+        now; what came before was the wait for the lock."""
+        req.queued_wall = time.time()
+        _H_INTAKE.observe(waited, tags={"engine": self.name},
+                          exemplar=req.trace_ctx[0]
+                          if req.trace_ctx else None)
+        if req.trace_ctx is not None:
+            _tracing.record_span(
+                "llm.intake_wait", req.trace_ctx, req.submitted_wall,
+                end=req.queued_wall, request_id=req.request_id,
+                engine=self.name)
 
     def add_prefilled(self, prompt: Sequence[int], kv_blocks: Dict[str, Any],
                       first_token: int, max_tokens: int = 16,
@@ -430,16 +554,17 @@ class LLMEngine:
         rid = f"req-{next(self._ids)}"
         stream = TokenStream(rid)
         trace_ctx = _tracing.current_context()
-        now_wall = time.time()
         req = Request(rid, prompt, int(max_tokens),
                       self.config.eos_id if eos_id == "__default__"
                       else eos_id,
                       stream, time.perf_counter(),
                       trace_ctx=tuple(trace_ctx) if trace_ctx else None,
-                      submitted_wall=now_wall, queued_wall=now_wall)
+                      submitted_wall=time.time())
         deadline = time.monotonic() + timeout
         while True:
-            with self._lock:
+            with self._locked("intake") as waited:
+                if not req.queued_wall:
+                    self._queued(req, waited)
                 # evicting alloc: a prefix-cached decode stage would
                 # otherwise wedge once rc-1 cache residency drains the
                 # free list (nothing here runs _admit's eviction path)
@@ -500,27 +625,19 @@ class LLMEngine:
         """One scheduler iteration: retire/admit/decode. Returns True if
         any work was done (callers can sleep when False)."""
         with self._lock:
-            ph = self._phase_s
-            p0, r0 = ph["prefill"], ph["retire"]
-            t0 = time.perf_counter()
-            admitted = self._admit()
-            t1 = time.perf_counter()
-            r1 = ph["retire"]
-            decoded = self._decode_iteration()
-            t2 = time.perf_counter()
-            # admit = scheduling overhead net of the prefill compute and
-            # any retires it triggered (both self-accumulate); decode
-            # likewise nets out retires
-            ph["admit"] += max(0.0, (t1 - t0) - (ph["prefill"] - p0)
-                               - (r1 - r0))
-            ph["decode"] += max(0.0, (t2 - t1) - (ph["retire"] - r1))
-            if self._prof is not None and (admitted or decoded):
-                self._prof["occupancy"].append(float(len(self._running)))
-                self._prof["kv_pressure"].append(round(
-                    self.pool.used_count / self.pool.num_blocks, 4))
-                self._prof["step_ms"].append(round((t2 - t0) * 1e3, 4))
-            self._update_gauges()
-            return admitted or decoded
+            # every phase below is a child span of this one (perf/
+            # recorder.py); a step that found no work leaves no event
+            with _FLREC.span("rtpu.llm.step", self.name) as sp:
+                admitted = self._admit()
+                decoded = self._decode_iteration()
+                sp.keep = worked = admitted or decoded
+                if self._prof is not None and worked:
+                    self._prof["occupancy"].append(float(len(self._running)))
+                    self._prof["kv_pressure"].append(round(
+                        self.pool.used_count / self.pool.num_blocks, 4))
+                self._update_gauges()
+            self._loop_lock_held_s += sp.dur
+            return worked
 
     def _alloc_with_evict(self, n: int) -> Optional[List[int]]:
         """Pool alloc that spends cached prefixes under pressure: when
@@ -536,6 +653,13 @@ class LLMEngine:
         return blocks
 
     def _admit(self) -> bool:
+        if not (self._waiting and self._free_slots):
+            return False
+        # scheduling; the prefills it starts are its child spans
+        with _FLREC.span("rtpu.llm.admit", self.name):
+            return self._admit_waiting()
+
+    def _admit_waiting(self) -> bool:
         cfg = self.config
         budget = cfg.max_prefill_tokens_per_step
         admitted = False
@@ -591,13 +715,11 @@ class LLMEngine:
             self._waiting.popleft()
             budget -= p - cached
             admitted = True
-            tp0 = time.perf_counter()
             tw0 = time.time()
             if cached:
                 self._prefill_cached(req, match, blocks)
             else:
                 self._prefill_into(req, blocks)
-            self._phase_s["prefill"] += time.perf_counter() - tp0
             if req.trace_ctx is not None:
                 _tracing.record_span(
                     "llm.prefill", req.trace_ctx, tw0,
@@ -615,11 +737,14 @@ class LLMEngine:
         toks[0, :p] = req.prompt
         row = np.full((cfg.max_blocks_per_seq,), -1, np.int32)
         row[:len(blocks)] = blocks
-        logits, kc, vc = self._prefill_fn(
-            self.params, self._cache["k"], self._cache["v"],
-            jnp.asarray(toks), jnp.int32(p), jnp.asarray(row))
-        self._cache = {"k": kc, "v": vc}
-        first = int(np.asarray(logits).argmax())
+        with _FLREC.span(self._span_prefill[bucket], self.name):
+            # dispatch, and the wait for the first token's logits
+            logits, kc, vc = self._prefill_fn(
+                self.params, self._cache["k"], self._cache["v"],
+                jnp.asarray(toks), jnp.int32(p), jnp.asarray(row))
+            self._cache = {"k": kc, "v": vc}
+            first = int(np.asarray(logits).argmax())
+        self._prefill_calls[bucket] += 1
         self._count_prefix(0, p)
         req.cache_hit_tokens, req.cache_miss_tokens = 0, p
         self._start_sequence(req, blocks, p, first)
@@ -644,6 +769,7 @@ class LLMEngine:
                 self._cache["k"], self._cache["v"],
                 jnp.int32(match.partial_block), jnp.int32(blocks[0]))
             self._cache = {"k": kc, "v": vc}
+            self._cow_copies += 1
             # the pin taken at match time was only for the copy
             self.pool.free([match.partial_block])
         suffix = req.prompt[cached:]
@@ -653,12 +779,14 @@ class LLMEngine:
         toks[0, :s] = suffix
         row = np.full((cfg.max_blocks_per_seq,), -1, np.int32)
         row[:len(table)] = table
-        logits, kc, vc = self._extend_fn(
-            self.params, self._cache["k"], self._cache["v"],
-            jnp.asarray(toks), jnp.int32(cached), jnp.int32(s),
-            jnp.asarray(row))
-        self._cache = {"k": kc, "v": vc}
-        first = int(np.asarray(logits).argmax())
+        with _FLREC.span(self._span_extend[bucket], self.name):
+            logits, kc, vc = self._extend_fn(
+                self.params, self._cache["k"], self._cache["v"],
+                jnp.asarray(toks), jnp.int32(cached), jnp.int32(s),
+                jnp.asarray(row))
+            self._cache = {"k": kc, "v": vc}
+            first = int(np.asarray(logits).argmax())
+        self._extend_calls += 1
         self._count_prefix(cached, s)
         req.cache_hit_tokens, req.cache_miss_tokens = cached, s
         self._start_sequence(req, table, p, first)
@@ -683,8 +811,10 @@ class LLMEngine:
                             exemplar=req.trace_ctx[0]
                             if req.trace_ctx else None)
         if req.trace_ctx is not None:
-            # queue wait + prefill, with the prefix-cache outcome as
-            # attributes (hit tokens reused KV; miss tokens paid compute)
+            # queue wait + prefill (the wait for the engine's lock is
+            # llm.intake_wait and ends where this starts), with the
+            # prefix-cache outcome as attributes (hit tokens reused KV;
+            # miss tokens paid compute)
             _tracing.record_span(
                 "llm.admit", req.trace_ctx, req.queued_wall,
                 request_id=req.request_id, engine=self.name,
@@ -705,11 +835,42 @@ class LLMEngine:
             _C_PREFIX_MISS.inc(miss, tags=tags)
 
     def _decode_iteration(self) -> bool:
-        cfg = self.config
         if not self._running:
             return False
-        # grow block tables for this iteration's writes; preempt the
-        # latest-admitted sequence when the pool is out of blocks
+        import jax.numpy as jnp
+
+        with _FLREC.span("rtpu.llm.decode.prepare", self.name):
+            host = self._decode_prepare()
+        if host is None:
+            return False
+        with _FLREC.span("rtpu.llm.decode.dispatch", self.name):
+            logits, kc, vc = self._decode_fn(
+                self.params, self._cache["k"], self._cache["v"],
+                *(jnp.asarray(a) for a in host))
+        self._cache = {"k": kc, "v": vc}
+        self._decode_steps += 1
+        with _FLREC.span("rtpu.llm.decode.fetch", self.name):
+            arr = np.asarray(logits)      # the host waits for the device
+        with _FLREC.span("rtpu.llm.decode.sample", self.name):
+            emitted = 0
+            for seq in list(self._running):
+                seq.seq_len += 1          # pending's KV landed this step
+                seq.tokens.append(seq.pending)
+                tok = int(arr[seq.slot].argmax())
+                seq.pending = tok
+                self._emit(seq, tok, decode_step=True)
+                emitted += 1
+        self._tok_events.append((time.perf_counter(), emitted))
+        self._total_generated += emitted
+        return True
+
+    def _decode_prepare(self) -> Optional[tuple]:
+        """Host side of a decode step: grows the block tables for this
+        iteration's writes (preempting the latest-admitted sequence when
+        the pool is out of blocks), duplicates shared write blocks, and
+        builds the program's four host arrays (tokens, positions, block
+        rows, active). -> None when nothing is left running."""
+        cfg = self.config
         i = 0
         while i < len(self._running):
             seq = self._running[i]
@@ -767,9 +928,7 @@ class LLMEngine:
                 seq.blocks.extend(got)
             i += 1
         if not self._running:
-            return False
-        import jax.numpy as jnp
-
+            return None
         b, m = cfg.max_batch, cfg.max_blocks_per_seq
         tokens = np.zeros((b,), np.int32)
         positions = np.zeros((b,), np.int32)
@@ -780,24 +939,7 @@ class LLMEngine:
             positions[seq.slot] = seq.seq_len
             rows[seq.slot, :len(seq.blocks)] = seq.blocks
             active[seq.slot] = True
-        logits, kc, vc = self._decode_fn(
-            self.params, self._cache["k"], self._cache["v"],
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(rows), jnp.asarray(active))
-        self._cache = {"k": kc, "v": vc}
-        arr = np.asarray(logits)
-        emitted = 0
-        for seq in list(self._running):
-            seq.seq_len += 1              # pending's KV landed this step
-            seq.tokens.append(seq.pending)
-            tok = int(arr[seq.slot].argmax())
-            seq.pending = tok
-            self._emit(seq, tok, decode_step=True)
-            emitted += 1
-        now = time.perf_counter()
-        self._tok_events.append((now, emitted))
-        self._total_generated += emitted
-        return True
+        return tokens, positions, rows, active
 
     # decode spans aggregate: one span per this many steps, not one per
     # token — span traffic stays O(tokens/32) while the trace still
@@ -843,12 +985,17 @@ class LLMEngine:
         kc, vc = self._cow_fn(self._cache["k"], self._cache["v"],
                               jnp.int32(seq.blocks[wi]), jnp.int32(fresh))
         self._cache = {"k": kc, "v": vc}
+        self._cow_copies += 1
         self.pool.free([seq.blocks[wi]])
         seq.blocks[wi] = fresh
 
     def _retire(self, seq: _Sequence, reason: str,
                 error: Optional[BaseException] = None) -> None:
-        t0 = time.perf_counter()
+        with _FLREC.span("rtpu.llm.retire", self.name):
+            self._retire_now(seq, reason, error)
+
+    def _retire_now(self, seq: _Sequence, reason: str,
+                    error: Optional[BaseException]) -> None:
         self._running.remove(seq)
         if self.prefix_cache is not None and error is None:
             # leave the full-block KV of prompt+completion behind for
@@ -874,7 +1021,6 @@ class LLMEngine:
                 cache_miss_tokens=req.cache_miss_tokens,
                 error=type(error).__name__ if error is not None else "")
         seq.req.stream._finish(reason, error)
-        self._phase_s["retire"] += time.perf_counter() - t0
 
     def _preempt(self, seq: _Sequence) -> None:
         """Free everything the sequence holds and requeue it at the front
@@ -930,7 +1076,9 @@ class LLMEngine:
                 self._fail_all(e)
                 worked = False
             if not worked:
-                self._stop.wait(self.config.idle_sleep_s)
+                # merged in the ring: an idle stretch is one event
+                with _FLREC.span("rtpu.llm.idle", self.name, merge=True):
+                    self._stop.wait(self.config.idle_sleep_s)
 
     def _fail_all(self, error: BaseException) -> None:
         try:
@@ -1001,36 +1149,37 @@ class LLMEngine:
         if flops_per_token is None:
             fpt = getattr(self.model, "flops_per_token", None)
             flops_per_token = float(fpt()) if callable(fpt) else 0.0
-        with self._lock:
-            self._prof = {"occupancy": [], "kv_pressure": [],
-                          "step_ms": []}
-            base = dict(self._phase_s)
+        with self._locked("observer"):
+            self._prof = {"occupancy": [], "kv_pressure": []}
             gen0 = self._total_generated
         t_start = time.time()
-        wall0 = time.perf_counter()
-        try:
-            if self.is_alive():
-                deadline = time.monotonic() + timeout
-                while time.monotonic() < deadline:
-                    with self._lock:
-                        if len(self._prof["step_ms"]) >= steps:
+        with _FLREC.span("rtpu.llm.profile", self.name) as window:
+            try:
+                if self.is_alive():
+                    deadline = time.monotonic() + timeout
+                    while time.monotonic() < deadline:
+                        # a racy length is good enough to stop polling
+                        # graftcheck: disable=GC050
+                        if len(self._prof["occupancy"]) >= steps:
                             break
-                    time.sleep(0.003)
-            else:
-                for _ in range(steps):
-                    self.step()
-        finally:
-            wall_s = time.perf_counter() - wall0
-            with self._lock:
-                prof, self._prof = self._prof, None
-                phases = {k: round((self._phase_s[k] - base[k]) * 1e3, 3)
-                          for k in base}
-                gen = self._total_generated - gen0
+                        time.sleep(0.003)
+                else:
+                    for _ in range(steps):
+                        self.step()
+            finally:
+                with self._locked("observer"):
+                    prof, self._prof = self._prof, None
+                    gen = self._total_generated - gen0
+        wall_s = window.dur
+        # the window's phases and step times come from its spans alone
+        # (the flight recorder must be on: it is the one timer there is)
         events = [ev for ev in _FLREC.snapshot(clear=False)
-                  if ev["ts"] >= t_start][-2000:]
+                  if ev["ts"] >= t_start
+                  and ("dur" not in ev or ev["label"] == self.name)][-2000:]
+        step_ms, phases = step_phases(events)
         return StepReport(
-            kind="llm", engine=self.name, steps=len(prof["step_ms"]),
-            wall_s=wall_s, step_ms=prof["step_ms"], phases=phases,
+            kind="llm", engine=self.name, steps=len(step_ms),
+            wall_s=wall_s, step_ms=step_ms, phases=phases,
             tokens=float(gen),
             tokens_per_s=gen / wall_s if gen and wall_s > 0 else 0.0,
             flops_per_token=flops_per_token, peak_flops=peak_flops,
@@ -1041,7 +1190,7 @@ class LLMEngine:
                    "preemptions": self._total_preemptions})
 
     def queue_depth(self) -> int:
-        with self._lock:
+        with self._locked("observer"):
             return len(self._waiting) + len(self._running)
 
     def _tokens_per_s(self, window_s: float = 10.0) -> float:
@@ -1078,8 +1227,12 @@ class LLMEngine:
         0..tp-1 (same keying as the pool's shard accounting and the
         `{chip=}` gauge; raw jax device ids are global on multi-host
         TPUs and would not line up)."""
-        with self._lock:  # metrics thread: the step loop mutates _cache
+        # metrics thread: the step loop mutates _cache
+        with self._locked("observer"):
             cache = dict(self._cache)
+        return self._kv_bytes(cache)
+
+    def _kv_bytes(self, cache: Dict[str, Any]) -> Dict[int, int]:
         if self.owner is None:
             total = sum(int(np.asarray(v).nbytes)
                         for v in cache.values())
@@ -1092,21 +1245,25 @@ class LLMEngine:
         """Prefix-cache health — the replica ships this in its health
         ping (replica.py) so the controller/balancer can prefer
         cache-warm replicas. All zeros with the cache disabled."""
-        with self._lock:
-            hit, miss = self._prefix_hits, self._prefix_misses
-            pc = self.prefix_cache
-            return {
-                "cache_hit_rate": round(hit / (hit + miss), 4)
-                if hit + miss else 0.0,
-                "prefix_hit_tokens": hit,
-                "prefix_miss_tokens": miss,
-                "prefix_blocks_resident": pc.resident_blocks if pc else 0,
-                "prefix_nodes": pc.num_nodes if pc else 0,
-                "prefix_evictions": pc.evictions if pc else 0,
-            }
+        with self._locked("observer"):
+            return self._cache_stats()
+
+    def _cache_stats(self) -> Dict[str, Any]:
+        hit, miss = self._prefix_hits, self._prefix_misses
+        pc = self.prefix_cache
+        return {
+            "cache_hit_rate": round(hit / (hit + miss), 4)
+            if hit + miss else 0.0,
+            "prefix_hit_tokens": hit,
+            "prefix_miss_tokens": miss,
+            "prefix_blocks_resident": pc.resident_blocks if pc else 0,
+            "prefix_nodes": pc.num_nodes if pc else 0,
+            "prefix_evictions": pc.evictions if pc else 0,
+        }
 
     def stats(self) -> Dict[str, Any]:
-        with self._lock:
+        with self._locked("observer"):
+            waits = self._lock_waits
             out = {
                 "engine": self.name,
                 "waiting": len(self._waiting),
@@ -1121,12 +1278,26 @@ class LLMEngine:
                 "preemptions": self._total_preemptions,
                 "tp": self.tp,
                 "kv_blocks_peak": self._peak_blocks,
+                # what the scheduler did, counted (the spans of
+                # perf/recorder.py say how long each took)
+                "decode_steps": self._decode_steps,
+                "prefill_calls": {str(b): n for b, n
+                                  in self._prefill_calls.items()},
+                "extend_calls": self._extend_calls,
+                "cow_copies": self._cow_copies,
+                # waits for this lock, by who asked: intake =
+                # add_request/add_prefilled, observer = stats() and kin
+                "lock_waits": {w: c[0] for w, c in waits.items()},
+                "lock_wait_s": {w: c[1] for w, c in waits.items()},
+                "lock_wait_max_s": {w: c[2] for w, c in waits.items()},
+                "loop_lock_held_s": self._loop_lock_held_s,
             }
             if self.prefix_cache is not None:
-                out.update(self.cache_stats())
+                out.update(self._cache_stats())
             if self.tp > 1:
                 out["kv_blocks_per_chip"] = self.pool.used_per_shard()
                 out["kv_blocks_peak_per_chip"] = list(self._peak_per_chip)
                 out["kv_bytes_per_chip"] = {
-                    str(d): b for d, b in self.kv_bytes_per_chip().items()}
+                    str(d): b
+                    for d, b in self._kv_bytes(dict(self._cache)).items()}
             return out

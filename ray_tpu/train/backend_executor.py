@@ -19,6 +19,7 @@ from ray_tpu.core.placement_group import placement_group, remove_placement_group
 from ray_tpu.util.queue import Queue
 
 from ..parallel.mesh import MeshSpec
+from ..perf.recorder import get_recorder
 from ..parallel.mesh_group import MeshWorkerMixin
 from .config import ScalingConfig
 from .session import TrainContext, init_session, shutdown_session
@@ -84,9 +85,14 @@ class BackendExecutor:
         n = s.num_workers
         res = s.worker_resources()
         bundles = [dict(res) for _ in range(n)]
-        self._pg = placement_group(bundles, strategy=s.placement_strategy)
-        if not self._pg.ready(timeout=60.0):
-            raise TrainWorkerError("placement group for train workers not ready")
+        # start-up spans, in the driver's ring (docs/OBSERVABILITY.md)
+        rec = get_recorder()
+        with rec.span("rtpu.train.pg_ready", pin=True):
+            self._pg = placement_group(bundles,
+                                       strategy=s.placement_strategy)
+            if not self._pg.ready(timeout=60.0):
+                raise TrainWorkerError(
+                    "placement group for train workers not ready")
         self.queue = Queue()
         cls = ray_tpu.remote(_TrainWorker)
         self.workers = [
@@ -101,17 +107,26 @@ class BackendExecutor:
         spec = s.mesh or MeshSpec()
         spec_kwargs = {"dp": spec.dp, "fsdp": spec.fsdp, "tp": spec.tp,
                        "sp": spec.sp, "ep": spec.ep, "pp": spec.pp}
-        ray_tpu.get([
-            w.setup_mesh.remote(i, n, None, spec_kwargs, s.devices_per_worker)
-            for i, w in enumerate(self.workers)])
+        with rec.span("rtpu.train.setup_mesh", pin=True) as sp:
+            # covers the lease, the worker process (rtpu.core.
+            # worker_spawn) and the actor's construction too; the
+            # workers say how much of it was jax and the mesh
+            ready = ray_tpu.get([
+                w.setup_mesh.remote(i, n, None, spec_kwargs,
+                                    s.devices_per_worker)
+                for i, w in enumerate(self.workers)])
+            sp.data = {k: [getattr(r, k, None) for r in ready]
+                       for k in ("entered", "jax_start_s", "mesh_s")}
         shard_blobs = []
         for i in range(n):
             shard = dataset_shards[i] if dataset_shards else None
             shard_blobs.append(cloudpickle.dumps(shard) if shard else None)
-        ray_tpu.get([
-            w.setup_session.remote(i, n, self.queue.actor, shard_blobs[i],
-                                   checkpoint, self.experiment_name)
-            for i, w in enumerate(self.workers)])
+        with rec.span("rtpu.train.setup_session", pin=True):
+            ray_tpu.get([
+                w.setup_session.remote(i, n, self.queue.actor,
+                                       shard_blobs[i], checkpoint,
+                                       self.experiment_name)
+                for i, w in enumerate(self.workers)])
         blob = cloudpickle.dumps(train_fn)
         self._run_refs = [w.run_train_fn.remote(blob, train_config)
                           for w in self.workers]

@@ -11,6 +11,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from ..perf.recorder import get_recorder
+
 _session_lock = threading.Lock()
 _session: Optional["_Session"] = None
 
@@ -82,14 +84,16 @@ def report(metrics: Dict[str, Any], checkpoint=None) -> None:
     """Report metrics (and optionally a checkpoint) for this iteration.
     Only rank 0's checkpoint is persisted (reference semantics)."""
     s = _get_session()
-    s.iteration += 1
-    payload = {
-        "rank": s.context.world_rank,
-        "iteration": s.iteration,
-        "metrics": dict(metrics),
-        "checkpoint": checkpoint if s.context.world_rank == 0 else None,
-    }
-    s.result_queue.put(payload)
+    # in the chip's process, so a gap in a training trace has a name
+    with get_recorder().span("rtpu.train.report"):
+        s.iteration += 1
+        payload = {
+            "rank": s.context.world_rank,
+            "iteration": s.iteration,
+            "metrics": dict(metrics),
+            "checkpoint": checkpoint if s.context.world_rank == 0 else None,
+        }
+        s.result_queue.put(payload)
 
 
 def get_checkpoint():

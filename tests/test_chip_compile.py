@@ -75,6 +75,42 @@ def test_flash_attention_fwd_bwd_gpt2_small(one_chip, compiled_kernels):
         qkv, qkv, qkv).compile().as_text()
     # forward kernel + the fused single-block backward kernel
     assert text.count("tpu_custom_call") >= 2
+    assert _kernel_names(text) == {"flash_fwd_single", "flash_bwd_fused"}
+
+
+def _kernel_names(compiled_text: str) -> set:
+    """The Pallas kernels of a compiled program by the names a device
+    trace shows: each is a custom call to ``tpu_custom_call`` whose
+    instruction is named after the kernel (``%flash_bwd_fused.9`` in the
+    train step, ``%transpose_jvp_flash_bwd_dq__.1`` under a bare grad)."""
+    from ray_tpu.ops.flash_attention import KERNEL_NAMES
+
+    longest_first = sorted(KERNEL_NAMES.values(), key=len, reverse=True)
+    out = set()
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            inst = line.split(" = ")[0]
+            out.add(next((n for n in longest_first if n in inst), inst))
+    return out
+
+
+def test_flash_attention_streamed_kernels_are_named(one_chip,
+                                                    compiled_kernels):
+    """S=1024 in blocks of 512: the streamed forward and the two-pass
+    backward, the three kernels the single-block case does not reach."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=512,
+                               block_k=512).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    assert _kernel_names(text) == {"flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv"}
 
 
 @pytest.mark.parametrize("n,d,f", [(8192, 768, 3072), (8192, 768, 2304)])

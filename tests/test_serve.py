@@ -105,6 +105,40 @@ def test_replica_recovery_after_kill(cluster):
         time.sleep(0.2)
 
 
+def test_replica_constructor_error_fails_run_at_once(cluster):
+    """A replica whose constructor raises: ``serve.run`` fails with the
+    replica's own traceback, not after three health-check timeouts of a
+    replica that will never answer (which here would be 3 x 120 s)."""
+    @serve.deployment(health_check_period_s=0.5,
+                      health_check_timeout_s=120.0)
+    class Broken:
+        def __init__(self):
+            raise ValueError("no room for the KV pool")
+
+        def __call__(self, x):
+            return x
+
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError) as err:
+        serve.run(Broken.bind(), timeout=300.0)
+    assert time.monotonic() - t0 < 60
+    msg = str(err.value)
+    assert "constructor raised" in msg
+    assert "ValueError: no room for the KV pool" in msg
+    assert "in __init__" in msg                     # the remote traceback
+    assert "no room" in serve.status()["Broken"]["constructor_error"]
+    # a redeploy of working code clears it and comes up
+
+    @serve.deployment(name="Broken")
+    class Mended:
+        def __call__(self, x):
+            return x * 2
+
+    h = serve.run(Mended.bind())
+    assert ray_tpu.get(h.remote(4), timeout=60) == 8
+    assert serve.status()["Broken"]["constructor_error"] == ""
+
+
 def test_rolling_update_changes_code(cluster):
     @serve.deployment(num_replicas=2, user_config={"bias": 1})
     class V:

@@ -1,0 +1,174 @@
+"""ISSUE 24: the names a device trace shows are part of the measurement.
+The four engine programs (``jit__decode``, ``jit__prefill``,
+``jit__extend``, ``jit__cow``: the benchmark's readers match them) on both
+lowering paths, the five flash kernels, and the model's named scopes in
+the ``op_name`` of the operations a loss lowers to."""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import GPT, GPTConfig, Llama, LlamaConfig
+import importlib
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
+from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
+
+PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
+
+
+@pytest.fixture(scope="module")
+def engines():
+    m, params = build_model("gpt-tiny")
+    cfg = dict(block_size=4, num_blocks=32, max_batch=4,
+               max_blocks_per_seq=8, prefill_buckets=(8, 16))
+    return {tp: LLMEngine(m, params, EngineConfig(tp=tp, **cfg),
+                          name=f"names-tp{tp}") for tp in (1, 2)}
+
+
+def _program_args(eng, which):
+    cfg = eng.config
+    kc, vc = eng._cache["k"], eng._cache["v"]
+    b, m = cfg.max_batch, cfg.max_blocks_per_seq
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    return {
+        "_decode": (eng.params, kc, vc, i32(b), i32(b), i32(b, m),
+                    jnp.zeros((b,), bool)),
+        "_prefill": (eng.params, kc, vc, i32(1, 8), jnp.int32(3), i32(m)),
+        "_extend": (eng.params, kc, vc, i32(1, 8), jnp.int32(4),
+                    jnp.int32(3), i32(m)),
+        "_cow": (kc, vc, jnp.int32(0), jnp.int32(1)),
+    }[which]
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("which", PROGRAMS)
+def test_engine_program_names_are_pinned(engines, which, tp):
+    eng = engines[tp]
+    fn = getattr(eng, which + "_fn")
+    text = fn.lower(*_program_args(eng, which)).as_text()
+    # what a profiler's "XLA Modules" line calls the program
+    assert re.search(r"module @jit_" + which + r"\b", text), text[:200]
+
+
+def _fwd_bwd_text(seq, block):
+    q = jnp.zeros((1, seq, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, block_q=block,
+                                  block_k=block).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def kernel_texts():
+    # one K/V block: the single-block forward and the fused backward;
+    # two: the streamed forward and the two-pass backward
+    return {1: _fwd_bwd_text(128, 128), 2: _fwd_bwd_text(256, 128)}
+
+
+@pytest.mark.parametrize("key,name,blocks", [
+    ("fwd_single", "flash_fwd_single", 1),
+    ("bwd_fused", "flash_bwd_fused", 1),
+    ("fwd", "flash_fwd", 2),
+    ("bwd_dq", "flash_bwd_dq", 2),
+    ("bwd_dkv", "flash_bwd_dkv", 2),
+])
+def test_flash_kernel_names_are_pinned(kernel_texts, key, name, blocks):
+    assert fa.KERNEL_NAMES[key] == name
+    assert len(set(fa.KERNEL_NAMES.values())) == 5
+    # the name is on the name stack of what the kernel lowers to here
+    # (interpret mode), and is the kernel's name in a compiled program
+    # (tests/test_chip_compile.py)
+    pattern = r"[/\"(]" + name + r"[/\")]"
+    assert re.search(pattern, kernel_texts[blocks])
+    assert not re.search(pattern, kernel_texts[3 - blocks])
+
+
+MODELS = {
+    "gpt": lambda: GPT(GPTConfig.tiny()),
+    "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
+    "llama": lambda: Llama(LlamaConfig.tiny()),
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_losses():
+    out = {}
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    for name, make in MODELS.items():
+        m = make()
+        p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+        text = jax.jit(jax.value_and_grad(m.loss)).lower(
+            p, toks, toks).as_text(debug_info=True)
+        out[name] = set(re.findall(r'loc\("([^"]+)"', text))
+    return out
+
+
+@pytest.mark.parametrize("scope", ["embed", "attn", "mlp", "lm_head",
+                                   "loss"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
+                                                  scope):
+    names = lowered_losses[model]
+    # forward and backward: the scope survives jvp and transpose
+    fwd = [n for n in names if re.search(r"(^|/)jvp\(" + scope + r"\)/", n)
+           or re.search(r"(^|/)" + scope + "/", n)]
+    bwd = [n for n in names
+           if re.search(r"transpose\(jvp\(" + scope + r"\)\)", n)
+           or ("transpose" in n or "checkpoint" in n)
+           and re.search(r"(^|/)" + scope + "/", n)]
+    assert fwd, (model, scope)
+    assert bwd, (model, scope)
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_the_paged_entry_points_carry_the_same_scopes(family):
+    m = GPT(GPTConfig.tiny()) if family == "gpt" else Llama(
+        LlamaConfig.tiny())
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: m.init_paged_cache(16, 4))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    texts = {
+        "decode": jax.jit(m.paged_decode_step).lower(
+            p, cache, i32(2), i32(2), i32(2, 8),
+            jax.ShapeDtypeStruct((2,), jnp.bool_)),
+        "prefill": jax.jit(m.paged_prefill).lower(
+            p, cache, i32(1, 8), i32(), i32(8)),
+        "extend": jax.jit(m.paged_prefill_extend).lower(
+            p, cache, i32(1, 8), i32(), i32(), i32(8)),
+    }
+    for which, lowered in texts.items():
+        names = set(re.findall(r'loc\("([^"]+)"',
+                               lowered.as_text(debug_info=True)))
+        for scope in ("embed", "attn", "attn/kv_write", "attn/paged_attn",
+                      "mlp", "lm_head"):
+            assert any(re.search(r"(^|/)" + scope + r"(/|$)", n)
+                       for n in names), (family, which, scope)
+        assert not any("/loss/" in n for n in names)
+
+
+def test_scopes_are_metadata_the_lowered_program_is_the_same():
+    """``jax.named_scope`` changes no operation: a loss lowered with the
+    scopes and one lowered with them stripped differ in locations only."""
+    import contextlib
+    from unittest import mock
+
+    m = GPT(GPTConfig.tiny())
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+
+    def text():
+        return jax.jit(jax.value_and_grad(m.loss)).lower(
+            p, toks, toks).as_text()
+
+    with_scopes = text()
+    with mock.patch.object(jax, "named_scope",
+                           lambda name: contextlib.nullcontext()):
+        without = text()
+    assert with_scopes == without
+    assert np.all([s not in with_scopes for s in ("loc(", "attn/")])
