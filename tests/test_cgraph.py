@@ -187,7 +187,7 @@ def test_ray_tpu_get_on_cgraph_ref(ray_start_regular):
     compiled = _compile_chain(a)
     try:
         ref = compiled.execute(41)
-        assert ray_tpu.get(ref) == 42
+        assert ray_tpu.get(ref, timeout=60) == 42
     finally:
         compiled.teardown()
 

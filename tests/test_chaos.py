@@ -298,7 +298,7 @@ class TestLiveInjection:
             dag = a.fwd.bind(inp)
         compiled = dag.experimental_compile()
         try:
-            assert ray_tpu.get(compiled.execute(1)) == 2
+            assert ray_tpu.get(compiled.execute(1), timeout=60) == 2
             chaos.enable("seed=1;channel_poison=1.0")
             with pytest.raises(exceptions.CompiledGraphError):
                 compiled.execute(2).get(timeout=30)
@@ -351,7 +351,7 @@ class TestTeardownIdempotency:
         with ray_tpu.InputNode() as inp:
             dag = a.f.bind(inp)
         compiled = dag.experimental_compile()
-        assert ray_tpu.get(compiled.execute(5)) == 5
+        assert ray_tpu.get(compiled.execute(5), timeout=60) == 5
         errs = []
 
         def tear():

@@ -18,7 +18,7 @@ def test_multi_node_spread(ray_start_cluster):
     def where():
         return ray_tpu.get_runtime_context().get_node_id()
 
-    nodes = set(ray_tpu.get([where.remote() for _ in range(12)]))
+    nodes = set(ray_tpu.get([where.remote() for _ in range(12)], timeout=60))
     assert len(nodes) >= 2
 
 
@@ -31,7 +31,7 @@ def test_node_affinity(ray_start_cluster):
         return ray_tpu.get_runtime_context().get_node_id()
 
     strat = NodeAffinitySchedulingStrategy(n2.node_id)
-    out = ray_tpu.get(where.options(scheduling_strategy=strat).remote())
+    out = ray_tpu.get(where.options(scheduling_strategy=strat).remote(), timeout=60)
     assert out == n2.node_id.hex()
 
 
@@ -43,7 +43,7 @@ def test_custom_resource(ray_start_cluster):
     def where():
         return ray_tpu.get_runtime_context().get_node_id()
 
-    assert ray_tpu.get(where.remote()) == special.node_id.hex()
+    assert ray_tpu.get(where.remote(), timeout=60) == special.node_id.hex()
 
 
 def test_resource_gating(ray_start_regular):
@@ -55,7 +55,7 @@ def test_resource_gating(ray_start_regular):
 
     t0 = time.monotonic()
     refs = [hold.remote() for _ in range(4)]
-    ray_tpu.get(refs)
+    ray_tpu.get(refs, timeout=60)
     elapsed = time.monotonic() - t0
     assert elapsed >= 1.0  # two waves of 0.6s
 
@@ -76,7 +76,7 @@ def test_placement_group_strict_spread(ray_start_cluster):
         where.options(scheduling_strategy=PlacementGroupSchedulingStrategy(
             pg, placement_group_bundle_index=i)).remote()
         for i in range(3)
-    ])
+    ], timeout=60)
     assert len(set(outs)) == 3
     ray_tpu.remove_placement_group(pg)
 
@@ -94,7 +94,7 @@ def test_placement_group_strict_pack(ray_start_cluster):
     outs = ray_tpu.get([
         where.options(scheduling_strategy=PlacementGroupSchedulingStrategy(pg)).remote()
         for _ in range(2)
-    ])
+    ], timeout=60)
     assert len(set(outs)) == 1
 
 

@@ -11,15 +11,15 @@ from ray_tpu import exceptions
 
 def test_put_get(ray_start_regular):
     ref = ray_tpu.put(42)
-    assert ray_tpu.get(ref) == 42
+    assert ray_tpu.get(ref, timeout=60) == 42
     ref2 = ray_tpu.put({"a": [1, 2, 3], "b": "x"})
-    assert ray_tpu.get(ref2) == {"a": [1, 2, 3], "b": "x"}
+    assert ray_tpu.get(ref2, timeout=60) == {"a": [1, 2, 3], "b": "x"}
 
 
 def test_put_get_large_numpy(ray_start_regular):
     arr = np.arange(1_000_000, dtype=np.float32)
     ref = ray_tpu.put(arr)
-    out = ray_tpu.get(ref)
+    out = ray_tpu.get(ref, timeout=60)
     np.testing.assert_array_equal(arr, out)
 
 
@@ -28,7 +28,7 @@ def test_simple_task(ray_start_regular):
     def add(a, b):
         return a + b
 
-    assert ray_tpu.get(add.remote(1, 2)) == 3
+    assert ray_tpu.get(add.remote(1, 2), timeout=60) == 3
 
 
 def test_task_with_ref_args(ray_start_regular):
@@ -39,7 +39,7 @@ def test_task_with_ref_args(ray_start_regular):
     x = ray_tpu.put(10)
     y = add.remote(x, 5)
     z = add.remote(y, y)
-    assert ray_tpu.get(z) == 30
+    assert ray_tpu.get(z, timeout=60) == 30
 
 
 def test_many_tasks(ray_start_regular):
@@ -48,7 +48,7 @@ def test_many_tasks(ray_start_regular):
         return x * x
 
     refs = [square.remote(i) for i in range(50)]
-    assert ray_tpu.get(refs) == [i * i for i in range(50)]
+    assert ray_tpu.get(refs, timeout=60) == [i * i for i in range(50)]
 
 
 def test_multiple_returns(ray_start_regular):
@@ -57,7 +57,7 @@ def test_multiple_returns(ray_start_regular):
         return 1, 2, 3
 
     a, b, c = three.remote()
-    assert ray_tpu.get([a, b, c]) == [1, 2, 3]
+    assert ray_tpu.get([a, b, c], timeout=60) == [1, 2, 3]
 
 
 def test_large_task_output(ray_start_regular):
@@ -65,7 +65,7 @@ def test_large_task_output(ray_start_regular):
     def big():
         return np.ones((1000, 1000), dtype=np.float32)
 
-    out = ray_tpu.get(big.remote())
+    out = ray_tpu.get(big.remote(), timeout=60)
     assert out.shape == (1000, 1000)
     assert out.sum() == 1_000_000
 
@@ -76,7 +76,7 @@ def test_task_error_propagates(ray_start_regular):
         raise ValueError("kaboom")
 
     with pytest.raises(exceptions.TaskError) as ei:
-        ray_tpu.get(boom.remote())
+        ray_tpu.get(boom.remote(), timeout=60)
     assert "kaboom" in str(ei.value)
 
 
@@ -87,9 +87,9 @@ def test_nested_tasks(ray_start_regular):
 
     @ray_tpu.remote
     def outer(x):
-        return ray_tpu.get(inner.remote(x)) + 10  # graftcheck: disable=GC001
+        return ray_tpu.get(inner.remote(x), timeout=60) + 10  # graftcheck: disable=GC001
 
-    assert ray_tpu.get(outer.remote(1)) == 12
+    assert ray_tpu.get(outer.remote(1), timeout=60) == 12
 
 
 def test_wait(ray_start_regular):
@@ -122,7 +122,7 @@ def test_options_override(ray_start_regular):
     def f():
         return 1
 
-    assert ray_tpu.get(f.options(num_cpus=2).remote()) == 1
+    assert ray_tpu.get(f.options(num_cpus=2).remote(), timeout=60) == 1
 
 
 def test_cluster_resources(ray_start_regular):
@@ -143,7 +143,7 @@ def test_nested_tasks_deeper_than_cpus():
         def parent(depth):
             if depth == 0:
                 return 0
-            return ray_tpu.get(parent.remote(depth - 1)) + 1  # graftcheck: disable=GC001
+            return ray_tpu.get(parent.remote(depth - 1), timeout=60) + 1  # graftcheck: disable=GC001
 
         # depth 10 > the worker soft limit (8): blocked workers must be
         # excluded from the start-worker cap, not just release their CPUs
@@ -166,7 +166,7 @@ def test_nested_wait_releases_lease():
         def parent():
             ref = leaf.remote()
             ready, _ = ray_tpu.wait([ref], num_returns=1, timeout=30)
-            return ray_tpu.get(ready[0])  # graftcheck: disable=GC001
+            return ray_tpu.get(ready[0], timeout=60)  # graftcheck: disable=GC001
 
         assert ray_tpu.get(parent.remote(), timeout=60) == 7
     finally:
@@ -189,7 +189,7 @@ def test_idle_workers_reclaimed():
         def f(x):
             return x
 
-        assert ray_tpu.get([f.remote(i) for i in range(8)]) == list(range(8))
+        assert ray_tpu.get([f.remote(i) for i in range(8)], timeout=60) == list(range(8))
         from ray_tpu.core import runtime as runtime_mod
 
         rt = runtime_mod.maybe_runtime()

@@ -178,7 +178,7 @@ class TestDataFeed:
             eng.shutdown()
 
     def test_pump_death_typed_error_and_recover_reattaches(
-            self, ray_start_regular):
+            self, ray_start_regular, wait_engine_aborted):
         """Killing a pump actor aborts the engine with DataFeedError;
         recover() respawns the stages AND re-attaches the feed from its
         factories (a fresh iterator), so fed steps run again."""
@@ -196,10 +196,7 @@ class TestDataFeed:
             eng.attach_feed(DataFeed([_repeat_factory(mbs, tgts, 100)]))
             first = eng.step()
             ray_tpu.kill(eng._feed_actors[0])
-            deadline = time.monotonic() + 30
-            while eng._closed_error is None:
-                assert time.monotonic() < deadline
-                time.sleep(0.05)
+            assert wait_engine_aborted(eng)
             assert isinstance(eng._closed_error, exceptions.DataFeedError)
             with pytest.raises(exceptions.DataFeedError):
                 eng.step()

@@ -313,7 +313,8 @@ class TestResize:
 
     def test_recover_reshards_stale_width_checkpoint(self,
                                                      ray_start_regular,
-                                                     tmp_path):
+                                                     tmp_path,
+                                                     wait_engine_aborted):
         """recover() after a resize finds the newest commit written at
         the OLD width and reshards it to the current one instead of
         rejecting the restore."""
@@ -335,10 +336,7 @@ class TestResize:
             eng.wait_for_checkpoints()
             eng.resize(1)
             ray_tpu.kill(eng.actors[0])  # unplanned death after resize
-            deadline = time.monotonic() + 30
-            while eng._closed_error is None:
-                assert time.monotonic() < deadline
-                time.sleep(0.05)
+            assert wait_engine_aborted(eng)
             assert eng.recover() == 1    # dp=2 commit resharded to dp=1
             assert eng.dp == 1
             eng.step(mbs, tgts)
@@ -589,7 +587,8 @@ class TestPreemptionNotice:
         finally:
             c.shutdown()
 
-    def test_notice_then_premature_sigkill_recovers(self, tmp_path):
+    def test_notice_then_premature_sigkill_recovers(self, tmp_path,
+                                                    wait_engine_aborted):
         """The race the ISSUE names: notice delivered, but the axe lands
         before the drain finishes — the engine falls back to the PR 9
         checkpoint/recover path and resumes bit-consistently."""
@@ -631,6 +630,9 @@ class TestPreemptionNotice:
                     deadline = time.monotonic() + 30
                     while time.monotonic() < deadline:
                         eng.step(mbs, tgts, timeout=30)
+                # whichever error came first, the node's death aborts the
+                # engine too: let that finish before recover()
+                assert wait_engine_aborted(eng)
                 resumed_from = eng.recover()
                 assert resumed_from >= 1
                 # resize may still be pending from the notice; stepping

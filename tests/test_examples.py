@@ -54,8 +54,12 @@ def test_llama_serve_example_tp():
     """--tp 2: the replica's engine lowers under a 2-chip mesh (the
     subprocess env already forces 8 host devices) and the per-chip KV
     occupancy print shows blocks resident on BOTH chips."""
+    # 3 prompt + 20 new tokens span two 16-token blocks, and the pool
+    # takes them from alternate chips: each request alone puts a block on
+    # both, whether or not the three overlap (beside other work they may
+    # run one after the other)
     out = _run("llama_serve.py", "--tp", "2", "--requests", "3",
-               "--max-new", "6", timeout=300)
+               "--max-new", "20", timeout=300)
     assert "per-chip KV occupancy" in out
     assert "chip 0:" in out and "chip 1:" in out
     import re

@@ -51,7 +51,7 @@ def test_actor_restart(ray_start_regular):
             os._exit(1)
 
     p = Phoenix.remote()
-    assert ray_tpu.get(p.incr.remote()) == 1
+    assert ray_tpu.get(p.incr.remote(), timeout=60) == 1
     crash_ref = p.crash.remote()
     # the crash call itself dies with the worker (max_task_retries=0)
     with pytest.raises(exceptions.ActorDiedError):
@@ -71,7 +71,7 @@ def test_actor_no_restart_dies(ray_start_regular):
             return "pong"
 
     m = Mortal.remote()
-    assert ray_tpu.get(m.ping.remote()) == "pong"
+    assert ray_tpu.get(m.ping.remote(), timeout=60) == "pong"
     m.crash.remote()
     time.sleep(0.5)
     with pytest.raises(exceptions.ActorDiedError):

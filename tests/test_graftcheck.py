@@ -728,12 +728,12 @@ def test_lock_order_inversion_detected(debug_locks):
 
     t1 = threading.Thread(target=order_ab)
     t1.start()
-    t1.join()
+    t1.join(timeout=30)
     assert lockmod.get_lock_reports() == []  # one order alone is fine
 
     t2 = threading.Thread(target=order_ba)
     t2.start()
-    t2.join()
+    t2.join(timeout=30)
     reports = lockmod.get_lock_reports()
     assert any(r.kind == "lock-order-inversion" for r in reports)
     inv = next(r for r in reports if r.kind == "lock-order-inversion")
@@ -780,7 +780,7 @@ def test_three_lock_cycle_detected(debug_locks):
     def run(first, second):
         t = threading.Thread(target=lambda: _nest(first, second))
         t.start()
-        t.join()
+        t.join(timeout=30)
 
     def _nest(x, y):
         with x:

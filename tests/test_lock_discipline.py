@@ -27,7 +27,7 @@ def _held_by_someone(lock) -> bool:
 
     t = threading.Thread(target=probe)
     t.start()
-    t.join()
+    t.join(timeout=30)
     return not out["free"]
 
 

@@ -248,7 +248,7 @@ def test_concurrent_writers_do_not_shear_lines(cluster):
         for t in ts:
             t.start()
         for t in ts:
-            t.join()
+            t.join(timeout=30)
         return "storm-done"
 
     assert ray_tpu.get(storm.remote(), timeout=60) == "storm-done"

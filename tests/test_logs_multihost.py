@@ -94,7 +94,7 @@ def test_remote_concurrent_writers_no_shear(cluster):
         for t in ts:
             t.start()
         for t in ts:
-            t.join()
+            t.join(timeout=30)
         return 1
 
     assert ray_tpu.get(storm.options(

@@ -92,15 +92,15 @@ class TestCollective:
 
         world = 4
         ws = [Worker.remote(i, world) for i in range(world)]
-        outs = ray_tpu.get([w.do_allreduce.remote() for w in ws])
+        outs = ray_tpu.get([w.do_allreduce.remote() for w in ws], timeout=60)
         for o in outs:
             np.testing.assert_allclose(o, np.full((4,), 1.0 + 2 + 3 + 4))
-        outs = ray_tpu.get([w.do_broadcast.remote() for w in ws])
+        outs = ray_tpu.get([w.do_broadcast.remote() for w in ws], timeout=60)
         for o in outs:
             assert o[0] == 2
-        outs = ray_tpu.get([w.do_gather.remote() for w in ws])
+        outs = ray_tpu.get([w.do_gather.remote() for w in ws], timeout=60)
         assert outs[0] == [0, 1, 2, 3]
-        outs = ray_tpu.get([w.do_rs.remote() for w in ws])
+        outs = ray_tpu.get([w.do_rs.remote() for w in ws], timeout=60)
         np.testing.assert_allclose(outs[1], np.array([2., 3.]) * 4)
 
     def test_send_recv(self, ray_start_regular):
@@ -123,8 +123,8 @@ class TestCollective:
 
         a, b = P2P.remote(0, 2), P2P.remote(1, 2)
         r = b.do_recv.remote()
-        ray_tpu.get(a.do_send.remote())
-        np.testing.assert_allclose(ray_tpu.get(r), [42.0])
+        ray_tpu.get(a.do_send.remote(), timeout=60)
+        np.testing.assert_allclose(ray_tpu.get(r, timeout=60), [42.0])
 
 
 class TestMeshGroup:

@@ -12,14 +12,7 @@ import time
 import numpy as np
 import pytest
 
-import ray_tpu
-
-
-@pytest.fixture
-def cluster():
-    rt = ray_tpu.init(num_cpus=4)
-    yield rt
-    ray_tpu.shutdown()
+from _rl_fixtures import cluster  # noqa: F401
 
 
 class TestES:
@@ -149,6 +142,8 @@ while time.time() < deadline:
     client.end_episode(eid, None if done else s, truncated=not done)
 '''
 
+    @pytest.mark.slow  # 49-70 s alone, 189 s beside five workers
+    @pytest.mark.time_limit(400)  # its own learning deadline is 240 s
     def test_external_process_client_learns(self):
         """The VERDICT bar: an external-process CartPole client (own
         physics, no ray_tpu runtime — only the thin PolicyClient HTTP

@@ -89,7 +89,7 @@ class TestSpan:
             tok = rec.begin("rtpu.t.spawn", "w1", {"chip": True})
         t = threading.Thread(target=lambda: rec.end(tok, {"pid": 7}))
         t.start()
-        t.join()
+        t.join(timeout=30)
         ev = rec.spans("rtpu.t.spawn")[0]
         assert ev["parent"] == "rtpu.t.outer" and ev["label"] == "w1"
         assert ev["data"] == {"chip": True, "pid": 7} and ev["dur"] >= 0
@@ -106,7 +106,7 @@ class TestSpan:
         with rec.span("rtpu.t.main"):
             t = threading.Thread(target=other)
             t.start()
-            t.join()
+            t.join(timeout=30)
         assert seen == [""]
 
     def test_span_does_not_import_jax(self):
@@ -202,7 +202,7 @@ class TestEngineLockWaits:
             holder = _hold_lock(eng, 0.3)
             stream = eng.add_request([1, 5, 9], max_tokens=2,
                                      trace_ctx=("t" * 32, "p" * 16))
-            holder.join()
+            holder.join(timeout=30)
             eng.run_until_idle(timeout=300)
             assert len(stream.tokens()) == 2
         finally:
@@ -230,7 +230,7 @@ class TestEngineLockWaits:
         eng = mk_engine(tiny_model, f"lockwait-{call}", prefix_cache=True)
         holder = _hold_lock(eng, 0.15)
         OBSERVERS[call](eng)
-        holder.join()
+        holder.join(timeout=30)
         st = eng.stats()                    # itself one more observer
         assert st["lock_waits"] == {"intake": 0, "observer": 2}
         assert 0.1 <= st["lock_wait_max_s"]["observer"] < 5.0

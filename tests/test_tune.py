@@ -394,6 +394,8 @@ def test_tune_syncer_roundtrip_and_restore(rt, tmp_path):
     assert restored.get_best_result().metrics["score"] == 6.0
 
 
+@pytest.mark.slow  # 61 s alone, 95 s beside five workers (48 trial actors)
+@pytest.mark.time_limit(300)
 def test_gp_searcher_beats_random_on_quadratic(rt):
     """The native GP-EI searcher (pb2's GP promoted) concentrates near
     the optimum of a smooth deterministic surface."""
