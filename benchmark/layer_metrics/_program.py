@@ -17,13 +17,15 @@ the program (a parent commit) has no such span, counter or scope:
   difference of two ``engine.stats()`` samples, and the lock waits that
   completed between consecutive 100 ms samples;
 * ``scope_ms_per_step(view)``: device self time of a train step by the
-  ``jax.named_scope`` its operations carry in their ``op_name``;
+  ``jax.named_scope`` its operations carry in their ``op_name``, split by
+  the ``SCOPES`` of the cell's family (``benchmark/families/``);
 * ``idle_by_span(trace, spans)``: idle time of device 0 summed by the
   innermost program span that covers it.
 """
 from __future__ import annotations
 
 import bisect
+import functools
 import os
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -38,7 +40,6 @@ PREFIX = "rtpu."
 # spans of CALLER threads: they say who waited for the engine's lock, not
 # what the host did for the device, so they cover no idle time
 NOT_COVER = ("rtpu.llm.lock_wait.",)
-SCOPES = ("embed", "attn", "mlp", "lm_head", "loss")
 UNSCOPED = "(unscoped)"
 NO_SPAN = "(no span)"
 
@@ -132,15 +133,21 @@ def wait_samples(view, who: str) -> List[float]:
 # -- device self time by named scope  -----------------------------------------
 
 _STRIP = re.compile(r"p?jit\([^()]*\)")
-_SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(SCOPES) + r")(?=[/)]|$)")
 
 
-def scope_of(op_name: str) -> str:
+@functools.lru_cache(maxsize=None)
+def _scope_rx(scopes: Tuple[str, ...]):
+    return re.compile(r"(?:^|[/(])(" + "|".join(map(re.escape, scopes))
+                      + r")(?=[/)]|$)")
+
+
+def scope_of(op_name: str, scopes: Sequence[str]) -> str:
     """"jit(step)/jit(main)/transpose(jvp(attn))/dot_general" -> "attn":
-    the innermost of the model's scopes on the operation's name stack,
-    whatever transformation wraps it; names of jitted functions are not
-    scopes."""
-    found = _SCOPE.findall(_STRIP.sub("", op_name))
+    the innermost of ``scopes`` (a family's ``SCOPES``) on the operation's
+    name stack, whatever transformation wraps it; names of jitted
+    functions are not scopes, and a scope the family does not list is
+    none."""
+    found = _scope_rx(tuple(scopes)).findall(_STRIP.sub("", op_name))
     return found[-1] if found else UNSCOPED
 
 
@@ -232,10 +239,10 @@ def op_names(path: str) -> Dict[str, str]:
     return out
 
 
-def device_ops_with_scope(path: str) -> Optional[Tuple[List[list], dict]]:
+def device_ops_with_names(path: str) -> Optional[Tuple[List[list], dict]]:
     """-> (operation events [[name, start, seconds], ...] of device 0 on
     the clock of ``lib.trace.load_xplane`` (seconds from the first device
-    event), {operation name: scope}); None if no operation of the trace
+    event), {operation name: op_name}); None if no operation of the trace
     carries an ``op_name``."""
     key = "ops:" + path
     if key in _cache:
@@ -260,16 +267,16 @@ def device_ops_with_scope(path: str) -> Optional[Tuple[List[list], dict]]:
                                     e.duration_ns * 1e-9])
         for o in ops:
             o[1] = (o[1] - t0) * 1e-9
-    scope = {o[0]: scope_of(names.get(o[0], "")) for o in ops}
-    _cache[key] = (ops, scope) if ops else None
+    _cache[key] = (ops, names) if ops else None
     return _cache[key]
 
 
 def scope_ms_per_step(view) -> Optional[Dict[str, float]]:
-    """Milliseconds of one train step by scope: each operation's SELF
-    time (an enclosing while loop is charged its duration less its
-    body's) goes to the innermost scope in its ``op_name``, forward,
-    backward and recomputation alike; ``(unscoped)`` is the rest of the
+    """Milliseconds of one train step by the scopes of the cell's family
+    (its ``SCOPES`` and ``(unscoped)``): each operation's SELF time (an
+    enclosing while loop is charged its duration less its body's) goes to
+    the innermost of those scopes in its ``op_name``, forward, backward
+    and recomputation alike; ``(unscoped)`` is the rest of the
     step: operations under no scope (optimizer, the scan's stacking
     copies) and any time inside the program in which no operation ran.
     Means over the train-step programs that lie whole inside the trace;
@@ -278,16 +285,19 @@ def scope_ms_per_step(view) -> Optional[Dict[str, float]]:
     tr = view.get("trace")
     if tr is None:
         return None
+    scopes = tuple(spec.family_of(view["cell"]).SCOPES)
     path = trace_path(view)
     steps = complete_runs(tr, TRAIN_STEP)
     if path is None or not steps:
         return None
-    found = device_ops_with_scope(path)
+    found = device_ops_with_names(path)
     if found is None:
         return None
-    ops, scope = found
+    ops, names = found
+    scope = {n: scope_of(names.get(n, ""), scopes)
+             for n in {o[0] for o in ops}}
     if not any(s != UNSCOPED for s in scope.values()):
-        return None                 # the program names no scope
+        return None                 # the program names none of the scopes
     starts = [p[1] for p in steps]
     ends = [p[1] + p[2] for p in steps]
     inside = []
@@ -295,7 +305,7 @@ def scope_ms_per_step(view) -> Optional[Dict[str, float]]:
         i = bisect.bisect_right(starts, o[1] + 1e-9) - 1
         if i >= 0 and o[1] < ends[i]:
             inside.append(o)
-    out = {s: 0.0 for s in SCOPES + (UNSCOPED,)}
+    out = {s: 0.0 for s in scopes + (UNSCOPED,)}
     for name, sec in T.self_times(inside).items():
         out[scope[name]] += sec
     out[UNSCOPED] += sum(p[2] for p in steps) - sum(out.values())

@@ -20,6 +20,8 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu.serve.llm import LLMServer
 
+from .spec import load_family
+
 # correctness yardstick, as chip_smoke.py: the server computes in bf16, so
 # it is held to the float32 reference within NOISE_FACTOR times the largest
 # distance the run measures between the same plain forward in bf16 and in
@@ -327,20 +329,6 @@ def serve_reference_check(engine, reference: str, sample: List[dict],
 # training
 # ---------------------------------------------------------------------------
 
-def build_gpt(model: dict):
-    """The dict form ``build_model`` takes, for the trainer's side."""
-    from ray_tpu.models import GPT, GPTConfig, Llama, LlamaConfig
-
-    kw = dict(model)
-    family = kw.pop("family")
-    preset = kw.pop("preset", "tiny")
-    if family == "gpt":
-        return GPT(getattr(GPTConfig, preset)(**kw))
-    if family == "llama":
-        return Llama(getattr(LlamaConfig, preset)(**kw))
-    raise ValueError(f"unknown model family {family!r}")
-
-
 def make_train_step(model, tx):
     """The step a training cell runs: next-token loss (targets are the
     tokens rolled by one, on the device), adamw update, parameters and
@@ -387,7 +375,7 @@ def train_loop(config: dict) -> None:
     tr = config["trainer"]
     B, S = int(tr["batch"]), int(tr["seq"])
     mesh = train.get_mesh()
-    model = build_gpt(config["model"])
+    model = load_family(config["model"]["family"]).build(config["model"])
     init = jax.jit(model.init, out_shardings=model.param_shardings(mesh))
     params = init(jax.random.PRNGKey(config["seed"] % (1 << 31)))
     tx = make_optimizer(tr.get("optimizer", {}))

@@ -48,21 +48,64 @@ def load_cell(name: str, rehearse: bool = False) -> dict:
     return cell
 
 
+def _module_names(group: str) -> list:
+    """The files of ``benchmark/<group>/`` less ``.py``; those that start
+    with ``_`` are helpers."""
+    return sorted(f[:-3] for f in os.listdir(os.path.join(BENCH_DIR, group))
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def _load_module(group: str, name: str):
+    """``benchmark/<group>/<name>.py`` as a module of its own."""
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"\W", "_", f"benchmark_{group}_{name}"),
+        os.path.join(BENCH_DIR, group, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_metric_readers(group: str) -> dict:
     """One reader per file in ``benchmark/<group>/`` (``end_to_end`` or
     ``layer_metrics``), found by listing the directory. The file's name is
     the metric's; it holds UNIT, SOURCE, (for a layer metric) LAYER and
     MOVES, and ``read(view) -> number or None``."""
-    d = os.path.join(BENCH_DIR, group)
-    out = {}
-    for fname in sorted(os.listdir(d)):
-        if not fname.endswith(".py") or fname.startswith("_"):
-            continue
-        name = fname[:-3]
-        spec = importlib.util.spec_from_file_location(
-            f"benchmark_{group}_" + re.sub(r"\W", "_", name),
-            os.path.join(d, fname))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[name] = mod
-    return out
+    return {name: _load_module(group, name) for name in _module_names(group)}
+
+
+_families: dict = {}
+
+
+def load_family(name: str):
+    """``benchmark/families/<name>.py``: what the harness knows of a model
+    family, found by the ``family`` key of a configuration's ``model``
+    dict and by listing the directory. The file holds
+
+    * ``build(model: dict) -> model``: the object a training cell drives,
+      from the configuration's ``model`` dict (``family`` and whatever the
+      family's own builder takes). What the harness calls on it, and
+      nothing else: ``config.vocab_size`` (the ids the feed draws from),
+      ``config.padded_vocab`` (the rows of the embedding, for the
+      parameter count), ``init(rng) -> params``,
+      ``param_shardings(mesh)``, ``loss(params, tokens, targets) ->
+      scalar`` (the bare next-token loss) and ``num_params()``;
+    * ``SCOPES``: the ``jax.named_scope`` names by which a train step of
+      the family is split (``layer_metrics/_program.scope_ms_per_step``);
+    * ``train_flops_per_token(sizes, seq) -> int``: the model's own
+      forward + backward operations a token, from the configuration's
+      ``sizes``, recomputation not counted (``layer_metrics/mfu.py``).
+
+    The family's plain reference is ``reference/<family>.py``. An unknown
+    family fails with the list of those that have a file."""
+    if name not in _families:
+        known = _module_names("families")
+        if name not in known:
+            raise ValueError(f"unknown model family {name!r}: "
+                             f"benchmark/families/ has {known}")
+        _families[name] = _load_module("families", name)
+    return _families[name]
+
+
+def family_of(cell: dict):
+    """The family file of a loaded cell's configuration."""
+    return load_family(cell["config_file"]["model"]["family"])

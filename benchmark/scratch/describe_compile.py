@@ -79,8 +79,8 @@ def main() -> int:
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=sharding), tree)
 
+    model = spec.family_of(cell).build(cell["config_file"]["model"])
     if args.what == "train":
-        model = chip.build_gpt(cell["config_file"]["model"])
         tx = chip.make_optimizer(cell["trainer"].get("optimizer", {}))
         step = chip.make_train_step(model, tx)
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -96,7 +96,6 @@ def main() -> int:
 
     from ray_tpu.serve.llm import EngineConfig
 
-    model = chip.build_gpt(cell["config_file"]["model"])
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     base = dict(cell["engine"])
     tp = int(base.get("tp", 1))

@@ -7,7 +7,10 @@ unscoped operations, and, with a dump of the window's ``stats()`` samples,
 the distribution of the waits for the engine's lock.
 
     python3 benchmark/scratch/span_report.py <dir or .xplane.pb> \
-        [--window <json>]
+        [--family <family>] [--window <json>]
+
+``--family`` names the file in ``benchmark/families/`` whose ``SCOPES``
+split a train step; without it the scope tables are left out.
 
 The numbers of PERF.md section 5 and of PR 24's serving finding came from
 this script on the traces of PR 24's chip calls (``pr24_chip_calls.txt``).
@@ -25,11 +28,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 def main() -> int:
     from benchmark.layer_metrics import _program as P
     from benchmark.layer_metrics._common import TRAIN_STEP, complete_runs
+    from benchmark.lib import spec
     from benchmark.lib import trace as T
     from benchmark.lib.stats import median, percentile
 
     ap = argparse.ArgumentParser()
     ap.add_argument("xplane")
+    ap.add_argument("--family", default="")
     ap.add_argument("--window", default="")
     args = ap.parse_args()
     path = args.xplane
@@ -58,24 +63,26 @@ def main() -> int:
     for kind, sec in sorted(idle.items(), key=lambda kv: -kv[1]):
         print(f"  {kind:34s} {1e3 * sec:10.3f} ms {100 * sec / total:6.2f} %")
     steps = complete_runs(tr, TRAIN_STEP)
-    found = P.device_ops_with_scope(path) if steps else None
+    found = P.device_ops_with_names(path) if steps and args.family else None
     if found:
         P.trace_path = lambda view: path
-        by = P.scope_ms_per_step({"trace": tr, "cell": {"name": ""}})
+        by = P.scope_ms_per_step({"trace": tr, "cell": {
+            "name": "", "config_file": {"model": {"family": args.family}}}})
         if by:
             print(f"train step by scope (ms, mean of {len(steps)} steps):")
             for scope, ms in by.items():
                 print(f"  {scope:12s} {ms:10.3f}")
             print(f"  {'sum':12s} {sum(by.values()):10.3f}")
-        ops, scope = found
-        names = P.op_names(path)
+        ops, names = found
+        scopes = spec.load_family(args.family).SCOPES
         st = T.self_times(ops)
         # whole and cut steps alike ran these operations
         n_steps = sum(p[2] for p in T.programs(tr)) * len(steps) \
             / sum(p[2] for p in steps)
         print("largest unscoped operations (ms a step, op_name):")
-        for name in sorted((n for n in st if scope[n] == P.UNSCOPED),
-                           key=lambda n: -st[n])[:12]:
+        for name in sorted((n for n in st if P.scope_of(
+                names.get(n, ""), scopes) == P.UNSCOPED),
+                key=lambda n: -st[n])[:12]:
             print(f"  {1e3 * st[name] / n_steps:8.3f}"
                   f" {name} {names.get(name, '')[:90]}")
     if args.window:

@@ -44,7 +44,10 @@ def test_every_cell_file_resolves(cell):
     for rehearse in (False, True):
         c = spec.load_cell(cell, rehearse=rehearse)
         assert c["traffic_file"]["kind"] in spec.TRAFFIC_KINDS
-        assert c["config_file"]["model"]["family"] in ("gpt", "llama")
+        family = c["config_file"]["model"]["family"]
+        assert os.path.exists(os.path.join(spec.BENCH_DIR, "families",
+                                           family + ".py"))
+        assert callable(spec.family_of(c).build)
         ref = c["config_file"]["reference"]
         assert os.path.exists(os.path.join(spec.BENCH_DIR, "reference",
                                            ref + ".py"))

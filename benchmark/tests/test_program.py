@@ -14,12 +14,14 @@ import os
 
 import pytest
 
+from benchmark.layer_metrics import _common as C
 from benchmark.layer_metrics import _program as P
 from benchmark.lib import spec
 from benchmark.lib import trace as T
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 READERS = spec.load_metric_readers("layer_metrics")
+GPT_SCOPES = spec.load_family("gpt").SCOPES
 
 
 def _fixture(name):
@@ -27,18 +29,19 @@ def _fixture(name):
         return json.load(f)
 
 
-def _view(fx, monkeypatch, cell="fx"):
+def _view(fx, monkeypatch, cell="fx", family="gpt"):
     """A traced run's view over a fixture: the helper finds 'its' xplane at
-    a path that does not exist, already read."""
+    a path that does not exist, already read. The cell is of ``family``
+    (a file in ``benchmark/families/``)."""
     tr = T.Trace.from_json(fx["trace"])
     path = f"/nonexistent/{cell}.xplane.pb"
     monkeypatch.setattr(P, "trace_path", lambda view: path)
     ops = [o[:3] for o in tr.devices[0]["ops"]]
     monkeypatch.setitem(P._cache, "spans:" + path, tr.host)
-    monkeypatch.setitem(P._cache, "ops:" + path, (ops, {
-        o[0]: P.scope_of(fx["op_names"].get(o[0], "")) for o in ops}))
+    monkeypatch.setitem(P._cache, "ops:" + path, (ops, fx["op_names"]))
     return {"trace": tr, "cell": {"name": cell, "engine": fx.get("engine"),
-                                  "config_file": {"sizes": {}}},
+                                  "config_file": {
+                                      "sizes": {}, "model": {"family": family}}},
             "window": fx.get("window"), "spans": {},
             "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
 
@@ -60,7 +63,45 @@ def _view(fx, monkeypatch, cell="fx"):
     ("", P.UNSCOPED),
 ])
 def test_scope_of_an_op_name(op_name, scope):
-    assert P.scope_of(op_name) == scope
+    assert P.scope_of(op_name, GPT_SCOPES) == scope
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(bench_train_step)/transpose(jvp())/while/body/closed_call/"
+     "checkpoint/ssm/dot_general", "ssm"),
+    ("jit(bench_train_step)/jvp(moe)/router/top_k", "moe"),
+    ("jit(bench_train_step)/jvp()/while/body/attn/div", P.UNSCOPED),
+    ("jit(bench_train_step)/jvp(ssm_conv)/mul", P.UNSCOPED),  # whole names
+    ("jit(bench_train_step)/moe/ssm/add", "ssm"),             # innermost
+    ("jit(moe)/jit(main)/mul", P.UNSCOPED),
+])
+def test_scope_of_honours_the_scope_list_of_another_family(op_name, scope):
+    assert P.scope_of(op_name, ("ssm", "moe")) == scope
+
+
+def test_a_step_is_split_by_the_scopes_of_the_cells_family(monkeypatch):
+    """A family file with SCOPES = ("ssm", "moe"): the returned dict has
+    those scopes and (unscoped), and still sums to the mean step."""
+    class Fam:
+        SCOPES = ("ssm", "moe")
+    monkeypatch.setitem(spec._families, "fx_hybrid", Fam)
+    names = {"%s": "jit(s)/while/body/checkpoint/ssm/dot_general",
+             "%e": "jit(s)/transpose(jvp(moe))/dot_general",
+             "%a": "jit(s)/while/body/attn/div"}
+    ops = [["%s", 0.0, 0.4, ""], ["%e", 0.4, 0.3, ""], ["%a", 0.7, 0.2, ""]]
+    fx = {"trace": {"devices": {"0": {
+        "programs": [["jit_bench_train_step", 0.0, 1.0]], "ops": ops,
+        "async_ops": []}}, "host": []}, "op_names": names}
+    by = P.scope_ms_per_step(_view(fx, monkeypatch, family="fx_hybrid"))
+    assert by == {"ssm": pytest.approx(400.0), "moe": pytest.approx(300.0),
+                  P.UNSCOPED: pytest.approx(200.0 + 100.0)}
+    # the same events under a family that names attn
+    by = P.scope_ms_per_step(_view(fx, monkeypatch))
+    assert by["attn"] == pytest.approx(200.0) and set(by) == {
+        *GPT_SCOPES, P.UNSCOPED}
+    with pytest.raises(ValueError, match=r"unknown model family 'nope'.*"
+                       r"'gpt', 'llama'"):
+        P.scope_ms_per_step(_view(fx, monkeypatch, family="nope"))
 
 
 # -- the xplane's event metadata, read from the wire  -------------------------
@@ -170,14 +211,60 @@ def test_train_step_by_scope_on_the_recorded_trace(monkeypatch):
                if "tpu_custom_call" in o[3]]
     assert {o[0].split(".")[0] for o in kernels} == {
         "%flash_fwd_single", "%flash_bwd_fused"}
-    assert {P._cache["ops:/nonexistent/fx.xplane.pb"][1][o[0]]
-            for o in kernels} == {"attn"}
-    # PR 23's reader of the kernels still finds them, by their target
-    roof = READERS["flash_attention_roofline"].read(dict(
+    op_names = P._cache["ops:/nonexistent/fx.xplane.pb"][1]
+    assert {P.scope_of(op_names[o[0]], GPT_SCOPES) for o in kernels} == {
+        "attn"}
+    # the kernels' reader finds them by their pinned names (PR 26); every
+    # custom call to tpu_custom_call, PR 23's pattern, selects the same
+    # events of this step
+    flash = READERS["flash_attention_roofline"]
+    by_name = T.ops_matching(view["trace"], flash.KERNEL)
+    assert by_name == kernels and len(by_name) == 2 * 2 * 24
+    assert C.kernel_s_per_step(view, flash.KERNEL) == C.kernel_s_per_step(
+        view, r"custom_call_target=tpu_custom_call") == pytest.approx(
+            33.19e-3, abs=0.05e-3)
+    roof = flash.read(dict(
         view, train={"batch": 8, "seq": 1024},
         cell={"name": "fx", "config_file": {"sizes": {
             "n_head": 16, "d_model": 1024, "n_layer": 24}}}))
     assert roof == pytest.approx(22.07, abs=0.1)
+    # the model's count of operations is the family's: the number mfu read
+    # before families were files (6 N + 6 L D S of lib/flops.py)
+    from benchmark.lib import flops
+    sizes = spec.load_cell("gpt2m_train_s1024")["config_file"]["sizes"]
+    assert spec.load_family("gpt").train_flops_per_token(sizes, 1024) == \
+        flops.train_flops_per_token(sizes, 1024) == 2279933952
+    mfu = READERS["mfu"].read(dict(
+        view, train={"batch": 8, "seq": 1024},
+        cell={"name": "fx", "config_file": {
+            "sizes": sizes, "model": {"family": "gpt"}}}))
+    assert mfu == pytest.approx(48.05, abs=0.03)
+
+
+def test_kernel_time_and_roofline_share_on_synthetic_events():
+    # two whole steps of 1.0 s and a cut one; kernel %k.1 runs 0.1 s twice
+    # in every step, another custom call once
+    cc = "custom-call(...) custom_call_target=tpu_custom_call"
+    progs = [["jit_bench_train_step", 0.0, 0.4]]
+    ops = [["%k.1", 0.1, 0.1, cc]]
+    for t0 in (0.5, 2.0):
+        progs.append(["jit_bench_train_step", t0, 1.0])
+        ops += [["%k.1", t0, 0.1, cc], ["%k.2", t0 + 0.2, 0.1, cc],
+                ["%other", t0 + 0.5, 0.3, cc],
+                ["%fusion.1", t0 + 0.8, 0.1, "fusion(...)"]]
+    view = {"trace": T.Trace({0: {"programs": progs, "ops": ops,
+                                  "async_ops": []}}, []),
+            "device": {"kind": "TPU v5 lite"}}
+    assert C.kernel_s_per_step(view, r"^%k(\.\d+)?$") == pytest.approx(0.2)
+    assert C.kernel_s_per_step(view, "tpu_custom_call") == pytest.approx(0.5)
+    assert C.kernel_s_per_step(view, r"^%nothing$") is None
+    assert C.kernel_s_per_step({"trace": None}, "k") is None
+    # 19.7e12 operations in 0.2 s are half of the peak; bytes bound it
+    # where they take longer
+    assert C.roofline_pct(view, 0.2, 19.7e12, 0) == pytest.approx(50.0)
+    assert C.roofline_pct(view, 0.2, 19.7e12, 819e9 * 0.15) == \
+        pytest.approx(75.0)
+    assert C.roofline_pct(view, None, 1, 1) is None
 
 
 def test_a_program_without_scopes_reads_as_nothing(monkeypatch):

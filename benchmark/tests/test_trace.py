@@ -158,7 +158,14 @@ def test_recorded_trace_reduces(recorded):
             "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
     got = {n: r.read(view) for n, r in readers.items()}
     assert 100 < got["train_step_ms"] < 300
-    assert 0 < got["flash_attention_roofline"] < 100
+    # recorded in PR 23, before the kernels had names ("%closed_call.7",
+    # "%checkpoint.3"): the reader of the flash kernels knows them by their
+    # pinned names and finds nothing to read here (test_program.py reads
+    # it on a trace recorded since); their time is there all the same
+    assert got["flash_attention_roofline"] is None
+    from benchmark.layer_metrics._common import kernel_s_per_step
+    assert 0 < kernel_s_per_step(
+        view, r"custom_call_target=tpu_custom_call") < 0.036
     # 8 x 1024 tokens x 2.28 GFLOP in a 197.27 ms step of a 197 TFLOP/s chip
     assert got["mfu"] == pytest.approx(48.06, abs=0.05)
     assert got["collective_exposed_ms"] is None    # one chip: no collective
