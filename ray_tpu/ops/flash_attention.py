@@ -1,18 +1,35 @@
 """Flash attention for TPU (Pallas) — forward AND backward kernels.
 
-Tiled online-softmax attention. Layout [B,S,H,D] -> [B*H, S, D]; the grid
-streams Q and K/V blocks so nothing larger than a block is VMEM-resident
-(the round-1 kernel kept whole K/V per head in VMEM, capping sequence
-length). bf16 inputs feed the MXU directly (preferred_element_type=f32
-accumulate); all softmax state is f32 on the VPU — the standard TPU recipe
-(pallas_guide.md: MXU matmuls with preferred_element_type; min tile
-(16,128) for bf16).
+Tiled online-softmax attention. The kernels work on MERGED arrays
+[B, S, H*D], the layout the model's projections produce and consume: the
+grid runs over (batch, column block, sequence blocks) and each program
+gets [block, W] tiles of q, k, v (o, dO) by index map, W lanes wide. Where
+the heads allow it (``_heads_per_block``: D of 64 or 128, H*D a multiple
+of 128) W is 128 and a column block holds 128 // D whole heads, so every
+load and store is a dense 128-lane tile and nothing is transposed outside
+the kernels. Inside a program the heads of a block are worked one after
+the other on full-width tiles whose other lanes are zeroed
+(``_head_lanes``): a product with an exact zero adds an exact zero in the
+float32 accumulator, so each head's numbers are those of a kernel that
+saw its D lanes alone. Every other head size is first transposed to
+[B*H, S, D] (``_to_bhsd``) and runs the same kernels as B*H batches of
+one head, W = D: a 64-wide minor dimension would pad to 128 lanes in HBM,
+a 32-wide one to four times its size, which is why the merged form is
+what crosses the custom-VJP boundary either way.
+
+The grid streams Q and K/V blocks so nothing larger than a block is
+VMEM-resident. bf16 inputs feed the MXU directly
+(preferred_element_type=f32 accumulate); all softmax state is f32 on the
+VPU — the standard TPU recipe (pallas_guide.md: MXU matmuls with
+preferred_element_type; min tile (16,128) for bf16).
 
 Forward saves the logsumexp per row; backward is two Pallas kernels that
 recompute probabilities from (q, k, lse) inside the kernel — dq in one
 pass over K blocks, dk/dv in one pass over Q blocks — with f32 scratch
-accumulators. Causal masking skips fully-masked blocks via a predicate on
-the grid position, halving FLOPs for autoregressive models.
+accumulators, or one fused kernel where K/V are a single block; delta =
+rowsum(dO * O) is reduced inside them too (``_row_delta``). Causal
+masking skips fully-masked blocks via a predicate on the grid position,
+halving FLOPs for autoregressive models.
 
 Reference capability (not design): the reference has no first-party
 attention kernels at all (torch/NCCL stack); this is new TPU-native work
@@ -20,6 +37,7 @@ per SURVEY.md §5.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -27,9 +45,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..perf.recorder import record as _record
 from .attention import mha_reference
 
 _NEG_INF = -1e30
+_LANES = 128
 
 # The names of the five kernels, as a device trace and the compiled HLO
 # show them (``name=`` on ``pl.pallas_call``; each stays a custom call to
@@ -42,6 +62,13 @@ KERNEL_NAMES = {
     "bwd_dq": "flash_bwd_dq",           # dq, one pass over K blocks
     "bwd_dkv": "flash_bwd_dkv",         # dk and dv, one pass over Q blocks
 }
+
+# Traced calls of flash_attention by the layout each took: "merged" (the
+# kernels index [B, S, H*D] as it stands), "relayout" (transposed to
+# [B*H, S, D] around the kernels), "reference" (mha_reference, no
+# kernel). Counted where the choice is made, once per trace; the same
+# choice is the flight-recorder event ``rtpu.ops.flash.path``.
+PATH_COUNTS: collections.Counter = collections.Counter()
 
 
 def _use_interpret() -> bool:
@@ -63,6 +90,48 @@ def _fit_block(block: int, seq: int) -> int:
     return 128
 
 
+def _heads_per_block(heads: int, d: int) -> int:
+    """Whole heads in one 128-lane column block of [B, S, H*D], or 0 where
+    the merged layout cannot be cut that way (heads narrower than 64 would
+    be worked four or more to a block at a quarter of the MXU's width;
+    wider than 128 or not dividing it do not tile the lanes)."""
+    if d >= 64 and _LANES % d == 0 and (heads * d) % _LANES == 0:
+        return _LANES // d
+    return 0
+
+
+def _head_lanes(x, j: int, d: int):
+    """The tile with every lane outside head j's D zeroed; the tile itself
+    where it holds one head."""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= j * d) & (lane < (j + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_AB = ((1,), (0,))    # a @ b
+_ABT = ((1,), (1,))   # a @ b^T
+_ATB = ((0,), (0,))   # a^T @ b
+
+
+def _scores(q, k, sm_scale, causal, row0, col0):
+    """(q * scale) @ k^T in f32, causal-masked; row0/col0 are the block's
+    first q and k positions."""
+    # scale the (block_q, d) tile, not the (block_q, block_k) s matrix
+    s = _dot(q * jnp.asarray(sm_scale, q.dtype), k, _ABT)
+    if causal:
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(rows >= cols, s, _NEG_INF)
+    return s
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -70,12 +139,14 @@ def _fit_block(block: int, seq: int) -> int:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *,
-                sm_scale: float, causal: bool,
+                sm_scale: float, causal: bool, d: int,
                 block_q: int, block_k: int, num_kb: int):
-    """Grid: (B*H, num_q_blocks, num_k_blocks); K innermost so the f32
-    scratch (m, l, acc) carries across K iterations for one Q block."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    """Grid: (B, column blocks, num_q_blocks, num_k_blocks); K innermost
+    so the f32 scratch (m, l, acc: one of each per head of the block)
+    carries across K iterations for one Q block."""
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+    heads = lse_ref.shape[0]
 
     @pl.when(kb == 0)
     def _init():
@@ -89,148 +160,137 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[...]  # (block_q, d) input dtype — MXU fast path
+        q = q_ref[...]  # (block_q, W) input dtype — MXU fast path
         k = k_ref[...]
         v = v_ref[...]
-        # scale the (block_q, d) tile, not the (block_q, block_k) s matrix
-        s = jax.lax.dot_general(
-            q * jnp.asarray(sm_scale, q.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for j in range(heads):
+            s = _scores(_head_lanes(q, j, d), k, sm_scale, causal,
+                        qi * block_q, kb * block_k)
+            m_prev = m_scr[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[j] = acc_scr[j] * alpha + _dot(
+                p.astype(v.dtype), _head_lanes(v, j, d), _AB)
+            m_scr[j] = m_new
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[...] = (m_scr[...] + jnp.log(l)).T
+        out = None
+        for j in range(heads):
+            l = jnp.maximum(l_scr[j], 1e-30)
+            o = acc_scr[j] / l   # zero outside head j's lanes
+            out = o if out is None else out + o
+            lse_ref[j] = (m_scr[j] + jnp.log(l)).T
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                       sm_scale: float, causal: bool, block_q: int,
-                       block_k: int):
+                       sm_scale: float, causal: bool, d: int, block_q: int):
     """Single-K-block forward (S <= block_k): direct one-shot softmax, no
-    online-softmax scratch carry / rescale passes."""
-    qi = pl.program_id(1)
+    online-softmax scratch carry / rescale passes.
+    Grid: (B, column blocks, num_q_blocks)."""
+    qi = pl.program_id(2)
     q = q_ref[...]
     k = k_ref[...]
     v = v_ref[...]
-    s = jax.lax.dot_general(
-        q * jnp.asarray(sm_scale, q.dtype), k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[...] = (acc / l).astype(o_ref.dtype)
-    lse_ref[...] = (m + jnp.log(l)).T
+    out = None
+    for j in range(lse_ref.shape[0]):
+        s = _scores(_head_lanes(q, j, d), k, sm_scale, causal,
+                    qi * block_q, 0)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        # zero outside head j's lanes, so the heads' tiles add up exactly
+        o = _dot(p.astype(v.dtype), _head_lanes(v, j, d), _AB) / l
+        out = o if out is None else out + o
+        lse_ref[j] = (m + jnp.log(l)).T
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    """[B*H, S, D] in -> (out [B*H, S, D], lse [B*H, S])."""
-    bh, seq_q, d = q.shape
+def _cut(q, k, heads, hpb, block_q, block_k):
+    """How [B, S, heads*D] is cut into programs -> (d, width of a column
+    block, column blocks, block_q, block_k)."""
+    d = q.shape[-1] // heads
+    return (d, hpb * d, heads // hpb, _fit_block(block_q, q.shape[1]),
+            _fit_block(block_k, k.shape[1]))
+
+
+def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
+    """[B, S, heads*D] in, ``hpb`` heads to a column block ->
+    (out [B, S, heads*D], lse [B*heads, 1, S])."""
+    b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    block_q = _fit_block(block_q, seq_q)
-    block_k = _fit_block(block_k, seq_k)
+    d, w, ncb, block_q, block_k = _cut(q, k, heads, hpb, block_q, block_k)
     num_kb = seq_k // block_k
     from jax.experimental.pallas import tpu as pltpu
 
+    out_shape = [
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # [B*heads, 1, S]: q-positions on the LANE axis. A trailing
+        # singleton dim ([bh, S, 1]) would tile-pad 128x in HBM
+        # (1.5 MB -> 192 MB per layer) and dominate the step in
+        # residual-stacking copies; this layout pads 8x only.
+        jax.ShapeDtypeStruct((b * heads, 1, seq_q), jnp.float32),
+    ]
+    cost = pl.CostEstimate(
+        flops=4 * b * heads * seq_q * seq_k * d // (2 if causal else 1),
+        bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
+        transcendentals=b * heads * seq_q * seq_k,
+    )
+
     if num_kb == 1:
-        kernel = functools.partial(
-            _fwd_single_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k)
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=(bh, seq_q // block_q),
-            in_specs=[
-                pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((None, block_k, d), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((None, block_k, d), lambda b, i: (b, 0, 0)),
-            ],
+        q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i: (b, i, c))
+        kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i: (b, 0, c))
+        return pl.pallas_call(
+            functools.partial(
+                _fwd_single_kernel, sm_scale=sm_scale, causal=causal, d=d,
+                block_q=block_q),
+            grid=(b, ncb, seq_q // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=[
-                pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((None, 1, block_q), lambda b, i: (b, 0, i)),
+                q_spec,
+                pl.BlockSpec((hpb, 1, block_q),
+                             lambda b, c, i: (b * ncb + c, 0, i)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-                # [bh, 1, S]: q-positions on the LANE axis. A trailing
-                # singleton dim ([bh, S, 1]) would tile-pad 128x in HBM
-                # (1.5 MB -> 192 MB per layer) and dominate the step in
-                # residual-stacking copies; this layout pads 8x only.
-                jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
-            ],
+            out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             name=KERNEL_NAMES["fwd_single"],
             interpret=_use_interpret(),
-            cost_estimate=pl.CostEstimate(
-                flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
-                bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-                transcendentals=bh * seq_q * seq_k,
-            ),
+            cost_estimate=cost,
         )(q, k, v)
-        return out, lse
 
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_kb=num_kb)
-    grid = (bh, seq_q // block_q, num_kb)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
+    q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
+    kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i, j: (b, j, c))
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, d=d,
+            block_q=block_q, block_k=block_k, num_kb=num_kb),
+        grid=(b, ncb, seq_q // block_q, num_kb),
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
+            q_spec,
+            pl.BlockSpec((hpb, 1, block_q),
+                         lambda b, c, i, j: (b * ncb + c, 0, i)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-            # [bh, 1, S]: see _fwd_single_kernel's out_shape comment
-            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((hpb, block_q, w), jnp.float32),
         ],
-        # batch*head and q-block grid dims are independent — marking them
-        # parallel lets Mosaic pipeline the next block's DMA under compute;
-        # only the K dim (scratch carry) is sequential
+        # batch, column-block and q-block grid dims are independent —
+        # marking them parallel lets Mosaic pipeline the next block's DMA
+        # under compute; only the K dim (scratch carry) is sequential
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         name=KERNEL_NAMES["fwd"],
         interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
-            bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-            transcendentals=bh * seq_q * seq_k,
-        ),
+        cost_estimate=cost,
     )(q, k, v)
-    return out, lse
 
 
 # ---------------------------------------------------------------------------
@@ -238,15 +298,39 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_head(q, k, v, do, lse, delta, j, *, d, sm_scale, causal, row0,
+              col0):
+    """What head j of the block gives one (q block, k block) pair:
+    (p, ds, q_j, do_j), p the recomputed probabilities and ds = dL/ds with
+    the sm_scale of s = (q·scale)·kᵀ folded in once (it routes into both
+    dq and dk), both in the inputs' dtype for the MXU."""
+    qj = _head_lanes(q, j, d)
+    doj = _head_lanes(do, j, d)
+    p = jnp.exp(_scores(qj, k, sm_scale, causal, row0, col0) - lse)
+    dp = _dot(doj, v, _ABT)
+    ds = p * (dp - delta) * sm_scale
+    return p.astype(do.dtype), ds.astype(k.dtype), qj, doj
+
+
+def _row_delta(do, o, j, d):
+    """delta_i = rowsum(dO_i * O_i) over head j's lanes -> (block_q, 1)
+    f32. Computed where dO and O are already in VMEM: left to XLA, the
+    reduction over 64 of 1024 lanes made it keep dO with S on the lanes
+    and copy it back for the kernel."""
+    return jnp.sum(_head_lanes(do, j, d).astype(jnp.float32)
+                   * o.astype(jnp.float32), axis=-1, keepdims=True)
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                      sm_scale: float, causal: bool,
-                      block_q: int, block_k: int, num_qb: int):
+                      sm_scale: float, causal: bool, d: int,
+                      block_q: int, num_qb: int):
     """Single-pass backward for the num_kb == 1 case (S <= block_k): one
-    (b, qi) instance computes s/p ONCE and emits dq directly plus dk/dv
-    scratch accumulation — versus the two-pass scheme which recomputes
-    the s matrix, causal mask, and exp in both the dq and dkv kernels.
-    Grid: (B*H, 1, num_q_blocks); qi minor so dk/dv carry in scratch."""
+    (b, column block, qi) instance computes s/p ONCE per head and emits dq
+    directly plus dk/dv scratch accumulation — versus the two-pass scheme
+    which recomputes the s matrix, causal mask, and exp in both the dq and
+    dkv kernels. Grid: (B, column blocks, num_q_blocks); qi minor so dk/dv
+    carry in scratch."""
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -258,36 +342,19 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[...]
     v = v_ref[...]
     do = do_ref[...]
-    lse = lse_ref[...].T    # stored [1, block_q]; rows here are q-positions
-    delta = delta_ref[...].T
-    # scale on the (block_q, d) tile — 16x cheaper than scaling the
-    # (block_q, block_k) s matrix
-    qs = q * jnp.asarray(sm_scale, q.dtype)
-    s = jax.lax.dot_general(
-        qs, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
-    p = jnp.exp(s - lse)
-    pt = p.astype(do.dtype)
-    dv_scr[...] += jax.lax.dot_general(
-        pt, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # ds = dL/ds; the sm_scale factor of s = (q·scale)·kᵀ routes into both
-    # dq and dk, so fold it once here
-    dsc = (p * (dp - delta) * sm_scale).astype(k.dtype)
-    dq_ref[...] = jax.lax.dot_general(
-        dsc, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_scr[...] += jax.lax.dot_general(
-        dsc, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o = o_ref[...]
+    dq = None
+    for j in range(lse_ref.shape[0]):
+        # lse is stored [1, block_q]; rows here are q-positions
+        p, ds, qj, doj = _bwd_head(
+            q, k, v, do, lse_ref[j].T, _row_delta(do, o, j, d), j, d=d,
+            sm_scale=sm_scale, causal=causal, row0=qi * block_q, col0=0)
+        # each product is zero outside head j's lanes: the heads add up
+        dv_scr[...] += _dot(p, doj, _ATB)
+        dk_scr[...] += _dot(ds, qj, _ATB)
+        dqj = _dot(ds, _head_lanes(k, j, d), _AB)
+        dq = dqj if dq is None else dq + dqj
+    dq_ref[...] = dq.astype(dq_ref.dtype)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
@@ -295,16 +362,21 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale: float, causal: bool,
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                   dq_ref, delta_ref, dq_scr, *,
+                   sm_scale: float, causal: bool, d: int,
                    block_q: int, block_k: int, num_kb: int):
-    """Grid: (B*H, num_q_blocks, num_k_blocks); accumulates dq over K."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    """Grid: (B, column blocks, num_q_blocks, num_k_blocks); accumulates
+    dq over K. Also emits delta [B*heads, 1, S] (``_row_delta``), which it
+    needs itself and the dk/dv kernel reads."""
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
 
     @pl.when(kb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        for j in range(lse_ref.shape[0]):
+            delta_ref[j] = _row_delta(do_ref[...], o_ref[...], j, d).T
 
     run = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
 
@@ -314,25 +386,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[...]
         v = v_ref[...]
         do = do_ref[...]
-        lse = lse_ref[...].T
-        delta = delta_ref[...].T
-        s = jax.lax.dot_general(
-            q * jnp.asarray(sm_scale, q.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)  # (bq, bk) f32, exactly softmax(s)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for j in range(lse_ref.shape[0]):
+            _, ds, _, _ = _bwd_head(
+                q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
+                sm_scale=sm_scale, causal=causal, row0=qi * block_q,
+                col0=kb * block_k)
+            dq_scr[...] += _dot(ds, _head_lanes(k, j, d), _AB)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -341,11 +400,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    sm_scale: float, causal: bool,
+                    sm_scale: float, causal: bool, d: int,
                     block_q: int, block_k: int, num_qb: int):
-    """Grid: (B*H, num_k_blocks, num_q_blocks); accumulates dk/dv over Q."""
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
+    """Grid: (B, column blocks, num_k_blocks, num_q_blocks); accumulates
+    dk/dv over Q."""
+    kb = pl.program_id(2)
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -360,29 +420,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[...]
         v = v_ref[...]
         do = do_ref[...]
-        lse = lse_ref[...].T
-        delta = delta_ref[...].T
-        s = jax.lax.dot_general(
-            q * jnp.asarray(sm_scale, q.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        pt = p.astype(do.dtype)
-        dv_scr[...] += jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for j in range(lse_ref.shape[0]):
+            p, ds, qj, doj = _bwd_head(
+                q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
+                sm_scale=sm_scale, causal=causal, row0=qi * block_q,
+                col0=kb * block_k)
+            dv_scr[...] += _dot(p, doj, _ATB)
+            dk_scr[...] += _dot(ds, qj, _ATB)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
@@ -390,110 +434,110 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
-    bh, seq_q, d = q.shape
+def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
+               block_k):
+    """[B, S, heads*D] q, k, v, o, dO and lse [B*heads, 1, S] ->
+    dq, dk, dv [B, S, heads*D]."""
+    b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    block_q = _fit_block(block_q, seq_q)
-    block_k = _fit_block(block_k, seq_k)
+    d, w, ncb, block_q, block_k = _cut(q, k, heads, hpb, block_q, block_k)
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
-    # delta_i = rowsum(dO_i * O_i): cheap elementwise reduce — jnp/XLA.
-    # [bh, 1, S] like lse (a trailing dim would tile-pad 128x in HBM).
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-
     interp = _use_interpret()
     from jax.experimental.pallas import tpu as pltpu
+
+    bh = b * heads
+    half = 2 if causal else 1
+    bytes_qkv2 = (q.size * 2 + k.size * 2 + v.size * 2) * q.dtype.itemsize
 
     if num_kb == 1:
         # single K block: one fused pass computes s/p once and emits
         # dq + dk + dv together (the two-pass scheme below recomputes the
         # s matrix, mask, and exp in each kernel)
-        qb_spec = pl.BlockSpec((None, block_q, d), lambda b, j, i: (b, i, 0))
-        rowb_spec = pl.BlockSpec((None, 1, block_q),
-                                 lambda b, j, i: (b, 0, i))
-        kb_spec = pl.BlockSpec((None, block_k, d), lambda b, j, i: (b, j, 0))
-        dq, dk, dv = pl.pallas_call(
+        q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i: (b, i, c))
+        row_spec = pl.BlockSpec((hpb, 1, block_q),
+                                lambda b, c, i: (b * ncb + c, 0, i))
+        kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i: (b, 0, c))
+        return pl.pallas_call(
             functools.partial(
-                _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k, num_qb=num_qb),
-            grid=(bh, 1, num_qb),
-            in_specs=[qb_spec, kb_spec, kb_spec, qb_spec, rowb_spec,
-                      rowb_spec],
-            out_specs=[qb_spec, kb_spec, kb_spec],
+                _bwd_fused_kernel, sm_scale=sm_scale, causal=causal, d=d,
+                block_q=block_q, num_qb=num_qb),
+            grid=(b, ncb, num_qb),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+            out_specs=[q_spec, kv_spec, kv_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, w), jnp.float32),
+                pltpu.VMEM((block_k, w), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             name=KERNEL_NAMES["bwd_fused"],
             interpret=interp,
             cost_estimate=pl.CostEstimate(
-                flops=10 * bh * seq_q * seq_k * d // (2 if causal else 1),
-                bytes_accessed=(q.size * 2 + k.size * 2 + v.size * 2)
-                * q.dtype.itemsize,
+                flops=10 * bh * seq_q * seq_k * d // half,
+                bytes_accessed=bytes_qkv2,
                 transcendentals=bh * seq_q * seq_k,
             ),
-        )(q, k, v, g, lse, delta)
-        return dq, dk, dv
+        )(q, k, v, o, g, lse)
 
-    q_spec = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i))
-    k_spec = pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0))
+    q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
+    row_spec = pl.BlockSpec((hpb, 1, block_q),
+                            lambda b, c, i, j: (b * ncb + c, 0, i))
+    kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i, j: (b, j, c))
+    parallel3 = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
-    dq = pl.pallas_call(
+    dq, delta = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
+            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, d=d,
             block_q=block_q, block_k=block_k, num_kb=num_kb),
-        grid=(bh, num_qb, num_kb),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        grid=(b, ncb, num_qb, num_kb),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
+        compiler_params=parallel3,
         name=KERNEL_NAMES["bwd_dq"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * seq_q * seq_k * d // (2 if causal else 1),
+            flops=4 * bh * seq_q * seq_k * d // half,
             bytes_accessed=(q.size * 2 + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * seq_q * seq_k,
         ),
-    )(q, k, v, g, lse, delta)
+    )(q, k, v, o, g, lse)
 
     # dk/dv: Q streams in the minor grid dim.
-    qb_spec = pl.BlockSpec((None, block_q, d), lambda b, j, i: (b, i, 0))
-    rowb_spec = pl.BlockSpec((None, 1, block_q), lambda b, j, i: (b, 0, i))
-    kb_spec = pl.BlockSpec((None, block_k, d), lambda b, j, i: (b, j, 0))
+    qb_spec = pl.BlockSpec((None, block_q, w), lambda b, c, j, i: (b, i, c))
+    rowb_spec = pl.BlockSpec((hpb, 1, block_q),
+                             lambda b, c, j, i: (b * ncb + c, 0, i))
+    kb_spec = pl.BlockSpec((None, block_k, w), lambda b, c, j, i: (b, j, c))
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, d=d,
             block_q=block_q, block_k=block_k, num_qb=num_qb),
-        grid=(bh, num_kb, num_qb),
+        grid=(b, ncb, num_kb, num_qb),
         in_specs=[qb_spec, kb_spec, kb_spec, qb_spec, rowb_spec, rowb_spec],
         out_specs=[kb_spec, kb_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, w), jnp.float32),
+            pltpu.VMEM((block_k, w), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=parallel3,
         name=KERNEL_NAMES["bwd_dkv"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
-            flops=8 * bh * seq_q * seq_k * d // (2 if causal else 1),
-            bytes_accessed=(q.size * 2 + k.size * 2 + v.size * 2)
-            * q.dtype.itemsize,
+            flops=8 * bh * seq_q * seq_k * d // half,
+            bytes_accessed=bytes_qkv2,
             transcendentals=bh * seq_q * seq_k,
         ),
     )(q, k, v, g, lse, delta)
@@ -503,67 +547,76 @@ def _flash_bwd(q, k, v, o, lse, g, sm_scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 # custom VJP — boundary carries MERGED [B, S, H*D] tensors
 # ---------------------------------------------------------------------------
-# Residuals cross the fwd/bwd boundary in merged form on purpose: a
-# [B*H, S, 64] tensor tile-pads its 64-lane minor dim to 128 in HBM (2x
-# memory AND 2x traffic every time the remat machinery stacks it into the
-# per-layer residual buffers). [B, S, 768] is unpadded; the padded kernel
-# layout exists only transiently inside the fwd/bwd computations.
+# Residuals cross the fwd/bwd boundary in merged form, and where
+# ``_heads_per_block`` allows it (hpb > 0) nothing else exists: the
+# kernels read q, k, v, o, dO and write o, dq, dk, dv in that form, no
+# transpose or reshape stands around them. Elsewhere (hpb == 0: heads of
+# 32, 96, 256, ...) the kernels get [B*H, S, D] copies made here, which
+# exist only transiently inside the fwd/bwd computations: a [B*H, S, 64]
+# tensor tile-pads its 64-lane minor dim to 128 in HBM (2x memory AND 2x
+# traffic every time the remat machinery stacks it into the per-layer
+# residual buffers), [B, S, 768] is unpadded.
 
 
-def _to_bhsd(x):
-    b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+def _to_bhsd(x, h):
+    b, s, hd = x.shape
+    return x.reshape(b, s, h, hd // h).transpose(0, 2, 1, 3).reshape(
+        b * h, s, hd // h)
 
 
 def _from_bhsd(x, b, h):
-    bh, s, d = x.shape
-    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-
-
-def _merged_to_bhsd(x, h):
-    b, s, hd = x.shape
-    return _to_bhsd(x.reshape(b, s, h, hd // h))
-
-
-def _bhsd_to_merged(x, b, h):
     s, d = x.shape[1:]
-    return _from_bhsd(x, b, h).reshape(b, s, h * d)
+    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(qm, km, vm, h, sm_scale, causal, block_q, block_k):
-    out, _ = _flash_fwd(_merged_to_bhsd(qm, h), _merged_to_bhsd(km, h),
-                        _merged_to_bhsd(vm, h), sm_scale, causal,
-                        block_q, block_k)
-    return _bhsd_to_merged(out, qm.shape[0], h)
+def _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
+    if hpb:
+        return _flash_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q,
+                          block_k)
+    out, lse = _flash_fwd(_to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
+                          1, 1, sm_scale, causal, block_q, block_k)
+    return _from_bhsd(out, qm.shape[0], h), lse
 
 
-def _flash_vjp_fwd(qm, km, vm, h, sm_scale, causal, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
+    return _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
+                    block_k)[0]
+
+
+def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
     from jax.ad_checkpoint import checkpoint_name
 
-    out, lse = _flash_fwd(_merged_to_bhsd(qm, h), _merged_to_bhsd(km, h),
-                          _merged_to_bhsd(vm, h), sm_scale, causal,
-                          block_q, block_k)
+    out_m, lse = _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
+                          block_k)
     # Named so a remat policy can choose to SAVE these residuals: pallas
     # outputs are not dots, so a dots-saveable policy would otherwise
     # re-run the forward kernel inside the backward pass.
-    out_m = checkpoint_name(_bhsd_to_merged(out, qm.shape[0], h), "flash_out")
+    out_m = checkpoint_name(out_m, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return out_m, (qm, km, vm, out_m, lse)
 
 
-def _flash_vjp_bwd(h, sm_scale, causal, block_q, block_k, res, g):
+def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, res, g):
     qm, km, vm, out_m, lse = res
+    if hpb:
+        return _flash_bwd(qm, km, vm, out_m, lse, g, h, hpb, sm_scale,
+                          causal, block_q, block_k)
     b = qm.shape[0]
-    dq, dk, dv = _flash_bwd(
-        _merged_to_bhsd(qm, h), _merged_to_bhsd(km, h),
-        _merged_to_bhsd(vm, h), _merged_to_bhsd(out_m, h), lse,
-        _merged_to_bhsd(g, h), sm_scale, causal, block_q, block_k)
-    return (_bhsd_to_merged(dq, b, h), _bhsd_to_merged(dk, b, h),
-            _bhsd_to_merged(dv, b, h))
+    grads = _flash_bwd(
+        _to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
+        _to_bhsd(out_m, h), lse, _to_bhsd(g, h), 1, 1, sm_scale, causal,
+        block_q, block_k)
+    return tuple(_from_bhsd(x, b, h) for x in grads)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _note_path(layout: str, hpb: int, d: int, seq: int) -> None:
+    PATH_COUNTS[layout] += 1
+    _record("rtpu.ops.flash.path", layout,
+            {"layout": layout, "heads_per_block": hpb, "hd": d, "S": seq})
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -572,24 +625,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 1024, block_k: int = 1024) -> jax.Array:
     """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> same shape.
 
-    head_dim should be a multiple of 128 for MXU efficiency (pads are the
-    caller's job — model dims are chosen MXU-friendly instead)."""
+    Heads of 64 or 128 whose merged width heads*head_dim is a multiple of
+    128 run with no copy around the kernels; other head sizes are
+    transposed to and fro, and sequences that are no multiple of 128 go
+    to ``mha_reference`` (module docstring; ``PATH_COUNTS``)."""
+    b, s, h, d = q.shape
     if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if causal and q.shape[1] != k.shape[1]:
+        sm_scale = 1.0 / (d ** 0.5)
+    if causal and s != k.shape[1]:
         # The kernels' diagonal masks assume square attention; the reference
         # formulation applies a (seq_k - seq_q) offset this path does not.
         raise ValueError(
             f"causal flash_attention requires seq_q == seq_k, got "
-            f"{q.shape[1]} != {k.shape[1]}; use mha_reference for "
+            f"{s} != {k.shape[1]}; use mha_reference for "
             "offset-causal decode")
-    if q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0:
+    if s % 128 != 0 or k.shape[1] % 128 != 0:
         # Mosaic's minimum tile is (8, 128): sub-128 sequence blocks lower
         # to illegal or silently padded tiles on real TPU. Pads are the
         # caller's job; unpadded odd shapes go to the XLA reference.
+        _note_path("reference", 0, d, s)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    b, s, h, d = q.shape
+    hpb = _heads_per_block(h, d)
+    _note_path("merged" if hpb else "relayout", hpb, d, s)
     merge = lambda x: x.reshape(x.shape[0], x.shape[1], h * d)  # noqa: E731
-    out = _flash(merge(q), merge(k), merge(v), h, sm_scale, causal,
+    out = _flash(merge(q), merge(k), merge(v), h, hpb, sm_scale, causal,
                  block_q, block_k)
     return out.reshape(b, s, h, d)

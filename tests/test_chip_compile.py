@@ -78,6 +78,36 @@ def test_flash_attention_fwd_bwd_gpt2_small(one_chip, compiled_kernels):
     assert _kernel_names(text) == {"flash_fwd_single", "flash_bwd_fused"}
 
 
+def test_flash_attention_merged_layout_gpt2_medium(one_chip,
+                                                   compiled_kernels):
+    """The benchmark cell's attention (B=8, S=1024, 16 heads of 64), handed
+    over as models/gpt.py hands it: [B, S, H*hd] arrays reshaped to four
+    dimensions and back. The kernels index the merged arrays, so the
+    compiled program holds the two single-block kernels and no array whose
+    minor dimension is a head of 64 (it would pad to 128 lanes in HBM and
+    be a copy of 16 MB for each of q, k, v, o, dO, dq, dk, dv)."""
+    import re
+
+    from ray_tpu.ops.flash_attention import PATH_COUNTS, flash_attention
+
+    b, s, h, d = 8, 1024, 16, 64
+    merged = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16,
+                                  sharding=one_chip)
+
+    def loss(q, k, v):
+        heads = lambda x: x.reshape(b, s, h, d)  # noqa: E731
+        out = flash_attention(heads(q), heads(k), heads(v), causal=True)
+        return out.reshape(b, s, h * d).astype(jnp.float32).sum()
+
+    before = PATH_COUNTS["merged"]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        merged, merged, merged).compile().as_text()
+    assert PATH_COUNTS["merged"] == before + 1
+    assert _kernel_names(text) == {"flash_fwd_single", "flash_bwd_fused"}
+    assert not re.findall(r"\w+\[[\d,]*,64\]", text)
+    assert " transpose(" not in text
+
+
 def _kernel_names(compiled_text: str) -> set:
     """The Pallas kernels of a compiled program by the names a device
     trace shows: each is a custom call to ``tpu_custom_call`` whose

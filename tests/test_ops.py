@@ -61,6 +61,114 @@ class TestFlashAttention:
                                    atol=3e-2, rtol=3e-2)
 
 
+def _path_counts():
+    from ray_tpu.ops.flash_attention import PATH_COUNTS
+
+    return dict(PATH_COUNTS)
+
+
+def _took(before, layout, calls=1):
+    """flash_attention was traced ``calls`` times since ``before``, every
+    time by ``layout``."""
+    after = _path_counts()
+    delta = {k: after[k] - before.get(k, 0) for k in after}
+    assert delta.get(layout, 0) == calls and sum(delta.values()) == calls, \
+        (layout, delta)
+
+
+class TestFlashAttentionLayouts:
+    """The kernels on the model's own [B, S, H*D] arrays (heads of 64 or
+    128, merged width a multiple of 128) and on [B*H, S, D] copies (every
+    other head size), against the dense oracle; interpret mode."""
+
+    # block 128 at S=256 streams K/V: flash_fwd, flash_bwd_dq, flash_bwd_dkv
+    CASES = [(h, d, s, causal, 1024)
+             for h, d in [(2, 64), (4, 64), (1, 128), (2, 128)]
+             for s in (128, 256) for causal in (True, False)]
+    CASES += [(4, 64, 256, True, 128), (2, 128, 256, False, 128)]
+
+    @pytest.mark.parametrize("h,d,s,causal,block", CASES)
+    def test_merged_matches_reference(self, h, d, s, causal, block):
+        q, k, v = _qkv(jax.random.PRNGKey(h * d + s), s=s, h=h, d=d)
+        w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+        def loss(attn):
+            return lambda q, k, v: (attn(q, k, v) * w).sum()
+
+        flash = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, causal=causal, block_q=block, block_k=block)
+        ref = lambda q, k, v: mha_reference(q, k, v, causal=causal)  # noqa: E731
+        before = _path_counts()
+        out = flash(q, k, v)
+        g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        _took(before, "merged", calls=2)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        g2 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("h,d,s,layout", [
+        (2, 32, 128, "relayout"), (4, 32, 128, "relayout"),
+        (3, 64, 128, "relayout"),   # 192 lanes: no whole 128-lane blocks
+        (2, 64, 64, "reference"),   # S no multiple of 128: no kernel
+    ])
+    def test_other_shapes_keep_the_old_route(self, h, d, s, layout):
+        q, k, v = _qkv(jax.random.PRNGKey(h + d), s=s, h=h, d=d)
+        before = _path_counts()
+        out = flash_attention(q, k, v, causal=True)
+        g1 = jax.grad(lambda *a: flash_attention(*a).sum(),
+                      argnums=(0, 1, 2))(q, k, v)
+        _took(before, layout, calls=2)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(mha_reference(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        g2 = jax.grad(lambda *a: mha_reference(*a).sum(),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("h,d,moved", [(4, 64, False), (4, 32, True)])
+    def test_no_transpose_around_the_merged_kernels(self, h, d, moved):
+        """Forward and backward of the merged layout hold no transpose
+        outside the kernels; the old route (heads of 32) does."""
+        q, k, v = _qkv(jax.random.PRNGKey(4), s=128, h=h, d=d)
+        before = _path_counts()
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: flash_attention(*a).sum(), argnums=(0, 1, 2)))(q, k, v)
+        _took(before, "relayout" if moved else "merged")
+
+        def prims(jp):
+            for eqn in jp.eqns:
+                yield eqn.primitive.name
+                if eqn.primitive.name == "pallas_call":
+                    continue    # what a kernel does inside is its own
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from prims(sub)
+
+        names = list(prims(jaxpr.jaxpr))
+        assert names.count("pallas_call") == 2
+        assert ("transpose" in names) == moved
+
+    def test_path_is_a_flight_recorder_event(self):
+        from ray_tpu.perf.recorder import get_recorder
+
+        rec = get_recorder()
+        was, rec.enabled = rec.enabled, True
+        try:
+            q, k, v = _qkv(jax.random.PRNGKey(5), s=128, h=2, d=64)
+            flash_attention(q, k, v)
+            ev = [e for e in rec.snapshot()
+                  if e["kind"] == "rtpu.ops.flash.path"][-1]
+        finally:
+            rec.enabled = was
+        assert ev["label"] == "merged"
+        assert ev["data"] == {"layout": "merged", "heads_per_block": 2,
+                              "hd": 64, "S": 128}
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_dense(self, causal):
