@@ -56,8 +56,7 @@ class GPTStage:
         def fwd(x):
             if first:
                 x = model._embed(wte, wpe, x)
-            for i in range(hi - lo):
-                x = model._block(x, {k: v[i] for k, v in lp.items()}, None)
+            x = model._run_layers(x, lp, remat=False, scan=False)
             if last:
                 x = layernorm(x, lnf_g, lnf_b)
                 return model._lm_head(wte, x)

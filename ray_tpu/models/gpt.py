@@ -45,19 +45,13 @@ class GPTConfig:
     param_dtype: Any = jnp.float32
     remat: bool = True
     use_flash: bool = True
-    flash_block_q: int = 1024     # flash kernel tile sizes (clamped to seq)
-    flash_block_k: int = 1024
     # scan_layers=True compiles one block body (fast compile, the right
     # default for deep models); False unrolls the layer loop — slower to
     # compile but removes the scan's per-layer residual-stacking
-    # dynamic-update-slices, worth ~6% MFU on the training bench
+    # dynamic-update-slices (ROADMAP A3 decides between the two on the
+    # chip)
     scan_layers: bool = True
     seq_axis: Optional[str] = None  # set to "sp" to use ring attention
-    # hand-fused LN+matmul block entry / matmul+residual block exit
-    # (ops/fused.py Pallas kernels). A/B'd against XLA's own fusion in
-    # docs/PERF_NOTES.md round 5 — kept as a measured option, not the
-    # default
-    fused_entry_exit: bool = False
 
     @property
     def padded_vocab(self) -> int:
@@ -164,8 +158,8 @@ class GPT:
 
         Attention term: QK^T + PV are each 2·S·D MAC-FLOPs per token per
         layer forward (4·S·D), ×3 for fwd+bwd = 12·S·D, halved for causal
-        masking → 6·L·S·D. This is the single source of truth; bench.py
-        calls it rather than duplicating the formula."""
+        masking → 6·L·S·D. The benchmark counts the same operations from
+        a configuration's sizes (benchmark/lib/flops.py)."""
         c = self.config
         s = c.max_seq if seq is None else seq
         n = self.num_params()
@@ -180,8 +174,7 @@ class GPT:
         return jnp.where(keep, x / jnp.asarray(1.0 - rate, x.dtype),
                          jnp.zeros_like(x))
 
-    def _block(self, x: jax.Array, lp: Dict[str, jax.Array],
-               rng: Optional[jax.Array]) -> jax.Array:
+    def _block(self, x: jax.Array, lp: Dict[str, jax.Array]) -> jax.Array:
         c = self.config
         B, S, D = x.shape
         H, hd = c.n_head, c.head_dim
@@ -191,22 +184,13 @@ class GPT:
         drop = c.dropout > 0.0 and key is not None
         if drop:
             k_attn, k_mlp = jax.random.split(key)
-        fused_exit = c.fused_entry_exit and not drop
         # the two halves of a block are named scopes (metadata only):
         # a device trace charges every operation, forward, backward and
         # recomputed, to the scope in its op_name
         with jax.named_scope("attn"):
-            if c.fused_entry_exit:
-                from ..ops.fused import ln_matmul, matmul_residual
-
-                qkv = ln_matmul(
-                    x.reshape(B * S, D), lp["ln1_g"], lp["ln1_b"],
-                    lp["w_qkv"].astype(c.dtype),
-                    lp["b_qkv"].astype(c.dtype)).reshape(B, S, 3 * D)
-            else:
-                h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
-                qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
-                    + lp["b_qkv"].astype(c.dtype)
+            h = layernorm(x, lp["ln1_g"], lp["ln1_b"])
+            qkv = (h @ lp["w_qkv"].astype(c.dtype)) \
+                + lp["b_qkv"].astype(c.dtype)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(B, S, H, hd)
             k = k.reshape(B, S, H, hd)
@@ -215,34 +199,18 @@ class GPT:
                 attn = ring_attention(q, k, v, axis_name=c.seq_axis,
                                       causal=True)
             elif c.use_flash:
-                attn = flash_attention(q, k, v, causal=True,
-                                       block_q=c.flash_block_q,
-                                       block_k=c.flash_block_k)
+                attn = flash_attention(q, k, v, causal=True)
             else:
                 from ..ops import mha_reference
 
                 attn = mha_reference(q, k, v, causal=True)
             attn = attn.reshape(B, S, D)
-            if fused_exit:
-                x = matmul_residual(attn.reshape(B * S, D),
-                                    lp["w_proj"].astype(c.dtype),
-                                    lp["b_proj"].astype(c.dtype),
-                                    x.reshape(B * S, D)).reshape(B, S, D)
-            else:
-                proj = (attn @ lp["w_proj"].astype(c.dtype)) \
-                    + lp["b_proj"].astype(c.dtype)
-                if drop:
-                    proj = self._dropout(proj, k_attn)
-                x = x + proj
+            proj = (attn @ lp["w_proj"].astype(c.dtype)) \
+                + lp["b_proj"].astype(c.dtype)
+            if drop:
+                proj = self._dropout(proj, k_attn)
+            x = x + proj
         with jax.named_scope("mlp"):
-            if fused_exit:
-                h = ln_matmul(x.reshape(B * S, D), lp["ln2_g"], lp["ln2_b"],
-                              lp["w_fc"].astype(c.dtype),
-                              lp["b_fc"].astype(c.dtype))
-                h = gelu(h)
-                return matmul_residual(h, lp["w_out"].astype(c.dtype),
-                                       lp["b_out"].astype(c.dtype),
-                                       x.reshape(B * S, D)).reshape(B, S, D)
             h = layernorm(x, lp["ln2_g"], lp["ln2_b"])
             h = gelu((h @ lp["w_fc"].astype(c.dtype))
                      + lp["b_fc"].astype(c.dtype))
@@ -308,9 +276,7 @@ class GPT:
         the LM head + logsumexp run per token-chunk under jax.checkpoint,
         so only per-chunk logits ever exist (fwd and bwd) — e.g. 3.3 GB of
         GPT-2-small logits at B=16,S=1024 become 8 × 412 MB transients.
-        This is the bench configuration (bench.py): marginally faster than
-        plain `loss` at B=32+ and the only option once vocab*batch*seq
-        logits stop fitting HBM."""
+        The only option once vocab*batch*seq logits stop fitting HBM."""
         x = self._backbone(params, tokens, rng)         # [B,S,D] bf16
         return self._chunked_head_nll(params["wte"], x, targets, num_chunks)
 
@@ -380,12 +346,7 @@ class GPT:
         x_mb = x.reshape(M, B // M, S, D)
 
         def stage_fn(lp, xs):
-            def blk(h, lpp):
-                return self._block(h, lpp, None), None
-            body = jax.checkpoint(blk, policy=self._remat_policy()) \
-                if c.remat else blk
-            h, _ = jax.lax.scan(body, xs, lp)
-            return h
+            return self._run_layers(xs, lp, remat=c.remat, scan=True)
 
         y_mb = pipeline_spmd(stage_fn, stages, x_mb, mesh, pp_axis=pp_axis)
         x = y_mb.reshape(B, S, D)
@@ -574,7 +535,7 @@ class GPT:
     def pipeline_stages(self, params: Dict[str, jax.Array],
                         num_chunks: int):
         """Split this GPT into ``num_chunks`` pipeline chunks for the
-        actor-hosted engines: chunk 0 carries the embedding, the last
+        actor-hosted engine: chunk 0 carries the embedding, the last
         chunk the final LN + tied LM head + loss, layer blocks divide
         evenly. Returns ``(chunk_fns, chunk_params, tied)`` — with
         ``num_chunks = P * virtual_stages`` the same entry point feeds
@@ -598,32 +559,37 @@ class GPT:
             x = self._dropout(x, emb_key)
             layer_params["_dropout_key"] = jax.random.split(
                 layers_key, c.n_layer)
-        rng = None  # keys travel inside layer_params from here
-
-        if c.scan_layers:
-            def block_fn(x, lp):
-                return self._block(x, lp, rng), None
-
-            if c.remat:
-                block_fn = jax.checkpoint(block_fn,
-                                          policy=self._remat_policy())
-            x, _ = jax.lax.scan(block_fn, x, layer_params)
-        else:
-            blk = self._block
-            if c.remat:
-                blk = jax.checkpoint(blk, policy=self._remat_policy())
-            for i in range(c.n_layer):
-                lp = {k: v[i] for k, v in layer_params.items()}
-                x = blk(x, lp, rng)
+        x = self._run_layers(x, layer_params, remat=c.remat,
+                             scan=c.scan_layers)
         with jax.named_scope("lm_head"):     # the final norm goes with it
             return layernorm(x, params["lnf_g"], params["lnf_b"])
+
+    def _run_layers(self, x: jax.Array, layer_params: Dict[str, jax.Array],
+                    *, remat: bool, scan: bool) -> jax.Array:
+        """Run ``x`` through the blocks whose parameters are stacked on the
+        leading axis of ``layer_params``: the one place a stack of
+        ``_block`` is looped over and checkpointed. ``scan`` compiles one
+        block body; unrolled is slower to compile and leaves out the
+        scan's stacking of each layer's saved residuals. ``remat`` saves
+        what ``_remat_policy`` names and recomputes the rest in the
+        backward."""
+        def body(h, lp):
+            return self._block(h, lp), None
+
+        if remat:
+            body = jax.checkpoint(body, policy=self._remat_policy())
+        if scan:
+            return jax.lax.scan(body, x, layer_params)[0]
+        for i in range(jax.tree.leaves(layer_params)[0].shape[0]):
+            x, _ = body(x, {k: v[i] for k, v in layer_params.items()})
+        return x
 
 
 # ---------------------------------------------------------------------------
 # pipeline-stage slicing — the model side of the actor-hosted pipeline
-# engines (train/pipeline_engine.py dynamic, train/pipeline_cgraph.py
-# compiled). Lives with the model because the split points (embedding /
-# layer blocks / LN+head) are model knowledge, not engine knowledge.
+# engine (train/pipeline_cgraph.py). Lives with the model because the
+# split points (embedding / layer blocks / LN+head) are model knowledge,
+# not engine knowledge.
 # ---------------------------------------------------------------------------
 
 
@@ -664,10 +630,9 @@ def gpt_pipeline_stages(model: "GPT", params: Dict[str, jax.Array],
         chunk_params.append(sp)
 
     def run_layers(model, sp, x):
-        def blk(h, lp):
-            return model._block(h, lp, None), None
-        h, _ = jax.lax.scan(blk, x, sp["layers"])
-        return h
+        # no checkpoint: the engine's own remat recomputes at the stage
+        # boundary (train/pipeline_cgraph.py, _make_programs)
+        return model._run_layers(x, sp["layers"], remat=False, scan=True)
 
     def make_first(model):
         def fn(sp, tokens):

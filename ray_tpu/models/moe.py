@@ -45,8 +45,6 @@ class MoEConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     use_flash: bool = True
-    flash_block_q: int = 1024
-    flash_block_k: int = 1024
 
     @property
     def padded_vocab(self) -> int:
@@ -185,9 +183,7 @@ class MoE:
         H, hd = c.n_head, c.head_dim
         shp = lambda t: t.reshape(B, S, H, hd)  # noqa: E731
         if c.use_flash:
-            attn = flash_attention(shp(q), shp(k), shp(v), causal=True,
-                                   block_q=c.flash_block_q,
-                                   block_k=c.flash_block_k)
+            attn = flash_attention(shp(q), shp(k), shp(v), causal=True)
         else:
             from ..ops import mha_reference
 

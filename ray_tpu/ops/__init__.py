@@ -1,4 +1,4 @@
-"""ray_tpu.ops — TPU kernels (Pallas) and fused numerics.
+"""ray_tpu.ops — TPU kernels (Pallas) and the models' numerics.
 
 The compute-hot path of the framework. The reference has no first-party
 kernels (its CUDA appears only through torch/NCCL deps — SURVEY.md §2
@@ -7,8 +7,10 @@ legend); for a TPU-native framework the hot ops are first-party:
 - flash_attention: tiled online-softmax attention on the MXU (Pallas).
 - ring_attention: context-parallel attention over the `sp` mesh axis —
   K/V blocks rotate the ring via ppermute while compute overlaps.
-- fused layers: rmsnorm/layernorm/rope/cross-entropy shaped so XLA fuses
-  them into adjacent matmuls.
+- layers: rmsnorm/layernorm/gelu/rope/cross-entropy in plain jnp, shaped
+  so XLA fuses them into the adjacent matmuls.
+- paged_attention: reads and writes of the serving engine's block-pool
+  KV cache.
 
 Everything here runs in Pallas interpret mode on CPU (tests) and compiled
 on TPU.

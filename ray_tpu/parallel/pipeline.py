@@ -17,8 +17,9 @@ nothing stage-parallel). Two layers live here:
 
 2. `schedule_1f1b` — the explicit per-stage 1F1B order (warmup fwds, then
    alternating 1F/1B, then cooldown bwds). The actor-hosted engine
-   (ray_tpu/train/pipeline_engine.py) executes this schedule across stage
-   actors; tests assert its bubble structure.
+   (ray_tpu/train/pipeline_cgraph.py) executes it across stage actors
+   in its interleaved form, `schedule_interleaved_1f1b`, which is this
+   order at one chunk per actor; tests assert its bubble structure.
 """
 from __future__ import annotations
 

@@ -18,7 +18,6 @@ from .trainer import DataParallelTrainer, JaxTrainer, TorchTrainer
 from .backend_executor import BackendExecutor, TrainWorkerError
 from .pipeline_cgraph import (CompiledPipelineEngine,
                               reshard_checkpoint, run_reference_1f1b)
-from .pipeline_engine import PipelineEngine
 
 __all__ = [
     "Checkpoint", "CheckpointConfig", "FailureConfig", "Result", "RunConfig",
@@ -26,6 +25,6 @@ __all__ = [
     "get_checkpoint", "get_mesh",
     "get_dataset_shard", "DataParallelTrainer", "JaxTrainer", "TorchTrainer",
     "BackendExecutor", "TrainWorkerError",
-    "CompiledPipelineEngine", "PipelineEngine", "reshard_checkpoint",
+    "CompiledPipelineEngine", "reshard_checkpoint",
     "run_reference_1f1b",
 ]

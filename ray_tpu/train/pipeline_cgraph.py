@@ -1,9 +1,9 @@
 """MPMD pipeline-parallel training engine on compiled graphs.
 
-The successor to the dynamic actor engine in pipeline_engine.py: same
-1F1B semantics, but the steady-state microbatch loop runs over
-PRE-ALLOCATED cgraph channels instead of per-call ``.remote()`` task
-specs — the exact hot path PR 4's compiled graphs made ~10x faster.
+The pipeline engine over actors: 1F1B with the steady-state microbatch
+loop running over PRE-ALLOCATED cgraph channels instead of per-call
+``.remote()`` task specs (the dynamic engine that dispatched each hop as
+a task was deleted once this one passed its numeric tests).
 
 Shape ("Scaling Deep Learning Training with MPMD Pipeline Parallelism",
 PAPERS.md): each stage actor holds resident JITTED fwd/bwd/update

@@ -30,8 +30,7 @@ with the fault story.
         --chaos "seed=7;kill=replica:LLMServer@4" --json /tmp/row.json
 
 Library use: ``make_trace`` / ``replay`` / ``summarize`` are imported
-by scripts/traffic_smoke.py (the CI gate) and bench.py (the
-``traffic_*`` rows).
+by scripts/traffic_smoke.py and scripts/trace_smoke.py (CI gates).
 """
 from __future__ import annotations
 

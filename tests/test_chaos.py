@@ -370,7 +370,7 @@ class TestTeardownIdempotency:
         # every waiter returned only after the segments were released
         assert node.store.stats()["num_channels"] == before
 
-    def test_pipeline_engine_concurrent_shutdown(self, ray_start_regular):
+    def test_compiled_pipeline_concurrent_shutdown(self, ray_start_regular):
         import optax
 
         from ray_tpu.train.pipeline_cgraph import CompiledPipelineEngine

@@ -42,9 +42,8 @@ def one_chip():
 def compiled_kernels(monkeypatch):
     """The kernels decide interpret mode from jax.default_backend(), which
     is the CPU here: steer them to the compiled path for these tests."""
-    for mod in ("ray_tpu.ops.flash_attention", "ray_tpu.ops.fused"):
-        monkeypatch.setattr(importlib.import_module(mod), "_use_interpret",
-                            lambda: False)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.flash_attention"),
+                        "_use_interpret", lambda: False)
 
 
 def _on(sharding, tree):
@@ -143,20 +142,6 @@ def test_flash_attention_streamed_kernels_are_named(one_chip,
                                    "flash_bwd_dkv"}
 
 
-@pytest.mark.parametrize("n,d,f", [(8192, 768, 3072), (8192, 768, 2304)])
-def test_fused_entry_exit_kernels(one_chip, compiled_kernels, n, d, f):
-    from ray_tpu.ops.fused import ln_matmul, matmul_residual
-
-    bf = jnp.bfloat16
-    sds = lambda *shape: jax.ShapeDtypeStruct(shape, bf, sharding=one_chip)  # noqa: E731
-    text = jax.jit(ln_matmul).lower(
-        sds(n, d), sds(d), sds(d), sds(d, f), sds(f)).compile().as_text()
-    assert "tpu_custom_call" in text
-    text = jax.jit(matmul_residual).lower(
-        sds(n, f), sds(f, d), sds(d), sds(n, d)).compile().as_text()
-    assert "tpu_custom_call" in text
-
-
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
     """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
     B=8, S=1024, params and optimizer state donated."""
@@ -203,3 +188,46 @@ def test_gpt2_small_engine_programs(one_chip):
         params, cache, i32(8), i32(8), i32(8, 40),
         jax.ShapeDtypeStruct((8,), jnp.bool_, sharding=one_chip)).compile()
     _fits(decode)
+
+
+def test_hlo_comparison_ignores_where_code_stands(one_chip,
+                                                  compiled_kernels):
+    """scripts/train_step_hlo.py compares two trees' programs with source
+    locations stripped: the same function written on two different lines,
+    flash kernels inside, compiles to texts that differ as they stand
+    (metadata, the stack-frame tables, the MLIR inside each custom call)
+    and are equal once stripped; another program stays different."""
+    import importlib.util
+    import os
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    spec = importlib.util.spec_from_file_location(
+        "train_step_hlo", os.path.join(os.path.dirname(__file__), "..",
+                                       "scripts", "train_step_hlo.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def here(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    def there(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    def other(q, k, v):
+        return flash_attention(q, k, v, causal=False).astype(
+            jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct((2, 256, 2, 64), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def text(f):
+        f.__name__ = "loss"           # the name goes into every op_name
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+            qkv, qkv, qkv).compile().as_text()
+
+    a, b, c = text(here), text(there), text(other)
+    assert a != b
+    assert tool.strip_locations(a) == tool.strip_locations(b)
+    assert "tpu_custom_call" in tool.strip_locations(a)
+    assert tool.strip_locations(a) != tool.strip_locations(c)

@@ -1549,7 +1549,8 @@ def test_flash_attention_pallas_sites_visited_and_clean():
     res = check_project([os.path.join(REPO, "ray_tpu", "ops")],
                         rules={"GC042"}, cache_path=None)
     assert res.findings == []
-    assert res.shape_stats.get("pallas_sites", 0) >= 7
+    # the five flash kernels (flash_attention.KERNEL_NAMES)
+    assert res.shape_stats.get("pallas_sites", 0) >= 5
 
 
 # ---------------------------------------------------------------------------

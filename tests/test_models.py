@@ -92,6 +92,91 @@ class TestGPT:
         assert 120e6 < n < 165e6  # 124M + vocab padding
 
 
+def _stack_setup(**kw):
+    cfg = GPTConfig.tiny(dtype=jnp.float32, use_flash=False, **kw)
+    model = GPT(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
+                                cfg.vocab_size)
+    return model, params, tokens
+
+
+@pytest.mark.parametrize("scan,remat", [(True, True), (True, False),
+                                        (False, True), (False, False)],
+                         ids=["scan-remat", "scan-plain", "unrolled-remat",
+                              "unrolled-plain"])
+def test_gpt_stack_forms_agree(scan, remat):
+    """GPT._run_layers is one loop in four forms (scanned or unrolled,
+    checkpointed or not): each gives the loss and the gradients of the
+    default, scan + remat, to float32 rounding."""
+    model, params, tokens = _stack_setup(scan_layers=scan, remat=remat)
+    default = GPT(GPTConfig.tiny(dtype=jnp.float32, use_flash=False))
+    assert default.config.scan_layers and default.config.remat
+    targets = jnp.roll(tokens, -1, axis=1)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(
+        params, tokens, targets)
+    want, want_grads = jax.jit(jax.value_and_grad(default.loss))(
+        params, tokens, targets)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    for name in want_grads:
+        np.testing.assert_allclose(
+            np.asarray(grads[name]), np.asarray(want_grads[name]),
+            rtol=1e-4, atol=1e-6, err_msg=name)
+
+
+def test_gpt_pipeline_callers_of_the_stack_match_backbone(monkeypatch):
+    """The two pipelines hand GPT._run_layers slices of the stack: the
+    actor engine's chunk functions chained by hand, and loss_pp's stage
+    function under pipeline_spmd on a pp=2 mesh, both end at the
+    activations _backbone computes from the same parameters."""
+    from ray_tpu.ops import layernorm
+
+    model, params, tokens = _stack_setup(n_layer=6)
+    want = np.asarray(jax.jit(model._backbone)(params, tokens))
+
+    (first, mid, _), chunks, _ = model.pipeline_stages(params, 3)
+    h = first(chunks[0], tokens)
+    for chunk in chunks[1:]:
+        h = mid(chunk, h)                 # a middle chunk reads "layers" only
+    got = layernorm(h, params["lnf_g"], params["lnf_b"])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+    seen = []
+    monkeypatch.setattr(
+        model, "_chunked_head_nll",
+        lambda wte, x, targets, num_chunks: seen.append(x) or jnp.float32(0))
+    mesh = virtual_mesh(8, MeshSpec(pp=2, dp=4))
+    model.loss_pp(params, tokens, jnp.roll(tokens, -1, axis=1), mesh,
+                  num_microbatches=2)
+    np.testing.assert_allclose(np.asarray(seen[0]), want,
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("family", ["gpt", "moe"])
+def test_flash_call_site_matches_reference(family):
+    """At a sequence the kernels take (a multiple of 128) the models call
+    flash_attention with no block size of their own: the loss is the
+    use_flash=False loss, and the call took a kernel path, not the
+    reference fallback that shorter test sequences fall into."""
+    from ray_tpu.models import MoE, MoEConfig
+    from ray_tpu.ops.flash_attention import PATH_COUNTS
+
+    make = {"gpt": lambda **kw: GPT(GPTConfig.tiny(**kw)),
+            "moe": lambda **kw: MoE(MoEConfig.tiny(**kw))}[family]
+    flash = make(dtype=jnp.float32, use_flash=True)
+    plain = make(dtype=jnp.float32, use_flash=False)
+    params = jax.jit(flash.init)(jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 512)
+    targets = jnp.roll(tokens, -1, axis=1)
+    before = dict(PATH_COUNTS)
+    got = jax.jit(flash.loss)(params, tokens, targets)
+    took = {k: PATH_COUNTS[k] - before.get(k, 0) for k in PATH_COUNTS}
+    assert took.get("reference", 0) == 0 and sum(took.values()) >= 1, took
+    want = jax.jit(plain.loss)(params, tokens, targets)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+
+
 class TestLlama:
     def test_forward_and_gqa(self):
         cfg = LlamaConfig.tiny(dtype=jnp.float32, use_flash=False, remat=False)

@@ -129,98 +129,6 @@ def test_failure_exhausts_budget(rt, tmp_path):
     assert result.error is not None
 
 
-class TestPipelineEngine:
-    """Actor-hosted 1F1B pipeline (train/pipeline_engine.py)."""
-
-    def test_gpt_pipeline_matches_single_process(self, rt):
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        import optax
-
-        from ray_tpu.models import GPT, GPTConfig
-        from ray_tpu.train.pipeline_engine import (PipelineEngine,
-                                                   gpt_pipeline_stages)
-
-        cfg = GPTConfig.tiny(dtype=jnp.float32, use_flash=False, remat=False)
-        model = GPT(cfg)
-        params = jax.jit(model.init)(jax.random.PRNGKey(0))
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
-                                    cfg.vocab_size)
-        targets = jnp.roll(tokens, -1, axis=1)
-
-        tx = optax.adam(1e-3)
-        stage_fns, stage_params, tied = gpt_pipeline_stages(model, params, 2)
-        eng = PipelineEngine(stage_fns, stage_params, tx=tx, tied=tied)
-        try:
-            mbs = [tokens[:2], tokens[2:]]
-            tgts = [targets[:2], targets[2:]]
-            loss_pp = eng.step(mbs, tgts)
-
-            # single-process reference: same loss and same updated params
-            loss_ref, grads = jax.value_and_grad(model.loss)(
-                params, tokens, targets)
-            assert abs(loss_pp - float(loss_ref)) < 1e-4
-
-            opt_state = tx.init(params)
-            updates, _ = tx.update(grads, opt_state, params)
-            params_ref = optax.apply_updates(params, updates)
-
-            new_stage_params = eng.get_params()
-            # stage 0 holds wte/wpe + first half of layers
-            np.testing.assert_allclose(
-                np.asarray(new_stage_params[0]["wte"]),
-                np.asarray(params_ref["wte"]), atol=1e-5, rtol=1e-5)
-            half = cfg.n_layer // 2
-            np.testing.assert_allclose(
-                np.asarray(new_stage_params[0]["layers"]["w_qkv"]),
-                np.asarray(params_ref["w_qkv"][:half]), atol=1e-5, rtol=1e-5)
-            np.testing.assert_allclose(
-                np.asarray(new_stage_params[1]["layers"]["w_qkv"]),
-                np.asarray(params_ref["w_qkv"][half:]), atol=1e-5, rtol=1e-5)
-            np.testing.assert_allclose(
-                np.asarray(new_stage_params[1]["lnf_g"]),
-                np.asarray(params_ref["lnf_g"]), atol=1e-5, rtol=1e-5)
-        finally:
-            eng.shutdown()
-
-    def test_1f1b_in_flight_bound(self, rt):
-        """The live-residual count on each stage respects the 1F1B memory
-        bound during a step (this is the point of 1F1B over GPipe)."""
-        import jax
-        import jax.numpy as jnp
-        import optax
-
-        from ray_tpu.models import GPT, GPTConfig
-        from ray_tpu.train.pipeline_engine import (PipelineEngine,
-                                                   gpt_pipeline_stages)
-
-        cfg = GPTConfig.tiny(dtype=jnp.float32, use_flash=False, remat=False)
-        model = GPT(cfg)
-        params = jax.jit(model.init)(jax.random.PRNGKey(0))
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
-                                    cfg.vocab_size)
-        targets = jnp.roll(tokens, -1, axis=1)
-        stage_fns, stage_params, tied = gpt_pipeline_stages(model, params, 2)
-        eng = PipelineEngine(stage_fns, stage_params, tx=optax.sgd(1e-3), tied=tied)
-        try:
-            mbs = [tokens[i:i + 2] for i in range(0, 8, 2)]
-            tgts = [targets[i:i + 2] for i in range(0, 8, 2)]
-            eng.step(mbs, tgts)
-            # after the step everything is drained
-            assert ray_tpu.get(
-                [s.in_flight.remote() for s in eng.stages], timeout=60) \
-                == [0, 0]
-            # the 1F1B memory bound held DURING the step: peak in-flight
-            # residuals per stage <= num_stages - stage_idx (a GPipe
-            # regression would show peak == M == 4 on every stage)
-            peaks = ray_tpu.get(
-                [s.max_in_flight.remote() for s in eng.stages], timeout=60)
-            assert peaks[0] <= 2 and peaks[1] <= 1, peaks
-        finally:
-            eng.shutdown()
-
-
 class TestTorchTrainer:
     def test_real_ddp_allreduce_across_gang(self, rt):
         """Smoke: TorchTrainer forms a real gloo process group
