@@ -27,9 +27,22 @@ Forward saves the logsumexp per row; backward is two Pallas kernels that
 recompute probabilities from (q, k, lse) inside the kernel — dq in one
 pass over K blocks, dk/dv in one pass over Q blocks — with f32 scratch
 accumulators, or one fused kernel where K/V are a single block; delta =
-rowsum(dO * O) is reduced inside them too (``_row_delta``). Causal
-masking skips fully-masked blocks via a predicate on the grid position,
-halving FLOPs for autoregressive models.
+rowsum(dO * O) is reduced inside them too (``_row_delta``).
+
+Causal calls do not compute what the mask would throw away, in one of two
+ways. The STREAMED kernels (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``: S larger than a block) skip fully-masked blocks by a
+predicate on the grid position. The SINGLE-BLOCK kernels
+(``flash_fwd_single``, ``flash_bwd_fused``), where one program holds the
+whole S x S square of a column block, cut its rows into bands of a
+quarter of the sequence (``_band_height``) and work band r on the columns
+[0, (r+1)*h) it can see: a Python loop over static slices of tiles that
+are in VMEM already, no predicate and no second call, 62.5 % of the
+square's matmuls and softmax at four bands. A masked score gave
+exp(-1e30 - m) = 0 to its row's sum and an exact zero to every product,
+so each band's numbers are the square's, summed over the non-zero terms.
+Non-causal calls and a single-block call with several q blocks work the
+whole block as one band.
 
 Reference capability (not design): the reference has no first-party
 attention kernels at all (torch/NCCL stack); this is new TPU-native work
@@ -70,6 +83,11 @@ KERNEL_NAMES = {
 # choice is the flight-recorder event ``rtpu.ops.flash.path``.
 PATH_COUNTS: collections.Counter = collections.Counter()
 
+# The same traced calls by the number of causal row bands a program works
+# (``_band_height``): 4 at S=1024, 1 where nothing is banded (non-causal,
+# streamed, S=128), 0 on the reference route. ``bands`` in the event's data.
+BAND_COUNTS: collections.Counter = collections.Counter()
+
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -88,6 +106,53 @@ def _fit_block(block: int, seq: int) -> int:
         if seq % b == 0:
             return b
     return 128
+
+
+def _band_height(seq: int, causal: bool, block_q: int, block_k: int) -> int:
+    """Height of the causal row bands of a call that one program holds
+    whole (seq <= both blocks), or 0 where nothing is banded: a non-causal
+    call, or one streamed in blocks, whose kernels skip masked blocks by
+    predicate. Band r owns rows [r*h, (r+1)*h) and computes the columns
+    [0, (r+1)*h) its rows can see, all slices static: of the square's
+    area (nb + 1) / (2 nb) is issued. The height is a quarter of the
+    sequence in whole 128-lane tiles. At S=1024 on a v5e (PERF.md, PR 31)
+    four bands of 256 (62.5 % of the area) took 64.5 % of the square's
+    time, two of 512 75.3 %, and eight of 128 (56 %) 61.9 %: fastest, but
+    a band is unrolled Python, traced and lowered at every start of a
+    process, and eight bands cost the step's build 0.6 s against 0.15 s
+    for four."""
+    if not causal or _fit_block(block_q, seq) != seq \
+            or _fit_block(block_k, seq) != seq:
+        return 0
+    return _fit_block(max(_LANES, seq // 4 // _LANES * _LANES), seq)
+
+
+def _row_bands(rows: int, cols: int, band: int):
+    """(first row, rows, columns computed) of each band of one program's
+    [rows, cols] scores: the whole block where nothing is banded."""
+    if not band:
+        return [(0, rows, cols)]
+    return [(r, band, r + band) for r in range(0, rows, band)]
+
+
+def _one_ahead(units, first, banded):
+    """(unit, first(unit)) of each (band, head) unit of a program, in
+    order. In a banded program the first stage of unit i+1 (its scores'
+    matmuls) is written before unit i's is handed out: Mosaic schedules
+    close to the order of the program, and with a unit's matmuls behind
+    the softmax of the one before, the MXU waited for the VPU between
+    bands (forward at S=1024: 80 % of the square's time so, 67 % one
+    ahead; PERF.md, PR 31). The square's two heads are left in the
+    parent's order: it gains nothing there, and the backward's second
+    [1024, 1024] set of tiles does not fit VMEM."""
+    if not banded:
+        for u in units:
+            yield u, first(u)
+        return
+    nxt = first(units[0])
+    for i, u in enumerate(units):
+        cur, nxt = nxt, (first(units[i + 1]) if i + 1 < len(units) else None)
+        yield u, cur
 
 
 def _heads_per_block(heads: int, d: int) -> int:
@@ -130,6 +195,57 @@ def _scores(q, k, sm_scale, causal, row0, col0):
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(rows >= cols, s, _NEG_INF)
     return s
+
+
+def _units(rows: int, cols: int, band: int, heads: int):
+    """The (band, head) units of a single-block program, in the order
+    they are worked: (first row, rows, columns computed, head)."""
+    return [(r0, h, end, j) for r0, h, end in _row_bands(rows, cols, band)
+            for j in range(heads)]
+
+
+def _rows(x, r0: int, h: int):
+    """Rows [r0, r0 + h) of a tile held as a value (a static, tile-aligned
+    slice: no data moves); the tile itself where that is all of it."""
+    if h == x.shape[0]:
+        return x
+    return jax.lax.slice(x, (r0, 0), (r0 + h, x.shape[1]))
+
+
+def _visible(row0, rows: int, cols: int):
+    """([rows, cols] of row0 + i >= c, as many of -1e30): the causal mask
+    of a tile whose first row is row0 columns below its first column, and
+    what ``_masked`` puts where it is False."""
+    return (row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1),
+            jnp.full((rows, cols), _NEG_INF, jnp.float32))
+
+
+def _masked(s, visible):
+    """s with its LAST visible.shape[1] columns causal-masked; the columns
+    before them are visible to every row (a row band's columns before its
+    diagonal tile). The mask is made once a program and shared by its
+    units: every band's diagonal tile has the same one."""
+    if visible is None:
+        return s
+    visible, neg = visible
+    free = s.shape[1] - visible.shape[1]
+    if not free:
+        return jax.lax.select(visible, s, neg)
+    return jax.lax.concatenate(
+        [jax.lax.slice(s, (0, 0), (s.shape[0], free)),
+         jax.lax.select(visible, jax.lax.slice(s, (0, free), s.shape), neg)],
+        1)
+
+
+def _col(x):
+    """Row-wise reduction result [rows] -> a column [rows, 1]."""
+    return jax.lax.expand_dims(x, (1,))
+
+
+def _along(col, like):
+    """A column [rows, 1] broadcast along the lanes of ``like``."""
+    return jax.lax.broadcast_in_dim(col, like.shape, (0, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -187,34 +303,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                       sm_scale: float, causal: bool, d: int, block_q: int):
+                       sm_scale: float, causal: bool, d: int, block_q: int,
+                       band: int):
     """Single-K-block forward (S <= block_k): direct one-shot softmax, no
-    online-softmax scratch carry / rescale passes.
-    Grid: (B, column blocks, num_q_blocks)."""
-    qi = pl.program_id(2)
-    q = q_ref[...]
-    k = k_ref[...]
+    online-softmax scratch carry / rescale passes. Where the call is
+    banded (``_band_height``) each row band computes only the columns it
+    can see: a band's rows have all their columns before them, so the
+    softmax stays one-shot. What does not depend on the band (the heads'
+    zeroed and scaled tiles, the diagonal tile's mask) is made once a
+    program: the body below is traced and lowered once per unit, at every
+    start of a process. Grid: (B, column blocks, num_q_blocks)."""
+    heads = lse_ref.shape[0]
+    block_k = k_ref.shape[0]
+    # scale the (block_q, d) tile, not the (block_q, block_k) s matrix
+    q = q_ref[...] * jnp.asarray(sm_scale, q_ref.dtype)
     v = v_ref[...]
+    qs = [_head_lanes(q, j, d) for j in range(heads)]
+    vs = [_head_lanes(v, j, d) for j in range(heads)]
+    visible = None
+    if causal:
+        visible = _visible(0, band, band) if band else _visible(
+            pl.program_id(2) * block_q, block_q, block_k)
+
+    def scores(u):
+        r0, h, end, j = u
+        return _masked(_dot(_rows(qs[j], r0, h), k_ref[:end, :], _ABT),
+                       visible)
+
     out = None
-    for j in range(lse_ref.shape[0]):
-        s = _scores(_head_lanes(q, j, d), k, sm_scale, causal,
-                    qi * block_q, 0)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    for (r0, h, end, j), s in _one_ahead(
+            _units(block_q, block_k, band, heads), scores, band):
+        # jax.lax, not jnp, in a unit's body: a jnp call costs several
+        # times as much to trace, and every unit is traced at every start
+        m = _col(jax.lax.reduce_max(s, (1,)))
+        p = jax.lax.exp(jax.lax.sub(s, _along(m, s)))
+        l = jax.lax.max(_col(jax.lax.reduce_sum(p, (1,))), 1e-30)
         # zero outside head j's lanes, so the heads' tiles add up exactly
-        o = _dot(p.astype(v.dtype), _head_lanes(v, j, d), _AB) / l
-        out = o if out is None else out + o
-        lse_ref[j] = (m + jnp.log(l)).T
-    o_ref[...] = out.astype(o_ref.dtype)
+        o = _dot(jax.lax.convert_element_type(p, v.dtype),
+                 _rows(vs[j], 0, end), _AB)
+        o = jax.lax.div(o, _along(l, o))
+        out = o if j == 0 else jax.lax.add(out, o)
+        lse_ref[j, :, r0:r0 + h] = jax.lax.transpose(
+            jax.lax.add(m, jax.lax.log(l)), (1, 0))
+        if j == heads - 1:
+            o_ref[r0:r0 + h, :] = out.astype(o_ref.dtype)
 
 
-def _cut(q, k, heads, hpb, block_q, block_k):
+def _cut(q, k, heads, hpb, causal, block_q, block_k):
     """How [B, S, heads*D] is cut into programs -> (d, width of a column
-    block, column blocks, block_q, block_k)."""
+    block, column blocks, block_q, block_k, height of a causal row band
+    inside a program or 0)."""
     d = q.shape[-1] // heads
     return (d, hpb * d, heads // hpb, _fit_block(block_q, q.shape[1]),
-            _fit_block(block_k, k.shape[1]))
+            _fit_block(block_k, k.shape[1]),
+            _band_height(q.shape[1], causal, block_q, block_k))
 
 
 def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
@@ -222,7 +364,8 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
     (out [B, S, heads*D], lse [B*heads, 1, S])."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    d, w, ncb, block_q, block_k = _cut(q, k, heads, hpb, block_q, block_k)
+    d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
+                                             block_q, block_k)
     num_kb = seq_k // block_k
     from jax.experimental.pallas import tpu as pltpu
 
@@ -246,7 +389,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
         return pl.pallas_call(
             functools.partial(
                 _fwd_single_kernel, sm_scale=sm_scale, causal=causal, d=d,
-                block_q=block_q),
+                block_q=block_q, band=band),
             grid=(b, ncb, seq_q // block_q),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=[
@@ -324,13 +467,17 @@ def _row_delta(do, o, j, d):
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                       sm_scale: float, causal: bool, d: int,
-                      block_q: int, num_qb: int):
+                      block_q: int, num_qb: int, band: int):
     """Single-pass backward for the num_kb == 1 case (S <= block_k): one
     (b, column block, qi) instance computes s/p ONCE per head and emits dq
     directly plus dk/dv scratch accumulation — versus the two-pass scheme
     which recomputes the s matrix, causal mask, and exp in both the dq and
-    dkv kernels. Grid: (B, column blocks, num_q_blocks); qi minor so dk/dv
-    carry in scratch."""
+    dkv kernels. A banded call (``_band_height``) works its row bands one
+    after the other, each on the columns it can see, and adds into the
+    same rows of the dk/dv scratch; what does not depend on the band is
+    made once a program, as in the forward. The numbers of a unit are
+    ``_bwd_head``'s. Grid: (B, column blocks, num_q_blocks); qi minor so
+    dk/dv carry in scratch."""
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -338,23 +485,43 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[...]
-    k = k_ref[...]
-    v = v_ref[...]
-    do = do_ref[...]
-    o = o_ref[...]
+    heads = lse_ref.shape[0]
+    block_k = k_ref.shape[0]
+    q, k, do, o = q_ref[...], k_ref[...], do_ref[...], o_ref[...]
+    qm = [_head_lanes(q, j, d) for j in range(heads)]
+    qs = [x * jnp.asarray(sm_scale, q.dtype) for x in qm]
+    ks = [_head_lanes(k, j, d) for j in range(heads)]
+    dos = [_head_lanes(do, j, d) for j in range(heads)]
+    # lse is stored [1, block_q]; rows here are q-positions
+    lse = [lse_ref[j].T for j in range(heads)]
+    delta = [_row_delta(do, o, j, d) for j in range(heads)]
+    visible = None
+    if causal:
+        visible = _visible(0, band, band) if band else _visible(
+            qi * block_q, block_q, block_k)
+
+    def head(u):
+        r0, h, end, j = u
+        doj = _rows(dos[j], r0, h)
+        s = _masked(_dot(_rows(qs[j], r0, h), k_ref[:end, :], _ABT), visible)
+        # jax.lax in a unit's body, as in the forward
+        p = jax.lax.exp(jax.lax.sub(s, _along(_rows(lse[j], r0, h), s)))
+        dp = _dot(doj, v_ref[:end, :], _ABT)
+        ds = jax.lax.mul(jax.lax.mul(p, jax.lax.sub(
+            dp, _along(_rows(delta[j], r0, h), dp))), sm_scale)
+        return (jax.lax.convert_element_type(p, do.dtype),
+                jax.lax.convert_element_type(ds, k.dtype), doj)
+
     dq = None
-    for j in range(lse_ref.shape[0]):
-        # lse is stored [1, block_q]; rows here are q-positions
-        p, ds, qj, doj = _bwd_head(
-            q, k, v, do, lse_ref[j].T, _row_delta(do, o, j, d), j, d=d,
-            sm_scale=sm_scale, causal=causal, row0=qi * block_q, col0=0)
+    for (r0, h, end, j), (p, ds, doj) in _one_ahead(
+            _units(block_q, block_k, band, heads), head, band):
         # each product is zero outside head j's lanes: the heads add up
-        dv_scr[...] += _dot(p, doj, _ATB)
-        dk_scr[...] += _dot(ds, qj, _ATB)
-        dqj = _dot(ds, _head_lanes(k, j, d), _AB)
-        dq = dqj if dq is None else dq + dqj
-    dq_ref[...] = dq.astype(dq_ref.dtype)
+        dv_scr[:end, :] += _dot(p, doj, _ATB)
+        dk_scr[:end, :] += _dot(ds, _rows(qm[j], r0, h), _ATB)
+        dqj = _dot(ds, _rows(ks[j], 0, end), _AB)
+        dq = dqj if j == 0 else jax.lax.add(dq, dqj)
+        if j == heads - 1:
+            dq_ref[r0:r0 + h, :] = dq.astype(dq_ref.dtype)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
@@ -440,7 +607,8 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
     dq, dk, dv [B, S, heads*D]."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    d, w, ncb, block_q, block_k = _cut(q, k, heads, hpb, block_q, block_k)
+    d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
+                                             block_q, block_k)
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
     interp = _use_interpret()
@@ -461,7 +629,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
         return pl.pallas_call(
             functools.partial(
                 _bwd_fused_kernel, sm_scale=sm_scale, causal=causal, d=d,
-                block_q=block_q, num_qb=num_qb),
+                block_q=block_q, num_qb=num_qb, band=band),
             grid=(b, ncb, num_qb),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
             out_specs=[q_spec, kv_spec, kv_spec],
@@ -613,10 +781,12 @@ def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _note_path(layout: str, hpb: int, d: int, seq: int) -> None:
+def _note_path(layout: str, hpb: int, d: int, seq: int, bands: int) -> None:
     PATH_COUNTS[layout] += 1
+    BAND_COUNTS[bands] += 1
     _record("rtpu.ops.flash.path", layout,
-            {"layout": layout, "heads_per_block": hpb, "hd": d, "S": seq})
+            {"layout": layout, "heads_per_block": hpb, "hd": d, "S": seq,
+             "bands": bands})
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -643,10 +813,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # Mosaic's minimum tile is (8, 128): sub-128 sequence blocks lower
         # to illegal or silently padded tiles on real TPU. Pads are the
         # caller's job; unpadded odd shapes go to the XLA reference.
-        _note_path("reference", 0, d, s)
+        _note_path("reference", 0, d, s, 0)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
     hpb = _heads_per_block(h, d)
-    _note_path("merged" if hpb else "relayout", hpb, d, s)
+    band = _band_height(s, causal, block_q, block_k)
+    _note_path("merged" if hpb else "relayout", hpb, d, s,
+               s // band if band else 1)
     merge = lambda x: x.reshape(x.shape[0], x.shape[1], h * d)  # noqa: E731
     out = _flash(merge(q), merge(k), merge(v), h, hpb, sm_scale, causal,
                  block_q, block_k)

@@ -77,19 +77,23 @@ def test_flash_attention_fwd_bwd_gpt2_small(one_chip, compiled_kernels):
     assert _kernel_names(text) == {"flash_fwd_single", "flash_bwd_fused"}
 
 
-def test_flash_attention_merged_layout_gpt2_medium(one_chip,
-                                                   compiled_kernels):
-    """The benchmark cell's attention (B=8, S=1024, 16 heads of 64), handed
-    over as models/gpt.py hands it: [B, S, H*hd] arrays reshaped to four
+@pytest.mark.parametrize("b,s,h,d", [(8, 1024, 16, 64), (2, 1024, 8, 128)],
+                         ids=["gpt2_medium", "hd128"])
+def test_flash_attention_merged_layout(one_chip, compiled_kernels,
+                                       b, s, h, d):
+    """The benchmark cell's attention (B=8, S=1024, 16 heads of 64), and a
+    head of 128 to a column block (B=2, S=1024, 8 heads), handed over as
+    models/gpt.py hands it: [B, S, H*hd] arrays reshaped to four
     dimensions and back. The kernels index the merged arrays, so the
-    compiled program holds the two single-block kernels and no array whose
+    compiled program holds the two single-block kernels, each working its
+    four causal row bands of 256 inside one program, and no array whose
     minor dimension is a head of 64 (it would pad to 128 lanes in HBM and
     be a copy of 16 MB for each of q, k, v, o, dO, dq, dk, dv)."""
     import re
 
-    from ray_tpu.ops.flash_attention import PATH_COUNTS, flash_attention
+    from ray_tpu.ops.flash_attention import (BAND_COUNTS, PATH_COUNTS,
+                                             flash_attention)
 
-    b, s, h, d = 8, 1024, 16, 64
     merged = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16,
                                   sharding=one_chip)
 
@@ -98,13 +102,14 @@ def test_flash_attention_merged_layout_gpt2_medium(one_chip,
         out = flash_attention(heads(q), heads(k), heads(v), causal=True)
         return out.reshape(b, s, h * d).astype(jnp.float32).sum()
 
-    before = PATH_COUNTS["merged"]
+    before = PATH_COUNTS["merged"], BAND_COUNTS[4]
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         merged, merged, merged).compile().as_text()
-    assert PATH_COUNTS["merged"] == before + 1
+    assert (PATH_COUNTS["merged"], BAND_COUNTS[4]) == (before[0] + 1,
+                                                       before[1] + 1)
     assert _kernel_names(text) == {"flash_fwd_single", "flash_bwd_fused"}
     assert not re.findall(r"\w+\[[\d,]*,64\]", text)
-    assert " transpose(" not in text
+    assert " transpose(" not in text and " copy(" not in text
 
 
 def _kernel_names(compiled_text: str) -> set:

@@ -153,20 +153,127 @@ class TestFlashAttentionLayouts:
         assert ("transpose" in names) == moved
 
     def test_path_is_a_flight_recorder_event(self):
+        from ray_tpu.ops.flash_attention import BAND_COUNTS
         from ray_tpu.perf.recorder import get_recorder
 
         rec = get_recorder()
         was, rec.enabled = rec.enabled, True
+        before = dict(BAND_COUNTS)
         try:
-            q, k, v = _qkv(jax.random.PRNGKey(5), s=128, h=2, d=64)
+            q, k, v = _qkv(jax.random.PRNGKey(5), s=256, h=2, d=64)
             flash_attention(q, k, v)
-            ev = [e for e in rec.snapshot()
-                  if e["kind"] == "rtpu.ops.flash.path"][-1]
+            flash_attention(q, k, v, causal=False)
+            flash_attention(q[:, :64], k[:, :64], v[:, :64])
+            evs = [e for e in rec.snapshot()
+                   if e["kind"] == "rtpu.ops.flash.path"][-3:]
         finally:
             rec.enabled = was
-        assert ev["label"] == "merged"
-        assert ev["data"] == {"layout": "merged", "heads_per_block": 2,
-                              "hd": 64, "S": 128}
+        assert evs[0]["label"] == "merged"
+        assert evs[0]["data"] == {"layout": "merged", "heads_per_block": 2,
+                                  "hd": 64, "S": 256, "bands": 2}
+        assert [e["data"]["bands"] for e in evs] == [2, 1, 0]
+        assert evs[2]["label"] == "reference"
+        # one count per traced call, keyed by its number of bands
+        assert {n: BAND_COUNTS[n] - before.get(n, 0)
+                for n in (0, 1, 2)} == {0: 1, 1: 1, 2: 1}
+
+
+def _band_counts():
+    from ray_tpu.ops.flash_attention import BAND_COUNTS
+
+    return dict(BAND_COUNTS)
+
+
+def _kernels_of(jp):
+    """The ``name`` of every pallas_call of a jaxpr, inner jaxprs too."""
+    for eqn in jp.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernels_of(sub)
+
+
+def _grads(attn, w, q, k, v):
+    return jax.grad(lambda q, k, v: (attn(q, k, v) * w).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+class TestFlashAttentionCausalBands:
+    """A causal call that one program holds whole computes row bands of a
+    quarter of the sequence, each on the columns it can see, and not the
+    masked half of the square; every other call runs the unbanded code.
+    Interpret mode, against the dense oracle."""
+
+    @pytest.mark.parametrize("s", [256, 512, 1024])
+    @pytest.mark.parametrize("h,d,layout", [
+        (4, 64, "merged"), (2, 128, "merged"), (2, 32, "relayout")])
+    def test_banded_matches_reference(self, h, d, layout, s):
+        q, k, v = _qkv(jax.random.PRNGKey(h * d + s), b=1, s=s, h=h, d=d)
+        w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+        paths, bands = _path_counts(), _band_counts()
+        out = flash_attention(q, k, v, causal=True)
+        g1 = _grads(flash_attention, w, q, k, v)
+        _took(paths, layout, calls=2)
+        want = s // max(128, s // 4)
+        after = _band_counts()
+        assert want > 1 and after[want] - bands.get(want, 0) == 2
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(mha_reference(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        for a, b in zip(g1, _grads(mha_reference, w, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("causal,block", [(False, 1024), (True, 512)])
+    def test_other_calls_are_not_banded(self, causal, block):
+        """Non-causal, and S=1024 streamed in blocks of 512 (whose kernels
+        skip masked blocks by predicate): one band, the whole block."""
+        q, k, v = _qkv(jax.random.PRNGKey(11), b=1, s=1024, h=2, d=64)
+        w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+        flash = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, causal=causal, block_q=block, block_k=block)
+        ref = lambda q, k, v: mha_reference(q, k, v, causal=causal)  # noqa: E731
+        bands = _band_counts()
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v)
+        after = _band_counts()
+        assert {n: after[n] - bands.get(n, 0) for n in after
+                if after[n] != bands.get(n, 0)} == {1: 1}
+        kernels = ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} if causal
+                   else {"flash_fwd_single", "flash_bwd_fused"})
+        assert set(_kernels_of(jaxpr.jaxpr)) == kernels
+        out = flash(q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        for a, b in zip(_grads(flash, w, q, k, v), _grads(ref, w, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("s", range(128, 2049, 128))
+    def test_band_geometry(self, s):
+        """Every (row, col <= row) lies in exactly one band's
+        [rows] x [0, end), and no band reaches a column past its own
+        diagonal tile; heights are whole 128-lane tiles that divide S."""
+        from ray_tpu.ops.flash_attention import _band_height, _row_bands
+
+        band = _band_height(s, True, 2048, 2048)
+        assert band % 128 == 0 and s % band == 0
+        assert band == 128 or band <= s // 4
+        bands = _row_bands(s, s, band)
+        owner = np.zeros(s, np.int64)
+        for r0, h, end in bands:
+            assert h == band and end == r0 + h   # its own diagonal tile
+            owner[r0:r0 + h] += 1
+        assert (owner == 1).all()                # rows partitioned
+        ends = np.repeat([end for _, _, end in bands], band)
+        rows = np.arange(s)
+        assert (ends > rows).all()               # every col <= row computed
+        assert (ends - rows <= band).all()       # and nothing past the tile
+        # what is not banded is one band, the whole block
+        assert _band_height(s, False, 2048, 2048) == 0
+        assert _band_height(s, True, s // 2 if s > 128 else 64, 2048) == 0
+        assert _row_bands(s, s, 0) == [(0, s, s)]
 
 
 class TestRingAttention:
