@@ -14,8 +14,10 @@ from .resnet import ResNet, ResNetConfig
 from .vit import ViT, ViTConfig
 from .mlp import MLP, MLPConfig
 from .moe import MoE, MoEConfig
+from .deepseek_v3 import DeepseekV3, DeepseekV3Config
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
     "ViT", "ViTConfig", "MLP", "MLPConfig", "MoE", "MoEConfig",
+    "DeepseekV3", "DeepseekV3Config",
 ]

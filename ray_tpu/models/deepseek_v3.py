@@ -1,0 +1,284 @@
+"""DeepSeek-V3 shaped decoder (``model_type: deepseek_v3``), training path:
+multi-head latent attention without a query bottleneck (``q_lora_rank``
+null), ``first_k_dense`` leading layers with a dense gated MLP and then
+layers of routed + shared experts, of which this chip may hold a share.
+
+Per layer, x̂ = RMSNorm(x):
+
+* attention: per head ``[q_nope | q_rope] = x̂ W_q``; ``[c | k_pe] =
+  x̂ W_kva``; ``[k_nope | v] = RMSNorm(c) W_kvb``; RoPE (interleaved pairs)
+  on every head's ``q_rope`` and on the ONE ``k_pe`` all heads share;
+  ``score_h = (q_nope_h·k_nope_h + q_rope_h·k_pe) / sqrt(dn + dr)``, causal
+  softmax, ``o_h = P v_h``; out ``concat_h(o_h) W_o``. The attention runs
+  in the latent flash kernels (``ops/flash_attention.py``), which take the
+  two parts of the score apart, so the projections are kept as separate
+  matrices (``w_q_nope``, ``w_q_rope``, ``w_kv_a``, ``w_k_rope``, ``w_k_b``,
+  ``w_v_b``): the published matrices with their columns sorted by kind.
+* expert layers: ``ops.expert_layer.held_expert_layer`` (sigmoid scores,
+  top k of score + selection bias, weights normalised over the chosen k
+  and scaled, shared experts added; dropless; ``experts_held`` of
+  ``n_routed_experts`` from ``expert_offset``).
+
+``vocab_size`` is the vocabulary this chip holds: embedding, head, logits
+and loss are over it (a sliced vocabulary is a smaller vocabulary).
+
+The stack: the dense layers one by one, then the expert layers through
+one scanned runner; every layer is rematerialised, saving what
+``_REMAT_SAVE`` names.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..ops import (apply_rope, cross_entropy_loss, flash_attention, rmsnorm,
+                   rope_cache)
+from ..ops.expert_layer import held_expert_layer
+
+
+# What a rematerialised layer keeps for its backward, by
+# ``checkpoint_name``: the latent flash kernels' output and row statistics
+# (so the backward never re-runs the forward kernel) and q as the kernels
+# read it. Everything else is computed again, k and v included: at 16 384
+# tokens a step and five layers, keeping them too is 88 MB more than a v5e
+# holds beside 576 M parameters' adamw state (PERF.md, PR 33).
+_REMAT_SAVE = ("flash_out", "flash_lse", "attn_q")
+
+
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
+
+
+@dataclass(frozen=True)
+class DeepseekV3Config:
+    vocab_size: int = 128256          # the ids held here
+    n_layer: int = 48
+    first_k_dense: int = 1
+    d_model: int = 2048
+    n_head: int = 32
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    d_ff: int = 6144                  # the dense layers' gated MLP
+    d_expert: int = 768               # one routed expert's gated MLP
+    n_routed_experts: int = 128       # the router's width
+    experts_held: int = 128           # experts on this chip ...
+    expert_offset: int = 0            # ... from this one
+    n_shared_experts: int = 2
+    top_k: int = 6
+    routed_scaling_factor: float = 2.448
+    max_seq: int = 32768
+    rope_base: float = 1000000.0
+    rms_eps: float = 1e-6
+    init_std: float = 0.02            # residual projections: / sqrt(2 L)
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    @property
+    def padded_vocab(self) -> int:
+        return _round_up(self.vocab_size, 128)
+
+    @staticmethod
+    def tiny(**kw) -> "DeepseekV3Config":
+        base = dict(vocab_size=512, n_layer=3, d_model=64, n_head=2,
+                    kv_lora_rank=32, d_ff=128, d_expert=32,
+                    n_routed_experts=8, experts_held=8, n_shared_experts=2,
+                    top_k=3, max_seq=128)
+        base.update(kw)
+        return DeepseekV3Config(**base)
+
+    @staticmethod
+    def kanana2_30b_a3b(**kw) -> "DeepseekV3Config":
+        """kakaocorp/kanana-2-30b-a3b-instruct-2601 ``config.json``."""
+        return DeepseekV3Config(**kw)
+
+
+class DeepseekV3:
+    """init / loss pytree model in the house style (gpt.py, llama.py).
+    Parameters are one flat dict: ``wte``, ``lm_head``, ``out_norm``,
+    ``dense.<name>`` stacked over the leading dense layers and
+    ``moe.<name>`` stacked over the expert layers."""
+
+    def __init__(self, config: DeepseekV3Config):
+        self.config = config
+
+    # -- parameters --------------------------------------------------------
+
+    def _shapes(self) -> Dict[str, Tuple[Tuple[int, ...], Optional[float]]]:
+        """name -> (shape, std of its normal init; None: ones, 0: zeros)."""
+        c = self.config
+        d, h, r = c.d_model, c.n_head, c.kv_lora_rank
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        std, res = c.init_std, c.init_std / math.sqrt(2 * c.n_layer)
+        attn = {
+            "attn_norm": ((d,), None),
+            "w_q_nope": ((d, h * dn), std), "w_q_rope": ((d, h * dr), std),
+            "w_kv_a": ((d, r), std), "w_k_rope": ((d, dr), std),
+            "kv_norm": ((r,), None),
+            "w_k_b": ((r, h * dn), std), "w_v_b": ((r, h * dv), std),
+            "w_o": ((h * dv, d), res), "mlp_norm": ((d,), None),
+        }
+        fs = c.n_shared_experts * c.d_expert
+        g = c.experts_held
+        kinds = {
+            "dense": (c.first_k_dense, dict(attn, **{
+                "w_gate": ((d, c.d_ff), std), "w_up": ((d, c.d_ff), std),
+                "w_down": ((c.d_ff, d), res)})),
+            "moe": (c.n_layer - c.first_k_dense, dict(attn, **{
+                "w_router": ((d, c.n_routed_experts), std),
+                "router_bias": ((c.n_routed_experts,), 0.0),
+                "s_gate": ((d, fs), std), "s_up": ((d, fs), std),
+                "s_down": ((fs, d), res),
+                "e_gate": ((g, d, c.d_expert), std),
+                "e_up": ((g, d, c.d_expert), std),
+                "e_down": ((g, c.d_expert, d), res)})),
+        }
+        out = {"wte": ((c.padded_vocab, d), std),
+               "lm_head": ((c.padded_vocab, d), std),
+               "out_norm": ((d,), None)}
+        for kind, (layers, shapes) in kinds.items():
+            for name, (shape, s) in shapes.items():
+                out[f"{kind}.{name}"] = ((layers,) + shape, s)
+        return out
+
+    def init(self, rng: jax.Array) -> Dict[str, jax.Array]:
+        pd = self.config.param_dtype
+        shapes = self._shapes()
+        keys = jax.random.split(rng, len(shapes))
+        return {n: (jnp.ones(shape, pd) if std is None else
+                    jax.random.normal(k, shape, pd) * std)
+                for k, (n, (shape, std)) in zip(keys, shapes.items())}
+
+    def param_shardings(self, mesh, rules=None):
+        """Replicated but for the vocabulary's rows: this model is one
+        chip's share of an expert-parallel job (the experts it holds are
+        its own), so no axis of the mesh cuts a layer."""
+        from jax.sharding import NamedSharding
+
+        from ..parallel.mesh import AxisRules
+
+        rules = rules or AxisRules()
+        return {n: NamedSharding(mesh, rules.mesh_axes(
+            ("vocab", "embed") if n in ("wte", "lm_head")
+            else (None,) * len(shape)))
+            for n, (shape, _) in self._shapes().items()}
+
+    def num_params(self) -> int:
+        return sum(math.prod(shape) for shape, _ in self._shapes().values())
+
+    # -- layers ------------------------------------------------------------
+
+    def _attention(self, x, lp, cos, sin):
+        c = self.config
+        b, s, _ = x.shape
+        h, dt = c.n_head, c.dtype
+        with jax.named_scope("attn"):
+            xn = rmsnorm(x, lp["attn_norm"], c.rms_eps)
+            latent = rmsnorm(xn @ lp["w_kv_a"].astype(dt), lp["kv_norm"],
+                             c.rms_eps)
+            heads = lambda t: t.reshape(b, s, h, -1)  # noqa: E731
+            q_nope = xn @ lp["w_q_nope"].astype(dt)
+            q_rope = _rope_interleaved(heads(xn @ lp["w_q_rope"].astype(dt)),
+                                       cos, sin).reshape(b, s, -1)
+            k_rope = _rope_interleaved(
+                (xn @ lp["w_k_rope"].astype(dt))[:, :, None, :], cos,
+                sin)[:, :, 0, :]
+            k_nope = latent @ lp["w_k_b"].astype(dt)
+            v = latent @ lp["w_v_b"].astype(dt)
+            # named in the merged [B, S, H*d] form the kernels read (a
+            # 64-wide minor dimension would be kept padded to 128 lanes)
+            q_nope, q_rope = (checkpoint_name(t, "attn_q")
+                              for t in (q_nope, q_rope))
+            o = flash_attention(heads(q_nope), heads(k_nope), heads(v),
+                                causal=True, q_rope=heads(q_rope),
+                                k_rope=k_rope)
+            return x + o.reshape(b, s, -1) @ lp["w_o"].astype(dt)
+
+    def _dense_block(self, x, lp, cos, sin):
+        c = self.config
+        x = self._attention(x, lp, cos, sin)
+        with jax.named_scope("mlp"):
+            xn = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
+            hid = jax.nn.silu(xn @ lp["w_gate"].astype(c.dtype)) \
+                * (xn @ lp["w_up"].astype(c.dtype))
+            return x + hid @ lp["w_down"].astype(c.dtype)
+
+    def _moe_block(self, x, lp, cos, sin):
+        """-> (the layer's output, the rows its held experts worked)."""
+        c = self.config
+        b, s, d = x.shape
+        x = self._attention(x, lp, cos, sin)
+        with jax.named_scope("router"):    # the norm goes with the router
+            xn = rmsnorm(x, lp["mlp_norm"], c.rms_eps).reshape(b * s, d)
+        y, rows = held_expert_layer(
+            xn, lp, experts_held=c.experts_held,
+            expert_offset=c.expert_offset, top_k=c.top_k,
+            routed_scale=c.routed_scaling_factor)
+        return x + y.reshape(b, s, d), rows
+
+    def _run_layers(self, x, params, cos, sin):
+        """The one place the stack is walked: the dense layers, then the
+        expert layers through one scanned body, each rematerialised. ->
+        (x, held rows of each expert layer)."""
+        c = self.config
+        policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_SAVE)
+        group = lambda kind: {n.split(".", 1)[1]: v  # noqa: E731
+                              for n, v in params.items()
+                              if n.startswith(kind + ".")}
+        dense = jax.checkpoint(
+            lambda h, lp: self._dense_block(h, lp, cos, sin), policy=policy)
+        for i in range(c.first_k_dense):
+            x = dense(x, {n: v[i] for n, v in group("dense").items()})
+
+        return jax.lax.scan(
+            jax.checkpoint(lambda h, lp: self._moe_block(h, lp, cos, sin),
+                           policy=policy), x, group("moe"))
+
+    def _backbone(self, params, tokens):
+        c = self.config
+        with jax.named_scope("embed"):
+            x = params["wte"].astype(c.dtype)[tokens]
+        cos, sin = rope_cache(tokens.shape[1], c.qk_rope_head_dim,
+                              c.rope_base)
+        x, rows = self._run_layers(x, params, cos, sin)
+        with jax.named_scope("lm_head"):     # the final norm goes with it
+            return rmsnorm(x, params["out_norm"], c.rms_eps), rows
+
+    def apply(self, params: Dict[str, jax.Array],
+              tokens: jax.Array) -> jax.Array:
+        """tokens [B, S] -> logits [B, S, padded_vocab] f32."""
+        x, _ = self._backbone(params, tokens)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bsd,vd->bsv", x,
+                              params["lm_head"].astype(self.config.dtype),
+                              preferred_element_type=jnp.float32)
+
+    def loss(self, params: Dict[str, jax.Array], tokens: jax.Array,
+             targets: jax.Array) -> jax.Array:
+        """The bare next-token loss over the vocabulary held here."""
+        logits = self.apply(params, tokens)
+        with jax.named_scope("loss"):
+            return cross_entropy_loss(logits, targets)
+
+    def routing_stats(self, params: Dict[str, jax.Array],
+                      tokens: jax.Array) -> jax.Array:
+        """(token, choice) pairs that name a held expert, one count an
+        expert layer [n_layer - first_k_dense]: the rows its grouped
+        product works. Jit it; it is no part of a train step."""
+        return self._backbone(params, tokens)[1]
+
+
+def _rope_interleaved(x, cos, sin):
+    """RoPE on x [B, S, H, D] whose rotated pairs are NEIGHBOURS
+    (x0, x1), (x2, x3), ... (``rope_interleave``): the pairs are first
+    sorted into halves, as the published code does, then rotated in the
+    half-split form. q and k get the same order, so the score is that of
+    the pairwise rotation."""
+    b, s, h, d = x.shape
+    halves = x.reshape(b, s, h, d // 2, 2).swapaxes(-1, -2).reshape(b, s, h, d)
+    return apply_rope(halves, cos, sin)

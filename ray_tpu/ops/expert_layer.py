@@ -1,0 +1,385 @@
+"""A dropless mixture-of-experts layer for ONE chip's share of the experts,
+and the grouped matrix product (Pallas) it rests on.
+
+``held_expert_layer`` is told which experts live here (``experts_held``,
+``expert_offset``), scores every token against ALL experts, takes the top
+k of all of them, normalises over the k chosen whether held or not, and
+returns the shared experts' output plus the part of the routed sum that
+the held experts give. What the absent experts would add is left out (it
+is another chip's to compute; nothing here stands in for them or for
+their exchange). With every expert held it is the whole layer.
+
+No token is dropped whatever the routing, and no shape or trip count
+depends on it: the (token, choice) pairs that name a held expert are
+sorted by expert into a row buffer of static size that covers the worst
+case (every token choosing held experts only), each expert's rows
+starting on a tile of ``ROW_TILE`` rows, so that a tile belongs to one
+expert. The grouped product walks the buffer's tiles with the tile's
+expert as a prefetched scalar; tiles past the last used one are skipped
+by predicate and fetch nothing, so the time follows the rows that are
+there while the grid does not. Rows move by gathers in both directions
+(forward and backward each know token -> row and row -> token), never by
+a scatter; they are made for the whole buffer, so that only the grouped
+product's time follows the routing.
+
+Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
+``router`` (scores, top-k, the sort, the gather into the buffer and the
+weighted sum back), ``experts`` (the grouped products), ``shared_expert``.
+"""
+from __future__ import annotations
+
+import collections
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from ..perf.recorder import record as _record
+
+# Names of the two Pallas calls as a device trace shows them; part of the
+# measurement (tests/test_tracing_names.py).
+KERNEL_NAMES = {
+    "rows": "grouped_matmul",        # y[tile] = x[tile] @ w[expert(tile)]
+    "weights": "grouped_matmul_dw",  # dw[e] = sum over e's tiles x^T dy
+}
+
+# Rows of a tile. An expert's rows are padded to whole tiles (at least
+# one), so a tile multiplies one expert's weights: up to ROW_TILE - 1
+# rows of zeros an expert are the price.
+ROW_TILE = 256
+
+# Traced calls of the layer by (experts held, experts in all).
+LAYER_COUNTS: collections.Counter = collections.Counter()
+
+_VMEM_BYTES = 64 * 1024 * 1024   # the dw kernel keeps a [K, N] f32 tile
+
+
+def _use_interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def buffer_rows(tokens: int, top_k: int, experts_held: int,
+                tile: int = ROW_TILE) -> int:
+    """Rows of the static buffer: every token choosing held experts only,
+    each expert's last tile ragged, and one tile for an expert with no
+    row."""
+    worst = tokens * min(top_k, experts_held)
+    return (-(-worst // tile) + experts_held) * tile
+
+
+# ---------------------------------------------------------------------------
+# the grouped product
+# ---------------------------------------------------------------------------
+
+
+def _rows_kernel(tile_expert, n_used, x_ref, w_ref, o_ref, *, transposed):
+    @pl.when(pl.program_id(0) < n_used[0])
+    def _():
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[...],
+            (((1,), (1,) if transposed else (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _weights_kernel(tile_expert, n_used, x_ref, dy_ref, dw_ref, acc, *,
+                    n_tiles):
+    i = pl.program_id(0)
+    used = i < n_used[0]
+    here = tile_expert[i]
+
+    @pl.when(used & ((i == 0) | (tile_expert[jnp.maximum(i - 1, 0)] != here)))
+    def _first_of_expert():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(used)
+    def _():
+        acc[...] += jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(used & ((i == n_used[0] - 1)
+                     | (tile_expert[jnp.minimum(i + 1, n_tiles - 1)] != here)))
+    def _last_of_expert():
+        dw_ref[...] = acc[...].astype(dw_ref.dtype)
+
+
+def _last_used(i, n_used):
+    """Tile i, or the last used tile past it: a skipped step names the
+    block that is already there, so nothing is fetched or written."""
+    return jnp.minimum(i, n_used[0] - 1)
+
+
+def _rows_call(x, w, tile_expert, n_used, transposed, tile):
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    n = w.shape[1] if transposed else w.shape[2]
+    n_tiles = rows // tile
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tile, k),
+                             lambda i, te, nu: (_last_used(i, nu), 0)),
+                pl.BlockSpec((None,) + w.shape[1:],
+                             lambda i, te, nu: (te[_last_used(i, nu)], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tile, n), lambda i, te, nu: (_last_used(i, nu), 0))),
+        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name=KERNEL_NAMES["rows"],
+        interpret=_use_interpret(),
+    )(tile_expert, n_used, x, w)
+
+
+def _weights_call(x, dy, tile_expert, n_used, experts, dtype, tile):
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    n = dy.shape[1]
+    n_tiles = rows // tile
+    return pl.pallas_call(
+        functools.partial(_weights_kernel, n_tiles=n_tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tile, k),
+                             lambda i, te, nu: (_last_used(i, nu), 0)),
+                pl.BlockSpec((tile, n),
+                             lambda i, te, nu: (_last_used(i, nu), 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, k, n),
+                lambda i, te, nu: (te[_last_used(i, nu)], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((k, n), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((experts, k, n), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BYTES),
+        name=KERNEL_NAMES["weights"],
+        interpret=_use_interpret(),
+    )(tile_expert, n_used, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, w, tile_expert, n_used, tile=ROW_TILE):
+    """x [rows, K] @ w[expert of the row's tile] [K, N] -> [rows, N].
+
+    ``tile_expert`` [rows // tile] int32 names each tile's expert,
+    ``n_used`` [1] int32 how many leading tiles hold rows; every expert
+    owns at least one used tile. Rows of tiles past ``n_used`` are not
+    written (whatever the buffer held stays there): the caller masks what
+    it reads from them."""
+    return _rows_call(x, w, tile_expert, n_used, False, tile)
+
+
+def _grouped_fwd(x, w, tile_expert, n_used, tile):
+    return (_rows_call(x, w, tile_expert, n_used, False, tile),
+            (x, w, tile_expert, n_used))
+
+
+def _grouped_bwd(tile, res, dy):
+    x, w, tile_expert, n_used = res
+    dx = _rows_call(dy, w, tile_expert, n_used, True, tile)
+    dw = _weights_call(x, dy, tile_expert, n_used, w.shape[0], w.dtype, tile)
+    return dx, dw, None, None
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ---------------------------------------------------------------------------
+# rows in and out of the buffer: gathers both ways
+# ---------------------------------------------------------------------------
+# ``at`` (``sort_rows``) knows pair -> row and row -> pair (a pair is
+# token * k + choice), so tokens go to rows and rows come back to tokens
+# by gathers, and each is the other's transpose: no scatter in either
+# direction, and nothing but copies of bf16 rows (the routing weights are
+# applied to the rows inside the buffer, ``held_expert_layer``).
+
+
+def _rows_of(values, at, pairs_each: int):
+    """values [n, *width], each standing for ``pairs_each`` pairs in a
+    row (a token's k choices, or one pair) -> [rows, *width]: each row
+    its pair's value, zeros where the row is padding. Every row of the
+    buffer is made, used or not: made only as far as the used tiles
+    reach (a sixteenth of the buffer at a time, the rest skipped by
+    predicate) the step was 2.9 % shorter, and its length followed the
+    routing: six seeds then spread by 0.5 % where they spread by 0.15 %
+    so (PERF.md, PR 33)."""
+    pairs = values.shape[0] * pairs_each
+    pair = at["row_pair"]
+    filled = (pair < pairs).reshape((-1,) + (1,) * (values.ndim - 1))
+    return jnp.where(filled,
+                     values[jnp.minimum(pair, pairs - 1) // pairs_each], 0)
+
+
+def _tokens_to_rows_impl(x, at):
+    return _rows_of(x, at, at["pair_row"].shape[1])
+
+
+def _rows_to_tokens_impl(y, at):
+    picked = jnp.where(at["pair_held"][..., None], y[at["pair_row"]],
+                       jnp.zeros((), y.dtype))
+    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(y.dtype)
+
+
+@jax.custom_vjp
+def tokens_to_rows(x, at):
+    """x [T, D] -> the buffer [rows, D]: each row its pair's token, zeros
+    where the row is padding."""
+    return _tokens_to_rows_impl(x, at)
+
+
+@jax.custom_vjp
+def rows_to_tokens(y, at):
+    """The buffer y [rows, D] -> [T, D]: each token the sum (in f32) of
+    the rows of its held pairs."""
+    return _rows_to_tokens_impl(y, at)
+
+
+tokens_to_rows.defvjp(
+    lambda x, at: (_tokens_to_rows_impl(x, at), at),
+    lambda at, g: (_rows_to_tokens_impl(g, at), None))
+rows_to_tokens.defvjp(
+    lambda y, at: (_rows_to_tokens_impl(y, at), at),
+    lambda at, g: (_tokens_to_rows_impl(g, at), None))
+
+
+@jax.custom_vjp
+def pairs_to_rows(w, at):
+    """A value a pair, w [T, k] -> a value a row [rows] (0 for padding)."""
+    return _rows_of(w.reshape(-1), at, 1)
+
+
+pairs_to_rows.defvjp(
+    lambda w, at: (_rows_of(w.reshape(-1), at, 1), at),
+    lambda at, g: (jnp.where(at["pair_held"], g[at["pair_row"]], 0.0), None))
+
+
+# ---------------------------------------------------------------------------
+# the layer
+# ---------------------------------------------------------------------------
+
+
+def route(x, w_router, bias, *, top_k: int, routed_scale: float):
+    """DeepSeek-V3's router without group limiting (``n_group`` 1):
+    s = sigmoid(x W) in f32; the k experts are the top k of s + bias (the
+    selection bias is a buffer: no gradient reaches it); their weights
+    are s over the chosen k, summing to 1, times ``routed_scale``.
+    x [T, D] -> (weights [T, k] f32, experts [T, k] int32)."""
+    scores = jax.nn.sigmoid(jnp.dot(x, w_router.astype(x.dtype),
+                                    preferred_element_type=jnp.float32))
+    _, chosen = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    # the chosen scores by comparison, not by index: the gradient of a
+    # gather is a scatter into [T, E]
+    s = jnp.sum(jnp.where(
+        chosen[:, :, None] == jnp.arange(scores.shape[1])[None, None, :],
+        scores[:, None, :], 0.0), axis=-1)
+    return s / jnp.sum(s, axis=1, keepdims=True) * routed_scale, chosen
+
+
+def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
+              tile: int = ROW_TILE):
+    """Where each (token, choice) pair lies in the row buffer. chosen
+    [T, k] int32 over all experts -> dict of
+
+    ``pair_held`` [T, k] bool: the pair's expert lives here;
+    ``pair_row`` [T, k] int32: its row (0 where not held);
+    ``row_pair`` [rows] int32: the pair of each row, T * k for padding;
+    ``tile_expert`` [rows // tile], ``n_used`` [1]: ``grouped_matmul``'s;
+    ``held_rows``: pairs held, a scalar (the counter of the layer)."""
+    t, k = chosen.shape
+    pairs = t * k
+    n_tiles = rows // tile
+    local = chosen - expert_offset
+    held = (local >= 0) & (local < experts_held)
+    key = jnp.where(held, local, experts_held).reshape(pairs)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)   # pairs by expert
+    counts = jnp.sum(key[:, None] == jnp.arange(experts_held)[None, :],
+                     axis=0, dtype=jnp.int32)                 # [held]
+    first_pair = jnp.cumsum(counts) - counts     # in the sorted order
+    tiles = jnp.maximum(-(-counts // tile), 1)
+    last_tile = jnp.cumsum(tiles)
+    first_row = (last_tile - tiles) * tile
+
+    def of_expert(table, e):
+        """table[e] for e [n] over the few held experts, by comparison:
+        a gather of one scalar an index is the slow way on this chip."""
+        return jnp.sum(jnp.where(
+            e[:, None] == jnp.arange(experts_held)[None, :], table[None, :],
+            0), axis=1)
+
+    # pair -> row: its rank among its expert's pairs, from the sort
+    rank = jnp.argsort(order).astype(jnp.int32) - of_expert(first_pair, key)
+    pair_row = jnp.where(held.reshape(pairs),
+                         of_expert(first_row, key) + rank, 0).reshape(t, k)
+    # row -> pair: the row's tile names its expert
+    n_used = last_tile[-1:]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(last_tile, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right"), experts_held - 1).astype(jnp.int32)
+
+    e = jnp.repeat(tile_expert, tile)
+    r = jnp.arange(rows, dtype=jnp.int32) - of_expert(first_row, e)
+    row_pair = jnp.where(
+        r < of_expert(counts, e),
+        order[jnp.clip(of_expert(first_pair, e) + r, 0, pairs - 1)], pairs)
+    return {"pair_held": held, "pair_row": pair_row, "row_pair": row_pair,
+            "tile_expert": tile_expert, "n_used": n_used,
+            "held_rows": jnp.sum(counts)}
+
+
+def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
+    """The gated SiLU MLP; ``row_weight`` [rows] (f32) scales a row's
+    hidden activation, i.e. its output."""
+    h = jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up)
+    if row_weight is not None:
+        h = (h.astype(jnp.float32) * row_weight[:, None]).astype(h.dtype)
+    return matmul(h, w_down)
+
+
+def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
+                      top_k: int, routed_scale: float, tile: int = ROW_TILE):
+    """x [T, D] (normalised) -> (shared(x) + the held experts' part of
+    sum_e w_e expert_e(x), [T, D] in x's dtype; the (token, choice) pairs
+    that named a held expert, i.e. the rows the grouped product worked).
+
+    ``p``: ``w_router`` [D, E] over ALL E experts and ``router_bias`` [E];
+    ``e_gate``, ``e_up`` [held, D, F], ``e_down`` [held, F, D] of the
+    experts ``expert_offset`` .. ``expert_offset + experts_held``;
+    ``s_gate``, ``s_up`` [D, Fs], ``s_down`` [Fs, D] of the shared experts
+    (side by side, one gated MLP)."""
+    t, d = x.shape
+    dt = x.dtype
+    n_experts = p["w_router"].shape[1]
+    rows = buffer_rows(t, top_k, experts_held, tile)
+    LAYER_COUNTS[(experts_held, n_experts)] += 1
+    _record("rtpu.ops.expert_layer", "held",
+            {"experts_held": experts_held, "of": n_experts, "top_k": top_k,
+             "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
+             "row_tile": tile})
+    with jax.named_scope("shared_expert"):
+        shared = _gated(x, p["s_gate"].astype(dt), p["s_up"].astype(dt),
+                        p["s_down"].astype(dt), jnp.dot)
+    with jax.named_scope("router"):
+        weights, chosen = route(x, p["w_router"], p["router_bias"],
+                                top_k=top_k, routed_scale=routed_scale)
+        at = sort_rows(chosen, experts_held, expert_offset, rows, tile)
+        held_rows = at.pop("held_rows")
+        buf = tokens_to_rows(x, at)
+        row_weight = pairs_to_rows(weights, at)
+    with jax.named_scope("experts"):
+        y = _gated(buf, p["e_gate"].astype(dt), p["e_up"].astype(dt),
+                   p["e_down"].astype(dt),
+                   lambda a, w: grouped_matmul(a, w, at["tile_expert"],
+                                               at["n_used"], tile),
+                   row_weight)
+    with jax.named_scope("router"):
+        return shared + rows_to_tokens(y, at), held_rows

@@ -3,19 +3,35 @@
 Tiled online-softmax attention. The kernels work on MERGED arrays
 [B, S, H*D], the layout the model's projections produce and consume: the
 grid runs over (batch, column block, sequence blocks) and each program
-gets [block, W] tiles of q, k, v (o, dO) by index map, W lanes wide. Where
-the heads allow it (``_heads_per_block``: D of 64 or 128, H*D a multiple
-of 128) W is 128 and a column block holds 128 // D whole heads, so every
-load and store is a dense 128-lane tile and nothing is transposed outside
-the kernels. Inside a program the heads of a block are worked one after
-the other on full-width tiles whose other lanes are zeroed
-(``_head_lanes``): a product with an exact zero adds an exact zero in the
-float32 accumulator, so each head's numbers are those of a kernel that
-saw its D lanes alone. Every other head size is first transposed to
-[B*H, S, D] (``_to_bhsd``) and runs the same kernels as B*H batches of
-one head, W = D: a 64-wide minor dimension would pad to 128 lanes in HBM,
-a 32-wide one to four times its size, which is why the merged form is
-what crosses the custom-VJP boundary either way.
+gets [block, W] tiles of q, k, v (o, dO) by index map, W lanes wide.
+
+The routes, chosen by what a call shows (``PATH_COUNTS``, the event
+``rtpu.ops.flash.path``; no argument or configuration selects one):
+
+* ``merged``: one head size D for q, k and v, D of 64 or 128 and H*D a
+  multiple of 128 (``_heads_per_block``). W is 128 and a column block
+  holds 128 // D whole heads, so every load and store is a dense 128-lane
+  tile and nothing is transposed outside the kernels. Inside a program
+  the heads of a block are worked one after the other on full-width tiles
+  whose other lanes are zeroed (``_head_lanes``): a product with an exact
+  zero adds an exact zero in the float32 accumulator, so each head's
+  numbers are those of a kernel that saw its D lanes alone.
+* ``relayout``: every other single head size (32, 96, 192, 256, ..., or a
+  merged width that is no multiple of 128) is first transposed to
+  [B*H, S, D] (``_to_bhsd``) and runs the same kernels as B*H batches of
+  one head, W = D: a 64-wide minor dimension would pad to 128 lanes in
+  HBM, a 32-wide one to four times its size, which is why the merged form
+  is what crosses the custom-VJP boundary either way.
+* ``latent``: a call that brings ``q_rope`` [B, S, H, dr] and ONE
+  ``k_rope`` [B, S, dr] for all heads: the score is
+  (q·k + q_rope·k_rope) * scale and v may have another head size than q
+  and k (multi-head latent attention: 128 + 64 against 128). dr of 64 or
+  128, the q/k and v head sizes multiples of 128 (``_latent_ok``): three
+  kernels of their own (``LATENT_KERNEL_NAMES``), 128 // dr heads to a
+  program, the shared key never copied to the heads; see the section
+  "latent attention" below.
+* ``reference`` / ``latent_reference``: S no multiple of 128, or latent
+  head sizes that do not tile the lanes: ``mha_reference``, no kernel.
 
 The grid streams Q and K/V blocks so nothing larger than a block is
 VMEM-resident. bf16 inputs feed the MXU directly
@@ -74,6 +90,14 @@ KERNEL_NAMES = {
     "bwd_fused": "flash_bwd_fused",     # dq, dk, dv in one pass
     "bwd_dq": "flash_bwd_dq",           # dq, one pass over K blocks
     "bwd_dkv": "flash_bwd_dkv",         # dk and dv, one pass over Q blocks
+}
+
+# The three kernels of a call whose score has a second part against one
+# shared key (latent attention), at any S; pinned in the same test.
+LATENT_KERNEL_NAMES = {
+    "fwd": "flash_latent_fwd",
+    "bwd_dq": "flash_latent_bwd_dq",        # dq_nope, dq_rope
+    "bwd_dkv": "flash_latent_bwd_dkv",      # dk_nope, dv, d(shared key)
 }
 
 # Traced calls of flash_attention by the layout each took: "merged" (the
@@ -781,24 +805,451 @@ def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _note_path(layout: str, hpb: int, d: int, seq: int, bands: int) -> None:
+# ---------------------------------------------------------------------------
+# latent attention: a score in two parts, the second against ONE shared key
+# ---------------------------------------------------------------------------
+# score_h = (q_nope_h . k_nope_h + q_rope_h . k_rope) * scale, o_h = P v_h
+# (DeepSeek-V2's multi-head latent attention after the up-projection): the
+# q/k head is dn + dr wide, the value dv, and k_rope [B, S, dr] is one
+# vector a position that every head shares. Everything stays on 128-lane
+# tiles and the shared key is never broadcast to the heads: a program
+# holds the 128 // dr heads whose rope parts fill one 128-lane block of
+# q_rope [B, S, H*dr]; their nope and value parts are whole 128-lane
+# tiles of [B, S, H*dn] and [B, S, H*dv] (dn, dv multiples of 128), cut
+# by static slices; the rope part of head j is the q_rope tile with the
+# other heads' lanes zeroed (``_head_lanes``) against k_rope repeated to
+# 128 lanes (``kr``), so that the zeroed lanes add exact zeros. The
+# gradient of ``kr`` comes out of the dk/dv kernel summed over every head
+# of the batch row: its grid runs the head blocks INSIDE a key block and
+# carries one [block_k, 128] accumulator across them. One set of three
+# kernels serves every S (a single block is a grid of one step); causal
+# blocks above the diagonal are skipped by predicate and fetch nothing
+# (their index maps point at the block before), and only blocks the
+# diagonal crosses are masked.
+
+
+def _latent_cut(qn, qr, v, heads, block_q, block_k):
+    """-> (dn, dr, dv, heads to a program, programs a batch row, block_q,
+    block_k) of a latent call on merged arrays."""
+    dn, dr, dv = (x.shape[-1] // heads for x in (qn, qr, v))
+    hpb = _LANES // dr
+    return (dn, dr, dv, hpb, heads // hpb, _fit_block(block_q, qn.shape[1]),
+            _fit_block(block_k, qn.shape[1]))
+
+
+def _latent_ok(heads: int, dn: int, dr: int, dv: int) -> bool:
+    """Whether the latent kernels can cut these heads into 128-lane
+    tiles."""
+    return (dr in (64, _LANES) and dn % _LANES == 0 and dv % _LANES == 0
+            and heads % (_LANES // dr) == 0)
+
+
+def _lanes(x, j: int, d: int):
+    """Head j's d lanes of a tile of whole 128-lane head tiles."""
+    return x[:, j * d:(j + 1) * d]
+
+
+def _causal_steps(causal, qi, kb, block_q, block_k, body):
+    """Run ``body(masked)`` for the (q block, k block) pair: not at all
+    where the causal mask hides the whole block, unmasked where every
+    column is visible to every row."""
+    if not causal:
+        body(False)
+        return
+    first_row, last_row = qi * block_q, qi * block_q + block_q - 1
+    first_col, last_col = kb * block_k, kb * block_k + block_k - 1
+    pl.when(first_row >= last_col)(lambda: body(False))
+    pl.when((last_row >= first_col) & (first_row < last_col))(
+        lambda: body(True))
+
+
+def _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked, row0, col0):
+    """Head j's [block_q, block_k] scores in f32: both parts on the MXU,
+    q scaled on its (block_q, d) tiles."""
+    scale = jnp.asarray(sm_scale, qn.dtype)
+    s = _dot(_lanes(qn, j, dn) * scale, _lanes(kn, j, dn), _ABT) \
+        + _dot(_head_lanes(qr, j, dr) * scale, kr, _ABT)
+    if masked:
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(rows >= cols, s, _NEG_INF)
+    return s
+
+
+def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                       m_scr, l_scr, acc_scr, *, sm_scale, causal, dn, dr,
+                       dv, block_q, block_k, num_kb):
+    """Grid (B, head blocks, q blocks, k blocks), K innermost: online
+    softmax with one (m, l, acc) a head of the block."""
+    qi, kb = pl.program_id(2), pl.program_id(3)
+    heads = lse_ref.shape[0]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def compute(masked):
+        qn, qr, kn, kr, v = (qn_ref[...], qr_ref[...], kn_ref[...],
+                             kr_ref[...], v_ref[...])
+        for j in range(heads):
+            s = _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked,
+                               qi * block_q, kb * block_k)
+            m_prev = m_scr[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[j] = acc_scr[j] * alpha + _dot(
+                p.astype(v.dtype), _lanes(v, j, dv), _AB)
+            m_scr[j] = m_new
+
+    _causal_steps(causal, qi, kb, block_q, block_k, compute)
+
+    @pl.when(kb == num_kb - 1)
+    def _finalize():
+        for j in range(heads):
+            l = jnp.maximum(l_scr[j], 1e-30)
+            o_ref[:, j * dv:(j + 1) * dv] = (acc_scr[j] / l).astype(
+                o_ref.dtype)
+            lse_ref[j] = (m_scr[j] + jnp.log(l)).T
+
+
+def _latent_bwd_head(qn, qr, kn, kr, v, do, lse, delta, j, *, dn, dr, dv,
+                     sm_scale, masked, row0, col0):
+    """(p, ds) of head j for one (q block, k block) pair, in the inputs'
+    dtype for the MXU; ds carries the score's scale (``_bwd_head``)."""
+    s = _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked, row0,
+                       col0)
+    p = jnp.exp(s - lse)
+    dp = _dot(_lanes(do, j, dv), _lanes(v, j, dv), _ABT)
+    ds = p * (dp - delta) * sm_scale
+    return p.astype(do.dtype), ds.astype(kn.dtype)
+
+
+def _latent_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                          do_ref, lse_ref, dqn_ref, dqr_ref, delta_ref,
+                          dqn_scr, dqr_scr, *, sm_scale, causal, dn, dr, dv,
+                          block_q, block_k, num_kb):
+    """Grid (B, head blocks, q blocks, k blocks): dq_nope and dq_rope
+    accumulated over K; emits delta [B*H, 1, S] for the dk/dv kernel."""
+    qi, kb = pl.program_id(2), pl.program_id(3)
+    heads = lse_ref.shape[0]
+
+    @pl.when(kb == 0)
+    def _init():
+        dqn_scr[...] = jnp.zeros_like(dqn_scr)
+        dqr_scr[...] = jnp.zeros_like(dqr_scr)
+        for j in range(heads):
+            delta_ref[j] = jnp.sum(
+                _lanes(do_ref[...], j, dv).astype(jnp.float32)
+                * _lanes(o_ref[...], j, dv).astype(jnp.float32),
+                axis=-1, keepdims=True).T
+
+    def compute(masked):
+        qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
+                                 kr_ref[...], v_ref[...], do_ref[...])
+        for j in range(heads):
+            _, ds = _latent_bwd_head(
+                qn, qr, kn, kr, v, do, lse_ref[j].T, delta_ref[j].T, j,
+                dn=dn, dr=dr, dv=dv, sm_scale=sm_scale, masked=masked,
+                row0=qi * block_q, col0=kb * block_k)
+            dqn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(kn, j, dn),
+                                                    _AB)
+            # ds @ [k_rope | k_rope]: head j keeps its own lanes
+            dqr_scr[...] += _dot(ds, _head_lanes(kr, j, dr), _AB)
+
+    _causal_steps(causal, qi, kb, block_q, block_k, compute)
+
+    @pl.when(kb == num_kb - 1)
+    def _finalize():
+        dqn_ref[...] = dqn_scr[...].astype(dqn_ref.dtype)
+        dqr_ref[...] = dqr_scr[...].astype(dqr_ref.dtype)
+
+
+def _latent_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                           lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
+                           dkn_scr, dkr_scr, dv_scr, *, sm_scale, causal, dn,
+                           dr, dv, block_q, block_k, num_qb, num_cb):
+    """Grid (B, k blocks, head blocks, q blocks): dk_nope and dv
+    accumulated over Q for one head block; the shared key's gradient over
+    Q AND over the head blocks, written once a key block."""
+    kb, cb, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    heads = lse_ref.shape[0]
+
+    @pl.when(qi == 0)
+    def _init():
+        dkn_scr[...] = jnp.zeros_like(dkn_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when((qi == 0) & (cb == 0))
+    def _init_shared():
+        dkr_scr[...] = jnp.zeros_like(dkr_scr)
+
+    def compute(masked):
+        qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
+                                 kr_ref[...], v_ref[...], do_ref[...])
+        for j in range(heads):
+            p, ds = _latent_bwd_head(
+                qn, qr, kn, kr, v, do, lse_ref[j].T, delta_ref[j].T, j,
+                dn=dn, dr=dr, dv=dv, sm_scale=sm_scale, masked=masked,
+                row0=qi * block_q, col0=kb * block_k)
+            dv_scr[:, j * dv:(j + 1) * dv] += _dot(p, _lanes(do, j, dv),
+                                                   _ATB)
+            dkn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(qn, j, dn),
+                                                    _ATB)
+            # zero outside head j's lanes: lane block j of the repeated
+            # key collects the heads that read it
+            dkr_scr[...] += _dot(ds, _head_lanes(qr, j, dr), _ATB)
+
+    _causal_steps(causal, qi, kb, block_q, block_k, compute)
+
+    @pl.when(qi == num_qb - 1)
+    def _finalize():
+        dkn_ref[...] = dkn_scr[...].astype(dkn_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when((qi == num_qb - 1) & (cb == num_cb - 1))
+    def _finalize_shared():
+        dkr_ref[...] = dkr_scr[...].astype(dkr_ref.dtype)
+
+
+# v5e's default scoped VMEM (16 MiB) does not hold three [1024, 1024] f32
+# tiles of scores beside double-buffered operands of two heads; the chip
+# has 128 MiB.
+_LATENT_VMEM_BYTES = 96 * 1024 * 1024
+
+
+def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths):
+    """Block specs of the latent kernels for a grid (B, head block, q
+    block, k block) (``q_major``) or (B, k block, head block, q block):
+    (q_nope, q_rope, o / dO, k_nope, v, shared key, row statistics). A
+    skipped causal step names the block of the last computed step, so
+    nothing is fetched for it."""
+    wn, wv = widths
+
+    def at(pick):
+        def index_map(*g):
+            b, c, i, j = g if q_major else (g[0], g[2], g[3], g[1])
+            if causal and q_major:   # k blocks past the diagonal: skipped
+                j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+            elif causal:             # q blocks before the diagonal: skipped
+                i = jnp.maximum(i, j * block_k // block_q)
+            return pick(b, c, i, j)
+        return index_map
+
+    def q_side(w):
+        return pl.BlockSpec((None, block_q, w), at(lambda b, c, i, j: (b, i, c)))
+
+    def k_side(w):
+        return pl.BlockSpec((None, block_k, w), at(lambda b, c, i, j: (b, j, c)))
+
+    shared = pl.BlockSpec((None, block_k, _LANES),
+                          at(lambda b, c, i, j: (b, j, 0)))
+    rows = pl.BlockSpec((hpb, 1, block_q),
+                        at(lambda b, c, i, j: (b * ncb + c, 0, i)))
+    return q_side(wn), q_side(_LANES), q_side(wv), k_side(wn), k_side(wv), \
+        shared, rows
+
+
+def _latent_fwd(qn, qr, kn, kr, v, heads, sm_scale, causal, block_q,
+                block_k):
+    """Merged q_nope, k_nope [B, S, H*dn], q_rope [B, S, H*dr], the shared
+    key repeated to 128 lanes kr [B, S, 128], v [B, S, H*dv] ->
+    (o [B, S, H*dv], lse [B*H, 1, S])."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, seq, _ = qn.shape
+    dn, dr, dv, hpb, ncb, block_q, block_k = _latent_cut(
+        qn, qr, v, heads, block_q, block_k)
+    num_kb = seq // block_k
+    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
+        True, causal, block_q, block_k, hpb, ncb, (hpb * dn, hpb * dv))
+    half = 2 if causal else 1
+    return pl.pallas_call(
+        functools.partial(
+            _latent_fwd_kernel, sm_scale=sm_scale, causal=causal, dn=dn,
+            dr=dr, dv=dv, block_q=block_q, block_k=block_k, num_kb=num_kb),
+        grid=(b, ncb, seq // block_q, num_kb),
+        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s],
+        out_specs=[qv_s, row_s],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b * heads, 1, seq), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((hpb, block_q, 1), jnp.float32),
+            pltpu.VMEM((hpb, block_q, dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES),
+        name=LATENT_KERNEL_NAMES["fwd"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * heads * seq * seq * (dn + dr + dv) // half,
+            bytes_accessed=(qn.size + qr.size + kn.size + kr.size
+                            + 2 * v.size) * qn.dtype.itemsize,
+            transcendentals=b * heads * seq * seq // half),
+    )(qn, qr, kn, kr, v)
+
+
+def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
+                block_q, block_k):
+    """-> dq_nope, dq_rope, dk_nope, d(kr) [B, S, 128] (the heads of a
+    batch row summed; lane block j holds the heads whose rope part reads
+    it), dv."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, seq, _ = qn.shape
+    dn, dr, dv, hpb, ncb, block_q, block_k = _latent_cut(
+        qn, qr, v, heads, block_q, block_k)
+    num_qb, num_kb = seq // block_q, seq // block_k
+    wn, wv = hpb * dn, hpb * dv
+    half = 2 if causal else 1
+    pairs = b * heads * seq * seq // half
+    itemsize = qn.dtype.itemsize
+    kernel_kw = dict(sm_scale=sm_scale, causal=causal, dn=dn, dr=dr, dv=dv,
+                     block_q=block_q, block_k=block_k)
+
+    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
+        True, causal, block_q, block_k, hpb, ncb, (wn, wv))
+    dqn, dqr, delta = pl.pallas_call(
+        functools.partial(_latent_bwd_dq_kernel, num_kb=num_kb, **kernel_kw),
+        grid=(b, ncb, num_qb, num_kb),
+        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, qv_s, row_s],
+        out_specs=[qn_s, qr_s, row_s],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, wn), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES),
+        name=LATENT_KERNEL_NAMES["bwd_dq"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (2 * (dn + dr) + dv),
+            bytes_accessed=(2 * qn.size + 2 * qr.size + kn.size + kr.size
+                            + 3 * v.size) * itemsize,
+            transcendentals=pairs),
+    )(qn, qr, kn, kr, v, o, g, lse)
+
+    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
+        False, causal, block_q, block_k, hpb, ncb, (wn, wv))
+    dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_latent_bwd_dkv_kernel, num_qb=num_qb, num_cb=ncb,
+                          **kernel_kw),
+        grid=(b, num_kb, ncb, num_qb),
+        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, row_s, row_s],
+        out_specs=[kn_s, kr_s, kv_s],
+        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, wn), jnp.float32),
+                        pltpu.VMEM((block_k, _LANES), jnp.float32),
+                        pltpu.VMEM((block_k, wv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES),
+        name=LATENT_KERNEL_NAMES["bwd_dkv"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (2 * (dn + dr) + 2 * dv),
+            bytes_accessed=(2 * qn.size + 2 * qr.size + 2 * kn.size
+                            + 2 * kr.size + 3 * v.size) * itemsize,
+            transcendentals=pairs),
+    )(qn, qr, kn, kr, v, g, lse, delta)
+    return dqn, dqr, dkn, dkr, dvv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_latent(qn, qr, kn, kr, v, h, sm_scale, causal, block_q, block_k):
+    return _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
+                       block_k)[0]
+
+
+def _flash_latent_vjp_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
+                          block_k):
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, lse = _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
+                           block_k)
+    out = checkpoint_name(out, "flash_out")    # as ``_flash_vjp_fwd``
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (qn, qr, kn, kr, v, out, lse)
+
+
+def _flash_latent_vjp_bwd(h, sm_scale, causal, block_q, block_k, res, g):
+    qn, qr, kn, kr, v, out, lse = res
+    return _latent_bwd(qn, qr, kn, kr, v, out, lse, g, h, sm_scale, causal,
+                       block_q, block_k)
+
+
+_flash_latent.defvjp(_flash_latent_vjp_fwd, _flash_latent_vjp_bwd)
+
+
+def _note_path(layout: str, hpb: int, d: int, seq: int, bands: int,
+               **more) -> None:
     PATH_COUNTS[layout] += 1
     BAND_COUNTS[bands] += 1
     _record("rtpu.ops.flash.path", layout,
-            {"layout": layout, "heads_per_block": hpb, "hd": d, "S": seq,
-             "bands": bands})
+            dict({"layout": layout, "heads_per_block": hpb, "hd": d,
+                  "S": seq, "bands": bands}, **more))
+
+
+def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
+                      block_k):
+    """The call with a second, shared part of the score: q, k
+    [B, S, H, dn], v [B, S, H, dv], q_rope [B, S, H, dr], k_rope
+    [B, S, dr] -> [B, S, H, dv]."""
+    b, s, h, dn = q.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    if sm_scale is None:
+        sm_scale = 1.0 / ((dn + dr) ** 0.5)
+    if k.shape[1] != s:
+        raise ValueError("latent flash_attention requires seq_q == seq_k, "
+                         f"got {s} != {k.shape[1]}")
+    facts = {"hd_qk": dn + dr, "hd_v": dv, "shared_key": dr}
+    if s % 128 != 0 or not _latent_ok(h, dn, dr, dv):
+        _note_path("latent_reference", 0, dn + dr, s, 0, **facts)
+        shared = jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))
+        return mha_reference(jnp.concatenate([q, q_rope], -1),
+                             jnp.concatenate([k, shared], -1), v,
+                             causal=causal, sm_scale=sm_scale)
+    _note_path("latent", _LANES // dr, dn + dr, s, 1, **facts)
+    merge = lambda x: x.reshape(b, s, -1)  # noqa: E731
+    out = _flash_latent(merge(q), merge(q_rope), merge(k),
+                        jnp.tile(k_rope, (1, 1, _LANES // dr)), merge(v), h,
+                        sm_scale, causal, block_q, block_k)
+    return out.reshape(b, s, h, dv)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 1024, block_k: int = 1024) -> jax.Array:
-    """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> same shape.
+                    block_q: int = 1024, block_k: int = 1024,
+                    q_rope: Optional[jax.Array] = None,
+                    k_rope: Optional[jax.Array] = None) -> jax.Array:
+    """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> v's shape.
 
     Heads of 64 or 128 whose merged width heads*head_dim is a multiple of
     128 run with no copy around the kernels; other head sizes are
     transposed to and fro, and sequences that are no multiple of 128 go
-    to ``mha_reference`` (module docstring; ``PATH_COUNTS``)."""
+    to ``mha_reference`` (module docstring; ``PATH_COUNTS``).
+
+    A call that brings ``q_rope`` [batch, seq, heads, dr] and ONE
+    ``k_rope`` [batch, seq, dr] for all heads has a score in two parts,
+    (q·k + q_rope·k_rope) * sm_scale (default 1/sqrt(head_dim + dr)), and
+    v may be of another head size than q and k: the latent kernels, where
+    the sizes tile 128 lanes (``_latent_ok``)."""
+    if q_rope is not None:
+        return _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale,
+                                 block_q, block_k)
     b, s, h, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)

@@ -147,6 +147,70 @@ def test_flash_attention_streamed_kernels_are_named(one_chip,
                                    "flash_bwd_dkv"}
 
 
+def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
+                                                       compiled_kernels):
+    """ISSUE 33: kanana2_train_s8192's attention, B=2, S=8192, 32 heads of
+    128 + 64 against 128, one shared rope key: the three latent kernels at
+    the default blocks of 1024 (they ask for more VMEM than the default
+    scope, which only the chip's compiler checks), fed as the model feeds
+    them, with no copy of a head-shaped array around them."""
+    import re
+
+    from ray_tpu.ops.flash_attention import (LATENT_KERNEL_NAMES,
+                                             flash_attention)
+
+    b, s, h = 2, 8192, 32
+    sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(qn, kn, v, qr, kr):
+        heads = lambda x: x.reshape(b, s, h, -1)  # noqa: E731
+        return flash_attention(heads(qn), heads(kn), heads(v), causal=True,
+                               q_rope=heads(qr), k_rope=kr).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd(b, s, h * 128), sd(b, s, h * 128), sd(b, s, h * 128),
+        sd(b, s, h * 64), sd(b, s, 64)).compile().as_text()
+    names = {n for n in LATENT_KERNEL_NAMES.values()
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and n in line.split(" = ")[0]}
+    assert names == set(LATENT_KERNEL_NAMES.values())
+    # q_rope, two heads of 64 to a block, is never laid out by head
+    assert not re.findall(r"\w+\[2,8192,32,64\]", text)
+    assert " transpose(" not in text
+
+
+def test_held_expert_layer_at_the_benchmark_cells_shape(one_chip,
+                                                        monkeypatch):
+    """ISSUE 33: 16 384 tokens, 16 of 128 experts of 2048 x 768 held, top
+    6: forward and backward of the layer, the two grouped-product kernels
+    by name, no scatter."""
+    el = importlib.import_module("ray_tpu.ops.expert_layer")
+    monkeypatch.setattr(el, "_use_interpret", lambda: False)
+    t, d, f, held, of = 16384, 2048, 768, 16, 128
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    p = {"w_router": sd((d, of)), "router_bias": sd((of,)),
+         "s_gate": sd((d, 2 * f)), "s_up": sd((d, 2 * f)),
+         "s_down": sd((2 * f, d)), "e_gate": sd((held, d, f)),
+         "e_up": sd((held, d, f)), "e_down": sd((held, f, d))}
+
+    def loss(x, p):
+        return el.held_expert_layer(
+            x, p, experts_held=held, expert_offset=0, top_k=6,
+            routed_scale=2.448)[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sd((t, d), jnp.bfloat16), p).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("grouped_matmul_dw" in c for c in calls)
+    assert any("grouped_matmul" in c and "_dw" not in c for c in calls)
+    assert " scatter(" not in text
+
+
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
     """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
     B=8, S=1024, params and optimizer state donated."""

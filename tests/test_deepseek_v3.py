@@ -1,0 +1,339 @@
+"""ISSUE 33: the DeepSeek-V3 shaped model (latent attention in the flash
+kernels, a dropless held-expert layer with shared experts, a leading dense
+layer, a vocabulary slice) against the benchmark's plain reference
+(``benchmark/reference/deepseek_v3.py``: the one copy), on seeded random
+weights at a small size. Pallas kernels run in interpret mode here.
+
+Tolerances. Program and reference both compute in float32 here, so they
+differ by the order of their sums only. Read on this seed: the loss by
+4.8e-7 (one float32 step at 6.26), the gradients by at most 7.2e-7 of a
+parameter's largest entry. The limits: 3e-6 on the loss, 2e-5 of the
+largest entry on each gradient. Parameters rounded to bf16 move the loss
+by 1.8e-5 and every parameter's gradient by 2.1e-3 to 7.4e-3 of its
+largest entry; a missing ``routed_scaling_factor`` changes the routed sum
+by a factor of 2.448: both fail the limits (the last two tests hold that).
+"""
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import DeepseekV3, DeepseekV3Config
+from ray_tpu.models.deepseek_v3 import _rope_interleaved
+from ray_tpu.ops import mha_reference
+from ray_tpu.ops.expert_layer import (buffer_rows, held_expert_layer,
+                                      sort_rows)
+from ray_tpu.ops.flash_attention import PATH_COUNTS, flash_attention
+
+
+def _reference():
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "reference",
+        "deepseek_v3.py")
+    spec = importlib.util.spec_from_file_location("reference_deepseek_v3",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _reference()
+F32 = dict(dtype=jnp.float32)
+LOSS_LIMIT = 3e-6     # absolute, on a loss of 6.26 (module docstring)
+GRAD_LIMIT = 2e-5     # of the gradient's largest entry
+
+
+def _ref_loss(model, params, tokens):
+    h = ref.hidden(params, tokens, jnp.float32,
+                   **ref.model_kwargs(model.config))
+    logits = ref.head(params, h, jnp.float32)
+    targets = jnp.roll(tokens, -1, 1)
+    lse = jax.scipy.special.logsumexp(logits, -1)
+    return jnp.mean(lse - jnp.take_along_axis(
+        logits, targets[..., None], -1)[..., 0])
+
+
+def _tokens(vocab, seed=1, shape=(2, 128)):
+    return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, vocab)
+
+
+@pytest.fixture(scope="module")
+def whole():
+    """All experts held, the whole vocabulary: (model, params, tokens,
+    the program's loss and gradients, the reference's)."""
+    model = DeepseekV3(DeepseekV3Config.tiny(**F32))
+    params = model.init(jax.random.PRNGKey(0))
+    # a selection bias that is not zero, so that it is seen to select
+    params["moe.router_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(5), params["moe.router_bias"].shape)
+    toks = _tokens(model.config.vocab_size)
+    mine = jax.jit(jax.value_and_grad(model.loss))(
+        params, toks, jnp.roll(toks, -1, 1))
+    theirs = jax.jit(jax.value_and_grad(
+        lambda p: _ref_loss(model, p, toks)))(params)
+    return model, params, toks, mine, theirs
+
+
+def test_loss_equals_the_references(whole):
+    _, _, _, (loss, _), (ref_loss, _) = whole
+    assert abs(float(loss) - float(ref_loss)) < LOSS_LIMIT
+
+
+def test_gradients_equal_the_references(whole):
+    _, params, _, (_, grads), (_, ref_grads) = whole
+    for name in params:
+        g, r = np.asarray(grads[name]), np.asarray(ref_grads[name])
+        scale = np.abs(r).max()
+        if name == "moe.router_bias":       # a buffer: selects, no gradient
+            assert not g.any() and not r.any()
+            continue
+        assert scale > 0, name
+        assert np.abs(g - r).max() < GRAD_LIMIT * scale, name
+
+
+def test_the_loss_over_a_vocabulary_slice_is_the_references_over_it():
+    """``vocab_size`` an eighth of the tiny vocabulary, no multiple of
+    128: embedding, head and loss are over the slice (padded to 128 rows,
+    which take part in the softmax on both sides)."""
+    model = DeepseekV3(DeepseekV3Config.tiny(vocab_size=64, **F32))
+    assert model.config.padded_vocab == 128
+    params = model.init(jax.random.PRNGKey(2))
+    assert params["wte"].shape[0] == params["lm_head"].shape[0] == 128
+    toks = _tokens(64, seed=3)
+    loss = jax.jit(model.loss)(params, toks, jnp.roll(toks, -1, 1))
+    want = _ref_loss(model, params, toks)
+    assert abs(float(loss) - float(want)) < LOSS_LIMIT
+
+
+# -- the latent kernels ------------------------------------------------------
+
+
+def _latent_inputs(b=2, s=256, h=4, dn=128, dr=64, dv=128):
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    n = jax.random.normal
+    return (n(ks[0], (b, s, h, dn)), n(ks[1], (b, s, h, dn)),
+            n(ks[2], (b, s, h, dv)), n(ks[3], (b, s, h, dr)),
+            n(ks[4], (b, s, dr))), n(ks[5], (b, s, h, dv))
+
+
+def _latent_plain(q, k, v, q_rope, k_rope):
+    """The 192-wide key written out for every head."""
+    shared = jnp.broadcast_to(k_rope[:, :, None, :],
+                              q_rope.shape[:3] + k_rope.shape[-1:])
+    qq = jnp.concatenate([q, q_rope], -1)
+    return mha_reference(qq, jnp.concatenate([k, shared], -1), v,
+                         causal=True, sm_scale=qq.shape[-1] ** -0.5)
+
+
+@pytest.fixture(scope="module")
+def latent_grads():
+    args, w = _latent_inputs()
+    out = {}
+    for blocks in (256, 128):     # one K block a program; streamed
+        def loss(*a, blocks=blocks):
+            return (flash_attention(a[0], a[1], a[2], causal=True,
+                                    block_q=blocks, block_k=blocks,
+                                    q_rope=a[3], k_rope=a[4]) * w).sum()
+        out[blocks] = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+    out["plain"] = jax.value_and_grad(
+        lambda *a: (_latent_plain(*a) * w).sum(), argnums=(0, 1, 2, 3, 4))(
+        *args)
+    return out
+
+
+@pytest.mark.parametrize("blocks", [256, 128])
+@pytest.mark.parametrize("which", ["forward", "dq_nope", "dk_nope", "dv",
+                                   "dq_rope", "dk_rope"])
+def test_latent_kernels_against_mha_reference(latent_grads, blocks, which):
+    """Head sizes 192 (128 + 64, the 64 one key for all heads) and 128,
+    float32 in interpret mode: the sums' order only, values of order 1 to
+    5; 2e-5 absolute."""
+    (val, grads), (want_val, want) = latent_grads[blocks], latent_grads["plain"]
+    if which == "forward":
+        assert abs(float(val) - float(want_val)) < 2e-5 * abs(float(want_val)) + 2e-4
+        return
+    i = ["dq_nope", "dk_nope", "dv", "dq_rope", "dk_rope"].index(which)
+    assert grads[i].shape == want[i].shape
+    assert float(jnp.abs(grads[i] - want[i]).max()) < 2e-5
+
+
+def test_a_latent_call_takes_the_latent_kernels_and_says_so():
+    args, _ = _latent_inputs(b=1, s=128, h=2)
+    before = PATH_COUNTS["latent"]
+    text = jax.jit(lambda *a: flash_attention(
+        a[0], a[1], a[2], q_rope=a[3], k_rope=a[4])).lower(*args).as_text(
+        debug_info=True)
+    assert PATH_COUNTS["latent"] == before + 1
+    assert "flash_latent_fwd" in text and "flash_fwd_single" not in text
+    # heads that do not tile the lanes: the plain route, named as such
+    odd = _latent_inputs(b=1, s=128, h=2, dn=32, dr=16, dv=32)[0]
+    before = PATH_COUNTS["latent_reference"]
+    got = flash_attention(odd[0], odd[1], odd[2], q_rope=odd[3],
+                          k_rope=odd[4])
+    assert PATH_COUNTS["latent_reference"] == before + 1
+    assert float(jnp.abs(got - _latent_plain(*odd)).max()) < 1e-5
+
+
+def test_the_score_is_scaled_by_one_over_sqrt_192_by_hand():
+    """q = ones, every key c_pos * ones: score = 192 c_pos / sqrt(192);
+    v_pos = pos. The last row sees every position."""
+    s, h = 128, 2
+    c = np.linspace(-0.2, 0.2, s).astype(np.float32)
+    ones = lambda d: jnp.ones((1, s, h, d))  # noqa: E731
+    k = jnp.asarray(c)[None, :, None, None] * ones(128)
+    k_rope = jnp.asarray(c)[None, :, None] * jnp.ones((1, s, 64))
+    v = jnp.arange(s, dtype=jnp.float32)[None, :, None, None] * ones(128)
+    o = flash_attention(ones(128), k, v, q_rope=ones(64), k_rope=k_rope)
+    e = np.exp(c.astype(np.float64) * np.sqrt(192.0))
+    want_last = float((e * np.arange(s)).sum() / e.sum())
+    want_mid = float((e[:65] * np.arange(65)).sum() / e[:65].sum())
+    assert abs(float(o[0, -1, 0, 0]) - want_last) < 1e-3
+    assert abs(float(o[0, 64, 1, 5]) - want_mid) < 1e-3
+    # sqrt(128), the nope part's own width, would give another value
+    e2 = np.exp(c.astype(np.float64) * 192.0 / np.sqrt(128.0))
+    assert abs((e2 * np.arange(s)).sum() / e2.sum() - want_last) > 1.0
+
+
+def test_rope_turns_neighbouring_pairs_by_hand():
+    """One head of 4 at position 1, base 100: the pair (x0, x1) turns by
+    1 rad, (x2, x3) by 100**-0.5 = 0.1 rad. The program sorts the pairs
+    into halves first, as the published code does: (x0, x2 | x1, x3)."""
+    x = jnp.zeros((1, 2, 1, 4)).at[0, 1, 0].set(jnp.array([1., 0., 0., 2.]))
+    want_pairs = np.array([np.cos(1.0), np.sin(1.0),
+                           -2 * np.sin(0.1), 2 * np.cos(0.1)])
+    got_ref = np.asarray(ref.rope_pairs(x, 100.0))[0, 1, 0]
+    np.testing.assert_allclose(got_ref, want_pairs, atol=1e-6)
+    half = 1.0 / (100.0 ** (jnp.arange(0, 2, dtype=jnp.float32) / 2))
+    ang = jnp.arange(2, dtype=jnp.float32)[:, None] * half[None]
+    got = np.asarray(_rope_interleaved(x, jnp.cos(ang), jnp.sin(ang)))[0, 1, 0]
+    np.testing.assert_allclose(got, want_pairs[[0, 2, 1, 3]], atol=1e-6)
+    # position 0 is left alone
+    assert not np.asarray(ref.rope_pairs(x, 100.0))[0, 0].any()
+
+
+# -- the expert layer --------------------------------------------------------
+
+
+def _layer_params(model, seed=11, layer=0):
+    p = model.init(jax.random.PRNGKey(seed))
+    return {n.split(".", 1)[1]: v[layer] for n, v in p.items()
+            if n.startswith("moe.")}
+
+
+def _layer_kw(c, held, offset):
+    return dict(experts_held=held, expert_offset=offset, top_k=c.top_k,
+                routed_scale=c.routed_scaling_factor)
+
+
+def _share(lp, held, offset):
+    return dict(lp, **{n: lp[n][offset:offset + held]
+                       for n in ("e_gate", "e_up", "e_down")})
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """16 experts over 8 chips, 2 each: the shares' outputs, with the
+    shared experts (which every chip computes alike) counted once, are
+    the reference's whole layer."""
+    c = DeepseekV3Config.tiny(n_routed_experts=16, experts_held=16, top_k=6,
+                              **F32)
+    lp = _layer_params(DeepseekV3(c))
+    x = jax.random.normal(jax.random.PRNGKey(4), (96, c.d_model))
+    shared = ref.shared_expert(x, lp)
+    whole = shared + ref.routed_experts(
+        x, lp, top_k=6, routed_scale=c.routed_scaling_factor)
+    total, rows = jnp.zeros_like(x), 0
+    for chip in range(8):
+        y, n = held_expert_layer(x, _share(lp, 2, 2 * chip),
+                                 **_layer_kw(c, 2, 2 * chip))
+        # the reference, given the same share, gives the same part
+        part = ref.routed_experts(
+            x, _share(lp, 2, 2 * chip), top_k=6,
+            routed_scale=c.routed_scaling_factor, expert_offset=2 * chip)
+        assert float(jnp.abs(y - shared - part).max()) < 1e-6
+        total, rows = total + y - shared, rows + int(n)
+    # outputs of order 1e-2; float32 sums in another order
+    assert float(jnp.abs(total + shared - whole).max()) < 1e-6
+    assert rows == 96 * 6        # every (token, choice) pair on some chip
+
+
+@pytest.mark.parametrize("favoured,rows", [("held", 64 * 3), ("absent", 0)])
+def test_no_token_is_dropped_whatever_the_routing(favoured, rows):
+    """A selection bias that sends EVERY token to the same three experts:
+    the held ones (the buffer's worst case, all 192 rows on experts 0-2 of
+    the 4 held) or absent ones (no row at all). Equal to the reference,
+    which computes every held expert for every token."""
+    c = DeepseekV3Config.tiny(experts_held=4, **F32)      # 4 of 8, top 3
+    lp = _share(_layer_params(DeepseekV3(c)), 4, 0)
+    first = 0 if favoured == "held" else 5
+    lp["router_bias"] = jnp.zeros(8).at[first:first + 3].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, c.d_model))
+    y, n = held_expert_layer(x, lp, tile=8, **_layer_kw(c, 4, 0))
+    want = ref.shared_expert(x, lp) + ref.routed_experts(
+        x, lp, top_k=3, routed_scale=c.routed_scaling_factor)
+    assert int(n) == rows
+    assert float(jnp.abs(y - want).max()) < 1e-6
+    if favoured == "held":
+        assert float(jnp.abs(y - ref.shared_expert(x, lp)).max()) > 1e-3
+    # and the gradient reaches x through every row
+    g = jax.grad(lambda x: held_expert_layer(
+        x, lp, tile=8, **_layer_kw(c, 4, 0))[0].sum())(x)
+    g_want = jax.grad(lambda x: (ref.shared_expert(x, lp) + ref.routed_experts(
+        x, lp, top_k=3, routed_scale=c.routed_scaling_factor)).sum())(x)
+    assert float(jnp.abs(g - g_want).max()) < 1e-5 * float(
+        jnp.abs(g_want).max()) + 1e-7
+
+
+def test_the_row_buffer_is_static_and_covers_the_worst_case():
+    assert buffer_rows(64, 3, 4, 8) == 64 * 3 + 4 * 8
+    assert buffer_rows(16384, 6, 16) == (16384 * 6 // 256 + 16) * 256
+    assert buffer_rows(10, 6, 2, 8) == (3 + 2) * 8     # 2 held: 2 a token
+    # every pair on one expert: its rows are the first 192, the other
+    # three experts own one empty tile each
+    chosen = jnp.zeros((64, 3), jnp.int32)
+    at = sort_rows(chosen, 4, 0, buffer_rows(64, 3, 4, 8), 8)
+    assert int(at["n_used"][0]) == 24 + 3
+    assert sorted(np.asarray(at["pair_row"]).ravel()) == list(range(192))
+    assert list(np.asarray(at["tile_expert"])[:27]) == [0] * 24 + [1, 2, 3]
+    assert (np.asarray(at["row_pair"])[192:] == 192).all()
+
+
+def test_routing_stats_counts_the_held_rows_of_each_expert_layer():
+    c = DeepseekV3Config.tiny(experts_held=2, expert_offset=2, **F32)
+    model = DeepseekV3(c)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = _tokens(c.vocab_size)
+    rows = np.asarray(jax.jit(model.routing_stats)(params, toks))
+    assert rows.shape == (c.n_layer - c.first_k_dense,)
+    assert (rows > 0).all() and (rows < toks.size * c.top_k).all()
+    # with every expert held, every pair is a row
+    every = DeepseekV3(DeepseekV3Config.tiny(**F32))
+    rows = jax.jit(every.routing_stats)(every.init(jax.random.PRNGKey(0)),
+                                        toks)
+    assert (np.asarray(rows) == toks.size * c.top_k).all()
+
+
+# -- the limits refuse what they must ----------------------------------------
+
+
+def test_bf16_parameters_fail_the_limits(whole):
+    model, params, toks, (loss, grads), _ = whole
+    rounded = {n: v.astype(jnp.bfloat16).astype(jnp.float32)
+               for n, v in params.items()}
+    other, other_grads = jax.jit(jax.value_and_grad(model.loss))(
+        rounded, toks, jnp.roll(toks, -1, 1))
+    assert abs(float(other) - float(loss)) > 3 * LOSS_LIMIT
+    for name in ("lm_head", "moe.e_down", "moe.w_q_rope", "dense.w_gate"):
+        g = np.asarray(grads[name])
+        assert np.abs(np.asarray(other_grads[name]) - g).max() \
+            > 50 * GRAD_LIMIT * np.abs(g).max(), name
+
+
+def test_a_missing_routed_scaling_factor_fails_the_loss_limit(whole):
+    model, params, toks, (loss, _), _ = whole
+    unscaled = DeepseekV3(DeepseekV3Config.tiny(routed_scaling_factor=1.0,
+                                                **F32))
+    other = jax.jit(unscaled.loss)(params, toks, jnp.roll(toks, -1, 1))
+    assert abs(float(other) - float(loss)) > 3 * LOSS_LIMIT

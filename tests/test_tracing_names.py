@@ -10,10 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import GPT, GPTConfig, Llama, LlamaConfig
+from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
+                            Llama, LlamaConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
+el = importlib.import_module("ray_tpu.ops.expert_layer")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -89,7 +91,83 @@ def test_flash_kernel_names_are_pinned(kernel_texts, key, name, blocks):
     assert not re.search(pattern, kernel_texts[3 - blocks])
 
 
+@pytest.fixture(scope="module")
+def latent_text():
+    q = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    qr, kr = jnp.zeros((1, 256, 2, 64), q.dtype), jnp.zeros((1, 256, 64),
+                                                             q.dtype)
+
+    def loss(q, k, v, qr, kr):
+        return fa.flash_attention(q, k, v, causal=True, block_q=128,
+                                  block_k=128, q_rope=qr,
+                                  k_rope=kr).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, qr, kr).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("key,name", [
+    ("fwd", "flash_latent_fwd"), ("bwd_dq", "flash_latent_bwd_dq"),
+    ("bwd_dkv", "flash_latent_bwd_dkv")])
+def test_latent_flash_kernel_names_are_pinned(latent_text, key, name):
+    """ISSUE 33: ``mla_attention_roofline`` finds its kernels by these."""
+    assert fa.LATENT_KERNEL_NAMES[key] == name
+    assert len(set(fa.LATENT_KERNEL_NAMES.values())) == 3
+    assert not set(fa.LATENT_KERNEL_NAMES.values()) & set(
+        fa.KERNEL_NAMES.values())
+    assert re.search(r"[/\"(]" + name + r"[/\")]", latent_text)
+    # no kernel of the one-part score is in a latent call
+    for other in fa.KERNEL_NAMES.values():
+        assert not re.search(r"[/\"(]" + other + r"[/\")]", latent_text)
+
+
+@pytest.mark.parametrize("key,name", [("rows", "grouped_matmul"),
+                                      ("weights", "grouped_matmul_dw")])
+def test_grouped_matmul_kernel_names_are_pinned(key, name):
+    """ISSUE 33: ``grouped_matmul_roofline`` finds its kernels by these."""
+    assert el.KERNEL_NAMES[key] == name
+    x, w = jnp.zeros((32, 16), jnp.bfloat16), jnp.zeros((2, 16, 8),
+                                                        jnp.bfloat16)
+    at = el.sort_rows(jnp.zeros((8, 2), jnp.int32), 2, 0, 32, 8)
+    text = jax.jit(jax.grad(lambda x, w: el.grouped_matmul(
+        x, w, at["tile_expert"], at["n_used"], 8).astype(
+        jnp.float32).sum(), argnums=(0, 1))).lower(x, w).as_text(
+        debug_info=True)
+    assert re.search(r"[/\"(]" + name + r"[/\")]", text)
+
+
+def test_the_expert_layer_and_the_latent_route_leave_their_events():
+    """ISSUE 33: ``rtpu.ops.expert_layer`` at trace time (experts held, of
+    how many, top k, the row buffer) and ``rtpu.ops.flash.path`` with the
+    head sizes and the shared key of a latent call."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = DeepseekV3(DeepseekV3Config.tiny(experts_held=2, expert_offset=4))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    before = el.LAYER_COUNTS[(2, 8)], fa.PATH_COUNTS["latent"]
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        jax.jit(m.loss).lower(p, toks, toks)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    assert el.LAYER_COUNTS[(2, 8)] == before[0] + 1
+    assert fa.PATH_COUNTS["latent"] > before[1]
+    layer = [e for e in events if e["kind"] == "rtpu.ops.expert_layer"][-1]
+    assert layer["data"] == {
+        "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
+        "row_tile": el.ROW_TILE}
+    path = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
+            and e["label"] == "latent"][-1]
+    assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
+    assert path["data"]["shared_key"] == 64 and path["data"]["S"] == 128
+
+
 MODELS = {
+    "deepseek_v3": lambda: DeepseekV3(DeepseekV3Config.tiny(experts_held=4)),
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
@@ -109,9 +187,10 @@ def lowered_losses():
     return out
 
 
-@pytest.mark.parametrize("scope", ["embed", "attn", "mlp", "lm_head",
-                                   "loss"])
-@pytest.mark.parametrize("model", sorted(MODELS))
+@pytest.mark.parametrize("scope,model", [
+    (s, m) for m in sorted(MODELS)
+    for s in ("embed", "attn", "mlp", "lm_head", "loss")
+    + (("router", "experts", "shared_expert") if m == "deepseek_v3" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
     names = lowered_losses[model]
