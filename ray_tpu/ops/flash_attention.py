@@ -26,15 +26,20 @@ The routes, chosen by what a call shows (``PATH_COUNTS``, the event
   ``k_rope`` [B, S, dr] for all heads: the score is
   (q·k + q_rope·k_rope) * scale and v may have another head size than q
   and k (multi-head latent attention: 128 + 64 against 128). dr of 64 or
-  128, the q/k and v head sizes multiples of 128 (``_latent_ok``): three
+  128, the q/k and v head sizes multiples of 128 (``_latent_ok``):
   kernels of their own (``LATENT_KERNEL_NAMES``), 128 // dr heads to a
-  program, the shared key never copied to the heads; see the section
-  "latent attention" below.
+  program, the shared key never copied to the heads: a forward and, for
+  a causal call whose whole-sequence accumulators fit VMEM, ONE backward
+  kernel that makes the score and dP once a block pair; for every other
+  call two (``_latent_backward``; ``backward`` in the event). See the
+  section "latent attention" below.
 * ``reference`` / ``latent_reference``: S no multiple of 128, or latent
   head sizes that do not tile the lanes: ``mha_reference``, no kernel.
 
 The grid streams Q and K/V blocks so nothing larger than a block is
-VMEM-resident. bf16 inputs feed the MXU directly
+VMEM-resident (but the float32 dq of one head block, which the latent
+route's fused backward keeps for the whole sequence). bf16 inputs feed
+the MXU directly
 (preferred_element_type=f32 accumulate); all softmax state is f32 on the
 VPU — the standard TPU recipe (pallas_guide.md: MXU matmuls with
 preferred_element_type; min tile (16,128) for bf16).
@@ -92,12 +97,21 @@ KERNEL_NAMES = {
     "bwd_dkv": "flash_bwd_dkv",         # dk and dv, one pass over Q blocks
 }
 
-# The three kernels of a call whose score has a second part against one
-# shared key (latent attention), at any S; pinned in the same test.
+# The kernels of a call whose score has a second part against one shared
+# key (latent attention), at any S; pinned in the same test. A call runs
+# the forward and either ``bwd_dkv`` alone or ``bwd_dq`` then ``bwd_dkv``
+# (``_latent_backward``). The FUSED backward is launched as
+# ``flash_latent_bwd_dkv``: it is that kernel, its accumulators over the q
+# blocks kept, grown by the dq outputs, and the benchmark's reader
+# (``benchmark/layer_metrics/mla_attention_roofline.py``) finds the
+# kernels' time by exactly these three names: under a fourth the time
+# would leave the metric while the operations stayed in its count.
+# ``flash_latent_bwd_dq`` is the first kernel of the split path only.
 LATENT_KERNEL_NAMES = {
     "fwd": "flash_latent_fwd",
-    "bwd_dq": "flash_latent_bwd_dq",        # dq_nope, dq_rope
-    "bwd_dkv": "flash_latent_bwd_dkv",      # dk_nope, dv, d(shared key)
+    "bwd_dq": "flash_latent_bwd_dq",        # split: dq_nope, dq_rope
+    "bwd_dkv": "flash_latent_bwd_dkv",      # dk_nope, dv, d(shared key);
+                                            # fused: dq_nope, dq_rope too
 }
 
 # Traced calls of flash_attention by the layout each took: "merged" (the
@@ -111,6 +125,11 @@ PATH_COUNTS: collections.Counter = collections.Counter()
 # (``_band_height``): 4 at S=1024, 1 where nothing is banded (non-causal,
 # streamed, S=128), 0 on the reference route. ``bands`` in the event's data.
 BAND_COUNTS: collections.Counter = collections.Counter()
+
+# The traced LATENT calls by the backward their shapes select
+# (``_latent_backward``): "fused" (one kernel) or "split" (two).
+# ``backward`` in the event's data.
+BACKWARD_COUNTS: collections.Counter = collections.Counter()
 
 
 def _use_interpret() -> bool:
@@ -821,11 +840,15 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # 128 lanes (``kr``), so that the zeroed lanes add exact zeros. The
 # gradient of ``kr`` comes out of the dk/dv kernel summed over every head
 # of the batch row: its grid runs the head blocks INSIDE a key block and
-# carries one [block_k, 128] accumulator across them. One set of three
-# kernels serves every S (a single block is a grid of one step); causal
-# blocks above the diagonal are skipped by predicate and fetch nothing
-# (their index maps point at the block before), and only blocks the
-# diagonal crosses are masked.
+# carries one [block_k, 128] accumulator across them. One set of kernels
+# serves every S (a single block is a grid of one step); causal blocks
+# above the diagonal are skipped by predicate and fetch nothing (their
+# index maps point at the block before), and only blocks the diagonal
+# crosses are masked. The two backward kernels each make the score, dP,
+# the mask and exp of a block pair: 5 + 6 passes of a 128-deep
+# contraction a pair and head. A causal call runs ONE kernel instead
+# (``_latent_bwd_fused_kernel``, 8 passes) where its shapes allow
+# (``_latent_backward``).
 
 
 def _latent_cut(qn, qr, v, heads, block_q, block_k):
@@ -1015,23 +1038,98 @@ def _latent_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         dkr_ref[...] = dkr_scr[...].astype(dkr_ref.dtype)
 
 
+def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                             do_ref, lse_ref, dqn_ref, dqr_ref, dkn_ref,
+                             dkr_ref, dv_ref, dqn_scr, dqr_scr, dkn_scr,
+                             dkr_scr, dv_scr, *, sm_scale, dn, dr, dv, block,
+                             num_qb, num_cb):
+    """The whole backward of a causal call in one kernel, blocks square:
+    grid (B, head blocks, k blocks, q blocks). ``_latent_bwd_head``'s
+    (p, ds) are made once a (q block, k block) pair and head and feed all
+    five gradients. dk_nope and dv accumulate over Q as in
+    ``_latent_bwd_dkv_kernel``. dq_nope and dq_rope accumulate over the
+    key blocks in VMEM for the WHOLE sequence of one head block
+    ([q blocks, block, width] f32): rows of q block i are complete at the
+    diagonal step (kb = i, qi = i), the first computed step of key block
+    i, and are written there; their output block is (b, kb, c), which
+    holds still through the inner loop, so each goes to HBM once. The
+    shared key's gradient accumulates for the whole sequence too, across
+    the head blocks, and leaves in the last one. delta = rowsum(dO * o)
+    is made from the tiles at hand (``_row_delta``'s reason)."""
+    cb, kb, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    heads = lse_ref.shape[0]
+
+    @pl.when(qi == 0)
+    def _init():
+        dkn_scr[...] = jnp.zeros_like(dkn_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when((qi == 0) & (cb == 0))
+    def _init_shared():
+        dkr_scr[kb] = jnp.zeros(dkr_scr.shape[1:], dkr_scr.dtype)
+
+    @pl.when(kb == 0)
+    def _init_dq():
+        dqn_scr[qi] = jnp.zeros(dqn_scr.shape[1:], dqn_scr.dtype)
+        dqr_scr[qi] = jnp.zeros(dqr_scr.shape[1:], dqr_scr.dtype)
+
+    def compute(masked):
+        qn, qr, kn, kr, v, o, do = (
+            qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...], v_ref[...],
+            o_ref[...], do_ref[...])
+        for j in range(heads):
+            doj = _lanes(do, j, dv)
+            delta = jnp.sum(doj.astype(jnp.float32)
+                            * _lanes(o, j, dv).astype(jnp.float32),
+                            axis=-1, keepdims=True)
+            p, ds = _latent_bwd_head(
+                qn, qr, kn, kr, v, do, lse_ref[j].T, delta, j, dn=dn, dr=dr,
+                dv=dv, sm_scale=sm_scale, masked=masked, row0=qi * block,
+                col0=kb * block)
+            dv_scr[:, j * dv:(j + 1) * dv] += _dot(p, doj, _ATB)
+            dkn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(qn, j, dn),
+                                                    _ATB)
+            dkr_scr[kb] += _dot(ds, _head_lanes(qr, j, dr), _ATB)
+            dqn_scr[qi, :, j * dn:(j + 1) * dn] += _dot(
+                ds, _lanes(kn, j, dn), _AB)
+            dqr_scr[qi] += _dot(ds, _head_lanes(kr, j, dr), _AB)
+        if masked:   # the diagonal step: the last key block these rows see
+            dqn_ref[...] = dqn_scr[qi].astype(dqn_ref.dtype)
+            dqr_ref[...] = dqr_scr[qi].astype(dqr_ref.dtype)
+
+    _causal_steps(True, qi, kb, block, block, compute)
+
+    @pl.when(qi == num_qb - 1)
+    def _finalize():
+        dkn_ref[...] = dkn_scr[...].astype(dkn_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when((qi == num_qb - 1) & (cb == num_cb - 1))
+    def _finalize_shared():
+        dkr_ref[...] = dkr_scr[kb].astype(dkr_ref.dtype)
+
+
 # v5e's default scoped VMEM (16 MiB) does not hold three [1024, 1024] f32
 # tiles of scores beside double-buffered operands of two heads; the chip
 # has 128 MiB.
 _LATENT_VMEM_BYTES = 96 * 1024 * 1024
 
 
-def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths):
+def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths,
+                  heads_outer: bool = False):
     """Block specs of the latent kernels for a grid (B, head block, q
-    block, k block) (``q_major``) or (B, k block, head block, q block):
-    (q_nope, q_rope, o / dO, k_nope, v, shared key, row statistics). A
-    skipped causal step names the block of the last computed step, so
-    nothing is fetched for it."""
+    block, k block) (``q_major``), (B, k block, head block, q block) or
+    (``heads_outer``) (B, head block, k block, q block): (q_nope, q_rope,
+    o / dO, k_nope, v, shared key, row statistics). A skipped causal step
+    names the block of the last computed step, so nothing is fetched for
+    it."""
     wn, wv = widths
 
     def at(pick):
         def index_map(*g):
-            b, c, i, j = g if q_major else (g[0], g[2], g[3], g[1])
+            b, c, i, j = g if q_major else (
+                (g[0], g[1], g[3], g[2]) if heads_outer
+                else (g[0], g[2], g[3], g[1]))
             if causal and q_major:   # k blocks past the diagonal: skipped
                 j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
             elif causal:             # q blocks before the diagonal: skipped
@@ -1095,6 +1193,79 @@ def _latent_fwd(qn, qr, kn, kr, v, heads, sm_scale, causal, block_q,
     )(qn, qr, kn, kr, v)
 
 
+def _latent_backward(seq: int, causal: bool, block_q: int, block_k: int,
+                     wn: int, wv: int, itemsize: int) -> str:
+    """Which backward a latent call takes, from its shapes alone
+    (``backward`` in the event ``rtpu.ops.flash.path``,
+    ``BACKWARD_COUNTS``). ``fused``: one kernel
+    (``_latent_bwd_fused_kernel``) that makes the score, p, dP and ds once
+    a block pair, 8 passes of the MXU a pair and head where the two
+    kernels of ``split`` issue 11. It needs the causal order (a q block's
+    last key block is its own), square blocks, and VMEM for what it keeps
+    of the whole sequence beside a block pair's tiles; a non-causal call
+    and a sequence too long for that take the two kernels. At S=8192 and
+    two heads of 128 to a block: 16.8 MB of whole-sequence accumulators +
+    16.8 MB of score tiles + 2 MB + 10.5 MB of operands: 46 of 96."""
+    if not causal or block_q != block_k:
+        return "split"
+    whole = seq * (wn + 2 * _LANES) * 4         # dq_nope, dq_rope, d(kr)
+    tiles = block_q * block_k * (3 * 4 + 2 * itemsize)  # s, dP, ds; p, ds
+    dkv = block_k * (wn + wv) * 4
+    # q_nope, q_rope, o, dO, k_nope, kr, v in; the five gradients out;
+    # each double-buffered
+    operands = 2 * 2 * itemsize * (block_q * (wn + wv + _LANES)
+                                   + block_k * (wn + wv + _LANES))
+    fits = whole + tiles + dkv + operands <= _LATENT_VMEM_BYTES
+    return "fused" if fits else "split"
+
+
+def _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, block):
+    """``_latent_bwd`` of a causal call in one kernel, launched under the
+    dk/dv kernel's name (``LATENT_KERNEL_NAMES``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, seq, _ = qn.shape
+    dn, dr, dv, hpb, ncb, _, _ = _latent_cut(qn, qr, v, heads, block, block)
+    nb = seq // block
+    wn, wv = hpb * dn, hpb * dv
+    pairs = b * heads * seq * seq // 2
+    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
+        False, True, block, block, hpb, ncb, (wn, wv), heads_outer=True)
+    # dq's blocks leave by key block (square blocks: k_nope's spec fits
+    # dq_nope); the shared key's gradient leaves in the last head block
+    # and names one block until then, so that nothing is written before
+    dqr_s = pl.BlockSpec((None, block, _LANES), lambda b, c, j, i: (b, j, c))
+    dkr_s = pl.BlockSpec(
+        (None, block, _LANES),
+        lambda b, c, j, i: (b, jnp.where(c == ncb - 1, j, 0), 0))
+    return pl.pallas_call(
+        functools.partial(
+            _latent_bwd_fused_kernel, sm_scale=sm_scale, dn=dn, dr=dr, dv=dv,
+            block=block, num_qb=nb, num_cb=ncb),
+        grid=(b, ncb, nb, nb),
+        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, qv_s, row_s],
+        out_specs=[kn_s, dqr_s, kn_s, dkr_s, kv_s],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qn, qr, kn, kr, v)],
+        scratch_shapes=[pltpu.VMEM((nb, block, wn), jnp.float32),
+                        pltpu.VMEM((nb, block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, wn), jnp.float32),
+                        pltpu.VMEM((nb, block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, wv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES),
+        name=LATENT_KERNEL_NAMES["bwd_dkv"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (3 * (dn + dr) + 2 * dv),
+            bytes_accessed=(2 * qn.size + 2 * qr.size + 2 * kn.size
+                            + 2 * kr.size + 4 * v.size) * qn.dtype.itemsize,
+            transcendentals=pairs),
+    )(qn, qr, kn, kr, v, o, g, lse)
+
+
 def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
                 block_q, block_k):
     """-> dq_nope, dq_rope, dk_nope, d(kr) [B, S, 128] (the heads of a
@@ -1107,9 +1278,13 @@ def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
         qn, qr, v, heads, block_q, block_k)
     num_qb, num_kb = seq // block_q, seq // block_k
     wn, wv = hpb * dn, hpb * dv
+    itemsize = qn.dtype.itemsize
+    if _latent_backward(seq, causal, block_q, block_k, wn, wv,
+                        itemsize) == "fused":
+        return _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads,
+                                 sm_scale, block_q)
     half = 2 if causal else 1
     pairs = b * heads * seq * seq // half
-    itemsize = qn.dtype.itemsize
     kernel_kw = dict(sm_scale=sm_scale, causal=causal, dn=dn, dr=dr, dv=dv,
                      block_q=block_q, block_k=block_k)
 
@@ -1221,10 +1396,15 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
         return mha_reference(jnp.concatenate([q, q_rope], -1),
                              jnp.concatenate([k, shared], -1), v,
                              causal=causal, sm_scale=sm_scale)
-    _note_path("latent", _LANES // dr, dn + dr, s, 1, **facts)
+    hpb = _LANES // dr
+    backward = _latent_backward(
+        s, causal, _fit_block(block_q, s), _fit_block(block_k, s), hpb * dn,
+        hpb * dv, q.dtype.itemsize)
+    BACKWARD_COUNTS[backward] += 1
+    _note_path("latent", hpb, dn + dr, s, 1, backward=backward, **facts)
     merge = lambda x: x.reshape(b, s, -1)  # noqa: E731
     out = _flash_latent(merge(q), merge(q_rope), merge(k),
-                        jnp.tile(k_rope, (1, 1, _LANES // dr)), merge(v), h,
+                        jnp.tile(k_rope, (1, 1, hpb)), merge(v), h,
                         sm_scale, causal, block_q, block_k)
     return out.reshape(b, s, h, dv)
 

@@ -112,14 +112,16 @@ def test_flash_attention_merged_layout(one_chip, compiled_kernels,
     assert " transpose(" not in text and " copy(" not in text
 
 
-def _kernel_names(compiled_text: str) -> set:
+def _kernel_names(compiled_text: str, latent: bool = False) -> set:
     """The Pallas kernels of a compiled program by the names a device
     trace shows: each is a custom call to ``tpu_custom_call`` whose
     instruction is named after the kernel (``%flash_bwd_fused.9`` in the
-    train step, ``%transpose_jvp_flash_bwd_dq__.1`` under a bare grad)."""
-    from ray_tpu.ops.flash_attention import KERNEL_NAMES
+    train step, ``%transpose_jvp_flash_bwd_dq__.1`` under a bare grad).
+    ``latent``: among the latent route's names."""
+    from ray_tpu.ops.flash_attention import KERNEL_NAMES, LATENT_KERNEL_NAMES
 
-    longest_first = sorted(KERNEL_NAMES.values(), key=len, reverse=True)
+    names = LATENT_KERNEL_NAMES if latent else KERNEL_NAMES
+    longest_first = sorted(names.values(), key=len, reverse=True)
     out = set()
     for line in compiled_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
@@ -150,14 +152,15 @@ def test_flash_attention_streamed_kernels_are_named(one_chip,
 def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
                                                        compiled_kernels):
     """ISSUE 33: kanana2_train_s8192's attention, B=2, S=8192, 32 heads of
-    128 + 64 against 128, one shared rope key: the three latent kernels at
-    the default blocks of 1024 (they ask for more VMEM than the default
-    scope, which only the chip's compiler checks), fed as the model feeds
-    them, with no copy of a head-shaped array around them."""
+    128 + 64 against 128, one shared rope key, at the default blocks of
+    1024, fed as the model feeds them, with no copy of a head-shaped array
+    around them. ISSUE 34: the forward and ONE backward kernel, under the
+    dk/dv kernel's name; it keeps dq of a head block for the whole
+    sequence in VMEM, 46 MB with the tiles, which only the chip's
+    compiler checks."""
     import re
 
-    from ray_tpu.ops.flash_attention import (LATENT_KERNEL_NAMES,
-                                             flash_attention)
+    from ray_tpu.ops.flash_attention import flash_attention
 
     b, s, h = 2, 8192, 32
     sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
@@ -172,14 +175,33 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sd(b, s, h * 128), sd(b, s, h * 128), sd(b, s, h * 128),
         sd(b, s, h * 64), sd(b, s, 64)).compile().as_text()
-    names = {n for n in LATENT_KERNEL_NAMES.values()
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line
-             and n in line.split(" = ")[0]}
-    assert names == set(LATENT_KERNEL_NAMES.values())
+    assert _kernel_names(text, latent=True) == {"flash_latent_fwd",
+                                                "flash_latent_bwd_dkv"}
     # q_rope, two heads of 64 to a block, is never laid out by head
     assert not re.findall(r"\w+\[2,8192,32,64\]", text)
     assert " transpose(" not in text
+
+
+def test_a_latent_call_that_cannot_fuse_compiles_the_two_kernels(
+        one_chip, compiled_kernels):
+    """ISSUE 34: a non-causal latent call has no diagonal step at which a
+    q block's dq is complete, so it takes the two backward kernels as
+    they were: all three names, at blocks of 1024."""
+    from ray_tpu.ops.flash_attention import (LATENT_KERNEL_NAMES,
+                                             flash_attention)
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, qr, kr):
+        return flash_attention(q, k, v, causal=False, q_rope=qr,
+                               k_rope=kr).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd(1, 2048, 4, 128), sd(1, 2048, 4, 128), sd(1, 2048, 4, 128),
+        sd(1, 2048, 4, 64), sd(1, 2048, 64)).compile().as_text()
+    assert _kernel_names(text, latent=True) == set(
+        LATENT_KERNEL_NAMES.values())
 
 
 def test_held_expert_layer_at_the_benchmark_cells_shape(one_chip,

@@ -119,39 +119,66 @@ def _latent_inputs(b=2, s=256, h=4, dn=128, dr=64, dv=128):
             n(ks[4], (b, s, dr))), n(ks[5], (b, s, h, dv))
 
 
-def _latent_plain(q, k, v, q_rope, k_rope):
+def _latent_plain(q, k, v, q_rope, k_rope, causal=True):
     """The 192-wide key written out for every head."""
     shared = jnp.broadcast_to(k_rope[:, :, None, :],
                               q_rope.shape[:3] + k_rope.shape[-1:])
     qq = jnp.concatenate([q, q_rope], -1)
     return mha_reference(qq, jnp.concatenate([k, shared], -1), v,
-                         causal=True, sm_scale=qq.shape[-1] ** -0.5)
+                         causal=causal, sm_scale=qq.shape[-1] ** -0.5)
+
+
+# case -> (S, blocks, causal, VMEM budget or None for the module's, the
+# backward its shapes select). ISSUE 34: "fused" has diagonal and full
+# block pairs and two head blocks, whose shared-key gradients add up;
+# "split" is the same call with no room for the whole-sequence
+# accumulators; "full" a non-causal call, which never fuses.
+LATENT_CASES = {
+    256: (256, 256, True, None, "fused"),      # one K block a program
+    128: (256, 128, True, None, "fused"),      # streamed
+    "fused": (512, 128, True, None, "fused"),
+    "split": (512, 128, True, 1 << 20, "split"),
+    "full": (256, 128, False, None, "split"),
+}
 
 
 @pytest.fixture(scope="module")
 def latent_grads():
-    args, w = _latent_inputs()
+    import sys
+    fa = sys.modules[flash_attention.__module__]   # the module, not the op
     out = {}
-    for blocks in (256, 128):     # one K block a program; streamed
-        def loss(*a, blocks=blocks):
-            return (flash_attention(a[0], a[1], a[2], causal=True,
+    for case, (s, blocks, causal, vmem, backward) in LATENT_CASES.items():
+        args, w = _latent_inputs(s=s)
+
+        def loss(*a, blocks=blocks, causal=causal, w=w):
+            return (flash_attention(a[0], a[1], a[2], causal=causal,
                                     block_q=blocks, block_k=blocks,
                                     q_rope=a[3], k_rope=a[4]) * w).sum()
-        out[blocks] = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
-    out["plain"] = jax.value_and_grad(
-        lambda *a: (_latent_plain(*a) * w).sum(), argnums=(0, 1, 2, 3, 4))(
-        *args)
+        before = fa.BACKWARD_COUNTS[backward]
+        with pytest.MonkeyPatch.context() as mp:
+            if vmem:
+                mp.setattr(fa, "_LATENT_VMEM_BYTES", vmem)
+            out[case] = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+                *args)
+        assert fa.BACKWARD_COUNTS[backward] == before + 1, case
+        if (s, causal) not in out:
+            out[s, causal] = jax.value_and_grad(
+                lambda *a, causal=causal, w=w: (
+                    _latent_plain(*a, causal=causal) * w).sum(),
+                argnums=(0, 1, 2, 3, 4))(*args)
     return out
 
 
-@pytest.mark.parametrize("blocks", [256, 128])
+@pytest.mark.parametrize("blocks", list(LATENT_CASES))
 @pytest.mark.parametrize("which", ["forward", "dq_nope", "dk_nope", "dv",
                                    "dq_rope", "dk_rope"])
 def test_latent_kernels_against_mha_reference(latent_grads, blocks, which):
     """Head sizes 192 (128 + 64, the 64 one key for all heads) and 128,
     float32 in interpret mode: the sums' order only, values of order 1 to
-    5; 2e-5 absolute."""
-    (val, grads), (want_val, want) = latent_grads[blocks], latent_grads["plain"]
+    5; 2e-5 absolute. Both backwards (``LATENT_CASES``)."""
+    s, _, causal = LATENT_CASES[blocks][:3]
+    (val, grads), (want_val, want) = latent_grads[blocks], \
+        latent_grads[s, causal]
     if which == "forward":
         assert abs(float(val) - float(want_val)) < 2e-5 * abs(float(want_val)) + 2e-4
         return
