@@ -7,8 +7,11 @@ legend); for a TPU-native framework the hot ops are first-party:
 - flash_attention: tiled online-softmax attention on the MXU (Pallas).
 - ring_attention: context-parallel attention over the `sp` mesh axis —
   K/V blocks rotate the ring via ppermute while compute overlaps.
-- layers: rmsnorm/layernorm/gelu/rope/cross-entropy in plain jnp, shaped
-  so XLA fuses them into the adjacent matmuls.
+- ssd_scan: Mamba-2's state-space recurrence as a chunked scan, forward
+  and backward kernels with the state carried in VMEM (Pallas).
+- layers: rmsnorm/layernorm/gelu/rope/cross-entropy, the causal depthwise
+  convolution and the gated norm in plain jnp, shaped so XLA fuses them
+  into the adjacent matmuls.
 - paged_attention: reads and writes of the serving engine's block-pool
   KV cache.
 
@@ -19,7 +22,8 @@ from .attention import mha_reference
 from .flash_attention import flash_attention
 from .ring_attention import ring_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
-                     rope_cache, apply_rope)
+                     rope_cache, apply_rope, causal_conv1d, gated_rmsnorm)
+from .ssd_scan import ssd_scan
 from .paged_attention import (paged_attention_decode,
                               paged_attention_prefill, paged_gather_kv,
                               paged_write_prefill, paged_write_step)
@@ -27,7 +31,7 @@ from .paged_attention import (paged_attention_decode,
 __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
-    "cross_entropy_loss",
+    "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "ssd_scan",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",
     "paged_write_step",

@@ -77,3 +77,29 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
         nll = nll + z_loss * jnp.square(lse)
     mask = (labels != ignore_index).astype(jnp.float32)
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def causal_conv1d(x: jax.Array, weight: jax.Array,
+                  bias: Optional[jax.Array] = None) -> jax.Array:
+    """Depthwise causal convolution over the sequence: x [B, T, C], weight
+    [K, C] (tap K-1 meets the token itself, tap 0 the one K-1 before it;
+    positions before the sequence are zeros), bias [C] -> [B, T, C] in x's
+    dtype, summed in f32. K shifted products in one pass: the padded copy
+    stays in x's dtype and each shifted slice is widened where it is used
+    (widened first, a bf16 x is written and read back as a padded f32 array:
+    1.42 against 0.58 ms forward at [2, 4096, 4352] on a v5e, PR 36)."""
+    taps, t = weight.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = sum(xp[:, k:k + t].astype(jnp.float32) * w[k] for k in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def gated_rmsnorm(y: jax.Array, gate: jax.Array, weight: jax.Array,
+                  eps: float = 1e-6) -> jax.Array:
+    """rmsnorm(y * silu(gate)) * weight over the last axis (Mamba-2's
+    output norm, one group), statistics in f32."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    return rmsnorm(gated.astype(y.dtype), weight, eps)

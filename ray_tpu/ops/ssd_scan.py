@@ -1,0 +1,484 @@
+"""Mamba-2's state-space recurrence (SSD) for TPU: a chunked scan with its
+backward, as two Pallas kernels.
+
+Per head (state h [P, N], one scalar decay a token):
+
+    h_t = exp(dt_t A) h_{t-1} + dt_t x_t (outer) B_t
+    y_t = h_t C_t + D x_t
+
+x [B, T, H, P], dt [B, T, H] (positive: the step after its softplus),
+A [H] (negative), B and C [B, T, G, N] shared by the H / G heads of a
+group, D [H].
+
+Chunked form. With a_t = dt_t A and c_t its cumulative sum INSIDE a chunk
+of Q tokens, for rows t and columns s of one chunk and the state h_0 the
+chunk starts from:
+
+    y_t   = sum_{s<=t} (C_t.B_s) exp(c_t - c_s) dt_s x_s + exp(c_t) h_0 C_t
+    h_end = exp(c_Q) h_0 + sum_s exp(c_Q - c_s) dt_s x_s (outer) B_s
+
+Every exponent is a difference of cumulative sums with t >= s, so it is
+<= 0: nothing overflows however strong the decay (a factorised
+exp(c_t) exp(-c_s) would). Chunking is not part of the mathematics; any
+chunk gives the recurrence's numbers up to rounding.
+
+The routes, chosen by what a call shows (``PATH_COUNTS``, the event
+``rtpu.ops.ssd.path``; no argument or configuration selects one):
+
+* ``kernel``: heads of 64 or 128 whose merged width H*P is a multiple of
+  128, one group, a state that is a multiple of 128, a chunk that is a
+  multiple of 128 and divides T. The kernels index the model's merged
+  [B, T, H*P] arrays (two heads of 64 to a 128-lane tile, each worked on
+  the full tile with the other's lanes zeroed, as ``flash_attention``
+  does), grid (batch, chunk, head block), the chunks in order and the
+  head blocks inside a chunk: the state of every head stays in ONE float32
+  VMEM scratch [H*P, N] from chunk to chunk (2 MB at 64 heads of 64 x
+  128), the group's C B^T [Q, Q] is made once a chunk and shared by its
+  head blocks, and each head's decay matrix exp(c_t - c_s) [Q, Q] exists
+  only in VMEM. The forward also writes the state each chunk starts from
+  ([B, T/Q, H*P, N] float32); the backward walks the chunks in reverse,
+  carries the state's gradient in the same kind of scratch and
+  accumulates dB and dC over the head blocks in their resident output
+  block. No array of shape [.., chunks, heads, Q, Q] reaches HBM, forward
+  or backward (tests/test_chip_compile.py holds that).
+* ``reference``: every other shape (several groups, T no multiple of the
+  chunk, a chunk of 1, heads of 32): the same chunked form in plain
+  ``jnp``, differentiated by jax; T is padded to whole chunks with dt = 0
+  (a step that neither decays nor writes the state).
+
+Precision: matrix products take their operands in x's dtype (bf16 in a
+model) and accumulate in float32; dt, the cumulative sums, every decay
+and the state are float32 throughout.
+"""
+from __future__ import annotations
+
+import collections
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from ..perf.recorder import record as _record
+from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot, _head_lanes
+
+# The module, not the function of its name that the package exports: the
+# kernels here run interpreted where the flash kernels do, by the one
+# switch (``_use_interpret``) a described-chip compile steers.
+_flash = importlib.import_module(__package__ + ".flash_attention")
+
+# The names of the two kernels, as a device trace and the compiled HLO
+# show them (``name=`` on ``pl.pallas_call``). Part of the measurement:
+# pinned in tests/test_tracing_names.py; the benchmark's
+# ``ssd_scan_roofline`` finds the kernels' time by them.
+KERNEL_NAMES = {
+    "fwd": "ssd_chunk_fwd",     # y and the state each chunk starts from
+    "bwd": "ssd_chunk_bwd",     # dx, d(dt), d(cumulative sum), dB, dC
+}
+
+# Traced calls of ssd_scan by the route each took ("kernel", "reference");
+# the same choice is the flight-recorder event ``rtpu.ops.ssd.path``.
+PATH_COUNTS: collections.Counter = collections.Counter()
+
+_MAX_HEADS_PER_BLOCK = 16     # 8 tiles of two heads unrolled in a program
+_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _heads_per_block(heads: int, p: int) -> int:
+    """Heads a program works, or 0 where the merged layout cannot be cut:
+    whole 128-lane tiles of [.., H*P], and rows of the [H, T] arrays of dt
+    that tile the sublanes (a multiple of 8, or all of them)."""
+    if p not in (64, _LANES) or (heads * p) % _LANES:
+        return 0
+    per_tile = _LANES // p
+    for hpb in range(min(heads, _MAX_HEADS_PER_BLOCK), 0, -1):
+        if heads % hpb == 0 and hpb % per_tile == 0 \
+                and (hpb % 8 == 0 or hpb == heads):
+            return hpb
+    return 0
+
+
+# ---------------------------------------------------------------------------
+# what both kernels share
+# ---------------------------------------------------------------------------
+
+
+def _group_scores(c, b):
+    """C B^T of one chunk [Q, Q] in f32, zero above the diagonal."""
+    g = _dot(c, b, _ABT)
+    rows = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    return jnp.where(rows >= cols, g, 0.0)
+
+
+def _head_vectors(dt_ref, cum_ref, k: int):
+    """Head k of the block: (cumulative sum as a row [1, Q], as a column
+    [Q, 1], dt as a column, the chunk's last cumulative sum [1, 1])."""
+    cr = cum_ref[k:k + 1, :]
+    cc = cr.T
+    # the last entry by a masked sum: a [1, 1] cut from lane Q-1 of the
+    # row is a layout Mosaic cannot broadcast down a column
+    last = jax.lax.broadcasted_iota(jnp.int32, cc.shape, 0) == cc.shape[0] - 1
+    cq = jnp.sum(jnp.where(last, cc, 0.0), axis=0, keepdims=True)
+    return cr, cc, dt_ref[k:k + 1, :].T, cq
+
+
+def _decay(cc, cr):
+    """exp(c_t - c_s) [Q, Q]; the exponent is <= 0 wherever t >= s, and
+    the rest is thrown away by the zeros of ``_group_scores``."""
+    return jnp.exp(jnp.minimum(cc - cr, 0.0))
+
+
+def _by_lanes(cols, p: int):
+    """One [Q, 1] column a head of a 128-lane tile -> [Q, 128], each
+    head's P lanes holding its column."""
+    shape = (cols[0].shape[0], _LANES)
+    out = jnp.broadcast_to(cols[0], shape)
+    if len(cols) == 2:
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        out = jnp.where(lane < p, out, jnp.broadcast_to(cols[1], shape))
+    return out
+
+
+def _by_rows(vals, p: int, n: int):
+    """One [1, 1] value a head of a tile -> [128, n], each head's P rows
+    of the tile's state holding its value."""
+    shape = (_LANES, n)
+    out = jnp.broadcast_to(vals[0], shape)
+    if len(vals) == 2:
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        out = jnp.where(row < p, out, jnp.broadcast_to(vals[1], shape))
+    return out
+
+
+def _head_sum(x, j: int, p: int):
+    """Row sums over head j's lanes of a [Q, 128] tile -> [Q, 1]."""
+    return jnp.sum(_head_lanes(x, j, p), axis=1, keepdims=True)
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
+                h_scr, g_scr, *, p: int):
+    """Grid (B, chunks, head blocks), head blocks innermost. ``h_scr``
+    [head blocks, W, N] f32 is every head's state, carried over the
+    chunks; ``g_scr`` the chunk's C B^T."""
+    ci, hb = pl.program_id(1), pl.program_id(2)
+    per_tile = _LANES // p
+    dtype = x_ref.dtype
+
+    @pl.when(ci == 0)
+    def _first_chunk():
+        h_scr[hb] = jnp.zeros(h_scr.shape[1:], h_scr.dtype)
+
+    @pl.when(hb == 0)
+    def _first_block():
+        g_scr[...] = _group_scores(c_ref[...], b_ref[...])
+
+    st_ref[...] = h_scr[hb]
+    g = g_scr[...]
+    bm, cm = b_ref[...], c_ref[...]
+    for i in range(x_ref.shape[1] // _LANES):
+        lanes = pl.ds(i * _LANES, _LANES)
+        xt = x_ref[:, lanes]
+        h0 = h_scr[hb, lanes, :]
+        y, into, carry, last = None, [], [], []
+        for j in range(per_tile):
+            cr, cc, dc, cq = _head_vectors(dt_ref, cum_ref, i * per_tile + j)
+            xd = (_head_lanes(xt, j, p).astype(jnp.float32) * dc).astype(dtype)
+            part = _dot((g * _decay(cc, cr)).astype(dtype), xd, _AB)
+            y = part if y is None else y + part
+            into.append(jnp.exp(cc))
+            carry.append(jnp.exp(cq - cc) * dc)
+            last.append(jnp.exp(cq))
+        y = y + _by_lanes(into, p) * _dot(cm, h0.astype(dtype), _ABT)
+        xw = (xt.astype(jnp.float32) * _by_lanes(carry, p)).astype(dtype)
+        h_scr[hb, lanes, :] = _by_rows(last, p, h0.shape[1]) * h0 \
+            + _dot(xw, bm, _ATB)
+        y_ref[:, lanes] = y.astype(y_ref.dtype)
+
+
+def _specs(b, t, h, p, n, chunk, hpb, reverse: bool):
+    """Block specs of the merged arrays on the grid (B, chunks, head
+    blocks); ``reverse`` walks the chunks from the last to the first."""
+    nc = t // chunk
+    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+    w = hpb * p
+    return {
+        "x": pl.BlockSpec((None, chunk, w), lambda b, c, k: (b, at(c), k)),
+        "dt": pl.BlockSpec((None, hpb, chunk),
+                           lambda b, c, k: (b, k, at(c))),
+        "bc": pl.BlockSpec((None, chunk, n), lambda b, c, k: (b, at(c), 0)),
+        "state": pl.BlockSpec((None, None, w, n),
+                              lambda b, c, k: (b, at(c), k, 0)),
+    }
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_BYTES)
+
+
+def _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
+    """x [B, T, H*P], dt and its cumulative sum [B, H, T] f32, B and C
+    [B, T, N] -> (y [B, T, H*P], states [B, T/Q, H*P, N] f32: the state
+    each chunk starts from)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hp = x.shape
+    h, n, nc = hp // p, bm.shape[-1], t // chunk
+    s = _specs(b, t, h, p, n, chunk, hpb, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, p=p),
+        grid=(b, nc, h // hpb),
+        in_specs=[s["x"], s["dt"], s["dt"], s["bc"], s["bc"]],
+        out_specs=[s["x"], s["state"]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((b, nc, hp, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h // hpb, hpb * p, n), jnp.float32),
+                        pltpu.VMEM((chunk, chunk), jnp.float32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["fwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * (h * p * (chunk + 2 * n) + n * chunk),
+            bytes_accessed=2 * x.size * x.dtype.itemsize + 4 * b * nc * hp * n,
+            transcendentals=b * t * h * chunk),
+    )(x, dt_t, cum_t, bm, cm)
+
+
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
+                dx_ref, ddt_ref, dcum_ref, db_ref, dc_ref,
+                dh_scr, g_scr, dg_scr, *, p: int):
+    """Grid (B, chunks from the last, head blocks). ``dh_scr`` is the
+    gradient of the state the chunk ENDS in, carried back over the chunks;
+    ``dg_scr`` the gradient of the chunk's C B^T, summed over its heads;
+    dB and dC accumulate in their output block, which holds still over the
+    head blocks. d(dt) here is through dt's own uses only; what reaches dt
+    and A through the cumulative sum leaves as d(cumulative sum)."""
+    ci, hb = pl.program_id(1), pl.program_id(2)
+    per_tile = _LANES // p
+    dtype = x_ref.dtype
+    q = x_ref.shape[0]
+
+    @pl.when(ci == 0)
+    def _last_chunk():
+        dh_scr[hb] = jnp.zeros(dh_scr.shape[1:], dh_scr.dtype)
+
+    @pl.when(hb == 0)
+    def _first_block():
+        g_scr[...] = _group_scores(c_ref[...], b_ref[...])
+        dg_scr[...] = jnp.zeros_like(dg_scr)
+        db_ref[...] = jnp.zeros_like(db_ref)
+        dc_ref[...] = jnp.zeros_like(dc_ref)
+
+    g = g_scr[...]
+    bm, cm = b_ref[...], c_ref[...]
+    end = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    dg = None
+    for i in range(x_ref.shape[1] // _LANES):
+        lanes = pl.ds(i * _LANES, _LANES)
+        xt, dyt = x_ref[:, lanes], dy_ref[:, lanes]
+        xf, dyf = xt.astype(jnp.float32), dyt.astype(jnp.float32)
+        h0, dh = st_ref[lanes, :], dh_scr[hb, lanes, :]
+        dxd, pairs, into, carry, step, last = None, [], [], [], [], []
+        for j in range(per_tile):
+            cr, cc, dc, cq = _head_vectors(dt_ref, cum_ref, i * per_tile + j)
+            decay = _decay(cc, cr)
+            dyj = _head_lanes(dyt, j, p)
+            xd = (_head_lanes(xf, j, p) * dc).astype(dtype)
+            u = _dot(dyj, xd, _ABT) * decay              # d(C B^T), unmasked
+            dg = u if dg is None else dg + u
+            w = u * g                                    # d(exponent) [t, s]
+            pairs.append(jnp.sum(w, axis=1, keepdims=True)
+                         - jnp.sum(w, axis=0, keepdims=True).T)
+            part = _dot((g * decay).astype(dtype), dyj, _ATB)
+            dxd = part if dxd is None else dxd + part
+            into.append(jnp.exp(cc))
+            carry.append(jnp.exp(cq - cc))
+            step.append(dc)
+            last.append(jnp.exp(cq))
+        ec, sw, dcl = _by_lanes(into, p), _by_lanes(carry, p), \
+            _by_lanes(step, p)
+        eq = _by_rows(last, p, h0.shape[1])
+        h0m, dhm = h0.astype(dtype), dh.astype(dtype)
+        e = dyf * ec
+        em = e.astype(dtype)
+        xw = xf * sw * dcl
+        dc_ref[...] += _dot(em, h0m, _AB)
+        db_ref[...] += _dot(xw.astype(dtype), dhm, _AB)
+        dxw = _dot(bm, dhm, _ABT)
+        dh_scr[hb, lanes, :] = _dot(em, cm, _ATB) + eq * dh
+        dx_ref[:, lanes] = ((dxd + dxw * sw) * dcl).astype(dx_ref.dtype)
+        through = e * _dot(cm, h0m, _ABT)     # dy . (what the state gave y)
+        written = dxw * xw                    # d(h_end) . (what s wrote)
+        dstep = (dxd + dxw * sw) * xf
+        kept = dh * eq * h0                   # d(h_end) . (what was kept)
+        row = jax.lax.broadcasted_iota(jnp.int32, kept.shape, 0)
+        for j in range(per_tile):
+            k = i * per_tile + j
+            wr = _head_sum(written, j, p)
+            mine = kept if per_tile == 1 else jnp.where(
+                (row >= j * p) & (row < (j + 1) * p), kept, 0.0)
+            dcq = jnp.sum(wr, axis=0, keepdims=True) + jnp.sum(
+                jnp.sum(mine, axis=1, keepdims=True), axis=0, keepdims=True)
+            dcum = pairs[j] + _head_sum(through, j, p) - wr \
+                + jnp.where(end, dcq, 0.0)
+            dcum_ref[k:k + 1, :] = dcum.T
+            ddt_ref[k:k + 1, :] = _head_sum(dstep, j, p).T
+    dg_scr[...] += dg
+
+    @pl.when(hb == pl.num_programs(2) - 1)
+    def _last_block():
+        rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+        dgm = jnp.where(rows >= cols, dg_scr[...], 0.0).astype(dtype)
+        dc_ref[...] += _dot(dgm, bm, _AB)
+        db_ref[...] += _dot(dgm, cm, _ATB)
+
+
+def _ssd_bwd(x, dy, dt_t, cum_t, bm, cm, states, p, chunk, hpb):
+    """-> dx [B, T, H*P], d(dt) and d(cumulative sum) [B, H, T] f32, dB
+    and dC [B, T, N] f32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hp = x.shape
+    h, n, nc = hp // p, bm.shape[-1], t // chunk
+    s = _specs(b, t, h, p, n, chunk, hpb, reverse=True)
+    rows = jax.ShapeDtypeStruct(dt_t.shape, jnp.float32)
+    shared = jax.ShapeDtypeStruct(bm.shape, jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, p=p),
+        grid=(b, nc, h // hpb),
+        in_specs=[s["x"], s["x"], s["dt"], s["dt"], s["bc"], s["bc"],
+                  s["state"]],
+        out_specs=[s["x"], s["dt"], s["dt"], s["bc"], s["bc"]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), rows, rows,
+                   shared, shared],
+        scratch_shapes=[pltpu.VMEM((h // hpb, hpb * p, n), jnp.float32),
+                        pltpu.VMEM((chunk, chunk), jnp.float32),
+                        pltpu.VMEM((chunk, chunk), jnp.float32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["bwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * (h * p * (2 * chunk + 5 * n) + 3 * n * chunk),
+            bytes_accessed=3 * x.size * x.dtype.itemsize + 4 * b * nc * hp * n,
+            transcendentals=b * t * h * chunk),
+    )(x, dy, dt_t, cum_t, bm, cm, states)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _ssd_kernels(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
+    return _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb)[0]
+
+
+def _ssd_vjp_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
+    y, states = _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb)
+    return y, (x, dt_t, cum_t, bm, cm, states)
+
+
+def _ssd_vjp_bwd(p, chunk, hpb, res, dy):
+    x, dt_t, cum_t, bm, cm, states = res
+    dx, ddt, dcum, db, dc = _ssd_bwd(x, dy, dt_t, cum_t, bm, cm, states, p,
+                                     chunk, hpb)
+    return dx, ddt, dcum, db.astype(bm.dtype), dc.astype(cm.dtype)
+
+
+_ssd_kernels.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the plain route
+# ---------------------------------------------------------------------------
+
+
+def _ssd_chunked(x, dt, a, bm, cm, chunk: int):
+    """The chunked form in plain ``jnp`` for any shape: x [B, T, H, P], dt
+    and a = dt A [B, T, H] f32, B and C [B, T, G, N] -> y [B, T, H, P].
+    Holds [B, chunks, Q, Q, H] arrays: small shapes only."""
+    b, t, h, p = x.shape
+    g, n = bm.shape[2:]
+    pad = -t % chunk
+    if pad:
+        x, dt, a, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
+                                    * (v.ndim - 2))
+                            for v in (x, dt, a, bm, cm))
+    nc = (t + pad) // chunk
+    cut = lambda v: v.reshape((b, nc, chunk) + v.shape[2:])  # noqa: E731
+    x, dt, a, bm, cm = map(cut, (x, dt, a, bm, cm))
+    ein = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    cum = jnp.cumsum(a, axis=2)                              # [b, c, Q, h]
+    seen = jnp.tril(jnp.ones((chunk, chunk), bool))[None, None, :, :, None]
+    decay = jnp.where(seen, jnp.exp(jnp.minimum(
+        cum[:, :, :, None] - cum[:, :, None], 0.0)), 0.0)    # [b,c,t,s,h]
+    scores = jnp.repeat(ein("bctgn,bcsgn->bctsg", cm, bm), h // g, axis=-1)
+    xd = (x.astype(jnp.float32) * dt[..., None]).astype(x.dtype)
+    y = ein("bctsh,bcshp->bcthp", (scores * decay).astype(x.dtype), xd)
+    xw = (xd.astype(jnp.float32) * jnp.exp(cum[:, :, -1:] - cum)[..., None]
+          ).astype(x.dtype)
+    heads = lambda v: jnp.repeat(v, h // g, axis=3)          # noqa: E731
+    wrote = ein("bcshp,bcshn->bchpn", xw, heads(bm))         # [b,c,h,p,n]
+    keep = jnp.exp(cum[:, :, -1])                            # [b, c, h]
+
+    def chunk_step(state, c):
+        kept, added = c
+        return kept[..., None, None] * state + added, state
+
+    _, starts = jax.lax.scan(
+        chunk_step, jnp.zeros((b, h, p, n), jnp.float32),
+        (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(wrote, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)                      # [b,c,h,p,n]
+    y = y + jnp.exp(cum)[..., None] * ein(
+        "bcthn,bchpn->bcthp", heads(cm), starts.astype(x.dtype))
+    return y.reshape(b, nc * chunk, h, p)[:, :t].astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the call
+# ---------------------------------------------------------------------------
+
+
+def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+             C: jax.Array, D: jax.Array, chunk: int = 256) -> jax.Array:
+    """Mamba-2's recurrence. x [batch, seq, heads, head_dim], dt [batch,
+    seq, heads] (positive), A [heads] (negative), B and C [batch, seq,
+    groups, state], D [heads] -> y of x's shape and dtype. Differentiable
+    in all six. ``chunk`` is how the work is cut, not what is computed."""
+    b, t, h, p = x.shape
+    g, n = B.shape[2:]
+    if h % g:
+        raise ValueError(f"{h} heads do not divide into {g} groups")
+    chunk = min(chunk, t)
+    dt = dt.astype(jnp.float32)
+    a = dt * A.astype(jnp.float32)
+    hpb = _heads_per_block(h, p)
+    kernel = bool(hpb) and g == 1 and n % _LANES == 0 \
+        and chunk % _LANES == 0 and t % chunk == 0
+    route = "kernel" if kernel else "reference"
+    PATH_COUNTS[route] += 1
+    _record("rtpu.ops.ssd.path", route,
+            {"route": route, "chunk": chunk, "heads": h, "head_dim": p,
+             "state": n, "groups": g, "chunks": -(-t // chunk)})
+    if kernel:
+        cum = jnp.cumsum(a.reshape(b, t // chunk, chunk, h), axis=2)
+        rows = lambda v: jnp.swapaxes(v.reshape(b, t, h), 1, 2)  # noqa: E731
+        y = _ssd_kernels(x.reshape(b, t, h * p), rows(dt), rows(cum),
+                         B.reshape(b, t, n), C.reshape(b, t, n), p, chunk,
+                         hpb).reshape(b, t, h, p)
+    else:
+        y = _ssd_chunked(x, dt, a, B, C, chunk)
+    skip = x.astype(jnp.float32) * D.astype(jnp.float32)[:, None]
+    return (y.astype(jnp.float32) + skip).astype(x.dtype)

@@ -233,6 +233,41 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(one_chip,
     assert " scatter(" not in text
 
 
+def test_ssd_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
+    """ISSUE 36: granite4h_train_s4096's state-space scan, B=2, S=4096, 64
+    heads of 64 with a state of 128, one group, chunks of 256, fed as the
+    model feeds it ([B, S, H*P] reshaped): forward and backward are the
+    two kernels by name, and NO array with two chunk-long dimensions (a
+    [.., chunks, heads, 256, 256] decay or score matrix: 268 MB a layer in
+    float32) is anywhere in the compiled program: they live in VMEM."""
+    import re
+
+    ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
+    b, t, h, p, n, chunk = 2, 4096, 64, 64, 128, 256
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def loss(x, dt, a, bm, cm, d):
+        return ssd.ssd_scan(x.reshape(b, t, h, p), dt, a, bm[:, :, None],
+                            cm[:, :, None], d, chunk=chunk).astype(
+            jnp.float32).sum()
+
+    before = ssd.PATH_COUNTS["kernel"]
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        sd((b, t, h * p)), sd((b, t, h), jnp.float32), sd((h,), jnp.float32),
+        sd((b, t, n)), sd((b, t, n)), sd((h,), jnp.float32)
+    ).compile().as_text()
+    assert ssd.PATH_COUNTS["kernel"] == before + 1
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert any(ssd.KERNEL_NAMES["fwd"] in c for c in calls)
+    assert any(ssd.KERNEL_NAMES["bwd"] in c for c in calls)
+    assert not re.findall(r"\w+\[[\d,]*%d,%d[\d,]*\]" % (chunk, chunk), text)
+    # nor is x laid out by head: two heads of 64 share a 128-lane tile
+    assert not re.findall(r"\w+\[2,4096,64,64\]", text)
+
+
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
     """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
     B=8, S=1024, params and optimizer state donated."""

@@ -11,11 +11,13 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
-                            Llama, LlamaConfig)
+                            GraniteHybrid, GraniteHybridConfig, Llama,
+                            LlamaConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
 el = importlib.import_module("ray_tpu.ops.expert_layer")
+ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -151,6 +153,26 @@ def test_grouped_matmul_kernel_names_are_pinned(key, name):
     assert re.search(r"[/\"(]" + name + r"[/\")]", text)
 
 
+@pytest.mark.parametrize("key,name", [("fwd", "ssd_chunk_fwd"),
+                                      ("bwd", "ssd_chunk_bwd")])
+def test_ssd_scan_kernel_names_are_pinned(key, name):
+    """ISSUE 36: ``ssd_scan_roofline`` finds its kernels by these."""
+    assert ssd.KERNEL_NAMES[key] == name
+    x = jnp.zeros((1, 256, 2, 64), jnp.bfloat16)
+    bc = jnp.zeros((1, 256, 1, 128), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda x, dt, bm, cm: ssd.ssd_scan(
+        x, dt, -jnp.ones((2,)), bm, cm, jnp.ones((2,)), chunk=128).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2, 3))).lower(
+        x, jnp.ones((1, 256, 2)), bc, bc).as_text(debug_info=True)
+    pattern = r"[/\"(]" + name + r"[/\")]"
+    assert re.search(pattern, text)
+    # a call whose shapes do not tile holds neither kernel
+    plain = jax.jit(lambda x, dt, bm, cm: ssd.ssd_scan(
+        x, dt, -jnp.ones((2,)), bm, cm, jnp.ones((2,)), chunk=96)).lower(
+        x, jnp.ones((1, 256, 2)), bc, bc).as_text(debug_info=True)
+    assert not re.search(pattern, plain)
+
+
 def test_the_expert_layer_and_the_latent_route_leave_their_events():
     """ISSUE 33: ``rtpu.ops.expert_layer`` at trace time (experts held, of
     how many, top k, the row buffer) and ``rtpu.ops.flash.path`` with the
@@ -188,6 +210,7 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
 MODELS = {
     "deepseek_v3": lambda: DeepseekV3(DeepseekV3Config.tiny(experts_held=4)),
     "gpt": lambda: GPT(GPTConfig.tiny()),
+    "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -209,7 +232,8 @@ def lowered_losses():
 @pytest.mark.parametrize("scope,model", [
     (s, m) for m in sorted(MODELS)
     for s in ("embed", "attn", "mlp", "lm_head", "loss")
-    + (("router", "experts", "shared_expert") if m == "deepseek_v3" else ())])
+    + (("router", "experts", "shared_expert") if m == "deepseek_v3" else ())
+    + (("mixer", "conv", "scan") if m == "granite_hybrid" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
     names = lowered_losses[model]
