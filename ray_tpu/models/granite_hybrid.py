@@ -40,16 +40,33 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (causal_conv1d, cross_entropy_loss, flash_attention,
                    gated_rmsnorm, rmsnorm, ssd_scan)
 from ..perf.recorder import record as _record
 
-# What a rematerialised layer keeps for its backward beside its input: the
-# flash kernels' output and row statistics, so that the attention layer's
-# backward does not run the forward kernel again. A state-space layer
-# keeps nothing: at 8192 tokens a step its activations are 0.5 GB a layer.
+# What a rematerialised layer keeps for its backward beside its input, by
+# ``checkpoint_name``. Every layer: the flash kernels' output and row
+# statistics, so that the attention layer's backward does not run the
+# forward kernel again. Every run but the FIRST: the gated MLP's two input
+# products too (``mlp_gate``, ``mlp_up``: 268 MB a layer at 8192 tokens of
+# ``d_ff`` 8192 in bf16), so that the backward does not run the layer's two
+# largest matmuls a second time. The backward walks the runs last to first:
+# what a later run keeps is freed before the step's peak, which is in the
+# first run's backward beside every gradient made by then. Asked of the
+# compiler for a described v5e (the benchmark's step: runs of 5, 1, 4, batch
+# 2 x 4096; PERF.md, PR 37): 1.34 GB kept in the runs of 1 and 4 costs 61 MB
+# of temporaries (8.65 GB) and nothing is made again. With the first run
+# keeping them too the step is still accepted (10.86 GB) but only because
+# the compiler's own rematerialisation has made it fit: the head's logits
+# and the mixers' products made again, 49.8 T matmul operations a step
+# where nothing kept is 47.4 T and this is 44.7 T, and 4 % slower on the
+# chip than nothing kept. The next supersets: + the mixers' ``w_z`` product
+# 11.82 GB, + ``w_xbc`` refused ("Used 16.25G of 15.75G hbm"); batch 3 with
+# every layer keeping both refused ("Used 17.15G").
 _REMAT_SAVE = ("flash_out", "flash_lse")
+_REMAT_SAVE_LATER_RUNS = _REMAT_SAVE + ("mlp_gate", "mlp_up")
 
 _PUBLISHED_LAYER_TYPES = tuple(
     "attention" if i % 10 == 5 else "mamba" for i in range(40))
@@ -265,25 +282,34 @@ class GraniteHybrid:
              else self._attention_mixer)(x, lp)
         with jax.named_scope("mlp"):
             xn = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
-            hid = jax.nn.silu(xn @ lp["w_gate"].astype(c.dtype)) \
-                * (xn @ lp["w_up"].astype(c.dtype))
+            gate = checkpoint_name(xn @ lp["w_gate"].astype(c.dtype),
+                                   "mlp_gate")
+            up = checkpoint_name(xn @ lp["w_up"].astype(c.dtype), "mlp_up")
             return x + c.residual_multiplier * (
-                hid @ lp["w_down"].astype(c.dtype))
+                (jax.nn.silu(gate) * up) @ lp["w_down"].astype(c.dtype))
 
     def _run_layers(self, x, params):
         """The one place the stack is walked: run after run of like
         layers, each layer rematerialised; a run of several is one scanned
         body over its stacked parameters, a run of one a plain call."""
-        policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_SAVE)
+        c = self.config
+        kept = [_REMAT_SAVE if i == 0 else _REMAT_SAVE_LATER_RUNS
+                for i in range(len(self.runs))]
+        products = 2 * math.prod(x.shape[:-1]) * c.d_ff \
+            * jnp.dtype(c.dtype).itemsize        # mlp_gate and mlp_up
         _record("rtpu.models.stack.runs", "granite_hybrid",
-                {"runs": [[kind, n] for kind, n in self.runs]})
+                {"runs": [[kind, n] for kind, n in self.runs],
+                 "kept": [list(names) for names in kept],
+                 "kept_bytes_per_layer": products,
+                 "kept_bytes": products * sum(n for _, n in self.runs[1:])})
         for i, (kind, n) in enumerate(self.runs):
             prefix = f"{i}.{kind}."
             lp = {name[len(prefix):]: v for name, v in params.items()
                   if name.startswith(prefix)}
             body = jax.checkpoint(
                 lambda h, p, kind=kind: self._block(kind, h, p),
-                policy=policy)
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *kept[i]))
             if n == 1:
                 x = body(x, {name: v[0] for name, v in lp.items()})
             else:

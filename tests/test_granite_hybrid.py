@@ -136,25 +136,152 @@ def test_the_published_period_is_runs_of_5_1_4():
         GraniteHybridConfig.tiny(layer_types=("mamba", "conv"))
 
 
+def _stack_event(model, batch, seq):
+    """The newest ``rtpu.models.stack.runs`` of a trace of ``model.loss``
+    from shapes."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        toks = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        jax.eval_shape(model.loss, jax.eval_shape(
+            model.init, jax.random.PRNGKey(0)), toks, toks)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    return [e for e in events if e["kind"] == "rtpu.models.stack.runs"][-1]
+
+
 def test_the_traced_runs_leave_their_event(tiny):
     """``rtpu.models.stack.runs`` at trace time: the run lengths and
     kinds the model walked; both state-space bodies take the kernels."""
     from ray_tpu.ops.ssd_scan import PATH_COUNTS
-    from ray_tpu.perf.recorder import get_recorder
 
-    model, params, toks = tiny.model, tiny.params, tiny.toks
-    rec = get_recorder()
-    was, rec.enabled = rec.enabled, True
     before = PATH_COUNTS["kernel"]
-    try:
-        jax.jit(model.loss).lower(params, toks, toks)
-        events = rec.snapshot()
-    finally:
-        rec.enabled = was
-    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"][-1]
-    assert runs["data"] == {"runs": [["mamba", 2], ["attention", 1],
-                                     ["mamba", 1]]}
+    runs = _stack_event(tiny.model, 2, 128)
+    assert runs["data"]["runs"] == [["mamba", 2], ["attention", 1],
+                                    ["mamba", 1]]
     assert PATH_COUNTS["kernel"] == before + 2     # one a run, not a layer
+
+
+def test_the_traced_runs_say_what_each_run_keeps(tiny):
+    """ISSUE 37: the event names what the layers of each run keep (the
+    first run the flash kernels' output only, later runs the MLP's two
+    products too) and their bytes from the traced shapes: 2 x B x S x d_ff
+    x itemsize a layer; at the benchmark cell's shape (batch 2 of 4096,
+    8192 wide, bf16) 268 435 456, 1.34 GB over the five layers of the runs
+    of 1 and 4."""
+    flash, both = ["flash_out", "flash_lse"], ["flash_out", "flash_lse",
+                                               "mlp_gate", "mlp_up"]
+    data = _stack_event(tiny.model, 2, 128)["data"]
+    assert data["kept"] == [flash, both, both]
+    assert data["kept_bytes_per_layer"] == 2 * 2 * 128 * 128 * 4
+    assert data["kept_bytes"] == 2 * data["kept_bytes_per_layer"]
+    cell = GraniteHybrid(GraniteHybridConfig.granite4_h_micro(
+        n_layer=10, vocab_size=12544))
+    data = _stack_event(cell, 2, 4096)["data"]
+    assert data["runs"] == [["mamba", 5], ["attention", 1], ["mamba", 4]]
+    assert data["kept"] == [flash, both, both]
+    assert data["kept_bytes_per_layer"] == 268_435_456
+    assert data["kept_bytes"] == 1_342_177_280
+
+
+_WITHOUT_THE_PRODUCTS = ("flash_out", "flash_lse")    # what PR 36 kept
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_keeping_the_mlp_products_changes_no_number(monkeypatch, dtype):
+    """ISSUE 37: a kept product is the array the recomputation would have
+    made again, so the loss and every gradient are those of the same
+    model with the two names taken out of ``_REMAT_SAVE_LATER_RUNS``
+    (monkeypatched: every run keeps what the first does): equal in EVERY
+    element, in float32 and in bf16 (read here, CPU). In bf16 that holds
+    with ``xla_allow_excess_precision`` off, as compiled here; XLA's
+    default lets a fusion skip the rounding between the recomputed
+    product and the silu behind it, which a kept bf16 array cannot, and
+    then the losses are still one float and the gradients up to 1.7 % of
+    a parameter's largest entry apart (``dt_bias`` of run 0): the
+    compiler's licence, not another mathematics."""
+    import ray_tpu.models.granite_hybrid as gh
+
+    model = GraniteHybrid(GraniteHybridConfig.tiny(
+        dtype=jnp.dtype(dtype), init_std=0.2))
+    params = model.init(jax.random.PRNGKey(0))
+    toks = _tokens(model.config.vocab_size)
+    args = (params, toks, jnp.roll(toks, -1, 1))
+
+    def read():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(model.loss)).lower(
+                *args).compile(compiler_options={
+                    "xla_allow_excess_precision": False})(*args)
+
+    assert gh._REMAT_SAVE == _WITHOUT_THE_PRODUCTS
+    assert set(gh._REMAT_SAVE_LATER_RUNS) - set(_WITHOUT_THE_PRODUCTS) == {
+        "mlp_gate", "mlp_up"}
+    loss, grads = read()
+    monkeypatch.setattr(gh, "_REMAT_SAVE_LATER_RUNS", _WITHOUT_THE_PRODUCTS)
+    loss_without, grads_without = read()
+    assert float(loss) == float(loss_without)
+    for name in params:
+        assert np.array_equal(np.asarray(grads[name], np.float32),
+                              np.asarray(grads_without[name], np.float32),
+                              equal_nan=False), name
+
+
+def _dot_outputs(jaxpr, times=1, out=None):
+    """Output shape -> how many ``dot_general``s of a jaxpr make it, those
+    of inner jaxprs included, a scan's counted by its length."""
+    import collections
+
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out[tuple(eqn.outvars[0].aval.shape)] += times
+        inner = times * (eqn.params["length"]
+                         if eqn.primitive.name == "scan" else 1)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dot_outputs(sub, inner, out)
+    return out
+
+
+def test_the_backward_does_not_make_the_mlp_products_again(monkeypatch):
+    """ISSUE 37, in the jaxpr of the gradient of a small period (runs of
+    2, 1 and 1; ``d_ff`` 192 so that no other product is as wide): the
+    products with output [B, S, d_ff] are 3 a layer of the later runs
+    (gate, up, and the hidden's gradient through ``w_down``) and 5 a layer
+    of the first run and of every run with the two names taken out (gate
+    and up made again), and every other product's count, the mixers'
+    in-projections and q, k, v among them, is what it was: nothing else
+    is kept."""
+    import ray_tpu.models.granite_hybrid as gh
+
+    model = GraniteHybrid(GraniteHybridConfig.tiny(d_ff=192, **F32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+
+    def count():
+        return _dot_outputs(jax.make_jaxpr(jax.grad(model.loss))(
+            params, toks, toks).jaxpr)
+
+    kept = count()
+    monkeypatch.setattr(gh, "_REMAT_SAVE_LATER_RUNS", _WITHOUT_THE_PRODUCTS)
+    without = count()
+    wide = (2, 128, 192)
+    first, later = model.runs[0][1], sum(n for _, n in model.runs[1:])
+    assert (first, later) == (2, 2)
+    assert kept[wide] == 5 * first + 3 * later
+    assert without[wide] == 5 * (first + later)
+    c = model.config
+    for width in (c.d_inner, c.d_conv_channels, c.n_head * c.head_dim,
+                  c.n_kv_head * c.head_dim, c.d_model):
+        assert kept[(2, 128, width)] > 0
+    del kept[wide], without[wide]
+    assert kept == without
 
 
 @pytest.mark.parametrize("field", [
