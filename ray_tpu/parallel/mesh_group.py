@@ -20,6 +20,7 @@ from typing import Any, Callable, List, Optional, Sequence
 import ray_tpu
 from ray_tpu.core.placement_group import placement_group, remove_placement_group
 
+from ..perf.jaxbuild import install_jax_spans
 from ..perf.recorder import get_recorder
 from .mesh import MeshSpec
 
@@ -68,6 +69,8 @@ class MeshWorkerMixin:
 
             devs = self._jax_devices(jax, process_id, num_processes,
                                      coordinator, devices_per_process)
+        # from here every program this worker builds leaves rtpu.jax.*
+        install_jax_spans()
         with rec.span("rtpu.train.mesh") as mesh:
             from .sharding import MeshOwner
 

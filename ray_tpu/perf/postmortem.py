@@ -11,6 +11,10 @@ dangling begin (asserted in tests/test_perf.py).
 
 Dumps are throttled per ``(origin, reason)`` so a poison that fans out
 through step()/teardown/abort produces one bundle, not three.
+
+A training run that ends well leaves the same file: ``fit()`` fetches its
+workers' rings before it kills them and writes one bundle to
+``<Result.path>/flight.json`` (``dump_bundle(..., path=)``).
 """
 from __future__ import annotations
 
@@ -51,13 +55,16 @@ def dump_bundle(reason: str, origin: str = "driver",
                 ring_fetchers: Optional[
                     Dict[str, Callable[[], List[dict]]]] = None,
                 meta: Optional[dict] = None,
-                throttle: bool = True) -> Optional[str]:
+                throttle: bool = True,
+                path: Optional[str] = None) -> Optional[str]:
     """Write one merged bundle and return its path (None when
     throttled). ``extra_rings`` are pre-drained event lists keyed by
     process label; ``ring_fetchers`` are best-effort callables (worker
     RPCs) — a fetcher that raises contributes an error marker instead of
     killing the dump, because the abort being recorded may be the very
-    thing that made the worker unreachable."""
+    thing that made the worker unreachable. ``path`` is where the bundle
+    goes (a run's own record, ``<Result.path>/flight.json``); without it
+    a file of its own name under ``bundle_dir()``."""
     global _last_path, _seq
     key = (origin, reason.split(":", 1)[0])
     now = time.monotonic()
@@ -79,19 +86,20 @@ def dump_bundle(reason: str, origin: str = "driver",
                             "label": proc, "data": {"error": repr(e)}}]
     bundle = {"reason": reason, "origin": origin, "time": time.time(),
               "rings": rings, "meta": meta or {}}
-    with _lock:
-        _seq += 1
-        seq = _seq
-    fname = (f"postmortem-{int(time.time() * 1000)}"
-             f"-{os.getpid()}-{seq}.json")
-    path = os.path.join(bundle_dir(), fname)
+    if path is None:
+        with _lock:
+            _seq += 1
+            seq = _seq
+        fname = (f"postmortem-{int(time.time() * 1000)}"
+                 f"-{os.getpid()}-{seq}.json")
+        path = os.path.join(bundle_dir(), fname)
+        _C_BUNDLES.inc(tags={"origin": origin})     # an abort's, not a run's
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
         json.dump(bundle, f, indent=1, sort_keys=True)
     os.replace(tmp, path)
     with _lock:
         _last_path = path
-    _C_BUNDLES.inc(tags={"origin": origin})
     return path
 
 

@@ -201,6 +201,17 @@ class FlightRecorder:
             self._append((ts, kind, label, d0, dur, parent), pin)
         return dur
 
+    def add_span(self, kind: str, ts: float, dur: float, label: str = "",
+                 data: Optional[Dict[str, Any]] = None,
+                 pin: bool = False) -> None:
+        """A span that someone else timed (jax its own build phases,
+        ``perf/jaxbuild.py``): ``ts`` its start on the wall clock, ``dur``
+        its seconds; ``parent`` is the span open on the calling thread."""
+        if self.enabled:
+            stack = getattr(_TLS, "stack", None)
+            self._append((ts, kind, label, data, dur,
+                          stack[-1] if stack else ""), pin)
+
     def _append(self, event: tuple, pin: bool) -> None:
         self._ring.append(event)
         self._appended += 1

@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ...perf.jaxbuild import install_jax_spans
 from ...perf.recorder import get_recorder as _get_recorder
 from ...util import metrics as _metrics
 from ...util import tracing as _tracing
@@ -363,6 +364,18 @@ class LLMEngine:
 
     def __init__(self, model: Any, params: Dict[str, Any],
                  config: Optional[EngineConfig] = None, name: str = ""):
+        # a replica's start-up gets the trainer's account: every program
+        # built from here on leaves rtpu.jax.* in this process's ring,
+        # those of the constructor as children of rtpu.llm.start (a
+        # bucket's program is built at its first request, under
+        # rtpu.llm.prefill.b<bucket>)
+        install_jax_spans()
+        with _FLREC.span("rtpu.llm.start", pin=True) as start:
+            self._build(model, params, config, name)
+            start.label = self.name
+
+    def _build(self, model: Any, params: Dict[str, Any],
+               config: Optional[EngineConfig], name: str) -> None:
         import jax
 
         self.model = model

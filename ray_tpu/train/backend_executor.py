@@ -50,6 +50,10 @@ class _TrainWorker(MeshWorkerMixin):
             checkpoint=checkpoint)
         return True
 
+    def flight_ring(self) -> List[dict]:
+        """This process's flight-recorder ring, left in place."""
+        return get_recorder().snapshot(clear=False)
+
     def run_train_fn(self, fn_blob: bytes, config: Dict[str, Any]):
         fn = cloudpickle.loads(fn_blob)
         try:
@@ -176,6 +180,14 @@ class BackendExecutor:
 
     def finish(self) -> List[Any]:
         return ray_tpu.get(self._run_refs)
+
+    def ring_fetchers(self) -> Dict[str, Callable[[], List[dict]]]:
+        """``dump_bundle``'s ``ring_fetchers`` for the gang as it stands:
+        each worker's ring by one actor call of 5 s at most. An actor
+        still inside its loop function does not answer in time."""
+        return {f"train_worker:{i}":
+                (lambda w=w: ray_tpu.get(w.flight_ring.remote(), timeout=5.0))
+                for i, w in enumerate(self.workers)}
 
     def shutdown(self) -> None:
         for w in self.workers:

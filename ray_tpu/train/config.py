@@ -120,3 +120,6 @@ class Result:
     path: str
     error: Optional[BaseException] = None
     metrics_history: list = field(default_factory=list)
+    # the run's flight record: the driver's ring and every worker's, as
+    # they stood when fit() ended (`ray_tpu postmortem <flight_path>`)
+    flight_path: Optional[str] = None

@@ -14,6 +14,8 @@ import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
+from ..perf.postmortem import dump_bundle
+from ..perf.recorder import get_recorder
 from .backend_executor import BackendExecutor, TrainWorkerError
 from .checkpoint import Checkpoint, prune_checkpoints
 from .config import (CheckpointConfig, FailureConfig, Result, RunConfig,
@@ -85,10 +87,12 @@ class DataParallelTrainer:
         history: List[Dict[str, Any]] = []
         last_metrics: Dict[str, Any] = {}
         error: Optional[BaseException] = None
+        flight_path: Optional[str] = None
 
         while True:
             executor = BackendExecutor(
                 self.scaling, experiment_name=self.run_config.name or "train")
+            ended: Optional[BaseException] = None   # how this gang ended
             try:
                 executor.start(self.train_loop, self.train_config,
                                dataset_shards=self._dataset_shards(),
@@ -110,20 +114,53 @@ class DataParallelTrainer:
                         prune_checkpoints(path, ckpt_cfg.num_to_keep)
                 break  # clean finish
             except TrainWorkerError as e:
+                ended = e
                 failures += 1
                 if max_failures >= 0 and failures > max_failures:
                     error = e
                     break
                 time.sleep(0.2)  # gang restart backoff
             except Exception as e:  # noqa: BLE001 — surface in Result
-                error = e
+                ended = error = e
                 traceback.print_exc()
                 break
             finally:
+                # the workers' rings die with them: fetch them first
+                flight_path = _flight_record(
+                    executor, path, ended, failures,
+                    len(history)) or flight_path
                 executor.shutdown()
 
         return Result(metrics=last_metrics, checkpoint=latest_ckpt,
-                      path=path, error=error, metrics_history=history)
+                      path=path, error=error, metrics_history=history,
+                      flight_path=flight_path)
+
+
+def _flight_record(executor: BackendExecutor, path: str,
+                   ended: Optional[BaseException], failures: int,
+                   iterations: int) -> Optional[str]:
+    """``<path>/flight.json``: the driver's ring and the ring of every
+    worker of ``executor`` that still answers (5 s each), in
+    ``dump_bundle``'s shape, so ``ray_tpu postmortem`` renders a run that
+    ended well as it renders an abort. Written when a gang ends, however
+    it ended and before it is killed; a restarted gang's record replaces
+    its predecessor's (the driver's ring holds both). Never raises, and
+    writes nothing with the recorder off. What it cost is the driver's
+    span ``rtpu.train.flight``."""
+    rec = get_recorder()
+    if not rec.enabled:
+        return None
+    try:
+        with rec.span("rtpu.train.flight", pin=True):
+            return dump_bundle(
+                "fit: " + ("ok" if ended is None else type(ended).__name__),
+                origin="driver", ring_fetchers=executor.ring_fetchers(),
+                meta={"error": ended and f"{type(ended).__name__}: {ended}",
+                      "failures": failures, "iterations": iterations},
+                throttle=False, path=os.path.join(path, "flight.json"))
+    except Exception:  # noqa: BLE001 - a record, never the run's failure
+        traceback.print_exc()
+        return None
 
 
 class JaxTrainer(DataParallelTrainer):

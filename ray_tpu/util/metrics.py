@@ -474,8 +474,9 @@ def _runtime_families(fams: "OrderedFams") -> None:
 
 
 def _jax_families(fams: "OrderedFams") -> None:
-    """Device-memory / compile-count gauges — only when the application
-    already imported jax (a scrape must not pay the jax import)."""
+    """Device-count and device-memory gauges — only when the application
+    already imported jax (a scrape must not pay the jax import). Compiles
+    are counted where they happen: ``perf/jaxbuild.py``."""
     import sys
 
     if "jax" not in sys.modules:
@@ -498,44 +499,6 @@ def _jax_families(fams: "OrderedFams") -> None:
         for key in ("bytes_in_use", "bytes_limit", "peak_bytes_in_use"):
             if key in stats:
                 mem.add("", {"device": str(d.id), "kind": key}, stats[key])
-    n = _jax_compile_count()
-    if n is not None:
-        fams.get("ray_tpu_jax_compilations_total", "counter",
-                 "XLA compilation events observed via jax.monitoring").add(
-            "", {}, n)
-
-
-_jax_compiles_lock = threading.Lock()
-_jax_compiles: Optional[int] = None  # None until the listener installs
-_jax_listener_state = "unset"  # unset | installed | failed
-
-
-def _jax_compile_count() -> Optional[int]:
-    global _jax_compiles, _jax_listener_state
-    # registration happens under the lock: /metrics is served by a
-    # ThreadingHTTPServer, and two concurrent first scrapes registering
-    # two listeners would double-count every compile forever
-    with _jax_compiles_lock:
-        if _jax_listener_state == "installed":
-            return _jax_compiles
-        if _jax_listener_state == "failed":
-            return None
-        try:
-            from jax._src import monitoring as _mon
-
-            def _on_event(event: str, **kw) -> None:
-                global _jax_compiles
-                with _jax_compiles_lock:
-                    if "compil" in event:
-                        _jax_compiles = (_jax_compiles or 0) + 1
-
-            _mon.register_event_listener(_on_event)
-            _jax_listener_state = "installed"
-            _jax_compiles = _jax_compiles or 0
-            return _jax_compiles
-        except Exception:
-            _jax_listener_state = "failed"
-            return None
 
 
 class OrderedFams:

@@ -301,6 +301,14 @@ class TestPostmortem:
         p2 = dump_bundle("unit: forced", origin="test", throttle=False)
         assert p2 and p2 != p1
         assert postmortem._C_BUNDLES.total() - before == 2
+        # a named destination (a run's flight record): written there,
+        # over what was there, and not counted among the aborts'
+        dest = str(tmp_path / "flight.json")
+        for reason in ("unit: run", "unit: run again"):
+            assert dump_bundle(reason, origin="test", throttle=False,
+                               path=dest) == dest
+        assert load_bundle(dest)["reason"] == "unit: run again"
+        assert postmortem._C_BUNDLES.total() - before == 2
 
     def test_cli_postmortem_render(self, tmp_path, capsys):
         from ray_tpu import cli
@@ -320,6 +328,186 @@ class TestPostmortem:
         monkeypatch.setattr(postmortem, "_last_path", None)
         assert cli.main(["postmortem"]) != 0
         assert "no post-mortem bundle" in capsys.readouterr().err
+
+
+# ---------------------------------------------------------------------------
+# jax's build phases as spans of the ring (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def jax_spans():
+    """The installer in force, the process recorder on, and
+    ``built(fn)``: the ``rtpu.jax.*`` spans that lowering and compiling
+    ``jax.jit(fn)`` for a vector of 8 appended to the ring (nothing
+    runs, so an executable read back from a cache is never executed)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.perf import get_recorder, install_jax_spans
+
+    install_jax_spans()
+    rec = get_recorder()
+    was = rec.enabled
+    rec.enabled = True
+    x = jnp.ones(8)     # made here: its own eager programs are not fn's
+
+    def built(fn):
+        t0 = time.time()
+        compiled = jax.jit(fn).lower(x).compile()
+        return compiled, [ev for ev in rec.spans("rtpu.jax.", since=t0)
+                          if fn.__name__ in ev["label"]]
+
+    yield built
+    rec.enabled = was
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """``use(directory or None)`` points jax's persistent compile cache
+    at a directory that keeps every program, or at none; what the
+    process had is put back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+
+    def use(directory):
+        jax.config.update(keys[0], directory)
+        jax.config.update(keys[1], 0)
+        jax.config.update(keys[2], -1)
+        cc.reset_cache()
+
+    yield use
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+class TestJaxBuildSpans:
+    def test_a_known_function_leaves_its_three_phases(self, jax_spans,
+                                                      compile_cache):
+        compile_cache(None)
+
+        def phases_fn(x):
+            return (x * x).sum()
+
+        rec = recorder.get_recorder()
+        t0 = time.time()
+        with rec.span("rtpu.test.around"):
+            _, evs = jax_spans(phases_fn)
+        assert [(e["kind"], e["label"]) for e in evs] == [
+            ("rtpu.jax.trace", "phases_fn"),
+            ("rtpu.jax.lower", "jit_phases_fn"),
+            ("rtpu.jax.compile", "jit_phases_fn")]
+        # the recorder's own shape: wall-clock start, seconds, what
+        # caused it; in the order they ran
+        assert all(e["dur"] >= 0 and e["parent"] == "rtpu.test.around"
+                   for e in evs)
+        assert evs[0]["ts"] <= evs[1]["ts"] <= evs[2]["ts"] <= time.time()
+        assert evs[2]["ts"] >= evs[1]["ts"] + evs[1]["dur"] - 1e-3
+        assert evs[2]["data"] == {"cache": "off"} and evs[1]["data"] is None
+        # the jitted helpers it calls (multiply, sum) are traced inside
+        # its trace: counted there, no spans of their own
+        assert evs[0]["data"]["inner"] >= 1
+        assert rec.spans("rtpu.jax.", since=t0) == evs
+
+    def test_cache_miss_then_hit_and_the_compile_counter(
+            self, jax_spans, compile_cache, tmp_path):
+        import jax
+
+        from ray_tpu.perf import jaxbuild
+
+        compile_cache(str(tmp_path / "cache"))
+
+        def cached_fn(x):
+            return (x + 3.0).prod()
+
+        n0 = jaxbuild._C_COMPILES.total()
+        _, first = jax_spans(cached_fn)
+        assert first[-1]["kind"] == "rtpu.jax.compile"
+        assert first[-1]["data"] == {"cache": "miss"}
+        assert jaxbuild._C_COMPILES.total() - n0 == 1   # a backend compile
+        jax.clear_caches()       # a new process's view: trace, lower, read
+        _, again = jax_spans(cached_fn)
+        assert [e["kind"] for e in again] == [
+            "rtpu.jax.trace", "rtpu.jax.lower", "rtpu.jax.compile"]
+        data = again[-1]["data"]
+        assert data["cache"] == "hit"
+        assert data["read_s"] > 0 and "saved_s" in data
+        assert jaxbuild._C_COMPILES.total() - n0 == 1   # a hit is no compile
+
+    def test_installing_twice_records_once(self, jax_spans):
+        from ray_tpu.perf import install_jax_spans
+
+        install_jax_spans()
+        install_jax_spans()
+
+        def twice_fn(x):
+            return x.max()
+
+        _, evs = jax_spans(twice_fn)
+        assert [e["kind"] for e in evs] == [
+            "rtpu.jax.trace", "rtpu.jax.lower", "rtpu.jax.compile"]
+
+    def test_a_built_program_records_nothing_when_called(self, jax_spans):
+        """The hot path is untouched: jax fires no event on its cached
+        dispatch, so a steady-state step leaves the ring as it was."""
+        import jax
+        import jax.numpy as jnp
+
+        rec = recorder.get_recorder()
+
+        def hot_fn(x):
+            return x * 2.0
+
+        f = jax.jit(hot_fn)
+        x = jnp.ones(8)
+        f(x).block_until_ready()
+        assert rec.spans("rtpu.jax.compile")[-1]["label"] == "jit_hot_fn"
+        before = rec.stats()["appended"]
+        for _ in range(3):
+            f(x).block_until_ready()
+        assert rec.stats()["appended"] == before
+
+    def test_recorder_off_records_and_counts_nothing(self, jax_spans):
+        from ray_tpu.perf import jaxbuild
+
+        rec = recorder.get_recorder()
+
+        def dark_fn(x):
+            return x.min()
+
+        rec.enabled = False
+        before = rec.stats()["appended"], jaxbuild._C_COMPILES.total()
+        _, evs = jax_spans(dark_fn)
+        rec.enabled = True
+        assert evs == []
+        assert (rec.stats()["appended"],
+                jaxbuild._C_COMPILES.total()) == before
+
+    def test_long_phases_are_pinned_past_the_rings_turnover(
+            self, monkeypatch):
+        from ray_tpu.perf import jaxbuild
+
+        rec = FlightRecorder(capacity=4, enabled=True)
+        monkeypatch.setattr(recorder, "_GLOBAL", rec)
+        event = "/jax/core/compile/backend_compile_duration"
+        jaxbuild._on_span(event, 100.0, 100.0 + 2 * jaxbuild.PIN_S,
+                          fun_name="jit(the_step)")
+        jaxbuild._on_span(event, 101.0, 101.01, fun_name="jit(eager)")
+        for i in range(8):
+            rec.record("noise", str(i))
+        kept = [(e["label"], e["ts"], e["dur"]) for e in rec.snapshot()
+                if "dur" in e]
+        assert kept == [("jit_the_step", 100.0,
+                         pytest.approx(2 * jaxbuild.PIN_S))]
+        # an event that is not a build phase is not a span
+        jaxbuild._on_span("/jax/other", 1.0, 2.0)
+        assert len(rec.snapshot()) == 5
 
 
 # ---------------------------------------------------------------------------

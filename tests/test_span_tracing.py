@@ -302,9 +302,11 @@ class TestEngineCounters:
             eng.stop()
         mine = [e for e in get_recorder().spans("rtpu.llm.")
                 if e["label"] == eng.name]
-        # some hundred empty steps and waits: merged, and no step event
-        assert [e["kind"] for e in mine] == ["rtpu.llm.idle"]
-        assert mine[0]["dur"] >= 0.2
+        # its constructor, then some hundred empty steps and waits:
+        # merged, and no step event
+        assert [e["kind"] for e in mine] == ["rtpu.llm.start",
+                                             "rtpu.llm.idle"]
+        assert mine[1]["dur"] >= 0.2
 
 
 class TestProfileFromSpans:

@@ -4,6 +4,7 @@ The four engine programs (``jit__decode``, ``jit__prefill``,
 lowering paths, the five flash kernels, and the model's named scopes in
 the ``op_name`` of the operations a loss lowers to."""
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -294,3 +295,51 @@ def test_scopes_are_metadata_the_lowered_program_is_the_same():
         without = text()
     assert with_scopes == without
     assert np.all([s not in with_scopes for s in ("loc(", "attn/")])
+
+
+@pytest.mark.parametrize("phase,kind", [
+    ("jaxpr_trace_duration", "rtpu.jax.trace"),
+    ("jaxpr_to_mlir_module_duration", "rtpu.jax.lower"),
+    ("backend_compile_duration", "rtpu.jax.compile")])
+def test_build_phase_span_kinds_are_pinned(phase, kind):
+    """ISSUE 38: ``step_trace_lower_s``, ``step_compile_s``,
+    ``other_programs_s`` and ``setup_unattributed_s`` find the chip
+    worker's build phases by these kinds, and a program by the name a
+    device trace gives it."""
+    from ray_tpu.perf import get_recorder, jaxbuild
+
+    assert jaxbuild._PHASES["/jax/core/compile/" + phase] == kind
+    jaxbuild.install_jax_spans()
+
+    def pinned_names_fn(x):
+        return x + 1
+
+    lowered = jax.jit(pinned_names_fn).lower(jnp.zeros(3))
+    label = [ev["label"] for ev in get_recorder().spans("rtpu.jax.")
+             if "pinned_names_fn" in ev["label"]
+             and ev["kind"] != "rtpu.jax.trace"][-1]
+    # the label of a lowered or compiled module IS the module's name
+    assert re.search(r"module @" + label + r"\b", lowered.as_text())
+
+
+def test_a_replicas_start_is_one_pinned_span():
+    """ISSUE 38: ``rtpu.llm.start`` around ``LLMEngine``'s constructor,
+    labelled with the engine; what the constructor built is its child."""
+    from ray_tpu.perf import get_recorder
+
+    m, params = build_model("gpt-tiny")
+    rec = get_recorder()
+    t0 = time.time()
+    # a pool of a size no other test makes: its programs are built here
+    eng = LLMEngine(m, params, EngineConfig(
+        block_size=4, num_blocks=37, max_batch=4, max_blocks_per_seq=8,
+        prefill_buckets=(8,)), name="names-start")
+    start = rec.spans("rtpu.llm.start", since=t0)
+    assert [ev["label"] for ev in start] == [eng.name]
+    assert any(ev[1] == "rtpu.llm.start" and ev[2] == eng.name
+               for ev in rec._pinned)
+    inside = [ev for ev in rec.spans("rtpu.jax.", since=t0)
+              if ev["parent"] == "rtpu.llm.start"
+              and ev["ts"] + ev["dur"]
+              <= start[0]["ts"] + start[0]["dur"] + 1e-3]
+    assert "rtpu.jax.compile" in {ev["kind"] for ev in inside}

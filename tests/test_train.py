@@ -127,6 +127,77 @@ def test_failure_exhausts_budget(rt, tmp_path):
     )
     result = trainer.fit()
     assert result.error is not None
+    # the flight record of a worker that is gone is its marker
+    from ray_tpu.perf import load_bundle
+
+    ring = load_bundle(result.flight_path)["rings"]["train_worker:0"]
+    assert [ev["kind"] for ev in ring] == ["postmortem.fetch_error"]
+
+
+def _jitting_loop(config):
+    import jax
+    import jax.numpy as jnp
+
+    def flight_step(x):
+        return (x * x).sum()
+
+    step = jax.jit(flight_step)
+    for i in range(3):
+        train.report({"i": i, "v": float(step(jnp.ones(4) * i))})
+    if config.get("boom"):
+        raise ValueError("boom at the end of the loop")
+
+
+@pytest.mark.parametrize("boom", [False, True],
+                         ids=["ended_well", "loop_raised"])
+def test_fit_leaves_one_flight_record(rt, tmp_path, capsys, boom):
+    """ISSUE 38: the workers' rings reach the driver before the workers
+    die, on every path out of fit(): one bundle beside the run, in the
+    post-mortem's own shape, which its CLI renders."""
+    from ray_tpu import cli
+    from ray_tpu.perf import load_bundle
+
+    result = JaxTrainer(
+        _jitting_loop, train_loop_config={"boom": boom, "pad": 1},
+        scaling_config=ScalingConfig(num_workers=1, devices_per_worker=4),
+        run_config=RunConfig(name="flight", storage_path=str(tmp_path)),
+    ).fit()
+    assert (result.error is not None) == boom
+    assert result.flight_path == os.path.join(result.path, "flight.json")
+    bundle = load_bundle(result.flight_path)
+    assert set(bundle["rings"]) == {"driver", "train_worker:0"}
+    assert bundle["origin"] == "driver"
+    assert bundle["meta"]["iterations"] == 3
+    worker = bundle["rings"]["train_worker:0"]
+    kinds = [ev["kind"] for ev in worker]
+    assert kinds.count("rtpu.train.report") == 3
+    assert "rtpu.train.jax_start" in kinds and "rtpu.train.mesh" in kinds
+    # the loop's own program, built in the worker after jax came up there
+    step = {ev["kind"]: ev for ev in worker
+            if ev["kind"].startswith("rtpu.jax.")
+            and "flight_step" in ev["label"]}
+    assert set(step) == {"rtpu.jax.trace", "rtpu.jax.lower",
+                         "rtpu.jax.compile"}
+    assert step["rtpu.jax.compile"]["label"] == "jit_flight_step"
+    assert step["rtpu.jax.compile"]["data"]["cache"] in ("off", "miss",
+                                                         "hit")
+    jax_up = next(ev for ev in worker
+                  if ev["kind"] == "rtpu.train.jax_start")
+    assert step["rtpu.jax.trace"]["ts"] >= jax_up["ts"] + jax_up["dur"]
+    # beside the driver's ring, on one clock
+    driver = {ev["kind"]: ev for ev in bundle["rings"]["driver"]}
+    assert "rtpu.train.setup_mesh" in driver
+    assert driver["rtpu.train.setup_mesh"]["ts"] <= jax_up["ts"]
+    if boom:
+        assert bundle["reason"] == "fit: TrainWorkerError"
+        assert "boom at the end of the loop" in bundle["meta"]["error"]
+    else:
+        assert bundle["reason"] == "fit: ok"
+        assert bundle["meta"]["error"] is None
+    assert cli.main(["postmortem", result.flight_path, "--tail", "500"]) == 0
+    out = capsys.readouterr().out
+    assert "train_worker:0" in out and "rtpu.train.report" in out
+    assert "rtpu.jax.compile" in out and bundle["reason"] in out
 
 
 class TestTorchTrainer:
