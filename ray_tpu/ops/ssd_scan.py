@@ -28,19 +28,44 @@ The routes, chosen by what a call shows (``PATH_COUNTS``, the event
 * ``kernel``: heads of 64 or 128 whose merged width H*P is a multiple of
   128, one group, a state that is a multiple of 128, a chunk that is a
   multiple of 128 and divides T. The kernels index the model's merged
-  [B, T, H*P] arrays (two heads of 64 to a 128-lane tile, each worked on
-  the full tile with the other's lanes zeroed, as ``flash_attention``
-  does), grid (batch, chunk, head block), the chunks in order and the
-  head blocks inside a chunk: the state of every head stays in ONE float32
-  VMEM scratch [H*P, N] from chunk to chunk (2 MB at 64 heads of 64 x
-  128), the group's C B^T [Q, Q] is made once a chunk and shared by its
-  head blocks, and each head's decay matrix exp(c_t - c_s) [Q, Q] exists
-  only in VMEM. The forward also writes the state each chunk starts from
-  ([B, T/Q, H*P, N] float32); the backward walks the chunks in reverse,
-  carries the state's gradient in the same kind of scratch and
-  accumulates dB and dC over the head blocks in their resident output
-  block. No array of shape [.., chunks, heads, Q, Q] reaches HBM, forward
-  or backward (tests/test_chip_compile.py holds that).
+  [B, T, H*P] arrays (two heads of 64 to a 128-lane tile), grid (batch,
+  chunk, head block), the chunks in order and the head blocks inside a
+  chunk: the state of every head stays in ONE float32 VMEM scratch
+  [H*P, N] from chunk to chunk (2 MB at 64 heads of 64 x 128), the group's
+  C B^T [Q, Q] is made once a chunk and shared by its head blocks, and
+  each head's decay matrix exp(c_t - c_s) [Q, Q] exists only in VMEM. The
+  forward also writes the state each chunk starts from ([B, T/Q, H*P, N]
+  float32); the backward walks the chunks in reverse, carries the state's
+  gradient in the same kind of scratch and accumulates dB and dC over the
+  head blocks in their resident output block. No array of shape
+  [.., chunks, heads, Q, Q] reaches HBM, forward or backward
+  (tests/test_chip_compile.py holds that).
+
+  How a program (one chunk of one block of up to 16 heads) lays out its
+  work, one body for heads of 64 and of 128 (``per_tile`` 2 or 1). A
+  quantity that is one number a head and token is never held as a
+  [Q, 1] column, which fills one lane of 128 and costs a register an
+  eighth of a row whatever is done to it (ISSUE 40; the ablation that
+  found two thirds of the backward there is in PERF.md section 7):
+  - once a program: everything that is a function of the cumulative sum
+    alone (exp(c), exp(c_Q - c), exp(c_Q)) on the dense [heads, Q] rows
+    the kernel receives, and those rows and dt turned to columns by
+    ``_down``: each head's row spread over the sublanes of an aligned
+    [128, Q] tile and turned by the XLU, so that it comes out down the
+    rows in EVERY lane that is the head's (all 128 for the decay's c_t,
+    its own P of the merged width for what multiplies x and dy);
+  - once a tile (two heads of 64, or one of 128): x dt, the two
+    (backward: five) products with the state, and in the backward the
+    sums over a head's lanes that become d(dt) and d(cumulative sum): the
+    tile turned once, a head's lanes then being sublanes, added register
+    by register into ROWS [heads, Q], which are kept in values and stored
+    once a program (``_head_rows``); what row t of d(exponent) gains is
+    folded into the tile's sums first, what column s loses is a row
+    already;
+  - once a head: the [Q, Q] chain alone: the decay, the two (forward:
+    one) big products, d(C B^T) and d(exponent).
+  The unrolled bodies are ``jax.lax`` primitives (``jnp`` calls on a
+  tracer are jitted helpers traced again at every use: ROADMAP A11).
 * ``reference``: every other shape (several groups, T no multiple of the
   chunk, a chunk of 1, heads of 32): the same chunked form in plain
   ``jnp``, differentiated by jax; T is padded to whole chunks with dt = 0
@@ -61,7 +86,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..perf.recorder import record as _record
-from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot, _head_lanes
+from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot
 
 # The module, not the function of its name that the package exports: the
 # kernels here run interpreted where the flash kernels do, by the one
@@ -103,6 +128,8 @@ def _heads_per_block(heads: int, p: int) -> int:
 # what both kernels share
 # ---------------------------------------------------------------------------
 
+_F32 = jnp.float32
+
 
 def _group_scores(c, b):
     """C B^T of one chunk [Q, Q] in f32, zero above the diagonal."""
@@ -112,49 +139,82 @@ def _group_scores(c, b):
     return jnp.where(rows >= cols, g, 0.0)
 
 
-def _head_vectors(dt_ref, cum_ref, k: int):
-    """Head k of the block: (cumulative sum as a row [1, Q], as a column
-    [Q, 1], dt as a column, the chunk's last cumulative sum [1, 1])."""
-    cr = cum_ref[k:k + 1, :]
-    cc = cr.T
-    # the last entry by a masked sum: a [1, 1] cut from lane Q-1 of the
-    # row is a layout Mosaic cannot broadcast down a column
-    last = jax.lax.broadcasted_iota(jnp.int32, cc.shape, 0) == cc.shape[0] - 1
-    cq = jnp.sum(jnp.where(last, cc, 0.0), axis=0, keepdims=True)
-    return cr, cc, dt_ref[k:k + 1, :].T, cq
+# The unrolled bodies below are written in ``jax.lax`` primitives: each
+# ``jnp`` call or operator on a tracer is a jitted helper traced again at
+# every use (ROADMAP A11), thousands in a model's step.
+_mul, _add, _sub = jax.lax.mul, jax.lax.add, jax.lax.sub
+
+
+def _spread(v, shape):
+    """[rows, 1] along the lanes, [1, lanes] down the rows, or [1, 1] over
+    both, of ``shape``."""
+    return jax.lax.broadcast_in_dim(v, shape, (0, 1))
+
+
+def _tile(v, i: int):
+    """Lanes [128 i, 128 (i + 1)) of v."""
+    return jax.lax.slice(v, (0, i * _LANES), (v.shape[0], (i + 1) * _LANES))
+
+
+def _sum(v, axis: int):
+    """Sum over one axis of a 2-d v, the axis kept."""
+    return jax.lax.expand_dims(jax.lax.reduce_sum(v, (axis,)), (axis,))
+
+
+def _row_is(sub, k: int):
+    """Where the row index ``sub`` (an iota) is k."""
+    return jax.lax.eq(sub, jax.lax.full_like(sub, k))
+
+
+def _only(x, j: int, p: int, first):
+    """``_head_lanes`` with the mask at hand: the tile with every lane
+    outside head j's P zeroed (``first``: the lanes of the tile's first
+    head); the tile itself where it holds one head."""
+    if first is None:
+        return x
+    zero = jax.lax.full_like(x, 0)
+    return jax.lax.select(first, x, zero) if j == 0 else \
+        jax.lax.select(first, zero, x)
 
 
 def _decay(cc, cr):
-    """exp(c_t - c_s) [Q, Q]; the exponent is <= 0 wherever t >= s, and
-    the rest is thrown away by the zeros of ``_group_scores``."""
-    return jnp.exp(jnp.minimum(cc - cr, 0.0))
+    """exp(c_t - c_s) [Q, Q] from c down the rows (every lane of [Q, 128]
+    holding it) and along the lanes [1, Q]; the exponent is <= 0 wherever
+    t >= s, and the rest is thrown away by the zeros of ``_group_scores``."""
+    q = cr.shape[1]
+    d = _sub(jax.lax.concatenate([cc] * (q // _LANES), 1),
+             _spread(cr, (q, q)))
+    return jax.lax.exp(jax.lax.min(d, jax.lax.full_like(d, 0)))
 
 
-def _by_lanes(cols, p: int):
-    """One [Q, 1] column a head of a 128-lane tile -> [Q, 128], each
-    head's P lanes holding its column."""
-    shape = (cols[0].shape[0], _LANES)
-    out = jnp.broadcast_to(cols[0], shape)
-    if len(cols) == 2:
-        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        out = jnp.where(lane < p, out, jnp.broadcast_to(cols[1], shape))
-    return out
+def _down(rows, heads: int):
+    """Per-head rows [hpb, Q] -> [Q, (hpb / heads) x 128]: every group of
+    ``heads`` rows turned down the rows of one 128-lane tile, each head's
+    row in every one of its own 128 / heads lanes. The row is spread over
+    its lanes' worth of sublanes first (a sublane broadcast is one cheap
+    pass) and the [128, Q] float32 tile turned by the XLU, aligned."""
+    hpb, q = rows.shape
+    return jax.lax.concatenate([jax.lax.transpose(jax.lax.concatenate(
+        [_spread(jax.lax.slice(rows, (k, 0), (k + 1, q)),
+                 (_LANES // heads, q)) for k in range(k0, k0 + heads)], 0),
+        (1, 0)) for k0 in range(0, hpb, heads)], 1)
 
 
-def _by_rows(vals, p: int, n: int):
-    """One [1, 1] value a head of a tile -> [128, n], each head's P rows
-    of the tile's state holding its value."""
-    shape = (_LANES, n)
-    out = jnp.broadcast_to(vals[0], shape)
-    if len(vals) == 2:
-        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        out = jnp.where(row < p, out, jnp.broadcast_to(vals[1], shape))
-    return out
+def _last_entry(cum):
+    """The chunk's last cumulative sum of every head [hpb, 1], by a masked
+    sum: a cut from lane Q-1 is a layout Mosaic cannot broadcast down the
+    rows of a state tile; a sum's is in every lane."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 1)
+    return jnp.sum(jnp.where(lane == cum.shape[1] - 1, cum, 0.0), axis=1,
+                   keepdims=True)
 
 
-def _head_sum(x, j: int, p: int):
-    """Row sums over head j's lanes of a [Q, 128] tile -> [Q, 1]."""
-    return jnp.sum(_head_lanes(x, j, p), axis=1, keepdims=True)
+def _last_rows(last, k0: int, per_tile: int, p: int, n: int):
+    """exp(c_Q) of the block's heads [hpb, 1] -> [128, n], each head's P
+    rows of tile k0's state holding its value."""
+    return jax.lax.concatenate(
+        [_spread(jax.lax.slice(last, (k, 0), (k + 1, 1)), (p, n))
+         for k in range(k0, k0 + per_tile)], 0)
 
 
 # ---------------------------------------------------------------------------
@@ -170,6 +230,8 @@ def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
     ci, hb = pl.program_id(1), pl.program_id(2)
     per_tile = _LANES // p
     dtype = x_ref.dtype
+    hpb, q = cum_ref.shape
+    n = b_ref.shape[1]
 
     @pl.when(ci == 0)
     def _first_chunk():
@@ -182,23 +244,32 @@ def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
     st_ref[...] = h_scr[hb]
     g = g_scr[...]
     bm, cm = b_ref[...], c_ref[...]
+    cum, dt = cum_ref[...], dt_ref[...]
+    cq = _last_entry(cum)
+    cc = _down(cum, 1)
+    into, carry, step = (_down(v, per_tile) for v in (
+        jnp.exp(cum), jnp.exp(cq - cum) * dt, dt))
+    last = jnp.exp(cq)
+    first = None if per_tile == 1 else \
+        jax.lax.broadcasted_iota(jnp.int32, (q, _LANES), 1) < p
     for i in range(x_ref.shape[1] // _LANES):
         lanes = pl.ds(i * _LANES, _LANES)
-        xt = x_ref[:, lanes]
+        xf = x_ref[:, lanes].astype(_F32)
         h0 = h_scr[hb, lanes, :]
-        y, into, carry, last = None, [], [], []
+        xd = _mul(xf, _tile(step, i)).astype(dtype)
+        y = None
         for j in range(per_tile):
-            cr, cc, dc, cq = _head_vectors(dt_ref, cum_ref, i * per_tile + j)
-            xd = (_head_lanes(xt, j, p).astype(jnp.float32) * dc).astype(dtype)
-            part = _dot((g * _decay(cc, cr)).astype(dtype), xd, _AB)
-            y = part if y is None else y + part
-            into.append(jnp.exp(cc))
-            carry.append(jnp.exp(cq - cc) * dc)
-            last.append(jnp.exp(cq))
-        y = y + _by_lanes(into, p) * _dot(cm, h0.astype(dtype), _ABT)
-        xw = (xt.astype(jnp.float32) * _by_lanes(carry, p)).astype(dtype)
-        h_scr[hb, lanes, :] = _by_rows(last, p, h0.shape[1]) * h0 \
-            + _dot(xw, bm, _ATB)
+            k = i * per_tile + j
+            decay = _decay(_tile(cc, k), jax.lax.slice(cum, (k, 0),
+                                                       (k + 1, q)))
+            part = _dot(_mul(g, decay).astype(dtype), _only(xd, j, p, first),
+                        _AB)
+            y = part if y is None else _add(y, part)
+        y = _add(y, _mul(_tile(into, i), _dot(cm, h0.astype(dtype), _ABT)))
+        xw = _mul(xf, _tile(carry, i)).astype(dtype)
+        h_scr[hb, lanes, :] = _add(
+            _mul(_last_rows(last, i * per_tile, per_tile, p, n), h0),
+            _dot(xw, bm, _ATB))
         y_ref[:, lanes] = y.astype(y_ref.dtype)
 
 
@@ -259,6 +330,30 @@ def _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
 # ---------------------------------------------------------------------------
 
 
+def _head_rows(vals, k0: int, hpb: int, p: int):
+    """Sums over each head's P lanes of [Q, 128] float32 tiles, as ROWS:
+    -> [hpb, Q] whose row k0 + j holds head j's sums and whose other rows
+    are zero. ``vals`` is a list of (tile, swapped): a swapped tile gives
+    its upper lanes to the first head (two heads a tile only).
+
+    The tile is turned once by the XLU; a head's lanes are then sublanes,
+    their sum is plain adds of registers, and it comes out with the tokens
+    along the lanes, which is how d(dt) and d(cumulative sum) leave."""
+    per_tile = _LANES // p
+    q = vals[0][0].shape[0]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 0)
+    out = jnp.zeros((hpb, q), _F32)
+    for v, swapped in vals:
+        vt = jax.lax.transpose(v, (1, 0))
+        for j in range(per_tile):
+            jj = per_tile - 1 - j if swapped else j
+            row = _sum(jax.lax.slice(vt, (jj * p, 0), ((jj + 1) * p, q)), 0)
+            out = _add(out, jax.lax.select(
+                _row_is(sub, k0 + j), _spread(row, (hpb, q)),
+                jax.lax.full_like(out, 0)))
+    return out
+
+
 def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
                 dx_ref, ddt_ref, dcum_ref, db_ref, dc_ref,
                 dh_scr, g_scr, dg_scr, *, p: int):
@@ -271,7 +366,8 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
     ci, hb = pl.program_id(1), pl.program_id(2)
     per_tile = _LANES // p
     dtype = x_ref.dtype
-    q = x_ref.shape[0]
+    hpb, q = cum_ref.shape
+    n = b_ref.shape[1]
 
     @pl.when(ci == 0)
     def _last_chunk():
@@ -286,59 +382,83 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
 
     g = g_scr[...]
     bm, cm = b_ref[...], c_ref[...]
-    end = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    cum, dt = cum_ref[...], dt_ref[...]
+    cq = _last_entry(cum)
+    cc = _down(cum, 1)
+    into, carry, step = (_down(v, per_tile) for v in (
+        jnp.exp(cum), jnp.exp(cq - cum), dt))
+    last = jnp.exp(cq)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 0)
+    end = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 1) == q - 1
+    first = None if per_tile == 1 else \
+        jax.lax.broadcasted_iota(jnp.int32, (q, _LANES), 1) < p
+    zeros = jnp.zeros((hpb, q), _F32)
+    # d(cumulative sum) and d(dt) of the block, the tokens along the lanes
+    dcum = ddt = zeros
     dg = None
     for i in range(x_ref.shape[1] // _LANES):
         lanes = pl.ds(i * _LANES, _LANES)
-        xt, dyt = x_ref[:, lanes], dy_ref[:, lanes]
-        xf, dyf = xt.astype(jnp.float32), dyt.astype(jnp.float32)
+        dyt = dy_ref[:, lanes]
+        xf, dyf = x_ref[:, lanes].astype(_F32), dyt.astype(_F32)
         h0, dh = st_ref[lanes, :], dh_scr[hb, lanes, :]
-        dxd, pairs, into, carry, step, last = None, [], [], [], [], []
+        ec, sw, dcl = _tile(into, i), _tile(carry, i), _tile(step, i)
+        xd = _mul(xf, dcl).astype(dtype)
+        dxd, across = None, []
         for j in range(per_tile):
-            cr, cc, dc, cq = _head_vectors(dt_ref, cum_ref, i * per_tile + j)
-            decay = _decay(cc, cr)
-            dyj = _head_lanes(dyt, j, p)
-            xd = (_head_lanes(xf, j, p) * dc).astype(dtype)
-            u = _dot(dyj, xd, _ABT) * decay              # d(C B^T), unmasked
-            dg = u if dg is None else dg + u
-            w = u * g                                    # d(exponent) [t, s]
-            pairs.append(jnp.sum(w, axis=1, keepdims=True)
-                         - jnp.sum(w, axis=0, keepdims=True).T)
-            part = _dot((g * decay).astype(dtype), dyj, _ATB)
-            dxd = part if dxd is None else dxd + part
-            into.append(jnp.exp(cc))
-            carry.append(jnp.exp(cq - cc))
-            step.append(dc)
-            last.append(jnp.exp(cq))
-        ec, sw, dcl = _by_lanes(into, p), _by_lanes(carry, p), \
-            _by_lanes(step, p)
-        eq = _by_rows(last, p, h0.shape[1])
+            k = i * per_tile + j
+            decay = _decay(_tile(cc, k), jax.lax.slice(cum, (k, 0),
+                                                       (k + 1, q)))
+            dyj = _only(dyt, j, p, first)
+            # d(C B^T) of this head, unmasked
+            u = _mul(_dot(dyj, _only(xd, j, p, first), _ABT), decay)
+            dg = u if dg is None else _add(dg, u)
+            w = _mul(u, g)                               # d(exponent) [t, s]
+            # what row t gains is summed over the lanes below, with the
+            # tile's other sums; what column s loses is a row already
+            gain = _tile(w, 0)
+            for m in range(1, q // _LANES):
+                gain = _add(gain, _tile(w, m))
+            across.append(gain)
+            dcum = _sub(dcum, jax.lax.select(
+                _row_is(sub, k), _spread(_sum(w, 0), (hpb, q)), zeros))
+            part = _dot(_mul(g, decay).astype(dtype), dyj, _ATB)
+            dxd = part if dxd is None else _add(dxd, part)
+        eq = _last_rows(last, i * per_tile, per_tile, p, n)
         h0m, dhm = h0.astype(dtype), dh.astype(dtype)
-        e = dyf * ec
+        e = _mul(dyf, ec)
         em = e.astype(dtype)
-        xw = xf * sw * dcl
+        xw = _mul(_mul(xf, sw), dcl)
         dc_ref[...] += _dot(em, h0m, _AB)
         db_ref[...] += _dot(xw.astype(dtype), dhm, _AB)
         dxw = _dot(bm, dhm, _ABT)
-        dh_scr[hb, lanes, :] = _dot(em, cm, _ATB) + eq * dh
-        dx_ref[:, lanes] = ((dxd + dxw * sw) * dcl).astype(dx_ref.dtype)
-        through = e * _dot(cm, h0m, _ABT)     # dy . (what the state gave y)
-        written = dxw * xw                    # d(h_end) . (what s wrote)
-        dstep = (dxd + dxw * sw) * xf
-        kept = dh * eq * h0                   # d(h_end) . (what was kept)
-        row = jax.lax.broadcasted_iota(jnp.int32, kept.shape, 0)
+        dh_scr[hb, lanes, :] = _add(_dot(em, cm, _ATB), _mul(eq, dh))
+        dxs = _add(dxd, _mul(dxw, sw))
+        dx_ref[:, lanes] = _mul(dxs, dcl).astype(dx_ref.dtype)
+        written = _mul(dxw, xw)               # d(h_end) . (what s wrote)
+        # dy . (what the state gave y) less what s wrote: d(c_t) of both
+        local = _sub(_mul(e, _dot(cm, h0m, _ABT)), written)
+        kept = _mul(_mul(dh, eq), h0)         # d(h_end) . (what was kept)
+        if per_tile == 1:
+            sums = [(_add(local, across[0]), False)]
+        else:
+            sums = [(_add(local, jax.lax.select(first, *across)), False),
+                    (jax.lax.select(first, *across[::-1]), True)]
+        dcum = _add(dcum, _head_rows(sums, i * per_tile, hpb, p))
+        ddt = _add(ddt, _head_rows([(_mul(dxs, xf), False)], i * per_tile,
+                                   hpb, p))
+        wrote = _sum(written, 0)                                   # [1, 128]
         for j in range(per_tile):
             k = i * per_tile + j
-            wr = _head_sum(written, j, p)
-            mine = kept if per_tile == 1 else jnp.where(
-                (row >= j * p) & (row < (j + 1) * p), kept, 0.0)
-            dcq = jnp.sum(wr, axis=0, keepdims=True) + jnp.sum(
-                jnp.sum(mine, axis=1, keepdims=True), axis=0, keepdims=True)
-            dcum = pairs[j] + _head_sum(through, j, p) - wr \
-                + jnp.where(end, dcq, 0.0)
-            dcum_ref[k:k + 1, :] = dcum.T
-            ddt_ref[k:k + 1, :] = _head_sum(dstep, j, p).T
+            dcq = _add(
+                _sum(_only(wrote, j, p, None if first is None else
+                           jax.lax.slice(first, (0, 0), (1, _LANES))), 1),
+                _sum(_sum(jax.lax.slice(kept, (j * p, 0), ((j + 1) * p, n)),
+                          0), 1))
+            dcum = _add(dcum, jax.lax.select(
+                _row_is(sub, k) & end, _spread(dcq, (hpb, q)), zeros))
     dg_scr[...] += dg
+    dcum_ref[...] = dcum
+    ddt_ref[...] = ddt
 
     @pl.when(hb == pl.num_programs(2) - 1)
     def _last_block():

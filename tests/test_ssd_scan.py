@@ -90,25 +90,35 @@ def worst(got, want):
     return out
 
 
-# (T, chunk, heads, groups, the route the call must take)
+# (T, chunk, heads, groups, the route the call must take, head size)
 CASES = [
-    pytest.param(256, 128, 4, 1, "kernel", id="kernel-two-chunks"),
-    pytest.param(128, 128, 2, 1, "kernel", id="kernel-single-chunk"),
-    pytest.param(256, 128, 16, 1, "kernel", id="kernel-two-head-blocks"),
-    pytest.param(96, 32, 4, 1, "reference", id="plain-three-chunks"),
-    pytest.param(100, 32, 4, 2, "reference", id="plain-T-no-multiple"),
-    pytest.param(24, 1, 2, 1, "reference", id="plain-chunk-of-one"),
-    pytest.param(64, 256, 2, 2, "reference", id="plain-single-chunk"),
+    pytest.param(256, 128, 4, 1, "kernel", 64, id="kernel-two-chunks"),
+    pytest.param(128, 128, 2, 1, "kernel", 64, id="kernel-single-chunk"),
+    pytest.param(256, 128, 16, 1, "kernel", 64, id="kernel-two-head-blocks"),
+    pytest.param(96, 32, 4, 1, "reference", 64, id="plain-three-chunks"),
+    pytest.param(100, 32, 4, 2, "reference", 64, id="plain-T-no-multiple"),
+    pytest.param(24, 1, 2, 1, "reference", 64, id="plain-chunk-of-one"),
+    pytest.param(64, 256, 2, 2, "reference", 64, id="plain-single-chunk"),
+    # ISSUE 40, the body that works a tile's two heads at once: both head
+    # sizes, both chunks, H > 16 (two head blocks of 16) and three chunks
+    pytest.param(384, 128, 32, 1, "kernel", 64,
+                 id="kernel-heads-64-two-blocks-three-chunks"),
+    pytest.param(384, 128, 32, 1, "kernel", 128,
+                 id="kernel-heads-128-two-blocks-three-chunks"),
+    pytest.param(768, 256, 4, 1, "kernel", 64,
+                 id="kernel-heads-64-chunk-256-three-chunks"),
+    pytest.param(768, 256, 4, 1, "kernel", 128,
+                 id="kernel-heads-128-chunk-256-three-chunks"),
 ]
 
 
-@pytest.mark.parametrize("t,chunk,heads,groups,route", CASES)
-def test_ssd_scan_is_the_recurrence(t, chunk, heads, groups, route):
+@pytest.mark.parametrize("t,chunk,heads,groups,route,p", CASES)
+def test_ssd_scan_is_the_recurrence(t, chunk, heads, groups, route, p):
     """y and the gradients of x, dt, A, B, C, D of the chunked form equal
     the token-by-token recurrence's (float32: 5e-5 of the largest entry;
     the module docstring says why)."""
-    args, dy = arguments(t + heads, t, heads, groups,
-                         batch=1 if heads == 16 else 2)
+    args, dy = arguments(t + heads, t, heads, groups, p=p,
+                         batch=1 if heads >= 16 else 2)
     before = ssd.PATH_COUNTS[route]
     got = value_and_grads(
         lambda *a: ssd.ssd_scan(*a, chunk=chunk), args, dy)
@@ -191,3 +201,82 @@ def test_the_route_leaves_its_event_and_count():
     assert events[1]["data"] == {
         "route": "reference", "chunk": 100, "heads": 4, "head_dim": 64,
         "state": 128, "groups": 2, "chunks": 1}
+
+
+def _parents_bodies():
+    """scripts/ssd_ablate.py as a module: it keeps PR 38's two kernel
+    bodies as they were (``whole``: nothing taken out), interpreted."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "ssd_ablate", os.path.join(os.path.dirname(__file__), "..",
+                                   "scripts", "ssd_ablate.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod.INTERPRET = True
+    return mod
+
+
+@pytest.mark.parametrize("p,heads", [(64, 16), (128, 8)])
+def test_the_body_against_the_parents_body(p, heads):
+    """ISSUE 40: bf16 arguments as the model hands them, two chunks, the
+    kernels alone. y, the states, dx, dB and dC are the parent's bit for
+    bit: the per-head columns are the same float32 numbers (made on the
+    [heads, Q] rows and turned, where the parent turned first) and every
+    product and sum that makes them is issued as it was. d(dt) and
+    d(cumulative sum) are the same float32 sums in another order (a head's
+    lanes turned to sublanes and added register by register, where the
+    parent crossed lanes on the XLU): 1e-6 of the largest entry, measured
+    0 to 2e-7."""
+    parent = _parents_bodies()
+    b, t, n, chunk = 1, 256, 128, 128
+    hpb = ssd._heads_per_block(heads, p)
+    x, dy, dt_t, cum_t, bm, cm = parent.inputs(b, t, heads, p, n, chunk)
+    kw = dict(p=p, chunk=chunk, hpb=hpb)
+    y0, st0 = parent.fwd_call(x, dt_t, cum_t, bm, cm, cut=frozenset(), **kw)
+    y1, st1 = ssd._ssd_fwd(x, dt_t, cum_t, bm, cm, **kw)
+    np.testing.assert_array_equal(np.asarray(y1, np.float32),
+                                  np.asarray(y0, np.float32))
+    np.testing.assert_array_equal(st1, st0)
+    was = parent.bwd_call(x, dy, dt_t, cum_t, bm, cm, st0,
+                          cut=frozenset(), **kw)
+    now = ssd._ssd_bwd(x, dy, dt_t, cum_t, bm, cm, st0, **kw)
+    for name, a, c in zip(("dx", "ddt", "dcum", "dB", "dC"), now, was):
+        a, c = np.asarray(a, np.float64), np.asarray(c, np.float64)
+        if name in ("ddt", "dcum"):
+            assert np.abs(a - c).max() < 1e-6 * np.abs(c).max(), name
+        else:
+            np.testing.assert_array_equal(a, c, err_msg=name)
+
+
+def test_the_cells_scan_traces_no_more_helpers_than_the_parents():
+    """A guard on the trace's cost that needs no clock: the jitted helpers
+    jax traces inside the gradient of ``ssd_scan`` at
+    ``granite4h_train_s4096``'s shapes (``data.inner`` of the
+    ``rtpu.jax.trace`` span, ``perf/jaxbuild.py``; from shapes, nothing
+    runs). PR 38's bodies traced 1471 with no helper cached; the bodies in
+    ``jax.lax`` primitives trace 89."""
+    from ray_tpu.perf import get_recorder, install_jax_spans
+
+    install_jax_spans()
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    b, t, h, p, n = 2, 4096, 64, 64, 128
+    sd = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+
+    def cell_scan_loss(x, dt, a, bm, cm, d):
+        return ssd.ssd_scan(x, dt, a, bm, cm, d).astype(jnp.float32).sum()
+
+    jax.clear_caches()      # a new process's view: every helper is traced
+    try:
+        jax.jit(jax.grad(cell_scan_loss, argnums=tuple(range(6)))).trace(
+            sd((b, t, h, p), bf), sd((b, t, h), jnp.float32),
+            sd((h,), jnp.float32), sd((b, t, 1, n), bf),
+            sd((b, t, 1, n), bf), sd((h,), jnp.float32))
+        span = [e for e in rec.spans("rtpu.jax.trace")
+                if e["label"] == "cell_scan_loss"][-1]
+    finally:
+        rec.enabled = was
+    assert 0 < span["data"]["inner"] <= 200, span
