@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu.serve.llm import LLMServer
 
-from .spec import load_family
+from .spec import load_family, objective_of
 
 # correctness yardstick, as chip_smoke.py: the server computes in bf16, so
 # it is held to the float32 reference within NOISE_FACTOR times the largest
@@ -320,6 +320,7 @@ def serve_reference_check(engine, reference: str, sample: List[dict],
             "tokens_total": exact + near + wrong, "tokens_exact": exact,
             "tokens_near_tie": near, "tokens_wrong": wrong,
             "bf16_noise": noise, "tolerance": tol,
+            "bf16_noise_limit": NOISE_CEILING * logit_max,
             "worst_gap_to_ref_top": worst_gap,
             "first_logits_max_abs_diff": first_diff,
             "first_logits_finite": finite, "ref_logit_abs_max": logit_max}
@@ -329,17 +330,27 @@ def serve_reference_check(engine, reference: str, sample: List[dict],
 # training
 # ---------------------------------------------------------------------------
 
-def make_train_step(model, tx):
-    """The step a training cell runs: next-token loss (targets are the
-    tokens rolled by one, on the device), adamw update, parameters and
-    optimizer state donated. Named, so the trace finds it."""
+def make_train_step(model, tx, objective=None):
+    """The step a training cell runs: the family's ``objective(params,
+    tokens) -> scalar`` where its file states one (``spec.objective_of``),
+    else the next-token loss (targets are the tokens rolled by one, on the
+    device); adamw update, parameters and optimizer state donated. An
+    objective gets the parameters and the batch and nothing else. Named,
+    so the trace finds it."""
     import jax
     import jax.numpy as jnp
     import optax
 
     def bench_train_step(params, opt_state, tokens):
-        targets = jnp.roll(tokens, -1, axis=1)
-        loss, grads = jax.value_and_grad(model.loss)(params, tokens, targets)
+        if objective is None:
+            # the targets are made outside what is differentiated: the
+            # program, to its operations' names, that every cell before
+            # the hook compiled
+            targets = jnp.roll(tokens, -1, axis=1)
+            loss, grads = jax.value_and_grad(model.loss)(params, tokens,
+                                                         targets)
+        else:
+            loss, grads = jax.value_and_grad(objective)(params, tokens)
         updates, opt_state = tx.update(grads, opt_state, params)
         return loss, optax.apply_updates(params, updates), opt_state
 
@@ -375,12 +386,16 @@ def train_loop(config: dict) -> None:
     tr = config["trainer"]
     B, S = int(tr["batch"]), int(tr["seq"])
     mesh = train.get_mesh()
-    model = load_family(config["model"]["family"]).build(config["model"])
+    family = load_family(config["model"]["family"])
+    ref = _reference_module(config["reference"])
+    objective = objective_of(family, ref)    # half an objective fails here
+    model = family.build(config["model"])
     init = jax.jit(model.init, out_shardings=model.param_shardings(mesh))
     params = init(jax.random.PRNGKey(config["seed"] % (1 << 31)))
     tx = make_optimizer(tr.get("optimizer", {}))
     opt_state = jax.jit(tx.init)(params)
-    step = jax.jit(make_train_step(model, tx), donate_argnums=(0, 1))
+    step = jax.jit(make_train_step(model, tx, objective and objective(model)),
+                   donate_argnums=(0, 1))
     data_sharding = NamedSharding(mesh, P(("dp", "fsdp"), None))
     feed = TokenFeed(config["traffic"], config["seed"],
                      int(model.config.vocab_size), B, S)
@@ -449,14 +464,24 @@ def train_loop(config: dict) -> None:
     loss_end = float(loss_end)
     del params, opt_state, loss, losses
     t_ref0 = time.time()
-    ref = _reference_module(config["reference"])
     rows = int(tr.get("reference_rows", 4))
     seed_key = jax.random.PRNGKey(config["seed"] % (1 << 31))
-    checks = [train_reference_check(ref, model, init(seed_key),
-                                    feed.batch(0), all_losses[0], rows)]
-    checks.append(train_reference_check(
-        ref, model, jax.device_put(trained, model.param_shardings(mesh)),
-        feed.batch(i), loss_end, rows))
+    # where the model counts the rows its held experts work, that count at
+    # the two points the reference looks at: one more program, after the
+    # window, and none for a model that has no such count
+    stats = jax.jit(model.routing_stats) \
+        if hasattr(model, "routing_stats") else None
+    checks, held = [], []
+
+    def look(at, batch, step_loss):
+        checks.append(train_reference_check(ref, model, at, batch,
+                                            step_loss, rows))
+        if stats is not None:
+            held.append(np.asarray(stats(at, batch)).tolist())
+
+    look(init(seed_key), feed.batch(0), all_losses[0])
+    look(jax.device_put(trained, model.param_shardings(mesh)),
+         feed.batch(i), loss_end)
     n_params = int(model.num_params())
     from_sizes = int(ref.num_params(config["sizes"],
                                     int(model.config.padded_vocab)))
@@ -475,7 +500,9 @@ def train_loop(config: dict) -> None:
         "compiles_at_warm": compiles_at_warm,
         "compiles_at_end": compiles_at_end,
         "compiles_after_reference": counts.snapshot(),
-        "trace_span": trace_span, "reference": verdict})
+        "trace_span": trace_span, "reference": verdict,
+        **({"held_rows": dict(zip(("first_step", "after_window"), held))}
+           if held else {})})
 
 
 def mean_loss_tolerance(n16, n32) -> dict:
@@ -501,16 +528,24 @@ def train_reference_check(ref, model, params, tokens, loss: float,
                           rows_per_call: int) -> dict:
     """``loss``, as the step program computed it from ``params`` on the
     batch ``tokens``, against the plain float32 loss from the same
-    parameters on the same batch, within ``mean_loss_tolerance`` as this
-    run measures it on that batch."""
+    parameters on the same batch (the mean of the reference's ``losses``
+    where it states the family's objective, else of the next-token terms),
+    within ``mean_loss_tolerance`` as this run measures it on that batch,
+    ``rows_per_call`` rows at a time."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     kw = ref.model_kwargs(model.config)
+    stated = getattr(ref, "losses", None)
 
-    def nll(dtype):
+    def terms(dtype):
+        """The objective's per-position terms [b, n] whose mean is the
+        loss: the reference's own ``losses`` where it states them, else
+        the next-token terms from its ``hidden`` and ``head``."""
         def fn(params, toks):
+            if stated is not None:
+                return stated(params, toks, dtype=dtype, **kw)
             targets = jnp.roll(toks, -1, axis=1)
             h = ref.hidden(params, toks, dtype=dtype, **kw)
             logits = ref.head(params, h, dtype).astype(jnp.float32)
@@ -519,7 +554,7 @@ def train_reference_check(ref, model, params, tokens, loss: float,
             return lse - gold                                   # [b, S]
         return jax.jit(fn)
 
-    f32, b16 = nll(jnp.float32), nll(jnp.bfloat16)
+    f32, b16 = terms(jnp.float32), terms(jnp.bfloat16)
     n32, n16 = [], []
     for lo in range(0, tokens.shape[0], rows_per_call):
         chunk = tokens[lo:lo + rows_per_call]
@@ -530,7 +565,8 @@ def train_reference_check(ref, model, params, tokens, loss: float,
     ref_loss = float(n32.mean())
     out = mean_loss_tolerance(n16, n32)
     out.update(loss=loss, reference_loss=ref_loss,
-               abs_diff=abs(loss - ref_loss))
+               abs_diff=abs(loss - ref_loss),
+               bf16_noise_limit=NOISE_CEILING * ref_loss)
     out["ok"] = bool(out["abs_diff"] <= out["tolerance"]
-                     and out["bf16_noise"] <= NOISE_CEILING * ref_loss)
+                     and out["bf16_noise"] <= out["bf16_noise_limit"])
     return out

@@ -1,5 +1,5 @@
 """Operations and bytes the algorithms need, from shapes alone. Copied
-arithmetic: ``GPT.flops_per_token`` (ray_tpu/models/gpt.py:162) and the
+arithmetic: ``GPT.flops_per_token`` (ray_tpu/models/gpt.py:156) and the
 ``cost_estimate`` of the flash kernels (ray_tpu/ops/flash_attention.py);
 kept here so that no later PR can move the yardstick."""
 from __future__ import annotations

@@ -87,13 +87,21 @@ def load_family(name: str):
       nothing else: ``config.vocab_size`` (the ids the feed draws from),
       ``config.padded_vocab`` (the rows of the embedding, for the
       parameter count), ``init(rng) -> params``,
-      ``param_shardings(mesh)``, ``loss(params, tokens, targets) ->
-      scalar`` (the bare next-token loss) and ``num_params()``;
+      ``param_shardings(mesh)``, ``num_params()`` and, where the family
+      states no objective of its own, ``loss(params, tokens, targets) ->
+      scalar``, the next-token loss (a term that the reference's
+      ``losses`` does not hold must be off); where it has one,
+      ``routing_stats(params, tokens)`` is called after the window and its
+      counts ride in the final report as ``held_rows``;
     * ``SCOPES``: the ``jax.named_scope`` names by which a train step of
       the family is split (``layer_metrics/_program.scope_ms_per_step``);
     * ``train_flops_per_token(sizes, seq) -> int``: the model's own
       forward + backward operations a token, from the configuration's
       ``sizes``, recomputation not counted (``layer_metrics/mfu.py``).
+
+    * optionally ``objective(model) -> fn(params, tokens) -> scalar``: the
+      loss the job minimises where that is not the next-token loss
+      (``objective_of`` has the rules).
 
     The family's plain reference is ``reference/<family>.py``. An unknown
     family fails with the list of those that have a file."""
@@ -109,3 +117,44 @@ def load_family(name: str):
 def family_of(cell: dict):
     """The family file of a loaded cell's configuration."""
     return load_family(cell["config_file"]["model"]["family"])
+
+
+def objective_of(family, reference):
+    """The training objective a family states, or None: then the step
+    minimises ``model.loss(params, tokens, roll(tokens, -1))`` and the
+    check forms the next-token terms from the reference's ``hidden`` and
+    ``head``. A family states its objective in two halves, found by what
+    the two files hold and by nothing else:
+
+    * ``families/<family>.py``: ``objective(model) -> fn(params, tokens)
+      -> scalar``, what the train step differentiates;
+    * ``reference/<family>.py``: ``losses(params, tokens, dtype, **kw) ->
+      float32 [b, n]`` (``kw`` from its ``model_kwargs``): the objective's
+      per-position terms, masks and weights applied, zero where a position
+      does not count, whose MEAN over all entries is that loss, in plain
+      ``jax.numpy``. The check calls it on ``reference_rows`` rows at a
+      time, so a row's terms depend on that row alone.
+
+    Both get the parameters and the batch and nothing else: an objective
+    that draws (which positions are masked, a block's noise level) draws
+    as a pure function of the row's ids and of constants written under
+    ``assumed`` in the configuration's file, so that both halves noise the
+    same positions, a seed repeats its run and the checks at the first
+    step and after the window compare like with like. ``tokens`` of the
+    final report stays steps x B x S, the data consumed; a longer sequence
+    run inside counts in ``train_flops_per_token``. One half without the
+    other fails here, before anything is built."""
+    objective = getattr(family, "objective", None)
+    losses = getattr(reference, "losses", None)
+    both = "a family states both halves of its objective or neither"
+    if objective is not None and losses is None:
+        raise ValueError(
+            f"half an objective: {family.__name__} defines 'objective' but "
+            f"{reference.__name__} (benchmark/reference/) defines no "
+            f"'losses'; {both}")
+    if losses is not None and objective is None:
+        raise ValueError(
+            f"half an objective: {reference.__name__} defines 'losses' but "
+            f"{family.__name__} (benchmark/families/) defines no "
+            f"'objective'; {both}")
+    return objective

@@ -128,21 +128,32 @@ def run_train(cell: dict, args, out_dir: str) -> dict:
     losses = final["losses"]
     window = losses[final["first_in_window"]:]
     finite = all(x == x and abs(x) != float("inf") for x in losses)
-    falling = (len(window) >= 20 and
-               sum(window[-10:]) / 10 < sum(window[:10]) / 10)
+    first10, last10 = sum(window[:10]) / 10, sum(window[-10:]) / 10
+    falling = len(window) >= 20 and last10 < first10
     inside = (final["compiles_at_end"]["backend_compiles"]
               - final["compiles_at_warm"]["backend_compiles"])
+    ref = final["reference"]
     say(f"  train: {final['steps']} steps of {final['batch']}x"
         f"{final['seq']} in {final['elapsed_s']:.2f} s; loss "
         f"{losses[0]:.4f} -> {losses[-1]:.4f}; reports {len(reports)}; "
-        f"compiles inside the window {inside}; reference "
-        f"{final['reference']}")
+        f"compiles inside the window {inside}; reference {ref}"
+        + (f"; held rows {final['held_rows']}" if "held_rows" in final
+           else ""))
     return {
         "kind": "train", "device": final["device"], "train": final,
-        "correct": bool(final["reference"]["ok"] and finite and falling
-                        and inside == 0),
-        "why_not": {"reference": final["reference"], "finite": finite,
+        "correct": bool(ref["ok"] and finite and falling and inside == 0),
+        "why_not": {"reference": ref, "finite": finite,
                     "falling": falling, "compiles_in_window": inside},
+        "compared": {
+            **{f"{at}.{name}": [ref[at][number], ref[at][limit]]
+               for at in ("first_step", "after_window")
+               for name, number, limit in (
+                   ("loss_abs_diff", "abs_diff", "tolerance"),
+                   ("bf16_noise", "bf16_noise", "bf16_noise_limit"))},
+            "n_params": [ref["n_params"], ref["n_params_from_sizes"]],
+            "window_steps_at_least": [len(window), 20],
+            "window_loss_last10_under_first10": [last10, first10],
+            "compiles_in_window": [inside, 0]},
         "attempted": final["steps"], "failed": 0,
         "spans": {
             "process_start_to_window": final["t_window"] - T_PROCESS_START,
@@ -310,6 +321,14 @@ def run_serve(cell: dict, args, out_dir: str) -> dict:
         "kind": cell["traffic_file"]["kind"], "device": fin["device"],
         "correct": bool(ref["ok"] and inside == 0),
         "why_not": {"reference": ref, "compiles_in_window": inside},
+        "compared": {
+            "tokens_wrong": [ref["tokens_wrong"], 0],
+            "worst_gap_to_ref_top": [ref["worst_gap_to_ref_top"],
+                                     ref["tolerance"]],
+            "first_logits_max_abs_diff": [ref["first_logits_max_abs_diff"],
+                                          ref["tolerance"]],
+            "bf16_noise": [ref["bf16_noise"], ref["bf16_noise_limit"]],
+            "compiles_in_window": [inside, 0]},
         "attempted": lat["attempted"], "failed": lat["failed"],
         "window": win, "latencies": lat, "finish": fin,
         "spans": {
@@ -469,6 +488,11 @@ def main() -> int:
                 "metric_names": sorted(line["metrics"]),
                 "device": {k: device[k] for k in ("platform", "kind",
                                                   "count")}}
+    # what `correct` compared, each number beside its limit: the last key
+    # of the line and the last lines of standard error
+    line["compared"] = result["compared"]
+    for name, (number, limit) in result["compared"].items():
+        say(f"  compared {name}: {number!r} limit {limit!r}")
     print(json.dumps(line), flush=True)
     return 0
 

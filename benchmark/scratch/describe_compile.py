@@ -82,7 +82,12 @@ def main() -> int:
     model = spec.family_of(cell).build(cell["config_file"]["model"])
     if args.what == "train":
         tx = chip.make_optimizer(cell["trainer"].get("optimizer", {}))
-        step = chip.make_train_step(model, tx)
+        # the family's own objective where its two files state one
+        objective = spec.objective_of(
+            spec.family_of(cell),
+            chip._reference_module(cell["config_file"]["reference"]))
+        step = chip.make_train_step(model, tx,
+                                    objective and objective(model))
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         opt = jax.eval_shape(tx.init, params)
         S = int(cell["trainer"]["seq"])

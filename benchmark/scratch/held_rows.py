@@ -174,8 +174,9 @@ def main() -> int:
 
         seed = int(args.seeds.split(",")[0])
         tx = chip.make_optimizer(cell["trainer"].get("optimizer", {}))
-        step = jax.jit(chip.make_train_step(model, tx),
-                       donate_argnums=(0, 1))
+        objective = spec.objective_of(spec.family_of(cell), ref)
+        step = jax.jit(chip.make_train_step(
+            model, tx, objective and objective(model)), donate_argnums=(0, 1))
         params = init(jax.random.PRNGKey(seed % (1 << 31)))
         opt = jax.jit(tx.init)(params)
         feed = TokenFeed(cell["traffic_file"], seed, int(c.vocab_size), b, s)

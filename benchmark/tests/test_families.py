@@ -89,26 +89,102 @@ def test_a_family_file_gives_what_the_harness_asks_of_it(
         assert jax.eval_shape(model.loss, shapes, tokens, tokens).shape == ()
         return
     ref = reference()
+    # the objective: the family's own where its two files state one, else
+    # the next-token loss, as ``make_train_step`` takes it
+    objective = spec.objective_of(fam, ref)
+    if objective is None:
+        def loss_of(params, tokens):
+            return model.loss(params, tokens, jnp.roll(tokens, -1, axis=1))
+    else:
+        loss_of = objective(model)
     assert n == ref.num_params(sizes, rows)
     flops = fam.train_flops_per_token(sizes, 16)
     assert isinstance(flops, int) and flops > 0
     params = jax.jit(model.init)(jax.random.PRNGKey(3))
     tokens = TokenFeed({"kind": "train", "token_dist": {"zipf_a": 1.0}},
                        2**31 + 5, vocab, 2, 16).batch(0)
-    loss = float(jax.jit(model.loss)(params, tokens,
-                                     jnp.roll(tokens, -1, axis=1)))
+    loss = float(jax.jit(loss_of)(params, tokens))
     check = chip.train_reference_check(ref, model, params, tokens, loss, 2)
     assert check["ok"], check
+    # the rows of a call are the check's own business: one at a time too
+    assert chip.train_reference_check(ref, model, params, tokens, loss,
+                                      1)["reference_loss"] == \
+        pytest.approx(check["reference_loss"], rel=1e-6)
     # the comparison is one that fails: a loss that left out half the batch
-    half = float(jax.jit(model.loss)(params, tokens[:1],
-                                     jnp.roll(tokens[:1], -1, axis=1)))
+    half = float(jax.jit(loss_of)(params, tokens[:1]))
     assert not chip.train_reference_check(ref, model, params, tokens, half,
                                           2)["ok"]
 
 
 def test_an_unknown_family_fails_with_the_list_of_families():
-    with pytest.raises(ValueError, match=r"unknown model family 'mamba'.*"
-                       r"\['gpt', 'llama'\]"):
+    # whatever files families/ holds, sorted
+    have = spec._module_names("families")
+    assert "gpt" in have and "mamba" not in have
+    with pytest.raises(ValueError) as e:
         spec.load_family("mamba")
+    assert "unknown model family 'mamba'" in str(e.value)
+    assert str(have) in str(e.value)
     with pytest.raises(ValueError, match="unknown model family '_helper'"):
         spec.load_family("_helper")
+
+
+def _module(name, **names):
+    import types
+
+    mod = types.ModuleType(name)
+    vars(mod).update(names)
+    return mod
+
+
+@pytest.mark.parametrize("family,reference,missing", [
+    (_module("fx_fam", objective=lambda model: None), _module("fx_ref"),
+     "fx_ref (benchmark/reference/) defines no 'losses'"),
+    (_module("fx_fam"), _module("fx_ref", losses=lambda *a, **kw: None),
+     "fx_fam (benchmark/families/) defines no 'objective'"),
+])
+def test_half_an_objective_is_refused_by_the_name_of_the_missing_half(
+        family, reference, missing):
+    with pytest.raises(ValueError, match="half an objective") as e:
+        spec.objective_of(family, reference)
+    assert missing in str(e.value)
+
+
+def test_a_family_states_its_objective_in_both_files_or_in_neither():
+    stated = lambda model: None  # noqa: E731
+    assert spec.objective_of(_module("fx_fam"), _module("fx_ref")) is None
+    assert spec.objective_of(
+        _module("fx_fam", objective=stated),
+        _module("fx_ref", losses=lambda *a, **kw: None)) is stated
+    # the families the benchmark has state none: the next-token loss
+    for name in spec._module_names("families"):
+        path = os.path.join(spec.BENCH_DIR, "reference", name + ".py")
+        if os.path.exists(path):
+            assert spec.objective_of(spec.load_family(name), importlib.
+                                     import_module("benchmark.reference."
+                                                   + name)) is None, name
+
+
+def test_the_fixture_objectives_noise_is_the_stated_function_of_the_row():
+    """Both halves of the denoising fixture noise a row as the rule says,
+    worked here one position at a time in Python's integers."""
+    import numpy as np
+
+    fam = spec._load_module("tests/fixtures/families", "fx_denoise")
+    ref = spec._load_module("tests/fixtures/reference", "fx_denoise")
+    block, levels, m32 = 4, 4, 0xFFFFFFFF
+    tokens = np.random.default_rng(5).integers(0, 512, (3, 64)).astype(
+        np.int32)
+    masked = np.zeros(tokens.shape, bool)
+    t = np.zeros(tokens.shape, np.float32)
+    for r, row in enumerate(tokens):
+        key = sum((int(x) + 1) * (2 * i + 1) for i, x in enumerate(row)) & m32
+        for i in range(len(row)):
+            t[r, i] = (1 + (key + 7 * (i // block)) % levels) / levels
+            h = ((key ^ (i * 0x9E3779B1 & m32)) * 0x85EBCA6B) & m32
+            h = ((h ^ (h >> 13)) * 0xC2B2AE35) & m32
+            masked[r, i] = ((h ^ (h >> 16)) >> 8) / float(1 << 24) < t[r, i]
+    assert 0.4 < masked.mean() < 0.8 and len(np.unique(t)) == levels
+    for noise in (fam._noise, ref.noise):
+        got_masked, got_t = noise(tokens, block, levels)
+        assert (np.asarray(got_masked) == masked).all()
+        assert np.allclose(np.asarray(got_t), t)

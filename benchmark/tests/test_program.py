@@ -100,7 +100,7 @@ def test_a_step_is_split_by_the_scopes_of_the_cells_family(monkeypatch):
     assert by["attn"] == pytest.approx(200.0) and set(by) == {
         *GPT_SCOPES, P.UNSCOPED}
     with pytest.raises(ValueError, match=r"unknown model family 'nope'.*"
-                       r"'gpt', 'llama'"):
+                       r"'gpt'"):
         P.scope_ms_per_step(_view(fx, monkeypatch, family="nope"))
 
 
