@@ -1,0 +1,13 @@
+"""Device self time of one train step under the scope ``gmu`` of the
+cell's family, forward, backward and recomputation alike."""
+from benchmark.layer_metrics._program import scope_ms_per_step
+
+LAYER = "models"
+UNIT = "ms"
+MOVES = "train_tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(view):
+    by = scope_ms_per_step(view)
+    return by.get("gmu") if by else None

@@ -28,9 +28,11 @@ vocabulary this chip holds: embedding, logits and loss are over it.
 
 The stack runs ``layer_types`` as RUNS of like layers (``stack_runs``):
 each run one scanned, rematerialised body over its own stacked
-parameters, a run of one a plain call. Parameters are one flat dict:
-``wte``, ``out_norm`` and ``<run>.<kind>.<name>`` stacked over the run's
-layers. Which runs a trace walked is the event ``rtpu.models.stack.runs``.
+parameters, a run of one a plain call, walked by ``models/stack.py`` (the
+walker ``sambay.py`` shares; a run of like layers is a run of periods of
+one kind). Parameters are one flat dict: ``wte``, ``out_norm`` and
+``<run>.<kind>.<name>`` stacked over the run's layers. Which runs a trace
+walked is the event ``rtpu.models.stack.runs``.
 """
 from __future__ import annotations
 
@@ -44,7 +46,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (causal_conv1d, cross_entropy_loss, flash_attention,
                    gated_rmsnorm, rmsnorm, ssd_scan)
-from ..perf.recorder import record as _record
+from .stack import period_runs, walk_stack
 
 # What a rematerialised layer keeps for its backward beside its input, by
 # ``checkpoint_name``. Every layer: the flash kernels' output and row
@@ -74,13 +76,7 @@ _PUBLISHED_LAYER_TYPES = tuple(
 
 def stack_runs(layer_types) -> List[Tuple[str, int]]:
     """``layer_types`` as runs of like layers: [(kind, length), ...]."""
-    runs: List[Tuple[str, int]] = []
-    for kind in layer_types:
-        if runs and runs[-1][0] == kind:
-            runs[-1] = (kind, runs[-1][1] + 1)
-        else:
-            runs.append((kind, 1))
-    return runs
+    return [(period[0], n) for period, n in period_runs(layer_types)]
 
 
 @dataclass(frozen=True)
@@ -289,31 +285,19 @@ class GraniteHybrid:
                 (jax.nn.silu(gate) * up) @ lp["w_down"].astype(c.dtype))
 
     def _run_layers(self, x, params):
-        """The one place the stack is walked: run after run of like
-        layers, each layer rematerialised; a run of several is one scanned
-        body over its stacked parameters, a run of one a plain call."""
+        """The stack, run after run of like layers, each layer
+        rematerialised (``stack.walk_stack``)."""
         c = self.config
         kept = [_REMAT_SAVE if i == 0 else _REMAT_SAVE_LATER_RUNS
                 for i in range(len(self.runs))]
         products = 2 * math.prod(x.shape[:-1]) * c.d_ff \
             * jnp.dtype(c.dtype).itemsize        # mlp_gate and mlp_up
-        _record("rtpu.models.stack.runs", "granite_hybrid",
-                {"runs": [[kind, n] for kind, n in self.runs],
-                 "kept": [list(names) for names in kept],
-                 "kept_bytes_per_layer": products,
-                 "kept_bytes": products * sum(n for _, n in self.runs[1:])})
-        for i, (kind, n) in enumerate(self.runs):
-            prefix = f"{i}.{kind}."
-            lp = {name[len(prefix):]: v for name, v in params.items()
-                  if name.startswith(prefix)}
-            body = jax.checkpoint(
-                lambda h, p, kind=kind: self._block(kind, h, p),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *kept[i]))
-            if n == 1:
-                x = body(x, {name: v[0] for name, v in lp.items()})
-            else:
-                x, _ = jax.lax.scan(lambda h, p: (body(h, p), None), x, lp)
+        x, _ = walk_stack(
+            x, [((kind,), n) for kind, n in self.runs], params,
+            lambda kind, h, p, side, _: (self._block(kind, h, p), {}),
+            kept, model="granite_hybrid",
+            facts={"kept_bytes_per_layer": products,
+                   "kept_bytes": products * sum(n for _, n in self.runs[1:])})
         return x
 
     def apply(self, params: Dict[str, jax.Array],

@@ -4,11 +4,15 @@ The compute-hot path of the framework. The reference has no first-party
 kernels (its CUDA appears only through torch/NCCL deps — SURVEY.md §2
 legend); for a TPU-native framework the hot ops are first-party:
 
-- flash_attention: tiled online-softmax attention on the MXU (Pallas).
+- flash_attention: tiled online-softmax attention on the MXU (Pallas);
+  ``window=`` keeps a causal call to the key blocks of its band.
 - ring_attention: context-parallel attention over the `sp` mesh axis —
   K/V blocks rotate the ring via ppermute while compute overlaps.
 - ssd_scan: Mamba-2's state-space recurrence as a chunked scan, forward
   and backward kernels with the state carried in VMEM (Pallas).
+- selective_scan: Mamba-1's recurrence (a decay for every channel AND
+  state), walked in chunks on the VPU with the state in VMEM, forward and
+  backward kernels (Pallas).
 - layers: rmsnorm/layernorm/gelu/rope/cross-entropy, the causal depthwise
   convolution and the gated norm in plain jnp, shaped so XLA fuses them
   into the adjacent matmuls.
@@ -24,6 +28,7 @@ from .ring_attention import ring_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d, gated_rmsnorm)
 from .ssd_scan import ssd_scan
+from .selective_scan import selective_scan
 from .paged_attention import (paged_attention_decode,
                               paged_attention_prefill, paged_gather_kv,
                               paged_write_prefill, paged_write_step)
@@ -32,6 +37,7 @@ __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "ssd_scan",
+    "selective_scan",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",
     "paged_write_step",

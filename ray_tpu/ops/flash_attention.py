@@ -65,6 +65,15 @@ so each band's numbers are the square's, summed over the non-zero terms.
 Non-causal calls and a single-block call with several q blocks work the
 whole block as one band.
 
+A causal call with a ``window`` (query i sees the keys i - window < j <= i)
+takes the streamed kernels whatever S, and their innermost grid dimension
+runs over the blocks of the band alone (``_band_blocks``): forward and dq
+over the key blocks a query block's window reaches, dk/dv over the query
+blocks whose windows reach a key block; the blocks the band's two edges
+cross are masked, a block wholly outside is never fetched. At S = 8192 in
+blocks of 1024 and a window of 512 that is 2 key blocks a query block (15
+of the 36 a causal call visits).
+
 Reference capability (not design): the reference has no first-party
 attention kernels at all (torch/NCCL stack); this is new TPU-native work
 per SURVEY.md §5.
@@ -228,16 +237,90 @@ _ABT = ((1,), (1,))   # a @ b^T
 _ATB = ((0,), (0,))   # a^T @ b
 
 
-def _scores(q, k, sm_scale, causal, row0, col0):
-    """(q * scale) @ k^T in f32, causal-masked; row0/col0 are the block's
-    first q and k positions."""
+def _scores(q, k, sm_scale, causal, row0, col0, window=None):
+    """(q * scale) @ k^T in f32, causal-masked (and, with a ``window``,
+    masked where the key lies ``window`` or more before the query);
+    row0/col0 are the block's first q and k positions."""
     # scale the (block_q, d) tile, not the (block_q, block_k) s matrix
     s = _dot(q * jnp.asarray(sm_scale, q.dtype), k, _ABT)
     if causal:
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        seen = rows >= cols
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        s = jnp.where(seen, s, _NEG_INF)
     return s
+
+
+# A window (causal, query i sees keys i - window < j <= i) in the streamed
+# kernels: the grid's innermost dimension runs over the blocks of the BAND
+# alone, ``_band_blocks`` of them, block ``first + step`` where ``first`` is
+# the first block the outer block's band reaches. A step past the band's
+# last block is skipped by the causal predicate the kernels have, and its
+# index maps hold still at that last block, so nothing is fetched for it.
+
+
+def _band_first(outer, block_outer: int, block_inner: int, back: int):
+    """The first inner block that the band of outer block ``outer``
+    reaches: its first position less ``back``, not before 0."""
+    return jnp.maximum(outer * block_outer - back, 0) // block_inner
+
+
+def _band_last(outer, block_outer: int, block_inner: int, ahead: int,
+               n_inner: int):
+    """The last inner block that band reaches."""
+    return jnp.minimum((outer * block_outer + block_outer - 1 + ahead)
+                       // block_inner, n_inner - 1)
+
+
+def _band_counts(n_outer: int, block_outer: int, block_inner: int, back: int,
+                 ahead: int, n_inner: int) -> list:
+    """The inner blocks each outer block's band touches. Query blocks over
+    key blocks look ``window - 1`` back and 0 ahead; key blocks over query
+    blocks 0 back and ``window - 1`` ahead."""
+    return [min((o * block_outer + block_outer - 1 + ahead) // block_inner,
+                n_inner - 1)
+            - max(o * block_outer - back, 0) // block_inner + 1
+            for o in range(n_outer)]
+
+
+def _band_blocks(*band) -> int:
+    """The most inner blocks any outer block's band touches: the steps of
+    the grid's innermost dimension."""
+    return max(_band_counts(*band))
+
+
+def _window_vmem(window) -> dict:
+    """The window's second comparison is one more [block_q, block_k] value
+    in a program: at blocks of 1024 the dk/dv kernel then asks 16.6 MB of
+    the compiler's default 16 MB of scoped VMEM. A windowed call asks for
+    32 (of a v5e's 128); a call without a window asks for nothing, as
+    before."""
+    return {} if window is None else {"vmem_limit_bytes": 32 * 1024 * 1024}
+
+
+def _band_of_keys(num_qb, num_kb, block_q, block_k, window):
+    """-> (index map (query block, step) -> key block, steps a query
+    block) of a windowed call's kernels that walk the keys innermost."""
+    def key_block(i, j):
+        return jnp.minimum(_band_first(i, block_q, block_k, window - 1) + j,
+                           _band_last(i, block_q, block_k, 0, num_kb))
+
+    return key_block, _band_blocks(num_qb, block_q, block_k, window - 1, 0,
+                                   num_kb)
+
+
+def _band_of_queries(num_qb, num_kb, block_q, block_k, window):
+    """-> (index map (key block, step) -> query block, steps a key block)
+    of the dk/dv kernel, which walks the queries innermost."""
+    def query_block(j, i):
+        return jnp.minimum(_band_first(j, block_k, block_q, 0) + i,
+                           _band_last(j, block_k, block_q, window - 1,
+                                      num_qb))
+
+    return query_block, _band_blocks(num_kb, block_k, block_q, 0,
+                                     window - 1, num_qb)
 
 
 def _units(rows: int, cols: int, band: int, heads: int):
@@ -299,10 +382,11 @@ def _along(col, like):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *,
                 sm_scale: float, causal: bool, d: int,
-                block_q: int, block_k: int, num_kb: int):
+                block_q: int, block_k: int, num_kb: int, window=None):
     """Grid: (B, column blocks, num_q_blocks, num_k_blocks); K innermost
     so the f32 scratch (m, l, acc: one of each per head of the block)
-    carries across K iterations for one Q block."""
+    carries across K iterations for one Q block. With a ``window`` the K
+    dimension is the ``num_kb`` steps of the band (``_band_blocks``)."""
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     heads = lse_ref.shape[0]
@@ -313,9 +397,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: the block [qi*bq, qi*bq+bq) x [kb*bk, kb*bk+bk) intersects the
-    # lower triangle iff its last row can see its first column.
-    run = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
+    # the key block of this step
+    col = kb if window is None else \
+        kb + _band_first(qi, block_q, block_k, window - 1)
+    # Causal: the block [qi*bq, qi*bq+bq) x [col*bk, col*bk+bk) intersects
+    # the lower triangle iff its last row can see its first column.
+    run = (qi * block_q + block_q - 1 >= col * block_k) if causal else True
 
     @pl.when(run)
     def _compute():
@@ -324,7 +411,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         v = v_ref[...]
         for j in range(heads):
             s = _scores(_head_lanes(q, j, d), k, sm_scale, causal,
-                        qi * block_q, kb * block_k)
+                        qi * block_q, col * block_k, window)
             m_prev = m_scr[j]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -402,9 +489,11 @@ def _cut(q, k, heads, hpb, causal, block_q, block_k):
             _band_height(q.shape[1], causal, block_q, block_k))
 
 
-def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
+               window=None):
     """[B, S, heads*D] in, ``hpb`` heads to a column block ->
-    (out [B, S, heads*D], lse [B*heads, 1, S])."""
+    (out [B, S, heads*D], lse [B*heads, 1, S]). A call with a ``window``
+    takes the streamed kernel whatever S."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
     d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
@@ -426,7 +515,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
         transcendentals=b * heads * seq_q * seq_k,
     )
 
-    if num_kb == 1:
+    if num_kb == 1 and window is None:
         q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i: (b, i, c))
         kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i: (b, 0, c))
         return pl.pallas_call(
@@ -448,13 +537,24 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
             cost_estimate=cost,
         )(q, k, v)
 
+    # the key block of step j of query block i: every block in turn, or,
+    # with a window, the blocks of the band alone
+    key_block, steps = (lambda i, j: j), num_kb
+    if window is not None:
+        key_block, steps = _band_of_keys(seq_q // block_q, num_kb, block_q,
+                                         block_k, window)
+        cost = pl.CostEstimate(
+            flops=4 * b * heads * seq_q * min(window, seq_k) * d,
+            bytes_accessed=cost.bytes_accessed,
+            transcendentals=b * heads * seq_q * min(window, seq_k))
     q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
-    kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i, j: (b, j, c))
+    kv_spec = pl.BlockSpec((None, block_k, w),
+                           lambda b, c, i, j: (b, key_block(i, j), c))
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal, d=d,
-            block_q=block_q, block_k=block_k, num_kb=num_kb),
-        grid=(b, ncb, seq_q // block_q, num_kb),
+            block_q=block_q, block_k=block_k, num_kb=steps, window=window),
+        grid=(b, ncb, seq_q // block_q, steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             q_spec,
@@ -472,7 +572,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
         # under compute; only the K dim (scratch carry) is sequential
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"), **_window_vmem(window)),
         name=KERNEL_NAMES["fwd"],
         interpret=_use_interpret(),
         cost_estimate=cost,
@@ -485,14 +585,14 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k):
 
 
 def _bwd_head(q, k, v, do, lse, delta, j, *, d, sm_scale, causal, row0,
-              col0):
+              col0, window=None):
     """What head j of the block gives one (q block, k block) pair:
     (p, ds, q_j, do_j), p the recomputed probabilities and ds = dL/ds with
     the sm_scale of s = (q·scale)·kᵀ folded in once (it routes into both
     dq and dk), both in the inputs' dtype for the MXU."""
     qj = _head_lanes(q, j, d)
     doj = _head_lanes(do, j, d)
-    p = jnp.exp(_scores(qj, k, sm_scale, causal, row0, col0) - lse)
+    p = jnp.exp(_scores(qj, k, sm_scale, causal, row0, col0, window) - lse)
     dp = _dot(doj, v, _ABT)
     ds = p * (dp - delta) * sm_scale
     return p.astype(do.dtype), ds.astype(k.dtype), qj, doj
@@ -575,10 +675,11 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                    dq_ref, delta_ref, dq_scr, *,
                    sm_scale: float, causal: bool, d: int,
-                   block_q: int, block_k: int, num_kb: int):
+                   block_q: int, block_k: int, num_kb: int, window=None):
     """Grid: (B, column blocks, num_q_blocks, num_k_blocks); accumulates
-    dq over K. Also emits delta [B*heads, 1, S] (``_row_delta``), which it
-    needs itself and the dk/dv kernel reads."""
+    dq over K (with a ``window``, over the band's ``num_kb`` steps). Also
+    emits delta [B*heads, 1, S] (``_row_delta``), which it needs itself and
+    the dk/dv kernel reads."""
     qi = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -588,7 +689,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         for j in range(lse_ref.shape[0]):
             delta_ref[j] = _row_delta(do_ref[...], o_ref[...], j, d).T
 
-    run = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
+    col = kb if window is None else \
+        kb + _band_first(qi, block_q, block_k, window - 1)
+    run = (qi * block_q + block_q - 1 >= col * block_k) if causal else True
 
     @pl.when(run)
     def _compute():
@@ -600,7 +703,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             _, ds, _, _ = _bwd_head(
                 q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
                 sm_scale=sm_scale, causal=causal, row0=qi * block_q,
-                col0=kb * block_k)
+                col0=col * block_k, window=window)
             dq_scr[...] += _dot(ds, _head_lanes(k, j, d), _AB)
 
     @pl.when(kb == num_kb - 1)
@@ -611,9 +714,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *,
                     sm_scale: float, causal: bool, d: int,
-                    block_q: int, block_k: int, num_qb: int):
+                    block_q: int, block_k: int, num_qb: int, window=None,
+                    seq_qb: int = 0):
     """Grid: (B, column blocks, num_k_blocks, num_q_blocks); accumulates
-    dk/dv over Q."""
+    dk/dv over Q (with a ``window``, over the ``num_qb`` steps of the band
+    of query blocks that see this key block, of ``seq_qb`` in all)."""
     kb = pl.program_id(2)
     qi = pl.program_id(3)
 
@@ -622,7 +727,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
+    if window is None:
+        row = qi
+        run = (qi * block_q + block_q - 1 >= kb * block_k) if causal else True
+    else:
+        # the query block of this step: from the first that holds a row at
+        # or past this key block's first key; none past the last whose
+        # window still reaches its last key
+        row = qi + _band_first(kb, block_k, block_q, 0)
+        run = row <= _band_last(kb, block_k, block_q, window - 1, seq_qb)
 
     @pl.when(run)
     def _compute():
@@ -633,8 +746,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for j in range(lse_ref.shape[0]):
             p, ds, qj, doj = _bwd_head(
                 q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
-                sm_scale=sm_scale, causal=causal, row0=qi * block_q,
-                col0=kb * block_k)
+                sm_scale=sm_scale, causal=causal, row0=row * block_q,
+                col0=kb * block_k, window=window)
             dv_scr[...] += _dot(p, doj, _ATB)
             dk_scr[...] += _dot(ds, qj, _ATB)
 
@@ -645,9 +758,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
-               block_k):
+               block_k, window=None):
     """[B, S, heads*D] q, k, v, o, dO and lse [B*heads, 1, S] ->
-    dq, dk, dv [B, S, heads*D]."""
+    dq, dk, dv [B, S, heads*D]. A call with a ``window`` takes the two
+    streamed kernels whatever S, each over its band of blocks alone."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
     d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
@@ -661,7 +775,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
     half = 2 if causal else 1
     bytes_qkv2 = (q.size * 2 + k.size * 2 + v.size * 2) * q.dtype.itemsize
 
-    if num_kb == 1:
+    if num_kb == 1 and window is None:
         # single K block: one fused pass computes s/p once and emits
         # dq + dk + dv together (the two-pass scheme below recomputes the
         # s matrix, mask, and exp in each kernel)
@@ -696,18 +810,32 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
             ),
         )(q, k, v, o, g, lse)
 
+    # the key block of step j of query block i and the query block of step
+    # i of key block j: every block in turn, or, with a window, the blocks
+    # of the band alone
+    key_block, k_steps = (lambda i, j: j), num_kb
+    query_block, q_steps = (lambda j, i: i), num_qb
+    if window is not None:
+        key_block, k_steps = _band_of_keys(num_qb, num_kb, block_q, block_k,
+                                           window)
+        query_block, q_steps = _band_of_queries(num_qb, num_kb, block_q,
+                                                block_k, window)
+        half = max(1, seq_k // min(window, seq_k))
+
     q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
     row_spec = pl.BlockSpec((hpb, 1, block_q),
                             lambda b, c, i, j: (b * ncb + c, 0, i))
-    kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i, j: (b, j, c))
+    kv_spec = pl.BlockSpec((None, block_k, w),
+                           lambda b, c, i, j: (b, key_block(i, j), c))
     parallel3 = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        **_window_vmem(window))
 
     dq, delta = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, d=d,
-            block_q=block_q, block_k=block_k, num_kb=num_kb),
-        grid=(b, ncb, num_qb, num_kb),
+            block_q=block_q, block_k=block_k, num_kb=k_steps, window=window),
+        grid=(b, ncb, num_qb, k_steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -724,15 +852,18 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
     )(q, k, v, o, g, lse)
 
     # dk/dv: Q streams in the minor grid dim.
-    qb_spec = pl.BlockSpec((None, block_q, w), lambda b, c, j, i: (b, i, c))
-    rowb_spec = pl.BlockSpec((hpb, 1, block_q),
-                             lambda b, c, j, i: (b * ncb + c, 0, i))
+    qb_spec = pl.BlockSpec((None, block_q, w),
+                           lambda b, c, j, i: (b, query_block(j, i), c))
+    rowb_spec = pl.BlockSpec(
+        (hpb, 1, block_q),
+        lambda b, c, j, i: (b * ncb + c, 0, query_block(j, i)))
     kb_spec = pl.BlockSpec((None, block_k, w), lambda b, c, j, i: (b, j, c))
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, d=d,
-            block_q=block_q, block_k=block_k, num_qb=num_qb),
-        grid=(b, ncb, num_kb, num_qb),
+            block_q=block_q, block_k=block_k, num_qb=q_steps, window=window,
+            seq_qb=num_qb),
+        grid=(b, ncb, num_kb, q_steps),
         in_specs=[qb_spec, kb_spec, kb_spec, qb_spec, rowb_spec, rowb_spec],
         out_specs=[kb_spec, kb_spec],
         out_shape=[
@@ -780,26 +911,29 @@ def _from_bhsd(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-def _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
+def _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
+             window):
     if hpb:
         return _flash_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                          block_k)
+                          block_k, window)
     out, lse = _flash_fwd(_to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
-                          1, 1, sm_scale, causal, block_q, block_k)
+                          1, 1, sm_scale, causal, block_q, block_k, window)
     return _from_bhsd(out, qm.shape[0], h), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
+           window=None):
     return _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                    block_k)[0]
+                    block_k, window)[0]
 
 
-def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
+def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
+                   window):
     from jax.ad_checkpoint import checkpoint_name
 
     out_m, lse = _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                          block_k)
+                          block_k, window)
     # Named so a remat policy can choose to SAVE these residuals: pallas
     # outputs are not dots, so a dots-saveable policy would otherwise
     # re-run the forward kernel inside the backward pass.
@@ -808,16 +942,17 @@ def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k):
     return out_m, (qm, km, vm, out_m, lse)
 
 
-def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, res, g):
+def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, window, res,
+                   g):
     qm, km, vm, out_m, lse = res
     if hpb:
         return _flash_bwd(qm, km, vm, out_m, lse, g, h, hpb, sm_scale,
-                          causal, block_q, block_k)
+                          causal, block_q, block_k, window)
     b = qm.shape[0]
     grads = _flash_bwd(
         _to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
         _to_bhsd(out_m, h), lse, _to_bhsd(g, h), 1, 1, sm_scale, causal,
-        block_q, block_k)
+        block_q, block_k, window)
     return tuple(_from_bhsd(x, b, h) for x in grads)
 
 
@@ -1414,7 +1549,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
                     q_rope: Optional[jax.Array] = None,
-                    k_rope: Optional[jax.Array] = None) -> jax.Array:
+                    k_rope: Optional[jax.Array] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> v's shape.
 
     Heads of 64 or 128 whose merged width heads*head_dim is a multiple of
@@ -1426,7 +1562,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``k_rope`` [batch, seq, dr] for all heads has a score in two parts,
     (q·k + q_rope·k_rope) * sm_scale (default 1/sqrt(head_dim + dr)), and
     v may be of another head size than q and k: the latent kernels, where
-    the sizes tile 128 lanes (``_latent_ok``)."""
+    the sizes tile 128 lanes (``_latent_ok``).
+
+    ``window`` (causal calls of the one-part score): query i sees the keys
+    i - window < j <= i, itself and the window - 1 before it. The streamed
+    kernels then run, whatever S, over the key blocks of each query
+    block's band alone (``window``, ``blocks_visited`` and
+    ``blocks_causal`` in the event)."""
+    if window is not None and (q_rope is not None or not causal
+                               or window < 1):
+        raise ValueError("a window takes a causal call of the one-part "
+                         f"score and at least one key, got window={window}")
     if q_rope is not None:
         return _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale,
                                  block_q, block_k)
@@ -1444,13 +1590,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # Mosaic's minimum tile is (8, 128): sub-128 sequence blocks lower
         # to illegal or silently padded tiles on real TPU. Pads are the
         # caller's job; unpadded odd shapes go to the XLA reference.
-        _note_path("reference", 0, d, s, 0)
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+        _note_path("reference", 0, d, s, 0,
+                   **({} if window is None else {"window": window}))
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                             window=window)
     hpb = _heads_per_block(h, d)
-    band = _band_height(s, causal, block_q, block_k)
-    _note_path("merged" if hpb else "relayout", hpb, d, s,
-               s // band if band else 1)
     merge = lambda x: x.reshape(x.shape[0], x.shape[1], h * d)  # noqa: E731
+    if window is None:
+        band = _band_height(s, causal, block_q, block_k)
+        bands, facts = s // band if band else 1, {}
+    else:
+        bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+        over = (s // bq, bq, bk)
+        bands, facts = 1, {
+            "window": window, "block_q": bq, "block_k": bk,
+            "blocks_visited": sum(_band_counts(*over, window - 1, 0,
+                                               s // bk)),
+            "blocks_causal": sum(_band_counts(*over, s, 0, s // bk))}
+    _note_path("merged" if hpb else "relayout", hpb, d, s, bands, **facts)
     out = _flash(merge(q), merge(k), merge(v), h, hpb, sm_scale, causal,
-                 block_q, block_k)
+                 block_q, block_k, window)
     return out.reshape(b, s, h, d)

@@ -9,6 +9,7 @@ takes libtpu. The shapes are chip_smoke.py's: GPT-2 small at full width,
 S=1024, the train batch chosen there and the serve engine's programs.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -317,6 +318,96 @@ def test_granite4h_train_step_keeps_its_room(one_chip, compiled_kernels,
     # by the product's own fusion; the run of 5 has none
     assert "bf16[4,2,4096,8192]" in text
     assert "bf16[5,2,4096,8192]" not in text
+
+
+def test_selective_scan_at_the_benchmark_cells_shape(one_chip,
+                                                     compiled_kernels):
+    """ISSUE 43: phi4flash_train_s8192's Mamba-1 scan, B=1, S=8192, 5120
+    channels with a state of 16, chunks of 128, as the model feeds it:
+    forward and backward are the two kernels by name, and NO array with
+    the sequence, the channels AND the state (the decays or states of a
+    row: 2.7 GB in float32) is anywhere in the compiled program: the state
+    lives in VMEM, and what reaches HBM of it is one [16, 5120] a chunk."""
+    import re
+
+    sel = importlib.import_module("ray_tpu.ops.selective_scan")
+    b, t, c, n = 1, 8192, 5120, 16
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def loss(x, dt, a, bm, cm, d):
+        return sel.selective_scan(x, dt, a, bm, cm, d, chunk=128).astype(
+            jnp.float32).sum()
+
+    before = sel.PATH_COUNTS["kernel"]
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        sd((b, t, c)), sd((b, t, c), jnp.float32), sd((c, n), jnp.float32),
+        sd((b, t, n)), sd((b, t, n)), sd((c,), jnp.float32)
+    ).compile().as_text()
+    assert sel.PATH_COUNTS["kernel"] == before + 1
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert any(sel.KERNEL_NAMES["fwd"] in c for c in calls)
+    assert any(sel.KERNEL_NAMES["bwd"] in c for c in calls)
+    assert not re.findall(r"f32\[1,8192,(16,5120|5120,16)\]", text)
+    assert re.findall(r"f32\[1,64,16,5120\]", text)     # the chunks' states
+
+
+def test_windowed_flash_at_the_benchmark_cells_shape(one_chip,
+                                                     compiled_kernels):
+    """ISSUE 43: phi4flash_train_s8192's window layer as the model calls
+    it: 80 heads of 64 (four a differential head), S=8192, window 512,
+    blocks of 1024: the three streamed kernels by name, within the scoped
+    VMEM a windowed call asks for (``_window_vmem``; at the default 16 MB
+    the dk/dv kernel is refused by 0.6 MB)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((1, 8192, 80, 64), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, sm_scale=0.125,
+                               window=512).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    assert _kernel_names(text) == {"flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv"}
+
+
+def test_phi4flash_train_step_keeps_its_room(one_chip, compiled_kernels,
+                                             monkeypatch):
+    """ISSUE 43: phi4flash_train_s8192's own train step (the harness's
+    ``make_train_step``, the cell's configuration, optimizer, batch 1 of
+    8192, parameters and optimizer state donated) for the described v5e:
+    697 M parameters at 12 B as arguments (8.37 GB), 5.454 GB of
+    temporaries with every layer keeping the kernels' outputs, the MLP's
+    two products and the mixers' input projections (5.230 GB with nothing
+    kept), the compiler rematerialising nothing on its own, 35.3 T matmul
+    operations a step (42.0 T with nothing kept; ``scripts/
+    train_step_hlo.py --census``), and each kernel in the program as often
+    as the six layers need it: no forward kernel a second time."""
+    import os
+
+    monkeypatch.syspath_prepend(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    tool = _hlo_tool()
+    compiled = tool.compile_step("phi4flash_train_s8192", one_chip)
+    assert 8.3e9 < _fits(compiled) < 8.45e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.6e9
+    text = compiled.as_text()
+    assert "s32[1,8192]" in text            # the cell's batch, not another
+    assert tool.compiler_remat(text) == 0
+    assert sum(tool.matmul_census(text).values()) < 35.5e12
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda name: sum(                                # noqa: E731
+        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
+    # two Mamba-1 layers; a window, a full and a cross layer
+    assert count("selscan_chunk_fwd") == count("selscan_chunk_bwd") == 2
+    assert count("flash_fwd") == count("flash_bwd_dq") \
+        == count("flash_bwd_dkv") == 3
 
 
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):

@@ -13,12 +13,13 @@ import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
                             GraniteHybrid, GraniteHybridConfig, Llama,
-                            LlamaConfig)
+                            LlamaConfig, SambaY, SambaYConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
 el = importlib.import_module("ray_tpu.ops.expert_layer")
 ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
+sel = importlib.import_module("ray_tpu.ops.selective_scan")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -207,8 +208,44 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
     # ISSUE 34: which backward the call's shapes selected
     assert path["data"]["backward"] == "fused"
 
+@pytest.mark.parametrize("key,name", [("fwd", "selscan_chunk_fwd"),
+                                      ("bwd", "selscan_chunk_bwd")])
+def test_selective_scan_kernel_names_are_pinned(key, name):
+    """ISSUE 43: ``selective_scan_roofline`` finds its kernels by these."""
+    assert sel.KERNEL_NAMES[key] == name
+
+    def lowered(channels):
+        x = jnp.zeros((1, 64, channels), jnp.bfloat16)
+        bc = jnp.zeros((1, 64, 16), jnp.bfloat16)
+        return jax.jit(jax.grad(lambda x, dt, bm, cm: sel.selective_scan(
+            x, dt, -jnp.ones((channels, 16)), bm, cm, jnp.ones((channels,)),
+            chunk=32).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))).lower(
+            x, jnp.ones((1, 64, channels)), bc, bc).as_text(debug_info=True)
+
+    pattern = r"[/\"(]" + name + r"[/\")]"
+    assert re.search(pattern, lowered(128))
+    # a call whose channels do not tile the lanes holds neither kernel
+    assert not re.search(pattern, lowered(96))
+
+
+@pytest.mark.parametrize("key,name", [("fwd", "flash_fwd"),
+                                      ("bwd_dq", "flash_bwd_dq"),
+                                      ("bwd_dkv", "flash_bwd_dkv")])
+def test_a_windowed_call_takes_the_streamed_kernels_by_name(key, name):
+    """ISSUE 43: a call with a window runs the streamed one-part kernels
+    under the names they have, whatever S (here one block), so that the
+    readers that find them by name find a window layer's time too."""
+    assert fa.KERNEL_NAMES[key] == name
+    q = jnp.zeros((1, 128, 2, 64), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda q, k, v: fa.flash_attention(
+        q, k, v, window=48).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(q, q, q).as_text(debug_info=True)
+    assert re.search(r"[/\"(]" + name + r"[/\")]", text)
+    assert "flash_fwd_single" not in text and "flash_bwd_fused" not in text
+
 
 MODELS = {
+    "sambay": lambda: SambaY(SambaYConfig.tiny()),
     "deepseek_v3": lambda: DeepseekV3(DeepseekV3Config.tiny(experts_held=4)),
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
@@ -234,7 +271,9 @@ def lowered_losses():
     (s, m) for m in sorted(MODELS)
     for s in ("embed", "attn", "mlp", "lm_head", "loss")
     + (("router", "experts", "shared_expert") if m == "deepseek_v3" else ())
-    + (("mixer", "conv", "scan") if m == "granite_hybrid" else ())])
+    + (("mixer", "conv", "scan") if m in ("granite_hybrid", "sambay")
+       else ())
+    + (("gmu", "cross_attn") if m == "sambay" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
     names = lowered_losses[model]
