@@ -36,13 +36,14 @@ with gain and bias, no position term anywhere:
   the side state's; full causal; the same differential form with its own
   ``lam`` vectors, sub-norm gain and ``W_o``.
 
-Each score map is formed TWICE here: the one-part flash kernels take one
-head size for q, k and v, so a differential head is handed to them as four
-heads of 64, (q1, k1, v1), (q1, k1, v2), (q2, k2, v1), (q2, k2, v2), in ONE
-call whose two-head column blocks come out as softmax(.)[v1 | v2] (the
-published code makes the same four products in four calls). A value head
-twice as wide as the score head inside the kernels would form each map
-once (ROADMAP B19).
+Each score map is formed ONCE: ``flash_attention`` is handed the pair of
+score heads (q1, q2), (k1, k2) of 64 and the ONE value [v1 | v2] of 128 as
+the projections made them, and its paired streamed kernels
+(``ops/flash_attention.py``) multiply each map with all 128 value lanes
+(the published code makes four products of 64 in four calls). Heads the
+paired kernels do not tile (the ``tiny`` preset's 16) are expanded inside
+``flash_attention`` to four heads a differential head. A key/value group
+is still repeated to its differential heads here (ROADMAP B19).
 
 ``layers`` are the published indices held here (all 32, or a pipeline
 stage's cut; a cut that holds a reader holds the writers), ``vocab_size``
@@ -83,7 +84,7 @@ from .stack import period_runs, walk_stack
 # GB; the compiler makes nothing again on its own in any of them. On the
 # chip the MLP's products are 451.8 against 479.1 ms a step. What is still
 # made again is cheap: norms, the convolution, silu and gates, the x- and
-# dt-projections, the four-heads expansion around the flash kernels.
+# dt-projections, the groups' repeat around the flash kernels.
 # (``granite_hybrid``'s first run cannot keep the products: its peak is in
 # that run's backward.)
 _REMAT_SAVE = ("flash_out", "flash_lse", "selscan_out", "selscan_states",
@@ -319,16 +320,15 @@ class SambaY:
         b, s, h, hd = q.shape
         nd, g = h // 2, k.shape[2] // 2
         share = nd // g
-        q = jnp.repeat(q.reshape(b, s, nd, 2, hd), 2, axis=3)
-        k = jnp.repeat(jnp.repeat(k.reshape(b, s, g, 2, hd), 2, axis=3),
-                       share, axis=2)
-        v = jnp.repeat(jnp.tile(v.reshape(b, s, g, 2, hd), (1, 1, 1, 2, 1)),
-                       share, axis=2)
-        # four heads of hd a differential head: (q1 k1 v1) (q1 k1 v2)
-        # (q2 k2 v1) (q2 k2 v2) -> softmax(q1 k1^T)[v1|v2], softmax(q2 k2^T)[v1|v2]
-        o = flash_attention(*(t.reshape(b, s, 4 * nd, hd) for t in (q, k, v)),
-                            causal=True, sm_scale=1.0 / math.sqrt(hd),
-                            window=window)
+        # a key/value group to its differential heads; the pair (k1, k2)
+        # and the value [v1 | v2] of 2 hd stay as projected
+        k = jnp.repeat(k.reshape(b, s, g, 2 * hd), share, axis=2)
+        v = jnp.repeat(v.reshape(b, s, g, 2 * hd), share, axis=2)
+        # a pair of score heads of hd a value of 2 hd: head 2i of o is
+        # softmax(q1 k1^T)[v1|v2], head 2i+1 softmax(q2 k2^T)[v1|v2], each
+        # map formed once where the paired kernels tile the call
+        o = flash_attention(q, k.reshape(b, s, h, hd), v, causal=True,
+                            sm_scale=1.0 / math.sqrt(hd), window=window)
         o = o.reshape(b, s, nd, 2, 2 * hd).astype(jnp.float32)
         f32 = lambda name: lp[name].astype(jnp.float32)       # noqa: E731
         lam_init = 0.8 - 0.6 * jnp.exp(-0.3 * index)

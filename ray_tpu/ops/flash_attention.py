@@ -16,6 +16,22 @@ The routes, chosen by what a call shows (``PATH_COUNTS``, the event
   whose other lanes are zeroed (``_head_lanes``): a product with an exact
   zero adds an exact zero in the float32 accumulator, so each head's
   numbers are those of a kernel that saw its D lanes alone.
+* ``paired``: a call that shows a PAIR of score heads a value: q and k
+  [B, S, H, 64], v [B, S, H/2, 128]; score heads 2i and 2i+1 share value
+  i, and the call returns [B, S, H, 128], head 2i softmax(q_2i k_2i^T) V_i
+  and head 2i+1 softmax(q_2i+1 k_2i+1^T) V_i (differential attention
+  before its subtraction). In the merged layout the pair (q_2i | q_2i+1)
+  is ONE 128-lane column block, the pair of keys another, and V_i a third:
+  one program of the STREAMED kernels holds all three, forms each of the
+  two maps once (the other head's lanes of q zeroed, as on the ``merged``
+  route) and multiplies it with all 128 value lanes; o and dO are two
+  128-lane blocks a program, dv the one value's with both maps summed
+  into it. The streamed kernels whatever S (one block is a grid of one
+  step); a branch of their bodies taken at trace time by the widths of
+  the tiles (``_value_lanes``). A paired call whose shapes do not tile (S
+  no multiple of 128, score heads not of 64) is expanded here to four
+  heads a pair, (q1 k1 v1) (q1 k1 v2) (q2 k2 v1) (q2 k2 v2), and takes the
+  routes of a call with one head size (``_paired_attention``).
 * ``relayout``: every other single head size (32, 96, 192, 256, ..., or a
   merged width that is no multiple of 128) is first transposed to
   [B*H, S, D] (``_to_bhsd``) and runs the same kernels as B*H batches of
@@ -124,9 +140,10 @@ LATENT_KERNEL_NAMES = {
 }
 
 # Traced calls of flash_attention by the layout each took: "merged" (the
-# kernels index [B, S, H*D] as it stands), "relayout" (transposed to
-# [B*H, S, D] around the kernels), "reference" (mha_reference, no
-# kernel). Counted where the choice is made, once per trace; the same
+# kernels index [B, S, H*D] as it stands), "paired" (merged, two score
+# heads of 64 and their one value of 128 to a program), "relayout"
+# (transposed to [B*H, S, D] around the kernels), "reference"
+# (mha_reference, no kernel). Counted where the choice is made, once per trace; the same
 # choice is the flight-recorder event ``rtpu.ops.flash.path``.
 PATH_COUNTS: collections.Counter = collections.Counter()
 
@@ -225,6 +242,19 @@ def _head_lanes(x, j: int, d: int):
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     return jnp.where((lane >= j * d) & (lane < (j + 1) * d), x,
                      jnp.zeros_like(x))
+
+
+def _value_lanes(x, j: int, d: int, paired: bool):
+    """What head j of a program reads of a tile on the value's side (v, o,
+    dO). One head size: head j's D lanes, the others zeroed. A PAIRED
+    program (two score maps of D against ONE value of 128): the whole tile
+    where it is that value (v), map j's 128 lanes of a tile that holds one
+    block a map (o, dO): a static slice on a lane-tile boundary."""
+    if not paired:
+        return _head_lanes(x, j, d)
+    if x.shape[-1] == _LANES:
+        return x
+    return x[:, j * _LANES:(j + 1) * _LANES]
 
 
 def _dot(a, b, contract):
@@ -386,10 +416,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     """Grid: (B, column blocks, num_q_blocks, num_k_blocks); K innermost
     so the f32 scratch (m, l, acc: one of each per head of the block)
     carries across K iterations for one Q block. With a ``window`` the K
-    dimension is the ``num_kb`` steps of the band (``_band_blocks``)."""
+    dimension is the ``num_kb`` steps of the band (``_band_blocks``). A
+    PAIRED program (o's tile twice as wide as q's) holds two score heads
+    of ``d`` and one value of 128: each map is multiplied with the whole v
+    tile and leaves through its own 128 lanes of o."""
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     heads = lse_ref.shape[0]
+    paired = o_ref.shape[-1] != q_ref.shape[-1]
 
     @pl.when(kb == 0)
     def _init():
@@ -418,7 +452,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_scr[j] = acc_scr[j] * alpha + _dot(
-                p.astype(v.dtype), _head_lanes(v, j, d), _AB)
+                p.astype(v.dtype), _value_lanes(v, j, d, paired), _AB)
             m_scr[j] = m_new
 
     @pl.when(kb == num_kb - 1)
@@ -427,9 +461,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         for j in range(heads):
             l = jnp.maximum(l_scr[j], 1e-30)
             o = acc_scr[j] / l   # zero outside head j's lanes
-            out = o if out is None else out + o
+            if paired:           # all 128 lanes are map j's
+                o_ref[:, j * _LANES:(j + 1) * _LANES] = o.astype(o_ref.dtype)
+            else:
+                out = o if out is None else out + o
             lse_ref[j] = (m_scr[j] + jnp.log(l)).T
-        o_ref[...] = out.astype(o_ref.dtype)
+        if not paired:
+            o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -479,30 +517,37 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             o_ref[r0:r0 + h, :] = out.astype(o_ref.dtype)
 
 
-def _cut(q, k, heads, hpb, causal, block_q, block_k):
+def _cut(q, k, heads, hpb, causal, block_q, block_k, paired=False):
     """How [B, S, heads*D] is cut into programs -> (d, width of a column
     block, column blocks, block_q, block_k, height of a causal row band
-    inside a program or 0)."""
+    inside a program or 0, lanes of o and dO a program holds, the head
+    size the cost estimates count: in a ``paired`` call two 128-lane
+    blocks and the mean of the score's 64 and the value's 128)."""
     d = q.shape[-1] // heads
-    return (d, hpb * d, heads // hpb, _fit_block(block_q, q.shape[1]),
+    w = hpb * d
+    return (d, w, heads // hpb, _fit_block(block_q, q.shape[1]),
             _fit_block(block_k, k.shape[1]),
-            _band_height(q.shape[1], causal, block_q, block_k))
+            _band_height(q.shape[1], causal, block_q, block_k),
+            *((2 * w, (d + w) // 2) if paired else (w, d)))
 
 
 def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
-               window=None):
+               window=None, paired=False):
     """[B, S, heads*D] in, ``hpb`` heads to a column block ->
     (out [B, S, heads*D], lse [B*heads, 1, S]). A call with a ``window``
-    takes the streamed kernel whatever S."""
+    takes the streamed kernel whatever S, and so does a ``paired`` one:
+    v [B, S, heads/2 * 128], the ONE value of each pair of score heads of
+    64 that a column block of q and k holds -> out [B, S, heads*128], a
+    128-lane block a score head."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
-                                             block_q, block_k)
+    d, w, ncb, block_q, block_k, band, wo, dd = _cut(
+        q, k, heads, hpb, causal, block_q, block_k, paired)
     num_kb = seq_k // block_k
     from jax.experimental.pallas import tpu as pltpu
 
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((b, seq_q, ncb * wo), q.dtype),
         # [B*heads, 1, S]: q-positions on the LANE axis. A trailing
         # singleton dim ([bh, S, 1]) would tile-pad 128x in HBM
         # (1.5 MB -> 192 MB per layer) and dominate the step in
@@ -510,12 +555,12 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
         jax.ShapeDtypeStruct((b * heads, 1, seq_q), jnp.float32),
     ]
     cost = pl.CostEstimate(
-        flops=4 * b * heads * seq_q * seq_k * d // (2 if causal else 1),
+        flops=4 * b * heads * seq_q * seq_k * dd // (2 if causal else 1),
         bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
         transcendentals=b * heads * seq_q * seq_k,
     )
 
-    if num_kb == 1 and window is None:
+    if num_kb == 1 and window is None and not paired:
         q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i: (b, i, c))
         kv_spec = pl.BlockSpec((None, block_k, w), lambda b, c, i: (b, 0, c))
         return pl.pallas_call(
@@ -544,7 +589,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
         key_block, steps = _band_of_keys(seq_q // block_q, num_kb, block_q,
                                          block_k, window)
         cost = pl.CostEstimate(
-            flops=4 * b * heads * seq_q * min(window, seq_k) * d,
+            flops=4 * b * heads * seq_q * min(window, seq_k) * dd,
             bytes_accessed=cost.bytes_accessed,
             transcendentals=b * heads * seq_q * min(window, seq_k))
     q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
@@ -557,7 +602,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
         grid=(b, ncb, seq_q // block_q, steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            q_spec,
+            pl.BlockSpec((None, block_q, wo), lambda b, c, i, j: (b, i, c)),
             pl.BlockSpec((hpb, 1, block_q),
                          lambda b, c, i, j: (b * ncb + c, 0, i)),
         ],
@@ -589,21 +634,26 @@ def _bwd_head(q, k, v, do, lse, delta, j, *, d, sm_scale, causal, row0,
     """What head j of the block gives one (q block, k block) pair:
     (p, ds, q_j, do_j), p the recomputed probabilities and ds = dL/ds with
     the sm_scale of s = (q·scale)·kᵀ folded in once (it routes into both
-    dq and dk), both in the inputs' dtype for the MXU."""
+    dq and dk), both in the inputs' dtype for the MXU. In a paired program
+    (dO's tile twice as wide as q's) do_j is map j's 128 lanes of dO, and
+    dP runs over all 128 lanes of the one value."""
     qj = _head_lanes(q, j, d)
-    doj = _head_lanes(do, j, d)
+    doj = _value_lanes(do, j, d, do.shape[-1] != q.shape[-1])
     p = jnp.exp(_scores(qj, k, sm_scale, causal, row0, col0, window) - lse)
     dp = _dot(doj, v, _ABT)
     ds = p * (dp - delta) * sm_scale
     return p.astype(do.dtype), ds.astype(k.dtype), qj, doj
 
 
-def _row_delta(do, o, j, d):
-    """delta_i = rowsum(dO_i * O_i) over head j's lanes -> (block_q, 1)
-    f32. Computed where dO and O are already in VMEM: left to XLA, the
-    reduction over 64 of 1024 lanes made it keep dO with S on the lanes
-    and copy it back for the kernel."""
-    return jnp.sum(_head_lanes(do, j, d).astype(jnp.float32)
+def _row_delta(do, o, j, d, paired=False):
+    """delta_i = rowsum(dO_i * O_i) over head j's lanes (a paired
+    program: over map j's 128) -> (block_q, 1) f32. Computed where dO and
+    O are already in VMEM: left to XLA, the reduction over 64 of 1024
+    lanes made it keep dO with S on the lanes and copy it back for the
+    kernel."""
+    if paired:
+        o = _value_lanes(o, j, d, True)
+    return jnp.sum(_value_lanes(do, j, d, paired).astype(jnp.float32)
                    * o.astype(jnp.float32), axis=-1, keepdims=True)
 
 
@@ -687,7 +737,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
         for j in range(lse_ref.shape[0]):
-            delta_ref[j] = _row_delta(do_ref[...], o_ref[...], j, d).T
+            delta_ref[j] = _row_delta(
+                do_ref[...], o_ref[...], j, d,
+                do_ref.shape[-1] != q_ref.shape[-1]).T
 
     col = kb if window is None else \
         kb + _band_first(qi, block_q, block_k, window - 1)
@@ -758,14 +810,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
-               block_k, window=None):
+               block_k, window=None, paired=False):
     """[B, S, heads*D] q, k, v, o, dO and lse [B*heads, 1, S] ->
     dq, dk, dv [B, S, heads*D]. A call with a ``window`` takes the two
-    streamed kernels whatever S, each over its band of blocks alone."""
+    streamed kernels whatever S, each over its band of blocks alone; so
+    does a ``paired`` one (``_flash_fwd``), whose o and dO are
+    [B, S, heads*128] and whose dv is the one value's, both maps summed."""
     b, seq_q, _ = q.shape
     seq_k = k.shape[1]
-    d, w, ncb, block_q, block_k, band = _cut(q, k, heads, hpb, causal,
-                                             block_q, block_k)
+    d, w, ncb, block_q, block_k, band, wo, dd = _cut(
+        q, k, heads, hpb, causal, block_q, block_k, paired)
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
     interp = _use_interpret()
@@ -775,7 +829,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
     half = 2 if causal else 1
     bytes_qkv2 = (q.size * 2 + k.size * 2 + v.size * 2) * q.dtype.itemsize
 
-    if num_kb == 1 and window is None:
+    if num_kb == 1 and window is None and not paired:
         # single K block: one fused pass computes s/p once and emits
         # dq + dk + dv together (the two-pass scheme below recomputes the
         # s matrix, mask, and exp in each kernel)
@@ -823,6 +877,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
         half = max(1, seq_k // min(window, seq_k))
 
     q_spec = pl.BlockSpec((None, block_q, w), lambda b, c, i, j: (b, i, c))
+    o_spec = pl.BlockSpec((None, block_q, wo), lambda b, c, i, j: (b, i, c))
     row_spec = pl.BlockSpec((hpb, 1, block_q),
                             lambda b, c, i, j: (b * ncb + c, 0, i))
     kv_spec = pl.BlockSpec((None, block_k, w),
@@ -836,7 +891,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, d=d,
             block_q=block_q, block_k=block_k, num_kb=k_steps, window=window),
         grid=(b, ncb, num_qb, k_steps),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, o_spec, o_spec, row_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
@@ -845,7 +900,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
         name=KERNEL_NAMES["bwd_dq"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * seq_q * seq_k * d // half,
+            flops=4 * bh * seq_q * seq_k * dd // half,
             bytes_accessed=(q.size * 2 + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * seq_q * seq_k,
         ),
@@ -854,6 +909,8 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
     # dk/dv: Q streams in the minor grid dim.
     qb_spec = pl.BlockSpec((None, block_q, w),
                            lambda b, c, j, i: (b, query_block(j, i), c))
+    dob_spec = pl.BlockSpec((None, block_q, wo),
+                            lambda b, c, j, i: (b, query_block(j, i), c))
     rowb_spec = pl.BlockSpec(
         (hpb, 1, block_q),
         lambda b, c, j, i: (b * ncb + c, 0, query_block(j, i)))
@@ -864,7 +921,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
             block_q=block_q, block_k=block_k, num_qb=q_steps, window=window,
             seq_qb=num_qb),
         grid=(b, ncb, num_kb, q_steps),
-        in_specs=[qb_spec, kb_spec, kb_spec, qb_spec, rowb_spec, rowb_spec],
+        in_specs=[qb_spec, kb_spec, kb_spec, dob_spec, rowb_spec, rowb_spec],
         out_specs=[kb_spec, kb_spec],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -878,7 +935,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
         name=KERNEL_NAMES["bwd_dkv"],
         interpret=interp,
         cost_estimate=pl.CostEstimate(
-            flops=8 * bh * seq_q * seq_k * d // half,
+            flops=8 * bh * seq_q * seq_k * dd // half,
             bytes_accessed=bytes_qkv2,
             transcendentals=bh * seq_q * seq_k,
         ),
@@ -912,28 +969,33 @@ def _from_bhsd(x, b, h):
 
 
 def _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
-             window):
+             window, paired):
     if hpb:
         return _flash_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                          block_k, window)
+                          block_k, window, paired)
     out, lse = _flash_fwd(_to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
                           1, 1, sm_scale, causal, block_q, block_k, window)
     return _from_bhsd(out, qm.shape[0], h), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
-           window=None):
+           window=None, paired=False):
+    """``paired`` (always with ``hpb`` 2): vm is [B, S, h/2 * 128], the
+    one value of each pair of score heads, and the output [B, S, h * 128]
+    (``_flash_fwd``); the merged widths of km and vm are then the same, so
+    the arrays alone do not say it."""
     return _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                    block_k, window)[0]
+                    block_k, window, paired)[0]
 
 
 def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
-                   window):
+                   window, paired):
     from jax.ad_checkpoint import checkpoint_name
 
     out_m, lse = _fwd_any(qm, km, vm, h, hpb, sm_scale, causal, block_q,
-                          block_k, window)
+                          block_k, window, paired)
     # Named so a remat policy can choose to SAVE these residuals: pallas
     # outputs are not dots, so a dots-saveable policy would otherwise
     # re-run the forward kernel inside the backward pass.
@@ -942,12 +1004,12 @@ def _flash_vjp_fwd(qm, km, vm, h, hpb, sm_scale, causal, block_q, block_k,
     return out_m, (qm, km, vm, out_m, lse)
 
 
-def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, window, res,
-                   g):
+def _flash_vjp_bwd(h, hpb, sm_scale, causal, block_q, block_k, window,
+                   paired, res, g):
     qm, km, vm, out_m, lse = res
     if hpb:
         return _flash_bwd(qm, km, vm, out_m, lse, g, h, hpb, sm_scale,
-                          causal, block_q, block_k, window)
+                          causal, block_q, block_k, window, paired)
     b = qm.shape[0]
     grads = _flash_bwd(
         _to_bhsd(qm, h), _to_bhsd(km, h), _to_bhsd(vm, h),
@@ -1544,6 +1606,49 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
     return out.reshape(b, s, h, dv)
 
 
+def _window_facts(window, s: int, block_q: int, block_k: int) -> dict:
+    """What the event of a windowed call says beside the layout: the
+    blocks its grids visit and those a causal call would; nothing for a
+    call without a window."""
+    if window is None:
+        return {}
+    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+    over = (s // bq, bq, bk)
+    return {"window": window, "block_q": bq, "block_k": bk,
+            "blocks_visited": sum(_band_counts(*over, window - 1, 0,
+                                               s // bk)),
+            "blocks_causal": sum(_band_counts(*over, s, 0, s // bk))}
+
+
+def _paired_attention(q, k, v, causal, sm_scale, block_q, block_k, window):
+    """The call that shows a PAIR of score heads a value: q, k
+    [B, S, H, d], v [B, S, H/2, 2d] -> [B, S, H, 2d], head 2i
+    softmax(q_2i k_2i^T) V_i and head 2i+1 softmax(q_2i+1 k_2i+1^T) V_i.
+    Where the shapes tile (d of 64, S a multiple of 128) the streamed
+    kernels, whatever S: one program holds the pair's 128 lanes of q and
+    of k and the value's 128 and forms each map once. Every other call is
+    expanded to four heads of d a pair, (q1 k1 v1) (q1 k1 v2) (q2 k2 v1)
+    (q2 k2 v2), and takes the routes of a call with one head size."""
+    b, s, h, d = q.shape
+    sk = k.shape[1]
+    if sm_scale is None:
+        sm_scale = 1.0 / (d ** 0.5)
+    if s % 128 or sk % 128 or _heads_per_block(h, d) != 2 \
+            or (causal and s != sk):
+        halves = jnp.tile(v.reshape(b, sk, h // 2, 1, 2 * d),
+                          (1, 1, 1, 2, 1)).reshape(b, sk, 2 * h, d)
+        out = flash_attention(jnp.repeat(q, 2, axis=2),
+                              jnp.repeat(k, 2, axis=2), halves, causal,
+                              sm_scale, block_q, block_k, window=window)
+        return out.reshape(b, s, h, 2 * d)
+    _note_path("paired", 2, d, s, 1, hd_v=2 * d,
+               **_window_facts(window, s, block_q, block_k))
+    merge = lambda x: x.reshape(b, x.shape[1], h * d)  # noqa: E731
+    out = _flash(merge(q), merge(k), merge(v), h, 2, sm_scale, causal,
+                 block_q, block_k, window, True)
+    return out.reshape(b, s, h, 2 * d)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     sm_scale: Optional[float] = None,
@@ -1551,12 +1656,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_rope: Optional[jax.Array] = None,
                     k_rope: Optional[jax.Array] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> v's shape.
+    """Flash attention. q/k/v: [batch, seq, heads, head_dim] -> v's shape
+    (a paired call: [batch, seq, heads, 2 * head_dim]).
 
     Heads of 64 or 128 whose merged width heads*head_dim is a multiple of
     128 run with no copy around the kernels; other head sizes are
     transposed to and fro, and sequences that are no multiple of 128 go
     to ``mha_reference`` (module docstring; ``PATH_COUNTS``).
+
+    A PAIRED call is known by its shapes: v has half as many heads as q
+    and k and twice their head size, [batch, seq, heads / 2, 2 * head_dim].
+    Score heads 2i and 2i+1 share value i; the result is
+    [batch, seq, heads, 2 * head_dim], head j softmax(q_j k_j^T) V_(j // 2):
+    each score map formed once against the whole value, in one program of
+    the streamed kernels where the score heads are of 64, and through the
+    four-head expansion otherwise (``layout`` ``paired`` with ``hd_v`` in
+    the event, or the expansion's own).
 
     A call that brings ``q_rope`` [batch, seq, heads, dr] and ONE
     ``k_rope`` [batch, seq, dr] for all heads has a score in two parts,
@@ -1577,6 +1692,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale,
                                  block_q, block_k)
     b, s, h, d = q.shape
+    if k.shape[2:] == (h, d) and (2 * v.shape[2], v.shape[3]) == (h, 2 * d):
+        return _paired_attention(q, k, v, causal, sm_scale, block_q,
+                                 block_k, window)
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if causal and s != k.shape[1]:
@@ -1596,18 +1714,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              window=window)
     hpb = _heads_per_block(h, d)
     merge = lambda x: x.reshape(x.shape[0], x.shape[1], h * d)  # noqa: E731
+    bands = 1
     if window is None:
         band = _band_height(s, causal, block_q, block_k)
-        bands, facts = s // band if band else 1, {}
-    else:
-        bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
-        over = (s // bq, bq, bk)
-        bands, facts = 1, {
-            "window": window, "block_q": bq, "block_k": bk,
-            "blocks_visited": sum(_band_counts(*over, window - 1, 0,
-                                               s // bk)),
-            "blocks_causal": sum(_band_counts(*over, s, 0, s // bk))}
-    _note_path("merged" if hpb else "relayout", hpb, d, s, bands, **facts)
+        bands = s // band if band else 1
+    _note_path("merged" if hpb else "relayout", hpb, d, s, bands,
+               **_window_facts(window, s, block_q, block_k))
     out = _flash(merge(q), merge(k), merge(v), h, hpb, sm_scale, causal,
                  block_q, block_k, window)
     return out.reshape(b, s, h, d)

@@ -376,6 +376,37 @@ def test_windowed_flash_at_the_benchmark_cells_shape(one_chip,
                                    "flash_bwd_dkv"}
 
 
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window-512"])
+def test_paired_flash_at_the_benchmark_cells_shape(one_chip, compiled_kernels,
+                                                   window):
+    """ISSUE 44: phi4flash_train_s8192's attention as the model calls it
+    now: 40 score heads of 64 in pairs against 20 values of 128, S=8192,
+    blocks of 1024, full causal (the full and the cross layer) and under
+    the window of 512: the three streamed kernels by name and no other
+    custom call, within the scoped VMEM each call asks for (a paired
+    program's dO and o tiles are [1024, 256]; the full call fits the
+    compiler's default, the windowed one asks what a windowed call
+    asks)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    qk = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 20, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, sm_scale=0.125,
+                               window=window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    assert _kernel_names(text) == {"flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # nothing is expanded to 80 heads around the kernels
+    assert "bf16[1,8192,5120]" in text and "[1,8192,80," not in text
+
+
 def test_phi4flash_train_step_keeps_its_room(one_chip, compiled_kernels,
                                              monkeypatch):
     """ISSUE 43: phi4flash_train_s8192's own train step (the harness's

@@ -57,8 +57,15 @@ F32 = dict(dtype=jnp.float32, init_std=0.2)
 LOSS_LIMIT = 5e-6     # absolute, on a loss of about 6.3 (module docstring)
 REL_LIMIT = 2e-4      # of the largest entry: logits, each gradient
 
+# ISSUE 44: the ``tiny`` preset's heads are of 64 (``head_dim`` is the
+# published one), so its attention layers take the PAIRED streamed kernels
+# (each score map once against the value of 128; at S 128 a grid of one
+# step). Heads of 16 do not tile: ``flash_attention`` expands them to four
+# heads a differential head, the form every cut had before.
+EXPANDED = dict(head_dim=16)
 CUTS = {
     "six-layer-cut": dict(),
+    "six-layer-cut-heads-of-16": EXPANDED,
     "whole-32-layer-pattern": dict(layers=tuple(range(32)), n_published=32),
 }
 
@@ -273,18 +280,72 @@ def test_the_traced_runs_leave_their_event_with_the_side_states_bytes():
     assert data["side_state_bytes"] == 2 * 8192 * (5120 + 2 * 1280)
 
 
-def _kernel_calls(jaxpr, out):
-    """Pallas calls by kernel name in ``jaxpr`` and every jaxpr under it."""
+def _pallas_eqns(jaxpr):
+    """(kernel name, equation) of every Pallas call in ``jaxpr`` and every
+    jaxpr under it."""
     for e in jaxpr.eqns:
         if e.primitive.name == "pallas_call":
             info = e.params.get("name_and_src_info") or e.params.get("name")
-            out[str(getattr(info, "name", info))] += 1
+            yield str(getattr(info, "name", info)), e
             continue
         for v in e.params.values():
             for j in (v if isinstance(v, (list, tuple)) else [v]):
                 j = getattr(j, "jaxpr", j)
                 if hasattr(j, "eqns"):
-                    _kernel_calls(j, out)
+                    yield from _pallas_eqns(j)
+
+
+def test_heads_of_64_take_the_paired_kernels_three_times():
+    """ISSUE 44: a traced loss of the six-layer cut (heads of 64) leaves
+    ``rtpu.ops.flash.path`` with ``layout`` ``paired`` three times (the
+    window, the full and the cross layer; one with its window) and no other
+    layout, and hands the kernels q, k and v at the ``n_head`` score heads
+    the projections made: nothing is expanded to four heads a differential
+    head. Heads of 16 fall back to that expansion."""
+    import collections
+    import time
+
+    from ray_tpu.perf.recorder import get_recorder
+
+    def traced(model):
+        rec = get_recorder()
+        was, rec.enabled = rec.enabled, True
+        try:
+            start = time.time()     # the ring may be full: by time
+            toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+            jaxpr = jax.make_jaxpr(jax.grad(model.loss))(
+                jax.eval_shape(model.init, jax.random.PRNGKey(0)), toks, toks)
+            events = [e["data"] for e in rec.snapshot()
+                      if e["kind"] == "rtpu.ops.flash.path"
+                      and e["ts"] >= start]
+        finally:
+            rec.enabled = was
+        return jaxpr.jaxpr, events
+
+    model = SambaY(SambaYConfig.tiny())
+    c = model.config
+    jaxpr, events = traced(model)
+    assert [e["layout"] for e in events] == ["paired"] * 3
+    assert all(e["hd"] == 64 and e["hd_v"] == 128 for e in events)
+    assert [e.get("window") for e in events] == [c.sliding_window, None, None]
+    assert dict(_kernel_calls(jaxpr, collections.Counter())) == {
+        "selscan_chunk_fwd": 2, "selscan_chunk_bwd": 2, "flash_fwd": 3,
+        "flash_bwd_dq": 3, "flash_bwd_dkv": 3}
+    widths = {v.aval.shape[-1] for name, e in _pallas_eqns(jaxpr)
+              if name == "flash_fwd" for v in e.invars + e.outvars[:1]}
+    # q, k, v at n_head x 64 lanes (v: n_head / 2 values of 128), o at
+    # n_head x 128; the four-head form's operands were 2 n_head x 64 wide
+    assert widths == {c.n_head * 64, c.n_head * 128}
+    # heads of 16 do not tile: expanded inside the call, the routes of a
+    # call with one head size (eight heads of 16 for n_head 4)
+    _, events = traced(SambaY(SambaYConfig.tiny(**EXPANDED)))
+    assert [e["layout"] for e in events] == ["relayout"] * 3
+    assert all(e["hd"] == 16 and "hd_v" not in e for e in events)
+
+
+def _kernel_calls(jaxpr, out):
+    """Pallas calls by kernel name in ``jaxpr`` and every jaxpr under it."""
+    out.update(name for name, _ in _pallas_eqns(jaxpr))
     return out
 
 
@@ -294,20 +355,26 @@ def test_each_kernel_body_stands_a_bounded_number_of_times():
     does: the Samba pairs are one scanned body and the cross-decoder pairs
     another, so the step's build does not grow with the depth. The scan
     kernels stand in two bodies (the pairs' and the writer's), the flash
-    kernels in three (the window's streamed pair; full and cross, which at
-    S = 128 are single-block calls); every forward kernel once, because
-    what it made is kept for the backward."""
+    kernels in three (window, full, cross: heads of 64 are PAIRED calls,
+    which take the streamed kernels whatever S; ISSUE 44); every forward
+    kernel once, because what it made is kept for the backward. With heads
+    of 16 the calls are expanded to one head size and take the routes they
+    took before: the window's streamed kernels, and for full and cross at
+    S = 128 the single-block pair."""
     import collections
 
-    def calls(depth):
+    def calls(depth, **kw):
         model = SambaY(SambaYConfig.tiny(layers=tuple(range(depth)),
-                                         n_published=depth))
+                                         n_published=depth, **kw))
         toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
         jaxpr = jax.make_jaxpr(jax.grad(model.loss))(
             jax.eval_shape(model.init, jax.random.PRNGKey(0)), toks, toks)
         return dict(_kernel_calls(jaxpr.jaxpr, collections.Counter()))
 
     assert calls(32) == calls(8) == {
+        "selscan_chunk_fwd": 2, "selscan_chunk_bwd": 2, "flash_fwd": 3,
+        "flash_bwd_dq": 3, "flash_bwd_dkv": 3}
+    assert calls(8, **EXPANDED) == {
         "selscan_chunk_fwd": 2, "selscan_chunk_bwd": 2, "flash_fwd": 1,
         "flash_bwd_dq": 1, "flash_bwd_dkv": 1, "flash_fwd_single": 2,
         "flash_bwd_fused": 2}

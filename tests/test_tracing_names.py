@@ -228,18 +228,42 @@ def test_selective_scan_kernel_names_are_pinned(key, name):
     assert not re.search(pattern, lowered(96))
 
 
+# what the call shows beside q [1, 128, 2, 64]: (v's heads, v's head size,
+# window). One block of S: a call with one head size and no window would
+# take the single-block kernels.
+STREAMED_WHATEVER_S = {"windowed": (2, 64, 48), "paired": (1, 128, None),
+                       "paired-windowed": (1, 128, 48)}
+
+
+@pytest.fixture(scope="module")
+def streamed_texts():
+    q = jnp.zeros((1, 128, 2, 64), jnp.bfloat16)
+    texts = {}
+    for call, (hv, dv, window) in STREAMED_WHATEVER_S.items():
+        v = jnp.zeros((1, 128, hv, dv), jnp.bfloat16)
+        texts[call] = jax.jit(jax.grad(
+            lambda q, k, v, window=window: fa.flash_attention(
+                q, k, v, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))).lower(q, q, v).as_text(debug_info=True)
+    return texts
+
+
+@pytest.mark.parametrize("call", sorted(STREAMED_WHATEVER_S))
 @pytest.mark.parametrize("key,name", [("fwd", "flash_fwd"),
                                       ("bwd_dq", "flash_bwd_dq"),
                                       ("bwd_dkv", "flash_bwd_dkv")])
-def test_a_windowed_call_takes_the_streamed_kernels_by_name(key, name):
+def test_a_windowed_call_takes_the_streamed_kernels_by_name(streamed_texts,
+                                                            key, name, call):
     """ISSUE 43: a call with a window runs the streamed one-part kernels
     under the names they have, whatever S (here one block), so that the
-    readers that find them by name find a window layer's time too."""
+    readers that find them by name find a window layer's time too.
+    ISSUE 44: so does a PAIRED call (two score heads of 64 against one
+    value of 128), with and without a window: its kernels are these three
+    and no other (``diff_attention_roofline`` finds their time by these
+    names; ``tests/test_chip_compile.py`` reads the compiled program's
+    custom calls)."""
     assert fa.KERNEL_NAMES[key] == name
-    q = jnp.zeros((1, 128, 2, 64), jnp.bfloat16)
-    text = jax.jit(jax.grad(lambda q, k, v: fa.flash_attention(
-        q, k, v, window=48).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))).lower(q, q, q).as_text(debug_info=True)
+    text = streamed_texts[call]
     assert re.search(r"[/\"(]" + name + r"[/\")]", text)
     assert "flash_fwd_single" not in text and "flash_bwd_fused" not in text
 
