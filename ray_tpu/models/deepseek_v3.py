@@ -1,14 +1,20 @@
-"""DeepSeek-V3 shaped decoder (``model_type: deepseek_v3``), training path:
-multi-head latent attention without a query bottleneck (``q_lora_rank``
-null), ``first_k_dense`` leading layers with a dense gated MLP and then
-layers of routed + shared experts, of which this chip may hold a share.
+"""DeepSeek-V3 shaped decoder (``model_type: deepseek_v3`` and the models
+that keep its keys), training path: multi-head latent attention with or
+without a query bottleneck (``q_lora_rank``), RoPE plain or YaRN-scaled,
+``first_k_dense`` leading layers with a dense gated MLP and then layers of
+routed + shared experts, of which this chip may hold a share; the
+residual one stream, or ``hc_mult`` streams mixed around every sublayer
+by manifold-constrained hyper-connections (``ops/hyper_connection.py``).
 
-Per layer, x̂ = RMSNorm(x):
+Per sublayer, x̂ = RMSNorm(x) (x the residual, or with ``hc_mult`` > 1 the
+streams' weighted sum z):
 
-* attention: per head ``[q_nope | q_rope] = x̂ W_q``; ``[c | k_pe] =
+* attention: per head ``[q_nope | q_rope] = x̂ W_q``, or with
+  ``q_lora_rank`` ``RMSNorm(x̂ W_qa) W_qb``; ``[c | k_pe] =
   x̂ W_kva``; ``[k_nope | v] = RMSNorm(c) W_kvb``; RoPE (interleaved pairs)
   on every head's ``q_rope`` and on the ONE ``k_pe`` all heads share;
-  ``score_h = (q_nope_h·k_nope_h + q_rope_h·k_pe) / sqrt(dn + dr)``, causal
+  ``score_h = (q_nope_h·k_nope_h + q_rope_h·k_pe) / sqrt(dn + dr)`` (times
+  YaRN's m² where ``rope_factor`` > 1: ``ops.layers.yarn_rope_cache``), causal
   softmax, ``o_h = P v_h``; out ``concat_h(o_h) W_o``. The attention runs
   in the latent flash kernels (``ops/flash_attention.py``), which take the
   two parts of the score apart, so the projections are kept as separate
@@ -24,11 +30,16 @@ and loss are over it (a sliced vocabulary is a smaller vocabulary).
 
 The stack: the dense layers one by one, then the expert layers through
 one scanned runner; every layer is rematerialised, saving what
-``_REMAT_SAVE`` names.
+``_REMAT_SAVE`` names. The carry is the residual [B, S, d], or the tuple
+of its ``hc_mult`` streams.
+
+Not here: the multi-token-prediction module (``num_nextn_predict_layers``)
+of the checkpoints that have one, and its second loss term.
 """
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -39,6 +50,10 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import (apply_rope, cross_entropy_loss, flash_attention, rmsnorm,
                    rope_cache)
 from ..ops.expert_layer import held_expert_layer
+from ..ops.hyper_connection import (HC_PARAMS, hc_coefficients,
+                                    hc_param_shapes, hc_post, hc_pre)
+from ..ops.layers import yarn_rope_cache, yarn_softmax_scale
+from ..perf.recorder import record as _record
 
 
 # What a rematerialised layer keeps for its backward, by
@@ -48,6 +63,12 @@ from ..ops.expert_layer import held_expert_layer
 # tokens a step and five layers, keeping them too is 88 MB more than a v5e
 # holds beside 576 M parameters' adamw state (PERF.md, PR 33).
 _REMAT_SAVE = ("flash_out", "flash_lse", "attn_q")
+# Behind a query bottleneck q is two products of a ``q_lora_rank``-wide row
+# and is made again. Four residual streams of d 3584 at 8192 tokens a step
+# make a layer's input 235 MB; with q kept too (101 MB a layer) a v5e
+# refused five layers beside 759 M parameters' adamw state by 1.73 MB
+# (PERF.md, PR 45).
+_REMAT_SAVE_BOTTLENECK = ("flash_out", "flash_lse")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -65,6 +86,7 @@ class DeepseekV3Config:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None  # the query bottleneck, if any
     d_ff: int = 6144                  # the dense layers' gated MLP
     d_expert: int = 768               # one routed expert's gated MLP
     n_routed_experts: int = 128       # the router's width
@@ -75,10 +97,33 @@ class DeepseekV3Config:
     routed_scaling_factor: float = 2.448
     max_seq: int = 32768
     rope_base: float = 1000000.0
+    # YaRN (``rope_scaling``, the DeepSeek-V3 convention); factor 1: plain
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # hyper-connections: residual streams (1: the plain residual)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    num_nextn_predict_layers: int = 0
     rms_eps: float = 1e-6
     init_std: float = 0.02            # residual projections: / sqrt(2 L)
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.num_nextn_predict_layers != 0:
+            raise ValueError(
+                f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
+                "this model has no multi-token-prediction module (one more "
+                "expert layer over [h | Emb(next)] W_proj sharing the "
+                "embedding and the head, and its weighted second loss "
+                "term); build with 0, which is the next-token model")
+
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, 128)
@@ -97,6 +142,24 @@ class DeepseekV3Config:
         """kakaocorp/kanana-2-30b-a3b-instruct-2601 ``config.json``."""
         return DeepseekV3Config(**kw)
 
+    @staticmethod
+    def xing4_29b_a4b(**kw) -> "DeepseekV3Config":
+        """XingChen-AGI/Xing4.0-29B-A4B ``config.json`` (``model_type``
+        ``xing4_0``), every published width; its prediction module
+        (``num_nextn_predict_layers`` 1) is not built."""
+        base = dict(vocab_size=131072, n_layer=40, first_k_dense=2,
+                    d_model=3584, n_head=32, kv_lora_rank=512,
+                    q_lora_rank=768, d_ff=9216, d_expert=1024,
+                    n_routed_experts=64, experts_held=64, n_shared_experts=1,
+                    top_k=4, routed_scaling_factor=2.0, max_seq=262144,
+                    rope_base=10000.0, rope_factor=64.0,
+                    rope_original_max=4096, rope_beta_fast=32.0,
+                    rope_beta_slow=1.0, rope_mscale=1.0,
+                    rope_mscale_all_dim=1.0, hc_mult=4, hc_sinkhorn_iters=20,
+                    hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0))
+        base.update(kw)
+        return DeepseekV3Config(**base)
+
 
 class DeepseekV3:
     """init / loss pytree model in the house style (gpt.py, llama.py).
@@ -110,19 +173,33 @@ class DeepseekV3:
     # -- parameters --------------------------------------------------------
 
     def _shapes(self) -> Dict[str, Tuple[Tuple[int, ...], Optional[float]]]:
-        """name -> (shape, std of its normal init; None: ones, 0: zeros)."""
+        """name -> (shape, std of its normal init; None: ones, 0: zeros,
+        ("fill", v): the constant v)."""
         c = self.config
         d, h, r = c.d_model, c.n_head, c.kv_lora_rank
         dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         std, res = c.init_std, c.init_std / math.sqrt(2 * c.n_layer)
+        q_in = c.q_lora_rank or d       # what the head projections read
         attn = {
             "attn_norm": ((d,), None),
-            "w_q_nope": ((d, h * dn), std), "w_q_rope": ((d, h * dr), std),
+            "w_q_nope": ((q_in, h * dn), std),
+            "w_q_rope": ((q_in, h * dr), std),
             "w_kv_a": ((d, r), std), "w_k_rope": ((d, dr), std),
             "kv_norm": ((r,), None),
             "w_k_b": ((r, h * dn), std), "w_v_b": ((r, h * dv), std),
             "w_o": ((h * dv, d), res), "mlp_norm": ((d,), None),
         }
+        if c.q_lora_rank:
+            attn.update({"w_q_a": ((d, c.q_lora_rank), std),
+                         "q_norm": ((c.q_lora_rank,), None)})
+        if c.hc_mult > 1:
+            # one set a sublayer: Φ ~ N(0, init_std), gain 1, the biases
+            # N(0, 1) so that no map starts degenerate, every α 0.01
+            how = {"phi": std, "gain": None, "bias": 1.0,
+                   "alpha": ("fill", 0.01)}
+            attn.update({f"{sub}.{name}": (shape, how[name])
+                         for sub in ("hc_attn", "hc_mlp") for name, shape
+                         in hc_param_shapes(c.hc_mult, d).items()})
         fs = c.n_shared_experts * c.d_expert
         g = c.experts_held
         kinds = {
@@ -151,7 +228,8 @@ class DeepseekV3:
         shapes = self._shapes()
         keys = jax.random.split(rng, len(shapes))
         return {n: (jnp.ones(shape, pd) if std is None else
-                    jax.random.normal(k, shape, pd) * std)
+                    jnp.full(shape, std[1], pd) if isinstance(std, tuple)
+                    else jax.random.normal(k, shape, pd) * std)
                 for k, (n, (shape, std)) in zip(keys, shapes.items())}
 
     def param_shardings(self, mesh, rules=None):
@@ -173,7 +251,27 @@ class DeepseekV3:
 
     # -- layers ------------------------------------------------------------
 
+    def _residual(self, x, lp, which, scope, f):
+        """The one residual path around a sublayer ``f(its input) -> (y,
+        aux)``. One stream: ``x + y``, the sum under the sublayer's own
+        ``scope`` as ever. ``hc_mult`` streams (x their tuple): the set
+        ``which`` of the layer's hyper-connection parameters reads them
+        into f's input and mixes f's output back into them. -> (x', aux)"""
+        c = self.config
+        if c.hc_mult == 1:
+            y, aux = f(x)
+            with jax.named_scope(scope) if scope else nullcontext():
+                return x + y, aux
+        pre, post, res = hc_coefficients(
+            x, {name: lp[f"{which}.{name}"] for name in HC_PARAMS},
+            iters=c.hc_sinkhorn_iters, eps=c.hc_eps,
+            clamp=tuple(c.hc_res_clamp), rms_eps=c.rms_eps)
+        y, aux = f(hc_pre(x, pre))
+        return hc_post(x, y, post, res), aux
+
     def _attention(self, x, lp, cos, sin):
+        """The attention sublayer of its input x, norm first, without the
+        residual."""
         c = self.config
         b, s, _ = x.shape
         h, dt = c.n_head, c.dtype
@@ -182,9 +280,12 @@ class DeepseekV3:
             latent = rmsnorm(xn @ lp["w_kv_a"].astype(dt), lp["kv_norm"],
                              c.rms_eps)
             heads = lambda t: t.reshape(b, s, h, -1)  # noqa: E731
-            q_nope = xn @ lp["w_q_nope"].astype(dt)
-            q_rope = _rope_interleaved(heads(xn @ lp["w_q_rope"].astype(dt)),
-                                       cos, sin).reshape(b, s, -1)
+            q_in = xn if not c.q_lora_rank else rmsnorm(
+                xn @ lp["w_q_a"].astype(dt), lp["q_norm"], c.rms_eps)
+            q_nope = q_in @ lp["w_q_nope"].astype(dt)
+            q_rope = _rope_interleaved(
+                heads(q_in @ lp["w_q_rope"].astype(dt)),
+                cos, sin).reshape(b, s, -1)
             k_rope = _rope_interleaved(
                 (xn @ lp["w_k_rope"].astype(dt))[:, :, None, :], cos,
                 sin)[:, :, 0, :]
@@ -194,39 +295,56 @@ class DeepseekV3:
             # 64-wide minor dimension would be kept padded to 128 lanes)
             q_nope, q_rope = (checkpoint_name(t, "attn_q")
                               for t in (q_nope, q_rope))
-            o = flash_attention(heads(q_nope), heads(k_nope), heads(v),
-                                causal=True, q_rope=heads(q_rope),
-                                k_rope=k_rope)
-            return x + o.reshape(b, s, -1) @ lp["w_o"].astype(dt)
+            o = flash_attention(
+                heads(q_nope), heads(k_nope), heads(v), causal=True,
+                q_rope=heads(q_rope), k_rope=k_rope,
+                sm_scale=None if c.rope_factor == 1.0 else yarn_softmax_scale(
+                    c.qk_nope_head_dim + c.qk_rope_head_dim, c.rope_factor,
+                    c.rope_mscale_all_dim))
+            return o.reshape(b, s, -1) @ lp["w_o"].astype(dt), None
+
+    def _attention_sublayer(self, x, lp, cos, sin):
+        return self._residual(
+            x, lp, "hc_attn", "attn",
+            lambda z: self._attention(z, lp, cos, sin))[0]
 
     def _dense_block(self, x, lp, cos, sin):
         c = self.config
-        x = self._attention(x, lp, cos, sin)
-        with jax.named_scope("mlp"):
-            xn = rmsnorm(x, lp["mlp_norm"], c.rms_eps)
-            hid = jax.nn.silu(xn @ lp["w_gate"].astype(c.dtype)) \
-                * (xn @ lp["w_up"].astype(c.dtype))
-            return x + hid @ lp["w_down"].astype(c.dtype)
+
+        def mlp(z):
+            with jax.named_scope("mlp"):
+                xn = rmsnorm(z, lp["mlp_norm"], c.rms_eps)
+                hid = jax.nn.silu(xn @ lp["w_gate"].astype(c.dtype)) \
+                    * (xn @ lp["w_up"].astype(c.dtype))
+                return hid @ lp["w_down"].astype(c.dtype), None
+
+        x = self._attention_sublayer(x, lp, cos, sin)
+        return self._residual(x, lp, "hc_mlp", "mlp", mlp)[0]
 
     def _moe_block(self, x, lp, cos, sin):
         """-> (the layer's output, the rows its held experts worked)."""
         c = self.config
-        b, s, d = x.shape
-        x = self._attention(x, lp, cos, sin)
-        with jax.named_scope("router"):    # the norm goes with the router
-            xn = rmsnorm(x, lp["mlp_norm"], c.rms_eps).reshape(b * s, d)
-        y, rows = held_expert_layer(
-            xn, lp, experts_held=c.experts_held,
-            expert_offset=c.expert_offset, top_k=c.top_k,
-            routed_scale=c.routed_scaling_factor)
-        return x + y.reshape(b, s, d), rows
+
+        def experts(z):
+            b, s, d = z.shape
+            with jax.named_scope("router"):  # the norm goes with the router
+                xn = rmsnorm(z, lp["mlp_norm"], c.rms_eps).reshape(b * s, d)
+            y, rows = held_expert_layer(
+                xn, lp, experts_held=c.experts_held,
+                expert_offset=c.expert_offset, top_k=c.top_k,
+                routed_scale=c.routed_scaling_factor)
+            return y.reshape(b, s, d), rows
+
+        x = self._attention_sublayer(x, lp, cos, sin)
+        return self._residual(x, lp, "hc_mlp", None, experts)
 
     def _run_layers(self, x, params, cos, sin):
         """The one place the stack is walked: the dense layers, then the
         expert layers through one scanned body, each rematerialised. ->
         (x, held rows of each expert layer)."""
         c = self.config
-        policy = jax.checkpoint_policies.save_only_these_names(*_REMAT_SAVE)
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *(_REMAT_SAVE_BOTTLENECK if c.q_lora_rank else _REMAT_SAVE))
         group = lambda kind: {n.split(".", 1)[1]: v  # noqa: E731
                               for n, v in params.items()
                               if n.startswith(kind + ".")}
@@ -243,9 +361,28 @@ class DeepseekV3:
         c = self.config
         with jax.named_scope("embed"):
             x = params["wte"].astype(c.dtype)[tokens]
-        cos, sin = rope_cache(tokens.shape[1], c.qk_rope_head_dim,
-                              c.rope_base)
+        if c.rope_factor == 1.0:
+            cos, sin = rope_cache(tokens.shape[1], c.qk_rope_head_dim,
+                                  c.rope_base)
+        else:
+            cos, sin = yarn_rope_cache(
+                tokens.shape[1], c.qk_rope_head_dim, c.rope_base,
+                factor=c.rope_factor, original_max=c.rope_original_max,
+                beta_fast=c.rope_beta_fast, beta_slow=c.rope_beta_slow,
+                mscale=c.rope_mscale, mscale_all_dim=c.rope_mscale_all_dim)
+        # what one rematerialised layer keeps of its input for its backward
+        _record("rtpu.models.deepseek_v3.residual", "streams", {
+            "hc_streams": c.hc_mult,
+            "hc_sublayers": 2 * c.n_layer if c.hc_mult > 1 else 0,
+            "residual_stream_bytes":
+                c.hc_mult * math.prod(x.shape) * x.dtype.itemsize})
+        if c.hc_mult > 1:
+            # every stream starts as the embedding; the head reads their sum
+            x = (x,) * c.hc_mult
         x, rows = self._run_layers(x, params, cos, sin)
+        if c.hc_mult > 1:
+            with jax.named_scope("mhc"):
+                x = sum(xj.astype(jnp.float32) for xj in x).astype(c.dtype)
         with jax.named_scope("lm_head"):     # the final norm goes with it
             return rmsnorm(x, params["out_norm"], c.rms_eps), rows
 

@@ -13,9 +13,12 @@ legend); for a TPU-native framework the hot ops are first-party:
 - selective_scan: Mamba-1's recurrence (a decay for every channel AND
   state), walked in chunks on the VPU with the state in VMEM, forward and
   backward kernels (Pallas).
-- layers: rmsnorm/layernorm/gelu/rope/cross-entropy, the causal depthwise
-  convolution and the gated norm in plain jnp, shaped so XLA fuses them
-  into the adjacent matmuls.
+- hyper_connection: several residual streams read, written and mixed
+  around a sublayer by maps made doubly stochastic by Sinkhorn
+  iterations (mHC); plain jnp, every coefficient tokens-minor.
+- layers: rmsnorm/layernorm/gelu/rope (plain and YaRN)/cross-entropy,
+  the causal depthwise convolution and the gated norm in plain jnp, shaped
+  so XLA fuses them into the adjacent matmuls.
 - paged_attention: reads and writes of the serving engine's block-pool
   KV cache.
 
@@ -29,6 +32,7 @@ from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d, gated_rmsnorm)
 from .ssd_scan import ssd_scan
 from .selective_scan import selective_scan
+from .hyper_connection import hc_coefficients, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
                               paged_attention_prefill, paged_gather_kv,
                               paged_write_prefill, paged_write_step)
@@ -37,7 +41,7 @@ __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "ssd_scan",
-    "selective_scan",
+    "selective_scan", "hc_coefficients", "hc_pre", "hc_post",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",
     "paged_write_step",

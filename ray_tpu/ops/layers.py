@@ -7,6 +7,7 @@ in f32 even under bf16 params — the TPU mixed-precision recipe.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -43,6 +44,52 @@ def rope_cache(seq_len: int, head_dim: int,
     t = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(t, freqs)
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's m(k) = 0.1 k ln(factor) + 1 (1 at factor <= 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_rope_cache(seq_len: int, head_dim: int, base: float = 10000.0, *,
+                    factor: float, original_max: int, beta_fast: float = 32.0,
+                    beta_slow: float = 1.0, mscale: float = 1.0,
+                    mscale_all_dim: float = 0.0
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """``rope_cache`` under YaRN (``rope_scaling`` type ``yarn``, the
+    DeepSeek-V3 convention): pair i of head_dim/2 turns by f'_i = f_i /
+    factor * ramp_i + f_i (1 - ramp_i), f_i = base^(-2i/head_dim), ramp_i
+    = clip((i - low) / (high - low), 0, 1) with low (high) the floor
+    (ceil) of head_dim ln(original_max / (beta 2π)) / (2 ln base) at
+    beta_fast (beta_slow), kept inside the table: the pairs that turn
+    more than beta_fast times in ``original_max`` positions keep their
+    frequency, those that turn less than beta_slow times are stretched by
+    ``factor``. The blend holds at every length. cos and sin are scaled
+    by m(mscale) / m(mscale_all_dim), m of ``yarn_mscale``. Factor 1 is
+    ``rope_cache``."""
+    half = head_dim // 2
+    freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+    def pair(beta):
+        return head_dim * math.log(original_max / (beta * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32),
+                       freqs / factor * ramp + freqs * (1.0 - ramp))
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return jnp.cos(angles) * m, jnp.sin(angles) * m
+
+
+def yarn_softmax_scale(qk_head_dim: int, factor: float,
+                       mscale_all_dim: float) -> float:
+    """The softmax scale of a YaRN-scaled attention: qk_head_dim^(-1/2)
+    times m(mscale_all_dim)² (1 where that key is 0)."""
+    m = yarn_mscale(factor, mscale_all_dim)
+    return qk_head_dim ** -0.5 * m * m
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,

@@ -150,27 +150,32 @@ def test_flash_attention_streamed_kernels_are_named(one_chip,
                                    "flash_bwd_dkv"}
 
 
+@pytest.mark.parametrize("s,sm_scale", [(8192, None), (4096, 0.1447)],
+                         ids=["kanana2_train_s8192", "xing4_train_s4096"])
 def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
-                                                       compiled_kernels):
+                                                       compiled_kernels, s,
+                                                       sm_scale):
     """ISSUE 33: kanana2_train_s8192's attention, B=2, S=8192, 32 heads of
     128 + 64 against 128, one shared rope key, at the default blocks of
     1024, fed as the model feeds them, with no copy of a head-shaped array
     around them. ISSUE 34: the forward and ONE backward kernel, under the
     dk/dv kernel's name; it keeps dq of a head block for the whole
     sequence in VMEM, 46 MB with the tiles, which only the chip's
-    compiler checks."""
+    compiler checks. ISSUE 45: xing4_train_s4096's, the same heads at
+    S=4096 with YaRN's softmax scale (2.0047 / sqrt(192)) handed in."""
     import re
 
     from ray_tpu.ops.flash_attention import flash_attention
 
-    b, s, h = 2, 8192, 32
+    b, h = 2, 32
     sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(qn, kn, v, qr, kr):
         heads = lambda x: x.reshape(b, s, h, -1)  # noqa: E731
         return flash_attention(heads(qn), heads(kn), heads(v), causal=True,
-                               q_rope=heads(qr), k_rope=kr).astype(
+                               q_rope=heads(qr), k_rope=kr,
+                               sm_scale=sm_scale).astype(
             jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
@@ -179,7 +184,7 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     assert _kernel_names(text, latent=True) == {"flash_latent_fwd",
                                                 "flash_latent_bwd_dkv"}
     # q_rope, two heads of 64 to a block, is never laid out by head
-    assert not re.findall(r"\w+\[2,8192,32,64\]", text)
+    assert not re.findall(r"\w+\[2,%d,32,64\]" % s, text)
     assert " transpose(" not in text
 
 
@@ -205,25 +210,30 @@ def test_a_latent_call_that_cannot_fuse_compiles_the_two_kernels(
         LATENT_KERNEL_NAMES.values())
 
 
-def test_held_expert_layer_at_the_benchmark_cells_shape(one_chip,
-                                                        monkeypatch):
+@pytest.mark.parametrize(
+    "t,d,f,held,of,shared,top_k,scale",
+    [(16384, 2048, 768, 16, 128, 2, 6, 2.448),
+     (8192, 3584, 1024, 8, 64, 1, 4, 2.0)],
+    ids=["kanana2_train_s8192", "xing4_train_s4096"])
+def test_held_expert_layer_at_the_benchmark_cells_shape(
+        one_chip, monkeypatch, t, d, f, held, of, shared, top_k, scale):
     """ISSUE 33: 16 384 tokens, 16 of 128 experts of 2048 x 768 held, top
     6: forward and backward of the layer, the two grouped-product kernels
-    by name, no scatter."""
+    by name, no scatter. ISSUE 45: 8192 tokens, 8 of 64 experts of
+    3584 x 1024 held, top 4, one shared expert."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
     monkeypatch.setattr(el, "_use_interpret", lambda: False)
-    t, d, f, held, of = 16384, 2048, 768, 16, 128
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     p = {"w_router": sd((d, of)), "router_bias": sd((of,)),
-         "s_gate": sd((d, 2 * f)), "s_up": sd((d, 2 * f)),
-         "s_down": sd((2 * f, d)), "e_gate": sd((held, d, f)),
+         "s_gate": sd((d, shared * f)), "s_up": sd((d, shared * f)),
+         "s_down": sd((shared * f, d)), "e_gate": sd((held, d, f)),
          "e_up": sd((held, d, f)), "e_down": sd((held, f, d))}
 
     def loss(x, p):
         return el.held_expert_layer(
-            x, p, experts_held=held, expert_offset=0, top_k=6,
-            routed_scale=2.448)[0].astype(jnp.float32).sum()
+            x, p, experts_held=held, expert_offset=0, top_k=top_k,
+            routed_scale=scale)[0].astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         sd((t, d), jnp.bfloat16), p).compile().as_text()
@@ -439,6 +449,42 @@ def test_phi4flash_train_step_keeps_its_room(one_chip, compiled_kernels,
     assert count("selscan_chunk_fwd") == count("selscan_chunk_bwd") == 2
     assert count("flash_fwd") == count("flash_bwd_dq") \
         == count("flash_bwd_dkv") == 3
+
+
+def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
+                                         monkeypatch):
+    """ISSUE 45: xing4_train_s4096's own train step (the harness's
+    ``make_train_step``, the cell's configuration, optimizer, batch 2 of
+    4096, parameters and optimizer state donated) for the described v5e:
+    759.5 M parameters at 12 B as arguments (9.11 GB), 10.43 GB of
+    temporaries (they overlap the donated state) with a layer keeping its
+    four streams, the latent kernels' output and row statistics and NOT q
+    (``_REMAT_SAVE_BOTTLENECK``: with q kept too the compiler refused the
+    step by 1.73 MB). The room is gone: the compiler makes 67 instructions
+    again on its own to fit (mixed streams, the logits once), which is
+    what a change that needs more memory would turn into a refusal here
+    and not on the chip. The latent kernels stand once a layer and
+    direction, the mixings are no kernel, and no stream is laid out
+    [tokens, 4, d] (4 rows padded to 16)."""
+    import os
+
+    monkeypatch.syspath_prepend(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    tool = _hlo_tool()
+    compiled = tool.compile_step("xing4_train_s4096", one_chip)
+    assert 9.05e9 < _fits(compiled) < 9.2e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 10.6e9
+    text = compiled.as_text()
+    assert "s32[2,4096]" in text            # the cell's batch, not another
+    assert tool.compiler_remat(text) < 100
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda name: sum(                                # noqa: E731
+        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
+    # the dense layer by itself and the scanned expert layers' one body
+    assert count("flash_latent_fwd") == count("flash_latent_bwd_dkv") == 2
+    assert not re.findall(r"\w+\[2,4096,4,3584\]", text)
+    assert not re.findall(r"f32\[8192,4,4\]|f32\[2,4096,4,4\]", text)
 
 
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):

@@ -271,6 +271,9 @@ def test_a_windowed_call_takes_the_streamed_kernels_by_name(streamed_texts,
 MODELS = {
     "sambay": lambda: SambaY(SambaYConfig.tiny()),
     "deepseek_v3": lambda: DeepseekV3(DeepseekV3Config.tiny(experts_held=4)),
+    "deepseek_v3_hc": lambda: DeepseekV3(DeepseekV3Config.tiny(
+        experts_held=4, hc_mult=4, q_lora_rank=16, rope_factor=64.0,
+        rope_original_max=32, rope_mscale_all_dim=1.0)),
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
@@ -294,7 +297,10 @@ def lowered_losses():
 @pytest.mark.parametrize("scope,model", [
     (s, m) for m in sorted(MODELS)
     for s in ("embed", "attn", "mlp", "lm_head", "loss")
-    + (("router", "experts", "shared_expert") if m == "deepseek_v3" else ())
+    + (("router", "experts", "shared_expert")
+       if m.startswith("deepseek_v3") else ())
+    # ISSUE 45: everything ops/hyper_connection.py does, under one name
+    + (("mhc",) if m == "deepseek_v3_hc" else ())
     + (("mixer", "conv", "scan") if m in ("granite_hybrid", "sambay")
        else ())
     + (("gmu", "cross_attn") if m == "sambay" else ())])
