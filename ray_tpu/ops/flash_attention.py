@@ -69,7 +69,8 @@ rowsum(dO * O) is reduced inside them too (``_row_delta``).
 Causal calls do not compute what the mask would throw away, in one of two
 ways. The STREAMED kernels (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``: S larger than a block) skip fully-masked blocks by a
-predicate on the grid position. The SINGLE-BLOCK kernels
+predicate on the grid position, and so do the LATENT kernels, which also
+band the blocks the diagonal crosses (below). The SINGLE-BLOCK kernels
 (``flash_fwd_single``, ``flash_bwd_fused``), where one program holds the
 whole S x S square of a column block, cut its rows into bands of a
 quarter of the sequence (``_band_height``) and work band r on the columns
@@ -79,7 +80,15 @@ square's matmuls and softmax at four bands. A masked score gave
 exp(-1e30 - m) = 0 to its row's sum and an exact zero to every product,
 so each band's numbers are the square's, summed over the non-zero terms.
 Non-causal calls and a single-block call with several q blocks work the
-whole block as one band.
+whole block as one band. The LATENT kernels do the same inside every
+block the diagonal crosses, whatever S: where a causal call's blocks are
+square and a multiple of 256 (``_latent_band``), the step qi == kb cuts
+the block's rows into bands of a quarter of the BLOCK (256 at blocks of
+1024) and adds each band's terms into static slices of the scratch the
+kernel keeps across steps (the online softmax's m, l, acc at the band's
+rows; dk, dv at its columns and dq at its rows); the steps below the
+diagonal work the whole block unmasked, as before (``bands`` in the
+event: the bands of such a step).
 
 A causal call with a ``window`` (query i sees the keys i - window < j <= i)
 takes the streamed kernels whatever S, and their innermost grid dimension
@@ -149,7 +158,9 @@ PATH_COUNTS: collections.Counter = collections.Counter()
 
 # The same traced calls by the number of causal row bands a program works
 # (``_band_height``): 4 at S=1024, 1 where nothing is banded (non-causal,
-# streamed, S=128), 0 on the reference route. ``bands`` in the event's data.
+# streamed, S=128), 0 on the reference route; of a LATENT call the bands
+# of a step the diagonal crosses (``_latent_band``: 4 at blocks of 1024,
+# 2 at 256, 1 at 128 or non-causal). ``bands`` in the event's data.
 BAND_COUNTS: collections.Counter = collections.Counter()
 
 # The traced LATENT calls by the backward their shapes select
@@ -1041,7 +1052,17 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # serves every S (a single block is a grid of one step); causal blocks
 # above the diagonal are skipped by predicate and fetch nothing (their
 # index maps point at the block before), and only blocks the diagonal
-# crosses are masked. The two backward kernels each make the score, dP,
+# crosses are masked. Such a DIAGONAL step works causal row bands, not
+# the masked square, where the blocks are square and a multiple of 256
+# (``_latent_band``; every kernel of the route, by ``_latent_band_scores``
+# and ``_latent_bwd_bands``): band r of a quarter of the block takes the
+# rows [r*h, (r+1)*h) of q, o, dO and the row statistics and the columns
+# [0, (r+1)*h) of k_nope, the shared key and v, masks the last [h, h]
+# tile alone and adds into static slices of the scratch: 10 of a block's
+# 16 band tiles issued; at S 4096 in blocks of 1024 four of a head
+# block's ten computed steps are diagonal, at S 8192 eight of 36. Every
+# other call (non-causal, unequal blocks, blocks of 128) works the whole
+# block under the mask. The two backward kernels each make the score, dP,
 # the mask and exp of a block pair: 5 + 6 passes of a 128-deep
 # contraction a pair and head. A causal call runs ONE kernel instead
 # (``_latent_bwd_fused_kernel``, 8 passes) where its shapes allow
@@ -1096,11 +1117,51 @@ def _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked, row0, col0):
     return s
 
 
+def _latent_band(causal: bool, block_q: int, block_k: int) -> int:
+    """Height of the causal row bands a latent program works in a step
+    the diagonal crosses, or 0 where such a step works the whole block
+    under the mask: a non-causal call, unequal blocks (the diagonal then
+    cuts no aligned triangle out of a block), a block that is no multiple
+    of 256 (one band of 128 is the block). ``_band_height``'s rule
+    applied to the BLOCK: a quarter of it in whole 128-lane tiles, 256 at
+    blocks of 1024, 10 of a diagonal block's 16 band tiles issued."""
+    if not causal or block_q != block_k or block_q % (2 * _LANES):
+        return 0
+    return _band_height(block_q, True, block_q, block_k)
+
+
+def _latent_band_scores(band, qn_ref, qr_ref, kn_ref, kr_ref, heads, dn, dr,
+                        sm_scale):
+    """-> scores((first row, rows, columns, head)): the [rows, columns]
+    scores in f32 of one (band, head) unit of a DIAGONAL step, its last
+    [band, band] tile masked. What does not depend on the band (the
+    heads' scaled tiles of q, the one mask every band's diagonal tile
+    has) is made here, once a program (``_fwd_single_kernel``'s reason);
+    the keys a band can see are static slices of the tiles in VMEM."""
+    scale = jnp.asarray(sm_scale, qn_ref.dtype)
+    qn, qr = qn_ref[...] * scale, qr_ref[...] * scale
+    qns = [_lanes(qn, j, dn) for j in range(heads)]
+    qrs = [_head_lanes(qr, j, dr) for j in range(heads)]
+    visible = _visible(0, band, band)
+
+    def scores(u):
+        r0, h, end, j = u
+        return _masked(jax.lax.add(
+            _dot(_rows(qns[j], r0, h), kn_ref[:end, j * dn:(j + 1) * dn],
+                 _ABT),
+            _dot(_rows(qrs[j], r0, h), kr_ref[:end, :], _ABT)), visible)
+
+    return scores
+
+
 def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
                        m_scr, l_scr, acc_scr, *, sm_scale, causal, dn, dr,
-                       dv, block_q, block_k, num_kb):
+                       dv, block_q, block_k, num_kb, band):
     """Grid (B, head blocks, q blocks, k blocks), K innermost: online
-    softmax with one (m, l, acc) a head of the block."""
+    softmax with one (m, l, acc) a head of the block. In a banded call
+    (``_latent_band``) the step the diagonal crosses works row bands,
+    band r on the keys [0, (r+1)*band) of the block, and carries the
+    online softmax on at the band's rows of the scratch."""
     qi, kb = pl.program_id(2), pl.program_id(3)
     heads = lse_ref.shape[0]
 
@@ -1110,7 +1171,29 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def bands():
+        scores = _latent_band_scores(band, qn_ref, qr_ref, kn_ref, kr_ref,
+                                     heads, dn, dr, sm_scale)
+        for (r0, h, end, j), s in _one_ahead(
+                _units(block_q, block_k, band, heads), scores, True):
+            # jax.lax in a unit's body, as in the single-block kernels
+            rows = slice(r0, r0 + h)
+            m_prev = m_scr[j, rows, :]
+            m_new = jax.lax.max(m_prev, _col(jax.lax.reduce_max(s, (1,))))
+            p = jax.lax.exp(jax.lax.sub(s, _along(m_new, s)))
+            alpha = jax.lax.exp(jax.lax.sub(m_prev, m_new))
+            l_scr[j, rows, :] = jax.lax.add(
+                jax.lax.mul(l_scr[j, rows, :], alpha),
+                _col(jax.lax.reduce_sum(p, (1,))))
+            pv = _dot(jax.lax.convert_element_type(p, v_ref.dtype),
+                      v_ref[:end, j * dv:(j + 1) * dv], _AB)
+            acc_scr[j, rows, :] = jax.lax.add(
+                jax.lax.mul(acc_scr[j, rows, :], _along(alpha, pv)), pv)
+            m_scr[j, rows, :] = m_new
+
     def compute(masked):
+        if masked and band:
+            return bands()
         qn, qr, kn, kr, v = (qn_ref[...], qr_ref[...], kn_ref[...],
                              kr_ref[...], v_ref[...])
         for j in range(heads):
@@ -1148,10 +1231,69 @@ def _latent_bwd_head(qn, qr, kn, kr, v, do, lse, delta, j, *, dn, dr, dv,
     return p.astype(do.dtype), ds.astype(kn.dtype)
 
 
+def _latent_delta(do, o, j, dv):
+    """delta_i = rowsum(dO_i * O_i) of head j -> [block, 1] f32, from the
+    tiles at hand (``_row_delta``'s reason)."""
+    return jnp.sum(_lanes(do, j, dv).astype(jnp.float32)
+                   * _lanes(o, j, dv).astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
+def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                      lse_ref, delta, *, dn, dr, dv, sm_scale, dkv=None,
+                      dq=None):
+    """The DIAGONAL step of a banded call's backward (``_latent_band``),
+    for all three kernels: ``_latent_bwd_head``'s (p, ds) of each (band,
+    head) unit, [band, columns the band sees], and the products they feed,
+    added into static slices of what the kernel keeps: ``dkv`` = (dk_nope,
+    d(shared key), dv) accumulators of the key block at the band's
+    COLUMNS, ``dq`` = (dq_nope, dq_rope) of the q block at the band's
+    ROWS. ``delta`` is one [block, 1] column a head. Unit i+1's scores,
+    exp, dP and ds are written before unit i's products
+    (``_one_ahead``)."""
+    heads = lse_ref.shape[0]
+    block = qn_ref.shape[0]
+    # lse is stored [1, block]; rows here are q-positions
+    lse = [lse_ref[j].T for j in range(heads)]
+    scores = _latent_band_scores(band, qn_ref, qr_ref, kn_ref, kr_ref, heads,
+                                 dn, dr, sm_scale)
+    qn, do = qn_ref[...], do_ref[...]
+    # head j's lanes of q_rope (for the shared key's gradient) and of the
+    # repeated key (for dq_rope), the other heads' zeroed: once a program
+    qrs = dkv and [_head_lanes(qr_ref[...], j, dr) for j in range(heads)]
+    krs = dq and [_head_lanes(kr_ref[...], j, dr) for j in range(heads)]
+
+    def head(u):
+        r0, h, end, j = u
+        s = scores(u)
+        doj = _rows(_lanes(do, j, dv), r0, h)
+        p = jax.lax.exp(jax.lax.sub(s, _along(_rows(lse[j], r0, h), s)))
+        dp = _dot(doj, v_ref[:end, j * dv:(j + 1) * dv], _ABT)
+        ds = jax.lax.mul(jax.lax.mul(p, jax.lax.sub(
+            dp, _along(_rows(delta[j], r0, h), dp))), sm_scale)
+        return (jax.lax.convert_element_type(p, do.dtype),
+                jax.lax.convert_element_type(ds, kn_ref.dtype), doj)
+
+    for (r0, h, end, j), (p, ds, doj) in _one_ahead(
+            _units(block, block, band, heads), head, True):
+        rows, nope, val = (slice(r0, r0 + h), slice(j * dn, (j + 1) * dn),
+                           slice(j * dv, (j + 1) * dv))
+        if dkv is not None:
+            dkn_acc, dkr_acc, dv_acc = dkv
+            dv_acc[:end, val] += _dot(p, doj, _ATB)
+            dkn_acc[:end, nope] += _dot(ds, _rows(_lanes(qn, j, dn), r0, h),
+                                        _ATB)
+            dkr_acc[:end, :] += _dot(ds, _rows(qrs[j], r0, h), _ATB)
+        if dq is not None:
+            dqn_acc, dqr_acc = dq
+            dqn_acc[rows, nope] += _dot(ds, kn_ref[:end, nope], _AB)
+            dqr_acc[rows, :] += _dot(ds, _rows(krs[j], 0, end), _AB)
+
+
 def _latent_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
                           do_ref, lse_ref, dqn_ref, dqr_ref, delta_ref,
                           dqn_scr, dqr_scr, *, sm_scale, causal, dn, dr, dv,
-                          block_q, block_k, num_kb):
+                          block_q, block_k, num_kb, band):
     """Grid (B, head blocks, q blocks, k blocks): dq_nope and dq_rope
     accumulated over K; emits delta [B*H, 1, S] for the dk/dv kernel."""
     qi, kb = pl.program_id(2), pl.program_id(3)
@@ -1162,12 +1304,14 @@ def _latent_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
         dqn_scr[...] = jnp.zeros_like(dqn_scr)
         dqr_scr[...] = jnp.zeros_like(dqr_scr)
         for j in range(heads):
-            delta_ref[j] = jnp.sum(
-                _lanes(do_ref[...], j, dv).astype(jnp.float32)
-                * _lanes(o_ref[...], j, dv).astype(jnp.float32),
-                axis=-1, keepdims=True).T
+            delta_ref[j] = _latent_delta(do_ref[...], o_ref[...], j, dv).T
 
     def compute(masked):
+        if masked and band:
+            return _latent_bwd_bands(
+                band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                [delta_ref[j].T for j in range(heads)], dn=dn, dr=dr, dv=dv,
+                sm_scale=sm_scale, dq=(dqn_scr, dqr_scr))
         qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
                                  kr_ref[...], v_ref[...], do_ref[...])
         for j in range(heads):
@@ -1191,7 +1335,7 @@ def _latent_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
 def _latent_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
                            lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
                            dkn_scr, dkr_scr, dv_scr, *, sm_scale, causal, dn,
-                           dr, dv, block_q, block_k, num_qb, num_cb):
+                           dr, dv, block_q, block_k, num_qb, num_cb, band):
     """Grid (B, k blocks, head blocks, q blocks): dk_nope and dv
     accumulated over Q for one head block; the shared key's gradient over
     Q AND over the head blocks, written once a key block."""
@@ -1208,6 +1352,11 @@ def _latent_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         dkr_scr[...] = jnp.zeros_like(dkr_scr)
 
     def compute(masked):
+        if masked and band:
+            return _latent_bwd_bands(
+                band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                [delta_ref[j].T for j in range(heads)], dn=dn, dr=dr, dv=dv,
+                sm_scale=sm_scale, dkv=(dkn_scr, dkr_scr, dv_scr))
         qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
                                  kr_ref[...], v_ref[...], do_ref[...])
         for j in range(heads):
@@ -1239,7 +1388,7 @@ def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
                              do_ref, lse_ref, dqn_ref, dqr_ref, dkn_ref,
                              dkr_ref, dv_ref, dqn_scr, dqr_scr, dkn_scr,
                              dkr_scr, dv_scr, *, sm_scale, dn, dr, dv, block,
-                             num_qb, num_cb):
+                             num_qb, num_cb, band):
     """The whole backward of a causal call in one kernel, blocks square:
     grid (B, head blocks, k blocks, q blocks). ``_latent_bwd_head``'s
     (p, ds) are made once a (q block, k block) pair and head and feed all
@@ -1270,15 +1419,26 @@ def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
         dqn_scr[qi] = jnp.zeros(dqn_scr.shape[1:], dqn_scr.dtype)
         dqr_scr[qi] = jnp.zeros(dqr_scr.shape[1:], dqr_scr.dtype)
 
+    def bands():    # the diagonal step of a banded call
+        do, o = do_ref[...], o_ref[...]
+        _latent_bwd_bands(
+            band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+            [_latent_delta(do, o, j, dv) for j in range(heads)], dn=dn,
+            dr=dr, dv=dv,
+            sm_scale=sm_scale, dkv=(dkn_scr, dkr_scr.at[kb], dv_scr),
+            dq=(dqn_scr.at[qi], dqr_scr.at[qi]))
+        dqn_ref[...] = dqn_scr[qi].astype(dqn_ref.dtype)
+        dqr_ref[...] = dqr_scr[qi].astype(dqr_ref.dtype)
+
     def compute(masked):
+        if masked and band:
+            return bands()
         qn, qr, kn, kr, v, o, do = (
             qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...], v_ref[...],
             o_ref[...], do_ref[...])
         for j in range(heads):
             doj = _lanes(do, j, dv)
-            delta = jnp.sum(doj.astype(jnp.float32)
-                            * _lanes(o, j, dv).astype(jnp.float32),
-                            axis=-1, keepdims=True)
+            delta = _latent_delta(do, o, j, dv)
             p, ds = _latent_bwd_head(
                 qn, qr, kn, kr, v, do, lse_ref[j].T, delta, j, dn=dn, dr=dr,
                 dv=dv, sm_scale=sm_scale, masked=masked, row0=qi * block,
@@ -1365,7 +1525,8 @@ def _latent_fwd(qn, qr, kn, kr, v, heads, sm_scale, causal, block_q,
     return pl.pallas_call(
         functools.partial(
             _latent_fwd_kernel, sm_scale=sm_scale, causal=causal, dn=dn,
-            dr=dr, dv=dv, block_q=block_q, block_k=block_k, num_kb=num_kb),
+            dr=dr, dv=dv, block_q=block_q, block_k=block_k, num_kb=num_kb,
+            band=_latent_band(causal, block_q, block_k)),
         grid=(b, ncb, seq // block_q, num_kb),
         in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s],
         out_specs=[qv_s, row_s],
@@ -1438,7 +1599,8 @@ def _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, block):
     return pl.pallas_call(
         functools.partial(
             _latent_bwd_fused_kernel, sm_scale=sm_scale, dn=dn, dr=dr, dv=dv,
-            block=block, num_qb=nb, num_cb=ncb),
+            block=block, num_qb=nb, num_cb=ncb,
+            band=_latent_band(True, block, block)),
         grid=(b, ncb, nb, nb),
         in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, qv_s, row_s],
         out_specs=[kn_s, dqr_s, kn_s, dkr_s, kv_s],
@@ -1483,7 +1645,8 @@ def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
     half = 2 if causal else 1
     pairs = b * heads * seq * seq // half
     kernel_kw = dict(sm_scale=sm_scale, causal=causal, dn=dn, dr=dr, dv=dv,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k,
+                     band=_latent_band(causal, block_q, block_k))
 
     qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
         True, causal, block_q, block_k, hpb, ncb, (wn, wv))
@@ -1594,11 +1757,13 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
                              jnp.concatenate([k, shared], -1), v,
                              causal=causal, sm_scale=sm_scale)
     hpb = _LANES // dr
-    backward = _latent_backward(
-        s, causal, _fit_block(block_q, s), _fit_block(block_k, s), hpb * dn,
-        hpb * dv, q.dtype.itemsize)
+    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+    backward = _latent_backward(s, causal, bq, bk, hpb * dn, hpb * dv,
+                                q.dtype.itemsize)
     BACKWARD_COUNTS[backward] += 1
-    _note_path("latent", hpb, dn + dr, s, 1, backward=backward, **facts)
+    band = _latent_band(causal, bq, bk)
+    _note_path("latent", hpb, dn + dr, s, bq // band if band else 1,
+               backward=backward, **facts)
     merge = lambda x: x.reshape(b, s, -1)  # noqa: E731
     out = _flash_latent(merge(q), merge(q_rope), merge(k),
                         jnp.tile(k_rope, (1, 1, hpb)), merge(v), h,

@@ -162,10 +162,14 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     dk/dv kernel's name; it keeps dq of a head block for the whole
     sequence in VMEM, 46 MB with the tiles, which only the chip's
     compiler checks. ISSUE 45: xing4_train_s4096's, the same heads at
-    S=4096 with YaRN's softmax scale (2.0047 / sqrt(192)) handed in."""
+    S=4096 with YaRN's softmax scale (2.0047 / sqrt(192)) handed in.
+    ISSUE 46: both kernels work the blocks the diagonal crosses in four
+    row bands of 256 (static slices of refs and of the scratch, two of
+    them views at a dynamic block), which only the chip's compiler
+    checks."""
     import re
 
-    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.flash_attention import BAND_COUNTS, flash_attention
 
     b, h = 2, 32
     sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
@@ -178,9 +182,11 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
                                sm_scale=sm_scale).astype(
             jnp.float32).sum()
 
+    before = BAND_COUNTS[4]
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sd(b, s, h * 128), sd(b, s, h * 128), sd(b, s, h * 128),
         sd(b, s, h * 64), sd(b, s, 64)).compile().as_text()
+    assert BAND_COUNTS[4] == before + 1
     assert _kernel_names(text, latent=True) == {"flash_latent_fwd",
                                                 "flash_latent_bwd_dkv"}
     # q_rope, two heads of 64 to a block, is never laid out by head
@@ -188,24 +194,35 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     assert " transpose(" not in text
 
 
+@pytest.mark.parametrize("causal,s,h,bands", [(False, 2048, 4, 1),
+                                              (True, 40960, 2, 4)],
+                         ids=["non_causal", "too_long_to_fuse"])
 def test_a_latent_call_that_cannot_fuse_compiles_the_two_kernels(
-        one_chip, compiled_kernels):
+        one_chip, compiled_kernels, causal, s, h, bands):
     """ISSUE 34: a non-causal latent call has no diagonal step at which a
     q block's dq is complete, so it takes the two backward kernels as
-    they were: all three names, at blocks of 1024."""
-    from ray_tpu.ops.flash_attention import (LATENT_KERNEL_NAMES,
+    they were: all three names, at blocks of 1024. ISSUE 46: so does a
+    causal call whose whole-sequence accumulators do not fit VMEM
+    (S 40 960 at these widths), and its three kernels work their diagonal
+    steps in four row bands (``_latent_bwd_bands`` with each kernel's own
+    accumulators): no cell runs them, so this compile is their check."""
+    from ray_tpu.ops.flash_attention import (BACKWARD_COUNTS, BAND_COUNTS,
+                                             LATENT_KERNEL_NAMES,
                                              flash_attention)
 
     sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v, qr, kr):
-        return flash_attention(q, k, v, causal=False, q_rope=qr,
+        return flash_attention(q, k, v, causal=causal, q_rope=qr,
                                k_rope=kr).astype(jnp.float32).sum()
 
+    before = BACKWARD_COUNTS["split"], BAND_COUNTS[bands]
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        sd(1, 2048, 4, 128), sd(1, 2048, 4, 128), sd(1, 2048, 4, 128),
-        sd(1, 2048, 4, 64), sd(1, 2048, 64)).compile().as_text()
+        sd(1, s, h, 128), sd(1, s, h, 128), sd(1, s, h, 128),
+        sd(1, s, h, 64), sd(1, s, 64)).compile().as_text()
+    assert (BACKWARD_COUNTS["split"], BAND_COUNTS[bands]) == (
+        before[0] + 1, before[1] + 1)
     assert _kernel_names(text, latent=True) == set(
         LATENT_KERNEL_NAMES.values())
 

@@ -132,13 +132,19 @@ def _latent_plain(q, k, v, q_rope, k_rope, causal=True):
 # backward its shapes select). ISSUE 34: "fused" has diagonal and full
 # block pairs and two head blocks, whose shared-key gradients add up;
 # "split" is the same call with no room for the whole-sequence
-# accumulators; "full" a non-causal call, which never fuses.
+# accumulators; "full" a non-causal call, which never fuses. ISSUE 46:
+# blocks of 256 are worked in two row bands of 128 where the diagonal
+# crosses them (``_latent_band``): "banded" has two diagonal block pairs
+# and a full one, two head blocks, through the fused backward,
+# "banded_split" through the two kernels; case 256 is one banded block.
 LATENT_CASES = {
     256: (256, 256, True, None, "fused"),      # one K block a program
     128: (256, 128, True, None, "fused"),      # streamed
     "fused": (512, 128, True, None, "fused"),
     "split": (512, 128, True, 1 << 20, "split"),
     "full": (256, 128, False, None, "split"),
+    "banded": (512, 256, True, None, "fused"),
+    "banded_split": (512, 256, True, 1 << 20, "split"),
 }
 
 
@@ -202,6 +208,39 @@ def test_a_latent_call_takes_the_latent_kernels_and_says_so():
                           k_rope=odd[4])
     assert PATH_COUNTS["latent_reference"] == before + 1
     assert float(jnp.abs(got - _latent_plain(*odd)).max()) < 1e-5
+
+
+@pytest.mark.parametrize("blocks,causal,bands", [
+    (1024, True, 4), (256, True, 2), (128, True, 1), (1024, False, 1)])
+def test_a_latent_call_says_how_many_bands_a_diagonal_step_works(
+        blocks, causal, bands):
+    """ISSUE 46: ``bands`` of the event ``rtpu.ops.flash.path`` and
+    ``BAND_COUNTS``: a quarter of a block of 1024, halves of one of 256,
+    the whole block of 128 and of a non-causal call."""
+    import sys
+
+    from ray_tpu.perf.recorder import get_recorder
+
+    fa = sys.modules[flash_attention.__module__]
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    before = fa.BAND_COUNTS[bands]
+    try:
+        jax.eval_shape(
+            lambda q, k, v, qr, kr: flash_attention(
+                q, k, v, causal=causal, block_q=blocks, block_k=blocks,
+                q_rope=qr, k_rope=kr),
+            sd(1, 2048, 2, 128), sd(1, 2048, 2, 128), sd(1, 2048, 2, 128),
+            sd(1, 2048, 2, 64), sd(1, 2048, 64))
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    assert fa.BAND_COUNTS[bands] == before + 1
+    data = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
+            and e["label"] == "latent"][-1]["data"]
+    assert data["bands"] == bands and data["S"] == 2048
+    assert data["backward"] == ("fused" if causal else "split")
 
 
 def test_the_score_is_scaled_by_one_over_sqrt_192_by_hand():

@@ -99,22 +99,24 @@ def test_flash_kernel_names_are_pinned(kernel_texts, key, name, blocks):
 def latent_text():
     """Forward and backward of a latent call as lowered here, by the
     backward its shapes select (``_latent_backward``): a causal call
-    fuses, a non-causal one cannot."""
+    fuses, a non-causal one cannot. ISSUE 46: one block of 256, so the
+    causal call's kernels are the banded ones (two bands of 128)."""
     q = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
     qr, kr = jnp.zeros((1, 256, 2, 64), q.dtype), jnp.zeros((1, 256, 64),
                                                              q.dtype)
     texts = {}
     for backward, causal in (("fused", True), ("split", False)):
         def loss(q, k, v, qr, kr, causal=causal):
-            return fa.flash_attention(q, k, v, causal=causal, block_q=128,
-                                      block_k=128, q_rope=qr,
+            return fa.flash_attention(q, k, v, causal=causal, block_q=256,
+                                      block_k=256, q_rope=qr,
                                       k_rope=kr).astype(jnp.float32).sum()
 
-        before = fa.BACKWARD_COUNTS[backward]
+        before = fa.BACKWARD_COUNTS[backward], fa.BAND_COUNTS[2]
         texts[backward] = jax.jit(jax.grad(
             loss, argnums=(0, 1, 2, 3, 4))).lower(
             q, q, q, qr, kr).as_text(debug_info=True)
-        assert fa.BACKWARD_COUNTS[backward] == before + 1
+        assert fa.BACKWARD_COUNTS[backward] == before[0] + 1
+        assert fa.BAND_COUNTS[2] == before[1] + causal
     return texts
 
 
@@ -205,8 +207,10 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
             and e["label"] == "latent"][-1]
     assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
     assert path["data"]["shared_key"] == 64 and path["data"]["S"] == 128
-    # ISSUE 34: which backward the call's shapes selected
+    # ISSUE 34: which backward the call's shapes selected; ISSUE 46: the
+    # bands of a diagonal step (a block of 128 is one)
     assert path["data"]["backward"] == "fused"
+    assert path["data"]["bands"] == 1
 
 @pytest.mark.parametrize("key,name", [("fwd", "selscan_chunk_fwd"),
                                       ("bwd", "selscan_chunk_bwd")])
