@@ -50,8 +50,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import (apply_rope, cross_entropy_loss, flash_attention, rmsnorm,
                    rope_cache)
 from ..ops.expert_layer import held_expert_layer
-from ..ops.hyper_connection import (HC_PARAMS, hc_coefficients,
-                                    hc_param_shapes, hc_post, hc_pre)
+from ..ops.hyper_connection import HC_PARAMS, hc_mix, hc_param_shapes
 from ..ops.layers import yarn_rope_cache, yarn_softmax_scale
 from ..perf.recorder import record as _record
 
@@ -262,12 +261,10 @@ class DeepseekV3:
             y, aux = f(x)
             with jax.named_scope(scope) if scope else nullcontext():
                 return x + y, aux
-        pre, post, res = hc_coefficients(
-            x, {name: lp[f"{which}.{name}"] for name in HC_PARAMS},
+        return hc_mix(
+            x, {name: lp[f"{which}.{name}"] for name in HC_PARAMS}, f,
             iters=c.hc_sinkhorn_iters, eps=c.hc_eps,
             clamp=tuple(c.hc_res_clamp), rms_eps=c.rms_eps)
-        y, aux = f(hc_pre(x, pre))
-        return hc_post(x, y, post, res), aux
 
     def _attention(self, x, lp, cos, sin):
         """The attention sublayer of its input x, norm first, without the

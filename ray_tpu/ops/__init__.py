@@ -15,7 +15,9 @@ legend); for a TPU-native framework the hot ops are first-party:
   backward kernels (Pallas).
 - hyper_connection: several residual streams read, written and mixed
   around a sublayer by maps made doubly stochastic by Sinkhorn
-  iterations (mHC); plain jnp, every coefficient tokens-minor.
+  iterations (mHC); a Pallas kernel pair with a backward of its own, the
+  plain jnp form for shapes its tile cannot take; every coefficient
+  tokens-minor.
 - layers: rmsnorm/layernorm/gelu/rope (plain and YaRN)/cross-entropy,
   the causal depthwise convolution and the gated norm in plain jnp, shaped
   so XLA fuses them into the adjacent matmuls.
@@ -32,7 +34,7 @@ from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d, gated_rmsnorm)
 from .ssd_scan import ssd_scan
 from .selective_scan import selective_scan
-from .hyper_connection import hc_coefficients, hc_post, hc_pre
+from .hyper_connection import hc_coefficients, hc_mix, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
                               paged_attention_prefill, paged_gather_kv,
                               paged_write_prefill, paged_write_step)
@@ -41,7 +43,7 @@ __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "ssd_scan",
-    "selective_scan", "hc_coefficients", "hc_pre", "hc_post",
+    "selective_scan", "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",
     "paged_write_step",

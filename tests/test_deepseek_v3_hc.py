@@ -365,7 +365,10 @@ def test_bfloat16_coefficients_fail_the_limits(both, monkeypatch):
         return tuple(h.astype(jnp.bfloat16).astype(jnp.float32)
                      for h in real(*a, **kw))
 
-    monkeypatch.setattr("ray_tpu.models.deepseek_v3.hc_coefficients", rounded)
+    # d_model 64: the plain route of ``hc_mix``, which calls the module's
+    # ``hc_coefficients`` (tests/test_hyper_connection_kernels.py rounds
+    # the coefficients between the kernels)
+    monkeypatch.setattr(hc, "hc_coefficients", rounded)
     logits = jax.jit(model.apply)(params, toks)
     assert float(jnp.abs(logits - ref_logits).max()) \
         > LOGIT_LIMIT * float(jnp.abs(ref_logits).max())

@@ -20,6 +20,7 @@ fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
 el = importlib.import_module("ray_tpu.ops.expert_layer")
 ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
 sel = importlib.import_module("ray_tpu.ops.selective_scan")
+hc = importlib.import_module("ray_tpu.ops.hyper_connection")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -230,6 +231,73 @@ def test_selective_scan_kernel_names_are_pinned(key, name):
     assert re.search(pattern, lowered(128))
     # a call whose channels do not tile the lanes holds neither kernel
     assert not re.search(pattern, lowered(96))
+
+
+HC_KERNELS = [("pre_fwd", "mhc_pre_fwd"), ("post_fwd", "mhc_post_fwd"),
+              ("post_bwd", "mhc_post_bwd"), ("pre_bwd", "mhc_pre_bwd")]
+
+
+@pytest.fixture(scope="module")
+def hc_texts():
+    """One sublayer's forward + backward lowered at a shape the tile takes
+    (4 streams of d 128 over 256 tokens) and at one it cannot (d 64), with
+    the events each trace left."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    kw = dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0), rms_eps=1e-6)
+
+    def loss(x, p):
+        out, _ = hc.hc_mix(x, p, lambda z: (2.0 * z, None), **kw)
+        return sum(jnp.sum(jnp.square(o.astype(jnp.float32))) for o in out)
+
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    out = {}
+    try:
+        for d in (128, 64):
+            x = tuple(jnp.zeros((2, 128, d), jnp.bfloat16) for _ in range(4))
+            p = {k: jnp.zeros(v) for k, v in hc.hc_param_shapes(4, d).items()}
+            t0 = time.time()
+            text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                x, p).as_text(debug_info=True)
+            out[d] = (text, [e for e in rec.snapshot(clear=False)
+                             if e["kind"] == "rtpu.ops.hyper_connection"
+                             and e["ts"] >= t0])
+    finally:
+        rec.enabled = was
+    return out
+
+
+@pytest.mark.parametrize("key,name", HC_KERNELS)
+def test_hyper_connection_kernel_names_are_pinned(hc_texts, key, name):
+    """ISSUE 47: a device trace and ``scope_ops.py`` show the mixings'
+    four kernels by these, all under the scope ``mhc``, forward and
+    backward."""
+    assert hc.KERNEL_NAMES[key] == name
+    assert len(set(hc.KERNEL_NAMES.values())) == len(HC_KERNELS) == len(
+        hc.KERNEL_NAMES)
+    others = set(fa.KERNEL_NAMES.values()) | set(
+        fa.LATENT_KERNEL_NAMES.values()) | set(el.KERNEL_NAMES.values()) \
+        | set(ssd.KERNEL_NAMES.values()) | set(sel.KERNEL_NAMES.values())
+    assert not set(hc.KERNEL_NAMES.values()) & others
+    text, _ = hc_texts[128]
+    pattern = r"[/\"(]" + name + r"[/\")]"
+    assert re.search(pattern, text)
+    locs = [n for n in re.findall(r'loc\("([^"]+)"', text)
+            if re.search(r"(^|/)" + name + r"($|/)", n)]
+    assert locs and all(re.search(r"(^|[/(])mhc[/)]", n) for n in locs), locs
+    # a call whose d does not tile the lanes holds no kernel
+    assert not re.search(pattern, hc_texts[64][0])
+
+
+@pytest.mark.parametrize("d,route", [(128, "kernel"), (64, "plain")])
+def test_the_hyper_connections_route_leaves_its_event(hc_texts, d, route):
+    """ISSUE 47: one ``rtpu.ops.hyper_connection`` event a traced
+    sublayer, kind ``route``."""
+    _, events = hc_texts[d]
+    assert len(events) == 1 and events[0]["label"] == "route"
+    assert events[0]["data"] == {"route": route, "streams": 4, "d": d,
+                                 "tokens": 256, "tile": hc.TOKEN_TILE}
 
 
 # what the call shows beside q [1, 128, 2, 64]: (v's heads, v's head size,
