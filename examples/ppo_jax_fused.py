@@ -2,7 +2,8 @@
 rollout, GAE, and SGD compile into ONE XLA program per dispatch — the
 pipeline that runs the pixels benchmark at ~160k env-steps/s on a
 single v5e chip (vs ~100-500/s for any host-rollout design over a slow
-host<->device link). See docs/PERF_NOTES.md round 5.
+host<->device link; a reading of round 5, before PR 21: in git,
+`git show a61f7ab:docs/PERF_NOTES.md`, not measured on today's code).
 
 Usage:
     python examples/ppo_jax_fused.py                   # CartPole

@@ -8,7 +8,8 @@
  *
  * The hot frames are TaskSpec pushes (~40 primitive leaves per spec) and
  * task_done payloads — decoding them here instead of bytecode is a
- * ~5-10x win on the head-throughput envelope (docs/PERF_NOTES.md r5).
+ * ~5-10x win on the head-throughput envelope (a CPU harness of round 5,
+ * since deleted: not measured on today's code).
  */
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>

@@ -44,27 +44,28 @@ The routes, chosen by what a call shows (``PATH_COUNTS``, the event
   and k (multi-head latent attention: 128 + 64 against 128). dr of 64 or
   128, the q/k and v head sizes multiples of 128 (``_latent_ok``):
   kernels of their own (``LATENT_KERNEL_NAMES``), 128 // dr heads to a
-  program, the shared key never copied to the heads: a forward and, for
-  a causal call whose whole-sequence accumulators fit VMEM, ONE backward
-  kernel that makes the score and dP once a block pair; for every other
-  call two (``_latent_backward``; ``backward`` in the event). See the
-  section "latent attention" below.
-* ``reference`` / ``latent_reference``: S no multiple of 128, or latent
-  head sizes that do not tile the lanes: ``mha_reference``, no kernel.
+  program, the shared key never copied to the heads: a forward and ONE
+  backward kernel that makes the score and dP once a block pair, for
+  CAUSAL calls in square blocks (``_latent_attention``). See the section
+  "latent attention" below.
+* ``reference`` / ``latent_reference``: S no multiple of 128, latent
+  head sizes that do not tile the lanes, or a latent call that is not
+  causal: ``mha_reference``, no kernel.
 
 The grid streams Q and K/V blocks so nothing larger than a block is
 VMEM-resident (but the float32 dq of one head block, which the latent
-route's fused backward keeps for the whole sequence). bf16 inputs feed
-the MXU directly
-(preferred_element_type=f32 accumulate); all softmax state is f32 on the
-VPU — the standard TPU recipe (pallas_guide.md: MXU matmuls with
-preferred_element_type; min tile (16,128) for bf16).
+route's backward keeps for the whole sequence). bf16 inputs feed the MXU
+directly (preferred_element_type=f32 accumulate); all softmax state is
+f32 on the VPU — the standard TPU recipe (pallas_guide.md: MXU matmuls
+with preferred_element_type; min tile (16,128) for bf16).
 
-Forward saves the logsumexp per row; backward is two Pallas kernels that
+Forward saves the logsumexp per row. The backward of the ONE-PART score
+(``merged``, ``paired``, ``relayout``) is two Pallas kernels that
 recompute probabilities from (q, k, lse) inside the kernel — dq in one
 pass over K blocks, dk/dv in one pass over Q blocks — with f32 scratch
-accumulators, or one fused kernel where K/V are a single block; delta =
-rowsum(dO * O) is reduced inside them too (``_row_delta``).
+accumulators, or one fused kernel where K/V are a single block; the
+latent route's is one kernel at every S (below). delta = rowsum(dO * O)
+is reduced inside them too (``_row_delta``).
 
 Causal calls do not compute what the mask would throw away, in one of two
 ways. The STREAMED kernels (``flash_fwd``, ``flash_bwd_dq``,
@@ -81,14 +82,9 @@ exp(-1e30 - m) = 0 to its row's sum and an exact zero to every product,
 so each band's numbers are the square's, summed over the non-zero terms.
 Non-causal calls and a single-block call with several q blocks work the
 whole block as one band. The LATENT kernels do the same inside every
-block the diagonal crosses, whatever S: where a causal call's blocks are
-square and a multiple of 256 (``_latent_band``), the step qi == kb cuts
-the block's rows into bands of a quarter of the BLOCK (256 at blocks of
-1024) and adds each band's terms into static slices of the scratch the
-kernel keeps across steps (the online softmax's m, l, acc at the band's
-rows; dk, dv at its columns and dq at its rows); the steps below the
-diagonal work the whole block unmasked, as before (``bands`` in the
-event: the bands of such a step).
+block the diagonal crosses, whatever S, in bands of a quarter of the
+BLOCK (``_latent_band``; the section "latent attention" below; ``bands``
+in the event: the bands of such a step).
 
 A causal call with a ``window`` (query i sees the keys i - window < j <= i)
 takes the streamed kernels whatever S, and their innermost grid dimension
@@ -131,21 +127,17 @@ KERNEL_NAMES = {
     "bwd_dkv": "flash_bwd_dkv",         # dk and dv, one pass over Q blocks
 }
 
-# The kernels of a call whose score has a second part against one shared
-# key (latent attention), at any S; pinned in the same test. A call runs
-# the forward and either ``bwd_dkv`` alone or ``bwd_dq`` then ``bwd_dkv``
-# (``_latent_backward``). The FUSED backward is launched as
-# ``flash_latent_bwd_dkv``: it is that kernel, its accumulators over the q
-# blocks kept, grown by the dq outputs, and the benchmark's reader
-# (``benchmark/layer_metrics/mla_attention_roofline.py``) finds the
-# kernels' time by exactly these three names: under a fourth the time
-# would leave the metric while the operations stayed in its count.
-# ``flash_latent_bwd_dq`` is the first kernel of the split path only.
+# The two kernels of a call whose score has a second part against one
+# shared key (latent attention), at any S; pinned in the same test. The
+# backward makes all five gradients and is launched as
+# ``flash_latent_bwd_dkv``: the benchmark's reader
+# (``benchmark/layer_metrics/mla_attention_roofline.py``) knows that name,
+# and under another the time would leave the metric while the operations
+# stayed in its count.
 LATENT_KERNEL_NAMES = {
     "fwd": "flash_latent_fwd",
-    "bwd_dq": "flash_latent_bwd_dq",        # split: dq_nope, dq_rope
-    "bwd_dkv": "flash_latent_bwd_dkv",      # dk_nope, dv, d(shared key);
-                                            # fused: dq_nope, dq_rope too
+    "bwd_dkv": "flash_latent_bwd_dkv",      # dq_nope, dq_rope, dk_nope,
+                                            # d(shared key), dv
 }
 
 # Traced calls of flash_attention by the layout each took: "merged" (the
@@ -160,13 +152,8 @@ PATH_COUNTS: collections.Counter = collections.Counter()
 # (``_band_height``): 4 at S=1024, 1 where nothing is banded (non-causal,
 # streamed, S=128), 0 on the reference route; of a LATENT call the bands
 # of a step the diagonal crosses (``_latent_band``: 4 at blocks of 1024,
-# 2 at 256, 1 at 128 or non-causal). ``bands`` in the event's data.
+# 2 at 256, 1 at 128). ``bands`` in the event's data.
 BAND_COUNTS: collections.Counter = collections.Counter()
-
-# The traced LATENT calls by the backward their shapes select
-# (``_latent_backward``): "fused" (one kernel) or "split" (two).
-# ``backward`` in the event's data.
-BACKWARD_COUNTS: collections.Counter = collections.Counter()
 
 
 def _use_interpret() -> bool:
@@ -1046,27 +1033,26 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # by static slices; the rope part of head j is the q_rope tile with the
 # other heads' lanes zeroed (``_head_lanes``) against k_rope repeated to
 # 128 lanes (``kr``), so that the zeroed lanes add exact zeros. The
-# gradient of ``kr`` comes out of the dk/dv kernel summed over every head
-# of the batch row: its grid runs the head blocks INSIDE a key block and
-# carries one [block_k, 128] accumulator across them. One set of kernels
-# serves every S (a single block is a grid of one step); causal blocks
+# gradient of ``kr`` comes out of the backward summed over every head of
+# the batch row: one accumulator carried across the head blocks. One set
+# of kernels serves every S (a single block is a grid of one step); blocks
 # above the diagonal are skipped by predicate and fetch nothing (their
 # index maps point at the block before), and only blocks the diagonal
 # crosses are masked. Such a DIAGONAL step works causal row bands, not
 # the masked square, where the blocks are square and a multiple of 256
-# (``_latent_band``; every kernel of the route, by ``_latent_band_scores``
-# and ``_latent_bwd_bands``): band r of a quarter of the block takes the
+# (``_latent_band``; both kernels, by ``_latent_band_scores`` and
+# ``_latent_bwd_bands``): band r of a quarter of the block takes the
 # rows [r*h, (r+1)*h) of q, o, dO and the row statistics and the columns
 # [0, (r+1)*h) of k_nope, the shared key and v, masks the last [h, h]
 # tile alone and adds into static slices of the scratch: 10 of a block's
 # 16 band tiles issued; at S 4096 in blocks of 1024 four of a head
-# block's ten computed steps are diagonal, at S 8192 eight of 36. Every
-# other call (non-causal, unequal blocks, blocks of 128) works the whole
-# block under the mask. The two backward kernels each make the score, dP,
-# the mask and exp of a block pair: 5 + 6 passes of a 128-deep
-# contraction a pair and head. A causal call runs ONE kernel instead
-# (``_latent_bwd_fused_kernel``, 8 passes) where its shapes allow
-# (``_latent_backward``).
+# block's ten computed steps are diagonal, at S 8192 eight of 36. A call
+# in blocks of 128 works the whole block under the mask. The backward is
+# ONE kernel (``_latent_bwd_fused_kernel``): the score, dP, the mask and
+# exp of a block pair are made once and feed all five gradients, 8 passes
+# of a 128-deep contraction a pair and head (a dq kernel beside a dk/dv
+# kernel issued 5 + 6; PERF.md, PR 34), for causal calls in square
+# blocks: ``_latent_attention`` hands the kernels nothing else.
 
 
 def _latent_cut(qn, qr, v, heads, block_q, block_k):
@@ -1240,17 +1226,15 @@ def _latent_delta(do, o, j, dv):
 
 
 def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
-                      lse_ref, delta, *, dn, dr, dv, sm_scale, dkv=None,
-                      dq=None):
-    """The DIAGONAL step of a banded call's backward (``_latent_band``),
-    for all three kernels: ``_latent_bwd_head``'s (p, ds) of each (band,
-    head) unit, [band, columns the band sees], and the products they feed,
-    added into static slices of what the kernel keeps: ``dkv`` = (dk_nope,
-    d(shared key), dv) accumulators of the key block at the band's
-    COLUMNS, ``dq`` = (dq_nope, dq_rope) of the q block at the band's
-    ROWS. ``delta`` is one [block, 1] column a head. Unit i+1's scores,
-    exp, dP and ds are written before unit i's products
-    (``_one_ahead``)."""
+                      lse_ref, delta, *, dn, dr, dv, sm_scale, dkv, dq):
+    """The DIAGONAL step of a banded call's backward (``_latent_band``):
+    ``_latent_bwd_head``'s (p, ds) of each (band, head) unit, [band,
+    columns the band sees], and the products they feed, added into static
+    slices of what the kernel keeps: ``dkv`` = (dk_nope, d(shared key),
+    dv) accumulators of the key block at the band's COLUMNS, ``dq`` =
+    (dq_nope, dq_rope) of the q block at the band's ROWS. ``delta`` is one
+    [block, 1] column a head. Unit i+1's scores, exp, dP and ds are
+    written before unit i's products (``_one_ahead``)."""
     heads = lse_ref.shape[0]
     block = qn_ref.shape[0]
     # lse is stored [1, block]; rows here are q-positions
@@ -1260,8 +1244,10 @@ def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
     qn, do = qn_ref[...], do_ref[...]
     # head j's lanes of q_rope (for the shared key's gradient) and of the
     # repeated key (for dq_rope), the other heads' zeroed: once a program
-    qrs = dkv and [_head_lanes(qr_ref[...], j, dr) for j in range(heads)]
-    krs = dq and [_head_lanes(kr_ref[...], j, dr) for j in range(heads)]
+    qrs = [_head_lanes(qr_ref[...], j, dr) for j in range(heads)]
+    krs = [_head_lanes(kr_ref[...], j, dr) for j in range(heads)]
+    dkn_acc, dkr_acc, dv_acc = dkv
+    dqn_acc, dqr_acc = dq
 
     def head(u):
         r0, h, end, j = u
@@ -1278,110 +1264,11 @@ def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
             _units(block, block, band, heads), head, True):
         rows, nope, val = (slice(r0, r0 + h), slice(j * dn, (j + 1) * dn),
                            slice(j * dv, (j + 1) * dv))
-        if dkv is not None:
-            dkn_acc, dkr_acc, dv_acc = dkv
-            dv_acc[:end, val] += _dot(p, doj, _ATB)
-            dkn_acc[:end, nope] += _dot(ds, _rows(_lanes(qn, j, dn), r0, h),
-                                        _ATB)
-            dkr_acc[:end, :] += _dot(ds, _rows(qrs[j], r0, h), _ATB)
-        if dq is not None:
-            dqn_acc, dqr_acc = dq
-            dqn_acc[rows, nope] += _dot(ds, kn_ref[:end, nope], _AB)
-            dqr_acc[rows, :] += _dot(ds, _rows(krs[j], 0, end), _AB)
-
-
-def _latent_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
-                          do_ref, lse_ref, dqn_ref, dqr_ref, delta_ref,
-                          dqn_scr, dqr_scr, *, sm_scale, causal, dn, dr, dv,
-                          block_q, block_k, num_kb, band):
-    """Grid (B, head blocks, q blocks, k blocks): dq_nope and dq_rope
-    accumulated over K; emits delta [B*H, 1, S] for the dk/dv kernel."""
-    qi, kb = pl.program_id(2), pl.program_id(3)
-    heads = lse_ref.shape[0]
-
-    @pl.when(kb == 0)
-    def _init():
-        dqn_scr[...] = jnp.zeros_like(dqn_scr)
-        dqr_scr[...] = jnp.zeros_like(dqr_scr)
-        for j in range(heads):
-            delta_ref[j] = _latent_delta(do_ref[...], o_ref[...], j, dv).T
-
-    def compute(masked):
-        if masked and band:
-            return _latent_bwd_bands(
-                band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
-                [delta_ref[j].T for j in range(heads)], dn=dn, dr=dr, dv=dv,
-                sm_scale=sm_scale, dq=(dqn_scr, dqr_scr))
-        qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
-                                 kr_ref[...], v_ref[...], do_ref[...])
-        for j in range(heads):
-            _, ds = _latent_bwd_head(
-                qn, qr, kn, kr, v, do, lse_ref[j].T, delta_ref[j].T, j,
-                dn=dn, dr=dr, dv=dv, sm_scale=sm_scale, masked=masked,
-                row0=qi * block_q, col0=kb * block_k)
-            dqn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(kn, j, dn),
-                                                    _AB)
-            # ds @ [k_rope | k_rope]: head j keeps its own lanes
-            dqr_scr[...] += _dot(ds, _head_lanes(kr, j, dr), _AB)
-
-    _causal_steps(causal, qi, kb, block_q, block_k, compute)
-
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        dqn_ref[...] = dqn_scr[...].astype(dqn_ref.dtype)
-        dqr_ref[...] = dqr_scr[...].astype(dqr_ref.dtype)
-
-
-def _latent_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
-                           lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
-                           dkn_scr, dkr_scr, dv_scr, *, sm_scale, causal, dn,
-                           dr, dv, block_q, block_k, num_qb, num_cb, band):
-    """Grid (B, k blocks, head blocks, q blocks): dk_nope and dv
-    accumulated over Q for one head block; the shared key's gradient over
-    Q AND over the head blocks, written once a key block."""
-    kb, cb, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    heads = lse_ref.shape[0]
-
-    @pl.when(qi == 0)
-    def _init():
-        dkn_scr[...] = jnp.zeros_like(dkn_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    @pl.when((qi == 0) & (cb == 0))
-    def _init_shared():
-        dkr_scr[...] = jnp.zeros_like(dkr_scr)
-
-    def compute(masked):
-        if masked and band:
-            return _latent_bwd_bands(
-                band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
-                [delta_ref[j].T for j in range(heads)], dn=dn, dr=dr, dv=dv,
-                sm_scale=sm_scale, dkv=(dkn_scr, dkr_scr, dv_scr))
-        qn, qr, kn, kr, v, do = (qn_ref[...], qr_ref[...], kn_ref[...],
-                                 kr_ref[...], v_ref[...], do_ref[...])
-        for j in range(heads):
-            p, ds = _latent_bwd_head(
-                qn, qr, kn, kr, v, do, lse_ref[j].T, delta_ref[j].T, j,
-                dn=dn, dr=dr, dv=dv, sm_scale=sm_scale, masked=masked,
-                row0=qi * block_q, col0=kb * block_k)
-            dv_scr[:, j * dv:(j + 1) * dv] += _dot(p, _lanes(do, j, dv),
-                                                   _ATB)
-            dkn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(qn, j, dn),
-                                                    _ATB)
-            # zero outside head j's lanes: lane block j of the repeated
-            # key collects the heads that read it
-            dkr_scr[...] += _dot(ds, _head_lanes(qr, j, dr), _ATB)
-
-    _causal_steps(causal, qi, kb, block_q, block_k, compute)
-
-    @pl.when(qi == num_qb - 1)
-    def _finalize():
-        dkn_ref[...] = dkn_scr[...].astype(dkn_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
-
-    @pl.when((qi == num_qb - 1) & (cb == num_cb - 1))
-    def _finalize_shared():
-        dkr_ref[...] = dkr_scr[...].astype(dkr_ref.dtype)
+        dv_acc[:end, val] += _dot(p, doj, _ATB)
+        dkn_acc[:end, nope] += _dot(ds, _rows(_lanes(qn, j, dn), r0, h), _ATB)
+        dkr_acc[:end, :] += _dot(ds, _rows(qrs[j], r0, h), _ATB)
+        dqn_acc[rows, nope] += _dot(ds, kn_ref[:end, nope], _AB)
+        dqr_acc[rows, :] += _dot(ds, _rows(krs[j], 0, end), _AB)
 
 
 def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
@@ -1392,9 +1279,9 @@ def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
     """The whole backward of a causal call in one kernel, blocks square:
     grid (B, head blocks, k blocks, q blocks). ``_latent_bwd_head``'s
     (p, ds) are made once a (q block, k block) pair and head and feed all
-    five gradients. dk_nope and dv accumulate over Q as in
-    ``_latent_bwd_dkv_kernel``. dq_nope and dq_rope accumulate over the
-    key blocks in VMEM for the WHOLE sequence of one head block
+    five gradients. dk_nope and dv accumulate over Q for one head block.
+    dq_nope and dq_rope accumulate over the key blocks in VMEM for the
+    WHOLE sequence of one head block
     ([q blocks, block, width] f32): rows of q block i are complete at the
     diagonal step (kb = i, qi = i), the first computed step of key block
     i, and are written there; their output block is (b, kb, c), which
@@ -1472,21 +1359,17 @@ def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
 _LATENT_VMEM_BYTES = 96 * 1024 * 1024
 
 
-def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths,
-                  heads_outer: bool = False):
+def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths):
     """Block specs of the latent kernels for a grid (B, head block, q
-    block, k block) (``q_major``), (B, k block, head block, q block) or
-    (``heads_outer``) (B, head block, k block, q block): (q_nope, q_rope,
-    o / dO, k_nope, v, shared key, row statistics). A skipped causal step
-    names the block of the last computed step, so nothing is fetched for
-    it."""
+    block, k block) (``q_major``: the forward) or (B, head block, k block,
+    q block) (the backward): (q_nope, q_rope, o / dO, k_nope, v, shared
+    key, row statistics). A skipped causal step names the block of the
+    last computed step, so nothing is fetched for it."""
     wn, wv = widths
 
     def at(pick):
-        def index_map(*g):
-            b, c, i, j = g if q_major else (
-                (g[0], g[1], g[3], g[2]) if heads_outer
-                else (g[0], g[2], g[3], g[1]))
+        def index_map(b, c, x, y):
+            i, j = (x, y) if q_major else (y, x)
             if causal and q_major:   # k blocks past the diagonal: skipped
                 j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
             elif causal:             # q blocks before the diagonal: skipped
@@ -1551,44 +1434,43 @@ def _latent_fwd(qn, qr, kn, kr, v, heads, sm_scale, causal, block_q,
     )(qn, qr, kn, kr, v)
 
 
-def _latent_backward(seq: int, causal: bool, block_q: int, block_k: int,
-                     wn: int, wv: int, itemsize: int) -> str:
-    """Which backward a latent call takes, from its shapes alone
-    (``backward`` in the event ``rtpu.ops.flash.path``,
-    ``BACKWARD_COUNTS``). ``fused``: one kernel
-    (``_latent_bwd_fused_kernel``) that makes the score, p, dP and ds once
-    a block pair, 8 passes of the MXU a pair and head where the two
-    kernels of ``split`` issue 11. It needs the causal order (a q block's
-    last key block is its own), square blocks, and VMEM for what it keeps
-    of the whole sequence beside a block pair's tiles; a non-causal call
-    and a sequence too long for that take the two kernels. At S=8192 and
-    two heads of 128 to a block: 16.8 MB of whole-sequence accumulators +
-    16.8 MB of score tiles + 2 MB + 10.5 MB of operands: 46 of 96."""
-    if not causal or block_q != block_k:
-        return "split"
+def _latent_bwd_vmem(seq: int, block: int, wn: int, wv: int,
+                     itemsize: int) -> int:
+    """Bytes of VMEM the backward kernel asks for: what it keeps of the
+    whole sequence beside a block pair's tiles (``_latent_attention``
+    refuses a call over ``_LATENT_VMEM_BYTES``). At S=8192 and two heads
+    of 128 to a block: 16.8 MB of whole-sequence accumulators + 16.8 MB of
+    score tiles + 2 MB + 10.5 MB of operands: 46 of 96; S 34 816 is the
+    longest that fits at these widths."""
     whole = seq * (wn + 2 * _LANES) * 4         # dq_nope, dq_rope, d(kr)
-    tiles = block_q * block_k * (3 * 4 + 2 * itemsize)  # s, dP, ds; p, ds
-    dkv = block_k * (wn + wv) * 4
+    tiles = block * block * (3 * 4 + 2 * itemsize)      # s, dP, ds; p, ds
+    dkv = block * (wn + wv) * 4
     # q_nope, q_rope, o, dO, k_nope, kr, v in; the five gradients out;
-    # each double-buffered
-    operands = 2 * 2 * itemsize * (block_q * (wn + wv + _LANES)
-                                   + block_k * (wn + wv + _LANES))
-    fits = whole + tiles + dkv + operands <= _LATENT_VMEM_BYTES
-    return "fused" if fits else "split"
+    # each double-buffered; a q side and a k side of one block each
+    operands = 2 * 2 * itemsize * (2 * block * (wn + wv + _LANES))
+    return whole + tiles + dkv + operands
 
 
-def _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, block):
-    """``_latent_bwd`` of a causal call in one kernel, launched under the
-    dk/dv kernel's name (``LATENT_KERNEL_NAMES``)."""
+def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
+                block_q, block_k):
+    """-> dq_nope, dq_rope, dk_nope, d(kr) [B, S, 128] (the heads of a
+    batch row summed; lane block j holds the heads whose rope part reads
+    it), dv, of a CAUSAL call in square blocks: one kernel, launched under
+    the name the benchmark's reader knows (``LATENT_KERNEL_NAMES``)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, seq, _ = qn.shape
-    dn, dr, dv, hpb, ncb, _, _ = _latent_cut(qn, qr, v, heads, block, block)
+    dn, dr, dv, hpb, ncb, block, block_k = _latent_cut(
+        qn, qr, v, heads, block_q, block_k)
+    if not causal or block != block_k:
+        # a q block's dq is complete at ITS diagonal step: no other order
+        raise ValueError("the latent backward takes causal calls in square "
+                         f"blocks, got causal={causal}, {block} x {block_k}")
     nb = seq // block
     wn, wv = hpb * dn, hpb * dv
     pairs = b * heads * seq * seq // 2
     qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
-        False, True, block, block, hpb, ncb, (wn, wv), heads_outer=True)
+        False, True, block, block, hpb, ncb, (wn, wv))
     # dq's blocks leave by key block (square blocks: k_nope's spec fits
     # dq_nope); the shared key's gradient leaves in the last head block
     # and names one block until then, so that nothing is written before
@@ -1625,104 +1507,24 @@ def _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, block):
     )(qn, qr, kn, kr, v, o, g, lse)
 
 
-def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
-                block_q, block_k):
-    """-> dq_nope, dq_rope, dk_nope, d(kr) [B, S, 128] (the heads of a
-    batch row summed; lane block j holds the heads whose rope part reads
-    it), dv."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, seq, _ = qn.shape
-    dn, dr, dv, hpb, ncb, block_q, block_k = _latent_cut(
-        qn, qr, v, heads, block_q, block_k)
-    num_qb, num_kb = seq // block_q, seq // block_k
-    wn, wv = hpb * dn, hpb * dv
-    itemsize = qn.dtype.itemsize
-    if _latent_backward(seq, causal, block_q, block_k, wn, wv,
-                        itemsize) == "fused":
-        return _latent_bwd_fused(qn, qr, kn, kr, v, o, lse, g, heads,
-                                 sm_scale, block_q)
-    half = 2 if causal else 1
-    pairs = b * heads * seq * seq // half
-    kernel_kw = dict(sm_scale=sm_scale, causal=causal, dn=dn, dr=dr, dv=dv,
-                     block_q=block_q, block_k=block_k,
-                     band=_latent_band(causal, block_q, block_k))
-
-    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
-        True, causal, block_q, block_k, hpb, ncb, (wn, wv))
-    dqn, dqr, delta = pl.pallas_call(
-        functools.partial(_latent_bwd_dq_kernel, num_kb=num_kb, **kernel_kw),
-        grid=(b, ncb, num_qb, num_kb),
-        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, qv_s, row_s],
-        out_specs=[qn_s, qr_s, row_s],
-        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
-                   jax.ShapeDtypeStruct(qr.shape, qr.dtype),
-                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, wn), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=_LATENT_VMEM_BYTES),
-        name=LATENT_KERNEL_NAMES["bwd_dq"],
-        interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * pairs * (2 * (dn + dr) + dv),
-            bytes_accessed=(2 * qn.size + 2 * qr.size + kn.size + kr.size
-                            + 3 * v.size) * itemsize,
-            transcendentals=pairs),
-    )(qn, qr, kn, kr, v, o, g, lse)
-
-    qn_s, qr_s, qv_s, kn_s, kv_s, kr_s, row_s = _latent_specs(
-        False, causal, block_q, block_k, hpb, ncb, (wn, wv))
-    dkn, dkr, dvv = pl.pallas_call(
-        functools.partial(_latent_bwd_dkv_kernel, num_qb=num_qb, num_cb=ncb,
-                          **kernel_kw),
-        grid=(b, num_kb, ncb, num_qb),
-        in_specs=[qn_s, qr_s, kn_s, kr_s, kv_s, qv_s, row_s, row_s],
-        out_specs=[kn_s, kr_s, kv_s],
-        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
-                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, wn), jnp.float32),
-                        pltpu.VMEM((block_k, _LANES), jnp.float32),
-                        pltpu.VMEM((block_k, wv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
-            vmem_limit_bytes=_LATENT_VMEM_BYTES),
-        name=LATENT_KERNEL_NAMES["bwd_dkv"],
-        interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * pairs * (2 * (dn + dr) + 2 * dv),
-            bytes_accessed=(2 * qn.size + 2 * qr.size + 2 * kn.size
-                            + 2 * kr.size + 3 * v.size) * itemsize,
-            transcendentals=pairs),
-    )(qn, qr, kn, kr, v, g, lse, delta)
-    return dqn, dqr, dkn, dkr, dvv
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_latent(qn, qr, kn, kr, v, h, sm_scale, block):
+    return _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, True, block, block)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_latent(qn, qr, kn, kr, v, h, sm_scale, causal, block_q, block_k):
-    return _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
-                       block_k)[0]
-
-
-def _flash_latent_vjp_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
-                          block_k):
+def _flash_latent_vjp_fwd(qn, qr, kn, kr, v, h, sm_scale, block):
     from jax.ad_checkpoint import checkpoint_name
 
-    out, lse = _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, causal, block_q,
-                           block_k)
+    out, lse = _latent_fwd(qn, qr, kn, kr, v, h, sm_scale, True, block, block)
     out = checkpoint_name(out, "flash_out")    # as ``_flash_vjp_fwd``
     lse = checkpoint_name(lse, "flash_lse")
     return out, (qn, qr, kn, kr, v, out, lse)
 
 
-def _flash_latent_vjp_bwd(h, sm_scale, causal, block_q, block_k, res, g):
+def _flash_latent_vjp_bwd(h, sm_scale, block, res, g):
     qn, qr, kn, kr, v, out, lse = res
-    return _latent_bwd(qn, qr, kn, kr, v, out, lse, g, h, sm_scale, causal,
-                       block_q, block_k)
+    return _latent_bwd(qn, qr, kn, kr, v, out, lse, g, h, sm_scale, True,
+                       block, block)
 
 
 _flash_latent.defvjp(_flash_latent_vjp_fwd, _flash_latent_vjp_bwd)
@@ -1741,7 +1543,11 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
                       block_k):
     """The call with a second, shared part of the score: q, k
     [B, S, H, dn], v [B, S, H, dv], q_rope [B, S, H, dr], k_rope
-    [B, S, dr] -> [B, S, H, dv]."""
+    [B, S, dr] -> [B, S, H, dv]. The route is decided here, once, from
+    what the call shows: a causal call whose sizes tile takes the kernels,
+    in square blocks (the smaller of the two asked for); a call that is
+    not causal, or whose sizes do not tile, ``mha_reference``; a causal
+    call too long for what the backward keeps in VMEM is refused."""
     b, s, h, dn = q.shape
     dr, dv = q_rope.shape[-1], v.shape[-1]
     if sm_scale is None:
@@ -1750,24 +1556,28 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
         raise ValueError("latent flash_attention requires seq_q == seq_k, "
                          f"got {s} != {k.shape[1]}")
     facts = {"hd_qk": dn + dr, "hd_v": dv, "shared_key": dr}
-    if s % 128 != 0 or not _latent_ok(h, dn, dr, dv):
+    if s % 128 != 0 or not _latent_ok(h, dn, dr, dv) or not causal:
         _note_path("latent_reference", 0, dn + dr, s, 0, **facts)
         shared = jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))
         return mha_reference(jnp.concatenate([q, q_rope], -1),
                              jnp.concatenate([k, shared], -1), v,
                              causal=causal, sm_scale=sm_scale)
     hpb = _LANES // dr
-    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
-    backward = _latent_backward(s, causal, bq, bk, hpb * dn, hpb * dv,
-                                q.dtype.itemsize)
-    BACKWARD_COUNTS[backward] += 1
-    band = _latent_band(causal, bq, bk)
-    _note_path("latent", hpb, dn + dr, s, bq // band if band else 1,
-               backward=backward, **facts)
+    # square blocks: the smaller of the two fitted ones divides S too
+    block = min(_fit_block(block_q, s), _fit_block(block_k, s))
+    need = _latent_bwd_vmem(s, block, hpb * dn, hpb * dv, q.dtype.itemsize)
+    if need > _LATENT_VMEM_BYTES:
+        # the reference would make S x S scores a head: no route to fall to
+        raise ValueError(
+            f"latent flash_attention at S={s}: the backward keeps {need} "
+            f"bytes in VMEM, the limit is {_LATENT_VMEM_BYTES}")
+    band = _latent_band(True, block, block)
+    _note_path("latent", hpb, dn + dr, s, block // band if band else 1,
+               **facts)
     merge = lambda x: x.reshape(b, s, -1)  # noqa: E731
     out = _flash_latent(merge(q), merge(q_rope), merge(k),
                         jnp.tile(k_rope, (1, 1, hpb)), merge(v), h,
-                        sm_scale, causal, block_q, block_k)
+                        sm_scale, block)
     return out.reshape(b, s, h, dv)
 
 
@@ -1842,7 +1652,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``k_rope`` [batch, seq, dr] for all heads has a score in two parts,
     (q·k + q_rope·k_rope) * sm_scale (default 1/sqrt(head_dim + dr)), and
     v may be of another head size than q and k: the latent kernels, where
-    the sizes tile 128 lanes (``_latent_ok``).
+    the call is causal and the sizes tile 128 lanes (``_latent_ok``).
 
     ``window`` (causal calls of the one-part score): query i sees the keys
     i - window < j <= i, itself and the window - 1 before it. The streamed

@@ -13,7 +13,7 @@ House TPU shape: rollout workers are the shared numpy `RolloutWorker`
 (GAE worker-side), and the learner applies ONE jitted update per
 train() call — microbatch gradient accumulation runs as a lax.scan
 inside the same dispatch, so the host pays one round trip regardless
-of microbatch count (docs/PERF_NOTES.md learner rule).
+of microbatch count (the learner rule of this package).
 """
 from __future__ import annotations
 

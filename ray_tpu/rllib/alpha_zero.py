@@ -16,7 +16,7 @@ the network always sees the position from the player-to-move's
 perspective, so one net plays both sides.
 
 Learner: visit-count cross-entropy + outcome MSE, all minibatches in
-one jitted lax.scan dispatch (docs/PERF_NOTES.md learner rule).
+one jitted lax.scan dispatch (the learner rule of this package).
 """
 from __future__ import annotations
 

@@ -16,7 +16,7 @@ sequence replay (zero-initialized latent per sequence: the posterior
 re-syncs from observations within a few steps), and the ENTIRE
 world-model + imagination actor-critic update block for all K sequence
 minibatches runs as one jitted lax.scan dispatch per train() call
-(docs/PERF_NOTES.md learner rule)."""
+(the learner rule of this package)."""
 from __future__ import annotations
 
 import time

@@ -11,7 +11,7 @@ centralized training, decentralized execution).
 TPU-native shape: the whole K-minibatch update (per-agent Q forward,
 target mixer, TD loss, Adam) is ONE jitted lax.scan dispatch
 (`update_many`) — the same fused-learner rule every off-policy algo in
-this package follows (docs/PERF_NOTES.md learner rule: no host round
+this package follows (the learner rule: no host round
 trip between updates). The env steps in-process: cooperative
 small-team games are sampler-light, learner-heavy.
 """

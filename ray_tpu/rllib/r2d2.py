@@ -11,7 +11,7 @@ in workers — np_policy.py rationale) and emit fixed-length SEQUENCES
 with the recurrent state captured at each window start; the driver keeps
 a prioritized replay of sequences; the learner unrolls burn-in (gradient
 stopped) + training segment as lax.scan inside ONE jitted dispatch per
-train() call (docs/PERF_NOTES.md learner rule). Episode boundaries
+train() call (the learner rule of this package). Episode boundaries
 inside a window reset the hidden state identically in worker and
 learner, so stored and recomputed unrolls agree.
 """

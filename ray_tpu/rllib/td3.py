@@ -10,7 +10,7 @@ actors (deterministic tanh head + exploration noise), host replay
 buffer, and the whole per-iteration update block — K minibatches of
 twin-critic TD, every-other-step actor + polyak — as ONE jitted
 lax.scan with donated buffers: one dispatch, one stats readback per
-train() call (docs/PERF_NOTES.md learner rule).
+train() call (the learner rule of this package).
 """
 from __future__ import annotations
 

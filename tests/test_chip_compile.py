@@ -194,39 +194,6 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     assert " transpose(" not in text
 
 
-@pytest.mark.parametrize("causal,s,h,bands", [(False, 2048, 4, 1),
-                                              (True, 40960, 2, 4)],
-                         ids=["non_causal", "too_long_to_fuse"])
-def test_a_latent_call_that_cannot_fuse_compiles_the_two_kernels(
-        one_chip, compiled_kernels, causal, s, h, bands):
-    """ISSUE 34: a non-causal latent call has no diagonal step at which a
-    q block's dq is complete, so it takes the two backward kernels as
-    they were: all three names, at blocks of 1024. ISSUE 46: so does a
-    causal call whose whole-sequence accumulators do not fit VMEM
-    (S 40 960 at these widths), and its three kernels work their diagonal
-    steps in four row bands (``_latent_bwd_bands`` with each kernel's own
-    accumulators): no cell runs them, so this compile is their check."""
-    from ray_tpu.ops.flash_attention import (BACKWARD_COUNTS, BAND_COUNTS,
-                                             LATENT_KERNEL_NAMES,
-                                             flash_attention)
-
-    sd = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, jnp.bfloat16, sharding=one_chip)
-
-    def loss(q, k, v, qr, kr):
-        return flash_attention(q, k, v, causal=causal, q_rope=qr,
-                               k_rope=kr).astype(jnp.float32).sum()
-
-    before = BACKWARD_COUNTS["split"], BAND_COUNTS[bands]
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        sd(1, s, h, 128), sd(1, s, h, 128), sd(1, s, h, 128),
-        sd(1, s, h, 64), sd(1, s, 64)).compile().as_text()
-    assert (BACKWARD_COUNTS["split"], BAND_COUNTS[bands]) == (
-        before[0] + 1, before[1] + 1)
-    assert _kernel_names(text, latent=True) == set(
-        LATENT_KERNEL_NAMES.values())
-
-
 @pytest.mark.parametrize(
     "t,d,f,held,of,shared,top_k,scale",
     [(16384, 2048, 768, 16, 128, 2, 6, 2.448),

@@ -128,23 +128,27 @@ def _latent_plain(q, k, v, q_rope, k_rope, causal=True):
                          causal=causal, sm_scale=qq.shape[-1] ** -0.5)
 
 
-# case -> (S, blocks, causal, VMEM budget or None for the module's, the
-# backward its shapes select). ISSUE 34: "fused" has diagonal and full
-# block pairs and two head blocks, whose shared-key gradients add up;
-# "split" is the same call with no room for the whole-sequence
-# accumulators; "full" a non-causal call, which never fuses. ISSUE 46:
-# blocks of 256 are worked in two row bands of 128 where the diagonal
-# crosses them (``_latent_band``): "banded" has two diagonal block pairs
-# and a full one, two head blocks, through the fused backward,
-# "banded_split" through the two kernels; case 256 is one banded block.
+# case -> (S, (block_q, block_k) asked for, causal, heads, dr, the square
+# block the kernels run or None where the call takes ``mha_reference``).
+# ISSUE 34: "fused" has diagonal and full block pairs and two head blocks,
+# whose shared-key gradients add up. ISSUE 46: blocks of 256 are worked in
+# two row bands of 128 where the diagonal crosses them (``_latent_band``):
+# "banded" has two diagonal block pairs and a full one, two head blocks;
+# case 256 is one banded block. ISSUE 48: ONE backward; "full", a
+# non-causal call, takes the reference route and still answers;
+# "one_head_block" (two heads of 64: the shared key's gradient is
+# initialised and leaves in the SAME head block); "rope128" (a rope part
+# of 128: one head a program); "unequal" (the kernels run square blocks,
+# the smaller of the two asked for).
 LATENT_CASES = {
-    256: (256, 256, True, None, "fused"),      # one K block a program
-    128: (256, 128, True, None, "fused"),      # streamed
-    "fused": (512, 128, True, None, "fused"),
-    "split": (512, 128, True, 1 << 20, "split"),
-    "full": (256, 128, False, None, "split"),
-    "banded": (512, 256, True, None, "fused"),
-    "banded_split": (512, 256, True, 1 << 20, "split"),
+    256: (256, (256, 256), True, 4, 64, 256),   # one K block a program
+    128: (256, (128, 128), True, 4, 64, 128),   # streamed
+    "fused": (512, (128, 128), True, 4, 64, 128),
+    "full": (256, (128, 128), False, 4, 64, None),
+    "banded": (512, (256, 256), True, 4, 64, 256),
+    "one_head_block": (512, (256, 256), True, 2, 64, 256),
+    "rope128": (512, (128, 128), True, 2, 128, 128),
+    "unequal": (1024, (512, 1024), True, 2, 64, 512),
 }
 
 
@@ -153,22 +157,25 @@ def latent_grads():
     import sys
     fa = sys.modules[flash_attention.__module__]   # the module, not the op
     out = {}
-    for case, (s, blocks, causal, vmem, backward) in LATENT_CASES.items():
-        args, w = _latent_inputs(s=s)
+    for case, (s, (bq, bk), causal, h, dr, block) in LATENT_CASES.items():
+        args, w = _latent_inputs(s=s, h=h, dr=dr)
 
-        def loss(*a, blocks=blocks, causal=causal, w=w):
+        def loss(*a, bq=bq, bk=bk, causal=causal, w=w):
             return (flash_attention(a[0], a[1], a[2], causal=causal,
-                                    block_q=blocks, block_k=blocks,
+                                    block_q=bq, block_k=bk,
                                     q_rope=a[3], k_rope=a[4]) * w).sum()
-        before = fa.BACKWARD_COUNTS[backward]
+        layout = "latent" if block else "latent_reference"
+        before, ran = fa.PATH_COUNTS[layout], []
         with pytest.MonkeyPatch.context() as mp:
-            if vmem:
-                mp.setattr(fa, "_LATENT_VMEM_BYTES", vmem)
+            kernels = fa._flash_latent
+            mp.setattr(fa, "_flash_latent", lambda *a: (
+                ran.append(a[-1]), kernels(*a))[1])
             out[case] = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
                 *args)
-        assert fa.BACKWARD_COUNTS[backward] == before + 1, case
-        if (s, causal) not in out:
-            out[s, causal] = jax.value_and_grad(
+        assert fa.PATH_COUNTS[layout] == before + 1, case
+        assert ran == ([block] if block else []), case
+        if (s, causal, h, dr) not in out:
+            out[s, causal, h, dr] = jax.value_and_grad(
                 lambda *a, causal=causal, w=w: (
                     _latent_plain(*a, causal=causal) * w).sum(),
                 argnums=(0, 1, 2, 3, 4))(*args)
@@ -179,18 +186,54 @@ def latent_grads():
 @pytest.mark.parametrize("which", ["forward", "dq_nope", "dk_nope", "dv",
                                    "dq_rope", "dk_rope"])
 def test_latent_kernels_against_mha_reference(latent_grads, blocks, which):
-    """Head sizes 192 (128 + 64, the 64 one key for all heads) and 128,
-    float32 in interpret mode: the sums' order only, values of order 1 to
-    5; 2e-5 absolute. Both backwards (``LATENT_CASES``)."""
-    s, _, causal = LATENT_CASES[blocks][:3]
+    """Head sizes 192 (128 + 64, the 64 one key for all heads; "rope128":
+    256) and 128, float32 in interpret mode: the sums' order only, values
+    of order 1 to 5; 2e-5 absolute. Every route a latent call can take
+    (``LATENT_CASES``)."""
+    s, _, causal, h, dr, _ = LATENT_CASES[blocks]
     (val, grads), (want_val, want) = latent_grads[blocks], \
-        latent_grads[s, causal]
+        latent_grads[s, causal, h, dr]
     if which == "forward":
         assert abs(float(val) - float(want_val)) < 2e-5 * abs(float(want_val)) + 2e-4
         return
     i = ["dq_nope", "dk_nope", "dv", "dq_rope", "dk_rope"].index(which)
     assert grads[i].shape == want[i].shape
     assert float(jnp.abs(grads[i] - want[i]).max()) < 2e-5
+
+
+def _latent_shapes(s):
+    """q, k, v, q_rope, k_rope of a call with two heads, as shapes."""
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    return (sd(1, s, 2, 128), sd(1, s, 2, 128), sd(1, s, 2, 128),
+            sd(1, s, 2, 64), sd(1, s, 64))
+
+
+@pytest.mark.parametrize("causal", [False, True],
+                         ids=["non_causal", "too_long"])
+def test_a_latent_call_the_one_backward_cannot_take(monkeypatch, causal):
+    """ISSUE 48: the route is decided once, before anything is traced. A
+    non-causal call (no diagonal step at which a q block's dq is
+    complete) takes ``mha_reference`` and says so; a causal call whose
+    whole-sequence accumulators do not fit VMEM is refused with S, the
+    bytes and the limit (the reference would make S x S scores)."""
+    import sys
+
+    fa = sys.modules[flash_attention.__module__]
+
+    def loss(q, k, v, qr, kr):
+        return flash_attention(q, k, v, causal=causal, q_rope=qr,
+                               k_rope=kr).astype(jnp.float32).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    if causal:
+        monkeypatch.setattr(fa, "_LATENT_VMEM_BYTES", 1 << 20)
+        with pytest.raises(ValueError, match=r"S=2048.* bytes .*1048576"):
+            grad.lower(*_latent_shapes(2048))
+        return
+    before = PATH_COUNTS["latent_reference"]
+    text = grad.lower(*_latent_shapes(2048)).as_text(debug_info=True)
+    assert PATH_COUNTS["latent_reference"] == before + 1
+    assert "flash_latent_" not in text
 
 
 def test_a_latent_call_takes_the_latent_kernels_and_says_so():
@@ -211,18 +254,19 @@ def test_a_latent_call_takes_the_latent_kernels_and_says_so():
 
 
 @pytest.mark.parametrize("blocks,causal,bands", [
-    (1024, True, 4), (256, True, 2), (128, True, 1), (1024, False, 1)])
+    (1024, True, 4), (256, True, 2), (128, True, 1), (1024, False, 0)])
 def test_a_latent_call_says_how_many_bands_a_diagonal_step_works(
         blocks, causal, bands):
     """ISSUE 46: ``bands`` of the event ``rtpu.ops.flash.path`` and
     ``BAND_COUNTS``: a quarter of a block of 1024, halves of one of 256,
-    the whole block of 128 and of a non-causal call."""
+    the whole block of 128. ISSUE 48: a non-causal call leaves the
+    reference route's event, which has no bands."""
     import sys
 
     from ray_tpu.perf.recorder import get_recorder
 
     fa = sys.modules[flash_attention.__module__]
-    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    layout = "latent" if causal else "latent_reference"
     rec = get_recorder()
     was, rec.enabled = rec.enabled, True
     before = fa.BAND_COUNTS[bands]
@@ -230,17 +274,15 @@ def test_a_latent_call_says_how_many_bands_a_diagonal_step_works(
         jax.eval_shape(
             lambda q, k, v, qr, kr: flash_attention(
                 q, k, v, causal=causal, block_q=blocks, block_k=blocks,
-                q_rope=qr, k_rope=kr),
-            sd(1, 2048, 2, 128), sd(1, 2048, 2, 128), sd(1, 2048, 2, 128),
-            sd(1, 2048, 2, 64), sd(1, 2048, 64))
+                q_rope=qr, k_rope=kr), *_latent_shapes(2048))
         events = rec.snapshot()
     finally:
         rec.enabled = was
     assert fa.BAND_COUNTS[bands] == before + 1
-    data = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
-            and e["label"] == "latent"][-1]["data"]
-    assert data["bands"] == bands and data["S"] == 2048
-    assert data["backward"] == ("fused" if causal else "split")
+    last = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
+            and e["label"].startswith("latent")][-1]
+    assert last["label"] == last["data"]["layout"] == layout
+    assert last["data"]["bands"] == bands and last["data"]["S"] == 2048
 
 
 def test_the_score_is_scaled_by_one_over_sqrt_192_by_hand():

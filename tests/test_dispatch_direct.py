@@ -321,7 +321,7 @@ def test_inflight_direct_calls_survive_forced_peer_channel_close(cluster):
 
 
 def test_thread_count_flat_across_1k_actor_calls(cluster):
-    """PERF_NOTES round-5 flake lead (driver at 219 threads): with the
+    """A round-5 flake lead (the driver at 219 threads): with the
     pooled reader hub + elastic lanes, driver thread count must not grow
     with call count."""
     c = Counter.remote()

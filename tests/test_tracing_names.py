@@ -98,49 +98,40 @@ def test_flash_kernel_names_are_pinned(kernel_texts, key, name, blocks):
 
 @pytest.fixture(scope="module")
 def latent_text():
-    """Forward and backward of a latent call as lowered here, by the
-    backward its shapes select (``_latent_backward``): a causal call
-    fuses, a non-causal one cannot. ISSUE 46: one block of 256, so the
-    causal call's kernels are the banded ones (two bands of 128)."""
+    """Forward and backward of a causal latent call as lowered here.
+    ISSUE 46: one block of 256, so its kernels are the banded ones (two
+    bands of 128)."""
     q = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
     qr, kr = jnp.zeros((1, 256, 2, 64), q.dtype), jnp.zeros((1, 256, 64),
                                                              q.dtype)
-    texts = {}
-    for backward, causal in (("fused", True), ("split", False)):
-        def loss(q, k, v, qr, kr, causal=causal):
-            return fa.flash_attention(q, k, v, causal=causal, block_q=256,
-                                      block_k=256, q_rope=qr,
-                                      k_rope=kr).astype(jnp.float32).sum()
 
-        before = fa.BACKWARD_COUNTS[backward], fa.BAND_COUNTS[2]
-        texts[backward] = jax.jit(jax.grad(
-            loss, argnums=(0, 1, 2, 3, 4))).lower(
-            q, q, q, qr, kr).as_text(debug_info=True)
-        assert fa.BACKWARD_COUNTS[backward] == before[0] + 1
-        assert fa.BAND_COUNTS[2] == before[1] + causal
-    return texts
+    def loss(q, k, v, qr, kr):
+        return fa.flash_attention(q, k, v, causal=True, block_q=256,
+                                  block_k=256, q_rope=qr,
+                                  k_rope=kr).astype(jnp.float32).sum()
+
+    before = fa.PATH_COUNTS["latent"], fa.BAND_COUNTS[2]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, qr, kr).as_text(debug_info=True)
+    assert (fa.PATH_COUNTS["latent"], fa.BAND_COUNTS[2]) == (
+        before[0] + 1, before[1] + 1)
+    return text
 
 
-@pytest.mark.parametrize("key,name,fused", [
-    ("fwd", "flash_latent_fwd", True),
-    ("bwd_dq", "flash_latent_bwd_dq", False),
-    ("bwd_dkv", "flash_latent_bwd_dkv", True)])
-def test_latent_flash_kernel_names_are_pinned(latent_text, key, name, fused):
+@pytest.mark.parametrize("key,name", [
+    ("fwd", "flash_latent_fwd"), ("bwd_dkv", "flash_latent_bwd_dkv")])
+def test_latent_flash_kernel_names_are_pinned(latent_text, key, name):
     """ISSUE 33: ``mla_attention_roofline`` finds its kernels by these.
-    ISSUE 34: the fused backward IS ``flash_latent_bwd_dkv`` (the reader's
-    pattern knows no fourth name); ``flash_latent_bwd_dq`` is in a call
-    that takes the two kernels and in no call that fuses."""
+    ISSUE 34, 48: the ONE backward is launched as ``flash_latent_bwd_dkv``
+    (a name the reader's pattern knows)."""
     assert fa.LATENT_KERNEL_NAMES[key] == name
-    assert len(set(fa.LATENT_KERNEL_NAMES.values())) == 3
+    assert len(set(fa.LATENT_KERNEL_NAMES.values())) == 2
     assert not set(fa.LATENT_KERNEL_NAMES.values()) & set(
         fa.KERNEL_NAMES.values())
-    pattern = r"[/\"(]" + name + r"[/\")]"
-    assert re.search(pattern, latent_text["split"])
-    assert bool(re.search(pattern, latent_text["fused"])) == fused
+    assert re.search(r"[/\"(]" + name + r"[/\")]", latent_text)
     # no kernel of the one-part score is in a latent call
     for other in fa.KERNEL_NAMES.values():
-        for text in latent_text.values():
-            assert not re.search(r"[/\"(]" + other + r"[/\")]", text)
+        assert not re.search(r"[/\"(]" + other + r"[/\")]", latent_text)
 
 
 @pytest.mark.parametrize("key,name", [("rows", "grouped_matmul"),
@@ -187,8 +178,7 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
     m = DeepseekV3(DeepseekV3Config.tiny(experts_held=2, expert_offset=4))
     p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    before = (el.LAYER_COUNTS[(2, 8)], fa.PATH_COUNTS["latent"],
-              fa.BACKWARD_COUNTS["fused"])
+    before = el.LAYER_COUNTS[(2, 8)], fa.PATH_COUNTS["latent"]
     rec = get_recorder()
     was, rec.enabled = rec.enabled, True
     try:
@@ -198,7 +188,6 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
         rec.enabled = was
     assert el.LAYER_COUNTS[(2, 8)] == before[0] + 1
     assert fa.PATH_COUNTS["latent"] > before[1]
-    assert fa.BACKWARD_COUNTS["fused"] > before[2]
     layer = [e for e in events if e["kind"] == "rtpu.ops.expert_layer"][-1]
     assert layer["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
@@ -208,9 +197,8 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
             and e["label"] == "latent"][-1]
     assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
     assert path["data"]["shared_key"] == 64 and path["data"]["S"] == 128
-    # ISSUE 34: which backward the call's shapes selected; ISSUE 46: the
-    # bands of a diagonal step (a block of 128 is one)
-    assert path["data"]["backward"] == "fused"
+    # ISSUE 46: the bands of a diagonal step (a block of 128 is one)
+    assert path["data"]["layout"] == "latent"
     assert path["data"]["bands"] == 1
 
 @pytest.mark.parametrize("key,name", [("fwd", "selscan_chunk_fwd"),
