@@ -17,10 +17,11 @@ from .moe import MoE, MoEConfig
 from .deepseek_v3 import DeepseekV3, DeepseekV3Config
 from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .sambay import SambaY, SambaYConfig
+from .kimi_linear import KimiLinear, KimiLinearConfig
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
     "ViT", "ViTConfig", "MLP", "MLPConfig", "MoE", "MoEConfig",
     "DeepseekV3", "DeepseekV3Config", "GraniteHybrid", "GraniteHybridConfig",
-    "SambaY", "SambaYConfig",
+    "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
 ]

@@ -160,6 +160,58 @@ class DeepseekV3Config:
         return DeepseekV3Config(**base)
 
 
+def latent_attention(x, lp, *, n_head: int, dtype, eps: float, rope=None,
+                     sm_scale=None):
+    """The latent-attention sublayer of its input x [B, S, d], norm first,
+    without the residual, for any model that holds the layer's parameters
+    under these names (``attn_norm``, ``w_kv_a``, ``kv_norm``, ``w_q_nope``,
+    ``w_q_rope``, ``w_k_rope``, ``w_k_b``, ``w_v_b``, ``w_o``; behind a
+    query bottleneck ``w_q_a`` and ``q_norm`` too). ``rope`` = (cos, sin)
+    turns every head's ``q_rope`` and the one ``k_rope``; None leaves them
+    as the projections made them (a layer without positions). ``sm_scale``
+    None is 1 / sqrt(dn + dr)."""
+    b, s, _ = x.shape
+    h, dt = n_head, dtype
+    turn = (lambda t: t) if rope is None else (
+        lambda t: _rope_interleaved(t, *rope))
+    with jax.named_scope("attn"):
+        xn = rmsnorm(x, lp["attn_norm"], eps)
+        latent = rmsnorm(xn @ lp["w_kv_a"].astype(dt), lp["kv_norm"], eps)
+        heads = lambda t: t.reshape(b, s, h, -1)  # noqa: E731
+        q_in = xn if "w_q_a" not in lp else rmsnorm(
+            xn @ lp["w_q_a"].astype(dt), lp["q_norm"], eps)
+        q_nope = q_in @ lp["w_q_nope"].astype(dt)
+        q_rope = turn(heads(q_in @ lp["w_q_rope"].astype(dt))
+                      ).reshape(b, s, -1)
+        k_rope = turn((xn @ lp["w_k_rope"].astype(dt))[:, :, None, :]
+                      )[:, :, 0, :]
+        k_nope = latent @ lp["w_k_b"].astype(dt)
+        v = latent @ lp["w_v_b"].astype(dt)
+        # named in the merged [B, S, H*d] form the kernels read (a
+        # 64-wide minor dimension would be kept padded to 128 lanes)
+        q_nope, q_rope = (checkpoint_name(t, "attn_q")
+                          for t in (q_nope, q_rope))
+        o = flash_attention(
+            heads(q_nope), heads(k_nope), heads(v), causal=True,
+            q_rope=heads(q_rope), k_rope=k_rope, sm_scale=sm_scale)
+        return o.reshape(b, s, -1) @ lp["w_o"].astype(dt)
+
+
+def held_expert_sublayer(z, lp, *, eps: float, experts_held: int,
+                         expert_offset: int, top_k: int,
+                         routed_scale: float):
+    """The expert sublayer of its input z [B, S, d], norm first (it goes
+    with the router), without the residual -> (shared experts + the held
+    experts' part of the routed sum, the rows the held experts worked)."""
+    b, s, d = z.shape
+    with jax.named_scope("router"):
+        xn = rmsnorm(z, lp["mlp_norm"], eps).reshape(b * s, d)
+    y, rows = held_expert_layer(
+        xn, lp, experts_held=experts_held, expert_offset=expert_offset,
+        top_k=top_k, routed_scale=routed_scale)
+    return y.reshape(b, s, d), rows
+
+
 class DeepseekV3:
     """init / loss pytree model in the house style (gpt.py, llama.py).
     Parameters are one flat dict: ``wte``, ``lm_head``, ``out_norm``,
@@ -267,38 +319,14 @@ class DeepseekV3:
             clamp=tuple(c.hc_res_clamp), rms_eps=c.rms_eps)
 
     def _attention(self, x, lp, cos, sin):
-        """The attention sublayer of its input x, norm first, without the
-        residual."""
+        """``latent_attention`` with this model's positions and scale."""
         c = self.config
-        b, s, _ = x.shape
-        h, dt = c.n_head, c.dtype
-        with jax.named_scope("attn"):
-            xn = rmsnorm(x, lp["attn_norm"], c.rms_eps)
-            latent = rmsnorm(xn @ lp["w_kv_a"].astype(dt), lp["kv_norm"],
-                             c.rms_eps)
-            heads = lambda t: t.reshape(b, s, h, -1)  # noqa: E731
-            q_in = xn if not c.q_lora_rank else rmsnorm(
-                xn @ lp["w_q_a"].astype(dt), lp["q_norm"], c.rms_eps)
-            q_nope = q_in @ lp["w_q_nope"].astype(dt)
-            q_rope = _rope_interleaved(
-                heads(q_in @ lp["w_q_rope"].astype(dt)),
-                cos, sin).reshape(b, s, -1)
-            k_rope = _rope_interleaved(
-                (xn @ lp["w_k_rope"].astype(dt))[:, :, None, :], cos,
-                sin)[:, :, 0, :]
-            k_nope = latent @ lp["w_k_b"].astype(dt)
-            v = latent @ lp["w_v_b"].astype(dt)
-            # named in the merged [B, S, H*d] form the kernels read (a
-            # 64-wide minor dimension would be kept padded to 128 lanes)
-            q_nope, q_rope = (checkpoint_name(t, "attn_q")
-                              for t in (q_nope, q_rope))
-            o = flash_attention(
-                heads(q_nope), heads(k_nope), heads(v), causal=True,
-                q_rope=heads(q_rope), k_rope=k_rope,
-                sm_scale=None if c.rope_factor == 1.0 else yarn_softmax_scale(
-                    c.qk_nope_head_dim + c.qk_rope_head_dim, c.rope_factor,
-                    c.rope_mscale_all_dim))
-            return o.reshape(b, s, -1) @ lp["w_o"].astype(dt), None
+        return latent_attention(
+            x, lp, n_head=c.n_head, dtype=c.dtype, eps=c.rms_eps,
+            rope=(cos, sin),
+            sm_scale=None if c.rope_factor == 1.0 else yarn_softmax_scale(
+                c.qk_nope_head_dim + c.qk_rope_head_dim, c.rope_factor,
+                c.rope_mscale_all_dim)), None
 
     def _attention_sublayer(self, x, lp, cos, sin):
         return self._residual(
@@ -323,14 +351,10 @@ class DeepseekV3:
         c = self.config
 
         def experts(z):
-            b, s, d = z.shape
-            with jax.named_scope("router"):  # the norm goes with the router
-                xn = rmsnorm(z, lp["mlp_norm"], c.rms_eps).reshape(b * s, d)
-            y, rows = held_expert_layer(
-                xn, lp, experts_held=c.experts_held,
+            return held_expert_sublayer(
+                z, lp, eps=c.rms_eps, experts_held=c.experts_held,
                 expert_offset=c.expert_offset, top_k=c.top_k,
                 routed_scale=c.routed_scaling_factor)
-            return y.reshape(b, s, d), rows
 
         x = self._attention_sublayer(x, lp, cos, sin)
         return self._residual(x, lp, "hc_mlp", None, experts)

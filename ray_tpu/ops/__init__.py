@@ -10,6 +10,9 @@ legend); for a TPU-native framework the hot ops are first-party:
   K/V blocks rotate the ring via ppermute while compute overlaps.
 - ssd_scan: Mamba-2's state-space recurrence as a chunked scan, forward
   and backward kernels with the state carried in VMEM (Pallas).
+- kda_scan: Kimi Delta Attention's recurrence (a gated delta rule with a
+  decay for every key channel; a matrix state a head), chunked in the WY
+  form with every exponent <= 0, plain jnp under one ``lax.scan``.
 - selective_scan: Mamba-1's recurrence (a decay for every channel AND
   state), walked in chunks on the VPU with the state in VMEM, forward and
   backward kernels (Pallas).
@@ -19,8 +22,8 @@ legend); for a TPU-native framework the hot ops are first-party:
   plain jnp form for shapes its tile cannot take; every coefficient
   tokens-minor.
 - layers: rmsnorm/layernorm/gelu/rope (plain and YaRN)/cross-entropy,
-  the causal depthwise convolution and the gated norm in plain jnp, shaped
-  so XLA fuses them into the adjacent matmuls.
+  the causal depthwise convolution, the gated norms and a head's l2 norm
+  in plain jnp, shaped so XLA fuses them into the adjacent matmuls.
 - paged_attention: reads and writes of the serving engine's block-pool
   KV cache.
 
@@ -31,8 +34,10 @@ from .attention import mha_reference
 from .flash_attention import flash_attention
 from .ring_attention import ring_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
-                     rope_cache, apply_rope, causal_conv1d, gated_rmsnorm)
+                     rope_cache, apply_rope, causal_conv1d, gated_rmsnorm,
+                     l2norm, sigmoid_gated_rmsnorm)
 from .ssd_scan import ssd_scan
+from .kda_scan import kda_scan
 from .selective_scan import selective_scan
 from .hyper_connection import hc_coefficients, hc_mix, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
@@ -42,8 +47,9 @@ from .paged_attention import (paged_attention_decode,
 __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
-    "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "ssd_scan",
-    "selective_scan", "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
+    "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "l2norm",
+    "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan", "selective_scan",
+    "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",
     "paged_write_step",

@@ -150,3 +150,23 @@ def gated_rmsnorm(y: jax.Array, gate: jax.Array, weight: jax.Array,
     output norm, one group), statistics in f32."""
     gated = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
     return rmsnorm(gated.astype(y.dtype), weight, eps)
+
+
+def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """x / sqrt(sum(x^2) + eps) over the last axis (one head's q or k of a
+    delta-rule layer: a key of unit length is what makes ``beta`` the
+    share of the held value that a write replaces), statistics in f32."""
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt(
+        jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps)).astype(x.dtype)
+
+
+def sigmoid_gated_rmsnorm(y: jax.Array, gate: jax.Array, weight: jax.Array,
+                          eps: float = 1e-6) -> jax.Array:
+    """rmsnorm(y) * weight * sigmoid(gate) over the last axis: the norm
+    first, THEN the gate (``gated_rmsnorm`` gates by SiLU before the
+    norm), statistics in f32."""
+    yf = y.astype(jnp.float32)
+    var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
+    normed = yf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    return (normed * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(y.dtype)

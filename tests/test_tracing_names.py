@@ -12,14 +12,16 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
-                            GraniteHybrid, GraniteHybridConfig, Llama,
-                            LlamaConfig, SambaY, SambaYConfig)
+                            GraniteHybrid, GraniteHybridConfig, KimiLinear,
+                            KimiLinearConfig, Llama, LlamaConfig, SambaY,
+                            SambaYConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
 el = importlib.import_module("ray_tpu.ops.expert_layer")
 ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
 sel = importlib.import_module("ray_tpu.ops.selective_scan")
+kda = importlib.import_module("ray_tpu.ops.kda_scan")
 hc = importlib.import_module("ray_tpu.ops.hyper_connection")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
@@ -221,6 +223,42 @@ def test_selective_scan_kernel_names_are_pinned(key, name):
     assert not re.search(pattern, lowered(96))
 
 
+def test_the_delta_rule_scan_and_its_stack_leave_their_events():
+    """ISSUE 49: ``rtpu.ops.kda.path`` at trace time, once a KDA layer's
+    body (route, chunk, tokens, heads, padded tokens), and
+    ``rtpu.models.stack.runs`` with the runs the model walked and what each
+    keeps. No kernel name is pinned: the scan is plain ``jnp`` today, and
+    ``kda_scan_roofline`` finds its time by the scope ``scan``."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    assert not hasattr(kda, "KERNEL_NAMES")
+    m = KimiLinear(KimiLinearConfig.tiny(experts_held=2))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 150), jnp.int32)
+    before = kda.PATH_COUNTS["chunked_jnp"]
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        jax.jit(m.loss).lower(p, toks, toks)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    assert kda.PATH_COUNTS["chunked_jnp"] >= before + 3   # three KDA runs
+    path = [e for e in events if e["kind"] == "rtpu.ops.kda.path"][-1]
+    assert path["label"] == "chunked_jnp"
+    assert path["data"] == {
+        "route": "chunked_jnp", "chunk": 64, "tokens": 150,
+        "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
+        "chunks": 3}
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "kimi_linear"][-1]
+    assert runs["data"]["runs"] == [["kda_dense", 1], ["kda_moe", 2],
+                                    ["mla_moe", 1], ["kda_moe", 1]]
+    assert runs["data"]["kept"] == [
+        [], [], ["flash_out", "flash_lse", "attn_q"], []]
+    assert runs["data"]["side_state_bytes"] == 0
+
+
 HC_KERNELS = [("pre_fwd", "mhc_pre_fwd"), ("post_fwd", "mhc_post_fwd"),
               ("post_bwd", "mhc_post_bwd"), ("pre_bwd", "mhc_pre_bwd")]
 
@@ -336,6 +374,7 @@ MODELS = {
         rope_original_max=32, rope_mscale_all_dim=1.0)),
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
+    "kimi_linear": lambda: KimiLinear(KimiLinearConfig.tiny(experts_held=4)),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -358,11 +397,12 @@ def lowered_losses():
     (s, m) for m in sorted(MODELS)
     for s in ("embed", "attn", "mlp", "lm_head", "loss")
     + (("router", "experts", "shared_expert")
-       if m.startswith("deepseek_v3") else ())
+       if m.startswith("deepseek_v3") or m == "kimi_linear" else ())
     # ISSUE 45: everything ops/hyper_connection.py does, under one name
     + (("mhc",) if m == "deepseek_v3_hc" else ())
-    + (("mixer", "conv", "scan") if m in ("granite_hybrid", "sambay")
-       else ())
+    # ISSUE 49: a KDA layer's three, the names the other scans' readers read
+    + (("mixer", "conv", "scan")
+       if m in ("granite_hybrid", "sambay", "kimi_linear") else ())
     + (("gmu", "cross_attn") if m == "sambay" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
