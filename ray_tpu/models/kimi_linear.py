@@ -60,8 +60,13 @@ from .stack import period_runs, run_params, walk_stack
 # ``checkpoint_name``: in a latent-attention layer the flash kernels' output
 # and row statistics and q as the kernels read it (``deepseek_v3``'s set:
 # the backward never runs the forward kernel again). A KDA layer keeps its
-# input alone: its projections, convolutions, gates and the scan's chunk
-# matrices are made again (PERF.md, PR 49).
+# input alone: its projections, convolutions and gates are made again, and
+# ``kda_chunk_fwd`` runs a second time in the layer's backward. The kernel
+# names what would spare that ("kda_out", "kda_states": 0.67 GB a layer),
+# and the described-chip compile of the cell's step decided against it
+# (PERF.md, PR 50): with nothing kept the compiler makes 2 instructions
+# again on its own, with one layer's kept 20, with all four 45 (accepted,
+# temporaries 8.76 -> 10.17 GB).
 _REMAT_SAVE = {"mla": ("flash_out", "flash_lse", "attn_q"), "kda": ()}
 
 # config.json's 1-based lists

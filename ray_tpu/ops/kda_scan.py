@@ -1,5 +1,6 @@
 """Kimi Delta Attention's recurrence (KDA: a gated delta rule with a decay
-for every key channel) as a chunked scan, in plain ``jax.numpy``.
+for every key channel) as a chunked scan: a Pallas kernel pair, and the
+plain ``jax.numpy`` form that defines it.
 
 Per head (state S [d_k, d_v] float32 from zero; q_t, k_t [d_k], v_t [d_v],
 g_t [d_k] <= 0 the log of the token's decay a key channel, beta_t in (0, 1)
@@ -51,21 +52,65 @@ thirteen orders of magnitude, blocks of 32 by a tenth, of 16 by 5e-5, of 8
 by 1e-6 (``tests/test_kda_scan.py``). The solve's backward is its own
 (``dA = -T^T dT T^T``), not autodiff's through the products.
 
-How the work is cut: ONE ``lax.scan`` over the chunks carries the state; a
-turn makes its chunk's matrices (A, Q, T, W, U) for every batch row and
-head at once, reads and updates the state. The turn's body is
-rematerialised: autodiff keeps the state each chunk starts from and the
-inputs, and makes a chunk's matrices again in the backward, so no array of
-[tokens, 16, d_k] or [tokens, C] a head outlives its chunk. The backward is
-jax's, through the scan. (Measured on a v5e at the benchmark cell's shape,
-PERF.md PR 49: with the local matrices of 1 / 2 / 4 / 8 / 32 chunks made at
-once a layer costs a step 113 / 138 / 166 / 193 / 276 ms, one forward and one
-forward + backward: one chunk's arrays are small enough for the compiler to
-keep in VMEM.)
+Two routes, chosen by what a call shows (``PATH_COUNTS``, the event
+``rtpu.ops.kda.path``; no argument or configuration selects one). T is
+padded to whole chunks with zeros on both (g = 0 does not decay, beta = 0
+and k = 0 write nothing).
 
-One route today (``PATH_COUNTS``, the event ``rtpu.ops.kda.path``):
-``chunked_jnp``. T is padded to whole chunks with zeros (g = 0 does not
-decay, beta = 0 and k = 0 write nothing).
+* ``kernel``: heads of 128 key and 128 value channels (a head is one
+  128-lane tile of the model's merged [B, T, H x 128] arrays: nothing is
+  transposed or copied on the way in or out) and a chunk of 64. A Pallas
+  pair under one ``custom_vjp`` (``KERNEL_NAMES``), grid (batch, head
+  block, chunk), the chunks in order on the last, sequential axis; a
+  program is one chunk of a block of ``_MAX_HEADS_PER_BLOCK`` heads.
+  - ``kda_chunk_fwd`` carries each head's state, TRANSPOSED [d_v, d_k], in
+    a float32 VMEM scratch from chunk to chunk (what decays it is one number
+    a key channel: with the key channels along the lanes that is a row
+    spread down the sublanes, never a [d_k, 1] column) and writes o and the
+    state each chunk STARTS from ([B, T/C, H x 128, 128] float32), the
+    backward's one residual beside the inputs.
+  - ``kda_chunk_bwd`` walks the chunks from the last, carries dS in the
+    same kind of scratch, makes the chunk's matrices AGAIN from q, k, v, g,
+    beta and the saved start state (one body, ``_chunk_forward``, serves
+    both kernels), and writes dq, dk, dv (q's dtype), dg and dbeta
+    (float32). For a decayed score X_ij = sum_c x_ic k_jc exp(G_ic - G_jc):
+    dx_ic = sum_j dX_ij k_jc exp(.), dk_jc = sum_i dX_ij x_ic exp(.), and
+    the gates' share is elementwise, dG_ic += x_ic dx_ic, dG_jc -= k_jc
+    dk_jc (``_scores_bwd``; the reference of a block row drops out); dA =
+    -T^T dT T^T; dV' = scale Q^T dO + k_end dS_end; dS_0 = scale (q e^G)^T
+    dO + diag(e^G_C) dS_end - W^T dV'; dg is the triangle's product with dG
+    reversed. All held to ``jax.vjp`` of the plain route in the tests.
+  How a program lays out its work:
+  - once a program: the cumulative gates of the whole block (the triangle
+    of ones times g, g in three bfloat16 pieces: three exact passes);
+  - once a PAIR of heads (``_forward_of`` / ``_backward_of``: pure
+    functions of the pair's tiles under ``jax.jit``, so that the nine
+    kernel instances of a train step and the pairs of a program all get
+    the body traced ONCE; ``setup_s`` pays for every equation traced): the
+    solve, the two heads' A side by side [C, 2 C] against block-diagonal
+    right-hand sides (``_solve``: the MXU's time is the rows that stream
+    through it, so two heads cost one); its ten products at
+    ``Precision.HIGHEST``, which Mosaic honours (on the chip o is the plain
+    route's to a rounding of bf16, PERF.md PR 50);
+  - once a head: beta as a [C, 128] column by a product with ones (beta
+    comes in head-major, dense [heads, C] rows: a [C, 1] column costs a
+    register a sublane whatever is done to it, ISSUE 40), the block rows
+    against earlier columns (seven small products), W and U in one product
+    (T against [beta k e^G | beta v]), W S_0 and (q e^G) S_0 in one (both
+    against the state), Q V', the state's update;
+  - once a column of the diagonal sub-blocks, for all eight sub-blocks at
+    once ([8 blocks, 8 rows, 128] values: a block's rows are the sublanes
+    of its own tile): the differences, their exp, two sums over the lanes.
+  The bodies are ``jax.lax`` primitives (ROADMAP A11).
+* ``chunked_jnp``: every other shape, and the definition the kernels are
+  tested against: ONE ``lax.scan`` over the chunks carries the state; a
+  turn makes its chunk's matrices (A, Q, T, W, U) for every batch row and
+  head at once, reads and updates the state. The turn's body is
+  rematerialised: autodiff keeps the state each chunk starts from and the
+  inputs, and makes a chunk's matrices again in the backward. The backward
+  is jax's, through the scan. (Measured on a v5e at the benchmark cell's
+  shape, PERF.md PR 49 and 50: a layer 20.7 ms forward and 65.8 forward +
+  backward, where the kernels take 11.3 and 30.7.)
 
 Precision: matrix products take their operands in q's dtype (bf16 in a
 model) and accumulate in float32; the gates, their cumulative sums, every
@@ -75,11 +120,28 @@ from __future__ import annotations
 
 import collections
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
+from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot
 from .scan_common import pad_tokens, record_path
+from .ssd_scan import _spread, _sum
+
+# The module, not the function of its name that the package exports: the
+# kernels here run interpreted where the flash kernels do, by the one
+# switch (``_use_interpret``) a described-chip compile steers.
+_flash = importlib.import_module(__package__ + ".flash_attention")
+
+# The names of the two kernels, as a device trace and the compiled HLO
+# show them (``name=`` on ``pl.pallas_call``); pinned in
+# tests/test_tracing_names.py.
+KERNEL_NAMES = {
+    "fwd": "kda_chunk_fwd",     # o and the state each chunk starts from
+    "bwd": "kda_chunk_bwd",     # dq, dk, dv, dg, dbeta
+}
 
 # Traced calls of kda_scan by route; the same choice is the flight-recorder
 # event ``rtpu.ops.kda.path``.
@@ -220,6 +282,618 @@ def _chunked(q, k, v, g, beta, heads: int, chunk: int, scale: float):
     return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, t, heads * dv)
 
 
+# ---------------------------------------------------------------------------
+# the kernel route: what both kernels share
+# ---------------------------------------------------------------------------
+#
+# A program is one chunk of one block of heads; a head is one 128-lane tile
+# of the merged arrays. The state is held TRANSPOSED, St [d_v, d_k] (and so
+# is the residual the forward writes): what decays it, exp(G_C), is one
+# number a key channel, and with the key channels along the lanes that is a
+# row spread down the sublanes, never a [d_k, 1] column.
+
+_MAX_HEADS_PER_BLOCK = 4
+_VMEM_BYTES = 64 * 1024 * 1024
+
+_mul, _add, _minus = jax.lax.mul, jax.lax.add, jax.lax.sub
+
+
+def _exact_dot(a, b, contract):
+    """A float32 product at full float32 precision."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=_F32)
+
+
+def _heads_per_block(heads: int) -> int:
+    """Heads a program works: as many as divide the heads, an even number
+    (the solve takes them in pairs) unless the heads are odd."""
+    return max(n for n in range(1, _MAX_HEADS_PER_BLOCK + 1)
+               if heads % n == 0 and (n % 2 == 0 or heads % 2))
+
+
+def _iota(shape, axis: int):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _rows(v, lo: int, n: int):
+    return jax.lax.slice(v, (lo, 0), (lo + n, v.shape[1]))
+
+
+def _cols(v, lo: int, n: int):
+    return jax.lax.slice(v, (0, lo), (v.shape[0], lo + n))
+
+
+def _zeros_like(v):
+    return jax.lax.full_like(v, 0)
+
+
+def _full(shape, value, dtype=_F32):
+    return jax.lax.full(shape, value, dtype)
+
+
+_eq, _ge, _gt, _lt = jax.lax.eq, jax.lax.ge, jax.lax.gt, jax.lax.lt
+
+
+def _is(v, n: int):
+    """Where the integer array v is n."""
+    return _eq(v, jax.lax.full_like(v, n))
+
+
+def _below(v, n: int):
+    """Where the integer array v is under n."""
+    return _lt(v, jax.lax.full_like(v, n))
+
+
+def _decay(d):
+    """exp of a difference of cumulative gates that is <= 0 wherever it is
+    used; where it is not (and is masked later) it is held to 1."""
+    return jax.lax.exp(jax.lax.min(d, _zeros_like(d)))
+
+
+def _block_pieces(qf, kf, cum, lo: int, r: int):
+    return _rows(qf, lo, r), _rows(kf, lo, r), _rows(cum, lo, r)
+
+
+def _against_earlier(qb, kb, gb, kf, cum, dt):
+    """A block row against the columns before it, both sides scaled against
+    the block row's first token -> (rows [2r, d] of q then k, their scale,
+    cols [C, d], their scale); columns at or after the block row hold
+    garbage <= |k| and are masked by the caller."""
+    r, d = gb.shape
+    first = _rows(gb, 0, 1)
+    up = jax.lax.exp(_minus(gb, _spread(first, (r, d))))
+    up = jax.lax.concatenate([up, up], 0)
+    down = _decay(_minus(_spread(first, cum.shape), cum))
+    rows = _mul(jax.lax.concatenate([qb, kb], 0), up).astype(dt)
+    return rows, up, _mul(kf, down).astype(dt), down
+
+
+def _blocks(x, r: int):
+    """[C, w] -> [C / r, r, w]: the sub-blocks apart, a block's rows the
+    sublanes of its own tiles (no data moves at r = 8)."""
+    c, w = x.shape
+    return jax.lax.reshape(x, (c // r, r, w))
+
+
+def _own_column(shape, j: int):
+    """Where the lane of [blocks, r, C] is column j of the block's own
+    diagonal sub-block."""
+    r = shape[1]
+    return _is(_minus(_iota(shape, 2), _mul(
+        _iota(shape, 0), _full(shape, r, jnp.int32))), j)
+
+
+def _row_of_blocks(x3, j: int):
+    """Row j of every block of [blocks, r, w], down the block's rows."""
+    n, _, w = x3.shape
+    return jax.lax.broadcast_in_dim(
+        jax.lax.slice(x3, (0, j, 0), (n, j + 1, w)), x3.shape, (0, 1, 2))
+
+
+def _diagonal(qf, kf, cum, r: int):
+    """The diagonal sub-blocks of every block row at once: the [r, r, d]
+    differences themselves, column by column on the VPU -> (Q, K) [C, C],
+    zero outside the diagonal sub-blocks (and unmasked inside them)."""
+    c, d = qf.shape
+    q3, k3, g3 = (_blocks(x, r) for x in (qf, kf, cum))
+    shape = (c // r, r, c)
+    sq = sk = _full(shape, 0)
+    along = lambda x: jax.lax.broadcast_in_dim(              # noqa: E731
+        _sum(x, 2), shape, (0, 1, 2))
+    for j in range(r):
+        kj = _mul(_row_of_blocks(k3, j),
+                  _decay(_minus(g3, _row_of_blocks(g3, j))))
+        here = _own_column(shape, j)
+        sq = jax.lax.select(here, along(_mul(q3, kj)), sq)
+        sk = jax.lax.select(here, along(_mul(k3, kj)), sk)
+    return jax.lax.reshape(sq, (c, c)), jax.lax.reshape(sk, (c, c))
+
+
+def _diagonal_bwd(qf, kf, cum, dsq, dsk, r: int):
+    """``_diagonal``'s gradients: dsq, dsk [C, C] the scores' gradients
+    (only the diagonal sub-blocks are read) -> (dq, dk of the rows, dk of
+    the tokens as columns) [C, d]."""
+    c, d = qf.shape
+    q3, k3, g3 = (_blocks(x, r) for x in (qf, kf, cum))
+    dq3, dk3 = _blocks(dsq, r), _blocks(dsk, r)
+    zero = _zeros_like(dq3)
+    own = lambda x, here: jax.lax.broadcast_in_dim(          # noqa: E731
+        _sum(jax.lax.select(here, x, zero), 2), q3.shape, (0, 1, 2))
+    sub = _iota(q3.shape, 1)
+    dq = dk = dc = _full(q3.shape, 0)
+    for j in range(r):
+        e = _decay(_minus(g3, _row_of_blocks(g3, j)))
+        kj = _mul(_row_of_blocks(k3, j), e)
+        here = _own_column(dq3.shape, j)
+        cq, ck = own(dq3, here), own(dk3, here)
+        dq = _add(dq, _mul(cq, kj))
+        dk = _add(dk, _mul(ck, kj))
+        got = _sum(_mul(_add(_mul(cq, q3), _mul(ck, k3)), e), 1)
+        dc = jax.lax.select(_is(sub, j), jax.lax.broadcast_in_dim(
+            got, q3.shape, (0, 1, 2)), dc)
+    return tuple(jax.lax.reshape(x, (c, d)) for x in (dq, dk, dc))
+
+
+def _scores(qf, kf, cum, dt, r: int):
+    """q, k [C, d] float32, cum [C, d] -> (Q, K) [C, C] float32 as
+    ``_decayed_scores`` makes them: Q_ij for i >= j, K_ij for i > j."""
+    c, d = qf.shape
+    lane = _iota((2 * r, c), 1)
+    bands = [_full((2 * r, c), 0)]
+    for b in range(1, c // r):
+        rows, _, cols, _ = _against_earlier(
+            *_block_pieces(qf, kf, cum, b * r, r), kf, cum, dt)
+        off = _dot(rows, cols, _ABT)                           # [2r, C]
+        bands.append(jax.lax.select(_below(lane, b * r), off,
+                                    _zeros_like(off)))
+    sq, sk = _diagonal(qf, kf, cum, r)
+    sq = _add(sq, jax.lax.concatenate([_rows(x, 0, r) for x in bands], 0))
+    sk = _add(sk, jax.lax.concatenate([_rows(x, r, r) for x in bands], 0))
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    return (jax.lax.select(_ge(row, col), sq, _zeros_like(sq)),
+            jax.lax.select(_gt(row, col), sk, _zeros_like(sk)))
+
+
+def _scores_bwd(qf, kf, cum, dsq, dsk, dt, r: int):
+    """The gradients of ``_scores``: dsq, dsk [C, C] (zero where the score
+    is masked) -> (dq, dk as a row's key, dk as a column's key) [C, d].
+    The gates' share is elementwise from these (the caller's): dG_i +=
+    x_i dx_i for the rows, dG_j -= k_j dk_j for the columns."""
+    c, d = qf.shape
+    lane = _iota((2 * r, c), 1)
+    dq, dk, dcol = _diagonal_bwd(qf, kf, cum, dsq, dsk, r)
+    bands_q, bands_k = [_full((r, d), 0)], [_full((r, d), 0)]
+    for b in range(1, c // r):
+        lo = b * r
+        rows, up, cols, down = _against_earlier(
+            *_block_pieces(qf, kf, cum, lo, r), kf, cum, dt)
+        both = jax.lax.concatenate([_rows(dsq, lo, r), _rows(dsk, lo, r)], 0)
+        both = jax.lax.select(_below(lane, lo), both, _zeros_like(both)
+                              ).astype(dt)
+        drows = _mul(_dot(both, cols, _AB), up)                # [2r, d]
+        bands_q.append(_rows(drows, 0, r))
+        bands_k.append(_rows(drows, r, r))
+        dcol = _add(dcol, _mul(_dot(both, rows, _ATB), down))
+    return (_add(dq, jax.lax.concatenate(bands_q, 0)),
+            _add(dk, jax.lax.concatenate(bands_k, 0)), dcol)
+
+
+def _same_block(shape, size: int):
+    """Where row and column of side-by-side [C, C] matrices [C, n C] lie in
+    one block of ``size`` (a power of two) of their own matrix."""
+    shift = _full(shape, size.bit_length() - 1, jnp.int32)
+    col = jax.lax.bitwise_and(
+        _iota(shape, 1), _full(shape, shape[0] - 1, jnp.int32))
+    return _eq(jax.lax.shift_right_logical(_iota(shape, 0), shift),
+               jax.lax.shift_right_logical(col, shift))
+
+
+def _apart(y):
+    """Side-by-side matrices [C, n C] -> the block-diagonal [n C, n C] that
+    multiplies side-by-side matrices from the right each by its own."""
+    c, w = y.shape
+    if w == c:
+        return y
+    first, zero = _below(_iota(y.shape, 1), c), _zeros_like(y)
+    return jax.lax.concatenate([jax.lax.select(first, y, zero),
+                                jax.lax.select(first, zero, y)], 0)
+
+
+def _solve(a, r: int):
+    """``_inverse_by_blocks`` of one [C, C] matrix or of two side by side,
+    [C, 2 C] (two heads a product: the MXU's time is the rows that stream
+    through it, and a [C, 2 C] x [2 C, 2 C] product streams as many as a
+    [C, C] x [C, C] one): the r x r diagonal blocks by the Neumann product
+    (the products of block-diagonal matrices are the blocks' products), then
+    the blocks merged pair by pair, T <- T - T L T with L the part of a that
+    joins the pair."""
+    c = a.shape[0]
+    eye = _same_block(a.shape, 1).astype(_F32)
+    zero = _zeros_like(a)
+    p = jax.lax.select(_same_block(a.shape, r), a, zero)
+    t, m = _minus(eye, p), 2
+    while m < r:
+        p = _exact_dot(p, _apart(p), _AB)
+        t = _exact_dot(t, _apart(_add(eye, p)), _AB)
+        m *= 2
+    while r < c:
+        join = jax.lax.select(_same_block(a.shape, r), zero, jax.lax.select(
+            _same_block(a.shape, 2 * r), a, zero))
+        joined = _exact_dot(t, _apart(join), _AB)
+        t = _minus(t, _exact_dot(joined, _apart(t), _AB))
+        r *= 2
+    return t
+
+
+def _solve_heads(a, r: int):
+    """[T of each A in ``a``], the heads two to a product."""
+    c, out = a[0].shape[0], []
+    for i in range(0, len(a) - 1, 2):
+        t = _solve(jax.lax.concatenate(a[i:i + 2], 1), r)
+        out += [_cols(t, 0, c), _cols(t, c, c)]
+    return out + [_solve(x, r) for x in a[len(out):]]
+
+
+def _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt):
+    """One head's chunk from its scores and solve up to what it writes. q,
+    k, v [C, d] float32, cum [C, d], bcol [C, d] (beta_i in every lane), st
+    [d_v, d_k] float32 the state the chunk starts from, transposed, sq and
+    t [C, C] -> a dict of what the forward and the backward both use."""
+    c, d = qf.shape
+    kept = jax.lax.exp(cum)
+    bkv = jax.lax.concatenate([_mul(_mul(kf, bcol), kept), _mul(vf, bcol)],
+                              1).astype(dt)                   # [C, 2d]
+    wu = _dot(t.astype(dt), bkv, _AB)
+    w, u = _cols(wu, 0, d).astype(dt), _cols(wu, d, d)
+    q_in = _mul(qf, kept).astype(dt)
+    held = st.astype(dt)
+    from_state = _dot(jax.lax.concatenate([w, q_in], 0), held, _ABT)
+    wrote = _minus(u, _rows(from_state, 0, c)).astype(dt)
+    last = _spread(_rows(cum, c - 1, 1), (c, d))
+    to_end = jax.lax.exp(_minus(last, cum))
+    return dict(kept=kept, bkv=bkv, w=w, q_in=q_in,
+                held=held, wrote=wrote, to_end=to_end,
+                k_end=_mul(kf, to_end).astype(dt),
+                q_state=_rows(from_state, c, c),
+                at_end=jax.lax.exp(_rows(cum, c - 1, 1)))
+
+
+def _three(x):
+    """Float32 -> three bfloat16 pieces whose sum is x to 2^-24 of it: a
+    product of x with ones and zeros is then three passes of the MXU, each
+    exact, where ``_exact_dot`` splits both sides and makes six."""
+    pieces = []
+    for _ in range(3):
+        pieces.append(x.astype(jnp.bfloat16))
+        x = _minus(x, pieces[-1].astype(_F32))
+    return pieces
+
+
+def _triangle_sums(x, contract):
+    """The triangle of ones times x [C, w] (``_AB``: the inclusive
+    cumulative sum over the chunk's tokens) or its transpose times x
+    (``_ATB``: the sums from each token to the chunk's end), float32."""
+    c = x.shape[0]
+    tri = _ge(_iota((c, c), 0), _iota((c, c), 1)).astype(jnp.bfloat16)
+    a, b, e = (_dot(tri, piece, contract) for piece in _three(x))
+    return _add(_add(a, b), e)
+
+
+def _beta_column(row):
+    """A head's dense row of beta [1, C] -> [C, 128] with beta_i down the
+    rows in every lane: the row on the diagonals of three [C, C] pieces
+    times ones, on the MXU (a [C, 1] column costs a register a sublane
+    whatever is done to it)."""
+    c = row.shape[1]
+    row = _spread(row, (c, c))
+    on = _eq(_iota((c, c), 0), _iota((c, c), 1))
+    pieces = _three(jax.lax.select(on, row, _zeros_like(row)))
+    return _dot(jax.lax.concatenate(pieces, 1),
+                _full((3 * c, _LANES), 1, jnp.bfloat16), _AB)
+
+
+def _heads_scores(heads, dt, r: int):
+    """What the solve needs of some heads, each (q, k, v [C, 128] in the
+    model's dtype, its cumulative gates, its row of beta, ...) -> per head
+    (q, k, v float32, the gates, beta's column, Q, K), and the heads' T."""
+    out = []
+    for q, k, v, cum, beta, *_ in heads:
+        qf, kf = q.astype(_F32), k.astype(_F32)
+        out.append((qf, kf, v.astype(_F32), cum, _beta_column(beta))
+                   + _scores(qf, kf, cum, dt, r))
+    c = cum.shape[0]
+    return out, _solve_heads(
+        [_mul(_cols(bcol, 0, c), sk) for *_, bcol, _, sk in out], r)
+
+
+def _tiles(refs, h: int):
+    """Head h's [C, 128] tile of each merged ref."""
+    return tuple(x[:, pl.ds(h * _LANES, _LANES)] for x in refs)
+
+
+# Two heads' share of a program as a PURE function of values under
+# ``jax.jit``: a train step holds nine instances of these kernels (three
+# runs of the walker x forward sweep, rematerialised forward, backward) and
+# each program two or more pairs of heads; jit's cache hands every one of
+# them the body traced ONCE, and ``setup_s`` pays for every equation traced.
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "r"))
+def _forward_of(heads, *, scale: float, r: int):
+    """``heads``: one or two of (q, k, v, cumulative gates, beta's row, the
+    start state [d_v, d_k]) -> per head (o scaled in q's dtype, the state
+    the chunk ends in)."""
+    dt, d = heads[0][0].dtype, _LANES
+    scored, solved = _heads_scores(heads, dt, r)
+    out = []
+    for (*_, st), (qf, kf, vf, cum, bcol, sq, _), t in zip(
+            heads, scored, solved):
+        f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
+        o = _add(f["q_state"], _dot(sq.astype(dt), f["wrote"], _AB))
+        out.append((_mul(o, jax.lax.full_like(o, scale)).astype(dt),
+                    _add(_mul(_spread(f["at_end"], (d, d)), st),
+                         _dot(f["wrote"], f["k_end"], _ATB))))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+
+def _pairs(hpb: int):
+    """The block's heads as the solve takes them: in pairs, or one by one
+    where their number is odd."""
+    per = 2 - hpb % 2
+    return [range(i, i + per) for i in range(0, hpb, per)]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_scr,
+                *, scale: float, r: int):
+    """Grid (B, head blocks, chunks), the chunks in order. ``s_scr``
+    [heads x d_v, d_k] f32: the block's states, transposed, carried over
+    the chunks."""
+    d = _LANES
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        s_scr[...] = _full(s_scr.shape, 0)
+
+    st_ref[...] = s_scr[...]
+    cum_all = _triangle_sums(g_ref[...], _AB)
+    for hs in _pairs(q_ref.shape[1] // d):
+        done = _forward_of(tuple(
+            _tiles((q_ref, k_ref, v_ref), h)
+            + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
+               s_scr[pl.ds(h * d, d), :]) for h in hs), scale=scale, r=r)
+        for h, (o, st) in zip(hs, done):
+            o_ref[:, pl.ds(h * d, d)] = o
+            s_scr[pl.ds(h * d, d), :] = st
+
+
+def _specs(t: int, chunk: int, hpb: int, reverse: bool):
+    """Block specs on the grid (B, head blocks, chunks); ``reverse`` walks
+    the chunks from the last to the first."""
+    nc = t // chunk
+    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+    w = hpb * _LANES
+    return {
+        "x": pl.BlockSpec((None, chunk, w), lambda b, k, c: (b, at(c), k)),
+        "beta": pl.BlockSpec((None, None, None, hpb, chunk),
+                             lambda b, k, c: (b, k, at(c), 0, 0)),
+        "state": pl.BlockSpec((None, None, w, _LANES),
+                              lambda b, k, c: (b, at(c), k, 0)),
+    }
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_BYTES)
+
+
+def _kda_fwd(q, k, v, g, beta_t, scale, hpb):
+    """q, k, v, g [B, T, H*128], beta_t [B, H/hpb, T/C, hpb, C] -> (o [B,
+    T, H*128], states [B, T/C, H*128, 128] f32: the state each chunk
+    starts from, transposed)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hd = q.shape
+    chunk = beta_t.shape[-1]
+    nc, h = t // chunk, hd // _LANES
+    s = _specs(t, chunk, hpb, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, r=_SUB),
+        grid=(b, h // hpb, nc),
+        in_specs=[s["x"]] * 4 + [s["beta"]],
+        out_specs=[s["x"], s["state"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, nc, hd, _LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["fwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * hd * (4 * chunk + 3 * _LANES),
+            bytes_accessed=q.size * (4 * q.dtype.itemsize + 4)
+            + 4 * b * nc * hd * _LANES,
+            transcendentals=b * t * hd * (_SUB + 10)),
+    )(q, k, v, g, beta_t)
+
+
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "r"))
+def _backward_of(heads, *, scale: float, r: int):
+    """``heads``: one or two of (q, k, v, dO, cumulative gates, beta's row,
+    the start state, the END state's gradient) -> per head (dq, dk, dv in
+    q's dtype, d(cumulative gates) [C, 128], dbeta's row [1, C], the START
+    state's gradient). The chunk's matrices are made again first."""
+    dt, d = heads[0][0].dtype, _LANES
+    c = heads[0][0].shape[0]
+    scored, solved = _heads_scores(
+        [(q, k, v, cum, beta) for q, k, v, _, cum, beta, *_ in heads], dt, r)
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    ones = _full((8, d + c), 1)
+    last_row = _is(_iota((c, d), 0), c - 1)
+    out = []
+    for (*_, do, _, _, st, dst), (qf, kf, vf, cum, bcol, sq, sk), t in zip(
+            heads, scored, solved):
+        f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
+        held, wrote, w = f["held"], f["wrote"], f["w"]
+        do = do.astype(_F32)
+        do = _mul(do, jax.lax.full_like(do, scale)).astype(dt)
+        dsd = dst.astype(dt)
+        # what each token wrote: through o and through the end state
+        dwrote = _add(_dot(sq.astype(dt), do, _ATB),
+                      _dot(f["k_end"], dsd, _ABT))               # [C, d_v]
+        dwd = dwrote.astype(dt)
+        dsq = _dot(do, wrote, _ABT)
+        dsq = jax.lax.select(_ge(row, col), dsq, _zeros_like(dsq))
+        dq_in = _dot(do, held, _AB)                              # [C, d_k]
+        dk_end = _dot(wrote, dsd, _AB)
+        dw = jax.lax.neg(_dot(dwd, held, _AB))
+        dwu = jax.lax.concatenate([dw, dwrote], 1).astype(dt)
+        d_t = _dot(dwu, f["bkv"], _ABT)                          # [C, C]
+        dbkv = _dot(t.astype(dt), dwu, _ATB)                     # [C, 2d]
+        dbk, dbv = _cols(dbkv, 0, d), _cols(dbkv, d, d)
+        # the solve's own backward, dA = -T^T dT T^T
+        da = jax.lax.neg(_exact_dot(_exact_dot(t, d_t, _ATB), t, _ABT))
+        da = jax.lax.select(_gt(row, col), da, _zeros_like(da))
+        dsk = _mul(da, _cols(bcol, 0, c))
+        # the state the chunk starts from
+        decayed = _mul(dst, _spread(f["at_end"], (d, d)))
+        dst_start = _minus(
+            _add(decayed, _dot(do, f["q_in"], _ATB)), _dot(dwd, w, _ATB))
+        d_last = _sum(_mul(decayed, st), 0)                      # [1, d]
+        # beta: through A's rows, beta k exp(G) and beta v; a row of sums
+        # over the lanes by a product with ones, the tokens along the lanes
+        k_kept = _mul(kf, f["kept"])
+        sums = jax.lax.concatenate(
+            [_add(_mul(dbk, k_kept), _mul(dbv, vf)), _mul(da, sk)], 1)
+        dbeta = _rows(_exact_dot(ones, sums, _ABT), 0, 1)        # [1, C]
+        # the scores
+        dqs, dk_row, dk_col = _scores_bwd(qf, kf, cum, dsq, dsk, dt, r)
+        ended = _mul(dk_end, _mul(kf, f["to_end"]))
+        dq = _add(_mul(dq_in, f["kept"]), dqs)
+        dk = _add(_add(_mul(_mul(dbk, bcol), f["kept"]),
+                       _mul(dk_end, f["to_end"])), _add(dk_row, dk_col))
+        dc = _add(
+            _minus(_add(_mul(dbk, _mul(k_kept, bcol)),
+                        _mul(dq_in, _mul(qf, f["kept"]))), ended),
+            _add(_mul(qf, dqs), _mul(kf, _minus(dk_row, dk_col))))
+        at_last = _add(d_last, _sum(ended, 0))
+        dc = _add(dc, jax.lax.select(
+            last_row, _spread(at_last, (c, d)), _zeros_like(dc)))
+        out.append((dq.astype(dt), dk.astype(dt),
+                    _mul(dbv, bcol).astype(dt), dc, dbeta, dst_start))
+    return out
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_scr,
+                *, scale: float, r: int):
+    """Grid (B, head blocks, chunks from the last). ``ds_scr`` is the
+    gradient of the state the chunk ENDS in, transposed, carried back over
+    the chunks. The chunk's matrices are made again from the inputs and the
+    saved start state."""
+    d = _LANES
+
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        ds_scr[...] = _full(ds_scr.shape, 0)
+
+    cum_all = _triangle_sums(g_ref[...], _AB)
+    dcum = []
+    for hs in _pairs(q_ref.shape[1] // d):
+        done = _backward_of(tuple(
+            _tiles((q_ref, k_ref, v_ref, do_ref), h)
+            + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
+               st_ref[pl.ds(h * d, d), :], ds_scr[pl.ds(h * d, d), :])
+            for h in hs), scale=scale, r=r)
+        for h, (dq, dk, dv, dc, dbeta, dst) in zip(hs, done):
+            lanes = pl.ds(h * d, d)
+            dq_ref[:, lanes], dk_ref[:, lanes], dv_ref[:, lanes] = dq, dk, dv
+            dbeta_ref[pl.ds(h, 1), :] = dbeta
+            ds_scr[pl.ds(h * d, d), :] = dst
+            dcum.append(dc)
+    # dg from d(cumulative sum): the triangle's product, reversed
+    dg_ref[...] = _triangle_sums(jax.lax.concatenate(dcum, 1), _ATB)
+
+
+def _kda_bwd(q, k, v, g, beta_t, states, do, scale, hpb):
+    """-> dq, dk, dv [B, T, H*128] (q's dtype), dg f32, dbeta_t f32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hd = q.shape
+    chunk = beta_t.shape[-1]
+    nc, h = t // chunk, hd // _LANES
+    s = _specs(t, chunk, hpb, reverse=True)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, r=_SUB),
+        grid=(b, h // hpb, nc),
+        in_specs=[s["x"]] * 4 + [s["beta"], s["state"], s["x"]],
+        out_specs=[s["x"]] * 4 + [s["beta"]],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta_t)],
+        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["bwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * hd * (10 * chunk + 8 * _LANES),
+            bytes_accessed=q.size * (8 * q.dtype.itemsize + 8)
+            + 4 * b * nc * hd * _LANES,
+            transcendentals=b * t * hd * (2 * _SUB + 20)),
+    )(q, k, v, g, beta_t, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_kernels(q, k, v, g, beta_t, scale, hpb):
+    return _kda_fwd(q, k, v, g, beta_t, scale, hpb)[0]
+
+
+def _kda_vjp_fwd(q, k, v, g, beta_t, scale, hpb):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, states = _kda_fwd(q, k, v, g, beta_t, scale, hpb)
+    # named so a layer's remat policy can keep them (as ``flash_out``):
+    # with both kept the backward does not run the forward kernel again
+    o = checkpoint_name(o, "kda_out")
+    states = checkpoint_name(states, "kda_states")
+    return o, (q, k, v, g, beta_t, states)
+
+
+def _kda_vjp_bwd(scale, hpb, res, do):
+    return tuple(_kda_bwd(*res, do, scale, hpb))
+
+
+_kda_kernels.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
+
+
+def _kernel_route(q, k, v, g, beta, heads: int, chunk: int, scale: float,
+                  hpb: int):
+    """Whole chunks of merged arrays through the kernel pair; beta goes in
+    head-major, [B, head blocks, chunks, heads a block, C]: a program sees
+    dense rows."""
+    b, t, _ = q.shape
+    beta_t = beta.reshape(b, t // chunk, chunk, heads // hpb, hpb
+                          ).transpose(0, 3, 1, 4, 2)
+    return _kda_kernels(q, k, v, g, beta_t, scale, hpb)
+
+
+def _route(d_k: int, d_v: int, chunk: int) -> str:
+    """The route a call takes, by what it shows: the kernel pair where a
+    head is one 128-lane tile of keys and of values and the chunk is 64."""
+    return "kernel" if d_k == d_v == _LANES and chunk == 64 else "chunked_jnp"
+
+
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
              beta: jax.Array, *, scale: float, chunk: int = 64) -> jax.Array:
     """The gated delta rule with a decay a key channel. q, k [batch, seq,
@@ -230,12 +904,20 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     the work is cut, not what is computed."""
     b, t, _ = q.shape
     heads = beta.shape[-1]
-    chunk = min(chunk, t)
+    d_k, d_v = k.shape[-1] // heads, v.shape[-1] // heads
+    route = _route(d_k, d_v, chunk)
+    if route != "kernel":
+        chunk = min(chunk, t)
     g, beta = g.astype(_F32), beta.astype(_F32)
     (q, k, v, g, beta), pad = pad_tokens((q, k, v, g, beta), chunk)
-    record_path("rtpu.ops.kda.path", PATH_COUNTS, "chunked_jnp",
-                {"chunk": chunk, "tokens": t, "padded_tokens": pad,
-                 "heads": heads, "d_k": k.shape[-1] // heads,
-                 "d_v": v.shape[-1] // heads,
-                 "chunks": (t + pad) // chunk})
-    return _chunked(q, k, v, g, beta, heads, chunk, float(scale))[:, :t]
+    facts = {"chunk": chunk, "tokens": t, "padded_tokens": pad,
+             "heads": heads, "d_k": d_k, "d_v": d_v,
+             "chunks": (t + pad) // chunk}
+    if route == "kernel":
+        hpb = facts["heads_per_block"] = _heads_per_block(heads)
+    record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
+    if route == "kernel":
+        o = _kernel_route(q, k, v, g, beta, heads, chunk, float(scale), hpb)
+    else:
+        o = _chunked(q, k, v, g, beta, heads, chunk, float(scale))
+    return o[:, :t]

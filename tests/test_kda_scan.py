@@ -16,6 +16,12 @@ scalar decay a head (the channels' mean) 0.3 and a missing ``- S^T k``
 0.14, so each misses it (``test_a_wrong_scan_would_fail`` shows all three).
 With bf16 arguments the products' operands are bf16 (the MXU's path) and
 gates, sums, solve and state stay float32: 3e-2 of the largest entry.
+
+ISSUE 50: every test runs on both routes, ``chunked_jnp`` (the plain form,
+forced here by replacing the module's ``_route``) and ``kernel`` (the Pallas
+pair, interpreted on the CPU; heads of 128 and a chunk of 64 take it by
+themselves); the kernel route's five gradients are also held to ``jax.vjp``
+of the plain route.
 """
 import importlib
 
@@ -29,6 +35,27 @@ kda = importlib.import_module("ray_tpu.ops.kda_scan")
 NAMES = ("q", "k", "v", "g", "beta")
 F32_TOL = 1e-5
 D = 128
+ROUTES = ("chunked_jnp", "kernel")
+
+
+@pytest.fixture(params=ROUTES)
+def route(request, monkeypatch):
+    """The route the module's calls take in this test: the plain form for
+    every shape, or what the shape gives (the kernel pair at d 128 and a
+    chunk of 64)."""
+    if request.param == "chunked_jnp":
+        monkeypatch.setattr(kda, "_route", lambda *shape: "chunked_jnp")
+    return request.param
+
+
+def took(route, before, chunk=64):
+    """The one route counted since ``before`` (a copy of PATH_COUNTS) is
+    the one the test asked for, or the plain one for a chunk the kernels do
+    not take."""
+    want = route if chunk == 64 else "chunked_jnp"
+    gained = {k: n - before[k] for k, n in kda.PATH_COUNTS.items()
+              if n != before[k]}
+    assert set(gained) == {want}, (gained, want)
 
 
 def recurrence(q, k, v, g, beta, *, scale, heads, state_dtype=jnp.float32,
@@ -111,25 +138,30 @@ def both(args, do, heads=2, **kw):
 
 @pytest.mark.parametrize("t,chunk", [(150, 64), (64, 64), (40, 64), (96, 16)],
                          ids=["ragged", "one_chunk", "short", "chunk16"])
-def test_kda_scan_is_the_recurrence(t, chunk):
+def test_kda_scan_is_the_recurrence(t, chunk, route):
     """o and all five gradients, T a multiple of the chunk or not (150 =
-    2 chunks and 22 tokens: padded), decays as strong as the assumed
-    initialisation makes them."""
+    2 chunks and 22 tokens: padded; 40 tokens: the kernels pad them to one
+    chunk of 64), decays as strong as the assumed initialisation makes
+    them. A chunk of 16 is the plain form's on either route."""
     args, do = arguments(0, t)
+    before = kda.PATH_COUNTS.copy()
     got, want = both(args, do, chunk=chunk)
+    took(route, before, chunk)
     for name, err in worst(got, want).items():
         assert err < F32_TOL, (name, err)
     assert all(bool(jnp.all(jnp.isfinite(v))) for v in got.values())
 
 
-def test_kda_scan_under_the_strongest_decay():
+def test_kda_scan_under_the_strongest_decay(route):
     """g = -20 a token and channel: the cumulative gate of a chunk reaches
     -1280, exp(+1280) is inf in float32, so a factorised exp(G) exp(-G)
     would be NaN. Every exponent here is <= 0: the state is forgotten
     between tokens and o_t = scale beta_t (q_t.k_t) v_t, with every
     gradient finite."""
     args, do = arguments(1, 150, gate=-20.0)
+    before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
+    took(route, before)
     for name, v in got.items():
         assert bool(jnp.all(jnp.isfinite(v))), name
     # dg is of the order exp(-20) itself (2e-10 at its largest): held to
@@ -143,7 +175,7 @@ def test_kda_scan_under_the_strongest_decay():
                                atol=1e-6)
 
 
-def test_keys_alike_are_solved_in_blocks(monkeypatch):
+def test_keys_alike_are_solved_in_blocks(monkeypatch, route):
     """Neighbouring keys alike (k_i . k_j near 0.8), beta 0.9 and a weak
     decay: the chunk's A has entries near 0.7 everywhere under its
     diagonal, and the Neumann product over the WHOLE chunk, whose terms
@@ -157,7 +189,9 @@ def test_keys_alike_are_solved_in_blocks(monkeypatch):
         2, 150, 2 * D)
     args["beta"] = jnp.full((2, 150, 2), 0.9)
     args["g"] = args["g"] * 0.05
+    before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
+    took(route, before)
     for name, err in worst(got, want).items():
         assert err < F32_TOL, (name, err)
     monkeypatch.setattr(kda, "_SUB", 64)        # one block: the whole chunk
@@ -165,7 +199,8 @@ def test_keys_alike_are_solved_in_blocks(monkeypatch):
     assert not worst({"o": got["o"]}, {"o": want["o"]})["o"] < 1.0
 
 
-def test_chunk_16_equals_chunk_64_up_to_rounding():
+def test_chunk_16_equals_chunk_64_up_to_rounding(route):
+    """On the kernel route: the pair at 64 against the plain form at 16."""
     args, do = arguments(2, 192)
     scale = D ** -0.5
     a, b = (value_and_grads(
@@ -190,25 +225,74 @@ def test_a_wrong_scan_would_fail():
         assert err > at_least > 10 * F32_TOL, (wrong, err)
 
 
-def test_bf16_arguments():
+def test_bf16_arguments(route):
     """The model's call: bf16 q, k, v; g and beta float32."""
     args, do = arguments(3, 150, dtype=jnp.bfloat16)
+    before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
+    took(route, before)
     assert got["o"].dtype == jnp.bfloat16
     for name, err in worst(got, want).items():
         assert err < 3e-2, (name, err)
 
 
-def test_path_event_and_padding():
+def test_path_event_and_padding(route):
     from ray_tpu.perf import recorder
 
-    before = kda.PATH_COUNTS["chunked_jnp"]
+    before = kda.PATH_COUNTS[route]
     args, _ = arguments(4, 150)
     jax.eval_shape(lambda *a: kda.kda_scan(*a, scale=1.0),
                    *(args[n] for n in NAMES))
-    assert kda.PATH_COUNTS["chunked_jnp"] == before + 1
+    assert kda.PATH_COUNTS[route] == before + 1
     events = [e for e in recorder.get_recorder().snapshot()
               if e["kind"] == "rtpu.ops.kda.path"]
-    assert events and events[-1]["data"] == {
-        "route": "chunked_jnp", "chunk": 64, "tokens": 150,
-        "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3}
+    facts = {"route": route, "chunk": 64, "tokens": 150,
+             "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3}
+    if route == "kernel":
+        facts["heads_per_block"] = 2
+    assert events and events[-1]["data"] == facts
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32_TOL),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_kernels_gradients_are_the_plain_routes(dtype, tol):
+    """ISSUE 50: o and the five gradients of the kernel pair against
+    ``jax.vjp`` of the plain route, 3 chunks of 2 x 2 heads. With bf16
+    arguments o differs by a rounding of bf16 at most (the cumulative
+    gates are summed in another order); the gradients differ by the
+    rounding of the cotangents the plain form's autodiff casts to bf16 and
+    the backward kernel keeps in float32."""
+    args, do = arguments(6, 192, dtype=dtype)
+    scale = D ** -0.5
+    before = kda.PATH_COUNTS.copy()
+    got = value_and_grads(
+        lambda *a: kda.kda_scan(*a, scale=scale), args, do)
+    took("kernel", before)
+    want = value_and_grads(
+        lambda *a: kda._chunked(*a, 2, 64, scale), args, do)
+    want = {n: v.astype(jnp.float32) for n, v in want.items()}
+    for name, err in worst(got, want).items():
+        assert err < (8e-3 if name == "o" and tol > 1e-3 else tol), (name, err)
+    assert got["g"].dtype == got["beta"].dtype == jnp.float32
+    assert got["q"].dtype == got["k"].dtype == got["v"].dtype == dtype
+
+
+def test_other_shapes_fall_back_to_the_plain_route():
+    """Heads of 64 (two to a 128-lane tile) and a chunk that is not 64 are
+    the plain form's, and ``PATH_COUNTS`` says so; three heads of 128 take
+    the kernels (an odd number of heads is solved one by one)."""
+    r = jax.random.split(jax.random.PRNGKey(7), 5)
+    b, t, h = 1, 64, 2
+    for d, chunk, want in ((64, 64, "chunked_jnp"), (128, 32, "chunked_jnp"),
+                           (128, 64, "kernel")):
+        q, k, v = (jax.random.normal(r[i], (b, t, h * d)) for i in range(3))
+        g = -jnp.abs(jax.random.normal(r[3], (b, t, h * d))) * 0.1
+        beta = jax.nn.sigmoid(jax.random.normal(r[4], (b, t, h)))
+        before = kda.PATH_COUNTS.copy()
+        o = jax.eval_shape(lambda *a, c=chunk: kda.kda_scan(
+            *a, scale=1.0, chunk=c), q, k, v, g, beta)
+        assert o.shape == (b, t, h * d)
+        took(want, before)
+    assert [kda._heads_per_block(n) for n in (1, 2, 3, 6, 32)] == [
+        1, 2, 3, 2, 4]

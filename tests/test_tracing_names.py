@@ -224,32 +224,41 @@ def test_selective_scan_kernel_names_are_pinned(key, name):
 
 
 def test_the_delta_rule_scan_and_its_stack_leave_their_events():
-    """ISSUE 49: ``rtpu.ops.kda.path`` at trace time, once a KDA layer's
-    body (route, chunk, tokens, heads, padded tokens), and
+    """ISSUES 49, 50: ``rtpu.ops.kda.path`` at trace time, once a KDA
+    layer's body (route, chunk, tokens, heads, padded tokens; the tiny
+    preset's heads of 128 take the kernel pair, two heads a program), and
     ``rtpu.models.stack.runs`` with the runs the model walked and what each
-    keeps. No kernel name is pinned: the scan is plain ``jnp`` today, and
-    ``kda_scan_roofline`` finds its time by the scope ``scan``."""
+    keeps (a KDA run its layers' inputs alone). The kernels' names are
+    pinned: a device trace and the compiled HLO show them, and
+    ``kda_scan_roofline`` finds its time by the scope ``scan`` they run
+    under."""
     from ray_tpu.perf.recorder import get_recorder
 
-    assert not hasattr(kda, "KERNEL_NAMES")
+    assert kda.KERNEL_NAMES == {"fwd": "kda_chunk_fwd",
+                                "bwd": "kda_chunk_bwd"}
     m = KimiLinear(KimiLinearConfig.tiny(experts_held=2))
     p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 150), jnp.int32)
-    before = kda.PATH_COUNTS["chunked_jnp"]
+    before = kda.PATH_COUNTS.copy()
     rec = get_recorder()
     was, rec.enabled = rec.enabled, True
     try:
-        jax.jit(m.loss).lower(p, toks, toks)
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
         events = rec.snapshot()
     finally:
         rec.enabled = was
-    assert kda.PATH_COUNTS["chunked_jnp"] >= before + 3   # three KDA runs
+    gained = kda.PATH_COUNTS - before
+    assert set(gained) == {"kernel"} and gained["kernel"] >= 3  # three runs
     path = [e for e in events if e["kind"] == "rtpu.ops.kda.path"][-1]
-    assert path["label"] == "chunked_jnp"
+    assert path["label"] == "kernel"
     assert path["data"] == {
-        "route": "chunked_jnp", "chunk": 64, "tokens": 150,
+        "route": "kernel", "chunk": 64, "tokens": 150,
         "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
-        "chunks": 3}
+        "chunks": 3, "heads_per_block": 2}
+    # both kernels stand under the scope the roofline reads
+    for name in kda.KERNEL_NAMES.values():
+        assert re.search(r"scan/[^\n]*" + name, text), name
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "kimi_linear"][-1]
     assert runs["data"]["runs"] == [["kda_dense", 1], ["kda_moe", 2],
