@@ -15,7 +15,13 @@ Every layer is ``x = x + mixer(RMSNorm(x)); x = x + ffn(RMSNorm(x))``.
       beta = sigmoid(x̂ W_beta)                                      a head
       per head, S [d_k, d_v] float32 from zero:
           S <- diag(exp(g_t)) S;  S <- S + beta_t k_t (v_t - S^T k_t)^T
-          o_t = S^T (q_t / sqrt(d_k))                    (``ops.kda_scan``)
+          o_t = S^T (q_t / sqrt(d_k))              (``ops.kda_gated_scan``)
+
+  The norms of q and k and g are the scan's to make: the layer hands
+  ``ops.kda_gated_scan`` what its convolutions and its gate projection
+  left, and at the published head size (128 x 128: the kernel route) they
+  are made inside the scan's kernels, g never written; at any other size
+  by ``l2norm`` and the softplus, in float32, before the plain scan.
       y = (RMSNorm_head(o) w_norm * sigmoid((x̂ W_ga) W_gb)) W_o
 
   ``conv`` a causal depthwise convolution of ``kda_d_conv`` taps without
@@ -51,7 +57,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import (causal_conv1d, cross_entropy_loss, kda_scan, l2norm,
+from ..ops import (causal_conv1d, cross_entropy_loss, kda_gated_scan,
                    rmsnorm, sigmoid_gated_rmsnorm)
 from .deepseek_v3 import held_expert_sublayer, latent_attention
 from .stack import period_runs, run_params, walk_stack
@@ -66,7 +72,9 @@ from .stack import period_runs, run_params, walk_stack
 # and the described-chip compile of the cell's step decided against it
 # (PERF.md, PR 50): with nothing kept the compiler makes 2 instructions
 # again on its own, with one layer's kept 20, with all four 45 (accepted,
-# temporaries 8.76 -> 10.17 GB).
+# temporaries 8.76 -> 10.17 GB). Since the kernels make the norms and the
+# gate (PR 51) the step with nothing kept reads 7.95 GB and 0: what a next
+# census of the keep-set starts from.
 _REMAT_SAVE = {"mla": ("flash_out", "flash_lse", "attn_q"), "kda": ()}
 
 # config.json's 1-based lists
@@ -294,12 +302,9 @@ class KimiLinear:
             q, k, v = (jax.nn.silu(causal_conv1d(t, lp[n])) for t, n in
                        ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
         with jax.named_scope("scan"):
-            q, k = (merged(l2norm(heads(t))) for t in (q, k))
-            a = jnp.repeat(jnp.exp(lp["A_log"].astype(jnp.float32)), dk)
-            g = -a * jax.nn.softplus(step.astype(jnp.float32)
-                                     + lp["dt_bias"].astype(jnp.float32))
             beta = jax.nn.sigmoid(write.astype(jnp.float32))
-            o = kda_scan(q, k, v, g, beta, scale=dk ** -0.5)
+            o = kda_gated_scan(q, k, v, step, lp["A_log"], lp["dt_bias"],
+                               beta, scale=dk ** -0.5)
         with jax.named_scope("mixer"):
             o = merged(sigmoid_gated_rmsnorm(heads(o), heads(gate),
                                              lp["o_norm"], c.rms_eps))

@@ -12,7 +12,10 @@ legend); for a TPU-native framework the hot ops are first-party:
   and backward kernels with the state carried in VMEM (Pallas).
 - kda_scan: Kimi Delta Attention's recurrence (a gated delta rule with a
   decay for every key channel; a matrix state a head), chunked in the WY
-  form with every exponent <= 0, plain jnp under one ``lax.scan``.
+  form with every exponent <= 0: a Pallas kernel pair where a head is one
+  128-lane tile, else plain jnp under one ``lax.scan``. kda_gated_scan:
+  the same from what a KDA layer's convolutions and gate projection made
+  (the l2 norms of q and k and the gate's softplus inside the kernels).
 - selective_scan: Mamba-1's recurrence (a decay for every channel AND
   state), walked in chunks on the VPU with the state in VMEM, forward and
   backward kernels (Pallas).
@@ -37,7 +40,7 @@ from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d, gated_rmsnorm,
                      l2norm, sigmoid_gated_rmsnorm)
 from .ssd_scan import ssd_scan
-from .kda_scan import kda_scan
+from .kda_scan import kda_gated_scan, kda_scan
 from .selective_scan import selective_scan
 from .hyper_connection import hc_coefficients, hc_mix, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
@@ -48,7 +51,8 @@ __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "l2norm",
-    "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan", "selective_scan",
+    "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan", "kda_gated_scan",
+    "selective_scan",
     "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",

@@ -52,10 +52,30 @@ thirteen orders of magnitude, blocks of 32 by a tenth, of 16 by 5e-5, of 8
 by 1e-6 (``tests/test_kda_scan.py``). The solve's backward is its own
 (``dA = -T^T dT T^T``), not autodiff's through the products.
 
+Two entries. ``kda_scan(q, k, v, g, beta)`` is the recurrence above, q and
+k as the caller normalised them and g ready: the definition everything here
+is tested against. ``kda_gated_scan(q, k, v, step, a_log, dt_bias, beta)``
+is a KDA layer's call: q and k as its convolutions' SiLU left them, ``step``
+as its gate projection left it, and
+
+    q_n = q / sqrt(sum over a head's channels of q^2 + eps),  k_n likewise
+    g   = -exp(a_log)[head] * softplus(step + dt_bias)           float32
+    o   = kda_scan(q_n, k_n, v, g, beta)
+
+which on the plain route is literally that (``layers.l2norm``, the
+softplus, ``kda_scan``) and on the kernel route is the SAME kernel pair
+with a prologue: each program makes q_n, k_n and g on its own tiles in
+VMEM, and the backward kernel carries the chain rule on (below), so no
+float32 [B, T, H x 128] array is written or read on the way in or out.
+Who made them is the event's fact ``prologue``: ``in_kernel``, or ``jnp``
+(the plain route, and every ``kda_scan`` call).
+
 Two routes, chosen by what a call shows (``PATH_COUNTS``, the event
 ``rtpu.ops.kda.path``; no argument or configuration selects one). T is
 padded to whole chunks with zeros on both (g = 0 does not decay, beta = 0
-and k = 0 write nothing).
+and k = 0 write nothing; through the prologue a padded token's g is
+softplus(dt_bias)'s and not 0, which decays a state nothing reads any
+more).
 
 * ``kernel``: heads of 128 key and 128 value channels (a head is one
   128-lane tile of the model's merged [B, T, H x 128] arrays: nothing is
@@ -80,9 +100,27 @@ and k = 0 write nothing).
     -T^T dT T^T; dV' = scale Q^T dO + k_end dS_end; dS_0 = scale (q e^G)^T
     dO + diag(e^G_C) dS_end - W^T dV'; dg is the triangle's product with dG
     reversed. All held to ``jax.vjp`` of the plain route in the tests.
+  - the prologue (``kda_gated_scan``; ``_unit``, ``_gate``: pure functions
+    of tiles, skipped when g came ready, a static fact of the trace). A
+    program reads q, k and step in the model's dtype and two float32 rows
+    [2, H x 128] (A_log, a head's over its 128 channels, and dt_bias). The
+    norm is a sum over the lanes and a rsqrt a row, the gate an exp and a
+    log1p an element (softplus as max(x, 0) + log1p(exp(-|x|)): nothing
+    overflows), all float32; q_n and k_n stay float32 (the plain code
+    rounds them to q's dtype first: the kernels are the nearer to the
+    recurrence). ``kda_chunk_bwd`` makes them again and writes dq and dk of
+    the RAW q and k (d = r (dq_n - q_n sum over the lanes of dq_n q_n), r
+    the same rsqrt), ``dstep = dg (-exp(A_log)) sigmoid(step + dt_bias)``
+    in step's dtype in place of dg, and the rows' gradients as float32
+    partial sums over the chunks it walks, in an output block [2, W] a
+    (batch row, head block) that stays in VMEM over the sequential axis
+    (sum dg g a channel for A_log, dg / dA_log being g itself; sum dstep a
+    channel for dt_bias); the caller adds them over the batch, and
+    autodiff over a head's lanes.
   How a program lays out its work:
-  - once a program: the cumulative gates of the whole block (the triangle
-    of ones times g, g in three bfloat16 pieces: three exact passes);
+  - once a program: the gate (the prologue) and the cumulative gates of
+    the whole block (the triangle of ones times g, g in three bfloat16
+    pieces: three exact passes);
   - once a PAIR of heads (``_forward_of`` / ``_backward_of``: pure
     functions of the pair's tiles under ``jax.jit``, so that the nine
     kernel instances of a train step and the pairs of a program all get
@@ -113,8 +151,9 @@ and k = 0 write nothing).
   backward, where the kernels take 11.3 and 30.7.)
 
 Precision: matrix products take their operands in q's dtype (bf16 in a
-model) and accumulate in float32; the gates, their cumulative sums, every
-decay, the triangular solve and the state are float32 throughout.
+model) and accumulate in float32; the norms' statistics, the gates, their
+cumulative sums, every decay, the triangular solve and the state are
+float32 throughout.
 """
 from __future__ import annotations
 
@@ -127,6 +166,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot
+from .layers import l2norm
 from .scan_common import pad_tokens, record_path
 from .ssd_scan import _spread, _sum
 
@@ -140,11 +180,11 @@ _flash = importlib.import_module(__package__ + ".flash_attention")
 # tests/test_tracing_names.py.
 KERNEL_NAMES = {
     "fwd": "kda_chunk_fwd",     # o and the state each chunk starts from
-    "bwd": "kda_chunk_bwd",     # dq, dk, dv, dg, dbeta
+    "bwd": "kda_chunk_bwd",     # dq, dk, dv, dg (or dstep, drows), dbeta
 }
 
-# Traced calls of kda_scan by route; the same choice is the flight-recorder
-# event ``rtpu.ops.kda.path``.
+# Traced calls of either entry by route; the same choice is the
+# flight-recorder event ``rtpu.ops.kda.path``.
 PATH_COUNTS: collections.Counter = collections.Counter()
 
 _SUB = 8      # rows of a sub-block: of the decayed score matrices, and of
@@ -607,6 +647,62 @@ def _heads_scores(heads, dt, r: int):
         [_mul(_cols(bcol, 0, c), sk) for *_, bcol, _, sk in out], r)
 
 
+# The prologue: what ``kda_gated_scan``'s caller left to the kernels. Pure
+# functions of tiles that are in VMEM already; ``kda_scan``'s caller made q,
+# k and g itself and the bodies skip them (``eps`` None: a fact of the
+# trace).
+
+
+def _unit(x, eps: float):
+    """A head's tile x [C, 128] float32 -> (x / sqrt(sum over the lanes of
+    x^2 + eps): ``layers.l2norm``, and that reciprocal root in every
+    lane)."""
+    inv = jax.lax.rsqrt(_add(_sum(_mul(x, x), 1), _full((x.shape[0], 1), eps)))
+    inv = _spread(inv, x.shape)
+    return _mul(x, inv), inv
+
+
+def _unit_bwd(dy, y, inv):
+    """``_unit``'s gradient from its two results: inv (dy - y sum over the
+    lanes of dy y)."""
+    return _mul(inv, _minus(dy, _mul(y, _spread(_sum(_mul(dy, y), 1),
+                                                y.shape))))
+
+
+def _normed(heads, eps):
+    """Each head's q and k (its first two tiles) of unit length in float32
+    and the heads' (r_q, r_k), the reciprocal roots the backward's chain
+    rule takes; with ``eps`` None the heads as the caller normalised
+    them."""
+    if eps is None:
+        return heads, [None] * len(heads)
+    out, inv = [], []
+    for q, k, *rest in heads:
+        (q, rq), (k, rk) = (_unit(x.astype(_F32), eps) for x in (q, k))
+        out.append((q, k, *rest))
+        inv.append((rq, rk))
+    return out, inv
+
+
+def _gate(g_ref, rows_ref=None, *, slope: bool = False):
+    """A program's g [C, W] float32 -> (g, d g / d step where ``slope``
+    asks for it and the prologue made g, else None). ``g_ref`` alone holds
+    g as the caller made it; with ``rows_ref`` [2, W] float32 (A_log, a
+    head's over its 128 channels, and dt_bias) it holds the gate
+    projection's step and g = -exp(A_log) softplus(step + dt_bias), the
+    softplus as max(x, 0) + log1p(exp(-|x|)): nothing overflows."""
+    if rows_ref is None:
+        return g_ref[...], None
+    x = g_ref[...].astype(_F32)
+    rows = rows_ref[...]
+    x = _add(x, _spread(_rows(rows, 1, 1), x.shape))
+    neg_a = _spread(jax.lax.neg(jax.lax.exp(_rows(rows, 0, 1))), x.shape)
+    soft = _add(jax.lax.max(x, _zeros_like(x)),
+                jax.lax.log1p(jax.lax.exp(jax.lax.neg(jax.lax.abs(x)))))
+    return _mul(neg_a, soft), (
+        _mul(neg_a, jax.lax.logistic(x)) if slope else None)
+
+
 def _tiles(refs, h: int):
     """Head h's [C, 128] tile of each merged ref."""
     return tuple(x[:, pl.ds(h * _LANES, _LANES)] for x in refs)
@@ -619,12 +715,14 @@ def _tiles(refs, h: int):
 # them the body traced ONCE, and ``setup_s`` pays for every equation traced.
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "r"))
-def _forward_of(heads, *, scale: float, r: int):
+@functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
+def _forward_of(heads, *, scale: float, r: int, eps):
     """``heads``: one or two of (q, k, v, cumulative gates, beta's row, the
     start state [d_v, d_k]) -> per head (o scaled in q's dtype, the state
-    the chunk ends in)."""
+    the chunk ends in). ``eps``: the l2 norm's, where q and k come as the
+    convolutions left them; None where the caller normalised them."""
     dt, d = heads[0][0].dtype, _LANES
+    heads, _ = _normed(heads, eps)
     scored, solved = _heads_scores(heads, dt, r)
     out = []
     for (*_, st), (qf, kf, vf, cum, bcol, sq, _), t in zip(
@@ -649,11 +747,12 @@ def _pairs(hpb: int):
     return [range(i, i + per) for i in range(0, hpb, per)]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_scr,
-                *, scale: float, r: int):
-    """Grid (B, head blocks, chunks), the chunks in order. ``s_scr``
-    [heads x d_v, d_k] f32: the block's states, transposed, carried over
-    the chunks."""
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
+    """Grid (B, head blocks, chunks), the chunks in order. ``refs``: g (or
+    the prologue's step and rows, ``_gate``), beta, then o, the chunk's
+    start state and ``s_scr`` [heads x d_v, d_k] f32: the block's states,
+    transposed, carried over the chunks."""
+    *gate, beta_ref, o_ref, st_ref, s_scr = refs
     d = _LANES
 
     @pl.when(pl.program_id(2) == 0)
@@ -661,12 +760,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_scr,
         s_scr[...] = _full(s_scr.shape, 0)
 
     st_ref[...] = s_scr[...]
-    cum_all = _triangle_sums(g_ref[...], _AB)
+    cum_all = _triangle_sums(_gate(*gate)[0], _AB)
     for hs in _pairs(q_ref.shape[1] // d):
         done = _forward_of(tuple(
             _tiles((q_ref, k_ref, v_ref), h)
             + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
-               s_scr[pl.ds(h * d, d), :]) for h in hs), scale=scale, r=r)
+               s_scr[pl.ds(h * d, d), :]) for h in hs),
+            scale=scale, r=r, eps=eps)
         for h, (o, st) in zip(hs, done):
             o_ref[:, pl.ds(h * d, d)] = o
             s_scr[pl.ds(h * d, d), :] = st
@@ -682,6 +782,10 @@ def _specs(t: int, chunk: int, hpb: int, reverse: bool):
         "x": pl.BlockSpec((None, chunk, w), lambda b, k, c: (b, at(c), k)),
         "beta": pl.BlockSpec((None, None, None, hpb, chunk),
                              lambda b, k, c: (b, k, at(c), 0, 0)),
+        # the prologue's two rows, and their gradients' partial sums a
+        # batch row: one block over the whole sequential axis
+        "rows": pl.BlockSpec((2, w), lambda b, k, c: (0, k)),
+        "drows": pl.BlockSpec((None, 2, w), lambda b, k, c: (b, 0, k)),
         "state": pl.BlockSpec((None, None, w, _LANES),
                               lambda b, k, c: (b, at(c), k, 0)),
     }
@@ -695,10 +799,21 @@ def _params():
         vmem_limit_bytes=_VMEM_BYTES)
 
 
-def _kda_fwd(q, k, v, g, beta_t, scale, hpb):
-    """q, k, v, g [B, T, H*128], beta_t [B, H/hpb, T/C, hpb, C] -> (o [B,
-    T, H*128], states [B, T/C, H*128, 128] f32: the state each chunk
-    starts from, transposed)."""
+def _gate_specs(s, gate):
+    """The block specs of ``gate``: (g,), or the prologue's (step, rows)."""
+    return [s["x"]] + [s["rows"]] * (len(gate) - 1)
+
+
+def _gate_bytes(gate) -> int:
+    """What a kernel reads of ``gate`` (the backward writes as much)."""
+    return sum(x.size * x.dtype.itemsize for x in gate)
+
+
+def _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps):
+    """q, k, v [B, T, H*128], ``gate`` (g,) or the prologue's (step [B, T,
+    H*128], rows [2, H*128]), beta_t [B, H/hpb, T/C, hpb, C] -> (o [B, T,
+    H*128], states [B, T/C, H*128, 128] f32: the state each chunk starts
+    from, transposed)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, hd = q.shape
@@ -706,9 +821,9 @@ def _kda_fwd(q, k, v, g, beta_t, scale, hpb):
     nc, h = t // chunk, hd // _LANES
     s = _specs(t, chunk, hpb, reverse=False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, r=_SUB),
+        functools.partial(_fwd_kernel, scale=scale, r=_SUB, eps=eps),
         grid=(b, h // hpb, nc),
-        in_specs=[s["x"]] * 4 + [s["beta"]],
+        in_specs=[s["x"]] * 3 + _gate_specs(s, gate) + [s["beta"]],
         out_specs=[s["x"], s["state"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, nc, hd, _LANES), _F32)],
@@ -718,10 +833,10 @@ def _kda_fwd(q, k, v, g, beta_t, scale, hpb):
         interpret=_flash._use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * t * hd * (4 * chunk + 3 * _LANES),
-            bytes_accessed=q.size * (4 * q.dtype.itemsize + 4)
+            bytes_accessed=q.size * 4 * q.dtype.itemsize + _gate_bytes(gate)
             + 4 * b * nc * hd * _LANES,
-            transcendentals=b * t * hd * (_SUB + 10)),
-    )(q, k, v, g, beta_t)
+            transcendentals=b * t * hd * (_SUB + 10 + 2 * (len(gate) - 1))),
+    )(q, k, v, *gate, beta_t)
 
 
 # ---------------------------------------------------------------------------
@@ -729,22 +844,25 @@ def _kda_fwd(q, k, v, g, beta_t, scale, hpb):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "r"))
-def _backward_of(heads, *, scale: float, r: int):
+@functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
+def _backward_of(heads, *, scale: float, r: int, eps):
     """``heads``: one or two of (q, k, v, dO, cumulative gates, beta's row,
     the start state, the END state's gradient) -> per head (dq, dk, dv in
     q's dtype, d(cumulative gates) [C, 128], dbeta's row [1, C], the START
-    state's gradient). The chunk's matrices are made again first."""
+    state's gradient). The chunk's matrices are made again first; with
+    ``eps`` (``_forward_of``) dq and dk are the RAW q's and k's, through
+    the norm."""
     dt, d = heads[0][0].dtype, _LANES
     c = heads[0][0].shape[0]
+    heads, norms = _normed(heads, eps)
     scored, solved = _heads_scores(
         [(q, k, v, cum, beta) for q, k, v, _, cum, beta, *_ in heads], dt, r)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     ones = _full((8, d + c), 1)
     last_row = _is(_iota((c, d), 0), c - 1)
     out = []
-    for (*_, do, _, _, st, dst), (qf, kf, vf, cum, bcol, sq, sk), t in zip(
-            heads, scored, solved):
+    for (*_, do, _, _, st, dst), (qf, kf, vf, cum, bcol, sq, sk), t, inv \
+            in zip(heads, scored, solved, norms):
         f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
         held, wrote, w = f["held"], f["wrote"], f["w"]
         do = do.astype(_F32)
@@ -791,32 +909,43 @@ def _backward_of(heads, *, scale: float, r: int):
         at_last = _add(d_last, _sum(ended, 0))
         dc = _add(dc, jax.lax.select(
             last_row, _spread(at_last, (c, d)), _zeros_like(dc)))
+        if inv is not None:
+            dq, dk = _unit_bwd(dq, qf, inv[0]), _unit_bwd(dk, kf, inv[1])
         out.append((dq.astype(dt), dk.astype(dt),
                     _mul(dbv, bcol).astype(dt), dc, dbeta, dst_start))
     return out
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_scr,
-                *, scale: float, r: int):
-    """Grid (B, head blocks, chunks from the last). ``ds_scr`` is the
-    gradient of the state the chunk ENDS in, transposed, carried back over
-    the chunks. The chunk's matrices are made again from the inputs and the
+def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
+    """Grid (B, head blocks, chunks from the last). ``refs``: g (or the
+    prologue's step and rows), beta, the chunks' start states, dO; then dq,
+    dk, dv, dg (or dstep in step's dtype and ``drows`` [2, W] f32, the
+    rows' gradients summed over the chunks walked so far: the block stays
+    in VMEM over the sequential axis), dbeta; and ``ds_scr``, the gradient
+    of the state the chunk ENDS in, transposed, carried back over the
+    chunks. The chunk's matrices are made again from the inputs and the
     saved start state."""
+    n = 1 if eps is None else 2   # g ready, or the prologue's step and rows
+    gate = refs[:n]
+    (beta_ref, st_ref, do_ref, dq_ref, dk_ref, dv_ref, dgate_ref,
+     *drows_ref, dbeta_ref, ds_scr) = refs[n:]
     d = _LANES
 
     @pl.when(pl.program_id(2) == 0)
     def _last_chunk():
         ds_scr[...] = _full(ds_scr.shape, 0)
+        for ref in drows_ref:
+            ref[...] = _full(ref.shape, 0)
 
-    cum_all = _triangle_sums(g_ref[...], _AB)
+    g, slope = _gate(*gate, slope=True)
+    cum_all = _triangle_sums(g, _AB)
     dcum = []
     for hs in _pairs(q_ref.shape[1] // d):
         done = _backward_of(tuple(
             _tiles((q_ref, k_ref, v_ref, do_ref), h)
             + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
                st_ref[pl.ds(h * d, d), :], ds_scr[pl.ds(h * d, d), :])
-            for h in hs), scale=scale, r=r)
+            for h in hs), scale=scale, r=r, eps=eps)
         for h, (dq, dk, dv, dc, dbeta, dst) in zip(hs, done):
             lanes = pl.ds(h * d, d)
             dq_ref[:, lanes], dk_ref[:, lanes], dv_ref[:, lanes] = dq, dk, dv
@@ -824,11 +953,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
             ds_scr[pl.ds(h * d, d), :] = dst
             dcum.append(dc)
     # dg from d(cumulative sum): the triangle's product, reversed
-    dg_ref[...] = _triangle_sums(jax.lax.concatenate(dcum, 1), _ATB)
+    dg = _triangle_sums(jax.lax.concatenate(dcum, 1), _ATB)
+    if slope is None:
+        dgate_ref[...] = dg
+        return
+    # the gate's chain rule: dstep = dg dg/dstep, dA_log = sum dg g (dg /
+    # dA_log is g itself), ddt_bias = sum dstep, the sums in float32
+    dstep = _mul(dg, slope)
+    dgate_ref[...] = dstep.astype(dgate_ref.dtype)
+    drows_ref[0][...] = _add(drows_ref[0][...], jax.lax.concatenate(
+        [_sum(_mul(dg, g), 0), _sum(dstep, 0)], 0))
 
 
-def _kda_bwd(q, k, v, g, beta_t, states, do, scale, hpb):
-    """-> dq, dk, dv [B, T, H*128] (q's dtype), dg f32, dbeta_t f32."""
+def _kda_bwd(q, k, v, gate, beta_t, states, do, scale, hpb, eps):
+    """-> [dq, dk, dv [B, T, H*128] (q's dtype), dg f32 (or the
+    prologue's dstep in step's dtype and drows [B, 2, H*128] f32),
+    dbeta_t f32]."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, hd = q.shape
@@ -836,62 +976,97 @@ def _kda_bwd(q, k, v, g, beta_t, states, do, scale, hpb):
     nc, h = t // chunk, hd // _LANES
     s = _specs(t, chunk, hpb, reverse=True)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    drows = [jax.ShapeDtypeStruct((b,) + x.shape, x.dtype) for x in gate[1:]]
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, r=_SUB),
+        functools.partial(_bwd_kernel, scale=scale, r=_SUB, eps=eps),
         grid=(b, h // hpb, nc),
-        in_specs=[s["x"]] * 4 + [s["beta"], s["state"], s["x"]],
-        out_specs=[s["x"]] * 4 + [s["beta"]],
-        out_shape=[like(q), like(k), like(v), like(g), like(beta_t)],
+        in_specs=[s["x"]] * 3 + _gate_specs(s, gate)
+        + [s["beta"], s["state"], s["x"]],
+        out_specs=[s["x"]] * 4 + [s["drows"]] * len(drows) + [s["beta"]],
+        out_shape=[like(q), like(k), like(v), like(gate[0])] + drows
+        + [like(beta_t)],
         scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["bwd"],
         interpret=_flash._use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * t * hd * (10 * chunk + 8 * _LANES),
-            bytes_accessed=q.size * (8 * q.dtype.itemsize + 8)
-            + 4 * b * nc * hd * _LANES,
-            transcendentals=b * t * hd * (2 * _SUB + 20)),
-    )(q, k, v, g, beta_t, states, do)
+            bytes_accessed=q.size * 7 * q.dtype.itemsize
+            + 2 * _gate_bytes(gate) + 4 * b * nc * hd * _LANES,
+            transcendentals=b * t * hd * (2 * _SUB + 20
+                                          + 3 * (len(gate) - 1))),
+    )(q, k, v, *gate, beta_t, states, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kda_kernels(q, k, v, g, beta_t, scale, hpb):
-    return _kda_fwd(q, k, v, g, beta_t, scale, hpb)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_kernels(q, k, v, gate, beta_t, scale, hpb, eps):
+    return _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps)[0]
 
 
-def _kda_vjp_fwd(q, k, v, g, beta_t, scale, hpb):
+def _kda_vjp_fwd(q, k, v, gate, beta_t, scale, hpb, eps):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, states = _kda_fwd(q, k, v, g, beta_t, scale, hpb)
+    o, states = _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps)
     # named so a layer's remat policy can keep them (as ``flash_out``):
     # with both kept the backward does not run the forward kernel again
     o = checkpoint_name(o, "kda_out")
     states = checkpoint_name(states, "kda_states")
-    return o, (q, k, v, g, beta_t, states)
+    return o, (q, k, v, gate, beta_t, states)
 
 
-def _kda_vjp_bwd(scale, hpb, res, do):
-    return tuple(_kda_bwd(*res, do, scale, hpb))
+def _kda_vjp_bwd(scale, hpb, eps, res, do):
+    dq, dk, dv, *dgate, dbeta = _kda_bwd(*res, do, scale, hpb, eps)
+    # the rows' partial sums a batch row -> the rows' gradient
+    dgate[1:] = [x.sum(0) for x in dgate[1:]]
+    return dq, dk, dv, tuple(dgate), dbeta
 
 
 _kda_kernels.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
 
 
-def _kernel_route(q, k, v, g, beta, heads: int, chunk: int, scale: float,
-                  hpb: int):
+def _kernel_route(q, k, v, gate, beta, heads: int, chunk: int,
+                  scale: float, hpb: int, eps):
     """Whole chunks of merged arrays through the kernel pair; beta goes in
     head-major, [B, head blocks, chunks, heads a block, C]: a program sees
     dense rows."""
     b, t, _ = q.shape
     beta_t = beta.reshape(b, t // chunk, chunk, heads // hpb, hpb
                           ).transpose(0, 3, 1, 4, 2)
-    return _kda_kernels(q, k, v, g, beta_t, scale, hpb)
+    return _kda_kernels(q, k, v, gate, beta_t, scale, hpb, eps)
 
 
 def _route(d_k: int, d_v: int, chunk: int) -> str:
     """The route a call takes, by what it shows: the kernel pair where a
     head is one 128-lane tile of keys and of values and the chunk is 64."""
     return "kernel" if d_k == d_v == _LANES and chunk == 64 else "chunked_jnp"
+
+
+def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
+    """What both entries share: the route by what the call shows, whole
+    chunks, the path event. ``gate``: (g,) with ``eps`` None, or the
+    prologue's (step, rows) on the kernel route."""
+    b, t, _ = q.shape
+    heads = beta.shape[-1]
+    d_k, d_v = k.shape[-1] // heads, v.shape[-1] // heads
+    route = _route(d_k, d_v, chunk)
+    if route != "kernel":
+        chunk = min(chunk, t)
+    (q, k, v, lead, beta), pad = pad_tokens(
+        (q, k, v, gate[0], beta.astype(_F32)), chunk)
+    gate = (lead,) + tuple(gate[1:])
+    facts = {"chunk": chunk, "tokens": t, "padded_tokens": pad,
+             "heads": heads, "d_k": d_k, "d_v": d_v,
+             "chunks": (t + pad) // chunk,
+             "prologue": "jnp" if eps is None else "in_kernel"}
+    if route == "kernel":
+        hpb = facts["heads_per_block"] = _heads_per_block(heads)
+    record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
+    if route == "kernel":
+        o = _kernel_route(q, k, v, gate, beta, heads, chunk, float(scale),
+                          hpb, eps)
+    else:
+        o = _chunked(q, k, v, *gate, beta, heads, chunk, float(scale))
+    return o[:, :t]
 
 
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -902,22 +1077,34 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     beta [batch, seq, heads] -> o [batch, seq, heads * d_v] in q's dtype,
     o_t = S_t^T (scale q_t). Differentiable in all five. ``chunk`` is how
     the work is cut, not what is computed."""
-    b, t, _ = q.shape
+    return _scan(q, k, v, (g.astype(_F32),), beta, scale=scale, chunk=chunk,
+                 eps=None)
+
+
+def kda_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array,
+                   step: jax.Array, a_log: jax.Array, dt_bias: jax.Array,
+                   beta: jax.Array, *, scale: float, eps: float = 1e-6,
+                   chunk: int = 64) -> jax.Array:
+    """A KDA layer's scan from what its convolutions and its gate
+    projection made: ``kda_scan`` of q and k each normalised to unit length
+    a head (``layers.l2norm`` with ``eps``) and of
+
+        g = -exp(a_log)[head] * softplus(step + dt_bias)        float32
+
+    q, k, step [batch, seq, heads * d_k], a_log [heads], dt_bias [heads *
+    d_k]. Differentiable in all seven arrays. That sentence is this
+    function on the plain route, literally; on the kernel route the kernels
+    make the norms and the gate on their own tiles (the module's docstring:
+    the prologue) and no g is written."""
     heads = beta.shape[-1]
-    d_k, d_v = k.shape[-1] // heads, v.shape[-1] // heads
-    route = _route(d_k, d_v, chunk)
-    if route != "kernel":
-        chunk = min(chunk, t)
-    g, beta = g.astype(_F32), beta.astype(_F32)
-    (q, k, v, g, beta), pad = pad_tokens((q, k, v, g, beta), chunk)
-    facts = {"chunk": chunk, "tokens": t, "padded_tokens": pad,
-             "heads": heads, "d_k": d_k, "d_v": d_v,
-             "chunks": (t + pad) // chunk}
-    if route == "kernel":
-        hpb = facts["heads_per_block"] = _heads_per_block(heads)
-    record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
-    if route == "kernel":
-        o = _kernel_route(q, k, v, g, beta, heads, chunk, float(scale), hpb)
-    else:
-        o = _chunked(q, k, v, g, beta, heads, chunk, float(scale))
-    return o[:, :t]
+    d_k = k.shape[-1] // heads
+    a_log, dt_bias = a_log.astype(_F32), dt_bias.astype(_F32)
+    if _route(d_k, v.shape[-1] // heads, chunk) == "kernel":
+        rows = jnp.stack([jnp.repeat(a_log, d_k), dt_bias])
+        return _scan(q, k, v, (step, rows), beta, scale=scale, chunk=chunk,
+                     eps=float(eps))
+    unit = lambda x: l2norm(                                 # noqa: E731
+        x.reshape(*x.shape[:2], heads, d_k), eps).reshape(x.shape)
+    g = -jnp.repeat(jnp.exp(a_log), d_k) * jax.nn.softplus(
+        step.astype(_F32) + dt_bias)
+    return kda_scan(unit(q), unit(k), v, g, beta, scale=scale, chunk=chunk)

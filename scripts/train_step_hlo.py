@@ -164,9 +164,12 @@ def dump(tree: str, out: str, cell: str, census: bool = False) -> None:
 
     # the kernels pick interpret mode from jax.default_backend(), the CPU
     # here: take the compiled path, as the chip does
-    # (``ray_tpu.ops.flash_attention`` the attribute is the function)
-    importlib.import_module(
-        "ray_tpu.ops.flash_attention")._use_interpret = lambda: False
+    # (``ray_tpu.ops.flash_attention`` the attribute is the function; the
+    # expert layer's grouped products have a switch of their own, and
+    # interpreted their row buffers alone refuse kimilinear's step)
+    for module in ("flash_attention", "expert_layer"):
+        importlib.import_module(
+            "ray_tpu.ops." + module)._use_interpret = lambda: False
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

@@ -516,7 +516,28 @@ def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
     assert not re.findall(r"f32\[8192,4,4\]|f32\[2,4096,4,4\]", text)
 
 
-def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
+def _buffers_under(text: str, shape: str, scope: str):
+    """The instructions of an optimised program that PRODUCE an array of
+    ``shape`` (``f32[2,8192,4096]``) under the model's scope ``scope``: of
+    the entry and the loops' bodies, not those inside a fusion (they live
+    in registers and VMEM), by the scope in their ``op_name``."""
+    fused = set(re.findall(r" fusion\(.*?calls=%?([\w.\-]+)", text))
+    found, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+        elif inside not in fused and re.match(
+                r"\s*(?:ROOT )?%?\S+ = " + re.escape(shape) + r"[{ ]", line):
+            op = re.search(r'op_name="([^"]*)"', line)
+            if op and re.search(r"[/(]" + scope + r"[/)]", op.group(1)):
+                found.append(line.split(" = ")[0].strip())
+    return found
+
+
+@pytest.mark.parametrize("entry", ["scan", "gated"])
+def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels,
+                                               entry):
     """ISSUE 50: kimilinear_train_s8192's delta-rule scan, B=2, S=8192, 32
     heads of 128 x 128, chunks of 64, fed as the model feeds it (merged
     [B, S, H*128], g float32): the call takes the kernel route, forward and
@@ -525,20 +546,32 @@ def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
     64]), of a sub-block's pairwise differences ([.., 8, 8, 128]) or of its
     solve reaches HBM: of [128 chunks, ...] there is nothing but the states
     the chunks start from, [2, 128, 4096, 128] float32, and beta's
-    head-major rows."""
+    head-major rows. ISSUE 51, ``gated``: the model's call since, q, k and
+    the gate projection's step in bf16 as their layers left them, A_log and
+    dt_bias: the same two kernels make the norms and the gate, and NO
+    float32 [2, 8192, 4096] array (g, dg, a norm's square) is anywhere in
+    the program; the rows' gradients leave the kernel as [2, 2, 4096]
+    partial sums."""
     kda = importlib.import_module("ray_tpu.ops.kda_scan")
     b, t, h, d = 2, 8192, 32, 128
     sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
+    wide = sd((b, t, h * d))
+    if entry == "gated":
+        fn = kda.kda_gated_scan
+        args = (wide, wide, wide, wide, sd((h,), jnp.float32),
+                sd((h * d,), jnp.float32), sd((b, t, h), jnp.float32))
+    else:
+        fn = kda.kda_scan
+        args = (wide, wide, wide, sd((b, t, h * d), jnp.float32),
+                sd((b, t, h), jnp.float32))
 
-    def loss(q, k, v, g, beta):
-        return kda.kda_scan(q, k, v, g, beta, scale=d ** -0.5).astype(
-            jnp.float32).sum()
+    def loss(*a):
+        return fn(*a, scale=d ** -0.5).astype(jnp.float32).sum()
 
     before = kda.PATH_COUNTS.copy()
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
-        sd((b, t, h * d)), sd((b, t, h * d)), sd((b, t, h * d)),
-        sd((b, t, h * d), jnp.float32), sd((b, t, h), jnp.float32)).compile()
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))
+                       ).lower(*args).compile()
     assert kda.PATH_COUNTS - before == {"kernel": 1}
     text = compiled.as_text()
     calls = [line.split(" = ")[0] for line in text.splitlines()
@@ -555,9 +588,14 @@ def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
              if math.prod(int(n) for n in shape.split(",")) >= 1 << 20}
     assert large == {"2,8192,4096", "2,128,4096,128"}, large
     assert "f32[2,128,4096,128]" in text
+    if entry == "gated":
+        assert "f32[2,8192,4096]" not in text
+        assert "f32[2,2,4096]" in text
     # 4 units of [2, 8192, 4096] bf16 in, their gradients out, and the
     # chunk-start states (537 MB): well under the 5 GB the step can spare
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    # (read: 0.67 GB with the gate made in the kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        3.0e9 if entry == "scan" else 0.8e9)
 
 
 # 48 s alone since the delta rule is a kernel pair (85 s with its loops of
@@ -566,18 +604,24 @@ def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
 @pytest.mark.time_limit(480)
 def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
                                               monkeypatch):
-    """ISSUES 49, 50: kimilinear_train_s8192's own train step (the
+    """ISSUES 49, 50, 51: kimilinear_train_s8192's own train step (the
     harness's ``make_train_step``, the cell's configuration, optimizer,
     batch 2 of 8192, parameters and optimizer state donated) for the
     described v5e, the expert layer's kernels on their compiled path as on
     the chip (in interpret mode its row buffers are refused by 1.30 GB):
-    602.4 M parameters at 12 B as arguments (7.23 GB), 8.76 GB of
-    temporaries (they overlap the donated state) with a KDA layer keeping
-    its input alone and the latent layer its kernels' output, row
-    statistics and q; the compiler makes 2 instructions again on its own
-    (20 before the delta rule's kernels freed the turns' stacked inputs;
-    20 again with one KDA layer's o and chunk states kept, 45 with all
-    four: why they are not). The one latent layer's two kernels stand once
+    602.4 M parameters at 12 B as arguments (7.23 GB), 7.95 GB of
+    temporaries (they overlap the donated state; 8.76 GB while the plain
+    code made the norms of q and k and wrote the gate g in float32, ISSUE
+    51) with a KDA layer keeping its input alone and the latent layer its
+    kernels' output, row statistics and q; the compiler makes NO
+    instruction again on its own (2 before ISSUE 51; 20 before the delta
+    rule's kernels freed the turns' stacked inputs; 20 again with one KDA
+    layer's o and chunk states kept, 45 with all four: why they are not).
+    Under the scope ``scan`` no float32 [2, 8192, 4096] array is produced
+    any more (the parent's step held 21 such producers there: the gate,
+    its broadcast factor, the norms' squares, dg and its products): the
+    kernels read what the convolutions and the gate projection made. The
+    one latent layer's two kernels stand once
     each; the delta rule's forward kernel stands twice a run of KDA layers
     (the forward sweep and the rematerialised layer) and its backward
     once."""
@@ -590,16 +634,21 @@ def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
     tool = _hlo_tool()
     compiled = tool.compile_step("kimilinear_train_s8192", one_chip)
     assert 7.2e9 < _fits(compiled) < 7.3e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 8.9e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 8.1e9
     text = compiled.as_text()
     assert "s32[2,8192]" in text            # the cell's batch, not another
     assert tool.compiler_remat(text) <= 6
+    assert _buffers_under(text, "f32[2,8192,4096]", "scan") == []
+    assert _buffers_under(text, "f32[2,8192,4096]", "mixer")  # it can see
     calls = [line.split(" = ")[0] for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     count = lambda name: sum(                                # noqa: E731
         1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
     assert count("flash_latent_fwd") == count("flash_latent_bwd_dkv") == 1
     assert count("kda_chunk_fwd") == 6 and count("kda_chunk_bwd") == 3
+    for line in text.splitlines():          # all nine under the scope
+        if re.match(r"\s*%?kda_chunk_(fwd|bwd)(\.\d+)? = ", line):
+            assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
     assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 9
 
 

@@ -22,6 +22,20 @@ forced here by replacing the module's ``_route``) and ``kernel`` (the Pallas
 pair, interpreted on the CPU; heads of 128 and a chunk of 64 take it by
 themselves); the kernel route's five gradients are also held to ``jax.vjp``
 of the plain route.
+
+ISSUE 51: every such test also runs through the second entry,
+``kda_gated_scan`` (``entry`` = ``gated``): q and k as a layer's
+convolutions leave them (any length, some tokens so short that the norm's
+``eps`` shows), the gate projection's ``step``, ``A_log`` and ``dt_bias``,
+held to the recurrence of ``l2norm``-ed q and k and g = -exp(A_log)
+softplus(step + dt_bias) written out here, o and SEVEN gradients. On the
+kernel route the norms and the gate are made inside the kernels. The
+gradients of ``A_log`` and ``dt_bias`` are sums over every token (and a
+head's channels) of terms of both signs: they are held to a share of what
+those sums ADD UP in magnitude (``added``), as a sum in another order can
+be, not of the sum that is left (against float64 the plain route's own
+``A_log`` is off by 7e-5 of its largest entry here, the kernels' by 1.5e-4;
+of what is added both read under 1e-6).
 """
 import importlib
 
@@ -33,9 +47,12 @@ import pytest
 kda = importlib.import_module("ray_tpu.ops.kda_scan")
 
 NAMES = ("q", "k", "v", "g", "beta")
+GATED = ("q", "k", "v", "step", "a_log", "dt_bias", "beta")
 F32_TOL = 1e-5
 D = 128
+EPS = 1e-6      # ``layers.l2norm``'s
 ROUTES = ("chunked_jnp", "kernel")
+ENTRIES = ("scan", "gated")
 
 
 @pytest.fixture(params=ROUTES)
@@ -45,6 +62,13 @@ def route(request, monkeypatch):
     chunk of 64)."""
     if request.param == "chunked_jnp":
         monkeypatch.setattr(kda, "_route", lambda *shape: "chunked_jnp")
+    return request.param
+
+
+@pytest.fixture(params=ENTRIES)
+def entry(request):
+    """``scan``: ``kda_scan`` (q and k normalised, g ready); ``gated``:
+    ``kda_gated_scan`` from the raw q, k and the gate projection's step."""
     return request.param
 
 
@@ -85,6 +109,28 @@ def recurrence(q, k, v, g, beta, *, scale, heads, state_dtype=jnp.float32,
     return jnp.moveaxis(o, 0, 1).reshape(b, t, -1)
 
 
+def unit_heads(x, heads, eps=EPS):
+    """x [B, T, heads * d] float32 / sqrt(sum of a head's squares + eps)."""
+    xh = x.astype(jnp.float32).reshape(*x.shape[:2], heads, -1)
+    return (xh / jnp.sqrt(jnp.sum(xh * xh, -1, keepdims=True) + eps)
+            ).reshape(x.shape)
+
+
+def gate_of(step, a_log, dt_bias):
+    """g = -exp(A_log)[head] softplus(step + dt_bias), float32."""
+    x = step.astype(jnp.float32) + dt_bias
+    soft = jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+    return -jnp.repeat(jnp.exp(a_log), x.shape[-1] // a_log.shape[0]) * soft
+
+
+def gated_recurrence(q, k, v, step, a_log, dt_bias, beta, *, scale, heads,
+                     **wrong):
+    """``kda_gated_scan``'s definition, token by token."""
+    return recurrence(unit_heads(q, heads), unit_heads(k, heads), v,
+                      gate_of(step, a_log, dt_bias), beta, scale=scale,
+                      heads=heads, **wrong)
+
+
 def arguments(seed, t, heads=2, batch=2, dtype=jnp.float32, gate=None):
     """Keys and queries of unit length a head, decays exp(g) from 0.999 a
     token down to 0.2 (``A`` in [1, 16] x a step log-uniform in [0.001,
@@ -110,72 +156,142 @@ def arguments(seed, t, heads=2, batch=2, dtype=jnp.float32, gate=None):
     }, jax.random.normal(r[6], shape)
 
 
+def gated(args, dtype=None, gate=None):
+    """``arguments`` as a KDA layer hands them to ``kda_gated_scan``: q and
+    k of any length (a token's and head's from 0.01 to 10: at 0.01 the
+    norm's eps moves the result by 5e-3), the gate projection's step, A_log
+    and dt_bias in place of g (decays in the same range; ``gate`` a token
+    and channel where it is given: A_log = log(-gate), softplus(dt_bias) =
+    1, step 0)."""
+    b, t, h = args["beta"].shape
+    dtype = dtype or args["q"].dtype
+    r = jax.random.split(jax.random.PRNGKey(b * t), 5)
+
+    def any_length(x, key):
+        m = jnp.exp(jax.random.uniform(key, (b, t, h, 1), minval=np.log(
+            1e-2), maxval=np.log(10.0)))
+        return (x.astype(jnp.float32).reshape(b, t, h, D) * m).reshape(
+            x.shape).astype(dtype)
+
+    dt = jnp.exp(jax.random.uniform(r[2], (h * D,), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    out = {"q": any_length(args["q"], r[0]), "k": any_length(args["k"], r[1]),
+           "v": args["v"],
+           "step": (0.5 * jax.random.normal(r[3], (b, t, h * D))).astype(dtype),
+           "a_log": jnp.log(jax.random.uniform(r[4], (h,), minval=1.0,
+                                               maxval=16.0)),
+           "dt_bias": dt + jnp.log(-jnp.expm1(-dt)), "beta": args["beta"]}
+    if gate is not None:
+        out.update(step=jnp.zeros_like(out["step"]),
+                   a_log=jnp.full((h,), np.log(-gate), jnp.float32),
+                   dt_bias=jnp.full((h * D,), np.log(np.e - 1), jnp.float32))
+    return out
+
+
 def value_and_grads(fn, args, do):
+    names = GATED if "step" in args else NAMES
+
     def scalar(*a):
         o = fn(*a)
         return jnp.sum(o.astype(jnp.float32) * do), o
 
     (_, o), grads = jax.jit(jax.value_and_grad(
-        scalar, argnums=tuple(range(5)), has_aux=True))(
-        *(args[n] for n in NAMES))
-    return dict(zip(("o",) + NAMES, (o,) + grads))
+        scalar, argnums=tuple(range(len(names))), has_aux=True))(
+        *(args[n] for n in names))
+    return dict(zip(("o",) + names, (o,) + grads))
 
 
-def worst(got, want):
-    """{name: largest difference as a share of want's largest entry}."""
-    return {n: float(jnp.max(jnp.abs(got[n].astype(jnp.float32) - want[n]))
-                     / (jnp.max(jnp.abs(want[n])) + 1e-30)) for n in want}
+def added(args, want):
+    """What the gradients of ``a_log`` (dg g, a head) and ``dt_bias``
+    (dstep, a channel) ADD UP in magnitude, from ``want``'s dstep = dg g
+    sigmoid / softplus: the scale a sum in another order is held to."""
+    x = args["step"].astype(jnp.float32) + args["dt_bias"]
+    dstep = jnp.abs(want["step"].astype(jnp.float32))
+    b, t, _ = dstep.shape
+    dgg = dstep * jax.nn.softplus(x) / jax.nn.sigmoid(x)
+    return {"a_log": jnp.max(dgg.reshape(b, t, -1, D).sum((0, 1, 3))),
+            "dt_bias": jnp.max(dstep.sum((0, 1)))}
+
+
+def worst(got, want, args=None):
+    """{name: largest difference as a share of want's largest entry}; with
+    the ``gated`` arguments given, a_log's and dt_bias's as a share of what
+    their sums add up (``added``)."""
+    of = {n: jnp.max(jnp.abs(want[n].astype(jnp.float32))) for n in want}
+    if args is not None and "step" in args:
+        of.update(added(args, want))
+    return {n: float(jnp.max(jnp.abs(got[n].astype(jnp.float32)
+                                     - want[n].astype(jnp.float32)))
+                     / (of[n] + 1e-30)) for n in want}
 
 
 def both(args, do, heads=2, **kw):
+    """(the module's o and gradients, the recurrence's) of ``args``: the
+    five of ``kda_scan`` or, with a ``step`` among them, the seven of
+    ``kda_gated_scan``."""
     scale = D ** -0.5
+    ref, fn = (gated_recurrence, kda.kda_gated_scan) if "step" in args \
+        else (recurrence, kda.kda_scan)
     want = value_and_grads(
-        lambda *a: recurrence(*a, scale=scale, heads=heads), args, do)
-    got = value_and_grads(
-        lambda *a: kda.kda_scan(*a, scale=scale, **kw), args, do)
+        lambda *a: ref(*a, scale=scale, heads=heads), args, do)
+    got = value_and_grads(lambda *a: fn(*a, scale=scale, **kw), args, do)
     return got, want
 
 
 @pytest.mark.parametrize("t,chunk", [(150, 64), (64, 64), (40, 64), (96, 16)],
                          ids=["ragged", "one_chunk", "short", "chunk16"])
-def test_kda_scan_is_the_recurrence(t, chunk, route):
-    """o and all five gradients, T a multiple of the chunk or not (150 =
-    2 chunks and 22 tokens: padded; 40 tokens: the kernels pad them to one
-    chunk of 64), decays as strong as the assumed initialisation makes
-    them. A chunk of 16 is the plain form's on either route."""
+def test_kda_scan_is_the_recurrence(t, chunk, route, entry):
+    """o and all five gradients (seven through the gated entry), T a
+    multiple of the chunk or not (150 = 2 chunks and 22 tokens: padded; 40
+    tokens: the kernels pad them to one chunk of 64), decays as strong as
+    the assumed initialisation makes them. A chunk of 16 is the plain
+    form's on either route."""
     args, do = arguments(0, t)
+    if entry == "gated":
+        args = gated(args)
     before = kda.PATH_COUNTS.copy()
     got, want = both(args, do, chunk=chunk)
     took(route, before, chunk)
-    for name, err in worst(got, want).items():
+    for name, err in worst(got, want, args).items():
         assert err < F32_TOL, (name, err)
     assert all(bool(jnp.all(jnp.isfinite(v))) for v in got.values())
 
 
-def test_kda_scan_under_the_strongest_decay(route):
+def test_kda_scan_under_the_strongest_decay(route, entry):
     """g = -20 a token and channel: the cumulative gate of a chunk reaches
     -1280, exp(+1280) is inf in float32, so a factorised exp(G) exp(-G)
     would be NaN. Every exponent here is <= 0: the state is forgotten
     between tokens and o_t = scale beta_t (q_t.k_t) v_t, with every
-    gradient finite."""
+    gradient finite. Through the gated entry: A_log = log 20 and
+    softplus(step + dt_bias) = 1."""
     args, do = arguments(1, 150, gate=-20.0)
+    if entry == "gated":
+        args = gated(args, gate=-20.0)
     before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
     took(route, before)
     for name, v in got.items():
         assert bool(jnp.all(jnp.isfinite(v))), name
     # dg is of the order exp(-20) itself (2e-10 at its largest): held to
-    # zero, not to a share of it
-    assert float(jnp.max(jnp.abs(got["g"] - want["g"]))) < 1e-8
+    # zero, not to a share of it; and so is what the gate's chain rule
+    # makes of it (dstep = 12.6 dg: read 9e-8 from the kernels; dt_bias's
+    # gradient a sum of 300 such, 5e-6, A_log's of 38 400 of 20 dg, 1e-5),
+    # where the other gradients are of order 0.1
+    zero = {"g": 1e-8, "step": 1e-6, "dt_bias": 1e-4, "a_log": 1e-4}
+    for name in zero.keys() & got.keys():
+        assert float(jnp.max(jnp.abs(got[name] - want[name]))) < zero[name], \
+            name
     for name, err in worst(got, want).items():
-        assert name == "g" or err < F32_TOL, (name, err)
+        assert name in zero or err < F32_TOL, (name, err)
     q, k, v = (args[n].reshape(2, 150, 2, D) for n in "qkv")
+    if entry == "gated":
+        q, k = (unit_heads(args[n], 2).reshape(2, 150, 2, D) for n in "qk")
     alone = (D ** -0.5 * args["beta"] * (q * k).sum(-1))[..., None] * v
     np.testing.assert_allclose(got["o"], alone.reshape(2, 150, -1),
                                atol=1e-6)
 
 
-def test_keys_alike_are_solved_in_blocks(monkeypatch, route):
+def test_keys_alike_are_solved_in_blocks(monkeypatch, route, entry):
     """Neighbouring keys alike (k_i . k_j near 0.8), beta 0.9 and a weak
     decay: the chunk's A has entries near 0.7 everywhere under its
     diagonal, and the Neumann product over the WHOLE chunk, whose terms
@@ -189,11 +305,16 @@ def test_keys_alike_are_solved_in_blocks(monkeypatch, route):
         2, 150, 2 * D)
     args["beta"] = jnp.full((2, 150, 2), 0.9)
     args["g"] = args["g"] * 0.05
+    if entry == "gated":        # the keys alike in direction, of any length
+        args = gated(args)
+        args["a_log"] = args["a_log"] + np.log(0.05)
     before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
     took(route, before)
-    for name, err in worst(got, want).items():
+    for name, err in worst(got, want, args).items():
         assert err < F32_TOL, (name, err)
+    if entry == "gated":        # the solve is the one body's, shown once
+        return
     monkeypatch.setattr(kda, "_SUB", 64)        # one block: the whole chunk
     got, _ = both(args, do)
     assert not worst({"o": got["o"]}, {"o": want["o"]})["o"] < 1.0
@@ -210,9 +331,9 @@ def test_chunk_16_equals_chunk_64_up_to_rounding(route):
         assert err < F32_TOL, (name, err)
 
 
-def test_a_wrong_scan_would_fail():
-    """What the tolerance is for: a state kept in bf16, one scalar decay a
-    head, and a rule without its correction each read well over it."""
+def _the_scan_wrong():
+    """A state kept in bf16, one scalar decay a head, and a rule without
+    its correction each read well over the tolerance."""
     args, do = arguments(0, 150)
     scale = D ** -0.5
     right = recurrence(*(args[n] for n in NAMES), scale=scale, heads=2)
@@ -225,29 +346,91 @@ def test_a_wrong_scan_would_fail():
         assert err > at_least > 10 * F32_TOL, (wrong, err)
 
 
-def test_bf16_arguments(route):
-    """The model's call: bf16 q, k, v; g and beta float32."""
+def _the_norms_eps_dropped():
+    """The kernels' prologue with eps = 0: the tokens whose q or k is 0.01
+    long come out of unit length where ``l2norm`` leaves them 0.995 long; o
+    and the gradients through the norm miss the tolerance a hundredfold.
+    (Whole chunks: a padded token's q is 0, and 0 / sqrt(0 + 0) is NaN.)"""
+    args, do = arguments(0, 128)
+    args = gated(args)
+    before = kda.PATH_COUNTS.copy()
+    got, want = both(args, do, eps=0.0)
+    took("kernel", before)
+    err = worst(got, want, args)
+    assert min(err[n] for n in ("o", "q", "k")) > 1e-3 > 10 * F32_TOL, err
+
+
+def _the_softplus_slope_left_out(monkeypatch):
+    """The backward kernel's chain rule without softplus's derivative (a
+    sigmoid): dstep = dg (-exp(A_log)). o is right; step's and dt_bias's
+    gradients are wrong by their own size and more."""
+    whole = kda._gate
+
+    def no_sigmoid(g_ref, rows_ref=None, *, slope=False):
+        g, ds = whole(g_ref, rows_ref, slope=slope)
+        if ds is not None:
+            ds = jnp.broadcast_to(-jnp.exp(rows_ref[...][:1]), ds.shape)
+        return g, ds
+
+    monkeypatch.setattr(kda, "_gate", no_sigmoid)
+    args, do = arguments(0, 150)
+    args = gated(args)
+    got, want = both(args, do)
+    err = worst(got, want, args)
+    assert err["o"] < F32_TOL and err["a_log"] < F32_TOL, err
+    assert min(err["step"], err["dt_bias"]) > 0.5, err
+
+
+@pytest.mark.parametrize("fault", ["scan", "eps_dropped", "slope_left_out"])
+def test_a_wrong_scan_would_fail(fault, monkeypatch):
+    """What the tolerance is for: the recurrence computed wrongly in three
+    ways, and (ISSUE 51) two faults planted in the kernels' prologue, each
+    reads well over it."""
+    if fault == "scan":
+        _the_scan_wrong()
+    elif fault == "eps_dropped":
+        _the_norms_eps_dropped()
+    else:
+        _the_softplus_slope_left_out(monkeypatch)
+
+
+def test_bf16_arguments(route, entry):
+    """The model's call: bf16 q, k, v (and step, through the gated entry:
+    the model's call since ISSUE 51); g and beta, A_log and dt_bias
+    float32."""
     args, do = arguments(3, 150, dtype=jnp.bfloat16)
+    if entry == "gated":
+        args = gated(args)
     before = kda.PATH_COUNTS.copy()
     got, want = both(args, do)
     took(route, before)
     assert got["o"].dtype == jnp.bfloat16
-    for name, err in worst(got, want).items():
+    for name, err in worst(got, want, args).items():
         assert err < 3e-2, (name, err)
 
 
-def test_path_event_and_padding(route):
+def test_path_event_and_padding(route, entry):
+    """The event's facts; ``prologue`` (ISSUE 51) says who made the norms
+    and the gate: the kernels for the gated entry on the kernel route, the
+    plain code everywhere else."""
     from ray_tpu.perf import recorder
 
     before = kda.PATH_COUNTS[route]
     args, _ = arguments(4, 150)
-    jax.eval_shape(lambda *a: kda.kda_scan(*a, scale=1.0),
-                   *(args[n] for n in NAMES))
+    if entry == "gated":
+        args = gated(args)
+        jax.eval_shape(lambda *a: kda.kda_gated_scan(*a, scale=1.0),
+                       *(args[n] for n in GATED))
+    else:
+        jax.eval_shape(lambda *a: kda.kda_scan(*a, scale=1.0),
+                       *(args[n] for n in NAMES))
     assert kda.PATH_COUNTS[route] == before + 1
     events = [e for e in recorder.get_recorder().snapshot()
               if e["kind"] == "rtpu.ops.kda.path"]
     facts = {"route": route, "chunk": 64, "tokens": 150,
-             "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3}
+             "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3,
+             "prologue": "in_kernel" if (entry, route) == ("gated", "kernel")
+             else "jnp"}
     if route == "kernel":
         facts["heads_per_block"] = 2
     assert events and events[-1]["data"] == facts
@@ -256,32 +439,49 @@ def test_path_event_and_padding(route):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32_TOL),
                                        (jnp.bfloat16, 3e-2)],
                          ids=["float32", "bfloat16"])
-def test_the_kernels_gradients_are_the_plain_routes(dtype, tol):
+def test_the_kernels_gradients_are_the_plain_routes(dtype, tol, entry):
     """ISSUE 50: o and the five gradients of the kernel pair against
     ``jax.vjp`` of the plain route, 3 chunks of 2 x 2 heads. With bf16
     arguments o differs by a rounding of bf16 at most (the cumulative
     gates are summed in another order); the gradients differ by the
     rounding of the cotangents the plain form's autodiff casts to bf16 and
-    the backward kernel keeps in float32."""
+    the backward kernel keeps in float32. ISSUE 51, ``gated``: the fused
+    entry's o and SEVEN gradients against ``jax.vjp`` of ``l2norm``, the
+    softplus and the plain route; A_log's and dt_bias's come out of the
+    backward kernel as partial sums a batch row and channel. (The kernels
+    keep the normalised q and k in float32 where the definition rounds
+    them to bf16 first: nearer the recurrence, ``test_bf16_arguments``.)"""
     args, do = arguments(6, 192, dtype=dtype)
     scale = D ** -0.5
+    if entry == "gated":
+        args = gated(args)
+        fn = kda.kda_gated_scan
+
+        def plain(q, k, v, step, a_log, dt_bias, beta):
+            unit = lambda x: unit_heads(x, 2).astype(x.dtype)    # noqa: E731
+            return kda._chunked(unit(q), unit(k), v,
+                                gate_of(step, a_log, dt_bias), beta, 2, 64,
+                                scale)
+    else:
+        fn = kda.kda_scan
+        plain = lambda *a: kda._chunked(*a, 2, 64, scale)        # noqa: E731
     before = kda.PATH_COUNTS.copy()
-    got = value_and_grads(
-        lambda *a: kda.kda_scan(*a, scale=scale), args, do)
+    got = value_and_grads(lambda *a: fn(*a, scale=scale), args, do)
     took("kernel", before)
-    want = value_and_grads(
-        lambda *a: kda._chunked(*a, 2, 64, scale), args, do)
+    want = value_and_grads(plain, args, do)
     want = {n: v.astype(jnp.float32) for n, v in want.items()}
-    for name, err in worst(got, want).items():
+    for name, err in worst(got, want, args).items():
         assert err < (8e-3 if name == "o" and tol > 1e-3 else tol), (name, err)
-    assert got["g"].dtype == got["beta"].dtype == jnp.float32
-    assert got["q"].dtype == got["k"].dtype == got["v"].dtype == dtype
+    gates = ("g", "beta") if entry == "scan" else ("a_log", "dt_bias", "beta")
+    for name in got:
+        assert got[name].dtype == (jnp.float32 if name in gates else dtype)
 
 
-def test_other_shapes_fall_back_to_the_plain_route():
+def test_other_shapes_fall_back_to_the_plain_route(entry):
     """Heads of 64 (two to a 128-lane tile) and a chunk that is not 64 are
     the plain form's, and ``PATH_COUNTS`` says so; three heads of 128 take
-    the kernels (an odd number of heads is solved one by one)."""
+    the kernels (an odd number of heads is solved one by one). The gated
+    entry takes the same route by the same rule."""
     r = jax.random.split(jax.random.PRNGKey(7), 5)
     b, t, h = 1, 64, 2
     for d, chunk, want in ((64, 64, "chunked_jnp"), (128, 32, "chunked_jnp"),
@@ -290,8 +490,13 @@ def test_other_shapes_fall_back_to_the_plain_route():
         g = -jnp.abs(jax.random.normal(r[3], (b, t, h * d))) * 0.1
         beta = jax.nn.sigmoid(jax.random.normal(r[4], (b, t, h)))
         before = kda.PATH_COUNTS.copy()
-        o = jax.eval_shape(lambda *a, c=chunk: kda.kda_scan(
-            *a, scale=1.0, chunk=c), q, k, v, g, beta)
+        if entry == "gated":
+            o = jax.eval_shape(lambda *a, c=chunk: kda.kda_gated_scan(
+                *a, scale=1.0, chunk=c), q, k, v, g, jnp.zeros((h,)),
+                jnp.zeros((h * d,)), beta)
+        else:
+            o = jax.eval_shape(lambda *a, c=chunk: kda.kda_scan(
+                *a, scale=1.0, chunk=c), q, k, v, g, beta)
         assert o.shape == (b, t, h * d)
         took(want, before)
     assert [kda._heads_per_block(n) for n in (1, 2, 3, 6, 32)] == [

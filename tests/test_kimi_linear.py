@@ -18,6 +18,17 @@ every token moves the loss by 2.2e-3, one scalar decay a head (the
 channels' mean) by 3.9e-2, a delta rule without its ``- S^T k`` by 3.6e-3,
 and each moves some logit by 2 to 11 (a token's route flips): each misses
 the limits by a factor of four hundred or more.
+
+ISSUE 51: the model calls ``ops.kda_gated_scan`` with q and k as the
+convolutions left them and the gate projection's step. The tiny preset's
+heads are the published 128 x 128 and the scan's chunk 64, so the fixture
+``tiny`` runs the KERNEL route (interpreted here): the l2 norms and the
+gate are made inside the kernels, and the gradients of ``A_log``,
+``dt_bias``, ``w_f_b``, ``conv_q`` and ``conv_k`` come out of the backward
+kernel's chain rule (``test_what_the_kernels_prologue_differentiates``).
+``plain_route`` is the same model with the scan's route held to
+``chunked_jnp``: ``l2norm``, the softplus and the plain scan, literally.
+Both are held to the same reference by the same limits.
 """
 import importlib
 import importlib.util
@@ -82,6 +93,59 @@ def tiny():
     theirs = jax.jit(jax.value_and_grad(
         lambda p: _nll(_ref_logits(model, p, toks), toks)))(params)
     return model, params, toks, logits, mine, theirs
+
+
+@pytest.fixture(scope="module")
+def plain_route(tiny):
+    """``tiny``'s model, parameters and tokens with the scan's route held to
+    the plain form: (the routes its trace took, its loss and gradients)."""
+    kda = importlib.import_module("ray_tpu.ops.kda_scan")
+    model, params, toks = tiny[:3]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kda, "_route", lambda *shape: "chunked_jnp")
+        before = kda.PATH_COUNTS.copy()
+        mine = jax.jit(jax.value_and_grad(model.loss))(
+            params, toks, jnp.roll(toks, -1, 1))
+        routes = kda.PATH_COUNTS - before
+    return routes, mine
+
+
+def _kda_path_events():
+    from ray_tpu.perf.recorder import get_recorder
+
+    return [e["data"] for e in get_recorder().snapshot()
+            if e["kind"] == "rtpu.ops.kda.path"]
+
+
+PROLOGUE_PARAMS = ("A_log", "dt_bias", "w_f_b", "conv_q", "conv_k")
+
+
+@pytest.mark.parametrize("name", PROLOGUE_PARAMS)
+@pytest.mark.parametrize("route", ["kernel", "chunked_jnp"])
+def test_what_the_kernels_prologue_differentiates(tiny, plain_route, route,
+                                                  name):
+    """ISSUE 51: the parameters whose gradients pass through the norms of q
+    and k and through the gate (made inside the kernels on the kernel
+    route: ``A_log``'s and ``dt_bias``'s are the backward kernel's partial
+    sums, ``w_f_b``'s comes through its dstep, the convolutions' through
+    its dq and dk with respect to the RAW q and k), in every run of KDA
+    layers, against the float32 reference; and the plain route gives the
+    same model."""
+    _, params, _, _, (loss, grads), (ref_loss, ref_grads) = tiny
+    if route == "chunked_jnp":
+        routes, (loss, grads) = plain_route
+        assert set(routes) == {"chunked_jnp"}
+        assert abs(float(loss) - float(ref_loss)) < LOSS_LIMIT
+    else:
+        made = {e["prologue"] for e in _kda_path_events()
+                if e["route"] == "kernel" and e["tokens"] == 150}
+        assert "in_kernel" in made
+    names = [n for n in params if n.endswith("." + name)]
+    assert len(names) == 3          # the runs kda_dense, kda_moe x 2, kda_moe
+    for n in names:
+        g, r = np.asarray(grads[n]), np.asarray(ref_grads[n])
+        assert np.abs(r).max() > 0, n
+        assert np.abs(g - r).max() < GRAD_LIMIT * np.abs(r).max(), n
 
 
 def test_the_stack_is_the_published_order_in_runs(tiny):

@@ -224,9 +224,12 @@ def test_selective_scan_kernel_names_are_pinned(key, name):
 
 
 def test_the_delta_rule_scan_and_its_stack_leave_their_events():
-    """ISSUES 49, 50: ``rtpu.ops.kda.path`` at trace time, once a KDA
+    """ISSUES 49, 50, 51: ``rtpu.ops.kda.path`` at trace time, once a KDA
     layer's body (route, chunk, tokens, heads, padded tokens; the tiny
-    preset's heads of 128 take the kernel pair, two heads a program), and
+    preset's heads of 128 take the kernel pair, two heads a program, and
+    the model's call, ``kda_gated_scan``, has the kernels make the norms
+    of q and k and the gate: ``prologue: in_kernel``; a ``kda_scan`` call
+    with g ready says ``jnp``, ``tests/test_kda_scan.py``), and
     ``rtpu.models.stack.runs`` with the runs the model walked and what each
     keeps (a KDA run its layers' inputs alone). The kernels' names are
     pinned: a device trace and the compiled HLO show them, and
@@ -255,7 +258,7 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
     assert path["data"] == {
         "route": "kernel", "chunk": 64, "tokens": 150,
         "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
-        "chunks": 3, "heads_per_block": 2}
+        "chunks": 3, "heads_per_block": 2, "prologue": "in_kernel"}
     # both kernels stand under the scope the roofline reads
     for name in kda.KERNEL_NAMES.values():
         assert re.search(r"scan/[^\n]*" + name, text), name
