@@ -60,7 +60,8 @@ import jax.numpy as jnp
 from ..ops import (causal_conv1d, cross_entropy_loss, kda_gated_scan,
                    rmsnorm, sigmoid_gated_rmsnorm)
 from .deepseek_v3 import held_expert_sublayer, latent_attention
-from .stack import period_runs, run_params, walk_stack
+from .stack import (draw_params, period_runs, run_params,
+                    vocab_row_shardings, walk_stack)
 
 # What a rematerialised layer keeps for its backward beside its input, by
 # ``checkpoint_name``: in a latent-attention layer the flash kernels' output
@@ -244,40 +245,13 @@ class KimiLinear:
         initialisation: the decays exp(g) run from 0.2 to 0.999 a token),
         the convolutions uniform in +-1/sqrt(taps) (a depthwise conv1d's
         default)."""
-        c, pd = self.config, self.config.param_dtype
-        shapes = self._shapes()
-        keys = jax.random.split(rng, len(shapes))
-
-        def draw(key, shape, how):
-            if how is None:
-                return jnp.ones(shape, pd)
-            if how == "A_log":
-                return jnp.log(jax.random.uniform(key, shape, pd, 1.0, 16.0))
-            if how == "dt_bias":
-                dt = jnp.exp(jax.random.uniform(
-                    key, shape, pd, math.log(1e-3), math.log(0.1)))
-                return dt + jnp.log(-jnp.expm1(-dt))
-            if how == "conv":
-                bound = 1.0 / math.sqrt(c.kda_d_conv)
-                return jax.random.uniform(key, shape, pd, -bound, bound)
-            return jax.random.normal(key, shape, pd) * how
-
-        return {n: draw(k, shape, how)
-                for k, (n, (shape, how)) in zip(keys, shapes.items())}
+        c = self.config
+        return draw_params(self._shapes(), rng, c.param_dtype, c.kda_d_conv)
 
     def param_shardings(self, mesh, rules=None):
-        """Replicated but for the vocabulary's rows: this model is one
-        chip's share of an expert-parallel job (the experts it holds are
-        its own), so no axis of the mesh cuts a layer."""
-        from jax.sharding import NamedSharding
-
-        from ..parallel.mesh import AxisRules
-
-        rules = rules or AxisRules()
-        return {n: NamedSharding(mesh, rules.mesh_axes(
-            ("vocab", "embed") if n in ("wte", "lm_head")
-            else (None,) * len(shape)))
-            for n, (shape, _) in self._shapes().items()}
+        """Replicated but for the vocabulary's rows
+        (``stack.vocab_row_shardings``)."""
+        return vocab_row_shardings(self._shapes(), mesh, rules)
 
     def num_params(self) -> int:
         return sum(math.prod(shape) for shape, _ in self._shapes().values())

@@ -23,6 +23,10 @@ its turns would overwrite.
 
 Which runs a trace walked, what each keeps and the side state's bytes is
 the event ``rtpu.models.stack.runs``.
+
+``draw_params`` and ``vocab_row_shardings`` are what the delta-rule families
+on the walker (``kimi_linear.py``, ``qwen3_next.py``) share of ``init`` and
+``param_shardings``: one table name -> (shape, how it is drawn) a model.
 """
 from __future__ import annotations
 
@@ -30,6 +34,7 @@ import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from ..perf.recorder import record as _record
 
@@ -74,6 +79,51 @@ def run_params(params: Dict[str, jax.Array], run: int) -> Dict[str, Dict]:
             kind, leaf = name[len(prefix):].split(".", 1)
             out.setdefault(kind, {})[leaf] = v
     return out
+
+
+def draw_params(shapes: Dict[str, Tuple[Tuple[int, ...], Any]],
+                rng: jax.Array, dtype, conv_taps: int) -> Dict[str, jax.Array]:
+    """The flat parameter dict of a model on this walker from its table
+    name -> (shape, how it is drawn): a std of a normal draw, None for
+    ones, 0.0 for zeros, or one of the three rules of a delta-rule layer:
+    ``A_log`` the log of a uniform draw in [1, 16], ``dt_bias`` the inverse
+    softplus of a dt drawn log-uniformly in [0.001, 0.1] (the family's
+    public initialisation: the decays exp(g) run from 0.2 to 0.999 a
+    token), ``conv`` uniform in +-1/sqrt(taps) (a depthwise conv1d's
+    default). One key of ``rng`` a name, in the table's order."""
+    def draw(key, shape, how):
+        if how is None:
+            return jnp.ones(shape, dtype)
+        if how == "A_log":
+            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+        if how == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(
+                key, shape, dtype, math.log(1e-3), math.log(0.1)))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        if how == "conv":
+            bound = 1.0 / math.sqrt(conv_taps)
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+        return jax.random.normal(key, shape, dtype) * how
+
+    keys = jax.random.split(rng, len(shapes))
+    return {n: draw(k, shape, how)
+            for k, (n, (shape, how)) in zip(keys, shapes.items())}
+
+
+def vocab_row_shardings(shapes: Dict[str, Tuple[Tuple[int, ...], Any]], mesh,
+                        rules=None):
+    """Replicated but for the vocabulary's rows (``wte``, ``lm_head``): a
+    model that is one chip's share of an expert-parallel job (the experts
+    it holds are its own), so no axis of the mesh cuts a layer."""
+    from jax.sharding import NamedSharding
+
+    from ..parallel.mesh import AxisRules
+
+    rules = rules or AxisRules()
+    return {n: NamedSharding(mesh, rules.mesh_axes(
+        ("vocab", "embed") if n in ("wte", "lm_head")
+        else (None,) * len(shape)))
+        for n, (shape, _) in shapes.items()}
 
 
 def walk_stack(x: jax.Array, runs: List[Tuple[Period, int]],

@@ -16,6 +16,10 @@ legend); for a TPU-native framework the hot ops are first-party:
   128-lane tile, else plain jnp under one ``lax.scan``. kda_gated_scan:
   the same from what a KDA layer's convolutions and gate projection made
   (the l2 norms of q and k and the gate's softplus inside the kernels).
+  gated_delta_scan: the delta rule with ONE decay a head and several
+  value heads to a key head (Gated DeltaNet), the plain chunked form with
+  the decay factored out; gdn_gated_scan: a Gated DeltaNet layer's call,
+  through KDA's kernel pair where a head is one 128-lane tile.
 - selective_scan: Mamba-1's recurrence (a decay for every channel AND
   state), walked in chunks on the VPU with the state in VMEM, forward and
   backward kernels (Pallas).
@@ -25,7 +29,8 @@ legend); for a TPU-native framework the hot ops are first-party:
   plain jnp form for shapes its tile cannot take; every coefficient
   tokens-minor.
 - layers: rmsnorm/layernorm/gelu/rope (plain and YaRN)/cross-entropy,
-  the causal depthwise convolution, the gated norms and a head's l2 norm
+  the causal depthwise convolution, the gated norms (the gate before the
+  norm, or after it under sigmoid or SiLU) and a head's l2 norm
   in plain jnp, shaped so XLA fuses them into the adjacent matmuls.
 - paged_attention: reads and writes of the serving engine's block-pool
   KV cache.
@@ -38,9 +43,10 @@ from .flash_attention import flash_attention
 from .ring_attention import ring_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d, gated_rmsnorm,
-                     l2norm, sigmoid_gated_rmsnorm)
+                     l2norm, rmsnorm_then_gate, sigmoid_gated_rmsnorm)
 from .ssd_scan import ssd_scan
-from .kda_scan import kda_gated_scan, kda_scan
+from .kda_scan import (gated_delta_scan, gdn_gated_scan, kda_gated_scan,
+                       kda_scan)
 from .selective_scan import selective_scan
 from .hyper_connection import hc_coefficients, hc_mix, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
@@ -51,8 +57,8 @@ __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "l2norm",
-    "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan", "kda_gated_scan",
-    "selective_scan",
+    "rmsnorm_then_gate", "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan",
+    "kda_gated_scan", "gated_delta_scan", "gdn_gated_scan", "selective_scan",
     "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",

@@ -267,16 +267,27 @@ pairs_to_rows.defvjp(
 # ---------------------------------------------------------------------------
 
 
-def route(x, w_router, bias, *, top_k: int, routed_scale: float):
-    """DeepSeek-V3's router without group limiting (``n_group`` 1):
-    s = sigmoid(x W) in f32; the k experts are the top k of s + bias (the
-    selection bias is a buffer: no gradient reaches it); their weights
-    are s over the chosen k, summing to 1, times ``routed_scale``.
+def route(x, w_router, bias, *, top_k: int, routed_scale: float,
+          score: str = "sigmoid"):
+    """The router, by how it scores (a static fact of the model's
+    configuration). ``sigmoid``: DeepSeek-V3's without group limiting
+    (``n_group`` 1): s = sigmoid(x W) in f32; the k experts are the top k
+    of s + bias (the selection bias is a buffer: no gradient reaches it).
+    ``softmax``: s = softmax(x W) over ALL experts in f32, the k largest,
+    no bias (``bias`` None). Either way their weights are s over the
+    chosen k, summing to 1, times ``routed_scale``.
     x [T, D] -> (weights [T, k] f32, experts [T, k] int32)."""
-    scores = jax.nn.sigmoid(jnp.dot(x, w_router.astype(x.dtype),
-                                    preferred_element_type=jnp.float32))
-    _, chosen = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    logits = jnp.dot(x, w_router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, top_k)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    else:
+        raise ValueError(f"score is sigmoid or softmax, got {score!r}")
     # the chosen scores by comparison, not by index: the gradient of a
     # gather is a scatter into [T, E]
     s = jnp.sum(jnp.where(
@@ -346,16 +357,19 @@ def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
 
 
 def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
-                      top_k: int, routed_scale: float, tile: int = ROW_TILE):
+                      top_k: int, routed_scale: float, tile: int = ROW_TILE,
+                      score: str = "sigmoid"):
     """x [T, D] (normalised) -> (shared(x) + the held experts' part of
     sum_e w_e expert_e(x), [T, D] in x's dtype; the (token, choice) pairs
     that named a held expert, i.e. the rows the grouped product worked).
 
-    ``p``: ``w_router`` [D, E] over ALL E experts and ``router_bias`` [E];
+    ``p``: ``w_router`` [D, E] over ALL E experts and, where ``score`` is
+    ``sigmoid``, ``router_bias`` [E] (``route``);
     ``e_gate``, ``e_up`` [held, D, F], ``e_down`` [held, F, D] of the
     experts ``expert_offset`` .. ``expert_offset + experts_held``;
     ``s_gate``, ``s_up`` [D, Fs], ``s_down`` [Fs, D] of the shared experts
-    (side by side, one gated MLP)."""
+    (side by side, one gated MLP); where it holds ``s_gate_w`` [D, 1] the
+    shared experts' output is times ``sigmoid(x s_gate_w)``, f32."""
     t, d = x.shape
     dt = x.dtype
     n_experts = p["w_router"].shape[1]
@@ -364,13 +378,20 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     _record("rtpu.ops.expert_layer", "held",
             {"experts_held": experts_held, "of": n_experts, "top_k": top_k,
              "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
-             "row_tile": tile})
+             "row_tile": tile, "score": score,
+             "shared_gate": "s_gate_w" in p})
     with jax.named_scope("shared_expert"):
         shared = _gated(x, p["s_gate"].astype(dt), p["s_up"].astype(dt),
                         p["s_down"].astype(dt), jnp.dot)
+        if "s_gate_w" in p:
+            opened = jax.nn.sigmoid(jnp.dot(
+                x, p["s_gate_w"].astype(dt),
+                preferred_element_type=jnp.float32))
+            shared = (shared.astype(jnp.float32) * opened).astype(dt)
     with jax.named_scope("router"):
-        weights, chosen = route(x, p["w_router"], p["router_bias"],
-                                top_k=top_k, routed_scale=routed_scale)
+        weights, chosen = route(x, p["w_router"], p.get("router_bias"),
+                                top_k=top_k, routed_scale=routed_scale,
+                                score=score)
         at = sort_rows(chosen, experts_held, expert_offset, rows, tile)
         held_rows = at.pop("held_rows")
         buf = tokens_to_rows(x, at)

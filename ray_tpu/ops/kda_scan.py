@@ -1,6 +1,8 @@
 """Kimi Delta Attention's recurrence (KDA: a gated delta rule with a decay
 for every key channel) as a chunked scan: a Pallas kernel pair, and the
-plain ``jax.numpy`` form that defines it.
+plain ``jax.numpy`` form that defines it; and Gated DeltaNet's (the same
+rule with ONE decay a head and several value heads to a key head), its own
+plain form and a route through the same kernel pair.
 
 Per head (state S [d_k, d_v] float32 from zero; q_t, k_t [d_k], v_t [d_v],
 g_t [d_k] <= 0 the log of the token's decay a key channel, beta_t in (0, 1)
@@ -52,9 +54,39 @@ thirteen orders of magnitude, blocks of 32 by a tenth, of 16 by 5e-5, of 8
 by 1e-6 (``tests/test_kda_scan.py``). The solve's backward is its own
 (``dA = -T^T dT T^T``), not autodiff's through the products.
 
-Two entries. ``kda_scan(q, k, v, g, beta)`` is the recurrence above, q and
-k as the caller normalised them and g ready: the definition everything here
-is tested against. ``kda_gated_scan(q, k, v, step, a_log, dt_bias, beta)``
+**One decay a head (Gated DeltaNet).** Where g_t is one number a head the
+decay is a scalar a token, e^{G_i - G_j} comes out of the contraction over
+c, and the chunk's matrices are (G [C] a value head, K K^T and Q K^T made
+ONCE a key head, R value heads reading it):
+
+    A_ij  = beta_i (k_i . k_j) exp(G_i - G_j)            i > j, else 0
+    Q_ij  = (q_i . k_j) exp(G_i - G_j)                   i >= j
+    T, W, U, V', O as above with exp(G) a scalar a row
+    S_end = exp(G_C) S_0 + (k exp(G_C - G))^T V'
+
+one product a chunk and a [C, C] table of decays a value head in place of
+the sub-blocks of 8 rows: ``_head_decay_chunk_body``, the state [Hk, R,
+d_k, d_v]. Every exponent is still a later cumulative sum less an earlier
+one. ``gated_delta_scan(q, k, v, g, beta)`` is that definition (g and beta
+[B, T, Hv], q and k [B, T, Hk x d]); it always takes the plain route.
+
+Which entry a layer calls. A KDA layer (``models/kimi_linear.py``):
+``kda_gated_scan``. A Gated DeltaNet layer (``models/qwen3_next.py``):
+``gdn_gated_scan(q, k, v, a, a_log, dt_bias, beta)``, which on the plain
+route is ``l2norm`` a key head, g = -exp(a_log) softplus(a + dt_bias) a
+value head and ``gated_delta_scan``, and on the kernel route is the KDA
+pair below UNCHANGED: q and k repeated to the value heads, ``a`` spread
+over a head's 128 lanes as the prologue's step, a_log and dt_bias as its
+two rows (a decay that is constant over a head's lanes is a case of the
+one the kernels compute). The gradients come back through that spread and
+repeat by autodiff: dq and dk summed over a key head's value heads, da
+over a head's lanes. ``kda_scan`` and ``gated_delta_scan`` are the two
+definitions everything is tested against. The event's facts ``decay``
+(``channel`` or ``head``) and ``key_heads`` say which rule a call was.
+
+KDA's two entries. ``kda_scan(q, k, v, g, beta)`` is the recurrence above,
+q and k as the caller normalised them and g ready: the definition everything
+here is tested against. ``kda_gated_scan(q, k, v, step, a_log, dt_bias, beta)``
 is a KDA layer's call: q and k as its convolutions' SiLU left them, ``step``
 as its gate projection left it, and
 
@@ -290,19 +322,34 @@ def _chunk_body(state, xs, *, scale: float):
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
     t = _unit_lower_inverse(
         jnp.where(strict, sk * beta[..., None], 0.0)).astype(dt)
-    kept, held = jnp.exp(cum), state.astype(dt)    # decay since the chunk began
-    bk = (k.astype(_F32) * beta[..., None] * kept).astype(dt)
-    bv = (v.astype(_F32) * beta[..., None]).astype(dt)
-    w = _ein("bhij,bhjd->bhid", t, bk).astype(dt)
-    u = _ein("bhij,bhjd->bhid", t, bv)                       # f32
-    wrote = (u - _ein("bhid,bhde->bhie", w, held)).astype(dt)
-    q_in = (q.astype(_F32) * kept).astype(dt)
-    o = _ein("bhid,bhde->bhie", q_in, held) \
-        + _ein("bhij,bhje->bhie", sq.astype(dt), wrote)
     last = cum[:, :, -1:, :]                                 # [b, h, 1, d]
-    k_end = (k.astype(_F32) * jnp.exp(last - cum)).astype(dt)
-    state = kept[:, :, -1, :, None] * state + _ein(
-        "bhid,bhie->bhde", k_end, wrote)
+    return _chunk_tail(state, t, sq, q, k, v, beta[..., None], jnp.exp(cum),
+                       jnp.exp(last - cum), jnp.exp(last)[:, :, 0, :, None],
+                       scale)
+
+
+def _chunk_tail(state, t, sq, q, k, v, beta, kept, to_end, at_end,
+                scale: float):
+    """What a chunk does once its solve T and its decayed query-key scores
+    ``sq`` are made, whatever the decay's shape: W, U, what each token
+    writes, the output and the state after the chunk. ``kept`` = exp(G)
+    (the decay since the chunk began) and ``to_end`` = exp(G_C - G), [..,
+    C, d_k] for a decay a key channel or [.., C, 1] for one a head;
+    ``at_end`` = exp(G_C), [.., d_k, 1] or [.., 1, 1]; beta [.., C, 1]; q
+    and k broadcast against them. -> (state, o [.., C, d_v])."""
+    dt = q.dtype
+    held = state.astype(dt)
+    kf, qf = k.astype(_F32), q.astype(_F32)
+    bk = (kf * beta * kept).astype(dt)
+    bv = (v.astype(_F32) * beta).astype(dt)
+    w = _ein("...ij,...jd->...id", t, bk).astype(dt)
+    u = _ein("...ij,...jd->...id", t, bv)                    # f32
+    wrote = (u - _ein("...id,...de->...ie", w, held)).astype(dt)
+    q_in = (qf * kept).astype(dt)
+    o = _ein("...id,...de->...ie", q_in, held) \
+        + _ein("...ij,...je->...ie", sq.astype(dt), wrote)
+    k_end = (kf * to_end).astype(dt)
+    state = at_end * state + _ein("...id,...ie->...de", k_end, wrote)
     return state, (o * scale).astype(dt)
 
 
@@ -320,6 +367,60 @@ def _chunked(q, k, v, g, beta, heads: int, chunk: int, scale: float):
     body = jax.checkpoint(functools.partial(_chunk_body, scale=scale))
     _, o = jax.lax.scan(body, jnp.zeros((b, heads, dk, dv), _F32), xs)
     return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, t, heads * dv)
+
+
+def _head_decay_chunk_body(state, xs, *, scale: float):
+    """One chunk of the delta rule with ONE decay a value head and token.
+    state [B, Hk, R, d_k, d_v] f32 (R value heads to a key head); xs = (q,
+    k [B, Hk, C, d_k], v [B, Hk, R, C, d_v], g, beta [B, Hk, R, C] f32) ->
+    (the state after the chunk, o [B, Hk, R, C, d_v]). The decay factors
+    out of the contraction over the key channels: the scores are ONE
+    product a key head and a [C, C] table of decays a value head."""
+    q, k, v, g, beta = xs
+    dt = q.dtype
+    chunk = q.shape[2]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    cum = jnp.einsum("ij,bhrj->bhri", lower.astype(_F32), g,
+                     precision=jax.lax.Precision.HIGHEST)
+    decay = jnp.exp(jnp.where(
+        lower, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    sk = _ein("bhid,bhjd->bhij", k, k)[:, :, None] * decay   # [b, h, r, C, C]
+    sq = _ein("bhid,bhjd->bhij", q, k)[:, :, None] * decay
+    t = _unit_lower_inverse(jnp.where(
+        jnp.tril(lower, -1), sk * beta[..., None], 0.0)).astype(dt)
+    last = cum[..., -1:]                                     # [b, h, r, 1]
+    return _chunk_tail(state, t, sq, q[:, :, None], k[:, :, None], v,
+                       beta[..., None], jnp.exp(cum)[..., None],
+                       jnp.exp(last - cum)[..., None],
+                       jnp.exp(last)[..., None], scale)
+
+
+def _head_decay_chunked(q, k, v, g, beta, key_heads: int, chunk: int,
+                        scale: float):
+    """Whole chunks of merged arrays, q, k [B, T, Hk*d_k], v [B, T,
+    Hv*d_v], g, beta [B, T, Hv] -> o [B, T, Hv*d_v]; value head j reads
+    key head j // (Hv / Hk)."""
+    b, t, _ = q.shape
+    group = g.shape[-1] // key_heads
+
+    def chunks_first(x, *heads):     # [B, T, ..] -> [chunks, B, *heads, C, ..]
+        n = len(heads)
+        x = x.reshape(b, t // chunk, chunk, *heads, -1)
+        return jnp.moveaxis(x, (1,) + tuple(range(3, 3 + n)),
+                            (0,) + tuple(range(2, 2 + n)))
+
+    xs = (chunks_first(q, key_heads), chunks_first(k, key_heads),
+          chunks_first(v, key_heads, group),
+          chunks_first(g, key_heads, group)[..., 0],
+          chunks_first(beta, key_heads, group)[..., 0])
+    dk, dv = xs[1].shape[-1], xs[2].shape[-1]
+    body = jax.checkpoint(functools.partial(_head_decay_chunk_body,
+                                            scale=scale))
+    _, o = jax.lax.scan(
+        body, jnp.zeros((b, key_heads, group, dk, dv), _F32), xs)
+    # [chunks, B, Hk, R, C, d_v] -> [B, T, Hv * d_v]
+    return jnp.moveaxis(o, (0, 4), (1, 2)).reshape(
+        b, t, key_heads * group * dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1041,10 +1142,25 @@ def _route(d_k: int, d_v: int, chunk: int) -> str:
     return "kernel" if d_k == d_v == _LANES and chunk == 64 else "chunked_jnp"
 
 
-def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
-    """What both entries share: the route by what the call shows, whole
-    chunks, the path event. ``gate``: (g,) with ``eps`` None, or the
-    prologue's (step, rows) on the kernel route."""
+def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):
+    """The facts of ``rtpu.ops.kda.path`` that every entry states.
+    ``key_heads`` None: a decay a key channel, a key head a value head."""
+    return {"chunk": chunk, "tokens": tokens, "padded_tokens": pad,
+            "heads": heads, "d_k": d_k, "d_v": d_v,
+            "chunks": (tokens + pad) // chunk,
+            "prologue": "jnp" if eps is None else "in_kernel",
+            "decay": "channel" if key_heads is None else "head",
+            "key_heads": key_heads or heads}
+
+
+def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps,
+          key_heads=None):
+    """What KDA's two entries and Gated DeltaNet's kernel route share: the
+    route by what the call shows, whole chunks, the path event. ``gate``:
+    (g,) with ``eps`` None, or the prologue's (step, rows) on the kernel
+    route. ``key_heads``: None for a decay a key channel; the key heads
+    the caller repeated to the value heads for a decay a head (a fact of
+    the event, no part of the computation: q and k come repeated)."""
     b, t, _ = q.shape
     heads = beta.shape[-1]
     d_k, d_v = k.shape[-1] // heads, v.shape[-1] // heads
@@ -1054,10 +1170,7 @@ def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
     (q, k, v, lead, beta), pad = pad_tokens(
         (q, k, v, gate[0], beta.astype(_F32)), chunk)
     gate = (lead,) + tuple(gate[1:])
-    facts = {"chunk": chunk, "tokens": t, "padded_tokens": pad,
-             "heads": heads, "d_k": d_k, "d_v": d_v,
-             "chunks": (t + pad) // chunk,
-             "prologue": "jnp" if eps is None else "in_kernel"}
+    facts = _path_facts(chunk, t, pad, heads, d_k, d_v, eps, key_heads)
     if route == "kernel":
         hpb = facts["heads_per_block"] = _heads_per_block(heads)
     record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
@@ -1108,3 +1221,77 @@ def kda_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     g = -jnp.repeat(jnp.exp(a_log), d_k) * jax.nn.softplus(
         step.astype(_F32) + dt_bias)
     return kda_scan(unit(q), unit(k), v, g, beta, scale=scale, chunk=chunk)
+
+
+def gated_delta_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, *, scale: float,
+                     chunk: int = 64) -> jax.Array:
+    """The gated delta rule with ONE decay a head (Gated DeltaNet): the
+    plain definition, in the chunked form with the decay factored out
+    (the module's docstring). q, k [batch, seq, key_heads * d] as the
+    caller normalised them, v [batch, seq, value_heads * d], g (<= 0, the
+    log decay, float32) and beta [batch, seq, value_heads]; keys and
+    values share the head size d (which is how the key heads are known
+    from merged arrays), value_heads is a multiple of key_heads and value
+    head j reads key head j // (value_heads / key_heads) -> o [batch, seq,
+    value_heads * d] in q's dtype. Differentiable in all five. Always the
+    route ``chunked_jnp``; ``chunk`` is how the work is cut, not what is
+    computed."""
+    t = q.shape[1]
+    heads = beta.shape[-1]
+    d = v.shape[-1] // heads
+    key_heads = k.shape[-1] // d
+    if heads % key_heads or q.shape[-1] != k.shape[-1]:
+        raise ValueError(f"{heads} value heads over {key_heads} key heads "
+                         f"of {d}: q {q.shape}, k {k.shape}, v {v.shape}")
+    chunk = min(chunk, t)
+    (q, k, v, g, beta), pad = pad_tokens(
+        (q, k, v, g.astype(_F32), beta.astype(_F32)), chunk)
+    record_path("rtpu.ops.kda.path", PATH_COUNTS, "chunked_jnp",
+                _path_facts(chunk, t, pad, heads, d, d, None, key_heads))
+    return _head_decay_chunked(q, k, v, g, beta, key_heads, chunk,
+                               float(scale))[:, :t]
+
+
+def gdn_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
+                   a_log: jax.Array, dt_bias: jax.Array, beta: jax.Array, *,
+                   scale: float, eps: float = 1e-6,
+                   chunk: int = 64) -> jax.Array:
+    """A Gated DeltaNet layer's scan from what its convolution and its
+    ``b | a`` projection made: ``gated_delta_scan`` of q and k each
+    normalised to unit length a key head (``layers.l2norm`` with ``eps``)
+    and of
+
+        g = -exp(a_log) * softplus(a + dt_bias)      float32, a value head
+
+    q, k [batch, seq, key_heads * d], v [batch, seq, value_heads * d], a
+    and beta [batch, seq, value_heads], a_log and dt_bias [value_heads].
+    Differentiable in all seven arrays. That sentence is this function on
+    the plain route, literally. On the kernel route (heads of 128, chunk
+    64) it is KDA's kernel pair unchanged, a decay that is constant over a
+    head's lanes being a case of the one they compute: q and k repeated to
+    the value heads, ``a`` spread over a head's 128 lanes as the
+    prologue's ``step``, ``a_log`` and ``dt_bias`` as its two rows; the
+    norms and the gate are made in the kernels and no float32 g is
+    written. (A body of the pair for the scalar decay would read q and k
+    once a key head and make the scores in one product: ROADMAP A.)"""
+    heads = beta.shape[-1]
+    d = v.shape[-1] // heads
+    key_heads = k.shape[-1] // d
+    group = heads // key_heads
+    a_log, dt_bias = a_log.astype(_F32), dt_bias.astype(_F32)
+    if _route(d, d, chunk) == "kernel":
+        def to_value_heads(x):
+            return jnp.repeat(x.reshape(*x.shape[:2], key_heads, d), group,
+                              axis=2).reshape(*x.shape[:2], heads * d)
+
+        lanes = lambda x: jnp.repeat(x, d, axis=-1)          # noqa: E731
+        return _scan(to_value_heads(q), to_value_heads(k), v,
+                     (lanes(a), jnp.stack([lanes(a_log), lanes(dt_bias)])),
+                     beta, scale=scale, chunk=chunk, eps=float(eps),
+                     key_heads=key_heads)
+    unit = lambda x: l2norm(                                 # noqa: E731
+        x.reshape(*x.shape[:2], key_heads, d), eps).reshape(x.shape)
+    g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(_F32) + dt_bias)
+    return gated_delta_scan(unit(q), unit(k), v, g, beta, scale=scale,
+                            chunk=chunk)

@@ -14,12 +14,17 @@ import jax
 import jax.numpy as jnp
 
 
-def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6, *,
+            plus_one: bool = False) -> jax.Array:
+    """x / sqrt(mean(x^2) + eps) * weight over the last axis, statistics
+    in f32; with ``plus_one`` the gain is ``1 + weight``, the weight drawn
+    from zero (the ``qwen3_next`` form)."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32)).astype(dtype)
+    w = weight.astype(jnp.float32)
+    return (y * (1.0 + w if plus_one else w)).astype(dtype)
 
 
 def layernorm(x: jax.Array, weight: jax.Array, bias: jax.Array,
@@ -94,9 +99,16 @@ def yarn_softmax_scale(qk_head_dim: int, factor: float,
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                positions: Optional[jax.Array] = None) -> jax.Array:
-    """Rotary embedding. x: [B, S, H, D]; cos/sin: [S_max, D/2];
-    positions: [B, S] overrides the default arange (decode steps)."""
+    """Rotary embedding. x: [B, S, H, D]; cos/sin: [S_max, R/2], R <= D
+    the channels that turn: the first R of a head, channel i paired with
+    i + R/2, the other D - R as they are (``partial_rotary_factor``; the
+    tables' width says how many). positions: [B, S] overrides the default
+    arange (decode steps)."""
     dtype = x.dtype
+    rotary = 2 * cos.shape[-1]
+    rest = None
+    if rotary != x.shape[-1]:
+        x, rest = x[..., :rotary], x[..., rotary:]
     if positions is not None:
         c = cos[positions]          # [B, S, D/2]
         s = sin[positions]
@@ -107,7 +119,8 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     s = s[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rot = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-    return rot.astype(dtype)
+    rot = rot.astype(dtype)
+    return rot if rest is None else jnp.concatenate([rot, rest], axis=-1)
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
@@ -161,12 +174,20 @@ def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
         jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps)).astype(x.dtype)
 
 
-def sigmoid_gated_rmsnorm(y: jax.Array, gate: jax.Array, weight: jax.Array,
-                          eps: float = 1e-6) -> jax.Array:
-    """rmsnorm(y) * weight * sigmoid(gate) over the last axis: the norm
+def rmsnorm_then_gate(y: jax.Array, gate: jax.Array, weight: jax.Array,
+                      eps: float = 1e-6, *,
+                      activation=jax.nn.sigmoid) -> jax.Array:
+    """rmsnorm(y) * weight * activation(gate) over the last axis: the norm
     first, THEN the gate (``gated_rmsnorm`` gates by SiLU before the
-    norm), statistics in f32."""
+    norm), statistics in f32. Kimi Delta Attention gates by the sigmoid,
+    Gated DeltaNet by SiLU."""
     yf = y.astype(jnp.float32)
     var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
     normed = yf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
-    return (normed * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(y.dtype)
+    return (normed * activation(gate.astype(jnp.float32))).astype(y.dtype)
+
+
+def sigmoid_gated_rmsnorm(y: jax.Array, gate: jax.Array, weight: jax.Array,
+                          eps: float = 1e-6) -> jax.Array:
+    """``rmsnorm_then_gate`` under the sigmoid."""
+    return rmsnorm_then_gate(y, gate, weight, eps)

@@ -394,6 +394,84 @@ def test_no_token_is_dropped_whatever_the_routing(favoured, rows):
         jnp.abs(g_want).max()) + 1e-7
 
 
+def _softmax_layer(x, lp, top_k, expert_offset=0, gated=True):
+    """ISSUE 52's expert sublayer by hand: softmax over ALL experts, the
+    top k, weights over the chosen k, no bias, no scale; the shared expert
+    times sigmoid(x w_sg) -> (shared, the held experts' part)."""
+    p = jax.nn.softmax(x @ lp["w_router"], -1)
+    picked, chosen = jax.lax.top_k(p, top_k)
+    w = picked / picked.sum(-1, keepdims=True)
+    mlp = lambda g, u, d: (jax.nn.silu(x @ g) * (x @ u)) @ d   # noqa: E731
+    routed = jnp.zeros_like(x)
+    for e in range(lp["e_gate"].shape[0]):
+        w_e = jnp.where(chosen == e + expert_offset, w, 0.0).sum(-1)
+        routed += w_e[:, None] * mlp(lp["e_gate"][e], lp["e_up"][e],
+                                     lp["e_down"][e])
+    shared = mlp(lp["s_gate"], lp["s_up"], lp["s_down"])
+    if gated:
+        shared = shared * jax.nn.sigmoid(x @ lp["s_gate_w"])
+    return shared, routed
+
+
+@pytest.mark.parametrize("held,offset,gated", [(8, 0, True), (2, 4, False)],
+                         ids=["whole-gated", "share-ungated"])
+def test_the_softmax_route_and_the_gated_shared_expert(held, offset, gated):
+    """ISSUE 52: ``score="softmax"`` (no selection bias in the layer's
+    parameters at all) and ``s_gate_w`` in them, against the layer by hand:
+    the output, the held rows, and the gradients of the input, the router
+    and the shared expert's gate."""
+    c = DeepseekV3Config.tiny(**F32)                      # 8 experts, top 3
+    lp = _share(_layer_params(DeepseekV3(c)), held, offset)
+    del lp["router_bias"]
+    if gated:
+        lp["s_gate_w"] = 0.3 * jax.random.normal(jax.random.PRNGKey(8),
+                                                 (c.d_model, 1))
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, c.d_model))
+    kw = dict(experts_held=held, expert_offset=offset, top_k=3,
+              routed_scale=1.0, score="softmax", tile=8)
+
+    def mine(x, lp):
+        return held_expert_layer(x, lp, **kw)[0]
+
+    def theirs(x, lp):
+        return sum(_softmax_layer(x, lp, 3, offset, gated))
+
+    y, n = held_expert_layer(x, lp, **kw)
+    assert float(jnp.abs(y - theirs(x, lp)).max()) < 1e-6
+    p = jax.nn.softmax(x @ lp["w_router"], -1)
+    chosen = jax.lax.top_k(p, 3)[1]
+    assert int(n) == int(((chosen >= offset) & (chosen < offset + held)).sum())
+    if held == 8:
+        assert int(n) == 64 * 3
+    g = jax.grad(lambda x, lp: mine(x, lp).sum(), argnums=(0, 1))(x, lp)
+    g_want = jax.grad(lambda x, lp: theirs(x, lp).sum(), argnums=(0, 1))(x, lp)
+    names = ("w_router", "s_down") + (("s_gate_w",) if gated else ())
+    for got, want in [(g[0], g_want[0])] + [(g[1][k], g_want[1][k])
+                                            for k in names]:
+        assert float(jnp.abs(want).max()) > 0
+        assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+            jnp.abs(want).max())
+
+
+def test_the_softmax_weights_sum_to_one_over_the_chosen():
+    """``norm_topk_prob``: the k weights sum to 1 whatever the scores, the
+    chosen are the k largest of the softmax (of the logits), and a
+    sigmoid-scored call still needs its bias."""
+    from ray_tpu.ops.expert_layer import route
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (32, 16))
+    w_r = jax.random.normal(jax.random.PRNGKey(1), (16, 12))
+    w, chosen = route(x, w_r, None, top_k=4, routed_scale=1.0,
+                      score="softmax")
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(chosen), -1),
+        np.sort(np.asarray(jax.lax.top_k(x @ w_r, 4)[1]), -1))
+    assert bool((w[:, :-1] >= w[:, 1:]).all())       # largest first
+    with pytest.raises(ValueError, match="sigmoid or softmax"):
+        route(x, w_r, None, top_k=4, routed_scale=1.0, score="tanh")
+
+
 def test_the_row_buffer_is_static_and_covers_the_worst_case():
     assert buffer_rows(64, 3, 4, 8) == 64 * 3 + 4 * 8
     assert buffer_rows(16384, 6, 16) == (16384 * 6 // 256 + 16) * 256

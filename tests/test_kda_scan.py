@@ -36,6 +36,13 @@ those sums ADD UP in magnitude (``added``), as a sum in another order can
 be, not of the sum that is left (against float64 the plain route's own
 ``A_log`` is off by 7e-5 of its largest entry here, the kernels' by 1.5e-4;
 of what is added both read under 1e-6).
+
+ISSUE 52 (the file's last section): the delta rule with ONE decay a head
+and several value heads to a key head (Gated DeltaNet).
+``gated_delta_scan`` is held to the same recurrence (the decay spread over
+a head's channels, q and k repeated to the value heads) and to ``kda_scan``
+handed that spread gate; ``gdn_gated_scan``, a layer's entry, to the
+recurrence on both routes, o and seven gradients, by the same limits.
 """
 import importlib
 
@@ -430,7 +437,7 @@ def test_path_event_and_padding(route, entry):
     facts = {"route": route, "chunk": 64, "tokens": 150,
              "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3,
              "prologue": "in_kernel" if (entry, route) == ("gated", "kernel")
-             else "jnp"}
+             else "jnp", "decay": "channel", "key_heads": 2}
     if route == "kernel":
         facts["heads_per_block"] = 2
     assert events and events[-1]["data"] == facts
@@ -501,3 +508,209 @@ def test_other_shapes_fall_back_to_the_plain_route(entry):
         took(want, before)
     assert [kda._heads_per_block(n) for n in (1, 2, 3, 6, 32)] == [
         1, 2, 3, 2, 4]
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 52: one decay a head, value heads in groups over the key heads
+# ---------------------------------------------------------------------------
+
+GDN = ("q", "k", "v", "g", "beta")
+GDN_GATED = ("q", "k", "v", "a", "a_log", "dt_bias", "beta")
+
+
+def gdn_arguments(seed, t, key_heads=2, value_heads=4, batch=2, gate=None,
+                  raw=False):
+    """What a Gated DeltaNet layer hands its scan: q, k [B, T, Hk * 128] of
+    unit length a head (``raw``: of any length, 0.01 to 10), v [B, T, Hv *
+    128], one decay a VALUE head and token from 0.999 down to 0.2 (A in
+    [1, 16] x a step log-uniform in [0.001, 0.1]) or ``gate``, beta; and,
+    with ``raw``, the layer's own a, A_log and dt_bias in place of g."""
+    r = jax.random.split(jax.random.PRNGKey(seed), 10)
+    kshape, vshape = (batch, t, key_heads * D), (batch, t, value_heads * D)
+
+    def keys(key, length):
+        x = jax.random.normal(key, (batch, t, key_heads, D))
+        x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        if raw:
+            x = x * jnp.exp(jax.random.uniform(
+                length, (batch, t, key_heads, 1), minval=np.log(1e-2),
+                maxval=np.log(10.0)))
+        return x.reshape(kshape)
+
+    out = {"q": keys(r[0], r[6]), "k": keys(r[1], r[7]),
+           "v": jax.random.normal(r[2], vshape),
+           "beta": jax.nn.sigmoid(jax.random.normal(
+               r[5], (batch, t, value_heads)))}
+    a_log = jnp.log(jax.random.uniform(r[3], (value_heads,), minval=1.0,
+                                       maxval=16.0))
+    if raw:
+        dt = jnp.exp(jax.random.uniform(r[4], (value_heads,),
+                                        minval=np.log(1e-3),
+                                        maxval=np.log(0.1)))
+        out.update(a=0.5 * jax.random.normal(r[8], (batch, t, value_heads)),
+                   a_log=a_log, dt_bias=dt + jnp.log(-jnp.expm1(-dt)))
+    else:
+        step = jnp.exp(jax.random.uniform(
+            r[4], (batch, t, value_heads), minval=np.log(1e-3),
+            maxval=np.log(0.1)))
+        out["g"] = -jnp.exp(a_log) * step if gate is None \
+            else jnp.full((batch, t, value_heads), gate, jnp.float32)
+    return out, jax.random.normal(r[9], vshape)
+
+
+def to_value_heads(x, key_heads, value_heads):
+    """[B, T, Hk * 128] -> [B, T, Hv * 128]: value head j reads key head
+    j // (Hv / Hk)."""
+    b, t, _ = x.shape
+    return jnp.repeat(x.reshape(b, t, key_heads, D),
+                      value_heads // key_heads, 2).reshape(b, t, -1)
+
+
+def gdn_recurrence(q, k, v, g, beta, *, scale):
+    """The definition, token by token: the file's ``recurrence`` with the
+    head's one decay on all of its key channels."""
+    hv = beta.shape[-1]
+    hk = k.shape[-1] // D
+    return recurrence(to_value_heads(q, hk, hv), to_value_heads(k, hk, hv), v,
+                      jnp.repeat(g, D, -1), beta, scale=scale, heads=hv)
+
+
+def gdn_gated_recurrence(q, k, v, a, a_log, dt_bias, beta, *, scale):
+    hk = k.shape[-1] // D
+    g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+    return gdn_recurrence(unit_heads(q, hk), unit_heads(k, hk), v, g, beta,
+                          scale=scale)
+
+
+def gdn_both(args, do, **kw):
+    names = GDN_GATED if "a" in args else GDN
+    ref, fn = (gdn_gated_recurrence, kda.gdn_gated_scan) if "a" in args \
+        else (gdn_recurrence, kda.gated_delta_scan)
+    scale = D ** -0.5
+
+    def grads(f):
+        def scalar(*a):
+            o = f(*a)
+            return jnp.sum(o.astype(jnp.float32) * do), o
+
+        (_, o), g = jax.jit(jax.value_and_grad(
+            scalar, argnums=tuple(range(len(names))), has_aux=True))(
+            *(args[n] for n in names))
+        return dict(zip(("o",) + names, (o,) + g))
+
+    return (grads(lambda *a: fn(*a, scale=scale, **kw)),
+            grads(lambda *a: ref(*a, scale=scale)))
+
+
+def gdn_worst(got, want, args):
+    """``worst`` with the sums of the layer's entry held to what they add
+    up: dA_log = sum dg g and ddt_bias = sum da, over every token."""
+    of = {n: jnp.max(jnp.abs(want[n])) for n in want}
+    if "a" in args:
+        x = args["a"] + args["dt_bias"]
+        da = jnp.abs(want["a"])
+        of["a_log"] = jnp.max((da * jax.nn.softplus(x) / jax.nn.sigmoid(x)
+                               ).sum((0, 1)))
+        of["dt_bias"] = jnp.max(da.sum((0, 1)))
+    return {n: float(jnp.max(jnp.abs(got[n] - want[n])) / (of[n] + 1e-30))
+            for n in want}
+
+
+@pytest.mark.parametrize("t,chunk,heads", [
+    (150, 64, (2, 2)), (150, 64, (2, 4)), (150, 64, (1, 4)),
+    (64, 64, (2, 4)), (40, 64, (2, 4)), (96, 16, (2, 4))],
+    ids=["ragged-one_to_one", "ragged-two_to_one", "ragged-four_to_one",
+         "one_chunk", "short", "chunk16"])
+def test_gated_delta_scan_is_the_recurrence(t, chunk, heads):
+    """o and all five gradients (``jax.vjp`` through the chunks' scan), T a
+    whole number of chunks or not, one, two and four value heads to a key
+    head: dq and dk are sums over a key head's value heads."""
+    args, do = gdn_arguments(0, t, *heads)
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do, chunk=chunk)
+    took("chunked_jnp", before)
+    assert got["o"].shape == (2, t, heads[1] * D)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+
+
+def test_gated_delta_scan_is_kda_scan_under_a_channel_constant_gate(route):
+    """``kda_scan`` (either route) handed the head's decay on all 128 key
+    channels and q and k repeated to the value heads computes the same
+    thing: a decay that is constant over a head's lanes is a case of the
+    one it computes. o and the gradients of v and beta element by element,
+    dq and dk summed over a key head's value heads, dg over the lanes."""
+    args, do = gdn_arguments(3, 150)
+    got, _ = gdn_both(args, do)
+    wide = {"q": to_value_heads(args["q"], 2, 4),
+            "k": to_value_heads(args["k"], 2, 4), "v": args["v"],
+            "g": jnp.repeat(args["g"], D, -1), "beta": args["beta"]}
+    before = kda.PATH_COUNTS.copy()
+    theirs = value_and_grads(
+        lambda *a: kda.kda_scan(*a, scale=D ** -0.5), wide, do)
+    took(route, before)
+    group = lambda x: x.reshape(2, 150, 2, 2, D).sum(3).reshape(  # noqa: E731
+        2, 150, 2 * D)
+    want = {"o": theirs["o"], "q": group(theirs["q"]),
+            "k": group(theirs["k"]), "v": theirs["v"],
+            "g": theirs["g"].reshape(2, 150, 4, D).sum(-1),
+            "beta": theirs["beta"]}
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+
+
+def test_gated_delta_scan_under_the_strongest_decay():
+    """g = -20 a token: the state is forgotten between tokens, o_t = scale
+    beta_t (q_t . k_t) v_t, and every gradient is finite (every exponent
+    is a later cumulative sum less an earlier one)."""
+    args, do = gdn_arguments(1, 150, gate=-20.0)
+    got, want = gdn_both(args, do)
+    for name, v in got.items():
+        assert bool(jnp.all(jnp.isfinite(v))), name
+    assert float(jnp.max(jnp.abs(got["g"] - want["g"]))) < 1e-6
+    for name, err in gdn_worst(got, want, args).items():
+        assert name == "g" or err < F32_TOL, (name, err)
+    q, k = (to_value_heads(args[n], 2, 4).reshape(2, 150, 4, D) for n in "qk")
+    alone = (D ** -0.5 * args["beta"] * (q * k).sum(-1))[..., None] \
+        * args["v"].reshape(2, 150, 4, D)
+    np.testing.assert_allclose(got["o"], alone.reshape(2, 150, -1),
+                               atol=1e-6)
+
+
+def test_gated_delta_scan_refuses_heads_that_do_not_group():
+    args, _ = gdn_arguments(0, 64, key_heads=2, value_heads=3)
+    with pytest.raises(ValueError, match="3 value heads over 2 key heads"):
+        kda.gated_delta_scan(*(args[n] for n in GDN), scale=1.0)
+
+
+def test_a_gated_deltanet_layers_scan_is_the_recurrence(route, t=150):
+    """``gdn_gated_scan`` from what a layer's convolution and b | a
+    projection leave, on the kernel route (KDA's pair, q and k repeated,
+    ``a`` over a head's lanes: the norms and the gate made in the kernels)
+    and on the plain one (``l2norm``, the softplus, ``gated_delta_scan``):
+    o and seven gradients against the recurrence."""
+    args, do = gdn_arguments(2, t, raw=True)
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do)
+    took(route, before)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+
+
+def test_a_gated_deltanet_scans_path_event(route):
+    """ISSUE 52's facts of ``rtpu.ops.kda.path``: ``decay`` (``head``: one
+    a head, or ``channel``: KDA's) and ``key_heads``."""
+    from ray_tpu.perf import recorder
+
+    args, _ = gdn_arguments(4, 150, raw=True)
+    jax.eval_shape(lambda *a: kda.gdn_gated_scan(*a, scale=1.0),
+                   *(args[n] for n in GDN_GATED))
+    data = [e["data"] for e in recorder.get_recorder().snapshot()
+            if e["kind"] == "rtpu.ops.kda.path"][-1]
+    facts = {"route": route, "chunk": 64, "tokens": 150, "padded_tokens": 42,
+             "heads": 4, "d_k": D, "d_v": D, "chunks": 3, "decay": "head",
+             "key_heads": 2,
+             "prologue": "in_kernel" if route == "kernel" else "jnp"}
+    if route == "kernel":
+        facts["heads_per_block"] = 4
+    assert data == facts

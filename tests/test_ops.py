@@ -350,6 +350,66 @@ class TestLayers:
         y2 = apply_rope(full, cos, sin)[:, 4:]
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
 
+    def test_rmsnorm_one_plus_w(self):
+        """ISSUE 52: ``plus_one`` is the gain 1 + w (w from zero: no gain);
+        the same body as the plain form handed 1 + w."""
+        x = jax.random.normal(jax.random.PRNGKey(0), (4, 16))
+        w = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (16,))
+        y = rmsnorm(x, w, plus_one=True)
+        norm = np.asarray(x) / np.sqrt(
+            np.mean(np.square(np.asarray(x)), -1, keepdims=True) + 1e-6)
+        np.testing.assert_allclose(np.asarray(y),
+                                   norm * (1.0 + np.asarray(w)), atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(y),
+                                      np.asarray(rmsnorm(x, 1.0 + w)))
+        np.testing.assert_allclose(
+            np.asarray(rmsnorm(x, jnp.zeros(16), plus_one=True)), norm,
+            atol=1e-6)
+
+    @pytest.mark.parametrize("name", ["silu", "sigmoid"])
+    def test_the_norm_then_the_gate(self, name):
+        """ISSUE 52: rmsnorm(y) * w * act(gate), the norm FIRST (Gated
+        DeltaNet under SiLU, KDA under the sigmoid: one body), by hand;
+        ``gated_rmsnorm`` gates before the norm and differs."""
+        from ray_tpu.ops import (gated_rmsnorm, rmsnorm_then_gate,
+                                 sigmoid_gated_rmsnorm)
+
+        y = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 16))
+        gate = jax.random.normal(jax.random.PRNGKey(1), (3, 5, 16))
+        w = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (16,))
+        yn, gn = np.asarray(y, np.float64), np.asarray(gate, np.float64)
+        sig = 1.0 / (1.0 + np.exp(-gn))
+        want = yn / np.sqrt(np.mean(yn * yn, -1, keepdims=True) + 1e-6) \
+            * np.asarray(w) * (gn * sig if name == "silu" else sig)
+        got = rmsnorm_then_gate(y, gate, w, activation=getattr(jax.nn, name))
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-6)
+        if name == "sigmoid":
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(sigmoid_gated_rmsnorm(y, gate, w)))
+        else:
+            assert float(jnp.abs(got - gated_rmsnorm(y, gate, w)).max()) > 0.1
+
+    def test_rope_on_the_first_channels_of_a_head(self):
+        """ISSUE 52: tables half as wide as a quarter of the head turn its
+        first 16 channels of 64, channel i with i + 8, by position x
+        base^(-i/8), and leave the other 48 as they are."""
+        cos, sin = rope_cache(32, 16, 1e4)
+        assert cos.shape == (32, 8)
+        x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 3, 64))
+        y = np.asarray(apply_rope(x, cos, sin))
+        xn = np.asarray(x, np.float64)
+        ang = np.arange(32)[:, None] * 1e4 ** (-np.arange(8) / 8.0)[None]
+        c, s_ = np.cos(ang)[None, :, None], np.sin(ang)[None, :, None]
+        lo, hi = xn[..., :8], xn[..., 8:16]
+        np.testing.assert_allclose(y[..., :8], lo * c - hi * s_, atol=1e-5)
+        np.testing.assert_allclose(y[..., 8:16], lo * s_ + hi * c, atol=1e-5)
+        np.testing.assert_array_equal(y[..., 16:], np.asarray(x)[..., 16:])
+        # the whole head: what the call was before
+        full = rope_cache(32, 64, 1e4)
+        np.testing.assert_allclose(
+            np.linalg.norm(np.asarray(apply_rope(x, *full)), axis=-1),
+            np.linalg.norm(np.asarray(x), axis=-1), atol=1e-5)
+
     def test_cross_entropy(self):
         logits = jnp.array([[[2.0, 0.0, 0.0], [0.0, 3.0, 0.0]]])
         labels = jnp.array([[0, -100]])

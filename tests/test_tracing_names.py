@@ -13,8 +13,8 @@ import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
                             GraniteHybrid, GraniteHybridConfig, KimiLinear,
-                            KimiLinearConfig, Llama, LlamaConfig, SambaY,
-                            SambaYConfig)
+                            KimiLinearConfig, Llama, LlamaConfig, Qwen3Next,
+                            Qwen3NextConfig, SambaY, SambaYConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
@@ -194,7 +194,9 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
     assert layer["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
-        "row_tile": el.ROW_TILE}
+        "row_tile": el.ROW_TILE,
+        # ISSUE 52: how the router scores, whether the shared expert is gated
+        "score": "sigmoid", "shared_gate": False}
     path = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
             and e["label"] == "latent"][-1]
     assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
@@ -258,7 +260,9 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
     assert path["data"] == {
         "route": "kernel", "chunk": 64, "tokens": 150,
         "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
-        "chunks": 3, "heads_per_block": 2, "prologue": "in_kernel"}
+        "chunks": 3, "heads_per_block": 2, "prologue": "in_kernel",
+        # ISSUE 52: KDA's decay is one a key channel, a key head a value head
+        "decay": "channel", "key_heads": 2}
     # both kernels stand under the scope the roofline reads
     for name in kda.KERNEL_NAMES.values():
         assert re.search(r"scan/[^\n]*" + name, text), name
@@ -268,6 +272,53 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
                                     ["mla_moe", 1], ["kda_moe", 1]]
     assert runs["data"]["kept"] == [
         [], [], ["flash_out", "flash_lse", "attn_q"], []]
+    assert runs["data"]["side_state_bytes"] == 0
+
+
+def test_a_gated_deltanet_stack_leaves_its_events():
+    """ISSUE 52: what a Qwen3-Next shaped loss leaves at trace time.
+    ``rtpu.ops.kda.path``: the kernel route with ``decay`` ``head`` (one a
+    head, spread over its lanes) and the ``key_heads`` that were repeated to
+    the 4 value heads, the norms and the gate made in the kernels;
+    ``rtpu.ops.expert_layer``: ``score`` ``softmax`` and ``shared_gate``;
+    ``rtpu.ops.flash.path``: heads of 256 on the ``relayout`` route;
+    ``rtpu.models.stack.runs``: 3 scanned Gated DeltaNet layers that keep
+    their inputs alone, then the attention layer with the flash kernels'
+    output and row statistics. KDA's two kernels stand under the scope
+    ``scan``, where ``gdn_scan_roofline`` finds their time."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = Qwen3Next(Qwen3NextConfig.tiny(experts_held=2, expert_offset=4))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    before = kda.PATH_COUNTS.copy()
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    assert set(kda.PATH_COUNTS - before) == {"kernel"}
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    assert last("rtpu.ops.kda.path")["data"] == {
+        "route": "kernel", "chunk": 64, "tokens": 256, "padded_tokens": 0,
+        "heads": 4, "d_k": 128, "d_v": 128, "chunks": 4,
+        "heads_per_block": 4, "prologue": "in_kernel", "decay": "head",
+        "key_heads": 2}
+    assert last("rtpu.ops.expert_layer")["data"] == {
+        "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
+        "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True}
+    flash = last("rtpu.ops.flash.path")
+    assert flash["label"] == "relayout" and flash["data"]["hd"] == 256
+    for name in kda.KERNEL_NAMES.values():
+        assert re.search(r"scan/[^\n]*" + name, text), name
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "qwen3_next"][-1]
+    assert runs["data"]["runs"] == [["gdn_moe", 3], ["attn_moe", 1]]
+    assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
     assert runs["data"]["side_state_bytes"] == 0
 
 
@@ -387,6 +438,7 @@ MODELS = {
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
     "kimi_linear": lambda: KimiLinear(KimiLinearConfig.tiny(experts_held=4)),
+    "qwen3_next": lambda: Qwen3Next(Qwen3NextConfig.tiny(experts_held=4)),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -407,14 +459,18 @@ def lowered_losses():
 
 @pytest.mark.parametrize("scope,model", [
     (s, m) for m in sorted(MODELS)
-    for s in ("embed", "attn", "mlp", "lm_head", "loss")
+    # ISSUE 52: a Qwen3-Next shaped model has no dense MLP, so no ``mlp``
+    for s in ("embed", "attn", "lm_head", "loss")
+    + (("mlp",) if m != "qwen3_next" else ())
     + (("router", "experts", "shared_expert")
-       if m.startswith("deepseek_v3") or m == "kimi_linear" else ())
+       if m.startswith("deepseek_v3") or m in ("kimi_linear", "qwen3_next")
+       else ())
     # ISSUE 45: everything ops/hyper_connection.py does, under one name
     + (("mhc",) if m == "deepseek_v3_hc" else ())
     # ISSUE 49: a KDA layer's three, the names the other scans' readers read
     + (("mixer", "conv", "scan")
-       if m in ("granite_hybrid", "sambay", "kimi_linear") else ())
+       if m in ("granite_hybrid", "sambay", "kimi_linear", "qwen3_next")
+       else ())
     + (("gmu", "cross_attn") if m == "sambay" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
