@@ -81,7 +81,7 @@ from .stack import (draw_params, period_runs, run_params,
 # 2 x 16 heads x 8192 x 256 bf16 = 134 MB and the statistics, once). A
 # Gated DeltaNet layer keeps its input alone, as a KDA layer does
 # (``kimi_linear.py``): its projections, convolution and gates are made
-# again and ``kda_chunk_fwd`` runs a second time in the layer's backward.
+# again and ``gdn_chunk_fwd`` runs a second time in the layer's backward.
 # Decided by ``benchmark/scratch/describe_compile.py`` on the cell's step
 # (PERF.md, PR 52).
 _REMAT_SAVE = {"attn": ("flash_out", "flash_lse"), "gdn": ()}

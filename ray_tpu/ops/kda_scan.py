@@ -1,8 +1,8 @@
 """Kimi Delta Attention's recurrence (KDA: a gated delta rule with a decay
 for every key channel) as a chunked scan: a Pallas kernel pair, and the
 plain ``jax.numpy`` form that defines it; and Gated DeltaNet's (the same
-rule with ONE decay a head and several value heads to a key head), its own
-plain form and a route through the same kernel pair.
+rule with ONE decay a head and several value heads to a key head): its own
+plain form, and the same kernel pair with a body of its own.
 
 Per head (state S [d_k, d_v] float32 from zero; q_t, k_t [d_k], v_t [d_v],
 g_t [d_k] <= 0 the log of the token's decay a key channel, beta_t in (0, 1)
@@ -74,15 +74,16 @@ Which entry a layer calls. A KDA layer (``models/kimi_linear.py``):
 ``kda_gated_scan``. A Gated DeltaNet layer (``models/qwen3_next.py``):
 ``gdn_gated_scan(q, k, v, a, a_log, dt_bias, beta)``, which on the plain
 route is ``l2norm`` a key head, g = -exp(a_log) softplus(a + dt_bias) a
-value head and ``gated_delta_scan``, and on the kernel route is the KDA
-pair below UNCHANGED: q and k repeated to the value heads, ``a`` spread
-over a head's 128 lanes as the prologue's step, a_log and dt_bias as its
-two rows (a decay that is constant over a head's lanes is a case of the
-one the kernels compute). The gradients come back through that spread and
-repeat by autodiff: dq and dk summed over a key head's value heads, da
-over a head's lanes. ``kda_scan`` and ``gated_delta_scan`` are the two
-definitions everything is tested against. The event's facts ``decay``
-(``channel`` or ``head``) and ``key_heads`` say which rule a call was.
+value head and ``gated_delta_scan``, and on the kernel route is the kernel
+pair with the body for one decay a head (``gdn_chunk_fwd`` /
+``gdn_chunk_bwd``; below). The two rules' needs conflict (the general body
+cannot use a factored table, the scalar body is wrong for a vector decay),
+so they are separate paths, reached by the entry alone: no argument, flag
+or name chooses, and KDA's traced program knows nothing of the other.
+``kda_scan`` and ``gated_delta_scan`` are the two definitions everything is
+tested against. The event's facts ``decay`` (``channel`` or ``head``),
+``body`` (``channel_decay`` or ``head_decay``: which program ran) and
+``key_heads`` say which rule a call was.
 
 KDA's two entries. ``kda_scan(q, k, v, g, beta)`` is the recurrence above,
 q and k as the caller normalised them and g ready: the definition everything
@@ -172,6 +173,66 @@ more).
     once ([8 blocks, 8 rows, 128] values: a block's rows are the sublanes
     of its own tile): the differences, their exp, two sums over the lanes.
   The bodies are ``jax.lax`` primitives (ROADMAP A11).
+  One decay a head (``_gdn_chunk``, ``_gdn_forward_of`` /
+  ``_gdn_backward_of``; the grid, the state's layout, the residual, the
+  scratch, the ``custom_vjp`` and the once-traced pure bodies are the
+  pair's above, the body here a program's whole block). A program is one
+  chunk of a block of at most four VALUE heads that holds whole key heads
+  (``_gdn_heads_per_block``: four value heads over two key heads at
+  Qwen3-Next's two to one; more value heads to a key head than a block
+  holds are the plain route's):
+  - q and k are read ONCE a key head, from the model's merged [B, T, Hk x
+    128] arrays by a block spec of their own; v, o and the state a value
+    head. Nothing is repeated to the value heads and no copy of ``a`` over
+    a head's lanes exists. ``a`` and beta come in head-major ([B, head
+    blocks, chunks, heads a block, C] float32: dense rows), A_log and
+    dt_bias as rows a head block.
+  - the gate is a NUMBER a value head and token: g = -exp(A_log) softplus(
+    a + dt_bias), its cumulative sums over the chunk (one exact product
+    with the triangle of ones) and, in the backward, the whole chain rule
+    back to ``a`` are worked on one [8, C] tile a program (``_head_gate``:
+    the block's heads its first rows). A row becomes a [C, 128] column only
+    where a product's operand needs one, for beta and for G (``_columns``:
+    ``_beta_column`` for all of a block's rows in one product; exact, the
+    column is the row's own float32 numbers).
+  - once a key head: the l2 norms (the prologue, as KDA's), and Q K^T over
+    K K^T in ONE product [2C, 128] x [128, C] of the stacked, normalised q
+    and k rounded to the model's dtype, shared by the key head's value
+    heads.
+  - once a value head: the table exp(G_i - G_j) [C, C] laid over both
+    score matrices on the VPU (the differences themselves, every one used
+    <= 0; 4096 exps where the general body makes 65 536 on the diagonal
+    sub-blocks alone), the solve (``_solve_heads``, shared and unchanged:
+    blocks of 8, ``Precision.HIGHEST``, a key head's two value heads side
+    by side), and ONE product of the stacked q and k with the state: with a
+    scalar decay (q e^G) S_0 = e^G (q S_0), and what the tokens write is
+    V' = T (beta (v - e^G (k S_0))), one [C, C] x [C, 128] product after
+    the solve and no W of the keys' own.
+  - ``gdn_chunk_bwd`` makes that again and writes dq and dk of the RAW q
+    and k ALREADY SUMMED over a key head's value heads (they are in one
+    program), [B, T, Hk x 128] in q's dtype; dv; da and dbeta head-major
+    float32; A_log's and dt_bias's gradients as partial sums a (batch row,
+    head block), as KDA's ``drows``. The gates' gradients are numbers a
+    row: with P = dQ o Q + dK o K (the decayed scores times their
+    gradients) dG_i gains the sums of P's row i and loses those of its
+    column i, e^G's and e^(G_C - G)'s shares are sums over a row's lanes
+    (all of a head's in ONE product with ones, the tokens along its
+    lanes), dg is the sums from each token to the chunk's end.
+  - the heads' work stands SIDE BY SIDE in the program: a head's chunk is
+    a chain of products each waiting for the one before (the solve alone
+    ten at ``Precision.HIGHEST``), the chains of different heads are
+    independent, and issued a stage at a time for all of the block's heads
+    (``_solve`` handed the block's pairs as a list; ``_staged``) they
+    overlap in the MXU. The same products on the same numbers: on a v5e at
+    the cell's shape a layer's forward 9.6 -> 5.9 ms and its backward 14.7
+    -> 8.8 by the order of issue alone (PERF.md, PR 53).
+  Shared with KDA's body: ``_solve`` / ``_solve_heads``, ``_unit`` /
+  ``_unit_bwd``, ``_three``, ``_decay``, the specs' builder. Not
+  called here: ``_scores``, ``_against_earlier``, ``_diagonal``, their
+  backward twins, ``_gate``, ``_triangle_sums``. (On a v5e at the cell's
+  shape, PERF.md PR 53: a layer 6.1 ms forward and 15.5 forward + backward
+  where PR 52's route through KDA's body took 14.1 and 36.9; the solve,
+  two pairs in lock step, is 3.4 ms of either pass.)
 * ``chunked_jnp``: every other shape, and the definition the kernels are
   tested against: ONE ``lax.scan`` over the chunks carries the state; a
   turn makes its chunk's matrices (A, Q, T, W, U) for every batch row and
@@ -213,6 +274,9 @@ _flash = importlib.import_module(__package__ + ".flash_attention")
 KERNEL_NAMES = {
     "fwd": "kda_chunk_fwd",     # o and the state each chunk starts from
     "bwd": "kda_chunk_bwd",     # dq, dk, dv, dg (or dstep, drows), dbeta
+    # the same pair with the body for ONE decay a head (Gated DeltaNet)
+    "gdn_fwd": "gdn_chunk_fwd",
+    "gdn_bwd": "gdn_chunk_bwd",     # dq, dk a KEY head; dv, da, drows, dbeta
 }
 
 # Traced calls of either entry by route; the same choice is the
@@ -435,6 +499,9 @@ def _head_decay_chunked(q, k, v, g, beta, key_heads: int, chunk: int,
 
 _MAX_HEADS_PER_BLOCK = 4
 _VMEM_BYTES = 64 * 1024 * 1024
+_HEAD_ROWS = 8    # the sublanes of the tile on which a block's numbers a
+#                   head and token are worked (one decay a head): its heads
+#                   the first rows
 
 _mul, _add, _minus = jax.lax.mul, jax.lax.add, jax.lax.sub
 
@@ -648,31 +715,47 @@ def _solve(a, r: int):
     [C, C] x [C, C] one): the r x r diagonal blocks by the Neumann product
     (the products of block-diagonal matrices are the blocks' products), then
     the blocks merged pair by pair, T <- T - T L T with L the part of a that
-    joins the pair."""
-    c = a.shape[0]
-    eye = _same_block(a.shape, 1).astype(_F32)
-    zero = _zeros_like(a)
-    p = jax.lax.select(_same_block(a.shape, r), a, zero)
-    t, m = _minus(eye, p), 2
+    joins the pair.
+
+    ``a`` may be a LIST of such matrices of one shape -> the list of their
+    solves, made in lock step: a solve is a chain of ten products, each
+    waiting for the one before, and the chains of different matrices are
+    independent, so side by side in the program they overlap in the MXU
+    (the same products on the same numbers, in another order of issue)."""
+    if not isinstance(a, (list, tuple)):
+        return _solve([a], r)[0]
+    shape = a[0].shape
+    c = shape[0]
+    eye = _same_block(shape, 1).astype(_F32)
+    zero = _zeros_like(a[0])
+    inside = _same_block(shape, r)
+    p = [jax.lax.select(inside, x, zero) for x in a]
+    t, m = [_minus(eye, x) for x in p], 2
     while m < r:
-        p = _exact_dot(p, _apart(p), _AB)
-        t = _exact_dot(t, _apart(_add(eye, p)), _AB)
+        p = [_exact_dot(x, _apart(x), _AB) for x in p]
+        t = [_exact_dot(y, _apart(_add(eye, x)), _AB) for x, y in zip(p, t)]
         m *= 2
     while r < c:
-        join = jax.lax.select(_same_block(a.shape, r), zero, jax.lax.select(
-            _same_block(a.shape, 2 * r), a, zero))
-        joined = _exact_dot(t, _apart(join), _AB)
-        t = _minus(t, _exact_dot(joined, _apart(t), _AB))
+        inside, around = _same_block(shape, r), _same_block(shape, 2 * r)
+        joined = [_exact_dot(y, _apart(jax.lax.select(
+            inside, zero, jax.lax.select(around, x, zero))), _AB)
+            for x, y in zip(a, t)]
+        t = [_minus(y, _exact_dot(j, _apart(y), _AB))
+             for j, y in zip(joined, t)]
         r *= 2
     return t
 
 
 def _solve_heads(a, r: int):
-    """[T of each A in ``a``], the heads two to a product."""
-    c, out = a[0].shape[0], []
-    for i in range(0, len(a) - 1, 2):
-        t = _solve(jax.lax.concatenate(a[i:i + 2], 1), r)
-        out += [_cols(t, 0, c), _cols(t, c, c)]
+    """[T of each A in ``a``], the heads two to a product and the pairs in
+    lock step (``_solve``)."""
+    c = a[0].shape[0]
+    pairs = [jax.lax.concatenate(a[i:i + 2], 1)
+             for i in range(0, len(a) - 1, 2)]
+    solved = _solve(pairs, r) if len(pairs) > 1 \
+        else [_solve(x, r) for x in pairs]
+    out = [part for t in solved
+           for part in (_cols(t, 0, c), _cols(t, c, c))]
     return out + [_solve(x, r) for x in a[len(out):]]
 
 
@@ -873,13 +956,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
             s_scr[pl.ds(h * d, d), :] = st
 
 
-def _specs(t: int, chunk: int, hpb: int, reverse: bool):
+def _specs(t: int, chunk: int, hpb: int, reverse: bool, key_heads: int = 0):
     """Block specs on the grid (B, head blocks, chunks); ``reverse`` walks
-    the chunks from the last to the first."""
+    the chunks from the last to the first. ``key_heads``: the key heads a
+    block of ``hpb`` value heads reads, for the body with one decay a
+    head."""
     nc = t // chunk
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
     w = hpb * _LANES
-    return {
+    specs = {
         "x": pl.BlockSpec((None, chunk, w), lambda b, k, c: (b, at(c), k)),
         "beta": pl.BlockSpec((None, None, None, hpb, chunk),
                              lambda b, k, c: (b, k, at(c), 0, 0)),
@@ -890,6 +975,19 @@ def _specs(t: int, chunk: int, hpb: int, reverse: bool):
         "state": pl.BlockSpec((None, None, w, _LANES),
                               lambda b, k, c: (b, at(c), k, 0)),
     }
+    if key_heads:
+        specs.update({
+            "keys": pl.BlockSpec((None, chunk, key_heads * _LANES),
+                                 lambda b, k, c: (b, at(c), k)),
+            # A_log over 8 rows and dt_bias over 8 more, a block's heads
+            # the first of each (``_head_gate``), and their gradients'
+            # partial sums a batch row and token of the chunk
+            "head_rows": pl.BlockSpec((None, 2 * _HEAD_ROWS, chunk),
+                                      lambda b, k, c: (k, 0, 0)),
+            "head_drows": pl.BlockSpec((None, None, 2 * _HEAD_ROWS, chunk),
+                                       lambda b, k, c: (b, k, 0, 0)),
+        })
+    return specs
 
 
 def _params():
@@ -1125,15 +1223,461 @@ def _kda_vjp_bwd(scale, hpb, eps, res, do):
 _kda_kernels.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
 
 
+def _head_major(x, chunk: int, hpb: int):
+    """A number a token and head [B, T, H] -> [B, head blocks, chunks, heads
+    a block, C]: a program sees dense rows."""
+    b, t, heads = x.shape
+    return x.reshape(b, t // chunk, chunk, heads // hpb, hpb
+                     ).transpose(0, 3, 1, 4, 2)
+
+
 def _kernel_route(q, k, v, gate, beta, heads: int, chunk: int,
                   scale: float, hpb: int, eps):
     """Whole chunks of merged arrays through the kernel pair; beta goes in
-    head-major, [B, head blocks, chunks, heads a block, C]: a program sees
-    dense rows."""
-    b, t, _ = q.shape
-    beta_t = beta.reshape(b, t // chunk, chunk, heads // hpb, hpb
-                          ).transpose(0, 3, 1, 4, 2)
-    return _kda_kernels(q, k, v, gate, beta_t, scale, hpb, eps)
+    head-major (``_head_major``)."""
+    return _kda_kernels(q, k, v, gate, _head_major(beta, chunk, hpb), scale,
+                        hpb, eps)
+
+
+# ---------------------------------------------------------------------------
+# one decay a head (Gated DeltaNet): the body of the same kernel pair
+# ---------------------------------------------------------------------------
+#
+# The module docstring's "One decay a head" block as the kernels' body. A
+# program is one chunk of a block of VALUE heads and of the key heads they
+# read. What is a [C, 128] tile of gates in KDA's body is a row [1, C] a
+# value head here (head-major, as beta comes in): the gate, its cumulative
+# sum and the chain rule back to ``a`` are worked on ONE [8, C] tile a
+# program, and a row becomes a [C, 128] column (``_columns``) only where a
+# product's operand needs one.
+
+
+def _gdn_heads_per_block(heads: int, group: int):
+    """Value heads a program works where ``group`` of them read one key
+    head: whole key heads (dq and dk leave the backward kernel summed over a
+    key head's value heads, so those are in one program), as many as divide
+    the heads, an even number where there is one (the solve takes them in
+    pairs). None where no block of ``_MAX_HEADS_PER_BLOCK`` holds a key
+    head's value heads: the plain route's."""
+    fit = [n for n in range(group, _MAX_HEADS_PER_BLOCK + 1, group)
+           if heads % n == 0]
+    return max([n for n in fit if n % 2 == 0] or fit, default=None)
+
+
+def _head_tile(rows):
+    """Rows [1, C], one a head -> the [8, C] tile with head h in row h and
+    zeros under the heads."""
+    shape = (_HEAD_ROWS, rows[0].shape[1])
+    sub, out = _iota(shape, 0), _full(shape, 0)
+    for h, row in enumerate(rows):
+        out = jax.lax.select(_is(sub, h), _spread(row, shape), out)
+    return out
+
+
+def _lower_ones(c: int):
+    """The [C, C] float32 triangle of ones, row >= column: against a
+    head-major tile's tokens, ``_ABT`` is the inclusive cumulative sum over
+    the chunk and ``_AB`` the sums from each token to the chunk's end."""
+    return _ge(_iota((c, c), 0), _iota((c, c), 1)).astype(_F32)
+
+
+def _head_gate(a_ref, rows_ref):
+    """A block's ``a`` [heads, C] float32 and its rows [16, C] (A_log of
+    head h in row h, dt_bias in row 8 + h, each in every lane) -> (g =
+    -exp(A_log) softplus(a + dt_bias) [8, C], d g / d a, the inclusive
+    cumulative sums of g over the chunk's tokens): a number a head and
+    token, the softplus as ``_gate`` makes it."""
+    c = a_ref.shape[1]
+    x = _add(_head_tile([a_ref[pl.ds(h, 1), :]
+                         for h in range(a_ref.shape[0])]),
+             rows_ref[pl.ds(_HEAD_ROWS, _HEAD_ROWS), :])
+    neg_a = jax.lax.neg(jax.lax.exp(rows_ref[pl.ds(0, _HEAD_ROWS), :]))
+    soft = _add(jax.lax.max(x, _zeros_like(x)),
+                jax.lax.log1p(jax.lax.exp(jax.lax.neg(jax.lax.abs(x)))))
+    g = _mul(neg_a, soft)
+    return (g, _mul(neg_a, jax.lax.logistic(x)),
+            _exact_dot(g, _lower_ones(c), _ABT))
+
+
+def _columns(rows):
+    """Rows [1, C] -> each one's [C, 128] column as ``_beta_column`` makes
+    it (the row's own numbers, exactly), all of them in ONE product."""
+    c = rows[0].shape[1]
+    on = _eq(_iota((c, c), 0), _iota((c, c), 1))
+    zero = _full((c, c), 0)
+    diagonals = jax.lax.concatenate([jax.lax.concatenate(_three(
+        jax.lax.select(on, _spread(row, (c, c)), zero)), 1) for row in rows],
+        0)                                                    # [n C, 3 C]
+    out = _dot(diagonals, _full((3 * c, _LANES), 1, jnp.bfloat16), _AB)
+    return [_rows(out, i * c, c) for i in range(len(rows))]
+
+
+def _lane_sums(x):
+    """x [n, w] float32 -> the sums over its lanes as a ROW [1, n] (the
+    tokens along the lanes): three exact passes against ones."""
+    ones = _full((_HEAD_ROWS, x.shape[1]), 1, jnp.bfloat16)
+    a, b, e = (_dot(ones, piece, _ABT) for piece in _three(x))
+    return _rows(_add(_add(a, b), e), 0, 1)
+
+
+def _gdn_chunk(keys, values, dt, r: int, eps: float):
+    """What both kernels make of a block's chunk. ``keys``: (q, k [C, 128])
+    a key head, as the convolution left them; ``values``: (v [C, 128], the
+    cumulative gates' row [1, C], beta's row [1, C], the start state [d_v,
+    d_k], ...) a value head, ``len(values) // len(keys)`` of them to a key
+    head -> (a dict a key head, a dict a value head with the key head's
+    under ``key``).
+
+    A key head: q and k of unit length (float32, with the reciprocal
+    roots), stacked and rounded to the model's dtype, and Q K^T over K K^T
+    in ONE product [2C, 128] x [128, C]. A value head lays its table
+    exp(G_i - G_j) over them (of the differences themselves, every one that
+    is used <= 0; the column G_i is the row's own numbers, so the diagonal
+    is exp(0)), solves, and reads the state once for q and k both: with a
+    scalar decay (q e^G) S_0 = e^G (q S_0) and what the tokens write is T
+    (beta (v - e^G (k S_0))), no W of the keys' own."""
+    c, d = keys[0][0].shape[0], _LANES
+    group = len(values) // len(keys)
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    seen, earlier = _ge(row, col), _gt(row, col)
+    zero = _full((c, c), 0)
+    keyed = []
+    for q, k in keys:
+        (qn, rq), (kn, rk) = (_unit(x.astype(_F32), eps) for x in (q, k))
+        qk = jax.lax.concatenate([qn, kn], 0).astype(dt)        # [2C, 128]
+        kd = _rows(qk, c, c)
+        keyed.append(dict(qn=qn, kn=kn, rq=rq, rk=rk, qk=qk, kd=kd,
+                          scores=_dot(qk, kd, _ABT)))
+    heads = []
+    columns = _columns([row for _, grow, beta, *_ in values
+                        for row in (beta, grow)])
+    for i, (v, grow, beta, st, *_) in enumerate(values):
+        key = keyed[i // group]
+        bcol, gcol = columns[2 * i:2 * i + 2]
+        table = _decay(_minus(_cols(gcol, 0, c), _spread(grow, (c, c))))
+        last = _rows(gcol, c - 1, 1)                            # [1, 128]
+        heads.append(dict(
+            key=key, vf=v.astype(_F32), st=st, bcol=bcol, table=table,
+            sq=jax.lax.select(seen, _mul(_rows(key["scores"], 0, c), table),
+                              zero),
+            sk=jax.lax.select(earlier, _mul(_rows(key["scores"], c, c),
+                                            table), zero),
+            kept=jax.lax.exp(gcol), at_end=jax.lax.exp(last),
+            to_end=jax.lax.exp(_minus(_spread(last, (c, d)), gcol))))
+    solved = _solve_heads(
+        [_mul(_cols(f["bcol"], 0, c), f["sk"]) for f in heads], r)
+    for f, t in zip(heads, solved):
+        f.update(t=t, held=f["st"].astype(dt),
+                 k_end=_mul(f["key"]["kn"], f["to_end"]).astype(dt))
+    _staged(
+        heads,
+        # q S_0 over k S_0 [2C, d_v]
+        from_state=lambda f: _dot(f["key"]["qk"], f["held"], _ABT),
+        q_state=lambda f: _rows(f["from_state"], 0, c),
+        k_state=lambda f: _rows(f["from_state"], c, c),
+        inner=lambda f: _minus(f["vf"], _mul(f["kept"], f["k_state"])),
+        rhs=lambda f: _mul(f["bcol"], f["inner"]).astype(dt),
+        wrote=lambda f: _dot(f["t"].astype(dt), f["rhs"], _AB).astype(dt))
+    return keyed, heads
+
+
+def _staged(heads, **stages):
+    """``f[name] = stage(f)`` for every head's dict f, a stage at a time:
+    a head's chunk is a chain of products each waiting for the last, the
+    heads' chains are independent, and side by side in the program they
+    overlap in the MXU (as the pairs' solves do, ``_solve``)."""
+    for name, stage in stages.items():
+        for f in heads:
+            f[name] = stage(f)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
+def _gdn_forward_of(keys, values, *, scale: float, r: int, eps: float):
+    """A block's chunk (``_gdn_chunk``'s arguments) -> a value head (o
+    scaled in q's dtype, the state the chunk ends in)."""
+    dt, d = keys[0][0].dtype, _LANES
+    heads = _gdn_chunk(keys, values, dt, r, eps)[1]
+    _staged(
+        heads,
+        o=lambda f: _add(_mul(f["kept"], f["q_state"]),
+                         _dot(f["sq"].astype(dt), f["wrote"], _AB)),
+        ended=lambda f: _add(_mul(_spread(f["at_end"], (d, d)), f["st"]),
+                             _dot(f["wrote"], f["k_end"], _ATB)))
+    return [(_mul(f["o"], jax.lax.full_like(f["o"], scale)).astype(dt),
+             f["ended"]) for f in heads]
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, o_ref,
+                    st_ref, s_scr, *, scale: float, r: int, eps: float):
+    """Grid (B, head blocks, chunks), the chunks in order. q and k hold the
+    block's KEY heads, v, ``a`` and beta (head-major) its value heads;
+    ``s_scr`` [value heads x d_v, d_k] f32 carries their states,
+    transposed, over the chunks."""
+    d = _LANES
+    hpb = v_ref.shape[1] // d
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        s_scr[...] = _full(s_scr.shape, 0)
+
+    st_ref[...] = s_scr[...]
+    cum = _head_gate(a_ref, rows_ref)[2]
+    done = _gdn_forward_of(
+        tuple(_tiles((q_ref, k_ref), h) for h in range(q_ref.shape[1] // d)),
+        tuple(_tiles((v_ref,), h)
+              + (_rows(cum, h, 1), beta_ref[pl.ds(h, 1), :],
+                 s_scr[pl.ds(h * d, d), :]) for h in range(hpb)),
+        scale=scale, r=r, eps=eps)
+    for h, (o, st) in enumerate(done):
+        o_ref[:, pl.ds(h * d, d)] = o
+        s_scr[pl.ds(h * d, d), :] = st
+
+
+def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
+    """q, k [B, T, Hk*128], v [B, T, Hv*128], a_t and beta_t [B, Hv/hpb,
+    T/C, hpb, C] float32, rows [Hv/hpb, 16, C] (``_head_gate``) -> (o [B,
+    T, Hv*128], states [B, T/C, Hv*128, 128] f32: the state each chunk
+    starts from, transposed)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hd = v.shape
+    hpb, chunk = beta_t.shape[-2:]
+    nc, h = t // chunk, hd // _LANES
+    keys = hpb * q.shape[-1] // hd
+    s = _specs(t, chunk, hpb, reverse=False, key_heads=keys)
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, scale=scale, r=_SUB, eps=eps),
+        grid=(b, h // hpb, nc),
+        in_specs=[s["keys"]] * 2 + [s["x"], s["beta"], s["head_rows"],
+                                    s["beta"]],
+        out_specs=[s["x"], s["state"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, nc, hd, _LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["gdn_fwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * (hd * (8 * chunk + 4 * _LANES)
+                               + 2 * q.shape[-1] * chunk),
+            bytes_accessed=(2 * q.size + 2 * v.size) * q.dtype.itemsize
+            + 8 * a_t.size + 4 * b * nc * hd * _LANES,
+            transcendentals=b * t * h * (chunk + 3 * _LANES)),
+    )(q, k, v, a_t, rows, beta_t)
+
+
+def _summed(parts, onto=None):
+    """A key head's value heads' shares added up (onto the key head's
+    own)."""
+    return functools.reduce(_add, parts if onto is None else [onto] + parts)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
+def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
+    """``keys``: (q, k) a key head; ``values``: (v, the cumulative gates'
+    row, beta's row, the start state, dO, the END state's gradient) a value
+    head -> ([(dq, dk) a key head: the RAW q's and k's, through the norm,
+    summed over the key head's value heads, in q's dtype], [(dv, d(the
+    cumulative gates)'s row [1, C], dbeta's row [1, C], the START state's
+    gradient) a value head]). The chunk's matrices are made again first.
+
+    The gates' gradients are numbers a row: with P = dQ Q + dA-side K (the
+    two decayed score matrices times their gradients, elementwise) dG_i
+    gains the sums of P's row i and loses those of its column i; e^G's and
+    e^(G_C - G)'s shares are sums over a row's lanes. Every sum over the
+    lanes is one product with ones, the tokens along its lanes."""
+    dt, d = keys[0][0].dtype, _LANES
+    c = keys[0][0].shape[0]
+    keyed, heads = _gdn_chunk(keys, values, dt, r, eps)
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    seen, earlier = _ge(row, col), _gt(row, col)
+    zero = _full((c, c), 0)
+    at_last = _is(_iota((1, c), 1), c - 1)
+    for f, (*_, do, dst) in zip(heads, values):
+        do = do.astype(_F32)
+        do = _mul(do, jax.lax.full_like(do, scale))
+        f.update(do=do, dod=do.astype(dt), dst=dst, dsd=dst.astype(dt),
+                 tt=f["t"].astype(dt), bc=_cols(f["bcol"], 0, c),
+                 decayed=_mul(dst, _spread(f["at_end"], (d, d))))
+    _staged(
+        heads,
+        # what each token wrote: through o and through the end state
+        dwd=lambda f: _add(_dot(f["sq"].astype(dt), f["dod"], _ATB),
+                           _dot(f["k_end"], f["dsd"], _ABT)).astype(dt),
+        dsq=lambda f: jax.lax.select(
+            seen, _dot(f["dod"], f["wrote"], _ABT), zero),
+        dk_end=lambda f: _dot(f["wrote"], f["dsd"], _AB),        # [C, d_k]
+        d_t=lambda f: _dot(f["dwd"], f["rhs"], _ABT),            # [C, C]
+        drhs=lambda f: _dot(f["tt"], f["dwd"], _ATB),            # [C, d_v]
+        # the solve's own backward, dA = -T^T dT T^T
+        half=lambda f: _exact_dot(f["t"], f["d_t"], _ATB),
+        da=lambda f: jax.lax.select(
+            earlier, jax.lax.neg(_exact_dot(f["half"], f["t"], _ABT)), zero),
+        dsk=lambda f: _mul(f["da"], f["bc"]),
+        dinner=lambda f: _mul(f["drhs"], f["bcol"]),             # dv
+        # the state: q S_0 and k S_0 were one product, and so are these
+        dfs=lambda f: jax.lax.concatenate(
+            [_mul(f["do"], f["kept"]),
+             jax.lax.neg(_mul(f["dinner"], f["kept"]))], 0).astype(dt),
+        dst_start=lambda f: _add(f["decayed"],
+                                 _dot(f["dfs"], f["key"]["qk"], _ATB)),
+        dstate=lambda f: _dot(f["dfs"], f["held"], _AB),         # [2C, d_k]
+        ended=lambda f: _mul(f["dk_end"], _mul(f["key"]["kn"], f["to_end"])),
+        # the sums over a row's lanes, the tokens along the lanes: the
+        # gates' (e^G's share less e^(G_C - G)'s, and P's rows) and beta's
+        p=lambda f: _add(_mul(f["dsq"], f["sq"]), _mul(f["dsk"], f["sk"])),
+        gates=lambda f: _minus(_mul(f["kept"], _minus(
+            _mul(f["do"], f["q_state"]), _mul(f["dinner"], f["k_state"]))),
+            f["ended"]),
+        sums=lambda f: _lane_sums(jax.lax.concatenate([
+            jax.lax.concatenate([f["gates"], f["p"]], 1),
+            jax.lax.concatenate([_mul(f["drhs"], f["inner"]),
+                                 _mul(f["da"], f["sk"])], 1)], 0)),  # [1, 2C]
+        # G_C's own: the end state's decay, and every e^(G_C - G_i)
+        end=lambda f: _add(_sum(_sum(_mul(f["decayed"], f["st"]), 0), 1),
+                           _sum(_sum(f["ended"], 0), 1)),        # [1, 1]
+        dcum=lambda f: _add(
+            _minus(_cols(f["sums"], 0, c), _sum(f["p"], 0)), jax.lax.select(
+                at_last, _spread(f["end"], (1, c)), _full((1, c), 0))))
+    out = [(f["dinner"].astype(dt), f["dcum"], _cols(f["sums"], c, c),
+            f["dst_start"]) for f in heads]
+    dkeys = []
+    for key in keyed:     # what its value heads add up for the key head
+        mine = [f for f in heads if f["key"] is key]
+        dscores = _summed([jax.lax.concatenate(
+            [_mul(f["dsq"], f["table"]), _mul(f["dsk"], f["table"])], 0)
+            for f in mine]).astype(dt)                           # [2C, C]
+        dqk = _summed([f["dstate"] for f in mine],
+                      _dot(dscores, key["kd"], _AB))             # [2C, d_k]
+        dk = _summed([_mul(f["dk_end"], f["to_end"]) for f in mine], _add(
+            _rows(dqk, c, c), _dot(dscores, key["qk"], _ATB)))
+        dkeys.append((
+            _unit_bwd(_rows(dqk, 0, c), key["qn"], key["rq"]).astype(dt),
+            _unit_bwd(dk, key["kn"], key["rk"]).astype(dt)))
+    return dkeys, out
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, st_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, da_ref, drows_ref,
+                    dbeta_ref, ds_scr, *, scale: float, r: int, eps: float):
+    """Grid (B, head blocks, chunks from the last). dq and dk are the
+    block's key heads'; da and dbeta head-major, float32; ``drows`` [16, C]
+    the partial sums of A_log's gradient (rows 0.., sum dg g) and dt_bias's
+    (rows 8.., sum da) a token of the chunk over the chunks walked so far
+    (the block stays in VMEM over the sequential axis); ``ds_scr`` the
+    gradient of the states the chunk ENDS in, transposed."""
+    d = _LANES
+    hpb = v_ref.shape[1] // d
+
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        ds_scr[...] = _full(ds_scr.shape, 0)
+        drows_ref[...] = _full(drows_ref.shape, 0)
+
+    g, slope, cum = _head_gate(a_ref, rows_ref)
+    dkeys, done = _gdn_backward_of(
+        tuple(_tiles((q_ref, k_ref), h) for h in range(q_ref.shape[1] // d)),
+        tuple(_tiles((v_ref,), h)
+              + (_rows(cum, h, 1), beta_ref[pl.ds(h, 1), :],
+                 st_ref[pl.ds(h * d, d), :])
+              + _tiles((do_ref,), h) + (ds_scr[pl.ds(h * d, d), :],)
+              for h in range(hpb)), scale=scale, r=r, eps=eps)
+    for h, (dq, dk) in enumerate(dkeys):
+        dq_ref[:, pl.ds(h * d, d)], dk_ref[:, pl.ds(h * d, d)] = dq, dk
+    for h, (dv, _, dbeta, dst) in enumerate(done):
+        dv_ref[:, pl.ds(h * d, d)] = dv
+        dbeta_ref[pl.ds(h, 1), :] = dbeta
+        ds_scr[pl.ds(h * d, d), :] = dst
+    # dg from d(cumulative sum): the sums from each token to the chunk's
+    # end; then the gate's chain rule, all on the one [8, C] tile
+    dg = _exact_dot(_head_tile([dc for _, dc, _, _ in done]),
+                    _lower_ones(a_ref.shape[1]), _AB)
+    da = _mul(dg, slope)
+    for h in range(hpb):
+        da_ref[pl.ds(h, 1), :] = _rows(da, h, 1)
+    drows_ref[...] = _add(drows_ref[...],
+                          jax.lax.concatenate([_mul(dg, g), da], 0))
+
+
+def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
+    """-> [dq, dk [B, T, Hk*128], dv [B, T, Hv*128] (q's dtype), da_t f32,
+    drows [B, Hv/hpb, 16, C] f32, dbeta_t f32]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, hd = v.shape
+    hpb, chunk = beta_t.shape[-2:]
+    nc, h = t // chunk, hd // _LANES
+    keys = hpb * q.shape[-1] // hd
+    s = _specs(t, chunk, hpb, reverse=True, key_heads=keys)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, scale=scale, r=_SUB, eps=eps),
+        grid=(b, h // hpb, nc),
+        in_specs=[s["keys"]] * 2 + [s["x"], s["beta"], s["head_rows"],
+                                    s["beta"], s["state"], s["x"]],
+        out_specs=[s["keys"]] * 2 + [s["x"], s["beta"], s["head_drows"],
+                                     s["beta"]],
+        out_shape=[like(q), like(k), like(v), like(a_t),
+                   jax.ShapeDtypeStruct((b,) + rows.shape, _F32),
+                   like(beta_t)],
+        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["gdn_bwd"],
+        interpret=_flash._use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * t * (hd * (16 * chunk + 10 * _LANES)
+                               + 6 * q.shape[-1] * chunk),
+            bytes_accessed=(4 * q.size + 3 * v.size) * q.dtype.itemsize
+            + 16 * a_t.size + 4 * b * nc * hd * _LANES,
+            transcendentals=b * t * h * (chunk + 3 * _LANES)),
+    )(q, k, v, a_t, rows, beta_t, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _gdn_kernels(q, k, v, a_t, rows, beta_t, scale, eps):
+    return _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps)[0]
+
+
+def _gdn_vjp_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, states = _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps)
+    o = checkpoint_name(o, "kda_out")             # as ``_kda_vjp_fwd``
+    states = checkpoint_name(states, "kda_states")
+    return o, (q, k, v, a_t, rows, beta_t, states)
+
+
+def _gdn_vjp_bwd(scale, eps, res, do):
+    dq, dk, dv, da, drows, dbeta = _gdn_bwd(*res, do, scale, eps)
+    # the rows' partial sums a batch row -> the rows' gradient (a token of
+    # the chunk still: autodiff adds those, the rows being a broadcast)
+    return dq, dk, dv, da, drows.sum(0), dbeta
+
+
+_gdn_kernels.defvjp(_gdn_vjp_fwd, _gdn_vjp_bwd)
+
+
+def _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta, *, key_heads: int,
+                      hpb: int, scale: float, eps: float, chunk: int):
+    """A Gated DeltaNet layer's call through the pair with the body for one
+    decay a head: q and k as they are, [B, T, Hk*128]; ``a`` and beta go in
+    head-major (``_head_major``) in float32, A_log and dt_bias as rows a
+    head block."""
+    t, heads = q.shape[1], beta.shape[-1]
+    d = v.shape[-1] // heads
+    (q, k, v, a, beta), pad = pad_tokens(
+        (q, k, v, a.astype(_F32), beta.astype(_F32)), chunk)
+    facts = _path_facts(chunk, t, pad, heads, d, d, eps, key_heads)
+    facts["heads_per_block"] = hpb
+    record_path("rtpu.ops.kda.path", PATH_COUNTS, "kernel", facts)
+    rows = jnp.pad(jnp.stack([a_log, dt_bias]).reshape(2, heads // hpb, hpb),
+                   ((0, 0), (0, 0), (0, _HEAD_ROWS - hpb)))
+    rows = jnp.broadcast_to(
+        rows.transpose(1, 0, 2).reshape(heads // hpb, 2 * _HEAD_ROWS, 1),
+        (heads // hpb, 2 * _HEAD_ROWS, chunk))
+    return _gdn_kernels(q, k, v, _head_major(a, chunk, hpb), rows,
+                        _head_major(beta, chunk, hpb), float(scale),
+                        float(eps))[:, :t]
 
 
 def _route(d_k: int, d_v: int, chunk: int) -> str:
@@ -1144,23 +1688,24 @@ def _route(d_k: int, d_v: int, chunk: int) -> str:
 
 def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):
     """The facts of ``rtpu.ops.kda.path`` that every entry states.
-    ``key_heads`` None: a decay a key channel, a key head a value head."""
+    ``key_heads`` None: a decay a key channel, a key head a value head, and
+    the body that makes the decayed scores in sub-blocks; else the body
+    with the head's one decay factored out of them (``body``: which
+    program a run measured; PR 52 ran ``decay: head`` through
+    ``channel_decay``)."""
     return {"chunk": chunk, "tokens": tokens, "padded_tokens": pad,
             "heads": heads, "d_k": d_k, "d_v": d_v,
             "chunks": (tokens + pad) // chunk,
             "prologue": "jnp" if eps is None else "in_kernel",
             "decay": "channel" if key_heads is None else "head",
+            "body": "channel_decay" if key_heads is None else "head_decay",
             "key_heads": key_heads or heads}
 
 
-def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps,
-          key_heads=None):
-    """What KDA's two entries and Gated DeltaNet's kernel route share: the
-    route by what the call shows, whole chunks, the path event. ``gate``:
-    (g,) with ``eps`` None, or the prologue's (step, rows) on the kernel
-    route. ``key_heads``: None for a decay a key channel; the key heads
-    the caller repeated to the value heads for a decay a head (a fact of
-    the event, no part of the computation: q and k come repeated)."""
+def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
+    """What KDA's two entries share: the route by what the call shows,
+    whole chunks, the path event. ``gate``: (g,) with ``eps`` None, or the
+    prologue's (step, rows) on the kernel route."""
     b, t, _ = q.shape
     heads = beta.shape[-1]
     d_k, d_v = k.shape[-1] // heads, v.shape[-1] // heads
@@ -1170,7 +1715,7 @@ def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps,
     (q, k, v, lead, beta), pad = pad_tokens(
         (q, k, v, gate[0], beta.astype(_F32)), chunk)
     gate = (lead,) + tuple(gate[1:])
-    facts = _path_facts(chunk, t, pad, heads, d_k, d_v, eps, key_heads)
+    facts = _path_facts(chunk, t, pad, heads, d_k, d_v, eps, None)
     if route == "kernel":
         hpb = facts["heads_per_block"] = _heads_per_block(heads)
     record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
@@ -1267,29 +1812,25 @@ def gdn_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
     q, k [batch, seq, key_heads * d], v [batch, seq, value_heads * d], a
     and beta [batch, seq, value_heads], a_log and dt_bias [value_heads].
     Differentiable in all seven arrays. That sentence is this function on
-    the plain route, literally. On the kernel route (heads of 128, chunk
-    64) it is KDA's kernel pair unchanged, a decay that is constant over a
-    head's lanes being a case of the one they compute: q and k repeated to
-    the value heads, ``a`` spread over a head's 128 lanes as the
-    prologue's ``step``, ``a_log`` and ``dt_bias`` as its two rows; the
-    norms and the gate are made in the kernels and no float32 g is
-    written. (A body of the pair for the scalar decay would read q and k
-    once a key head and make the scores in one product: ROADMAP A.)"""
+    the plain route, literally. On the kernel route (heads of 128, a chunk
+    of 64, a block of at most four value heads that holds whole key heads)
+    it is the kernel pair with the body for one decay a head (the module's
+    docstring): a program reads q and k ONCE a key head from these arrays
+    as they are, makes the norms, the gate and its cumulative sums itself
+    (a number a value head and token: no float32 g, no copy of ``a`` over a
+    head's lanes is written), one product of scores a key head and a [C,
+    C] table of decays a value head over it; dq and dk come back summed
+    over a key head's value heads."""
     heads = beta.shape[-1]
     d = v.shape[-1] // heads
     key_heads = k.shape[-1] // d
-    group = heads // key_heads
     a_log, dt_bias = a_log.astype(_F32), dt_bias.astype(_F32)
-    if _route(d, d, chunk) == "kernel":
-        def to_value_heads(x):
-            return jnp.repeat(x.reshape(*x.shape[:2], key_heads, d), group,
-                              axis=2).reshape(*x.shape[:2], heads * d)
-
-        lanes = lambda x: jnp.repeat(x, d, axis=-1)          # noqa: E731
-        return _scan(to_value_heads(q), to_value_heads(k), v,
-                     (lanes(a), jnp.stack([lanes(a_log), lanes(dt_bias)])),
-                     beta, scale=scale, chunk=chunk, eps=float(eps),
-                     key_heads=key_heads)
+    hpb = _gdn_heads_per_block(heads, heads // key_heads) \
+        if heads % key_heads == 0 and q.shape[-1] == k.shape[-1] else None
+    if _route(d, d, chunk) == "kernel" and hpb:
+        return _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta,
+                                 key_heads=key_heads, hpb=hpb, scale=scale,
+                                 eps=eps, chunk=chunk)
     unit = lambda x: l2norm(                                 # noqa: E731
         x.reshape(*x.shape[:2], key_heads, d), eps).reshape(x.shape)
     g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(_F32) + dt_bias)

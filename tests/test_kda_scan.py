@@ -437,7 +437,8 @@ def test_path_event_and_padding(route, entry):
     facts = {"route": route, "chunk": 64, "tokens": 150,
              "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3,
              "prologue": "in_kernel" if (entry, route) == ("gated", "kernel")
-             else "jnp", "decay": "channel", "key_heads": 2}
+             else "jnp", "decay": "channel", "key_heads": 2,
+             "body": "channel_decay"}     # ISSUE 53: which body a run measured
     if route == "kernel":
         facts["heads_per_block"] = 2
     assert events and events[-1]["data"] == facts
@@ -683,23 +684,216 @@ def test_gated_delta_scan_refuses_heads_that_do_not_group():
         kda.gated_delta_scan(*(args[n] for n in GDN), scale=1.0)
 
 
-def test_a_gated_deltanet_layers_scan_is_the_recurrence(route, t=150):
+def in_dtype(args, dtype):
+    """A layer's arguments with what the model computes in its own dtype
+    (q, k, v and ``a``) cast to it; A_log, dt_bias and beta stay float32."""
+    return {n: x.astype(dtype) if n in ("q", "k", "v", "a") else x
+            for n, x in args.items()}
+
+
+def gdn_layer_worst(got, want, args):
+    """``gdn_worst`` for arguments of any dtype."""
+    f32 = lambda tree: {n: x.astype(jnp.float32)             # noqa: E731
+                        for n, x in tree.items()}
+    return gdn_worst(f32(got), f32(want), f32(args))
+
+
+@pytest.mark.parametrize("t,heads", [
+    (150, (2, 2)), (150, (2, 4)), (150, (1, 4)), (150, (1, 3)),
+    (150, (3, 3)), (64, (2, 4)), (40, (2, 4))],
+    ids=["ragged-one_to_one", "ragged-two_to_one", "ragged-four_to_one",
+         "ragged-three_to_one", "ragged-odd_heads", "one_chunk", "short"])
+def test_a_gated_deltanet_layers_scan_is_the_recurrence(route, t, heads):
     """``gdn_gated_scan`` from what a layer's convolution and b | a
-    projection leave, on the kernel route (KDA's pair, q and k repeated,
-    ``a`` over a head's lanes: the norms and the gate made in the kernels)
-    and on the plain one (``l2norm``, the softplus, ``gated_delta_scan``):
-    o and seven gradients against the recurrence."""
-    args, do = gdn_arguments(2, t, raw=True)
+    projection leave, on the kernel route (ISSUE 53: the pair's body for
+    one decay a head; q and k read once a key head, the norms, the gate and
+    its cumulative sums made in the kernels) and on the plain one
+    (``l2norm``, the softplus, ``gated_delta_scan``): o and seven gradients
+    against the recurrence. One, two, three and four value heads to a key
+    head (a program works four value heads over four, two and one key
+    heads, or three over one; three key heads of one value head each are
+    solved one by one), T ragged, one chunk, shorter than a chunk."""
+    args, do = gdn_arguments(2, t, *heads, raw=True)
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do)
+    took(route, before)
+    assert got["o"].shape == (2, t, heads[1] * D)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+
+
+def test_value_heads_no_block_holds_take_the_plain_route():
+    """Eight value heads to a key head: a program works at most four, and
+    dq and dk leave the backward kernel summed over a key head's value
+    heads, so a block holds whole key heads or the call is the plain
+    route's. It falls back, and the event says so."""
+    assert [kda._gdn_heads_per_block(h, g) for h, g in (
+        (32, 2), (4, 1), (4, 4), (6, 1), (6, 3), (3, 1), (8, 8), (10, 5))
+    ] == [4, 4, 4, 2, 3, 3, None, None]
+    args, do = gdn_arguments(2, 64, 1, 8, raw=True)
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do)
+    took("chunked_jnp", before)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+
+
+def test_a_gated_deltanet_layers_scan_under_the_strongest_decay(route):
+    """g = -20 a token through the layer's entry (A_log = log 20, softplus(
+    a + dt_bias) = 1): a chunk's cumulative gate reaches -1280 and the
+    table's exponents above the diagonal +1280, which are never taken (a
+    table factored as exp(G_i) exp(-G_j) would be inf x 0). The state is
+    forgotten between tokens, o_t = scale beta_t (q_t . k_t) v_t with q and
+    k of unit length, every gradient finite."""
+    args, do = gdn_arguments(1, 150, raw=True)
+    args.update(a=jnp.zeros_like(args["a"]),
+                a_log=jnp.full((4,), np.log(20.0), jnp.float32),
+                dt_bias=jnp.full((4,), np.log(np.e - 1), jnp.float32))
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do)
+    took(route, before)
+    for name, v in got.items():
+        assert bool(jnp.all(jnp.isfinite(v))), name
+    # the gate's gradients are of the order exp(-20) (KDA's test): held to
+    # zero, not to a share of themselves
+    zero = {"a": 1e-6, "dt_bias": 1e-4, "a_log": 1e-4}
+    for name, limit in zero.items():
+        assert float(jnp.max(jnp.abs(got[name] - want[name]))) < limit, name
+    for name, err in gdn_worst(got, want, args).items():
+        assert name in zero or err < F32_TOL, (name, err)
+    q, k = (to_value_heads(unit_heads(args[n], 2), 2, 4).reshape(
+        2, 150, 4, D) for n in "qk")
+    alone = (D ** -0.5 * args["beta"] * (q * k).sum(-1))[..., None] \
+        * args["v"].reshape(2, 150, 4, D)
+    np.testing.assert_allclose(got["o"], alone.reshape(2, 150, -1),
+                               atol=1e-6)
+
+
+def test_a_gated_deltanet_layers_keys_alike_are_solved_in_blocks(
+        monkeypatch, route):
+    """``test_keys_alike_are_solved_in_blocks`` for the body with one decay
+    a head: neighbouring keys alike in direction, beta 0.9, a weak decay.
+    The solve in blocks of 8, merged, is the one in use on both routes:
+    with one block of 64 (the Neumann product over the whole chunk) o is
+    wrong by more than its own size."""
+    args, do = gdn_arguments(5, 150, raw=True)
+    r = jax.random.split(jax.random.PRNGKey(105), 2)
+    k = jax.random.normal(r[0], (2, 1, 2, D)) \
+        + 0.5 * jax.random.normal(r[1], (2, 150, 2, D))
+    args.update(k=k.reshape(2, 150, 2 * D), beta=jnp.full((2, 150, 4), 0.9),
+                a_log=args["a_log"] + np.log(0.05))
     before = kda.PATH_COUNTS.copy()
     got, want = gdn_both(args, do)
     took(route, before)
     for name, err in gdn_worst(got, want, args).items():
         assert err < F32_TOL, (name, err)
+    monkeypatch.setattr(kda, "_SUB", 64)        # one block: the whole chunk
+    got, _ = gdn_both(args, do)
+    assert not gdn_worst({"o": got["o"]}, {"o": want["o"]}, {})["o"] < 1.0
+
+
+def test_a_gated_deltanet_layers_bf16_arguments(route):
+    """The model's call: bf16 q, k, v and ``a``; A_log, dt_bias and beta
+    float32. Products on bf16 operands, everything a gate touches float32:
+    3e-2 of the largest entry, as KDA's."""
+    args, do = gdn_arguments(3, 150, raw=True)
+    args = in_dtype(args, jnp.bfloat16)
+    before = kda.PATH_COUNTS.copy()
+    got, want = gdn_both(args, do)
+    took(route, before)
+    assert got["o"].dtype == jnp.bfloat16
+    for name, err in gdn_layer_worst(got, want, args).items():
+        assert err < 3e-2, (name, err)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32_TOL),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_head_decay_kernels_gradients_are_the_plain_routes(
+        dtype, tol, monkeypatch):
+    """ISSUE 53: o and the SEVEN gradients of ``gdn_gated_scan`` on the
+    kernel pair against ``jax.vjp`` of its plain route (``l2norm``, the
+    softplus, ``gated_delta_scan``), 3 chunks of a block of 4 value heads
+    over 2 key heads. dq and dk leave the backward kernel summed over a key
+    head's value heads, in q's dtype and shape; A_log's and dt_bias's as
+    partial sums a batch row, head and token of the chunk, held to what
+    they sum. With bf16 arguments o differs by a rounding of bf16 (the
+    kernels keep the normalised q and k and e^G (k S_0) in float32 where
+    the plain form rounds them first)."""
+    args, do = gdn_arguments(6, 192, raw=True)
+    args = in_dtype(args, dtype)
+    before = kda.PATH_COUNTS.copy()
+    got, _ = gdn_both(args, do)
+    took("kernel", before)
+    monkeypatch.setattr(kda, "_route", lambda *shape: "chunked_jnp")
+    before = kda.PATH_COUNTS.copy()
+    want, _ = gdn_both(args, do)
+    took("chunked_jnp", before)
+    for name, err in gdn_layer_worst(got, want, args).items():
+        assert err < (8e-3 if name == "o" and tol > 1e-3 else tol), (name, err)
+    for name in got:
+        assert got[name].shape == want[name].shape
+        assert got[name].dtype == (
+            jnp.float32 if name in ("a_log", "dt_bias", "beta") else dtype)
+
+
+def _traced_again():
+    """The pair's pure bodies are traced once a process (``jax.jit``): a
+    fault planted in what they call shows only to a fresh trace, and must
+    not outlive the test."""
+    kda._gdn_forward_of.clear_cache()
+    kda._gdn_backward_of.clear_cache()
+
+
+def _the_decay_table_dropped(monkeypatch):
+    """The value heads' tables exp(G_i - G_j) left off the shared scores:
+    every earlier token weighs as the newest."""
+    monkeypatch.setattr(kda, "_decay", lambda d: jnp.ones_like(d))
+    return ("o", "q", "k", "v", "a"), 1e-2
+
+
+def _dq_of_one_value_head(monkeypatch):
+    """dq and dk taken from the key head's LAST value head alone, not
+    summed over the pair: o and what belongs to a value head are right."""
+    monkeypatch.setattr(kda, "_summed",
+                        lambda parts, onto=None: parts[-1] if onto is None
+                        else onto + parts[-1])
+    return ("q", "k"), 1e-2
+
+
+@pytest.mark.parametrize("fault", ["table_dropped", "dq_of_one_value_head"])
+def test_a_wrong_head_decay_body_would_fail(fault, monkeypatch):
+    """What the limits of the kernel route are for: two faults planted in
+    the new body, each reads a thousand times over them."""
+    args, do = gdn_arguments(2, 150, raw=True)
+    plant = {"table_dropped": _the_decay_table_dropped,
+             "dq_of_one_value_head": _dq_of_one_value_head}[fault]
+    _traced_again()
+    try:
+        with monkeypatch.context() as m:
+            wrong, at_least = plant(m)
+            before = kda.PATH_COUNTS.copy()
+            got, want = gdn_both(args, do)
+            took("kernel", before)
+    finally:
+        _traced_again()
+    err = gdn_worst(got, want, args)
+    assert min(err[n] for n in wrong) > at_least > 10 * F32_TOL, err
+    right = set(err) - set(wrong) if fault == "dq_of_one_value_head" else ()
+    assert all(err[n] < F32_TOL for n in right), err
+    # and the body as it is, traced afresh, is right again
+    got, want = gdn_both(args, do)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
 
 
 def test_a_gated_deltanet_scans_path_event(route):
-    """ISSUE 52's facts of ``rtpu.ops.kda.path``: ``decay`` (``head``: one
-    a head, or ``channel``: KDA's) and ``key_heads``."""
+    """The facts of ``rtpu.ops.kda.path`` for a Gated DeltaNet layer's
+    call: ``decay`` (``head``: one a head, or ``channel``: KDA's),
+    ``key_heads`` (ISSUE 52) and ``body`` (ISSUE 53: ``head_decay``, the
+    program with the decay factored out of the scores, on both routes;
+    PR 52's kernel route said ``decay: head`` and ran ``channel_decay``);
+    on the kernel route four value heads a program, whole key heads."""
     from ray_tpu.perf import recorder
 
     args, _ = gdn_arguments(4, 150, raw=True)
@@ -709,7 +903,7 @@ def test_a_gated_deltanet_scans_path_event(route):
             if e["kind"] == "rtpu.ops.kda.path"][-1]
     facts = {"route": route, "chunk": 64, "tokens": 150, "padded_tokens": 42,
              "heads": 4, "d_k": D, "d_v": D, "chunks": 3, "decay": "head",
-             "key_heads": 2,
+             "key_heads": 2, "body": "head_decay",
              "prologue": "in_kernel" if route == "kernel" else "jnp"}
     if route == "kernel":
         facts["heads_per_block"] = 4

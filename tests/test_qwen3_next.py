@@ -25,9 +25,10 @@ a head makes the state less tender than KDA's, which read 2.2e-3).
 
 The tiny preset's Gated DeltaNet heads are the published 128 x 128 and the
 scan's chunk 64, so the fixture ``tiny`` runs the KERNEL route
-(interpreted here), q and k repeated to the value heads and ``a`` spread
-over a head's lanes; T = 128 is one block of the flash kernels and two
-chunks of the scan. ``plain_route`` is the same model with the scan's
+(interpreted here; ISSUE 53: the pair's body for one decay a head, a
+program the 4 value heads over their 2 key heads, q and k read once a key
+head and ``a`` a number a value head and token); T = 128 is one block of
+the flash kernels and two chunks of the scan. ``plain_route`` is the same model with the scan's
 route held to ``chunked_jnp``: ``l2norm``, the softplus and
 ``gated_delta_scan``, literally. Both are held to the same reference by
 the same limits.
@@ -150,14 +151,16 @@ def _grads_agree(params, grads, ref_grads):
 
 def test_gradients_equal_the_references(tiny):
     """Every parameter, through the kernel route: ``A_log`` and ``dt_bias``
-    (one a value head) from the backward kernel's partial sums over a
-    head's lanes, ``w_ba`` through its dstep summed over them, the
-    convolution through dq and dk summed over a key head's value heads."""
+    (one a value head) from the backward kernel's partial sums a token of
+    the chunk, ``w_ba`` through its da, the convolution through dq and dk,
+    which the backward kernel sums over a key head's value heads. The
+    routes the fixture took say which body ran (ISSUE 53)."""
     _, params, _, _, (_, grads), (_, ref_grads) = tiny
-    made = {(e["decay"], e["key_heads"], e["prologue"])
+    made = {(e["decay"], e["body"], e["key_heads"], e["heads_per_block"],
+             e["prologue"])
             for e in _kda_path_events()
             if e["route"] == "kernel" and e["tokens"] == 128}
-    assert ("head", 2, "in_kernel") in made
+    assert made == {("head", "head_decay", 2, 4, "in_kernel")}
     _grads_agree(params, grads, ref_grads)
 
 

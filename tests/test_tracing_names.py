@@ -239,8 +239,12 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
     under."""
     from ray_tpu.perf.recorder import get_recorder
 
+    # ISSUE 53: the body for one decay a head is a pair of its own names, so
+    # a trace's breakdown tells the two rules apart
     assert kda.KERNEL_NAMES == {"fwd": "kda_chunk_fwd",
-                                "bwd": "kda_chunk_bwd"}
+                                "bwd": "kda_chunk_bwd",
+                                "gdn_fwd": "gdn_chunk_fwd",
+                                "gdn_bwd": "gdn_chunk_bwd"}
     m = KimiLinear(KimiLinearConfig.tiny(experts_held=2))
     p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 150), jnp.int32)
@@ -262,10 +266,15 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
         "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
         "chunks": 3, "heads_per_block": 2, "prologue": "in_kernel",
         # ISSUE 52: KDA's decay is one a key channel, a key head a value head
-        "decay": "channel", "key_heads": 2}
-    # both kernels stand under the scope the roofline reads
-    for name in kda.KERNEL_NAMES.values():
+        "decay": "channel", "key_heads": 2,
+        # ISSUE 53: the body that makes the decayed scores in sub-blocks
+        "body": "channel_decay"}
+    # both kernels stand under the scope the roofline reads, and the other
+    # rule's are not in this program
+    for part in ("fwd", "bwd"):
+        name = kda.KERNEL_NAMES[part]
         assert re.search(r"scan/[^\n]*" + name, text), name
+        assert kda.KERNEL_NAMES["gdn_" + part] not in text
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "kimi_linear"][-1]
     assert runs["data"]["runs"] == [["kda_dense", 1], ["kda_moe", 2],
@@ -276,16 +285,20 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
 
 
 def test_a_gated_deltanet_stack_leaves_its_events():
-    """ISSUE 52: what a Qwen3-Next shaped loss leaves at trace time.
+    """ISSUES 52, 53: what a Qwen3-Next shaped loss leaves at trace time.
     ``rtpu.ops.kda.path``: the kernel route with ``decay`` ``head`` (one a
-    head, spread over its lanes) and the ``key_heads`` that were repeated to
-    the 4 value heads, the norms and the gate made in the kernels;
+    head) through the ``body`` ``head_decay`` (the decay factored out of
+    the scores; PR 52 ran it through ``channel_decay``), a program the 4
+    value heads over the 2 ``key_heads`` they read, the norms and the gate
+    made in the kernels;
     ``rtpu.ops.expert_layer``: ``score`` ``softmax`` and ``shared_gate``;
     ``rtpu.ops.flash.path``: heads of 256 on the ``relayout`` route;
     ``rtpu.models.stack.runs``: 3 scanned Gated DeltaNet layers that keep
     their inputs alone, then the attention layer with the flash kernels'
-    output and row statistics. KDA's two kernels stand under the scope
-    ``scan``, where ``gdn_scan_roofline`` finds their time."""
+    output and row statistics. The body's own two kernels (``gdn_chunk_fwd``
+    / ``gdn_chunk_bwd``) stand under the scope ``scan``, where
+    ``gdn_scan_roofline`` finds their time, and KDA's are not in the
+    program."""
     from ray_tpu.perf.recorder import get_recorder
 
     m = Qwen3Next(Qwen3NextConfig.tiny(experts_held=2, expert_offset=4))
@@ -306,15 +319,17 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "route": "kernel", "chunk": 64, "tokens": 256, "padded_tokens": 0,
         "heads": 4, "d_k": 128, "d_v": 128, "chunks": 4,
         "heads_per_block": 4, "prologue": "in_kernel", "decay": "head",
-        "key_heads": 2}
+        "key_heads": 2, "body": "head_decay"}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
         "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True}
     flash = last("rtpu.ops.flash.path")
     assert flash["label"] == "relayout" and flash["data"]["hd"] == 256
-    for name in kda.KERNEL_NAMES.values():
+    for part in ("fwd", "bwd"):
+        name = kda.KERNEL_NAMES["gdn_" + part]
         assert re.search(r"scan/[^\n]*" + name, text), name
+        assert kda.KERNEL_NAMES[part] not in text
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "qwen3_next"][-1]
     assert runs["data"]["runs"] == [["gdn_moe", 3], ["attn_moe", 1]]
