@@ -20,6 +20,7 @@ from typing import Any, Callable, List, Optional, Sequence
 import ray_tpu
 from ray_tpu.core.placement_group import placement_group, remove_placement_group
 
+from ..perf.chipwatch import start_chip_watch
 from ..perf.jaxbuild import install_jax_spans
 from ..perf.recorder import get_recorder
 from .mesh import MeshSpec
@@ -71,6 +72,9 @@ class MeshWorkerMixin:
                                      coordinator, devices_per_process)
         # from here every program this worker builds leaves rtpu.jax.*
         install_jax_spans()
+        # and, where the devices are TPUs, the chip's counters and the
+        # host's are sampled into the ring (rtpu.chip.sample, .stall)
+        start_chip_watch(devs)
         with rec.span("rtpu.train.mesh") as mesh:
             from .sharding import MeshOwner
 

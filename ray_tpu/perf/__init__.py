@@ -13,6 +13,10 @@ something to prove with:
 - :mod:`ray_tpu.perf.jaxbuild` — jax's own account of building a
   program (trace, lower, compile or the read from the compile cache) as
   ``rtpu.jax.*`` spans of that ring, and the compile counter.
+- :mod:`ray_tpu.perf.chipwatch` — the chip watcher: in the process that
+  holds a TPU, the runtime's own counters and the host's, sampled a few
+  times a second into that ring (``rtpu.chip.sample``), and a standstill
+  of device and host together as a span (``rtpu.chip.stall``).
 - :mod:`ray_tpu.perf.report` — :class:`StepReport`: the structured
   result of ``CompiledPipelineEngine.profile()`` /
   ``LLMEngine.profile()``, with per-stage exec/bubble/recv/sync
@@ -26,6 +30,7 @@ something to prove with:
 docs/OBSERVABILITY.md "Profiling & post-mortem" is the schema
 reference.
 """
+from .chipwatch import start_chip_watch  # noqa: F401
 from .jaxbuild import install_jax_spans  # noqa: F401
 from .recorder import (FlightRecorder, get_recorder, record,  # noqa: F401
                        recorder_enabled, set_enabled)
@@ -36,7 +41,7 @@ from .postmortem import (dump_bundle, last_bundle_path,  # noqa: F401
 
 __all__ = [
     "FlightRecorder", "get_recorder", "record", "recorder_enabled",
-    "set_enabled", "install_jax_spans", "StepReport",
+    "set_enabled", "install_jax_spans", "start_chip_watch", "StepReport",
     "analytic_bubble_frac", "compute_mfu",
     "dump_bundle", "last_bundle_path", "load_bundle", "render_bundle",
 ]

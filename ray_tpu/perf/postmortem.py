@@ -29,7 +29,8 @@ from ..util import metrics as _metrics
 from .recorder import get_recorder
 
 __all__ = ["bundle_dir", "dump_bundle", "load_bundle", "render_bundle",
-           "last_bundle_path", "find_dangling"]
+           "last_bundle_path", "find_dangling", "fetch_rings",
+           "spans_and_tail"]
 
 _C_BUNDLES = _metrics.Counter(
     "ray_tpu_postmortem_bundles_total",
@@ -56,7 +57,8 @@ def dump_bundle(reason: str, origin: str = "driver",
                     Dict[str, Callable[[], List[dict]]]] = None,
                 meta: Optional[dict] = None,
                 throttle: bool = True,
-                path: Optional[str] = None) -> Optional[str]:
+                path: Optional[str] = None,
+                origin_ring: Optional[List[dict]] = None) -> Optional[str]:
     """Write one merged bundle and return its path (None when
     throttled). ``extra_rings`` are pre-drained event lists keyed by
     process label; ``ring_fetchers`` are best-effort callables (worker
@@ -64,7 +66,10 @@ def dump_bundle(reason: str, origin: str = "driver",
     killing the dump, because the abort being recorded may be the very
     thing that made the worker unreachable. ``path`` is where the bundle
     goes (a run's own record, ``<Result.path>/flight.json``); without it
-    a file of its own name under ``bundle_dir()``."""
+    a file of its own name under ``bundle_dir()``. ``origin_ring`` stands
+    in for this process's ring where the caller has cut it (``fit()``'s
+    record: ``spans_and_tail``); every abort path leaves it out and gets
+    the ring whole."""
     global _last_path, _seq
     key = (origin, reason.split(":", 1)[0])
     now = time.monotonic()
@@ -75,15 +80,11 @@ def dump_bundle(reason: str, origin: str = "driver",
                 return None
             _recent[key] = now
     rings: Dict[str, List[dict]] = {
-        origin: get_recorder().snapshot(clear=False)}
+        origin: get_recorder().snapshot(clear=False)
+        if origin_ring is None else list(origin_ring)}
     for proc, events in (extra_rings or {}).items():
         rings[proc] = list(events or ())
-    for proc, fetch in (ring_fetchers or {}).items():
-        try:
-            rings[proc] = list(fetch() or ())
-        except Exception as e:
-            rings[proc] = [{"ts": time.time(), "kind": "postmortem.fetch_error",
-                            "label": proc, "data": {"error": repr(e)}}]
+    rings.update(fetch_rings(ring_fetchers or {}))
     bundle = {"reason": reason, "origin": origin, "time": time.time(),
               "rings": rings, "meta": meta or {}}
     if path is None:
@@ -103,9 +104,42 @@ def dump_bundle(reason: str, origin: str = "driver",
     return path
 
 
+def fetch_rings(ring_fetchers: Dict[str, Callable[[], List[dict]]]
+                ) -> Dict[str, List[dict]]:
+    """Every fetcher's ring, for ``dump_bundle``'s ``extra_rings``: what
+    ``ring_fetchers`` does inside it, for a caller that writes the rings
+    twice (``fit()``'s record of a stalled run)."""
+    rings = {}
+    for proc, fetch in ring_fetchers.items():
+        try:
+            rings[proc] = list(fetch() or ())
+        except Exception as e:
+            rings[proc] = [{"ts": time.time(), "kind": "postmortem.fetch_error",
+                            "label": proc, "data": {"error": repr(e)}}]
+    return rings
+
+
+def spans_and_tail(events: List[dict], tail: int = 256) -> List[dict]:
+    """A ring cut to its spans and its last ``tail`` instant events, in
+    the ring's order: a driver's ring is nine tenths ``dispatch.*`` events
+    of a trainer's polling, fifty a second, and what a run's record needs
+    of it is where its phases went and how it ended."""
+    instants = [i for i, ev in enumerate(events) if "dur" not in ev]
+    dropped = set(instants[:-tail] if tail else instants)
+    return [ev for i, ev in enumerate(events) if i not in dropped]
+
+
 def last_bundle_path() -> Optional[str]:
+    """The bundle this process wrote last; for a process that wrote none
+    (``ray_tpu postmortem`` without a path), the newest in
+    ``bundle_dir()``."""
     with _lock:
-        return _last_path
+        if _last_path is not None:
+            return _last_path
+    d = bundle_dir()
+    kept = [os.path.join(d, f) for f in os.listdir(d)
+            if f.startswith("postmortem-") and f.endswith(".json")]
+    return max(kept, key=os.path.getmtime, default=None)
 
 
 def load_bundle(path: str) -> dict:

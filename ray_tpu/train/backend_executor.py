@@ -22,7 +22,8 @@ from ..parallel.mesh import MeshSpec
 from ..perf.recorder import get_recorder
 from ..parallel.mesh_group import MeshWorkerMixin
 from .config import ScalingConfig
-from .session import TrainContext, init_session, shutdown_session
+from .session import (TrainContext, enter_loop, init_session,
+                      shutdown_session)
 
 
 class TrainWorkerError(RuntimeError):
@@ -56,6 +57,7 @@ class _TrainWorker(MeshWorkerMixin):
 
     def run_train_fn(self, fn_blob: bytes, config: Dict[str, Any]):
         fn = cloudpickle.loads(fn_blob)
+        enter_loop()
         try:
             if config:
                 return fn(config)

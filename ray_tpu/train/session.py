@@ -7,14 +7,20 @@ the channel is a ray_tpu Queue actor and the "process group" is the
 worker's mesh slice."""
 from __future__ import annotations
 
+import gc
+import resource
 import threading
+import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 from ..perf.recorder import get_recorder
 
 _session_lock = threading.Lock()
 _session: Optional["_Session"] = None
+# ident of the thread inside the loop function (``enter_loop``), for the
+# chip watcher's reading of that thread's CPU clock; None outside a loop
+_loop_thread: Optional[int] = None
 
 
 @dataclass
@@ -45,6 +51,7 @@ class _Session:
     latest_checkpoint: Optional[Any] = None
     iteration: int = 0
     stop_requested: bool = False
+    usage: Optional[Tuple[float, ...]] = None   # _usage() at the last report
 
 
 def init_session(context: TrainContext, result_queue, mesh=None,
@@ -57,9 +64,29 @@ def init_session(context: TrainContext, result_queue, mesh=None,
 
 
 def shutdown_session() -> None:
-    global _session
+    global _session, _loop_thread
     with _session_lock:
         _session = None
+        _loop_thread = None
+
+
+def enter_loop() -> None:
+    """Called by the worker on the thread that is about to run the loop
+    function: from here to ``shutdown_session`` that thread is "the loop's"
+    (``loop_state``), and the first report's ``data`` counts from here."""
+    global _loop_thread
+    _loop_thread = threading.get_ident()
+    s = _session
+    if s is not None and get_recorder().enabled:
+        s.usage = _usage()
+
+
+def loop_state() -> Tuple[Optional[int], int]:
+    """-> (ident of the thread inside the loop function or None, the
+    ``iteration`` of the last ``report``): what the chip watcher
+    (``perf/chipwatch.py``) reads at every sample, without a lock."""
+    s = _session
+    return _loop_thread, (s.iteration if s is not None else 0)
 
 
 def _get_session() -> "_Session":
@@ -80,12 +107,38 @@ def get_mesh():
     return _get_session().mesh
 
 
+_USAGE_KEYS = ("since_s", "cpu_s", "loop_cpu_s", "nvcsw", "nivcsw",
+               "majflt", "gc")
+
+
+def _usage() -> Tuple[float, ...]:
+    """Cumulative, in ``_USAGE_KEYS``' order: the wall clock, the process's
+    CPU seconds, the calling (loop) thread's, the process's voluntary and
+    involuntary context switches and major faults, the garbage collector's
+    collections. A few microseconds."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return (time.time(), time.process_time(), time.thread_time(),
+            ru.ru_nvcsw, ru.ru_nivcsw, ru.ru_majflt,
+            sum(g["collections"] for g in gc.get_stats()))
+
+
 def report(metrics: Dict[str, Any], checkpoint=None) -> None:
     """Report metrics (and optionally a checkpoint) for this iteration.
     Only rank 0's checkpoint is persisted (reference semantics)."""
     s = _get_session()
+    rec = get_recorder()
+    data = None
+    if rec.enabled:
+        # what lay between the last report's start (the loop's entry, for
+        # the first) and this one's: a loop that syncs on a loss just
+        # before it reports makes this a device-paced stamp
+        now, last = _usage(), s.usage
+        s.usage = now
+        data = {"iteration": s.iteration + 1}
+        if last is not None:
+            data.update((k, b - a) for k, a, b in zip(_USAGE_KEYS, last, now))
     # in the chip's process, so a gap in a training trace has a name
-    with get_recorder().span("rtpu.train.report"):
+    with rec.span("rtpu.train.report", data=data):
         s.iteration += 1
         payload = {
             "rank": s.context.world_rank,

@@ -14,7 +14,7 @@ import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
-from ..perf.postmortem import dump_bundle
+from ..perf.postmortem import dump_bundle, fetch_rings, spans_and_tail
 from ..perf.recorder import get_recorder
 from .backend_executor import BackendExecutor, TrainWorkerError
 from .checkpoint import Checkpoint, prune_checkpoints
@@ -139,12 +139,17 @@ class DataParallelTrainer:
 def _flight_record(executor: BackendExecutor, path: str,
                    ended: Optional[BaseException], failures: int,
                    iterations: int) -> Optional[str]:
-    """``<path>/flight.json``: the driver's ring and the ring of every
-    worker of ``executor`` that still answers (5 s each), in
-    ``dump_bundle``'s shape, so ``ray_tpu postmortem`` renders a run that
-    ended well as it renders an abort. Written when a gang ends, however
-    it ended and before it is killed; a restarted gang's record replaces
-    its predecessor's (the driver's ring holds both). Never raises, and
+    """``<path>/flight.json``: the driver's ring (its spans and its last
+    256 instant events: the rest is ``dispatch.*`` of ``next_results``'
+    polling) and the ring of every worker of ``executor`` that still
+    answers (5 s each), in ``dump_bundle``'s shape, so ``ray_tpu
+    postmortem`` renders a run that ended well as it renders an abort.
+    Written when a gang ends, however it ended and before it is killed; a
+    restarted gang's record replaces its predecessor's (the driver's ring
+    holds both). Where a worker's ring holds an ``rtpu.chip.stall`` (the
+    chip watcher saw the process stand still, ``perf/chipwatch.py``) the
+    same bundle is also left in ``bundle_dir()``, reason ``fit: stalled``,
+    where the next run of the job does not replace it. Never raises, and
     writes nothing with the recorder off. What it cost is the driver's
     span ``rtpu.train.flight``."""
     rec = get_recorder()
@@ -152,12 +157,18 @@ def _flight_record(executor: BackendExecutor, path: str,
         return None
     try:
         with rec.span("rtpu.train.flight", pin=True):
+            rings = fetch_rings(executor.ring_fetchers())
+            bundle = dict(
+                origin="driver", extra_rings=rings, throttle=False,
+                origin_ring=spans_and_tail(rec.snapshot(clear=False)),
+                meta={"error": ended and f"{type(ended).__name__}: {ended}",
+                      "failures": failures, "iterations": iterations})
+            if any(ev.get("kind") == "rtpu.chip.stall"
+                   for ring in rings.values() for ev in ring):
+                dump_bundle("fit: stalled", **bundle)
             return dump_bundle(
                 "fit: " + ("ok" if ended is None else type(ended).__name__),
-                origin="driver", ring_fetchers=executor.ring_fetchers(),
-                meta={"error": ended and f"{type(ended).__name__}: {ended}",
-                      "failures": failures, "iterations": iterations},
-                throttle=False, path=os.path.join(path, "flight.json"))
+                path=os.path.join(path, "flight.json"), **bundle)
     except Exception:  # noqa: BLE001 - a record, never the run's failure
         traceback.print_exc()
         return None
