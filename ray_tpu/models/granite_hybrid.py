@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import (causal_conv1d, cross_entropy_loss, flash_attention,
+from ..ops import (causal_conv1d_silu, cross_entropy_loss, flash_attention,
                    gated_rmsnorm, rmsnorm, ssd_scan)
 from .stack import period_runs, walk_stack
 
@@ -240,7 +240,7 @@ class GraniteHybrid:
             xbc = xn @ lp["w_xbc"].astype(dt)
             step = xn @ lp["w_dt"].astype(dt)
         with jax.named_scope("conv"):
-            xbc = jax.nn.silu(causal_conv1d(xbc, lp["conv_w"], lp["conv_b"]))
+            xbc = causal_conv1d_silu(xbc, lp["conv_w"], lp["conv_b"])
         with jax.named_scope("mixer"):
             xs = xbc[..., :di].reshape(b, s, h, p)
             bm = xbc[..., di:di + g * n].reshape(b, s, g, n)

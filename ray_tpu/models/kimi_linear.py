@@ -57,7 +57,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import (causal_conv1d, cross_entropy_loss, kda_gated_scan,
+from ..ops import (causal_conv1d_silu, cross_entropy_loss, kda_gated_scan,
                    rmsnorm, sigmoid_gated_rmsnorm)
 from .deepseek_v3 import held_expert_sublayer, latent_attention
 from .stack import (draw_params, period_runs, run_params,
@@ -273,7 +273,7 @@ class KimiLinear:
             gate = (xn @ w("w_g_a")) @ w("w_g_b")
             write = xn @ w("w_beta")
         with jax.named_scope("conv"):
-            q, k, v = (jax.nn.silu(causal_conv1d(t, lp[n])) for t, n in
+            q, k, v = (causal_conv1d_silu(t, lp[n]) for t, n in
                        ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
         with jax.named_scope("scan"):
             beta = jax.nn.sigmoid(write.astype(jnp.float32))

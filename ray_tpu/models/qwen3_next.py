@@ -68,7 +68,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import (apply_rope, causal_conv1d, cross_entropy_loss,
+from ..ops import (apply_rope, causal_conv1d_silu, cross_entropy_loss,
                    flash_attention, gdn_gated_scan, rmsnorm,
                    rmsnorm_then_gate, rope_cache)
 from ..ops.expert_layer import held_expert_layer
@@ -265,7 +265,7 @@ class Qwen3Next:
             qkv, z = qkvz[..., :2 * gk + gv], qkvz[..., 2 * gk + gv:]
             ba = xn @ lp["w_ba"].astype(dt)
         with jax.named_scope("conv"):
-            qkv = jax.nn.silu(causal_conv1d(qkv, lp["conv"]))
+            qkv = causal_conv1d_silu(qkv, lp["conv"])
         with jax.named_scope("scan"):
             beta = jax.nn.sigmoid(ba[..., :hv].astype(jnp.float32))
             o = gdn_gated_scan(

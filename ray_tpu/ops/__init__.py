@@ -42,8 +42,9 @@ from .attention import mha_reference
 from .flash_attention import flash_attention
 from .ring_attention import ring_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
-                     rope_cache, apply_rope, causal_conv1d, gated_rmsnorm,
-                     l2norm, rmsnorm_then_gate, sigmoid_gated_rmsnorm)
+                     rope_cache, apply_rope, causal_conv1d,
+                     causal_conv1d_silu, gated_rmsnorm, l2norm,
+                     rmsnorm_then_gate, sigmoid_gated_rmsnorm)
 from .ssd_scan import ssd_scan
 from .kda_scan import (gated_delta_scan, gdn_gated_scan, kda_gated_scan,
                        kda_scan)
@@ -56,7 +57,8 @@ from .paged_attention import (paged_attention_decode,
 __all__ = [
     "flash_attention", "ring_attention", "mha_reference",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
-    "cross_entropy_loss", "causal_conv1d", "gated_rmsnorm", "l2norm",
+    "cross_entropy_loss", "causal_conv1d", "causal_conv1d_silu",
+    "gated_rmsnorm", "l2norm",
     "rmsnorm_then_gate", "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan",
     "kda_gated_scan", "gated_delta_scan", "gdn_gated_scan", "selective_scan",
     "hc_coefficients", "hc_pre", "hc_post", "hc_mix",

@@ -303,6 +303,47 @@ def test_ssd_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels):
     assert not re.findall(r"\w+\[2,4096,64,64\]", text)
 
 
+@pytest.mark.parametrize("shape,bias", [
+    ((2, 8192, 4096), False), ((2, 8192, 8192), False),
+    ((2, 4096, 4352), True)], ids=["kimilinear", "qwen3next", "granite4h"])
+def test_conv_silu_backward_is_two_fusions_and_one_array(one_chip, shape,
+                                                         bias):
+    """ISSUE 55: value and gradient of ``causal_conv1d_silu`` at the three
+    cells' shapes. The backward is at most TWO fusions whose result holds a
+    full-size array (dpre with the sums for dw and db; dx) and its
+    temporaries at most one such array (dpre). Autodiff's of the same
+    expression is three such fusions (dpre; four full-size shifted
+    products, one a tap; their padded sum) and four arrays."""
+    from ray_tpu.ops.layers import causal_conv1d, causal_conv1d_silu
+
+    full = "bf16[%d,%d,%d]" % shape
+    array_bytes = 2 * math.prod(shape)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = (jax.ShapeDtypeStruct((4, shape[2]), jnp.float32,
+                                   sharding=one_chip),)
+    if bias:
+        params += (jax.ShapeDtypeStruct((shape[2],), jnp.float32,
+                                        sharding=one_chip),)
+
+    def backward_of(f):
+        def value_and_grads(dy, x, *params):
+            y, vjp = jax.vjp(f, x, *params)
+            return y, vjp(dy)
+        compiled = jax.jit(value_and_grads).lower(x, x, *params).compile()
+        entry = compiled.as_text().split("\nENTRY ")[1]
+        writers = [line for line in entry.splitlines() if " fusion(" in line
+                   and full in line.split(" fusion(")[0].partition(" = ")[2]]
+        # less the forward's one fusion, x -> y
+        return (len(writers) - 1,
+                compiled.memory_analysis().temp_size_in_bytes)
+
+    fusions, temp = backward_of(causal_conv1d_silu)
+    assert fusions <= 2 and temp <= 1.01 * array_bytes
+    fusions, temp = backward_of(
+        lambda x, *p: jax.nn.silu(causal_conv1d(x, *p)))
+    assert fusions == 3 and temp >= 3.9 * array_bytes
+
+
 def _hlo_tool():
     """scripts/train_step_hlo.py as a module."""
     import importlib.util
@@ -323,10 +364,11 @@ def test_granite4h_train_step_keeps_its_room(one_chip, compiled_kernels,
     4096, parameters and optimizer state donated) for the described v5e,
     the runs after the first keeping the gated MLP's two input products:
     8 652 767 744 bytes of temporaries beside 9.27 GB of arguments
-    (8 591 307 776 with nothing kept: what later runs keep is freed before
-    the peak, which is in the first run's backward), and the compiler
-    rematerialises NOTHING on its own. It does as soon as the first run
-    keeps a product too (``.remat`` instructions: the head's logits made
+    (8 635 408 896 since ISSUE 55's hand-written gradient of the
+    convolution; 8 591 307 776 with nothing kept: what later runs keep is
+    freed before the peak, which is in the first run's backward), and the
+    compiler rematerialises NOTHING on its own. It does as soon as the first
+    run keeps a product too (``.remat`` instructions: the head's logits made
     again, then the mixers' products), and with every layer keeping both
     the program holds more matmul operations than with nothing kept. A
     change that eats the room fails here, not as a slower step on the
@@ -352,6 +394,7 @@ def test_granite4h_train_step_keeps_its_room(one_chip, compiled_kernels,
     # by the product's own fusion; the run of 5 has none
     assert "bf16[4,2,4096,8192]" in text
     assert "bf16[5,2,4096,8192]" not in text
+    assert _conv_fusions_write_one_array_each(text, "bf16[2,4096,4352]")
 
 
 def test_selective_scan_at_the_benchmark_cells_shape(one_chip,
@@ -516,23 +559,42 @@ def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
     assert not re.findall(r"f32\[8192,4,4\]|f32\[2,4096,4,4\]", text)
 
 
-def _buffers_under(text: str, shape: str, scope: str):
-    """The instructions of an optimised program that PRODUCE an array of
-    ``shape`` (``f32[2,8192,4096]``) under the model's scope ``scope``: of
-    the entry and the loops' bodies, not those inside a fusion (they live
-    in registers and VMEM), by the scope in their ``op_name``."""
+def _unfused_under(text: str, scope: str):
+    """The instructions of an optimised program under the model's scope
+    ``scope`` (by their ``op_name``): of the entry and the loops' bodies,
+    not those inside a fusion (they live in registers and VMEM)."""
     fused = set(re.findall(r" fusion\(.*?calls=%?([\w.\-]+)", text))
-    found, inside = [], None
+    inside = None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
         if head:
             inside = head.group(1)
-        elif inside not in fused and re.match(
-                r"\s*(?:ROOT )?%?\S+ = " + re.escape(shape) + r"[{ ]", line):
+        elif inside not in fused:
             op = re.search(r'op_name="([^"]*)"', line)
             if op and re.search(r"[/(]" + scope + r"[/)]", op.group(1)):
-                found.append(line.split(" = ")[0].strip())
-    return found
+                yield line
+
+
+def _buffers_under(text: str, shape: str, scope: str):
+    """The instructions of an optimised program that PRODUCE an array of
+    ``shape`` (``f32[2,8192,4096]``) under the model's scope ``scope``."""
+    return [line.split(" = ")[0].strip()
+            for line in _unfused_under(text, scope)
+            if re.match(r"\s*(?:ROOT )?%?\S+ = " + re.escape(shape) + r"[{ ]",
+                        line)]
+
+
+def _conv_fusions_write_one_array_each(text: str, shape: str) -> bool:
+    """ISSUE 55: inside the rematerialised, scanned layers too, every fusion
+    under the scope ``conv`` writes at most ONE array of ``shape`` (the
+    forward y; the backward dpre beside the sums for dw and db; dx).
+    Autodiff's backward had fusions there with two (the rematerialised
+    forward wrote its pre-activation for the backward to read), three and
+    four (one shifted product a tap)."""
+    written = [line.split(" fusion(")[0].partition(" = ")[2].count(shape)
+               for line in _unfused_under(text, "conv") if " fusion(" in line]
+    # forward, rematerialised forward, dpre, dx
+    return max(written) == 1 and sum(written) >= 4
 
 
 @pytest.mark.parametrize("entry", ["scan", "gated"])
@@ -610,22 +672,22 @@ def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
     batch 2 of 8192, parameters and optimizer state donated) for the
     described v5e, the expert layer's kernels on their compiled path as on
     the chip (in interpret mode its row buffers are refused by 1.30 GB):
-    602.4 M parameters at 12 B as arguments (7.23 GB), 7.95 GB of
-    temporaries (they overlap the donated state; 8.76 GB while the plain
-    code made the norms of q and k and wrote the gate g in float32, ISSUE
-    51) with a KDA layer keeping its input alone and the latent layer its
-    kernels' output, row statistics and q; the compiler makes NO
-    instruction again on its own (2 before ISSUE 51; 20 before the delta
-    rule's kernels freed the turns' stacked inputs; 20 again with one KDA
-    layer's o and chunk states kept, 45 with all four: why they are not).
-    Under the scope ``scan`` no float32 [2, 8192, 4096] array is produced
-    any more (the parent's step held 21 such producers there: the gate,
-    its broadcast factor, the norms' squares, dg and its products): the
-    kernels read what the convolutions and the gate projection made. The
-    one latent layer's two kernels stand once
-    each; the delta rule's forward kernel stands twice a run of KDA layers
-    (the forward sweep and the rematerialised layer) and its backward
-    once."""
+    602.4 M parameters at 12 B as arguments (7.23 GB), 7.55 GB of
+    temporaries (they overlap the donated state; 7.95 GB while autodiff made
+    the convolutions' backward with four full-size arrays each, ISSUE 55;
+    8.76 GB while the plain code made the norms of q and k and wrote the
+    gate g in float32, ISSUE 51) with a KDA layer keeping its input alone
+    and the latent layer its kernels' output, row statistics and q; the
+    compiler makes NO instruction again on its own (2 before ISSUE 51; 20
+    before the delta rule's kernels freed the turns' stacked inputs; 20
+    again with one KDA layer's o and chunk states kept, 45 with all four:
+    why they are not). Under the scope ``scan`` no float32 [2, 8192, 4096]
+    array is produced any more (the parent's step held 21 such producers
+    there: the gate, its broadcast factor, the norms' squares, dg and its
+    products): the kernels read what the convolutions and the gate
+    projection made. The one latent layer's two kernels stand once each; the
+    delta rule's forward kernel stands twice a run of KDA layers (the
+    forward sweep and the rematerialised layer) and its backward once."""
     import os
 
     monkeypatch.syspath_prepend(
@@ -635,7 +697,7 @@ def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
     tool = _hlo_tool()
     compiled = tool.compile_step("kimilinear_train_s8192", one_chip)
     assert 7.2e9 < _fits(compiled) < 7.3e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 8.1e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.7e9
     text = compiled.as_text()
     assert "s32[2,8192]" in text            # the cell's batch, not another
     assert tool.compiler_remat(text) <= 6
@@ -651,6 +713,7 @@ def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
         if re.match(r"\s*%?kda_chunk_(fwd|bwd)(\.\d+)? = ", line):
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
     assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 9
+    assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,4096]")
 
 
 def test_gdn_gated_scan_at_the_benchmark_cells_shape(one_chip,
@@ -711,23 +774,23 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
                                              monkeypatch):
     """ISSUE 52: qwen3next_train_s8192's own train step (the harness's
     ``make_train_step``, the cell's configuration, optimizer, batch 2 of
-    8192, parameters and optimizer state donated) for the described v5e,
-    the expert layer's kernels on their compiled path as on the chip: 626.0
-    M parameters at 12 B as arguments (7.51 GB), 9.50 GB of temporaries
-    since ISSUE 53 (9.77 while q and k were repeated to the value heads;
-    they overlap the donated state) with a Gated DeltaNet layer keeping
-    its input alone and the attention layer its kernels' output and row
-    statistics (nothing kept in the attention layer reads 9.7677 against
-    9.7679 GB; a Gated DeltaNet layer keeping ``kda_out`` and
-    ``kda_states`` is refused, "Used 16.80G of 15.75G hbm"); the compiler
-    makes 4 instructions again on its own. Under the scope ``scan`` no
-    float32 [2, 8192, 4096] array is produced: the kernels make the norms
-    and the gate from what the convolution and ``W_ba`` left. The one
-    attention layer's two one-part flash kernels stand once each; the delta
-    rule's forward kernel (ISSUE 53: ``gdn_chunk_fwd``, the body for one
-    decay a head; KDA's is not in the program) twice in the scanned run's
-    loops (the forward sweep and the rematerialised layer) and its backward
-    once."""
+    8192, parameters and optimizer state donated) for the described v5e, the
+    expert layer's kernels on their compiled path as on the chip: 626.0 M
+    parameters at 12 B as arguments (7.51 GB), 9.50 GB of temporaries since
+    ISSUE 53 (9.77 while q and k were repeated to the value heads; they
+    overlap the donated state) with a Gated DeltaNet layer keeping its input
+    alone and the attention layer its kernels' output and row statistics
+    (nothing kept in the attention layer reads 9.7677 against 9.7679 GB; a
+    Gated DeltaNet layer keeping ``kda_out`` and ``kda_states`` is refused,
+    "Used 16.80G of 15.75G hbm"); the compiler makes 3 instructions again on
+    its own (4 until ISSUE 55: the convolution's forward a third time, for
+    autodiff's backward). Under the scope ``scan`` no float32 [2, 8192,
+    4096] array is produced: the kernels make the norms and the gate from
+    what the convolution and ``W_ba`` left. The one attention layer's two
+    one-part flash kernels stand once each; the delta rule's forward kernel
+    (ISSUE 53: ``gdn_chunk_fwd``, the body for one decay a head; KDA's is
+    not in the program) twice in the scanned run's loops (the forward sweep
+    and the rematerialised layer) and its backward once."""
     import os
 
     monkeypatch.syspath_prepend(
@@ -754,6 +817,7 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
         if re.match(r"\s*%?gdn_chunk_(fwd|bwd)(\.\d+)? = ", line):
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
     assert count("grouped_matmul") >= 6 and count("grouped_matmul_dw") >= 6
+    assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,8192]")
 
 
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):

@@ -461,14 +461,26 @@ MODELS = {
 
 @pytest.fixture(scope="module")
 def lowered_losses():
+    from ray_tpu.perf.recorder import get_recorder
+
     out = {}
     toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    for name, make in MODELS.items():
-        m = make()
-        p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
-        text = jax.jit(jax.value_and_grad(m.loss)).lower(
-            p, toks, toks).as_text(debug_info=True)
-        out[name] = set(re.findall(r'loc\("([^"]+)"', text))
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        for name, make in MODELS.items():
+            m = make()
+            p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+            mark = time.time()
+            text = jax.jit(jax.value_and_grad(m.loss)).lower(
+                p, toks, toks).as_text(debug_info=True)
+            out[name] = set(re.findall(r'loc\("([^"]+)"', text))
+            # ISSUE 55: what the trace left of the convolution's gradient
+            out["rtpu.ops.conv", name] = [
+                (e["label"], e["data"]) for e in rec.snapshot()
+                if e["kind"] == "rtpu.ops.conv" and e["ts"] >= mark]
+    finally:
+        rec.enabled = was
     return out
 
 
@@ -499,6 +511,28 @@ def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
            and re.search(r"(^|/)" + scope + "/", n)]
     assert fwd, (model, scope)
     assert bwd, (model, scope)
+
+
+@pytest.mark.parametrize("model,calls,data", [
+    # three runs of KDA layers, each with q's, k's and v's convolution
+    ("kimi_linear", 9, {"tokens": 256, "channels": 256, "taps": 4,
+                        "bias": False}),
+    # one run of Gated DeltaNet layers, one convolution over q | k | v
+    ("qwen3_next", 1, {"tokens": 256, "channels": 1024, "taps": 4,
+                       "bias": False}),
+    # two runs of Mamba-2 layers, one convolution over x | B | C
+    ("granite_hybrid", 2, {"tokens": 256, "channels": 512, "taps": 4,
+                           "bias": True}),
+    ("sambay", 0, None)])
+def test_the_hand_gradient_of_the_convolution_leaves_its_event(
+        lowered_losses, model, calls, data):
+    """ISSUE 55: ``rtpu.ops.conv`` (label ``hand_vjp``) at trace time, once
+    a traced call of ``causal_conv1d_silu`` (a scanned run traces its layer
+    once), says which steps hold the hand-written gradient: the three
+    models whose convolution feeds a scan kernel. ``sambay``'s feeds a
+    matrix product and keeps autodiff's: its record holds none."""
+    assert lowered_losses["rtpu.ops.conv", model] == [
+        ("hand_vjp", data)] * calls
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])
