@@ -19,11 +19,12 @@ from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .sambay import SambaY, SambaYConfig
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .qwen3_next import Qwen3Next, Qwen3NextConfig
+from .nemotron_h import NemotronH, NemotronHConfig
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
     "ViT", "ViTConfig", "MLP", "MLPConfig", "MoE", "MoEConfig",
     "DeepseekV3", "DeepseekV3Config", "GraniteHybrid", "GraniteHybridConfig",
     "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
-    "Qwen3Next", "Qwen3NextConfig",
+    "Qwen3Next", "Qwen3NextConfig", "NemotronH", "NemotronHConfig",
 ]

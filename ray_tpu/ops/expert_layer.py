@@ -24,7 +24,9 @@ product's time follows the routing.
 
 Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
 ``router`` (scores, top-k, the sort, the gather into the buffer and the
-weighted sum back), ``experts`` (the grouped products), ``shared_expert``.
+weighted sum back), ``experts`` (the grouped products), ``shared_expert``,
+``latent_proj`` (the projections into and out of the experts' latent, where
+the layer has one).
 """
 from __future__ import annotations
 
@@ -356,9 +358,30 @@ def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
     return matmul(h, w_down)
 
 
+def _relu2(x, w_up, w_down, matmul, row_weight=None):
+    """The squared-ReLU MLP of two matrices, ``W_down relu(x W_up)^2``, the
+    square (and ``row_weight``, as in ``_gated``) in f32."""
+    h = jax.nn.relu(matmul(x, w_up))
+    hf = jnp.square(h.astype(jnp.float32))
+    if row_weight is not None:
+        hf = hf * row_weight[:, None]
+    return matmul(hf.astype(h.dtype), w_down)
+
+
+def _mlp(expert: str, x, p, prefix: str, matmul, row_weight=None):
+    """The MLP of kind ``expert`` over ``p``'s ``<prefix>_gate`` (a gated
+    one alone), ``<prefix>_up`` and ``<prefix>_down`` in x's dtype."""
+    w = lambda name: p[f"{prefix}_{name}"].astype(x.dtype)    # noqa: E731
+    if expert == "swiglu":
+        return _gated(x, w("gate"), w("up"), w("down"), matmul, row_weight)
+    if expert == "relu2":
+        return _relu2(x, w("up"), w("down"), matmul, row_weight)
+    raise ValueError(f"expert is swiglu or relu2, got {expert!r}")
+
+
 def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
                       top_k: int, routed_scale: float, tile: int = ROW_TILE,
-                      score: str = "sigmoid"):
+                      score: str = "sigmoid", expert: str = "swiglu"):
     """x [T, D] (normalised) -> (shared(x) + the held experts' part of
     sum_e w_e expert_e(x), [T, D] in x's dtype; the (token, choice) pairs
     that named a held expert, i.e. the rows the grouped product worked).
@@ -369,20 +392,31 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     experts ``expert_offset`` .. ``expert_offset + experts_held``;
     ``s_gate``, ``s_up`` [D, Fs], ``s_down`` [Fs, D] of the shared experts
     (side by side, one gated MLP); where it holds ``s_gate_w`` [D, 1] the
-    shared experts' output is times ``sigmoid(x s_gate_w)``, f32."""
+    shared experts' output is times ``sigmoid(x s_gate_w)``, f32.
+
+    ``expert`` is the kind of every MLP of the layer, routed and shared:
+    ``swiglu`` the gated SiLU MLP of three matrices, ``relu2`` ``W_down
+    relu(x W_up)^2`` of two (no ``e_gate``, ``s_gate``). Where ``p`` holds
+    ``w_fc1`` [D, L] and ``w_fc2`` [L, D] the routed experts work in a
+    LATENT of width L: the router scores x, ``x w_fc1`` is what is gathered
+    into the row buffer, ``e_up`` is [held, L, F] and ``e_down`` [held, F,
+    L], the weighted sum comes back in L and ``w_fc2`` lifts it (scope
+    ``latent_proj``, both projections); the shared experts read x itself.
+    Both are static facts of the call."""
     t, d = x.shape
     dt = x.dtype
     n_experts = p["w_router"].shape[1]
+    latent = p["w_fc1"].shape[1] if "w_fc1" in p else 0
     rows = buffer_rows(t, top_k, experts_held, tile)
     LAYER_COUNTS[(experts_held, n_experts)] += 1
     _record("rtpu.ops.expert_layer", "held",
             {"experts_held": experts_held, "of": n_experts, "top_k": top_k,
              "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
              "row_tile": tile, "score": score,
-             "shared_gate": "s_gate_w" in p})
+             "shared_gate": "s_gate_w" in p, "expert": expert,
+             "latent": latent})
     with jax.named_scope("shared_expert"):
-        shared = _gated(x, p["s_gate"].astype(dt), p["s_up"].astype(dt),
-                        p["s_down"].astype(dt), jnp.dot)
+        shared = _mlp(expert, x, p, "s", jnp.dot)
         if "s_gate_w" in p:
             opened = jax.nn.sigmoid(jnp.dot(
                 x, p["s_gate_w"].astype(dt),
@@ -394,13 +428,21 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
                                 score=score)
         at = sort_rows(chosen, experts_held, expert_offset, rows, tile)
         held_rows = at.pop("held_rows")
+    if latent:
+        with jax.named_scope("latent_proj"):
+            x = jnp.dot(x, p["w_fc1"].astype(dt))
+    with jax.named_scope("router"):
         buf = tokens_to_rows(x, at)
         row_weight = pairs_to_rows(weights, at)
     with jax.named_scope("experts"):
-        y = _gated(buf, p["e_gate"].astype(dt), p["e_up"].astype(dt),
-                   p["e_down"].astype(dt),
-                   lambda a, w: grouped_matmul(a, w, at["tile_expert"],
-                                               at["n_used"], tile),
-                   row_weight)
+        y = _mlp(expert, buf, p, "e",
+                 lambda a, w: grouped_matmul(a, w, at["tile_expert"],
+                                             at["n_used"], tile),
+                 row_weight)
     with jax.named_scope("router"):
-        return shared + rows_to_tokens(y, at), held_rows
+        routed = rows_to_tokens(y, at)
+    if latent:
+        with jax.named_scope("latent_proj"):
+            routed = jnp.dot(routed, p["w_fc2"].astype(dt))
+    with jax.named_scope("router"):
+        return shared + routed, held_rows

@@ -13,8 +13,9 @@ import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
                             GraniteHybrid, GraniteHybridConfig, KimiLinear,
-                            KimiLinearConfig, Llama, LlamaConfig, Qwen3Next,
-                            Qwen3NextConfig, SambaY, SambaYConfig)
+                            KimiLinearConfig, Llama, LlamaConfig, NemotronH,
+                            NemotronHConfig, Qwen3Next, Qwen3NextConfig,
+                            SambaY, SambaYConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
@@ -196,7 +197,9 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE,
         # ISSUE 52: how the router scores, whether the shared expert is gated
-        "score": "sigmoid", "shared_gate": False}
+        "score": "sigmoid", "shared_gate": False,
+        # ISSUE 56: the kind of every MLP of the layer, the latent's width
+        "expert": "swiglu", "latent": 0}
     path = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
             and e["label"] == "latent"][-1]
     assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
@@ -323,7 +326,8 @@ def test_a_gated_deltanet_stack_leaves_its_events():
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
-        "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True}
+        "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True,
+        "expert": "swiglu", "latent": 0}
     flash = last("rtpu.ops.flash.path")
     assert flash["label"] == "relayout" and flash["data"]["hd"] == 256
     for part in ("fwd", "bwd"):
@@ -333,6 +337,54 @@ def test_a_gated_deltanet_stack_leaves_its_events():
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "qwen3_next"][-1]
     assert runs["data"]["runs"] == [["gdn_moe", 3], ["attn_moe", 1]]
+    assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
+    assert runs["data"]["side_state_bytes"] == 0
+
+
+def test_a_share_of_a_latent_expert_stack_leaves_its_events():
+    """ISSUE 56: what a Nemotron-H shaped loss leaves at trace time.
+    ``rtpu.models.nemotron_h.share``: the groups, heads, experts and
+    vocabulary rows held and of how many; ``rtpu.ops.expert_layer``: the
+    ``expert`` kind ``relu2`` and the ``latent`` width; ``rtpu.ops.ssd.path``:
+    the kernel route for the ONE group held; ``rtpu.models.stack.runs``: two
+    scanned (``moe``, ``mamba``) periods that keep their inputs alone, then
+    the attention layer with the flash kernels' output and row statistics.
+    The scan's kernels stand under the scope ``scan``."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = NemotronH(NemotronHConfig.tiny(
+        experts_held=2, expert_offset=4, mamba_groups_held=1,
+        mamba_group_offset=1, heads_held=2, head_offset=2))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    share = last("rtpu.models.nemotron_h.share")
+    assert share["label"] == "held" and share["data"] == {
+        "mamba_groups": [1, 2], "mamba_group_offset": 1,
+        "mamba_heads": [2, 4], "query_heads": [2, 4], "head_offset": 2,
+        "kv_heads": [1, 2], "experts": [2, 8], "expert_offset": 4,
+        "vocab_rows": 512}
+    assert last("rtpu.ops.expert_layer")["data"] == {
+        "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
+        "row_tile": el.ROW_TILE, "score": "sigmoid", "shared_gate": False,
+        "expert": "relu2", "latent": 32}
+    path = last("rtpu.ops.ssd.path")
+    assert path["label"] == "kernel" and path["data"]["groups"] == 1 \
+        and path["data"]["heads"] == 2
+    for part in ("fwd", "bwd"):
+        assert re.search(r"scan/[^\n]*" + ssd.KERNEL_NAMES[part], text)
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "nemotron_h"][-1]
+    assert runs["data"]["runs"] == [["moe+mamba", 2], ["attention", 1]]
     assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
     assert runs["data"]["side_state_bytes"] == 0
 
@@ -454,6 +506,8 @@ MODELS = {
     "granite_hybrid": lambda: GraniteHybrid(GraniteHybridConfig.tiny()),
     "kimi_linear": lambda: KimiLinear(KimiLinearConfig.tiny(experts_held=4)),
     "qwen3_next": lambda: Qwen3Next(Qwen3NextConfig.tiny(experts_held=4)),
+    "nemotron_h": lambda: NemotronH(NemotronHConfig.tiny(
+        experts_held=4, mamba_groups_held=1, heads_held=2)),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -488,16 +542,19 @@ def lowered_losses():
     (s, m) for m in sorted(MODELS)
     # ISSUE 52: a Qwen3-Next shaped model has no dense MLP, so no ``mlp``
     for s in ("embed", "attn", "lm_head", "loss")
-    + (("mlp",) if m != "qwen3_next" else ())
+    # ISSUE 56: nor has a Nemotron-H shaped one
+    + (("mlp",) if m not in ("qwen3_next", "nemotron_h") else ())
     + (("router", "experts", "shared_expert")
-       if m.startswith("deepseek_v3") or m in ("kimi_linear", "qwen3_next")
-       else ())
+       if m.startswith("deepseek_v3")
+       or m in ("kimi_linear", "qwen3_next", "nemotron_h") else ())
+    # ISSUE 56: both projections of the experts' latent, under one name
+    + (("latent_proj",) if m == "nemotron_h" else ())
     # ISSUE 45: everything ops/hyper_connection.py does, under one name
     + (("mhc",) if m == "deepseek_v3_hc" else ())
     # ISSUE 49: a KDA layer's three, the names the other scans' readers read
     + (("mixer", "conv", "scan")
-       if m in ("granite_hybrid", "sambay", "kimi_linear", "qwen3_next")
-       else ())
+       if m in ("granite_hybrid", "sambay", "kimi_linear", "qwen3_next",
+                "nemotron_h") else ())
     + (("gmu", "cross_attn") if m == "sambay" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
@@ -523,6 +580,9 @@ def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
     # two runs of Mamba-2 layers, one convolution over x | B | C
     ("granite_hybrid", 2, {"tokens": 256, "channels": 512, "taps": 4,
                            "bias": True}),
+    # one scanned run of Mamba-2 layers over the ONE group held: x | B | C
+    ("nemotron_h", 1, {"tokens": 256, "channels": 384, "taps": 4,
+                       "bias": True}),
     ("sambay", 0, None)])
 def test_the_hand_gradient_of_the_convolution_leaves_its_event(
         lowered_losses, model, calls, data):
