@@ -22,6 +22,15 @@ there while the grid does not. Rows move by gathers in both directions
 a scatter; they are made for the whole buffer, so that only the grouped
 product's time follows the routing.
 
+A token's k choices are k DIFFERENT experts, so a token holds at most
+min(k, held) rows; the pair domain is that, the buffer already was. Where
+``top_k > experts_held`` (two static arguments of the call) a token's held
+choices are compacted to ``experts_held`` slots before the sort
+(``compact_held``), and the sort, the row gathers and the weights' gather
+are sized by tokens x slots; where it is not, the compaction is not
+traced. The trace-time event ``rtpu.ops.expert_layer`` / ``held`` says
+which (``pair_slots`` beside ``top_k``).
+
 Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
 ``router`` (scores, top-k, the sort, the gather into the buffer and the
 weighted sum back), ``experts`` (the grouped products), ``shared_expert``,
@@ -301,7 +310,8 @@ def route(x, w_router, bias, *, top_k: int, routed_scale: float,
 def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
               tile: int = ROW_TILE):
     """Where each (token, choice) pair lies in the row buffer. chosen
-    [T, k] int32 over all experts -> dict of
+    [T, k] int32 over all experts (k the choices, or the slots of
+    ``compact_held``, an empty one -1) -> dict of
 
     ``pair_held`` [T, k] bool: the pair's expert lives here;
     ``pair_row`` [T, k] int32: its row (0 where not held);
@@ -347,6 +357,23 @@ def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
     return {"pair_held": held, "pair_row": pair_row, "row_pair": row_pair,
             "tile_expert": tile_expert, "n_used": n_used,
             "held_rows": jnp.sum(counts)}
+
+
+def compact_held(weights, chosen, experts_held: int, expert_offset: int):
+    """A token's held choices in its first slots. weights [T, k] f32, chosen
+    [T, k] int32 with k > ``experts_held`` -> ([T, held], [T, held]): the
+    held choices in their order of choice, then empty slots (expert -1,
+    which no chip holds, weight 0). By comparison, as ``of_expert``: no
+    gather, no scatter, no sort, and the weights' gradient comes back
+    through the same mask."""
+    local = chosen - expert_offset
+    held = (local >= 0) & (local < experts_held)
+    # a held pair's slot: the held pairs before it in its token
+    slot = jnp.cumsum(held, axis=1, dtype=jnp.int32) - 1
+    into = held[:, :, None] & (
+        slot[:, :, None] == jnp.arange(experts_held)[None, None, :])
+    return (jnp.sum(jnp.where(into, weights[:, :, None], 0.0), axis=1),
+            jnp.sum(jnp.where(into, chosen[:, :, None] + 1, 0), axis=1) - 1)
 
 
 def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
@@ -411,6 +438,7 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     LAYER_COUNTS[(experts_held, n_experts)] += 1
     _record("rtpu.ops.expert_layer", "held",
             {"experts_held": experts_held, "of": n_experts, "top_k": top_k,
+             "pair_slots": min(top_k, experts_held),
              "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
              "row_tile": tile, "score": score,
              "shared_gate": "s_gate_w" in p, "expert": expert,
@@ -426,6 +454,9 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
         weights, chosen = route(x, p["w_router"], p.get("router_bias"),
                                 top_k=top_k, routed_scale=routed_scale,
                                 score=score)
+        if top_k > experts_held:
+            weights, chosen = compact_held(weights, chosen, experts_held,
+                                           expert_offset)
         at = sort_rows(chosen, experts_held, expert_offset, rows, tile)
         held_rows = at.pop("held_rows")
     if latent:

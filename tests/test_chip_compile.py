@@ -196,29 +196,40 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
 
 
 @pytest.mark.parametrize(
-    "t,d,f,held,of,shared,top_k,scale",
-    [(16384, 2048, 768, 16, 128, 2, 6, 2.448),
-     (8192, 3584, 1024, 8, 64, 1, 4, 2.0)],
-    ids=["kanana2_train_s8192", "xing4_train_s4096"])
+    "t,d,f,held,of,shared,top_k,scale,expert,latent",
+    [(16384, 2048, 768, 16, 128, 2, 6, 2.448, "swiglu", 0),
+     (8192, 3584, 1024, 8, 64, 1, 4, 2.0, "swiglu", 0),
+     (16384, 4096, 2688, 8, 512, 2, 22, 5.0, "relu2", 1024)],
+    ids=["kanana2_train_s8192", "xing4_train_s4096",
+         "nemotron3super_train_s8192"])
 def test_held_expert_layer_at_the_benchmark_cells_shape(
-        one_chip, monkeypatch, t, d, f, held, of, shared, top_k, scale):
+        one_chip, monkeypatch, t, d, f, held, of, shared, top_k, scale,
+        expert, latent):
     """ISSUE 33: 16 384 tokens, 16 of 128 experts of 2048 x 768 held, top
     6: forward and backward of the layer, the two grouped-product kernels
     by name, no scatter. ISSUE 45: 8192 tokens, 8 of 64 experts of
-    3584 x 1024 held, top 4, one shared expert."""
+    3584 x 1024 held, top 4, one shared expert. ISSUE 57: 16 384 tokens of
+    4096, 8 of 512 squared-ReLU experts of 1024 x 2688 in a latent of 1024,
+    a shared expert of 5376, top 22: a token holds at most 8 rows, so the
+    pair domain is 131 072 and no array of the program is sized by the
+    360 448 (token, choice) pairs."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
     monkeypatch.setattr(el, "_use_interpret", lambda: False)
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
+    width = latent or d
     p = {"w_router": sd((d, of)), "router_bias": sd((of,)),
-         "s_gate": sd((d, shared * f)), "s_up": sd((d, shared * f)),
-         "s_down": sd((shared * f, d)), "e_gate": sd((held, d, f)),
-         "e_up": sd((held, d, f)), "e_down": sd((held, f, d))}
+         "s_up": sd((d, shared * f)), "s_down": sd((shared * f, d)),
+         "e_up": sd((held, width, f)), "e_down": sd((held, f, width))}
+    if expert == "swiglu":
+        p.update(s_gate=sd((d, shared * f)), e_gate=sd((held, width, f)))
+    if latent:
+        p.update(w_fc1=sd((d, latent)), w_fc2=sd((latent, d)))
 
     def loss(x, p):
         return el.held_expert_layer(
             x, p, experts_held=held, expert_offset=0, top_k=top_k,
-            routed_scale=scale)[0].astype(jnp.float32).sum()
+            routed_scale=scale, expert=expert)[0].astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         sd((t, d), jnp.bfloat16), p).compile().as_text()
@@ -227,6 +238,9 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     assert any("grouped_matmul_dw" in c for c in calls)
     assert any("grouped_matmul" in c and "_dw" not in c for c in calls)
     assert " scatter(" not in text
+    if top_k > held:
+        assert re.search(r"\[%d[,\]]" % (t * held), text)
+        assert not re.search(r"\[%d[,\]]" % (t * top_k), text)
 
 
 def test_hyper_connection_pair_at_the_benchmark_cells_shape(

@@ -384,12 +384,9 @@ def _dense_layer(x, p, *, top_k, scale, expert, offset):
     return mlp(x, "s") + routed
 
 
-@pytest.mark.parametrize("expert,latent", [
-    ("relu2", 32), ("relu2", 0), ("swiglu", 32)])
-def test_expert_kinds_and_the_latent_equal_a_dense_computation(expert, latent):
-    """Forward and every gradient of ``held_expert_layer`` by kind of expert
-    and with or without the latent against the one-hot form, 3 of 8 held."""
-    d, f, fs, e, held, off, k = 64, 48, 80, 8, 3, 2, 3
+def _layer_inputs(expert, latent, t, d, f, fs, e, held):
+    """(x [t, d], the parameters of one expert layer of kind ``expert``
+    holding ``held`` of ``e`` experts, in a latent where ``latent``)."""
     width = latent or d
     keys = iter(jax.random.split(jax.random.PRNGKey(5), 16))
     draw = lambda *s: 0.3 * jax.random.normal(next(keys), s)    # noqa: E731
@@ -400,7 +397,16 @@ def test_expert_kinds_and_the_latent_equal_a_dense_computation(expert, latent):
         p.update(s_gate=draw(d, fs), e_gate=draw(held, width, f))
     if latent:
         p.update(w_fc1=draw(d, latent), w_fc2=draw(latent, d))
-    x = jax.random.normal(next(keys), (32, d))
+    return jax.random.normal(next(keys), (t, d)), p
+
+
+@pytest.mark.parametrize("expert,latent", [
+    ("relu2", 32), ("relu2", 0), ("swiglu", 32)])
+def test_expert_kinds_and_the_latent_equal_a_dense_computation(expert, latent):
+    """Forward and every gradient of ``held_expert_layer`` by kind of expert
+    and with or without the latent against the one-hot form, 3 of 8 held."""
+    d, f, fs, e, held, off, k = 64, 48, 80, 8, 3, 2, 3
+    x, p = _layer_inputs(expert, latent, 32, d, f, fs, e, held)
     kw = dict(top_k=k, expert_offset=off)
 
     def program(x, p):
@@ -440,7 +446,7 @@ def test_the_swiglu_path_is_the_program_it_was():
     """The layer the four families of before ISSUE 56 call (gated SiLU
     experts on the model width) lowers to the text it lowered to when
     ``_gated`` was called by name: the same operations in the same order."""
-    d, f, e, held = 32, 16, 8, 2
+    d, f, e, held = 32, 16, 8, 3    # top 3 of 3 held: not compacted (ISSUE 57)
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 12))
     draw = lambda *s: jax.random.normal(next(keys), s)          # noqa: E731
     p = {"w_router": draw(d, e), "router_bias": draw(e),
@@ -475,6 +481,150 @@ def test_the_swiglu_path_is_the_program_it_was():
     texts = [jax.jit(lambda x, p: fn(x, p)).lower(x, p).as_text()
              for fn in (lambda x, p: held_expert_layer(x, p, **kw), before)]
     assert texts[0] == texts[1] and len(texts[0]) > 10000
+
+
+# -- ISSUE 57: the pair domain is what a token can hold -----------------------
+
+PAIR_TILE = 8
+PAIR_TOKENS = 64
+
+
+def _rows_by_hand(x, p, *, top_k, held, offset, scale, compact):
+    """The layer's way into the row buffer, its parts called one by one on
+    the [T, k] pair domain or, ``compact``, on ``compact_held``'s [T, held]
+    -> (``sort_rows``' dict, the pairs' weights and experts, the buffer)."""
+    rows = el.buffer_rows(x.shape[0], top_k, held, PAIR_TILE)
+    weights, chosen = el.route(x, p["w_router"], p["router_bias"],
+                               top_k=top_k, routed_scale=scale)
+    if compact:
+        weights, chosen = el.compact_held(weights, chosen, held, offset)
+    assert chosen.shape == weights.shape == (
+        x.shape[0], held if compact else top_k)
+    at = el.sort_rows(chosen, held, offset, rows, PAIR_TILE)
+    u = jnp.dot(x, p["w_fc1"].astype(x.dtype)) if "w_fc1" in p else x
+    return at, weights, chosen, el.tokens_to_rows(u, at)
+
+
+def _uncompacted(x, p, *, expert, **kw):
+    """``held_expert_layer`` composed by hand on the [T, k] pair domain, as
+    every layer was before ISSUE 57 -> (output, held rows, the row buffer,
+    ``n_used``)."""
+    at, weights, _, buf = _rows_by_hand(x, p, compact=False, **kw)
+    y = el._mlp(expert, buf, p, "e",
+                lambda a, w: el.grouped_matmul(a, w, at["tile_expert"],
+                                               at["n_used"], PAIR_TILE),
+                el.pairs_to_rows(weights, at))
+    routed = el.rows_to_tokens(y, at)
+    if "w_fc2" in p:
+        routed = jnp.dot(routed, p["w_fc2"].astype(x.dtype))
+    return (el._mlp(expert, x, p, "s", jnp.dot) + routed, at["held_rows"],
+            buf, at["n_used"])
+
+
+def _compacted_buffer(x, p, **kw):
+    """The row buffer and ``n_used`` of the compacted path and the slots
+    each token fills."""
+    at, _, chosen, buf = _rows_by_hand(x, p, compact=True, **kw)
+    return buf, at["n_used"], jnp.sum(chosen >= 0, axis=1)
+
+
+@pytest.mark.parametrize("top_k,held,offset,expert,latent,routing", [
+    shape + kind + ("drawn",)
+    for shape in [(22, 8, 0), (9, 4, 8), (5, 2, 2)]
+    for kind in [("relu2", 32), ("relu2", 0), ("swiglu", 32), ("swiglu", 0)]
+] + [(22, 8, 0, "relu2", 32, "every"), (22, 8, 0, "relu2", 32, "none")])
+def test_the_compacted_pair_domain_is_the_layer_it_was(
+        top_k, held, offset, expert, latent, routing):
+    """ISSUE 57: with a token's held choices compacted to ``held`` slots the
+    row buffer and the held rows EQUAL the [T, k] path's, and the output and
+    every gradient equal it in float32 (the stable sort orders an expert's
+    rows by token either way). ``every`` / ``none``: the selection bias makes
+    every token choose every held expert (all slots full, the buffer full: no
+    pair dropped) or none (all slots empty, one empty tile an expert)."""
+    t, d, e = PAIR_TOKENS, 32, 32 if top_k > 5 else 8
+    x, p = _layer_inputs(expert, latent, t, d, 16, 24, e, held)
+    if routing != "drawn":
+        here = (jnp.arange(e) >= offset) & (jnp.arange(e) < offset + held)
+        p["router_bias"] = jnp.where(
+            here, {"every": 10.0, "none": -10.0}[routing], 0.0)
+    kw = dict(top_k=top_k, held=held, offset=offset, scale=2.5)
+    # one cotangent for both, so that a gradient differs by its own sums alone
+    cot = jnp.cos(7.0 * x[:, ::-1])
+
+    def program(x, p):
+        y, rows = held_expert_layer(
+            x, p, experts_held=held, expert_offset=offset, top_k=top_k,
+            routed_scale=2.5, expert=expert, tile=PAIR_TILE)
+        return jnp.sum(y * cot), (y, rows)
+
+    def before(x, p):
+        y, rows, buf, n_used = _uncompacted(x, p, expert=expert, **kw)
+        return jnp.sum(y * cot), (y, rows, buf, n_used)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, rows)), g = jax.jit(jax.value_and_grad(
+            program, (0, 1), has_aux=True))(x, p)
+        (_, (want, want_rows, want_buf, want_used)), gw = jax.jit(
+            jax.value_and_grad(before, (0, 1), has_aux=True))(x, p)
+        buf, n_used, full = jax.jit(
+            lambda x, p: _compacted_buffer(x, p, **kw))(x, p)
+    np.testing.assert_array_equal(buf, want_buf)
+    assert int(n_used[0]) == int(want_used[0])
+    assert int(rows) == int(want_rows) == int(full.sum())
+    if routing == "drawn":
+        assert 0 < int(rows) < t * held and np.asarray(buf).any()
+        assert held >= int(full.max()) > int(full.min())
+    elif routing == "every":
+        assert int(rows) == t * held and (np.asarray(full) == held).all()
+        assert int(n_used[0]) * PAIR_TILE == t * held
+    else:
+        assert int(rows) == 0 and not np.asarray(full).any()
+        assert int(n_used[0]) == held and not np.asarray(buf).any()
+    # a token's rows summed over 8 slots or over 22 pairs, 14 of them zero:
+    # the same terms in another tree, read 1.8e-7 of the largest entry apart
+    # at most (one float32 step) at top 22 and 0 at top 9 and top 5
+    for got, ref_g, name in [(y, want, "y"), (g[0], gw[0], "x")] + [
+            (g[1][n], gw[1][n], n) for n in p]:
+        top = float(jnp.abs(ref_g).max())
+        if name != "router_bias" and (routing != "none"
+                                      or name[:2] not in ("e_", "w_")):
+            assert top > 0, name
+        # what is computed from the buffer's rows alone is EQUAL
+        limit = 1e-6 * top if name in ("y", "x", "w_fc1", "w_fc2") else 0.0
+        assert float(jnp.abs(got - ref_g).max()) <= limit, name
+
+
+@pytest.mark.parametrize("top_k,held,compacts", [
+    (6, 16, False), (8, 8, False), (9, 8, True)])
+def test_the_compaction_is_traced_only_where_more_are_chosen_than_held(
+        top_k, held, compacts, monkeypatch):
+    """ISSUE 57: the choice is ``top_k > experts_held``, two static arguments.
+    Where it is not so (kanana2's 6 of 16 held, kimilinear's 8 of 8) the
+    event's ``pair_slots`` is ``top_k``, ``compact_held`` is never entered and
+    the layer's jaxpr holds no cumsum over the k axis."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    entered = []
+    compact = el.compact_held
+    monkeypatch.setattr(el, "compact_held",
+                        lambda *a: entered.append(1) or compact(*a))
+    x, p = _layer_inputs("relu2", 32, PAIR_TOKENS, 32, 16, 24, 32, held)
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = str(jax.make_jaxpr(lambda x, p: held_expert_layer(
+            x, p, experts_held=held, expert_offset=8, top_k=top_k,
+            routed_scale=2.5, expert="relu2", tile=PAIR_TILE))(x, p))
+        event = [ev for ev in rec.snapshot()
+                 if ev["kind"] == "rtpu.ops.expert_layer"][-1]["data"]
+    finally:
+        rec.enabled = was
+    assert event["top_k"] == top_k
+    assert event["pair_slots"] == (held if compacts else top_k)
+    assert bool(entered) == compacts
+    # ``sort_rows``' own cumsums run over its [held] tables: axis 0
+    assert "cumsum[axis=0" in text
+    assert ("cumsum[axis=1" in text) == compacts
 
 
 def test_routing_stats_counts_the_held_rows_of_every_expert_layer(tiny):

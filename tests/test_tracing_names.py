@@ -194,6 +194,8 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
     layer = [e for e in events if e["kind"] == "rtpu.ops.expert_layer"][-1]
     assert layer["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
+        "pair_slots": 2,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE,
         # ISSUE 52: how the router scores, whether the shared expert is gated
@@ -325,6 +327,8 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "key_heads": 2, "body": "head_decay"}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
+        "pair_slots": 2,
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
         "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True,
         "expert": "swiglu", "latent": 0}
@@ -374,6 +378,8 @@ def test_a_share_of_a_latent_expert_stack_leaves_its_events():
         "vocab_rows": 512}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
+        "pair_slots": 2,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE, "score": "sigmoid", "shared_gate": False,
         "expert": "relu2", "latent": 32}
