@@ -20,6 +20,7 @@ from .sambay import SambaY, SambaYConfig
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .qwen3_next import Qwen3Next, Qwen3NextConfig
 from .nemotron_h import NemotronH, NemotronHConfig
+from .keye_vl2 import KeyeVL2, KeyeVL2Config
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
@@ -27,4 +28,5 @@ __all__ = [
     "DeepseekV3", "DeepseekV3Config", "GraniteHybrid", "GraniteHybridConfig",
     "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
     "Qwen3Next", "Qwen3NextConfig", "NemotronH", "NemotronHConfig",
+    "KeyeVL2", "KeyeVL2Config",
 ]

@@ -6,6 +6,11 @@ legend); for a TPU-native framework the hot ops are first-party:
 
 - flash_attention: tiled online-softmax attention on the MXU (Pallas);
   ``window=`` keeps a causal call to the key blocks of its band.
+- sparse_attention: grouped-query attention over the keys a learned indexer
+  selects for each query: index scores a block of queries at a time, an
+  exact top k with no sort, masked flash kernels of their own (one
+  key/value head and its group of query heads a program) with a backward
+  written by hand (Pallas).
 - ring_attention: context-parallel attention over the `sp` mesh axis —
   K/V blocks rotate the ring via ppermute while compute overlaps.
 - ssd_scan: Mamba-2's state-space recurrence as a chunked scan, forward
@@ -41,6 +46,7 @@ on TPU.
 from .attention import mha_reference
 from .flash_attention import flash_attention
 from .ring_attention import ring_attention
+from .sparse_attention import sparse_attention
 from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d,
                      causal_conv1d_silu, gated_rmsnorm, l2norm,
@@ -55,7 +61,7 @@ from .paged_attention import (paged_attention_decode,
                               paged_write_prefill, paged_write_step)
 
 __all__ = [
-    "flash_attention", "ring_attention", "mha_reference",
+    "flash_attention", "ring_attention", "mha_reference", "sparse_attention",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
     "cross_entropy_loss", "causal_conv1d", "causal_conv1d_silu",
     "gated_rmsnorm", "l2norm",

@@ -4,10 +4,11 @@ and the grouped matrix product (Pallas) it rests on.
 ``held_expert_layer`` is told which experts live here (``experts_held``,
 ``expert_offset``), scores every token against ALL experts, takes the top
 k of all of them, normalises over the k chosen whether held or not, and
-returns the shared experts' output plus the part of the routed sum that
-the held experts give. What the absent experts would add is left out (it
-is another chip's to compute; nothing here stands in for them or for
-their exchange). With every expert held it is the whole layer.
+returns the shared experts' output (where the layer has shared experts)
+plus the part of the routed sum that the held experts give. What the
+absent experts would add is left out (it is another chip's to compute;
+nothing here stands in for them or for their exchange). With every expert
+held it is the whole layer.
 
 No token is dropped whatever the routing, and no shape or trip count
 depends on it: the (token, choice) pairs that name a held expert are
@@ -31,11 +32,15 @@ are sized by tokens x slots; where it is not, the compaction is not
 traced. The trace-time event ``rtpu.ops.expert_layer`` / ``held`` says
 which (``pair_slots`` beside ``top_k``).
 
+``balance_term`` is a softmax router's load-balancing term, for a model
+that adds it to its loss: it reads the router alone (every chip holds it
+whole), so a share states it as the uncut layer does.
+
 Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
 ``router`` (scores, top-k, the sort, the gather into the buffer and the
-weighted sum back), ``experts`` (the grouped products), ``shared_expert``,
-``latent_proj`` (the projections into and out of the experts' latent, where
-the layer has one).
+weighted sum back), ``experts`` (the grouped products), ``shared_expert``
+(where the layer has one), ``latent_proj`` (the projections into and out
+of the experts' latent, where the layer has one).
 """
 from __future__ import annotations
 
@@ -283,10 +288,12 @@ def route(x, w_router, bias, *, top_k: int, routed_scale: float,
     """The router, by how it scores (a static fact of the model's
     configuration). ``sigmoid``: DeepSeek-V3's without group limiting
     (``n_group`` 1): s = sigmoid(x W) in f32; the k experts are the top k
-    of s + bias (the selection bias is a buffer: no gradient reaches it).
-    ``softmax``: s = softmax(x W) over ALL experts in f32, the k largest,
-    no bias (``bias`` None). Either way their weights are s over the
-    chosen k, summing to 1, times ``routed_scale``.
+    of s + bias (the selection bias is a buffer: no gradient reaches it);
+    ``deepseek_v3.py`` (and ``kimi_linear.py`` through its sublayer) and
+    ``nemotron_h.py`` call with it. ``softmax``: s = softmax(x W) over ALL
+    experts in f32, the k largest, no bias (``bias`` None);
+    ``qwen3_next.py`` and ``keye_vl2.py`` call with it. Either way their
+    weights are s over the chosen k, summing to 1, times ``routed_scale``.
     x [T, D] -> (weights [T, k] f32, experts [T, k] int32)."""
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)
@@ -305,6 +312,28 @@ def route(x, w_router, bias, *, top_k: int, routed_scale: float,
         chosen[:, :, None] == jnp.arange(scores.shape[1])[None, None, :],
         scores[:, None, :], 0.0), axis=-1)
     return s / jnp.sum(s, axis=1, keepdims=True) * routed_scale, chosen
+
+
+def balance_term(x, w_router, *, top_k: int, groups: int = 1):
+    """The load-balancing term of a ``softmax`` router (Switch Transformer,
+    eq. 4 to 6, with a token's k choices counted as GShard counts them),
+    a sequence at a time: E sum_e f_e P_e, f_e the share of the sequence's
+    (token, choice) pairs that name expert e (a count: no gradient), P_e
+    the sequence's mean of p_e = softmax(x W)_e in f32. 1 under a level
+    router, E / k where every token makes the same k choices with
+    certainty. The scores and the top k are ``route``'s own expressions on
+    the same operands, so XLA makes them once. x [T, D], the rows of
+    ``groups`` sequences one after another -> [groups] f32."""
+    logits = jnp.dot(x, w_router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1)
+    _, chosen = jax.lax.top_k(scores, top_k)
+    e = scores.shape[1]
+    named = jnp.sum(chosen[:, :, None] == jnp.arange(e)[None, None, :],
+                    axis=1, dtype=jnp.float32)                 # [T, E]
+    f = jnp.mean(named.reshape(groups, -1, e), axis=1) / top_k
+    p = jnp.mean(scores.reshape(groups, -1, e), axis=1)
+    return e * jnp.sum(jax.lax.stop_gradient(f) * p, axis=-1)
 
 
 def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
@@ -401,9 +430,7 @@ def _mlp(expert: str, x, p, prefix: str, matmul, row_weight=None):
     w = lambda name: p[f"{prefix}_{name}"].astype(x.dtype)    # noqa: E731
     if expert == "swiglu":
         return _gated(x, w("gate"), w("up"), w("down"), matmul, row_weight)
-    if expert == "relu2":
-        return _relu2(x, w("up"), w("down"), matmul, row_weight)
-    raise ValueError(f"expert is swiglu or relu2, got {expert!r}")
+    return _relu2(x, w("up"), w("down"), matmul, row_weight)
 
 
 def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
@@ -412,14 +439,17 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     """x [T, D] (normalised) -> (shared(x) + the held experts' part of
     sum_e w_e expert_e(x), [T, D] in x's dtype; the (token, choice) pairs
     that named a held expert, i.e. the rows the grouped product worked).
+    The shared experts are OPTIONAL: where ``p`` holds no ``s_up`` nothing
+    of the shared path is traced and the routed part alone is returned.
 
     ``p``: ``w_router`` [D, E] over ALL E experts and, where ``score`` is
     ``sigmoid``, ``router_bias`` [E] (``route``);
     ``e_gate``, ``e_up`` [held, D, F], ``e_down`` [held, F, D] of the
     experts ``expert_offset`` .. ``expert_offset + experts_held``;
-    ``s_gate``, ``s_up`` [D, Fs], ``s_down`` [Fs, D] of the shared experts
-    (side by side, one gated MLP); where it holds ``s_gate_w`` [D, 1] the
-    shared experts' output is times ``sigmoid(x s_gate_w)``, f32.
+    where the layer has shared experts, ``s_gate``, ``s_up`` [D, Fs],
+    ``s_down`` [Fs, D] (side by side, one gated MLP); where it holds
+    ``s_gate_w`` [D, 1] the shared experts' output is times
+    ``sigmoid(x s_gate_w)``, f32.
 
     ``expert`` is the kind of every MLP of the layer, routed and shared:
     ``swiglu`` the gated SiLU MLP of three matrices, ``relu2`` ``W_down
@@ -430,6 +460,9 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     L], the weighted sum comes back in L and ``w_fc2`` lifts it (scope
     ``latent_proj``, both projections); the shared experts read x itself.
     Both are static facts of the call."""
+    if expert not in ("swiglu", "relu2"):   # before anything is traced: a
+        # layer without shared experts reaches its first MLP late
+        raise ValueError(f"expert is swiglu or relu2, got {expert!r}")
     t, d = x.shape
     dt = x.dtype
     n_experts = p["w_router"].shape[1]
@@ -441,15 +474,18 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
              "pair_slots": min(top_k, experts_held),
              "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
              "row_tile": tile, "score": score,
-             "shared_gate": "s_gate_w" in p, "expert": expert,
+             "shared": "s_up" in p, "shared_gate": "s_gate_w" in p,
+             "expert": expert,
              "latent": latent})
-    with jax.named_scope("shared_expert"):
-        shared = _mlp(expert, x, p, "s", jnp.dot)
-        if "s_gate_w" in p:
-            opened = jax.nn.sigmoid(jnp.dot(
-                x, p["s_gate_w"].astype(dt),
-                preferred_element_type=jnp.float32))
-            shared = (shared.astype(jnp.float32) * opened).astype(dt)
+    shared = None
+    if "s_up" in p:
+        with jax.named_scope("shared_expert"):
+            shared = _mlp(expert, x, p, "s", jnp.dot)
+            if "s_gate_w" in p:
+                opened = jax.nn.sigmoid(jnp.dot(
+                    x, p["s_gate_w"].astype(dt),
+                    preferred_element_type=jnp.float32))
+                shared = (shared.astype(jnp.float32) * opened).astype(dt)
     with jax.named_scope("router"):
         weights, chosen = route(x, p["w_router"], p.get("router_bias"),
                                 top_k=top_k, routed_scale=routed_scale,
@@ -475,5 +511,7 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     if latent:
         with jax.named_scope("latent_proj"):
             routed = jnp.dot(routed, p["w_fc2"].astype(dt))
+    if shared is None:
+        return routed, held_rows
     with jax.named_scope("router"):
         return shared + routed, held_rows

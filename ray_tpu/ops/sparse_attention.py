@@ -1,0 +1,562 @@
+"""Grouped-query attention over the keys a learned INDEXER selects for each
+query (DeepSeek-Sparse-Attention's mechanism): a small head scores every
+causal pair, the ``topk`` best keys of a query are its set, and the main
+attention's softmax runs over that set alone. Training path, forward and a
+backward written by hand.
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])          float32
+    S_t     = the topk keys s <= t of largest I[t, s], ties to the lower s
+              (``lax.top_k``'s rule); every s <= t where t < topk
+    o[t, h] = sum_{s in S_t} softmax_{s in S_t}(q[t, h] . k[s, h // G]
+              * sm_scale) v[s, h // G]
+
+Three steps, each under a ``jax.named_scope`` of its own (the innermost
+scope is what a trace's reader attributes to):
+
+* ``indexer`` / ``index_scores``: the scores of ONE block of queries
+  (``q_chunk`` 512, the published tile) against the keys, a head at a time
+  into a float32 [chunk, S] accumulator, bf16 operands. Never [S, S]: the
+  blocks are walked by ``lax.map``, in up to four groups whose keys end
+  where the group's last query does (62.5 % of the square at four).
+* ``select`` / ``select``: the exact set, with no sort. The scores' bits
+  are mapped to integers of the same order and the k-th largest of a row
+  is found bit by bit, 32 counts over the block (``_kth_largest``): the
+  largest v with count(score >= v) >= topk. The set is the scores above
+  it and, of the scores equal to it, the first ``topk - count above`` by
+  position (a prefix count, made only where some row of the block has
+  more ties than it needs). That is ``lax.top_k``'s set; ``approx_max_k``
+  or a threshold that keeps more or fewer is another result. The order
+  is the total order ``lax.top_k`` sorts by: -0.0 below +0.0. The block
+  leaves as one BYTE a pair, [chunk, S] int8 (268 MB a layer at S 16 384,
+  where the indices [S, 2048] int32 would be 134 MB: a mask is what a
+  block-wise kernel reads, indices what a gather reads).
+* the attention (the caller's scope): flash kernels of their own
+  (``KERNEL_NAMES``) that take the mask's tile beside q, k and v and work
+  a GROUP at a time: one program holds one key/value head's block and the
+  G query heads that read it, so k, v and the mask's tile are fetched once
+  a group, and dk and dv leave summed over the group's query heads and
+  over the queries that selected a key. Blocks above the diagonal are
+  skipped by predicate and fetch nothing; a block under it is worked
+  whole, however few of its pairs are selected (under random weights the
+  selected keys are scattered evenly and no block is empty: what the
+  mechanism saves in arithmetic this route does not collect; PERF.md,
+  PR 59, has the gather route's cost beside it). Arrays cross the
+  kernels head-major, [B, H, S, D], so a head is a leading index. The
+  backward is two kernels that make the probabilities again from q, k,
+  the mask and the saved row statistics: dq over key blocks, dk/dv over
+  query blocks.
+
+No gradient passes the selection or the index scores: the indexer's three
+inputs are under ``stop_gradient`` on entry, so no backward of it is traced.
+
+Under a rematerialised layer the mask, the kernels' output and the row
+statistics carry ``checkpoint_name``s (``sparse_mask``, ``sparse_out``,
+``sparse_lse``): a policy that saves them runs neither the indexer, the
+selection nor the forward kernel again in the layer's backward.
+
+Where ``S <= topk`` every causal key is selected: the call repeats k and v
+to the query heads and is ``flash_attention(causal=True)``, the routes the
+other models take. Shapes the kernels cannot tile (S no multiple of 128, a
+head that is no multiple of 128 lanes) take the plain masked form, which
+holds [S, S] floats and is for small shapes alone.
+
+What a call did is the trace-time event ``rtpu.ops.sparse_attention`` /
+``selected`` and ``CALL_COUNTS``.
+"""
+from __future__ import annotations
+
+import collections
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from ..perf.recorder import record as _record
+from .flash_attention import (_AB, _ABT, _ATB, _LANES, _NEG_INF, _dot,
+                              _fit_block, flash_attention)
+
+# Names of the three Pallas calls as a device trace shows them; part of
+# the measurement (tests/test_tracing_names.py).
+KERNEL_NAMES = {
+    "fwd": "sparse_attn_fwd",
+    "bwd_dq": "sparse_attn_bwd_dq",     # dq, one pass over key blocks
+    "bwd_dkv": "sparse_attn_bwd_dkv",   # dk and dv, one pass over query
+                                        # blocks, summed over the group
+}
+
+# Traced calls by route: "masked_flash" (the kernels here), "causal_flash"
+# (S <= topk), "masked_reference" (no kernel).
+CALL_COUNTS: collections.Counter = collections.Counter()
+
+_VMEM_BYTES = 64 * 1024 * 1024
+# Groups of query blocks whose keys end with the group: more groups trace
+# and compile more copies of the block's program for less of the square.
+_KEY_GROUPS = 4
+
+
+def _use_interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def selected_pairs(seq: int, topk: int) -> int:
+    """(query, key) pairs a row of ``seq`` tokens selects:
+    sum_t min(t + 1, topk)."""
+    full = min(seq, topk)
+    return full * (full + 1) // 2 + (seq - full) * topk
+
+
+# ---------------------------------------------------------------------------
+# the indexer's scores and the selection, a block of queries at a time
+# ---------------------------------------------------------------------------
+
+
+def index_scores(q_idx, k_idx, w_idx):
+    """q_idx [C, Hi, Di], k_idx [S, Di] (bf16 in training), w_idx [C, Hi]
+    float32 with every scale folded in -> I [C, S] float32: the heads one
+    after the other into one accumulator, in their order."""
+    def head(acc, qw):
+        qj, wj = qw
+        dots = jax.lax.dot_general(qj, k_idx, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        return acc + wj[:, None] * jnp.maximum(dots, 0.0), None
+
+    acc = jnp.zeros((q_idx.shape[0], k_idx.shape[0]), jnp.float32)
+    return jax.lax.scan(head, acc, (jnp.swapaxes(q_idx, 0, 1),
+                                    w_idx.astype(jnp.float32).T))[0]
+
+
+def _sortable(x):
+    """float32 -> uint32 of the same order (-inf lowest, above 0)."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def _kth_largest(keys, k: int):
+    """keys [C, S] uint32 -> [C]: the largest v with count(keys >= v) >= k,
+    i.e. the k-th largest key of a row; 0 where a row has fewer than k keys
+    above 0. One bit a pass, the highest first."""
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = jnp.sum(keys >= cand[:, None], axis=1, dtype=jnp.int32)
+        return jnp.where(count >= k, cand, thr)
+
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.zeros(keys.shape[:1], jnp.uint32))
+
+
+def select(scores, topk: int, row0=0):
+    """scores [C, S] float32 of the queries ``row0`` .. ``row0 + C`` against
+    the keys 0 .. S -> [C, S] int8, 1 where key s is one of the ``topk``
+    keys s <= t of largest score, ties to the lower s: the set
+    ``lax.top_k`` takes of the causal part of the row, all of it where it
+    has no more than ``topk`` keys."""
+    c, s = scores.shape
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (c, s), 0)
+    causal = jax.lax.broadcasted_iota(jnp.int32, (c, s), 1) <= rows
+    keys = jnp.where(causal, _sortable(scores), jnp.uint32(0))
+    thr = _kth_largest(keys, topk)[:, None]
+    above = keys > thr
+    tie = (keys == thr) & causal
+    need = topk - jnp.sum(above, axis=1, dtype=jnp.int32)
+    # more ties than places: the first ``need`` of them by position
+    tie = jax.lax.cond(
+        jnp.any(jnp.sum(tie, axis=1, dtype=jnp.int32) > need),
+        lambda: tie & (jnp.cumsum(tie, axis=1, dtype=jnp.int32)
+                       <= need[:, None]),
+        lambda: tie)
+    return (above | tie).astype(jnp.int8)
+
+
+def selection_mask(q_idx, k_idx, w_idx, *, topk: int, q_chunk: int = 512):
+    """q_idx [B, S, Hi, Di], k_idx [B, S, Di], w_idx [B, S, Hi] (scaled)
+    -> [B, S, S] int8, 1 where query t selects key s. A block of
+    ``q_chunk`` queries at a time (``index_scores`` then ``select``); the
+    float32 scores of a block never leave it."""
+    b, s, hi, di = q_idx.shape
+    chunk = _chunk_of(s, q_chunk)
+    n = s // chunk
+    groups = min(_KEY_GROUPS, n)
+    bounds = [n * g // groups for g in range(groups + 1)]
+    q_idx = q_idx.reshape(b, n, chunk, hi, di)
+    w_idx = w_idx.astype(jnp.float32).reshape(b, n, chunk, hi)
+    parts = []
+    for first, last in zip(bounds, bounds[1:]):
+        keys = k_idx[:, :last * chunk]
+
+        def block(args, keys=keys):
+            qc, wc, row, at = args
+            with jax.named_scope("indexer"):
+                scores = index_scores(qc, keys[at], wc)
+            with jax.named_scope("select"):
+                return select(scores, topk, row * chunk)
+
+        m = last - first
+        flat = lambda x: x[:, first:last].reshape(             # noqa: E731
+            (b * m,) + x.shape[2:])
+        part = jax.lax.map(block, (
+            flat(q_idx), flat(w_idx),
+            jnp.tile(jnp.arange(first, last, dtype=jnp.int32), b),
+            jnp.repeat(jnp.arange(b, dtype=jnp.int32), m)))
+        with jax.named_scope("select"):
+            parts.append(jnp.pad(
+                part.reshape(b, m * chunk, last * chunk),
+                ((0, 0), (0, 0), (0, s - last * chunk))))
+    with jax.named_scope("select"):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
+
+
+def _chunk_of(seq: int, q_chunk: int) -> int:
+    """The largest divisor of ``seq`` that is no more than ``q_chunk``."""
+    chunk = min(q_chunk, seq)
+    while seq % chunk:
+        chunk -= 1
+    return chunk
+
+
+# ---------------------------------------------------------------------------
+# the kernels: q, o, dO, dq [B, H, S, D]; k, v, dk, dv [B, Hkv, S, D];
+# mask [B, S, S] int8; lse, delta [B * H, 1, S] float32
+# ---------------------------------------------------------------------------
+
+
+def _selected(mask_ref):
+    """The mask's tile as a predicate, made once a program for the group's
+    heads (the tile is widened first: the VPU compares 32-bit lanes)."""
+    return mask_ref[...].astype(jnp.int32) != 0
+
+
+def _masked_scores(q, k, sel, sm_scale):
+    return jnp.where(sel, _dot(q * jnp.asarray(sm_scale, q.dtype), k, _ABT),
+                     _NEG_INF)
+
+
+def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, sm_scale, block_q, block_k,
+                num_kb, group):
+    """Grid (B, key/value heads, query blocks, key blocks), keys innermost:
+    the float32 scratch (m, l, acc, one of each a query head of the group)
+    carries over a query block's key blocks."""
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
+    def _compute():
+        sel = _selected(mask_ref)
+        k = k_ref[...]
+        v = v_ref[...]
+
+        def head(h, carry):
+            s = _masked_scores(q_ref[h], k, sel, sm_scale)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v, _AB)
+            m_scr[h] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+    @pl.when(kb == num_kb - 1)
+    def _finalize():
+        for h in range(group):
+            l = jnp.maximum(l_scr[h], 1e-30)
+            o_ref[h] = (acc_scr[h] / l).astype(o_ref.dtype)
+            lse_ref[h] = (m_scr[h] + jnp.log(l)).T
+
+
+def _bwd_pair(q, do, k, v, sel, lse, delta, sm_scale):
+    """One query head against one block pair -> (p, ds) in the inputs'
+    dtype: the probabilities made again and dL/ds with the ``sm_scale`` of
+    s = (q scale) k^T folded in once."""
+    p = jnp.exp(_masked_scores(q, k, sel, sm_scale) - lse)
+    ds = p * (_dot(do, v, _ABT) - delta) * sm_scale
+    return p.astype(do.dtype), ds.astype(k.dtype)
+
+
+def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                   dq_ref, delta_ref, dq_scr, *, sm_scale, block_q, block_k,
+                   num_kb, group):
+    """Grid as the forward's; dq accumulates over the key blocks. Also
+    writes delta = rowsum(dO * O) a head, which it needs itself and the
+    dk/dv kernel reads."""
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+
+    @pl.when(kb == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        for h in range(group):
+            delta_ref[h] = jnp.sum(
+                do_ref[h].astype(jnp.float32) * o_ref[h].astype(jnp.float32),
+                axis=-1, keepdims=True).T
+
+    @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
+    def _compute():
+        sel = _selected(mask_ref)
+        k = k_ref[...]
+        v = v_ref[...]
+
+        def head(h, carry):
+            _, ds = _bwd_pair(q_ref[h], do_ref[h], k, v, sel, lse_ref[h].T,
+                              delta_ref[h].T, sm_scale)
+            dq_scr[h] += _dot(ds, k, _AB)
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+    @pl.when(kb == num_kb - 1)
+    def _finalize():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale,
+                    block_q, block_k, num_qb, group):
+    """Grid (B, key/value heads, key blocks, query blocks), queries
+    innermost: dk and dv of one key/value head's block accumulate over the
+    query blocks that see it and over the group's query heads."""
+    kb = pl.program_id(2)
+    qi = pl.program_id(3)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
+    def _compute():
+        sel = _selected(mask_ref)
+        k = k_ref[...]
+        v = v_ref[...]
+
+        def head(h, carry):
+            q = q_ref[h]
+            do = do_ref[h]
+            p, ds = _bwd_pair(q, do, k, v, sel, lse_ref[h].T,
+                              delta_ref[h].T, sm_scale)
+            dv_scr[...] += _dot(p, do, _ATB)
+            dk_scr[...] += _dot(ds, q, _ATB)
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+    @pl.when(qi == num_qb - 1)
+    def _finalize():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _blocks(seq: int, block_q: int, block_k: int):
+    return _fit_block(block_q, seq), _fit_block(block_k, seq)
+
+
+def _specs(group: int, kv: int, d: int, block_q: int, block_k: int,
+           q_major: bool):
+    """Block specs of a grid whose last two dimensions are (query block,
+    key block) where ``q_major`` and (key block, query block) where not.
+    A step the causal predicate skips names the block its neighbour
+    fetched (the last key block a query block sees, the first query block
+    that sees a key block), so nothing is fetched for it."""
+    def at(i, j):
+        if q_major:
+            return i, jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return jnp.maximum(j, i * block_k // block_q), i
+
+    def spec(shape, where):
+        return pl.BlockSpec(shape, lambda b, g, i, j: where(b, g, *at(i, j)))
+
+    return {
+        "mask": spec((None, block_q, block_k), lambda b, g, q, k: (b, q, k)),
+        "q": spec((None, group, block_q, d), lambda b, g, q, k: (b, g, q, 0)),
+        "kv": spec((None, None, block_k, d), lambda b, g, q, k: (b, g, k, 0)),
+        "row": spec((group, 1, block_q),
+                    lambda b, g, q, k: (b * kv + g, 0, q)),
+    }
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"),
+        vmem_limit_bytes=_VMEM_BYTES)
+
+
+def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    kv = k.shape[1]
+    group = h // kv
+    block_q, block_k = _blocks(s, block_q, block_k)
+    num_kb = s // block_k
+    sp = _specs(group, kv, d, block_q, block_k, True)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=block_q,
+                          block_k=block_k, num_kb=num_kb, group=group),
+        grid=(b, kv, s // block_q, num_kb),
+        in_specs=[sp["mask"], sp["q"], sp["kv"], sp["kv"]],
+        out_specs=[sp["q"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((group, block_q, 1), jnp.float32),
+                        pltpu.VMEM((group, block_q, 1), jnp.float32),
+                        pltpu.VMEM((group, block_q, d), jnp.float32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["fwd"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * h * s * s * d // 2,
+            bytes_accessed=(2 * q.size + k.size + v.size) * q.dtype.itemsize
+            + mask.size // 2,
+            transcendentals=b * h * s * s // 2),
+    )(mask, q, k, v)
+
+
+def _masked_bwd(q, k, v, mask, o, lse, g, sm_scale, block_q, block_k):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    kv = k.shape[1]
+    group = h // kv
+    block_q, block_k = _blocks(s, block_q, block_k)
+    num_qb, num_kb = s // block_q, s // block_k
+    itemsize = q.dtype.itemsize
+    sp = _specs(group, kv, d, block_q, block_k, True)
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, block_q=block_q,
+                          block_k=block_k, num_kb=num_kb, group=group),
+        grid=(b, kv, num_qb, num_kb),
+        in_specs=[sp["mask"], sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"],
+                  sp["row"]],
+        out_specs=[sp["q"], sp["row"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["bwd_dq"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * b * h * s * s * d // 2,
+            bytes_accessed=(4 * q.size + k.size + v.size) * itemsize
+            + mask.size // 2,
+            transcendentals=b * h * s * s // 2),
+    )(mask, q, k, v, o, g, lse)
+
+    sp = _specs(group, kv, d, block_q, block_k, False)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k, num_qb=num_qb,
+                          group=group),
+        grid=(b, kv, num_kb, num_qb),
+        in_specs=[sp["mask"], sp["q"], sp["kv"], sp["kv"], sp["q"],
+                  sp["row"], sp["row"]],
+        out_specs=[sp["kv"], sp["kv"]],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_params(),
+        name=KERNEL_NAMES["bwd_dkv"],
+        interpret=_use_interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=8 * b * h * s * s * d // 2,
+            bytes_accessed=(2 * q.size + 2 * k.size + 2 * v.size) * itemsize
+            + mask.size // 2,
+            transcendentals=b * h * s * s // 2),
+    )(mask, q, k, v, g, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _masked_gqa(q, k, v, mask, sm_scale, block_q, block_k):
+    return _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k)[0]
+
+
+def _masked_gqa_fwd(q, k, v, mask, sm_scale, block_q, block_k):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k)
+    o = checkpoint_name(o, "sparse_out")
+    lse = checkpoint_name(lse, "sparse_lse")
+    return o, (q, k, v, mask, o, lse)
+
+
+def _masked_gqa_bwd(sm_scale, block_q, block_k, res, g):
+    q, k, v, mask, o, lse = res
+    return _masked_bwd(q, k, v, mask, o, lse, g, sm_scale, block_q,
+                       block_k) + (None,)
+
+
+_masked_gqa.defvjp(_masked_gqa_fwd, _masked_gqa_bwd)
+
+
+def masked_attention_reference(q, k, v, mask, sm_scale):
+    """The plain form, float32, [S, S] scores a head: q [B, S, H, D], k and
+    v [B, S, Hkv, D], mask [B, S, S] -> [B, S, H, D]. Small shapes alone."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    qg = q.astype(jnp.float32).reshape(b, s, kv, h // kv, d)
+    scores = jnp.einsum("btkgd,bskd->bkgts", qg,
+                        k.astype(jnp.float32)) * sm_scale
+    scores = jnp.where(mask[:, None, None] != 0, scores, -jnp.inf)
+    out = jnp.einsum("bkgts,bskd->btkgd", jax.nn.softmax(scores, axis=-1),
+                     v.astype(jnp.float32))
+    return out.reshape(b, s, h, d).astype(q.dtype)
+
+
+def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     q_idx: jax.Array, k_idx: jax.Array, w_idx: jax.Array,
+                     *, topk: int, sm_scale: Optional[float] = None,
+                     q_chunk: int = 512, block_q: int = 1024,
+                     block_k: int = 1024) -> jax.Array:
+    """q [B, S, H, D], k and v [B, S, Hkv, D] (H a multiple of Hkv; query
+    head h reads key/value head h // (H / Hkv)); the indexer's q_idx
+    [B, S, Hi, Di], its ONE key k_idx [B, S, Di] and head weights w_idx
+    [B, S, Hi] with every scale folded in -> [B, S, H, D]: each query's
+    softmax over the ``topk`` causal keys of largest index score (module
+    docstring). No gradient reaches the indexer's three inputs."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    if h % kv:
+        raise ValueError(f"{h} query heads over {kv} key/value heads")
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    kernels = s % _LANES == 0 and d % _LANES == 0
+    route = "causal_flash" if s <= topk else \
+        "masked_flash" if kernels else "masked_reference"
+    CALL_COUNTS[route] += 1
+    _record("rtpu.ops.sparse_attention", "selected", {
+        "seq": s, "topk": topk, "index_heads": q_idx.shape[2],
+        "index_dim": q_idx.shape[3], "heads": h, "kv_heads": kv,
+        "head_dim": d, "route": route,
+        "saved": "none" if s <= topk else "mask_int8",
+        "q_chunk": _chunk_of(s, q_chunk),
+        "selected_pairs": selected_pairs(s, topk),
+        "causal_pairs": s * (s + 1) // 2})
+    if route == "causal_flash":
+        # every causal key is selected: the indexer decides nothing
+        return flash_attention(q, jnp.repeat(k, h // kv, axis=2),
+                               jnp.repeat(v, h // kv, axis=2), causal=True,
+                               sm_scale=sm_scale)
+    q_idx, k_idx, w_idx = jax.lax.stop_gradient((q_idx, k_idx, w_idx))
+    mask = checkpoint_name(
+        selection_mask(q_idx, k_idx, w_idx, topk=topk, q_chunk=q_chunk),
+        "sparse_mask")
+    if route == "masked_reference":
+        return masked_attention_reference(q, k, v, mask, sm_scale)
+    major = lambda x: jnp.swapaxes(x, 1, 2)                   # noqa: E731
+    return major(_masked_gqa(major(q), major(k), major(v), mask, sm_scale,
+                             block_q, block_k))

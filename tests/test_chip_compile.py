@@ -834,6 +834,47 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
     assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,8192]")
 
 
+def test_sparse_attention_layer_at_the_benchmark_cells_shape(one_chip,
+                                                             monkeypatch):
+    """ISSUE 59: keyevl2_train_s16384's attention sublayer (norm, q / k / v
+    with their norms and rotation, the indexer, the exact top 2048 a query,
+    the three kernels over the selection, the output projection) at ONE row
+    of 16 384, forward and backward, for the described v5e: the three
+    kernels by name; the selection as ONE byte a pair; and no float array
+    of 16384 x 16384, a head or not: never the main attention's scores or
+    probabilities, and the index scores a block of 512 queries at a time."""
+    from ray_tpu.models import KeyeVL2, KeyeVL2Config
+
+    sa = importlib.import_module("ray_tpu.ops.sparse_attention")
+    monkeypatch.setattr(sa, "_use_interpret", lambda: False)
+    model = KeyeVL2(KeyeVL2Config.keye_vl2_30b_a3b(
+        n_layer=1, experts_held=16, vocab_size=18992, max_seq=16384))
+    c = model.config
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    lp = {n.split(".", 2)[2]: jax.ShapeDtypeStruct(
+        v.shape[1:], v.dtype, sharding=one_chip)
+        for n, v in shapes.items() if n.startswith("0.")
+        and not n.split(".")[-1].startswith(("e_", "w_router", "mlp_"))}
+    x = jax.ShapeDtypeStruct((1, 16384, c.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(x, lp):
+        return model._attn(x, lp, *model._angles(None, 16384)).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, lp).compile().as_text()
+    calls = " ".join(line.split(" = ")[0] for line in text.splitlines()
+                     if 'custom_call_target="tpu_custom_call"' in line)
+    for name in sa.KERNEL_NAMES.values():
+        assert name in calls, (name, calls)
+    assert "s8[1,16384,16384]" in text               # the selection
+    assert re.search(r"f32\[512,\d+\]", text)        # a block's index scores
+    assert not re.findall(r"\b(?:f32|bf16|f16|f64)\[[\d,]*16384,16384\]",
+                          text)
+    assert not re.findall(r"\b(?:f32|bf16)\[[\d,]*16384,\d+,16384\]", text)
+
+
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
     """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
     B=8, S=1024, params and optimizer state donated."""

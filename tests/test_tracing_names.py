@@ -12,10 +12,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
-                            GraniteHybrid, GraniteHybridConfig, KimiLinear,
-                            KimiLinearConfig, Llama, LlamaConfig, NemotronH,
-                            NemotronHConfig, Qwen3Next, Qwen3NextConfig,
-                            SambaY, SambaYConfig)
+                            GraniteHybrid, GraniteHybridConfig, KeyeVL2,
+                            KeyeVL2Config, KimiLinear, KimiLinearConfig,
+                            Llama, LlamaConfig, NemotronH, NemotronHConfig,
+                            Qwen3Next, Qwen3NextConfig, SambaY, SambaYConfig)
 import importlib
 
 fa = importlib.import_module("ray_tpu.ops.flash_attention")  # the module
@@ -24,6 +24,7 @@ ssd = importlib.import_module("ray_tpu.ops.ssd_scan")
 sel = importlib.import_module("ray_tpu.ops.selective_scan")
 kda = importlib.import_module("ray_tpu.ops.kda_scan")
 hc = importlib.import_module("ray_tpu.ops.hyper_connection")
+sa = importlib.import_module("ray_tpu.ops.sparse_attention")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -199,7 +200,7 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE,
         # ISSUE 52: how the router scores, whether the shared expert is gated
-        "score": "sigmoid", "shared_gate": False,
+        "score": "sigmoid", "shared": True, "shared_gate": False,
         # ISSUE 56: the kind of every MLP of the layer, the latent's width
         "expert": "swiglu", "latent": 0}
     path = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
@@ -330,7 +331,8 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
         "pair_slots": 2,
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
-        "row_tile": el.ROW_TILE, "score": "softmax", "shared_gate": True,
+        "row_tile": el.ROW_TILE, "score": "softmax", "shared": True,
+        "shared_gate": True,
         "expert": "swiglu", "latent": 0}
     flash = last("rtpu.ops.flash.path")
     assert flash["label"] == "relayout" and flash["data"]["hd"] == 256
@@ -381,7 +383,7 @@ def test_a_share_of_a_latent_expert_stack_leaves_its_events():
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
         "pair_slots": 2,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
-        "row_tile": el.ROW_TILE, "score": "sigmoid", "shared_gate": False,
+        "row_tile": el.ROW_TILE, "score": "sigmoid", "shared": True, "shared_gate": False,
         "expert": "relu2", "latent": 32}
     path = last("rtpu.ops.ssd.path")
     assert path["label"] == "kernel" and path["data"]["groups"] == 1 \
@@ -393,6 +395,90 @@ def test_a_share_of_a_latent_expert_stack_leaves_its_events():
     assert runs["data"]["runs"] == [["moe+mamba", 2], ["attention", 1]]
     assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
     assert runs["data"]["side_state_bytes"] == 0
+
+
+@pytest.fixture(scope="module")
+def sparse_stack():
+    """A Keye-VL-2.0 shaped loss lowered (forward and backward) at S 256,
+    four times its selection of 64 -> (text, events)."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = KeyeVL2(KeyeVL2Config.tiny(experts_held=2, expert_offset=4))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    return text, events
+
+
+def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
+    """ISSUE 59: what a Keye-VL-2.0 shaped loss leaves at trace time.
+    ``rtpu.ops.sparse_attention`` / ``selected``: the row, the selection,
+    the indexer's heads, the ``route`` that computes the attention, what the
+    backward is handed (``saved``) and the selected beside the causal pairs
+    of a row; ``rtpu.models.keye_vl2.share``: the experts and vocabulary
+    rows held and of how many; ``rtpu.ops.expert_layer``: ``shared`` False;
+    ``rtpu.models.stack.runs``: ONE scanned run of like layers that keeps
+    the selection and the kernels' output and row statistics."""
+    _, events = sparse_stack
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    sel = last("rtpu.ops.sparse_attention")
+    assert sel["label"] == "selected" and sel["data"] == {
+        "seq": 256, "topk": 64, "index_heads": 4, "index_dim": 64,
+        "heads": 4, "kv_heads": 2, "head_dim": 128,
+        "route": "masked_flash", "saved": "mask_int8", "q_chunk": 64,
+        "selected_pairs": 64 * 65 // 2 + 192 * 64,
+        "causal_pairs": 256 * 257 // 2}
+    share = last("rtpu.models.keye_vl2.share")
+    assert share["label"] == "held" and share["data"] == {
+        "experts": [2, 8], "expert_offset": 4, "vocab_rows": 512,
+        "layers": 2}
+    assert last("rtpu.ops.expert_layer")["data"] == {
+        "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        "pair_slots": 2, "tokens": 512,
+        "row_buffer": el.buffer_rows(512, 3, 2), "row_tile": el.ROW_TILE,
+        "score": "softmax", "shared": False, "shared_gate": False,
+        "expert": "swiglu", "latent": 0}
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "keye_vl2"][-1]
+    assert runs["data"]["runs"] == [["attn_moe", 2]]
+    assert runs["data"]["kept"] == [["sparse_mask", "sparse_out",
+                                     "sparse_lse", "flash_out",
+                                     "flash_lse"]]
+    assert runs["data"]["side_state_bytes"] == 0
+
+
+@pytest.mark.parametrize("key,name", [("fwd", "sparse_attn_fwd"),
+                                      ("bwd_dq", "sparse_attn_bwd_dq"),
+                                      ("bwd_dkv", "sparse_attn_bwd_dkv")])
+def test_sparse_attention_kernel_names_are_pinned(sparse_stack, key, name):
+    """ISSUE 59: ``sparse_attention_roofline`` finds its kernels by these,
+    and they stand under the scope ``attn``."""
+    text, _ = sparse_stack
+    assert sa.KERNEL_NAMES[key] == name
+    assert re.search(r"attn/[^\n\"]*" + name + r"[/\")]", text), name
+    for other in fa.KERNEL_NAMES.values():      # no dense flash kernel
+        assert not re.search(r"[/\"(]" + other + r"[/\")]", text)
+
+
+@pytest.mark.parametrize("scope", ["indexer", "select"])
+def test_the_indexer_and_the_selection_have_scopes_and_no_backward(
+        sparse_stack, scope):
+    """ISSUE 59: ``train_indexer_ms`` and ``train_select_ms`` read these
+    scopes (the innermost on an operation's name stack, under ``attn``). No
+    gradient passes the selection, so neither stands under a transpose."""
+    text, _ = sparse_stack
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    mine = [n for n in names if re.search(r"(^|/)" + scope + "/", n)]
+    assert mine, scope
+    assert [n for n in mine if re.search(r"attn/(.*/)?" + scope + "/", n)]
+    assert not [n for n in mine if "transpose(" in n], scope
 
 
 HC_KERNELS = [("pre_fwd", "mhc_pre_fwd"), ("post_fwd", "mhc_post_fwd"),
@@ -514,6 +600,7 @@ MODELS = {
     "qwen3_next": lambda: Qwen3Next(Qwen3NextConfig.tiny(experts_held=4)),
     "nemotron_h": lambda: NemotronH(NemotronHConfig.tiny(
         experts_held=4, mamba_groups_held=1, heads_held=2)),
+    "keye_vl2": lambda: KeyeVL2(KeyeVL2Config.tiny(experts_held=4)),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -549,10 +636,13 @@ def lowered_losses():
     # ISSUE 52: a Qwen3-Next shaped model has no dense MLP, so no ``mlp``
     for s in ("embed", "attn", "lm_head", "loss")
     # ISSUE 56: nor has a Nemotron-H shaped one
-    + (("mlp",) if m not in ("qwen3_next", "nemotron_h") else ())
+    + (("mlp",) if m not in ("qwen3_next", "nemotron_h", "keye_vl2")
+       else ())
     + (("router", "experts", "shared_expert")
        if m.startswith("deepseek_v3")
        or m in ("kimi_linear", "qwen3_next", "nemotron_h") else ())
+    # ISSUE 59: an expert layer WITHOUT a shared expert
+    + (("router", "experts") if m == "keye_vl2" else ())
     # ISSUE 56: both projections of the experts' latent, under one name
     + (("latent_proj",) if m == "nemotron_h" else ())
     # ISSUE 45: everything ops/hyper_connection.py does, under one name
