@@ -42,9 +42,13 @@ scope is what a trace's reader attributes to):
   mechanism saves in arithmetic this route does not collect; PERF.md,
   PR 59, has the gather route's cost beside it). Arrays cross the
   kernels head-major, [B, H, S, D], so a head is a leading index. The
-  backward is two kernels that make the probabilities again from q, k,
-  the mask and the saved row statistics: dq over key blocks, dk/dv over
-  query blocks.
+  backward is ONE kernel on the forward's grid: it makes a block pair's
+  probabilities again from q, k, the mask and the saved row statistics,
+  ONCE, and feeds them to dq, dk and dv (5 products a pair and query
+  head). dq accumulates over a query block's key blocks; dk and dv
+  accumulate in VMEM for the whole sequence of a key/value head and leave
+  in the last query block's row, so a call whose sequence they cannot
+  hold is refused (``_bwd_vmem``).
 
 No gradient passes the selection or the index scores: the indexer's three
 inputs are under ``stop_gradient`` on entry, so no backward of it is traced.
@@ -77,13 +81,13 @@ from ..perf.recorder import record as _record
 from .flash_attention import (_AB, _ABT, _ATB, _LANES, _NEG_INF, _dot,
                               _fit_block, flash_attention)
 
-# Names of the three Pallas calls as a device trace shows them; part of
-# the measurement (tests/test_tracing_names.py).
+# Names of the two Pallas calls as a device trace shows them; part of the
+# measurement (tests/test_tracing_names.py).
 KERNEL_NAMES = {
     "fwd": "sparse_attn_fwd",
-    "bwd_dq": "sparse_attn_bwd_dq",     # dq, one pass over key blocks
-    "bwd_dkv": "sparse_attn_bwd_dkv",   # dk and dv, one pass over query
-                                        # blocks, summed over the group
+    # the whole backward: dq, and dk and dv summed over the group. The name
+    # is the one the benchmark's reader looks for
+    "bwd": "sparse_attn_bwd_dkv",
 }
 
 # Traced calls by route: "masked_flash" (the kernels here), "causal_flash"
@@ -217,7 +221,7 @@ def _chunk_of(seq: int, q_chunk: int) -> int:
 
 # ---------------------------------------------------------------------------
 # the kernels: q, o, dO, dq [B, H, S, D]; k, v, dk, dv [B, Hkv, S, D];
-# mask [B, S, S] int8; lse, delta [B * H, 1, S] float32
+# mask [B, S, S] int8; lse [B * H, 1, S] float32
 # ---------------------------------------------------------------------------
 
 
@@ -274,106 +278,84 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             lse_ref[h] = (m_scr[h] + jnp.log(l)).T
 
 
-def _bwd_pair(q, do, k, v, sel, lse, delta, sm_scale):
-    """One query head against one block pair -> (p, ds) in the inputs'
-    dtype: the probabilities made again and dL/ds with the ``sm_scale`` of
-    s = (q scale) k^T folded in once."""
-    p = jnp.exp(_masked_scores(q, k, sel, sm_scale) - lse)
-    ds = p * (_dot(do, v, _ABT) - delta) * sm_scale
-    return p.astype(do.dtype), ds.astype(k.dtype)
-
-
-def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, delta_ref, dq_scr, *, sm_scale, block_q, block_k,
-                   num_kb, group):
-    """Grid as the forward's; dq accumulates over the key blocks. Also
-    writes delta = rowsum(dO * O) a head, which it needs itself and the
-    dk/dv kernel reads."""
+def _bwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, delta_scr, dk_scr, dv_scr, *,
+                sm_scale, block_q, block_k, num_qb, num_kb, group):
+    """The whole backward, grid as the forward's: the probabilities and
+    dL/ds of a block pair and query head are made ONCE (the ``sm_scale`` of
+    s = (q scale) k^T folded in once) and feed dq, dk and dv: 5 products.
+    dq accumulates over a query block's key blocks and leaves at the last.
+    dk and dv accumulate in VMEM for the WHOLE sequence of the program's
+    one key/value head ([key blocks, block_k, D] f32 each), summed over
+    the group's query heads: a key block is complete in the LAST query
+    block's row, which computes every key block, and is written there;
+    until then its output names one block and holds still, so each goes to
+    HBM once. delta = rowsum(dO * O) a head is made at the row's first step
+    from the tiles at hand and never leaves VMEM."""
     qi = pl.program_id(2)
     kb = pl.program_id(3)
 
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_scr[kb] = jnp.zeros(dk_scr.shape[1:], dk_scr.dtype)
+        dv_scr[kb] = jnp.zeros(dv_scr.shape[1:], dv_scr.dtype)
+
     @pl.when(kb == 0)
-    def _init():
+    def _init_dq():
         dq_scr[...] = jnp.zeros_like(dq_scr)
         for h in range(group):
-            delta_ref[h] = jnp.sum(
+            delta_scr[h] = jnp.sum(
                 do_ref[h].astype(jnp.float32) * o_ref[h].astype(jnp.float32),
                 axis=-1, keepdims=True).T
 
     @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
     def _compute():
-        sel = _selected(mask_ref)
-        k = k_ref[...]
-        v = v_ref[...]
-
-        def head(h, carry):
-            _, ds = _bwd_pair(q_ref[h], do_ref[h], k, v, sel, lse_ref[h].T,
-                              delta_ref[h].T, sm_scale)
-            dq_scr[h] += _dot(ds, k, _AB)
-            return carry
-
-        jax.lax.fori_loop(0, group, head, 0)
-
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale,
-                    block_q, block_k, num_qb, group):
-    """Grid (B, key/value heads, key blocks, query blocks), queries
-    innermost: dk and dv of one key/value head's block accumulate over the
-    query blocks that see it and over the group's query heads."""
-    kb = pl.program_id(2)
-    qi = pl.program_id(3)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    @pl.when(qi * block_q + block_q - 1 >= kb * block_k)
-    def _compute():
-        sel = _selected(mask_ref)
+        # keys down, queries across: the row statistics broadcast as they
+        # are stored, [1, block_q], and of the three gradients' products
+        # only dq's takes a transposed operand (PERF.md, PR 60: 3 % faster)
+        sel = mask_ref[...].astype(jnp.float32).T != 0
         k = k_ref[...]
         v = v_ref[...]
 
         def head(h, carry):
             q = q_ref[h]
             do = do_ref[h]
-            p, ds = _bwd_pair(q, do, k, v, sel, lse_ref[h].T,
-                              delta_ref[h].T, sm_scale)
-            dv_scr[...] += _dot(p, do, _ATB)
-            dk_scr[...] += _dot(ds, q, _ATB)
+            s = jnp.where(
+                sel, _dot(k, q * jnp.asarray(sm_scale, q.dtype), _ABT),
+                _NEG_INF)
+            p = jnp.exp(s - lse_ref[h])
+            ds = (p * (_dot(v, do, _ABT) - delta_scr[h])
+                  * sm_scale).astype(k.dtype)
+            p = p.astype(do.dtype)
+            dq_scr[h] += _dot(ds, k, _ATB)
+            dv_scr[kb] += _dot(p, do, _AB)
+            dk_scr[kb] += _dot(ds, q, _AB)
             return carry
 
         jax.lax.fori_loop(0, group, head, 0)
 
+    @pl.when(kb == num_kb - 1)
+    def _dq_out():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
     @pl.when(qi == num_qb - 1)
-    def _finalize():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+    def _dkv_out():
+        dk_ref[...] = dk_scr[kb].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[kb].astype(dv_ref.dtype)
 
 
 def _blocks(seq: int, block_q: int, block_k: int):
     return _fit_block(block_q, seq), _fit_block(block_k, seq)
 
 
-def _specs(group: int, kv: int, d: int, block_q: int, block_k: int,
-           q_major: bool):
-    """Block specs of a grid whose last two dimensions are (query block,
-    key block) where ``q_major`` and (key block, query block) where not.
-    A step the causal predicate skips names the block its neighbour
-    fetched (the last key block a query block sees, the first query block
-    that sees a key block), so nothing is fetched for it."""
-    def at(i, j):
-        if q_major:
-            return i, jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
-        return jnp.maximum(j, i * block_k // block_q), i
-
+def _specs(group: int, kv: int, d: int, block_q: int, block_k: int):
+    """Block specs of the grid (B, key/value heads, query blocks, key
+    blocks). A step the causal predicate skips names the last key block its
+    query block sees, which its neighbour fetched, so nothing is fetched
+    for it."""
     def spec(shape, where):
-        return pl.BlockSpec(shape, lambda b, g, i, j: where(b, g, *at(i, j)))
+        return pl.BlockSpec(shape, lambda b, g, i, j: where(
+            b, g, i, jnp.minimum(j, (i * block_q + block_q - 1) // block_k)))
 
     return {
         "mask": spec((None, block_q, block_k), lambda b, g, q, k: (b, q, k)),
@@ -384,11 +366,10 @@ def _specs(group: int, kv: int, d: int, block_q: int, block_k: int,
     }
 
 
-def _params():
+def _params(blocks: str):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
+        dimension_semantics=("parallel", "parallel", blocks, "arbitrary"),
         vmem_limit_bytes=_VMEM_BYTES)
 
 
@@ -400,7 +381,7 @@ def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
     group = h // kv
     block_q, block_k = _blocks(s, block_q, block_k)
     num_kb = s // block_k
-    sp = _specs(group, kv, d, block_q, block_k, True)
+    sp = _specs(group, kv, d, block_q, block_k)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, num_kb=num_kb, group=group),
@@ -412,7 +393,7 @@ def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((group, block_q, 1), jnp.float32),
                         pltpu.VMEM((group, block_q, 1), jnp.float32),
                         pltpu.VMEM((group, block_q, d), jnp.float32)],
-        compiler_params=_params(),
+        compiler_params=_params("parallel"),
         name=KERNEL_NAMES["fwd"],
         interpret=_use_interpret(),
         cost_estimate=pl.CostEstimate(
@@ -423,7 +404,28 @@ def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
     )(mask, q, k, v)
 
 
+def _bwd_vmem(seq: int, block_q: int, block_k: int, group: int, d: int,
+              itemsize: int) -> int:
+    """Bytes of VMEM the backward kernel asks for: what it keeps of the
+    whole sequence beside a block pair's tiles (``sparse_attention``
+    refuses a call over ``_VMEM_BYTES``). At S 16 384 in blocks of 1024 x
+    1024, 8 query heads of 128 to a key/value head, bf16: 16.8 MB of dk and
+    dv + 16.8 MB of score tiles + 4.2 MB of dq + 21 MB of operands: 58.7
+    of 67.1; S 24 576 is the longest that fits in these blocks, S 49 152 in
+    blocks of 512 x 512."""
+    whole = 2 * seq * d * 4                                 # dk, dv
+    tiles = block_q * block_k * (3 * 4 + 2 * itemsize)      # s, dP, dS; p, dS
+    dq = group * block_q * d * 4
+    # q, o, dO in and dq out a group; k, v in and dk, dv out; the mask's
+    # tile; each double-buffered
+    operands = 2 * (itemsize * 4 * d * (group * block_q + block_k)
+                    + block_q * block_k)
+    return whole + tiles + dq + operands
+
+
 def _masked_bwd(q, k, v, mask, o, lse, g, sm_scale, block_q, block_k):
+    """-> dq, dk, dv: ONE kernel, launched under the name the benchmark's
+    reader knows (``KERNEL_NAMES``)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -431,51 +433,35 @@ def _masked_bwd(q, k, v, mask, o, lse, g, sm_scale, block_q, block_k):
     group = h // kv
     block_q, block_k = _blocks(s, block_q, block_k)
     num_qb, num_kb = s // block_q, s // block_k
-    itemsize = q.dtype.itemsize
-    sp = _specs(group, kv, d, block_q, block_k, True)
-    dq, delta = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, num_kb=num_kb, group=group),
+    sp = _specs(group, kv, d, block_q, block_k)
+    # dk and dv leave in the last query block's row and name one block
+    # until then, so that nothing is written before
+    dkv = pl.BlockSpec(
+        (None, None, block_k, d),
+        lambda b, g, i, j: (b, g, jnp.where(i == num_qb - 1, j, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, block_q=block_q,
+                          block_k=block_k, num_qb=num_qb, num_kb=num_kb,
+                          group=group),
         grid=(b, kv, num_qb, num_kb),
         in_specs=[sp["mask"], sp["q"], sp["kv"], sp["kv"], sp["q"], sp["q"],
                   sp["row"]],
-        out_specs=[sp["q"], sp["row"]],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32)],
-        compiler_params=_params(),
-        name=KERNEL_NAMES["bwd_dq"],
+        out_specs=[sp["q"], dkv, dkv],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32),
+                        pltpu.VMEM((group, 1, block_q), jnp.float32),
+                        pltpu.VMEM((num_kb, block_k, d), jnp.float32),
+                        pltpu.VMEM((num_kb, block_k, d), jnp.float32)],
+        compiler_params=_params("arbitrary"),
+        name=KERNEL_NAMES["bwd"],
         interpret=_use_interpret(),
         cost_estimate=pl.CostEstimate(
-            flops=6 * b * h * s * s * d // 2,
-            bytes_accessed=(4 * q.size + k.size + v.size) * itemsize
-            + mask.size // 2,
+            flops=10 * b * h * s * s * d // 2,
+            bytes_accessed=(4 * q.size + 2 * k.size + 2 * v.size)
+            * q.dtype.itemsize + mask.size // 2,
             transcendentals=b * h * s * s // 2),
     )(mask, q, k, v, o, g, lse)
-
-    sp = _specs(group, kv, d, block_q, block_k, False)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, num_qb=num_qb,
-                          group=group),
-        grid=(b, kv, num_kb, num_qb),
-        in_specs=[sp["mask"], sp["q"], sp["kv"], sp["kv"], sp["q"],
-                  sp["row"], sp["row"]],
-        out_specs=[sp["kv"], sp["kv"]],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_params(),
-        name=KERNEL_NAMES["bwd_dkv"],
-        interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=8 * b * h * s * s * d // 2,
-            bytes_accessed=(2 * q.size + 2 * k.size + 2 * v.size) * itemsize
-            + mask.size // 2,
-            transcendentals=b * h * s * s // 2),
-    )(mask, q, k, v, g, lse, delta)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -537,11 +523,24 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kernels = s % _LANES == 0 and d % _LANES == 0
     route = "causal_flash" if s <= topk else \
         "masked_flash" if kernels else "masked_reference"
+    fused = route == "masked_flash"
+    if fused:
+        need = _bwd_vmem(s, *_blocks(s, block_q, block_k), h // kv, d,
+                         q.dtype.itemsize)
+        if need > _VMEM_BYTES:
+            # the plain form would make S x S floats a head: no route to
+            # fall to
+            raise ValueError(
+                f"sparse_attention at S={s}: the backward keeps {need} bytes "
+                f"in VMEM, the limit is {_VMEM_BYTES}; smaller blocks than "
+                f"{block_q} x {block_k} keep less")
     CALL_COUNTS[route] += 1
     _record("rtpu.ops.sparse_attention", "selected", {
         "seq": s, "topk": topk, "index_heads": q_idx.shape[2],
         "index_dim": q_idx.shape[3], "heads": h, "kv_heads": kv,
         "head_dim": d, "route": route,
+        "backward": "fused" if fused else "none",
+        "bwd_products": 5 if fused else 0,
         "saved": "none" if s <= topk else "mask_int8",
         "q_chunk": _chunk_of(s, q_chunk),
         "selected_pairs": selected_pairs(s, topk),

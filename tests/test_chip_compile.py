@@ -838,9 +838,11 @@ def test_sparse_attention_layer_at_the_benchmark_cells_shape(one_chip,
                                                              monkeypatch):
     """ISSUE 59: keyevl2_train_s16384's attention sublayer (norm, q / k / v
     with their norms and rotation, the indexer, the exact top 2048 a query,
-    the three kernels over the selection, the output projection) at ONE row
-    of 16 384, forward and backward, for the described v5e: the three
-    kernels by name; the selection as ONE byte a pair; and no float array
+    the two kernels over the selection, the output projection) at ONE row
+    of 16 384, forward and backward, for the described v5e: the two
+    kernels by name (ISSUE 60: ONE backward, whose accumulators of a whole
+    row Mosaic takes under the module's VMEM limit, and whose delta is no
+    array any more); the selection as ONE byte a pair; and no float array
     of 16384 x 16384, a head or not: never the main attention's scores or
     probabilities, and the index scores a block of 512 queries at a time."""
     from ray_tpu.models import KeyeVL2, KeyeVL2Config
@@ -868,6 +870,11 @@ def test_sparse_attention_layer_at_the_benchmark_cells_shape(one_chip,
                      if 'custom_call_target="tpu_custom_call"' in line)
     for name in sa.KERNEL_NAMES.values():
         assert name in calls, (name, calls)
+    assert "sparse_attn_bwd_dq" not in calls
+    bwd = [line.split(" custom-call(")[0] for line in text.splitlines()
+           if re.match(r"\s*%?sparse_attn_bwd", line)]
+    # delta, rows of [.., 1, S] floats, is no output
+    assert len(bwd) == 1 and "f32[" not in bwd[0], bwd
     assert "s8[1,16384,16384]" in text               # the selection
     assert re.search(r"f32\[512,\d+\]", text)        # a block's index scores
     assert not re.findall(r"\b(?:f32|bf16|f16|f64)\[[\d,]*16384,16384\]",

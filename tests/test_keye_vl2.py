@@ -23,6 +23,7 @@ all pairs score exactly 0 (every relu shut), and 0 is the median of the
 scores, so for the queries near 128 the 64th largest score is a TIE and the
 tie rule decides their sets.
 """
+import functools
 import importlib
 import re
 
@@ -243,17 +244,24 @@ def _qkv(key, b, s, h, kv, d=128, hi=4, di=64):
             n(ks[4], (b, s, di)), n(ks[5], (b, s, hi)))
 
 
-def test_the_kernels_equal_the_plain_masked_attention_over_several_blocks():
-    """Query blocks of 128 by key blocks of 256 at S 512: blocks above the
-    diagonal skipped, the group's heads in one program, dk and dv summed
-    over a group's query heads; o and the three gradients."""
-    q, k, v, qi, ki, wi = _qkv(jax.random.PRNGKey(0), 1, 512, 2, 1)
+@pytest.mark.parametrize("kv, group, block_q, block_k", [
+    (1, 2, 128, 256), (1, 2, 128, 128), (2, 2, 256, 128), (1, 8, 128, 128),
+    (2, 8, 128, 256)])
+def test_the_kernels_equal_the_plain_masked_attention_over_several_blocks(
+        kv, group, block_q, block_k):
+    """S 512 in several block shapes: blocks above the diagonal skipped
+    (their outputs' index holding still), the group's heads in one program,
+    dk and dv summed over a group's query heads and over ALL query blocks
+    and written in the last one's row alone, key blocks whose first
+    computed query block is not block 0, a second key/value head that
+    finds the accumulators zeroed again; o and the three gradients."""
+    q, k, v, qi, ki, wi = _qkv(jax.random.PRNGKey(0), 1, 512, kv * group, kv)
     w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
     mask = sa.selection_mask(qi, ki, wi, topk=64, q_chunk=128)
 
     def kernels(q, k, v):
         return sa.sparse_attention(q, k, v, qi, ki, wi, topk=64, q_chunk=128,
-                                   block_q=128, block_k=256)
+                                   block_q=block_q, block_k=block_k)
 
     def plain(q, k, v):
         return sa.masked_attention_reference(q, k, v, mask, 128 ** -0.5)
@@ -264,6 +272,27 @@ def test_the_kernels_equal_the_plain_masked_attention_over_several_blocks():
     assert abs(float(got - want)) < 1e-3 * abs(float(want))
     for g, r in zip(grads, ref_grads):
         assert float(jnp.abs(g - r).max()) < 2e-5 * float(jnp.abs(r).max())
+
+
+def test_a_row_too_long_for_the_backwards_accumulators_is_refused():
+    """dk and dv of a key/value head's WHOLE sequence stay in VMEM through
+    the backward: a call they do not fit beside the tiles is refused by
+    name at trace time, with the bytes it would keep (shapes only)."""
+    s, h, kv = 32768, 32, 4
+    shapes = [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in (
+        (1, s, h, 128), (1, s, kv, 128), (1, s, kv, 128), (1, s, 16, 64),
+        (1, s, 64), (1, s, 16))]
+    need = sa._bwd_vmem(s, 1024, 1024, h // kv, 128, 2)
+    assert need > sa._VMEM_BYTES
+    with pytest.raises(ValueError, match=rf"sparse_attention at S={s}: the "
+                       rf"backward keeps {need} bytes in VMEM"):
+        jax.eval_shape(functools.partial(sa.sparse_attention, topk=2048),
+                       *shapes)
+    # the benchmark cell's row fits, and so does this one in smaller blocks
+    assert sa._bwd_vmem(16384, 1024, 1024, 8, 128, 2) < sa._VMEM_BYTES
+    out = jax.eval_shape(functools.partial(
+        sa.sparse_attention, topk=2048, block_q=512, block_k=512), *shapes)
+    assert out.shape == (1, s, h, 128)
 
 
 def test_no_more_keys_than_topk_is_causal_attention():

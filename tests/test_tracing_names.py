@@ -420,9 +420,11 @@ def sparse_stack():
 def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
     """ISSUE 59: what a Keye-VL-2.0 shaped loss leaves at trace time.
     ``rtpu.ops.sparse_attention`` / ``selected``: the row, the selection,
-    the indexer's heads, the ``route`` that computes the attention, what the
-    backward is handed (``saved``) and the selected beside the causal pairs
-    of a row; ``rtpu.models.keye_vl2.share``: the experts and vocabulary
+    the indexer's heads, the ``route`` that computes the attention, which
+    ``backward`` it has and the products a block pair and query head costs
+    it (ISSUE 60: ``fused``, 5), what that backward is handed (``saved``)
+    and the selected beside the causal pairs of a row;
+    ``rtpu.models.keye_vl2.share``: the experts and vocabulary
     rows held and of how many; ``rtpu.ops.expert_layer``: ``shared`` False;
     ``rtpu.models.stack.runs``: ONE scanned run of like layers that keeps
     the selection and the kernels' output and row statistics."""
@@ -432,7 +434,8 @@ def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
     assert sel["label"] == "selected" and sel["data"] == {
         "seq": 256, "topk": 64, "index_heads": 4, "index_dim": 64,
         "heads": 4, "kv_heads": 2, "head_dim": 128,
-        "route": "masked_flash", "saved": "mask_int8", "q_chunk": 64,
+        "route": "masked_flash", "backward": "fused", "bwd_products": 5,
+        "saved": "mask_int8", "q_chunk": 64,
         "selected_pairs": 64 * 65 // 2 + 192 * 64,
         "causal_pairs": 256 * 257 // 2}
     share = last("rtpu.models.keye_vl2.share")
@@ -455,14 +458,21 @@ def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
 
 
 @pytest.mark.parametrize("key,name", [("fwd", "sparse_attn_fwd"),
-                                      ("bwd_dq", "sparse_attn_bwd_dq"),
-                                      ("bwd_dkv", "sparse_attn_bwd_dkv")])
+                                      ("bwd", "sparse_attn_bwd_dkv")])
 def test_sparse_attention_kernel_names_are_pinned(sparse_stack, key, name):
     """ISSUE 59: ``sparse_attention_roofline`` finds its kernels by these,
-    and they stand under the scope ``attn``."""
+    and they stand under the scope ``attn``. ISSUE 60: the backward is ONE
+    kernel under the name the reader already had: no ``sparse_attn_bwd_dq``
+    stands in the text, and the scanned run's layer holds ONE backward call
+    beside its one forward call (kept: not run again)."""
     text, _ = sparse_stack
     assert sa.KERNEL_NAMES[key] == name
+    assert sorted(sa.KERNEL_NAMES.values()) == ["sparse_attn_bwd_dkv",
+                                                "sparse_attn_fwd"]
     assert re.search(r"attn/[^\n\"]*" + name + r"[/\")]", text), name
+    assert "sparse_attn_bwd_dq" not in text
+    calls = re.findall(r"(sparse_attn_\w+)/pallas_call\"", text)
+    assert sorted(calls) == ["sparse_attn_bwd_dkv", "sparse_attn_fwd"], calls
     for other in fa.KERNEL_NAMES.values():      # no dense flash kernel
         assert not re.search(r"[/\"(]" + other + r"[/\")]", text)
 
