@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..perf.recorder import record as _record
+from . import kernel_common
+from .kernel_common import VMEM_BYTES
 
 # Names of the two Pallas calls as a device trace shows them; part of the
 # measurement (tests/test_tracing_names.py).
@@ -67,12 +69,6 @@ ROW_TILE = 256
 
 # Traced calls of the layer by (experts held, experts in all).
 LAYER_COUNTS: collections.Counter = collections.Counter()
-
-_VMEM_BYTES = 64 * 1024 * 1024   # the dw kernel keeps a [K, N] f32 tile
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def buffer_rows(tokens: int, top_k: int, experts_held: int,
@@ -147,9 +143,9 @@ def _rows_call(x, w, tile_expert, n_used, transposed, tile):
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_BYTES),
+            vmem_limit_bytes=VMEM_BYTES),
         name=KERNEL_NAMES["rows"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
     )(tile_expert, n_used, x, w)
 
 
@@ -176,9 +172,9 @@ def _weights_call(x, dy, tile_expert, n_used, experts, dtype, tile):
         out_shape=jax.ShapeDtypeStruct((experts, k, n), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_BYTES),
+            vmem_limit_bytes=VMEM_BYTES),
         name=KERNEL_NAMES["weights"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
     )(tile_expert, n_used, x, dy)
 
 

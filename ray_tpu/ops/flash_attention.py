@@ -110,10 +110,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..perf.recorder import record as _record
+from . import kernel_common
 from .attention import mha_reference
-
-_NEG_INF = -1e30
-_LANES = 128
+from .kernel_common import AB, ABT, ATB, LANES, NEG_INF, dot, fit_block
 
 # The names of the five kernels, as a device trace and the compiled HLO
 # show them (``name=`` on ``pl.pallas_call``; each stays a custom call to
@@ -156,25 +155,6 @@ PATH_COUNTS: collections.Counter = collections.Counter()
 BAND_COUNTS: collections.Counter = collections.Counter()
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _fit_block(block: int, seq: int) -> int:
-    """Largest multiple of 128 that is <= block and divides seq. The
-    kernel path requires seq % 128 == 0 (flash_attention routes anything
-    else to mha_reference), so a 128-multiple divisor always exists —
-    sub-128 blocks would lower to illegal / silently padded Mosaic tiles
-    on real TPU."""
-    block = min(block, seq)
-    if seq % block == 0:
-        return block
-    for b in range(block - block % 128, 127, -128):
-        if seq % b == 0:
-            return b
-    return 128
-
-
 def _band_height(seq: int, causal: bool, block_q: int, block_k: int) -> int:
     """Height of the causal row bands of a call that one program holds
     whole (seq <= both blocks), or 0 where nothing is banded: a non-causal
@@ -188,10 +168,10 @@ def _band_height(seq: int, causal: bool, block_q: int, block_k: int) -> int:
     a band is unrolled Python, traced and lowered at every start of a
     process, and eight bands cost the step's build 0.6 s against 0.15 s
     for four."""
-    if not causal or _fit_block(block_q, seq) != seq \
-            or _fit_block(block_k, seq) != seq:
+    if not causal or fit_block(block_q, seq) != seq \
+            or fit_block(block_k, seq) != seq:
         return 0
-    return _fit_block(max(_LANES, seq // 4 // _LANES * _LANES), seq)
+    return fit_block(max(LANES, seq // 4 // LANES * LANES), seq)
 
 
 def _row_bands(rows: int, cols: int, band: int):
@@ -227,8 +207,8 @@ def _heads_per_block(heads: int, d: int) -> int:
     the merged layout cannot be cut that way (heads narrower than 64 would
     be worked four or more to a block at a quarter of the MXU's width;
     wider than 128 or not dividing it do not tile the lanes)."""
-    if d >= 64 and _LANES % d == 0 and (heads * d) % _LANES == 0:
-        return _LANES // d
+    if d >= 64 and LANES % d == 0 and (heads * d) % LANES == 0:
+        return LANES // d
     return 0
 
 
@@ -250,19 +230,9 @@ def _value_lanes(x, j: int, d: int, paired: bool):
     block a map (o, dO): a static slice on a lane-tile boundary."""
     if not paired:
         return _head_lanes(x, j, d)
-    if x.shape[-1] == _LANES:
+    if x.shape[-1] == LANES:
         return x
-    return x[:, j * _LANES:(j + 1) * _LANES]
-
-
-def _dot(a, b, contract):
-    return jax.lax.dot_general(a, b, (contract, ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_AB = ((1,), (0,))    # a @ b
-_ABT = ((1,), (1,))   # a @ b^T
-_ATB = ((0,), (0,))   # a^T @ b
+    return x[:, j * LANES:(j + 1) * LANES]
 
 
 def _scores(q, k, sm_scale, causal, row0, col0, window=None):
@@ -270,14 +240,14 @@ def _scores(q, k, sm_scale, causal, row0, col0, window=None):
     masked where the key lies ``window`` or more before the query);
     row0/col0 are the block's first q and k positions."""
     # scale the (block_q, d) tile, not the (block_q, block_k) s matrix
-    s = _dot(q * jnp.asarray(sm_scale, q.dtype), k, _ABT)
+    s = dot(q * jnp.asarray(sm_scale, q.dtype), k, ABT)
     if causal:
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         seen = rows >= cols
         if window is not None:
             seen = seen & (rows - cols < window)
-        s = jnp.where(seen, s, _NEG_INF)
+        s = jnp.where(seen, s, NEG_INF)
     return s
 
 
@@ -372,7 +342,7 @@ def _visible(row0, rows: int, cols: int):
     what ``_masked`` puts where it is False."""
     return (row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
             >= jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1),
-            jnp.full((rows, cols), _NEG_INF, jnp.float32))
+            jnp.full((rows, cols), NEG_INF, jnp.float32))
 
 
 def _masked(s, visible):
@@ -425,7 +395,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -449,8 +419,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[j] = acc_scr[j] * alpha + _dot(
-                p.astype(v.dtype), _value_lanes(v, j, d, paired), _AB)
+            acc_scr[j] = acc_scr[j] * alpha + dot(
+                p.astype(v.dtype), _value_lanes(v, j, d, paired), AB)
             m_scr[j] = m_new
 
     @pl.when(kb == num_kb - 1)
@@ -460,7 +430,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             l = jnp.maximum(l_scr[j], 1e-30)
             o = acc_scr[j] / l   # zero outside head j's lanes
             if paired:           # all 128 lanes are map j's
-                o_ref[:, j * _LANES:(j + 1) * _LANES] = o.astype(o_ref.dtype)
+                o_ref[:, j * LANES:(j + 1) * LANES] = o.astype(o_ref.dtype)
             else:
                 out = o if out is None else out + o
             lse_ref[j] = (m_scr[j] + jnp.log(l)).T
@@ -493,7 +463,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     def scores(u):
         r0, h, end, j = u
-        return _masked(_dot(_rows(qs[j], r0, h), k_ref[:end, :], _ABT),
+        return _masked(dot(_rows(qs[j], r0, h), k_ref[:end, :], ABT),
                        visible)
 
     out = None
@@ -505,8 +475,8 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         p = jax.lax.exp(jax.lax.sub(s, _along(m, s)))
         l = jax.lax.max(_col(jax.lax.reduce_sum(p, (1,))), 1e-30)
         # zero outside head j's lanes, so the heads' tiles add up exactly
-        o = _dot(jax.lax.convert_element_type(p, v.dtype),
-                 _rows(vs[j], 0, end), _AB)
+        o = dot(jax.lax.convert_element_type(p, v.dtype),
+                _rows(vs[j], 0, end), AB)
         o = jax.lax.div(o, _along(l, o))
         out = o if j == 0 else jax.lax.add(out, o)
         lse_ref[j, :, r0:r0 + h] = jax.lax.transpose(
@@ -523,8 +493,8 @@ def _cut(q, k, heads, hpb, causal, block_q, block_k, paired=False):
     blocks and the mean of the score's 64 and the value's 128)."""
     d = q.shape[-1] // heads
     w = hpb * d
-    return (d, w, heads // hpb, _fit_block(block_q, q.shape[1]),
-            _fit_block(block_k, k.shape[1]),
+    return (d, w, heads // hpb, fit_block(block_q, q.shape[1]),
+            fit_block(block_k, k.shape[1]),
             _band_height(q.shape[1], causal, block_q, block_k),
             *((2 * w, (d + w) // 2) if paired else (w, d)))
 
@@ -576,7 +546,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name=KERNEL_NAMES["fwd_single"],
-            interpret=_use_interpret(),
+            interpret=kernel_common.use_interpret(),
             cost_estimate=cost,
         )(q, k, v)
 
@@ -617,7 +587,7 @@ def _flash_fwd(q, k, v, heads, hpb, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"), **_window_vmem(window)),
         name=KERNEL_NAMES["fwd"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=cost,
     )(q, k, v)
 
@@ -638,7 +608,7 @@ def _bwd_head(q, k, v, do, lse, delta, j, *, d, sm_scale, causal, row0,
     qj = _head_lanes(q, j, d)
     doj = _value_lanes(do, j, d, do.shape[-1] != q.shape[-1])
     p = jnp.exp(_scores(qj, k, sm_scale, causal, row0, col0, window) - lse)
-    dp = _dot(doj, v, _ABT)
+    dp = dot(doj, v, ABT)
     ds = p * (dp - delta) * sm_scale
     return p.astype(do.dtype), ds.astype(k.dtype), qj, doj
 
@@ -694,10 +664,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def head(u):
         r0, h, end, j = u
         doj = _rows(dos[j], r0, h)
-        s = _masked(_dot(_rows(qs[j], r0, h), k_ref[:end, :], _ABT), visible)
+        s = _masked(dot(_rows(qs[j], r0, h), k_ref[:end, :], ABT), visible)
         # jax.lax in a unit's body, as in the forward
         p = jax.lax.exp(jax.lax.sub(s, _along(_rows(lse[j], r0, h), s)))
-        dp = _dot(doj, v_ref[:end, :], _ABT)
+        dp = dot(doj, v_ref[:end, :], ABT)
         ds = jax.lax.mul(jax.lax.mul(p, jax.lax.sub(
             dp, _along(_rows(delta[j], r0, h), dp))), sm_scale)
         return (jax.lax.convert_element_type(p, do.dtype),
@@ -707,9 +677,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     for (r0, h, end, j), (p, ds, doj) in _one_ahead(
             _units(block_q, block_k, band, heads), head, band):
         # each product is zero outside head j's lanes: the heads add up
-        dv_scr[:end, :] += _dot(p, doj, _ATB)
-        dk_scr[:end, :] += _dot(ds, _rows(qm[j], r0, h), _ATB)
-        dqj = _dot(ds, _rows(ks[j], 0, end), _AB)
+        dv_scr[:end, :] += dot(p, doj, ATB)
+        dk_scr[:end, :] += dot(ds, _rows(qm[j], r0, h), ATB)
+        dqj = dot(ds, _rows(ks[j], 0, end), AB)
         dq = dqj if j == 0 else jax.lax.add(dq, dqj)
         if j == heads - 1:
             dq_ref[r0:r0 + h, :] = dq.astype(dq_ref.dtype)
@@ -754,7 +724,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
                 sm_scale=sm_scale, causal=causal, row0=qi * block_q,
                 col0=col * block_k, window=window)
-            dq_scr[...] += _dot(ds, _head_lanes(k, j, d), _AB)
+            dq_scr[...] += dot(ds, _head_lanes(k, j, d), AB)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -798,8 +768,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 q, k, v, do, lse_ref[j].T, delta_ref[j].T, j, d=d,
                 sm_scale=sm_scale, causal=causal, row0=row * block_q,
                 col0=kb * block_k, window=window)
-            dv_scr[...] += _dot(p, doj, _ATB)
-            dk_scr[...] += _dot(ds, qj, _ATB)
+            dv_scr[...] += dot(p, doj, ATB)
+            dk_scr[...] += dot(ds, qj, ATB)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
@@ -820,7 +790,7 @@ def _flash_bwd(q, k, v, o, lse, g, heads, hpb, sm_scale, causal, block_q,
         q, k, heads, hpb, causal, block_q, block_k, paired)
     num_qb = seq_q // block_q
     num_kb = seq_k // block_k
-    interp = _use_interpret()
+    interp = kernel_common.use_interpret()
     from jax.experimental.pallas import tpu as pltpu
 
     bh = b * heads
@@ -1059,16 +1029,16 @@ def _latent_cut(qn, qr, v, heads, block_q, block_k):
     """-> (dn, dr, dv, heads to a program, programs a batch row, block_q,
     block_k) of a latent call on merged arrays."""
     dn, dr, dv = (x.shape[-1] // heads for x in (qn, qr, v))
-    hpb = _LANES // dr
-    return (dn, dr, dv, hpb, heads // hpb, _fit_block(block_q, qn.shape[1]),
-            _fit_block(block_k, qn.shape[1]))
+    hpb = LANES // dr
+    return (dn, dr, dv, hpb, heads // hpb, fit_block(block_q, qn.shape[1]),
+            fit_block(block_k, qn.shape[1]))
 
 
 def _latent_ok(heads: int, dn: int, dr: int, dv: int) -> bool:
     """Whether the latent kernels can cut these heads into 128-lane
     tiles."""
-    return (dr in (64, _LANES) and dn % _LANES == 0 and dv % _LANES == 0
-            and heads % (_LANES // dr) == 0)
+    return (dr in (64, LANES) and dn % LANES == 0 and dv % LANES == 0
+            and heads % (LANES // dr) == 0)
 
 
 def _lanes(x, j: int, d: int):
@@ -1094,12 +1064,12 @@ def _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked, row0, col0):
     """Head j's [block_q, block_k] scores in f32: both parts on the MXU,
     q scaled on its (block_q, d) tiles."""
     scale = jnp.asarray(sm_scale, qn.dtype)
-    s = _dot(_lanes(qn, j, dn) * scale, _lanes(kn, j, dn), _ABT) \
-        + _dot(_head_lanes(qr, j, dr) * scale, kr, _ABT)
+    s = dot(_lanes(qn, j, dn) * scale, _lanes(kn, j, dn), ABT) \
+        + dot(_head_lanes(qr, j, dr) * scale, kr, ABT)
     if masked:
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = jnp.where(rows >= cols, s, NEG_INF)
     return s
 
 
@@ -1111,7 +1081,7 @@ def _latent_band(causal: bool, block_q: int, block_k: int) -> int:
     of 256 (one band of 128 is the block). ``_band_height``'s rule
     applied to the BLOCK: a quarter of it in whole 128-lane tiles, 256 at
     blocks of 1024, 10 of a diagonal block's 16 band tiles issued."""
-    if not causal or block_q != block_k or block_q % (2 * _LANES):
+    if not causal or block_q != block_k or block_q % (2 * LANES):
         return 0
     return _band_height(block_q, True, block_q, block_k)
 
@@ -1133,9 +1103,9 @@ def _latent_band_scores(band, qn_ref, qr_ref, kn_ref, kr_ref, heads, dn, dr,
     def scores(u):
         r0, h, end, j = u
         return _masked(jax.lax.add(
-            _dot(_rows(qns[j], r0, h), kn_ref[:end, j * dn:(j + 1) * dn],
-                 _ABT),
-            _dot(_rows(qrs[j], r0, h), kr_ref[:end, :], _ABT)), visible)
+            dot(_rows(qns[j], r0, h), kn_ref[:end, j * dn:(j + 1) * dn],
+                ABT),
+            dot(_rows(qrs[j], r0, h), kr_ref[:end, :], ABT)), visible)
 
     return scores
 
@@ -1153,7 +1123,7 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -1171,8 +1141,8 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
             l_scr[j, rows, :] = jax.lax.add(
                 jax.lax.mul(l_scr[j, rows, :], alpha),
                 _col(jax.lax.reduce_sum(p, (1,))))
-            pv = _dot(jax.lax.convert_element_type(p, v_ref.dtype),
-                      v_ref[:end, j * dv:(j + 1) * dv], _AB)
+            pv = dot(jax.lax.convert_element_type(p, v_ref.dtype),
+                     v_ref[:end, j * dv:(j + 1) * dv], AB)
             acc_scr[j, rows, :] = jax.lax.add(
                 jax.lax.mul(acc_scr[j, rows, :], _along(alpha, pv)), pv)
             m_scr[j, rows, :] = m_new
@@ -1190,8 +1160,8 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[j] = acc_scr[j] * alpha + _dot(
-                p.astype(v.dtype), _lanes(v, j, dv), _AB)
+            acc_scr[j] = acc_scr[j] * alpha + dot(
+                p.astype(v.dtype), _lanes(v, j, dv), AB)
             m_scr[j] = m_new
 
     _causal_steps(causal, qi, kb, block_q, block_k, compute)
@@ -1212,7 +1182,7 @@ def _latent_bwd_head(qn, qr, kn, kr, v, do, lse, delta, j, *, dn, dr, dv,
     s = _latent_scores(qn, qr, kn, kr, j, dn, dr, sm_scale, masked, row0,
                        col0)
     p = jnp.exp(s - lse)
-    dp = _dot(_lanes(do, j, dv), _lanes(v, j, dv), _ABT)
+    dp = dot(_lanes(do, j, dv), _lanes(v, j, dv), ABT)
     ds = p * (dp - delta) * sm_scale
     return p.astype(do.dtype), ds.astype(kn.dtype)
 
@@ -1254,7 +1224,7 @@ def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         s = scores(u)
         doj = _rows(_lanes(do, j, dv), r0, h)
         p = jax.lax.exp(jax.lax.sub(s, _along(_rows(lse[j], r0, h), s)))
-        dp = _dot(doj, v_ref[:end, j * dv:(j + 1) * dv], _ABT)
+        dp = dot(doj, v_ref[:end, j * dv:(j + 1) * dv], ABT)
         ds = jax.lax.mul(jax.lax.mul(p, jax.lax.sub(
             dp, _along(_rows(delta[j], r0, h), dp))), sm_scale)
         return (jax.lax.convert_element_type(p, do.dtype),
@@ -1264,11 +1234,11 @@ def _latent_bwd_bands(band, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
             _units(block, block, band, heads), head, True):
         rows, nope, val = (slice(r0, r0 + h), slice(j * dn, (j + 1) * dn),
                            slice(j * dv, (j + 1) * dv))
-        dv_acc[:end, val] += _dot(p, doj, _ATB)
-        dkn_acc[:end, nope] += _dot(ds, _rows(_lanes(qn, j, dn), r0, h), _ATB)
-        dkr_acc[:end, :] += _dot(ds, _rows(qrs[j], r0, h), _ATB)
-        dqn_acc[rows, nope] += _dot(ds, kn_ref[:end, nope], _AB)
-        dqr_acc[rows, :] += _dot(ds, _rows(krs[j], 0, end), _AB)
+        dv_acc[:end, val] += dot(p, doj, ATB)
+        dkn_acc[:end, nope] += dot(ds, _rows(_lanes(qn, j, dn), r0, h), ATB)
+        dkr_acc[:end, :] += dot(ds, _rows(qrs[j], r0, h), ATB)
+        dqn_acc[rows, nope] += dot(ds, kn_ref[:end, nope], AB)
+        dqr_acc[rows, :] += dot(ds, _rows(krs[j], 0, end), AB)
 
 
 def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
@@ -1330,13 +1300,13 @@ def _latent_bwd_fused_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
                 qn, qr, kn, kr, v, do, lse_ref[j].T, delta, j, dn=dn, dr=dr,
                 dv=dv, sm_scale=sm_scale, masked=masked, row0=qi * block,
                 col0=kb * block)
-            dv_scr[:, j * dv:(j + 1) * dv] += _dot(p, doj, _ATB)
-            dkn_scr[:, j * dn:(j + 1) * dn] += _dot(ds, _lanes(qn, j, dn),
-                                                    _ATB)
-            dkr_scr[kb] += _dot(ds, _head_lanes(qr, j, dr), _ATB)
-            dqn_scr[qi, :, j * dn:(j + 1) * dn] += _dot(
-                ds, _lanes(kn, j, dn), _AB)
-            dqr_scr[qi] += _dot(ds, _head_lanes(kr, j, dr), _AB)
+            dv_scr[:, j * dv:(j + 1) * dv] += dot(p, doj, ATB)
+            dkn_scr[:, j * dn:(j + 1) * dn] += dot(ds, _lanes(qn, j, dn),
+                                                   ATB)
+            dkr_scr[kb] += dot(ds, _head_lanes(qr, j, dr), ATB)
+            dqn_scr[qi, :, j * dn:(j + 1) * dn] += dot(
+                ds, _lanes(kn, j, dn), AB)
+            dqr_scr[qi] += dot(ds, _head_lanes(kr, j, dr), AB)
         if masked:   # the diagonal step: the last key block these rows see
             dqn_ref[...] = dqn_scr[qi].astype(dqn_ref.dtype)
             dqr_ref[...] = dqr_scr[qi].astype(dqr_ref.dtype)
@@ -1383,11 +1353,11 @@ def _latent_specs(q_major: bool, causal, block_q, block_k, hpb, ncb, widths):
     def k_side(w):
         return pl.BlockSpec((None, block_k, w), at(lambda b, c, i, j: (b, j, c)))
 
-    shared = pl.BlockSpec((None, block_k, _LANES),
+    shared = pl.BlockSpec((None, block_k, LANES),
                           at(lambda b, c, i, j: (b, j, 0)))
     rows = pl.BlockSpec((hpb, 1, block_q),
                         at(lambda b, c, i, j: (b * ncb + c, 0, i)))
-    return q_side(wn), q_side(_LANES), q_side(wv), k_side(wn), k_side(wv), \
+    return q_side(wn), q_side(LANES), q_side(wv), k_side(wn), k_side(wv), \
         shared, rows
 
 
@@ -1425,7 +1395,7 @@ def _latent_fwd(qn, qr, kn, kr, v, heads, sm_scale, causal, block_q,
                                  "arbitrary"),
             vmem_limit_bytes=_LATENT_VMEM_BYTES),
         name=LATENT_KERNEL_NAMES["fwd"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * heads * seq * seq * (dn + dr + dv) // half,
             bytes_accessed=(qn.size + qr.size + kn.size + kr.size
@@ -1442,12 +1412,12 @@ def _latent_bwd_vmem(seq: int, block: int, wn: int, wv: int,
     of 128 to a block: 16.8 MB of whole-sequence accumulators + 16.8 MB of
     score tiles + 2 MB + 10.5 MB of operands: 46 of 96; S 34 816 is the
     longest that fits at these widths."""
-    whole = seq * (wn + 2 * _LANES) * 4         # dq_nope, dq_rope, d(kr)
+    whole = seq * (wn + 2 * LANES) * 4         # dq_nope, dq_rope, d(kr)
     tiles = block * block * (3 * 4 + 2 * itemsize)      # s, dP, ds; p, ds
     dkv = block * (wn + wv) * 4
     # q_nope, q_rope, o, dO, k_nope, kr, v in; the five gradients out;
     # each double-buffered; a q side and a k side of one block each
-    operands = 2 * 2 * itemsize * (2 * block * (wn + wv + _LANES))
+    operands = 2 * 2 * itemsize * (2 * block * (wn + wv + LANES))
     return whole + tiles + dkv + operands
 
 
@@ -1474,9 +1444,9 @@ def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
     # dq's blocks leave by key block (square blocks: k_nope's spec fits
     # dq_nope); the shared key's gradient leaves in the last head block
     # and names one block until then, so that nothing is written before
-    dqr_s = pl.BlockSpec((None, block, _LANES), lambda b, c, j, i: (b, j, c))
+    dqr_s = pl.BlockSpec((None, block, LANES), lambda b, c, j, i: (b, j, c))
     dkr_s = pl.BlockSpec(
-        (None, block, _LANES),
+        (None, block, LANES),
         lambda b, c, j, i: (b, jnp.where(c == ncb - 1, j, 0), 0))
     return pl.pallas_call(
         functools.partial(
@@ -1489,16 +1459,16 @@ def _latent_bwd(qn, qr, kn, kr, v, o, lse, g, heads, sm_scale, causal,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (qn, qr, kn, kr, v)],
         scratch_shapes=[pltpu.VMEM((nb, block, wn), jnp.float32),
-                        pltpu.VMEM((nb, block, _LANES), jnp.float32),
+                        pltpu.VMEM((nb, block, LANES), jnp.float32),
                         pltpu.VMEM((block, wn), jnp.float32),
-                        pltpu.VMEM((nb, block, _LANES), jnp.float32),
+                        pltpu.VMEM((nb, block, LANES), jnp.float32),
                         pltpu.VMEM((block, wv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=_LATENT_VMEM_BYTES),
         name=LATENT_KERNEL_NAMES["bwd_dkv"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * pairs * (3 * (dn + dr) + 2 * dv),
             bytes_accessed=(2 * qn.size + 2 * qr.size + 2 * kn.size
@@ -1562,9 +1532,9 @@ def _latent_attention(q, k, v, q_rope, k_rope, causal, sm_scale, block_q,
         return mha_reference(jnp.concatenate([q, q_rope], -1),
                              jnp.concatenate([k, shared], -1), v,
                              causal=causal, sm_scale=sm_scale)
-    hpb = _LANES // dr
+    hpb = LANES // dr
     # square blocks: the smaller of the two fitted ones divides S too
-    block = min(_fit_block(block_q, s), _fit_block(block_k, s))
+    block = min(fit_block(block_q, s), fit_block(block_k, s))
     need = _latent_bwd_vmem(s, block, hpb * dn, hpb * dv, q.dtype.itemsize)
     if need > _LATENT_VMEM_BYTES:
         # the reference would make S x S scores a head: no route to fall to
@@ -1587,7 +1557,7 @@ def _window_facts(window, s: int, block_q: int, block_k: int) -> dict:
     call without a window."""
     if window is None:
         return {}
-    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+    bq, bk = fit_block(block_q, s), fit_block(block_k, s)
     over = (s // bq, bq, bk)
     return {"window": window, "block_q": bq, "block_k": bk,
             "blocks_visited": sum(_band_counts(*over, window - 1, 0,

@@ -77,7 +77,6 @@ from __future__ import annotations
 
 import collections
 import functools
-import importlib
 from typing import Callable, Dict, Sequence, Tuple
 
 import jax
@@ -85,11 +84,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..perf.recorder import record as _record
-
-# The module, not the function of its name that the package exports: the
-# kernels here run interpreted where the flash kernels do, by the one
-# switch (``_use_interpret``) a described-chip compile steers.
-_flash = importlib.import_module(__package__ + ".flash_attention")
+from . import kernel_common
+from .kernel_common import LANES, VMEM_BYTES
 
 HC_PARAMS = ("phi", "gain", "bias", "alpha")
 SCOPE = "mhc"
@@ -110,9 +106,7 @@ ROUTE_COUNTS: collections.Counter = collections.Counter()
 
 TOKEN_TILE = 128              # tokens a program works
 _GROUP = 8                    # rows a group of coefficients takes
-_LANES = 128
 _ROWS = 16                    # tokens a loop iteration mixes
-_VMEM_BYTES = 64 * 1024 * 1024
 _F32 = jnp.float32
 # what the coefficients are held in between the kernels (a test rounds
 # them to show that its limits would see it)
@@ -216,7 +210,7 @@ def _to_columns(rows, col_ref) -> None:
     tokens-major streams need them."""
     r, tt = rows.shape
     full = jnp.concatenate(
-        [rows.astype(_F32), jnp.zeros((_LANES - r, tt), _F32)], axis=0)
+        [rows.astype(_F32), jnp.zeros((LANES - r, tt), _F32)], axis=0)
     col_ref[...] = full.T
 
 
@@ -233,9 +227,9 @@ def _put_lane(block, c: int, col):
 
 def _fold(v):
     """[rows, W] summed over its lane tiles and lanes -> [rows, 1]."""
-    out = v[:, :_LANES]
-    for i in range(1, v.shape[1] // _LANES):
-        out = out + v[:, i * _LANES:(i + 1) * _LANES]
+    out = v[:, :LANES]
+    for i in range(1, v.shape[1] // LANES):
+        out = out + v[:, i * LANES:(i + 1) * LANES]
     return jnp.sum(out, axis=1, keepdims=True)
 
 
@@ -330,7 +324,7 @@ def _pre_fwd_kernel(*refs, n, d, iters, eps, clamp, rms_eps):
     def statistic(rows):
         sq = [jnp.square(_f32(x[j], rows)) for j in range(n)]
         col_ref[rows, :] = jnp.broadcast_to(_fold(sum(sq[1:], sq[0])),
-                                            (_ROWS, _LANES))
+                                            (_ROWS, LANES))
 
     _each_rows(tt, statistic)
     ss = col_ref[...].T[0:1, :]                         # [1, tile]
@@ -379,7 +373,7 @@ def _post_bwd_kernel(*refs, n):
     def reduce(rows):
         h = col_ref[rows, :]
         xf, yf = [_f32(x[j], rows) for j in range(n)], _f32(y_ref, rows)
-        block = jnp.zeros((_ROWS, _LANES), _F32)
+        block = jnp.zeros((_ROWS, LANES), _F32)
         dy = None
         for i in range(n):
             gf = _f32(g[i], rows)
@@ -407,7 +401,7 @@ def _pre_bwd_kernel(*refs, n, d, iters, eps, clamp):
 
     def reduce(rows):                                   # dH_pre
         dzf = _f32(dz_ref, rows)
-        block = jnp.zeros((_ROWS, _LANES), _F32)
+        block = jnp.zeros((_ROWS, LANES), _F32)
         for j in range(n):
             block = _put_lane(block, j, _fold(dzf * _f32(x[j], rows)))
         dcol_ref[rows, :] = block
@@ -478,8 +472,8 @@ def _call(kernel, name, tokens, grid_in, out_specs, out_shape, scratch,
         input_output_aliases=aliases or {},
         scratch_shapes=[pltpu.VMEM(s, _F32) for s in scratch],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=order, vmem_limit_bytes=_VMEM_BYTES),
-        name=KERNEL_NAMES[name], interpret=_flash._use_interpret())
+            dimension_semantics=order, vmem_limit_bytes=VMEM_BYTES),
+        name=KERNEL_NAMES[name], interpret=kernel_common.use_interpret())
 
 
 def _stream_spec(d):
@@ -495,13 +489,13 @@ def _whole(shape):
 
 
 def _cols():
-    return (TOKEN_TILE, _LANES)
+    return (TOKEN_TILE, LANES)
 
 
 def _cut():
     """What a kernel's trace reads from this module beside its arguments."""
     return (TOKEN_TILE, _ROWS, jnp.dtype(_COEF_DTYPE).name,
-            _flash._use_interpret())
+            kernel_common.use_interpret())
 
 
 def _traced_once(fn):
@@ -592,7 +586,7 @@ def _read(static, x, phi_t, scale, bias):
 
 def _scale_bias_lanes(scale, bias):
     sb = jnp.stack([scale, bias], 1).astype(_F32)
-    return jnp.pad(sb, ((0, 0), (0, _LANES - 2)))
+    return jnp.pad(sb, ((0, 0), (0, LANES - 2)))
 
 
 def _read_fwd(static, x, phi_t, scale, bias):
@@ -674,8 +668,8 @@ def _vmem_bytes(n: int, d: int, itemsize: int) -> int:
 
 
 def _route(n: int, d: int, tokens: int, itemsize: int) -> str:
-    fits = d % _LANES == 0 and tokens % TOKEN_TILE == 0 and n <= _GROUP \
-        and _vmem_bytes(n, d, itemsize) <= 0.9 * _VMEM_BYTES
+    fits = d % LANES == 0 and tokens % TOKEN_TILE == 0 and n <= _GROUP \
+        and _vmem_bytes(n, d, itemsize) <= 0.9 * VMEM_BYTES
     return "kernel" if fits else "plain"
 
 

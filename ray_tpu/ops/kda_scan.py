@@ -252,21 +252,15 @@ from __future__ import annotations
 
 import collections
 import functools
-import importlib
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot
+from . import kernel_common
+from .kernel_common import (AB, ABT, ATB, LANES, VMEM_BYTES, dot, lane_sum,
+                            pad_tokens, record_path, spread)
 from .layers import l2norm
-from .scan_common import pad_tokens, record_path
-from .ssd_scan import _spread, _sum
-
-# The module, not the function of its name that the package exports: the
-# kernels here run interpreted where the flash kernels do, by the one
-# switch (``_use_interpret``) a described-chip compile steers.
-_flash = importlib.import_module(__package__ + ".flash_attention")
 
 # The names of the two kernels, as a device trace and the compiled HLO
 # show them (``name=`` on ``pl.pallas_call``); pinned in
@@ -498,7 +492,6 @@ def _head_decay_chunked(q, k, v, g, beta, key_heads: int, chunk: int,
 # row spread down the sublanes, never a [d_k, 1] column.
 
 _MAX_HEADS_PER_BLOCK = 4
-_VMEM_BYTES = 64 * 1024 * 1024
 _HEAD_ROWS = 8    # the sublanes of the tile on which a block's numbers a
 #                   head and token are worked (one decay a head): its heads
 #                   the first rows
@@ -570,9 +563,9 @@ def _against_earlier(qb, kb, gb, kf, cum, dt):
     garbage <= |k| and are masked by the caller."""
     r, d = gb.shape
     first = _rows(gb, 0, 1)
-    up = jax.lax.exp(_minus(gb, _spread(first, (r, d))))
+    up = jax.lax.exp(_minus(gb, spread(first, (r, d))))
     up = jax.lax.concatenate([up, up], 0)
-    down = _decay(_minus(_spread(first, cum.shape), cum))
+    down = _decay(_minus(spread(first, cum.shape), cum))
     rows = _mul(jax.lax.concatenate([qb, kb], 0), up).astype(dt)
     return rows, up, _mul(kf, down).astype(dt), down
 
@@ -608,7 +601,7 @@ def _diagonal(qf, kf, cum, r: int):
     shape = (c // r, r, c)
     sq = sk = _full(shape, 0)
     along = lambda x: jax.lax.broadcast_in_dim(              # noqa: E731
-        _sum(x, 2), shape, (0, 1, 2))
+        lane_sum(x, 2), shape, (0, 1, 2))
     for j in range(r):
         kj = _mul(_row_of_blocks(k3, j),
                   _decay(_minus(g3, _row_of_blocks(g3, j))))
@@ -627,7 +620,7 @@ def _diagonal_bwd(qf, kf, cum, dsq, dsk, r: int):
     dq3, dk3 = _blocks(dsq, r), _blocks(dsk, r)
     zero = _zeros_like(dq3)
     own = lambda x, here: jax.lax.broadcast_in_dim(          # noqa: E731
-        _sum(jax.lax.select(here, x, zero), 2), q3.shape, (0, 1, 2))
+        lane_sum(jax.lax.select(here, x, zero), 2), q3.shape, (0, 1, 2))
     sub = _iota(q3.shape, 1)
     dq = dk = dc = _full(q3.shape, 0)
     for j in range(r):
@@ -637,7 +630,7 @@ def _diagonal_bwd(qf, kf, cum, dsq, dsk, r: int):
         cq, ck = own(dq3, here), own(dk3, here)
         dq = _add(dq, _mul(cq, kj))
         dk = _add(dk, _mul(ck, kj))
-        got = _sum(_mul(_add(_mul(cq, q3), _mul(ck, k3)), e), 1)
+        got = lane_sum(_mul(_add(_mul(cq, q3), _mul(ck, k3)), e), 1)
         dc = jax.lax.select(_is(sub, j), jax.lax.broadcast_in_dim(
             got, q3.shape, (0, 1, 2)), dc)
     return tuple(jax.lax.reshape(x, (c, d)) for x in (dq, dk, dc))
@@ -652,7 +645,7 @@ def _scores(qf, kf, cum, dt, r: int):
     for b in range(1, c // r):
         rows, _, cols, _ = _against_earlier(
             *_block_pieces(qf, kf, cum, b * r, r), kf, cum, dt)
-        off = _dot(rows, cols, _ABT)                           # [2r, C]
+        off = dot(rows, cols, ABT)                           # [2r, C]
         bands.append(jax.lax.select(_below(lane, b * r), off,
                                     _zeros_like(off)))
     sq, sk = _diagonal(qf, kf, cum, r)
@@ -679,10 +672,10 @@ def _scores_bwd(qf, kf, cum, dsq, dsk, dt, r: int):
         both = jax.lax.concatenate([_rows(dsq, lo, r), _rows(dsk, lo, r)], 0)
         both = jax.lax.select(_below(lane, lo), both, _zeros_like(both)
                               ).astype(dt)
-        drows = _mul(_dot(both, cols, _AB), up)                # [2r, d]
+        drows = _mul(dot(both, cols, AB), up)                # [2r, d]
         bands_q.append(_rows(drows, 0, r))
         bands_k.append(_rows(drows, r, r))
-        dcol = _add(dcol, _mul(_dot(both, rows, _ATB), down))
+        dcol = _add(dcol, _mul(dot(both, rows, ATB), down))
     return (_add(dq, jax.lax.concatenate(bands_q, 0)),
             _add(dk, jax.lax.concatenate(bands_k, 0)), dcol)
 
@@ -732,15 +725,15 @@ def _solve(a, r: int):
     p = [jax.lax.select(inside, x, zero) for x in a]
     t, m = [_minus(eye, x) for x in p], 2
     while m < r:
-        p = [_exact_dot(x, _apart(x), _AB) for x in p]
-        t = [_exact_dot(y, _apart(_add(eye, x)), _AB) for x, y in zip(p, t)]
+        p = [_exact_dot(x, _apart(x), AB) for x in p]
+        t = [_exact_dot(y, _apart(_add(eye, x)), AB) for x, y in zip(p, t)]
         m *= 2
     while r < c:
         inside, around = _same_block(shape, r), _same_block(shape, 2 * r)
         joined = [_exact_dot(y, _apart(jax.lax.select(
-            inside, zero, jax.lax.select(around, x, zero))), _AB)
+            inside, zero, jax.lax.select(around, x, zero))), AB)
             for x, y in zip(a, t)]
-        t = [_minus(y, _exact_dot(j, _apart(y), _AB))
+        t = [_minus(y, _exact_dot(j, _apart(y), AB))
              for j, y in zip(joined, t)]
         r *= 2
     return t
@@ -768,13 +761,13 @@ def _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt):
     kept = jax.lax.exp(cum)
     bkv = jax.lax.concatenate([_mul(_mul(kf, bcol), kept), _mul(vf, bcol)],
                               1).astype(dt)                   # [C, 2d]
-    wu = _dot(t.astype(dt), bkv, _AB)
+    wu = dot(t.astype(dt), bkv, AB)
     w, u = _cols(wu, 0, d).astype(dt), _cols(wu, d, d)
     q_in = _mul(qf, kept).astype(dt)
     held = st.astype(dt)
-    from_state = _dot(jax.lax.concatenate([w, q_in], 0), held, _ABT)
+    from_state = dot(jax.lax.concatenate([w, q_in], 0), held, ABT)
     wrote = _minus(u, _rows(from_state, 0, c)).astype(dt)
-    last = _spread(_rows(cum, c - 1, 1), (c, d))
+    last = spread(_rows(cum, c - 1, 1), (c, d))
     to_end = jax.lax.exp(_minus(last, cum))
     return dict(kept=kept, bkv=bkv, w=w, q_in=q_in,
                 held=held, wrote=wrote, to_end=to_end,
@@ -795,12 +788,12 @@ def _three(x):
 
 
 def _triangle_sums(x, contract):
-    """The triangle of ones times x [C, w] (``_AB``: the inclusive
+    """The triangle of ones times x [C, w] (``AB``: the inclusive
     cumulative sum over the chunk's tokens) or its transpose times x
-    (``_ATB``: the sums from each token to the chunk's end), float32."""
+    (``ATB``: the sums from each token to the chunk's end), float32."""
     c = x.shape[0]
     tri = _ge(_iota((c, c), 0), _iota((c, c), 1)).astype(jnp.bfloat16)
-    a, b, e = (_dot(tri, piece, contract) for piece in _three(x))
+    a, b, e = (dot(tri, piece, contract) for piece in _three(x))
     return _add(_add(a, b), e)
 
 
@@ -810,11 +803,11 @@ def _beta_column(row):
     times ones, on the MXU (a [C, 1] column costs a register a sublane
     whatever is done to it)."""
     c = row.shape[1]
-    row = _spread(row, (c, c))
+    row = spread(row, (c, c))
     on = _eq(_iota((c, c), 0), _iota((c, c), 1))
     pieces = _three(jax.lax.select(on, row, _zeros_like(row)))
-    return _dot(jax.lax.concatenate(pieces, 1),
-                _full((3 * c, _LANES), 1, jnp.bfloat16), _AB)
+    return dot(jax.lax.concatenate(pieces, 1),
+               _full((3 * c, LANES), 1, jnp.bfloat16), AB)
 
 
 def _heads_scores(heads, dt, r: int):
@@ -841,16 +834,17 @@ def _unit(x, eps: float):
     """A head's tile x [C, 128] float32 -> (x / sqrt(sum over the lanes of
     x^2 + eps): ``layers.l2norm``, and that reciprocal root in every
     lane)."""
-    inv = jax.lax.rsqrt(_add(_sum(_mul(x, x), 1), _full((x.shape[0], 1), eps)))
-    inv = _spread(inv, x.shape)
+    inv = jax.lax.rsqrt(_add(lane_sum(_mul(x, x), 1),
+                             _full((x.shape[0], 1), eps)))
+    inv = spread(inv, x.shape)
     return _mul(x, inv), inv
 
 
 def _unit_bwd(dy, y, inv):
     """``_unit``'s gradient from its two results: inv (dy - y sum over the
     lanes of dy y)."""
-    return _mul(inv, _minus(dy, _mul(y, _spread(_sum(_mul(dy, y), 1),
-                                                y.shape))))
+    return _mul(inv, _minus(dy, _mul(y, spread(lane_sum(_mul(dy, y), 1),
+                                               y.shape))))
 
 
 def _normed(heads, eps):
@@ -879,8 +873,8 @@ def _gate(g_ref, rows_ref=None, *, slope: bool = False):
         return g_ref[...], None
     x = g_ref[...].astype(_F32)
     rows = rows_ref[...]
-    x = _add(x, _spread(_rows(rows, 1, 1), x.shape))
-    neg_a = _spread(jax.lax.neg(jax.lax.exp(_rows(rows, 0, 1))), x.shape)
+    x = _add(x, spread(_rows(rows, 1, 1), x.shape))
+    neg_a = spread(jax.lax.neg(jax.lax.exp(_rows(rows, 0, 1))), x.shape)
     soft = _add(jax.lax.max(x, _zeros_like(x)),
                 jax.lax.log1p(jax.lax.exp(jax.lax.neg(jax.lax.abs(x)))))
     return _mul(neg_a, soft), (
@@ -889,7 +883,7 @@ def _gate(g_ref, rows_ref=None, *, slope: bool = False):
 
 def _tiles(refs, h: int):
     """Head h's [C, 128] tile of each merged ref."""
-    return tuple(x[:, pl.ds(h * _LANES, _LANES)] for x in refs)
+    return tuple(x[:, pl.ds(h * LANES, LANES)] for x in refs)
 
 
 # Two heads' share of a program as a PURE function of values under
@@ -905,17 +899,17 @@ def _forward_of(heads, *, scale: float, r: int, eps):
     start state [d_v, d_k]) -> per head (o scaled in q's dtype, the state
     the chunk ends in). ``eps``: the l2 norm's, where q and k come as the
     convolutions left them; None where the caller normalised them."""
-    dt, d = heads[0][0].dtype, _LANES
+    dt, d = heads[0][0].dtype, LANES
     heads, _ = _normed(heads, eps)
     scored, solved = _heads_scores(heads, dt, r)
     out = []
     for (*_, st), (qf, kf, vf, cum, bcol, sq, _), t in zip(
             heads, scored, solved):
         f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
-        o = _add(f["q_state"], _dot(sq.astype(dt), f["wrote"], _AB))
+        o = _add(f["q_state"], dot(sq.astype(dt), f["wrote"], AB))
         out.append((_mul(o, jax.lax.full_like(o, scale)).astype(dt),
-                    _add(_mul(_spread(f["at_end"], (d, d)), st),
-                         _dot(f["wrote"], f["k_end"], _ATB))))
+                    _add(_mul(spread(f["at_end"], (d, d)), st),
+                         dot(f["wrote"], f["k_end"], ATB))))
     return out
 
 
@@ -937,14 +931,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
     start state and ``s_scr`` [heads x d_v, d_k] f32: the block's states,
     transposed, carried over the chunks."""
     *gate, beta_ref, o_ref, st_ref, s_scr = refs
-    d = _LANES
+    d = LANES
 
     @pl.when(pl.program_id(2) == 0)
     def _first_chunk():
         s_scr[...] = _full(s_scr.shape, 0)
 
     st_ref[...] = s_scr[...]
-    cum_all = _triangle_sums(_gate(*gate)[0], _AB)
+    cum_all = _triangle_sums(_gate(*gate)[0], AB)
     for hs in _pairs(q_ref.shape[1] // d):
         done = _forward_of(tuple(
             _tiles((q_ref, k_ref, v_ref), h)
@@ -963,7 +957,7 @@ def _specs(t: int, chunk: int, hpb: int, reverse: bool, key_heads: int = 0):
     head."""
     nc = t // chunk
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
-    w = hpb * _LANES
+    w = hpb * LANES
     specs = {
         "x": pl.BlockSpec((None, chunk, w), lambda b, k, c: (b, at(c), k)),
         "beta": pl.BlockSpec((None, None, None, hpb, chunk),
@@ -972,12 +966,12 @@ def _specs(t: int, chunk: int, hpb: int, reverse: bool, key_heads: int = 0):
         # batch row: one block over the whole sequential axis
         "rows": pl.BlockSpec((2, w), lambda b, k, c: (0, k)),
         "drows": pl.BlockSpec((None, 2, w), lambda b, k, c: (b, 0, k)),
-        "state": pl.BlockSpec((None, None, w, _LANES),
+        "state": pl.BlockSpec((None, None, w, LANES),
                               lambda b, k, c: (b, at(c), k, 0)),
     }
     if key_heads:
         specs.update({
-            "keys": pl.BlockSpec((None, chunk, key_heads * _LANES),
+            "keys": pl.BlockSpec((None, chunk, key_heads * LANES),
                                  lambda b, k, c: (b, at(c), k)),
             # A_log over 8 rows and dt_bias over 8 more, a block's heads
             # the first of each (``_head_gate``), and their gradients'
@@ -995,7 +989,7 @@ def _params():
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_BYTES)
+        vmem_limit_bytes=VMEM_BYTES)
 
 
 def _gate_specs(s, gate):
@@ -1017,7 +1011,7 @@ def _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps):
 
     b, t, hd = q.shape
     chunk = beta_t.shape[-1]
-    nc, h = t // chunk, hd // _LANES
+    nc, h = t // chunk, hd // LANES
     s = _specs(t, chunk, hpb, reverse=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, r=_SUB, eps=eps),
@@ -1025,15 +1019,15 @@ def _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps):
         in_specs=[s["x"]] * 3 + _gate_specs(s, gate) + [s["beta"]],
         out_specs=[s["x"], s["state"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, nc, hd, _LANES), _F32)],
-        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+                   jax.ShapeDtypeStruct((b, nc, hd, LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * LANES, LANES), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["fwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * t * hd * (4 * chunk + 3 * _LANES),
+            flops=2 * b * t * hd * (4 * chunk + 3 * LANES),
             bytes_accessed=q.size * 4 * q.dtype.itemsize + _gate_bytes(gate)
-            + 4 * b * nc * hd * _LANES,
+            + 4 * b * nc * hd * LANES,
             transcendentals=b * t * hd * (_SUB + 10 + 2 * (len(gate) - 1))),
     )(q, k, v, *gate, beta_t)
 
@@ -1051,7 +1045,7 @@ def _backward_of(heads, *, scale: float, r: int, eps):
     state's gradient). The chunk's matrices are made again first; with
     ``eps`` (``_forward_of``) dq and dk are the RAW q's and k's, through
     the norm."""
-    dt, d = heads[0][0].dtype, _LANES
+    dt, d = heads[0][0].dtype, LANES
     c = heads[0][0].shape[0]
     heads, norms = _normed(heads, eps)
     scored, solved = _heads_scores(
@@ -1068,33 +1062,33 @@ def _backward_of(heads, *, scale: float, r: int, eps):
         do = _mul(do, jax.lax.full_like(do, scale)).astype(dt)
         dsd = dst.astype(dt)
         # what each token wrote: through o and through the end state
-        dwrote = _add(_dot(sq.astype(dt), do, _ATB),
-                      _dot(f["k_end"], dsd, _ABT))               # [C, d_v]
+        dwrote = _add(dot(sq.astype(dt), do, ATB),
+                      dot(f["k_end"], dsd, ABT))               # [C, d_v]
         dwd = dwrote.astype(dt)
-        dsq = _dot(do, wrote, _ABT)
+        dsq = dot(do, wrote, ABT)
         dsq = jax.lax.select(_ge(row, col), dsq, _zeros_like(dsq))
-        dq_in = _dot(do, held, _AB)                              # [C, d_k]
-        dk_end = _dot(wrote, dsd, _AB)
-        dw = jax.lax.neg(_dot(dwd, held, _AB))
+        dq_in = dot(do, held, AB)                              # [C, d_k]
+        dk_end = dot(wrote, dsd, AB)
+        dw = jax.lax.neg(dot(dwd, held, AB))
         dwu = jax.lax.concatenate([dw, dwrote], 1).astype(dt)
-        d_t = _dot(dwu, f["bkv"], _ABT)                          # [C, C]
-        dbkv = _dot(t.astype(dt), dwu, _ATB)                     # [C, 2d]
+        d_t = dot(dwu, f["bkv"], ABT)                          # [C, C]
+        dbkv = dot(t.astype(dt), dwu, ATB)                     # [C, 2d]
         dbk, dbv = _cols(dbkv, 0, d), _cols(dbkv, d, d)
         # the solve's own backward, dA = -T^T dT T^T
-        da = jax.lax.neg(_exact_dot(_exact_dot(t, d_t, _ATB), t, _ABT))
+        da = jax.lax.neg(_exact_dot(_exact_dot(t, d_t, ATB), t, ABT))
         da = jax.lax.select(_gt(row, col), da, _zeros_like(da))
         dsk = _mul(da, _cols(bcol, 0, c))
         # the state the chunk starts from
-        decayed = _mul(dst, _spread(f["at_end"], (d, d)))
+        decayed = _mul(dst, spread(f["at_end"], (d, d)))
         dst_start = _minus(
-            _add(decayed, _dot(do, f["q_in"], _ATB)), _dot(dwd, w, _ATB))
-        d_last = _sum(_mul(decayed, st), 0)                      # [1, d]
+            _add(decayed, dot(do, f["q_in"], ATB)), dot(dwd, w, ATB))
+        d_last = lane_sum(_mul(decayed, st), 0)                      # [1, d]
         # beta: through A's rows, beta k exp(G) and beta v; a row of sums
         # over the lanes by a product with ones, the tokens along the lanes
         k_kept = _mul(kf, f["kept"])
         sums = jax.lax.concatenate(
             [_add(_mul(dbk, k_kept), _mul(dbv, vf)), _mul(da, sk)], 1)
-        dbeta = _rows(_exact_dot(ones, sums, _ABT), 0, 1)        # [1, C]
+        dbeta = _rows(_exact_dot(ones, sums, ABT), 0, 1)        # [1, C]
         # the scores
         dqs, dk_row, dk_col = _scores_bwd(qf, kf, cum, dsq, dsk, dt, r)
         ended = _mul(dk_end, _mul(kf, f["to_end"]))
@@ -1105,9 +1099,9 @@ def _backward_of(heads, *, scale: float, r: int, eps):
             _minus(_add(_mul(dbk, _mul(k_kept, bcol)),
                         _mul(dq_in, _mul(qf, f["kept"]))), ended),
             _add(_mul(qf, dqs), _mul(kf, _minus(dk_row, dk_col))))
-        at_last = _add(d_last, _sum(ended, 0))
+        at_last = _add(d_last, lane_sum(ended, 0))
         dc = _add(dc, jax.lax.select(
-            last_row, _spread(at_last, (c, d)), _zeros_like(dc)))
+            last_row, spread(at_last, (c, d)), _zeros_like(dc)))
         if inv is not None:
             dq, dk = _unit_bwd(dq, qf, inv[0]), _unit_bwd(dk, kf, inv[1])
         out.append((dq.astype(dt), dk.astype(dt),
@@ -1128,7 +1122,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
     gate = refs[:n]
     (beta_ref, st_ref, do_ref, dq_ref, dk_ref, dv_ref, dgate_ref,
      *drows_ref, dbeta_ref, ds_scr) = refs[n:]
-    d = _LANES
+    d = LANES
 
     @pl.when(pl.program_id(2) == 0)
     def _last_chunk():
@@ -1137,7 +1131,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
             ref[...] = _full(ref.shape, 0)
 
     g, slope = _gate(*gate, slope=True)
-    cum_all = _triangle_sums(g, _AB)
+    cum_all = _triangle_sums(g, AB)
     dcum = []
     for hs in _pairs(q_ref.shape[1] // d):
         done = _backward_of(tuple(
@@ -1152,7 +1146,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
             ds_scr[pl.ds(h * d, d), :] = dst
             dcum.append(dc)
     # dg from d(cumulative sum): the triangle's product, reversed
-    dg = _triangle_sums(jax.lax.concatenate(dcum, 1), _ATB)
+    dg = _triangle_sums(jax.lax.concatenate(dcum, 1), ATB)
     if slope is None:
         dgate_ref[...] = dg
         return
@@ -1161,7 +1155,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
     dstep = _mul(dg, slope)
     dgate_ref[...] = dstep.astype(dgate_ref.dtype)
     drows_ref[0][...] = _add(drows_ref[0][...], jax.lax.concatenate(
-        [_sum(_mul(dg, g), 0), _sum(dstep, 0)], 0))
+        [lane_sum(_mul(dg, g), 0), lane_sum(dstep, 0)], 0))
 
 
 def _kda_bwd(q, k, v, gate, beta_t, states, do, scale, hpb, eps):
@@ -1172,7 +1166,7 @@ def _kda_bwd(q, k, v, gate, beta_t, states, do, scale, hpb, eps):
 
     b, t, hd = q.shape
     chunk = beta_t.shape[-1]
-    nc, h = t // chunk, hd // _LANES
+    nc, h = t // chunk, hd // LANES
     s = _specs(t, chunk, hpb, reverse=True)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
     drows = [jax.ShapeDtypeStruct((b,) + x.shape, x.dtype) for x in gate[1:]]
@@ -1184,14 +1178,14 @@ def _kda_bwd(q, k, v, gate, beta_t, states, do, scale, hpb, eps):
         out_specs=[s["x"]] * 4 + [s["drows"]] * len(drows) + [s["beta"]],
         out_shape=[like(q), like(k), like(v), like(gate[0])] + drows
         + [like(beta_t)],
-        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * LANES, LANES), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["bwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * t * hd * (10 * chunk + 8 * _LANES),
+            flops=2 * b * t * hd * (10 * chunk + 8 * LANES),
             bytes_accessed=q.size * 7 * q.dtype.itemsize
-            + 2 * _gate_bytes(gate) + 4 * b * nc * hd * _LANES,
+            + 2 * _gate_bytes(gate) + 4 * b * nc * hd * LANES,
             transcendentals=b * t * hd * (2 * _SUB + 20
                                           + 3 * (len(gate) - 1))),
     )(q, k, v, *gate, beta_t, states, do)
@@ -1270,14 +1264,14 @@ def _head_tile(rows):
     shape = (_HEAD_ROWS, rows[0].shape[1])
     sub, out = _iota(shape, 0), _full(shape, 0)
     for h, row in enumerate(rows):
-        out = jax.lax.select(_is(sub, h), _spread(row, shape), out)
+        out = jax.lax.select(_is(sub, h), spread(row, shape), out)
     return out
 
 
 def _lower_ones(c: int):
     """The [C, C] float32 triangle of ones, row >= column: against a
-    head-major tile's tokens, ``_ABT`` is the inclusive cumulative sum over
-    the chunk and ``_AB`` the sums from each token to the chunk's end."""
+    head-major tile's tokens, ``ABT`` is the inclusive cumulative sum over
+    the chunk and ``AB`` the sums from each token to the chunk's end."""
     return _ge(_iota((c, c), 0), _iota((c, c), 1)).astype(_F32)
 
 
@@ -1296,7 +1290,7 @@ def _head_gate(a_ref, rows_ref):
                 jax.lax.log1p(jax.lax.exp(jax.lax.neg(jax.lax.abs(x)))))
     g = _mul(neg_a, soft)
     return (g, _mul(neg_a, jax.lax.logistic(x)),
-            _exact_dot(g, _lower_ones(c), _ABT))
+            _exact_dot(g, _lower_ones(c), ABT))
 
 
 def _columns(rows):
@@ -1306,9 +1300,9 @@ def _columns(rows):
     on = _eq(_iota((c, c), 0), _iota((c, c), 1))
     zero = _full((c, c), 0)
     diagonals = jax.lax.concatenate([jax.lax.concatenate(_three(
-        jax.lax.select(on, _spread(row, (c, c)), zero)), 1) for row in rows],
+        jax.lax.select(on, spread(row, (c, c)), zero)), 1) for row in rows],
         0)                                                    # [n C, 3 C]
-    out = _dot(diagonals, _full((3 * c, _LANES), 1, jnp.bfloat16), _AB)
+    out = dot(diagonals, _full((3 * c, LANES), 1, jnp.bfloat16), AB)
     return [_rows(out, i * c, c) for i in range(len(rows))]
 
 
@@ -1316,7 +1310,7 @@ def _lane_sums(x):
     """x [n, w] float32 -> the sums over its lanes as a ROW [1, n] (the
     tokens along the lanes): three exact passes against ones."""
     ones = _full((_HEAD_ROWS, x.shape[1]), 1, jnp.bfloat16)
-    a, b, e = (_dot(ones, piece, _ABT) for piece in _three(x))
+    a, b, e = (dot(ones, piece, ABT) for piece in _three(x))
     return _rows(_add(_add(a, b), e), 0, 1)
 
 
@@ -1336,7 +1330,7 @@ def _gdn_chunk(keys, values, dt, r: int, eps: float):
     is exp(0)), solves, and reads the state once for q and k both: with a
     scalar decay (q e^G) S_0 = e^G (q S_0) and what the tokens write is T
     (beta (v - e^G (k S_0))), no W of the keys' own."""
-    c, d = keys[0][0].shape[0], _LANES
+    c, d = keys[0][0].shape[0], LANES
     group = len(values) // len(keys)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     seen, earlier = _ge(row, col), _gt(row, col)
@@ -1347,14 +1341,14 @@ def _gdn_chunk(keys, values, dt, r: int, eps: float):
         qk = jax.lax.concatenate([qn, kn], 0).astype(dt)        # [2C, 128]
         kd = _rows(qk, c, c)
         keyed.append(dict(qn=qn, kn=kn, rq=rq, rk=rk, qk=qk, kd=kd,
-                          scores=_dot(qk, kd, _ABT)))
+                          scores=dot(qk, kd, ABT)))
     heads = []
     columns = _columns([row for _, grow, beta, *_ in values
                         for row in (beta, grow)])
     for i, (v, grow, beta, st, *_) in enumerate(values):
         key = keyed[i // group]
         bcol, gcol = columns[2 * i:2 * i + 2]
-        table = _decay(_minus(_cols(gcol, 0, c), _spread(grow, (c, c))))
+        table = _decay(_minus(_cols(gcol, 0, c), spread(grow, (c, c))))
         last = _rows(gcol, c - 1, 1)                            # [1, 128]
         heads.append(dict(
             key=key, vf=v.astype(_F32), st=st, bcol=bcol, table=table,
@@ -1363,7 +1357,7 @@ def _gdn_chunk(keys, values, dt, r: int, eps: float):
             sk=jax.lax.select(earlier, _mul(_rows(key["scores"], c, c),
                                             table), zero),
             kept=jax.lax.exp(gcol), at_end=jax.lax.exp(last),
-            to_end=jax.lax.exp(_minus(_spread(last, (c, d)), gcol))))
+            to_end=jax.lax.exp(_minus(spread(last, (c, d)), gcol))))
     solved = _solve_heads(
         [_mul(_cols(f["bcol"], 0, c), f["sk"]) for f in heads], r)
     for f, t in zip(heads, solved):
@@ -1372,12 +1366,12 @@ def _gdn_chunk(keys, values, dt, r: int, eps: float):
     _staged(
         heads,
         # q S_0 over k S_0 [2C, d_v]
-        from_state=lambda f: _dot(f["key"]["qk"], f["held"], _ABT),
+        from_state=lambda f: dot(f["key"]["qk"], f["held"], ABT),
         q_state=lambda f: _rows(f["from_state"], 0, c),
         k_state=lambda f: _rows(f["from_state"], c, c),
         inner=lambda f: _minus(f["vf"], _mul(f["kept"], f["k_state"])),
         rhs=lambda f: _mul(f["bcol"], f["inner"]).astype(dt),
-        wrote=lambda f: _dot(f["t"].astype(dt), f["rhs"], _AB).astype(dt))
+        wrote=lambda f: dot(f["t"].astype(dt), f["rhs"], AB).astype(dt))
     return keyed, heads
 
 
@@ -1395,14 +1389,14 @@ def _staged(heads, **stages):
 def _gdn_forward_of(keys, values, *, scale: float, r: int, eps: float):
     """A block's chunk (``_gdn_chunk``'s arguments) -> a value head (o
     scaled in q's dtype, the state the chunk ends in)."""
-    dt, d = keys[0][0].dtype, _LANES
+    dt, d = keys[0][0].dtype, LANES
     heads = _gdn_chunk(keys, values, dt, r, eps)[1]
     _staged(
         heads,
         o=lambda f: _add(_mul(f["kept"], f["q_state"]),
-                         _dot(f["sq"].astype(dt), f["wrote"], _AB)),
-        ended=lambda f: _add(_mul(_spread(f["at_end"], (d, d)), f["st"]),
-                             _dot(f["wrote"], f["k_end"], _ATB)))
+                         dot(f["sq"].astype(dt), f["wrote"], AB)),
+        ended=lambda f: _add(_mul(spread(f["at_end"], (d, d)), f["st"]),
+                             dot(f["wrote"], f["k_end"], ATB)))
     return [(_mul(f["o"], jax.lax.full_like(f["o"], scale)).astype(dt),
              f["ended"]) for f in heads]
 
@@ -1413,7 +1407,7 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, o_ref,
     block's KEY heads, v, ``a`` and beta (head-major) its value heads;
     ``s_scr`` [value heads x d_v, d_k] f32 carries their states,
     transposed, over the chunks."""
-    d = _LANES
+    d = LANES
     hpb = v_ref.shape[1] // d
 
     @pl.when(pl.program_id(2) == 0)
@@ -1442,7 +1436,7 @@ def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
 
     b, t, hd = v.shape
     hpb, chunk = beta_t.shape[-2:]
-    nc, h = t // chunk, hd // _LANES
+    nc, h = t // chunk, hd // LANES
     keys = hpb * q.shape[-1] // hd
     s = _specs(t, chunk, hpb, reverse=False, key_heads=keys)
     return pl.pallas_call(
@@ -1452,17 +1446,17 @@ def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
                                     s["beta"]],
         out_specs=[s["x"], s["state"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, nc, hd, _LANES), _F32)],
-        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+                   jax.ShapeDtypeStruct((b, nc, hd, LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * LANES, LANES), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["gdn_fwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * t * (hd * (8 * chunk + 4 * _LANES)
+            flops=2 * b * t * (hd * (8 * chunk + 4 * LANES)
                                + 2 * q.shape[-1] * chunk),
             bytes_accessed=(2 * q.size + 2 * v.size) * q.dtype.itemsize
-            + 8 * a_t.size + 4 * b * nc * hd * _LANES,
-            transcendentals=b * t * h * (chunk + 3 * _LANES)),
+            + 8 * a_t.size + 4 * b * nc * hd * LANES,
+            transcendentals=b * t * h * (chunk + 3 * LANES)),
     )(q, k, v, a_t, rows, beta_t)
 
 
@@ -1486,7 +1480,7 @@ def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
     gains the sums of P's row i and loses those of its column i; e^G's and
     e^(G_C - G)'s shares are sums over a row's lanes. Every sum over the
     lanes is one product with ones, the tokens along its lanes."""
-    dt, d = keys[0][0].dtype, _LANES
+    dt, d = keys[0][0].dtype, LANES
     c = keys[0][0].shape[0]
     keyed, heads = _gdn_chunk(keys, values, dt, r, eps)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
@@ -1498,21 +1492,21 @@ def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
         do = _mul(do, jax.lax.full_like(do, scale))
         f.update(do=do, dod=do.astype(dt), dst=dst, dsd=dst.astype(dt),
                  tt=f["t"].astype(dt), bc=_cols(f["bcol"], 0, c),
-                 decayed=_mul(dst, _spread(f["at_end"], (d, d))))
+                 decayed=_mul(dst, spread(f["at_end"], (d, d))))
     _staged(
         heads,
         # what each token wrote: through o and through the end state
-        dwd=lambda f: _add(_dot(f["sq"].astype(dt), f["dod"], _ATB),
-                           _dot(f["k_end"], f["dsd"], _ABT)).astype(dt),
+        dwd=lambda f: _add(dot(f["sq"].astype(dt), f["dod"], ATB),
+                           dot(f["k_end"], f["dsd"], ABT)).astype(dt),
         dsq=lambda f: jax.lax.select(
-            seen, _dot(f["dod"], f["wrote"], _ABT), zero),
-        dk_end=lambda f: _dot(f["wrote"], f["dsd"], _AB),        # [C, d_k]
-        d_t=lambda f: _dot(f["dwd"], f["rhs"], _ABT),            # [C, C]
-        drhs=lambda f: _dot(f["tt"], f["dwd"], _ATB),            # [C, d_v]
+            seen, dot(f["dod"], f["wrote"], ABT), zero),
+        dk_end=lambda f: dot(f["wrote"], f["dsd"], AB),        # [C, d_k]
+        d_t=lambda f: dot(f["dwd"], f["rhs"], ABT),            # [C, C]
+        drhs=lambda f: dot(f["tt"], f["dwd"], ATB),            # [C, d_v]
         # the solve's own backward, dA = -T^T dT T^T
-        half=lambda f: _exact_dot(f["t"], f["d_t"], _ATB),
+        half=lambda f: _exact_dot(f["t"], f["d_t"], ATB),
         da=lambda f: jax.lax.select(
-            earlier, jax.lax.neg(_exact_dot(f["half"], f["t"], _ABT)), zero),
+            earlier, jax.lax.neg(_exact_dot(f["half"], f["t"], ABT)), zero),
         dsk=lambda f: _mul(f["da"], f["bc"]),
         dinner=lambda f: _mul(f["drhs"], f["bcol"]),             # dv
         # the state: q S_0 and k S_0 were one product, and so are these
@@ -1520,8 +1514,8 @@ def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
             [_mul(f["do"], f["kept"]),
              jax.lax.neg(_mul(f["dinner"], f["kept"]))], 0).astype(dt),
         dst_start=lambda f: _add(f["decayed"],
-                                 _dot(f["dfs"], f["key"]["qk"], _ATB)),
-        dstate=lambda f: _dot(f["dfs"], f["held"], _AB),         # [2C, d_k]
+                                 dot(f["dfs"], f["key"]["qk"], ATB)),
+        dstate=lambda f: dot(f["dfs"], f["held"], AB),         # [2C, d_k]
         ended=lambda f: _mul(f["dk_end"], _mul(f["key"]["kn"], f["to_end"])),
         # the sums over a row's lanes, the tokens along the lanes: the
         # gates' (e^G's share less e^(G_C - G)'s, and P's rows) and beta's
@@ -1534,11 +1528,13 @@ def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
             jax.lax.concatenate([_mul(f["drhs"], f["inner"]),
                                  _mul(f["da"], f["sk"])], 1)], 0)),  # [1, 2C]
         # G_C's own: the end state's decay, and every e^(G_C - G_i)
-        end=lambda f: _add(_sum(_sum(_mul(f["decayed"], f["st"]), 0), 1),
-                           _sum(_sum(f["ended"], 0), 1)),        # [1, 1]
+        end=lambda f: _add(
+            lane_sum(lane_sum(_mul(f["decayed"], f["st"]), 0), 1),
+            lane_sum(lane_sum(f["ended"], 0), 1)),                   # [1, 1]
         dcum=lambda f: _add(
-            _minus(_cols(f["sums"], 0, c), _sum(f["p"], 0)), jax.lax.select(
-                at_last, _spread(f["end"], (1, c)), _full((1, c), 0))))
+            _minus(_cols(f["sums"], 0, c), lane_sum(f["p"], 0)),
+            jax.lax.select(at_last, spread(f["end"], (1, c)),
+                           _full((1, c), 0))))
     out = [(f["dinner"].astype(dt), f["dcum"], _cols(f["sums"], c, c),
             f["dst_start"]) for f in heads]
     dkeys = []
@@ -1548,9 +1544,9 @@ def _gdn_backward_of(keys, values, *, scale: float, r: int, eps: float):
             [_mul(f["dsq"], f["table"]), _mul(f["dsk"], f["table"])], 0)
             for f in mine]).astype(dt)                           # [2C, C]
         dqk = _summed([f["dstate"] for f in mine],
-                      _dot(dscores, key["kd"], _AB))             # [2C, d_k]
+                      dot(dscores, key["kd"], AB))             # [2C, d_k]
         dk = _summed([_mul(f["dk_end"], f["to_end"]) for f in mine], _add(
-            _rows(dqk, c, c), _dot(dscores, key["qk"], _ATB)))
+            _rows(dqk, c, c), dot(dscores, key["qk"], ATB)))
         dkeys.append((
             _unit_bwd(_rows(dqk, 0, c), key["qn"], key["rq"]).astype(dt),
             _unit_bwd(dk, key["kn"], key["rk"]).astype(dt)))
@@ -1566,7 +1562,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, st_ref,
     (rows 8.., sum da) a token of the chunk over the chunks walked so far
     (the block stays in VMEM over the sequential axis); ``ds_scr`` the
     gradient of the states the chunk ENDS in, transposed."""
-    d = _LANES
+    d = LANES
     hpb = v_ref.shape[1] // d
 
     @pl.when(pl.program_id(2) == 0)
@@ -1591,7 +1587,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, st_ref,
     # dg from d(cumulative sum): the sums from each token to the chunk's
     # end; then the gate's chain rule, all on the one [8, C] tile
     dg = _exact_dot(_head_tile([dc for _, dc, _, _ in done]),
-                    _lower_ones(a_ref.shape[1]), _AB)
+                    _lower_ones(a_ref.shape[1]), AB)
     da = _mul(dg, slope)
     for h in range(hpb):
         da_ref[pl.ds(h, 1), :] = _rows(da, h, 1)
@@ -1606,7 +1602,7 @@ def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
 
     b, t, hd = v.shape
     hpb, chunk = beta_t.shape[-2:]
-    nc, h = t // chunk, hd // _LANES
+    nc, h = t // chunk, hd // LANES
     keys = hpb * q.shape[-1] // hd
     s = _specs(t, chunk, hpb, reverse=True, key_heads=keys)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
@@ -1620,16 +1616,16 @@ def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
         out_shape=[like(q), like(k), like(v), like(a_t),
                    jax.ShapeDtypeStruct((b,) + rows.shape, _F32),
                    like(beta_t)],
-        scratch_shapes=[pltpu.VMEM((hpb * _LANES, _LANES), _F32)],
+        scratch_shapes=[pltpu.VMEM((hpb * LANES, LANES), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["gdn_bwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * t * (hd * (16 * chunk + 10 * _LANES)
+            flops=2 * b * t * (hd * (16 * chunk + 10 * LANES)
                                + 6 * q.shape[-1] * chunk),
             bytes_accessed=(4 * q.size + 3 * v.size) * q.dtype.itemsize
-            + 16 * a_t.size + 4 * b * nc * hd * _LANES,
-            transcendentals=b * t * h * (chunk + 3 * _LANES)),
+            + 16 * a_t.size + 4 * b * nc * hd * LANES,
+            transcendentals=b * t * h * (chunk + 3 * LANES)),
     )(q, k, v, a_t, rows, beta_t, states, do)
 
 
@@ -1683,7 +1679,7 @@ def _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta, *, key_heads: int,
 def _route(d_k: int, d_v: int, chunk: int) -> str:
     """The route a call takes, by what it shows: the kernel pair where a
     head is one 128-lane tile of keys and of values and the chunk is 64."""
-    return "kernel" if d_k == d_v == _LANES and chunk == 64 else "chunked_jnp"
+    return "kernel" if d_k == d_v == LANES and chunk == 64 else "chunked_jnp"
 
 
 def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):

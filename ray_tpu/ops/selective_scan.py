@@ -53,18 +53,13 @@ from __future__ import annotations
 
 import collections
 import functools
-import importlib
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..perf.recorder import record as _record
-
-# The module, not the function of its name that the package exports: the
-# kernels here run interpreted where the flash kernels do, by the one
-# switch (``_use_interpret``) a described-chip compile steers.
-_flash = importlib.import_module(__package__ + ".flash_attention")
+from . import kernel_common
+from .kernel_common import LANES, VMEM_BYTES, record_path
 
 # The names of the two kernels, as a device trace and the compiled HLO
 # show them (``name=`` on ``pl.pallas_call``). Part of the measurement:
@@ -79,17 +74,15 @@ KERNEL_NAMES = {
 # "reference"); the same choice is the event ``rtpu.ops.selscan.path``.
 PATH_COUNTS: collections.Counter = collections.Counter()
 
-_LANES = 128
 _STEPS = 8                    # tokens a loop iteration: one [8, W] tile
 _MAX_BLOCK = 512              # channels a program works
-_VMEM_BYTES = 64 * 1024 * 1024
 _F32 = jnp.float32
 
 
 def _block_width(channels: int) -> int:
     """Channels a program works: the widest multiple of 128 up to
     ``_MAX_BLOCK`` that divides them, or 0 where there is none."""
-    for w in range(min(channels, _MAX_BLOCK) // _LANES * _LANES, 0, -_LANES):
+    for w in range(min(channels, _MAX_BLOCK) // LANES * LANES, 0, -LANES):
         if channels % w == 0:
             return w
     return 0
@@ -98,15 +91,15 @@ def _block_width(channels: int) -> int:
 def _wide(tile, width: int):
     """One token's [N, 128] tile of B or C for every lane tile of a block
     [N, width]."""
-    reps = width // _LANES
+    reps = width // LANES
     return tile if reps == 1 else jnp.concatenate([tile] * reps, axis=1)
 
 
 def _fold(v):
     """[N, W] summed over its lane tiles -> [N, 128]."""
-    out = v[:, :_LANES]
-    for i in range(1, v.shape[1] // _LANES):
-        out = out + v[:, i * _LANES:(i + 1) * _LANES]
+    out = v[:, :LANES]
+    for i in range(1, v.shape[1] // LANES):
+        out = out + v[:, i * LANES:(i + 1) * LANES]
     return out
 
 
@@ -170,7 +163,7 @@ def _specs(t, n, chunk, w, reverse: bool):
     return {
         "x": pl.BlockSpec((None, chunk, w), lambda b, c, k: (b, at(c), k)),
         "a": pl.BlockSpec((n, w), lambda b, c, k: (0, k)),
-        "bc": pl.BlockSpec((None, chunk, n, _LANES),
+        "bc": pl.BlockSpec((None, chunk, n, LANES),
                            lambda b, c, k: (b, at(c), 0, 0)),
         "state": pl.BlockSpec((None, None, n, w),
                               lambda b, c, k: (b, at(c), 0, k)),
@@ -182,7 +175,7 @@ def _params():
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_BYTES)
+        vmem_limit_bytes=VMEM_BYTES)
 
 
 def _scan_fwd(x, dt, a_t, bw, cw, chunk, w):
@@ -206,7 +199,7 @@ def _scan_fwd(x, dt, a_t, bw, cw, chunk, w):
                         pltpu.VMEM((chunk, w), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["fwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=7 * b * t * ch * n,
             bytes_accessed=x.size * (2 * x.dtype.itemsize + 4)
@@ -319,7 +312,7 @@ def _scan_bwd(x, dy, dt, a_t, bw, cw, states, chunk, w):
                         pltpu.VMEM((chunk, w), _F32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["bwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=18 * b * t * ch * n,
             bytes_accessed=x.size * (3 * x.dtype.itemsize + 8)
@@ -394,18 +387,20 @@ def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     w = _block_width(ch) if n % 8 == 0 else 0
     route = "kernel" if w else "reference"
     chunk = -(-min(chunk, t) // _STEPS) * _STEPS     # whole loop iterations
-    PATH_COUNTS[route] += 1
-    _record("rtpu.ops.selscan.path", route,
-            {"route": route, "chunk": chunk, "channels": ch, "state": n,
-             "block_channels": w, "chunks": -(-t // chunk),
-             "steps_per_iteration": _STEPS})
+    record_path("rtpu.ops.selscan.path", PATH_COUNTS, route,
+                {"chunk": chunk, "channels": ch, "state": n,
+                 "block_channels": w, "chunks": -(-t // chunk),
+                 "steps_per_iteration": _STEPS})
     if not w:
         return selective_scan_reference(x, dt, A, B, C, D)
     dt = dt.astype(_F32)
+    # not ``kernel_common.pad_tokens``: it pads nothing where T is whole
+    # chunks, these pads are traced all the same, and phi4flash's compiled
+    # step numbers its instructions after them (ROADMAP C26(d))
     pad = -t % chunk
     rows = lambda v: jnp.pad(v, ((0, 0), (0, pad), (0, 0)))  # noqa: E731
     spread = lambda v: jnp.broadcast_to(                      # noqa: E731
-        rows(v.astype(_F32))[..., None], (b, t + pad, n, _LANES))
+        rows(v.astype(_F32))[..., None], (b, t + pad, n, LANES))
     y = _scan_kernels(rows(x), rows(dt), A.astype(_F32).T, spread(B),
                       spread(C), chunk, w)[:, :t]
     skip = x.astype(_F32) * D.astype(_F32)

@@ -78,8 +78,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..perf.recorder import record as _record
-from .flash_attention import (_AB, _ABT, _ATB, _LANES, _NEG_INF, _dot,
-                              _fit_block, flash_attention)
+from . import kernel_common
+from .flash_attention import flash_attention
+from .kernel_common import (AB, ABT, ATB, LANES, NEG_INF, VMEM_BYTES, dot,
+                            fit_block)
 
 # Names of the two Pallas calls as a device trace shows them; part of the
 # measurement (tests/test_tracing_names.py).
@@ -94,14 +96,9 @@ KERNEL_NAMES = {
 # (S <= topk), "masked_reference" (no kernel).
 CALL_COUNTS: collections.Counter = collections.Counter()
 
-_VMEM_BYTES = 64 * 1024 * 1024
 # Groups of query blocks whose keys end with the group: more groups trace
 # and compile more copies of the block's program for less of the square.
 _KEY_GROUPS = 4
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def selected_pairs(seq: int, topk: int) -> int:
@@ -232,8 +229,8 @@ def _selected(mask_ref):
 
 
 def _masked_scores(q, k, sel, sm_scale):
-    return jnp.where(sel, _dot(q * jnp.asarray(sm_scale, q.dtype), k, _ABT),
-                     _NEG_INF)
+    return jnp.where(sel, dot(q * jnp.asarray(sm_scale, q.dtype), k, ABT),
+                     NEG_INF)
 
 
 def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -247,7 +244,7 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -264,7 +261,7 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v, _AB)
+            acc_scr[h] = acc_scr[h] * alpha + dot(p.astype(v.dtype), v, AB)
             m_scr[h] = m_new
             return carry
 
@@ -321,15 +318,15 @@ def _bwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             q = q_ref[h]
             do = do_ref[h]
             s = jnp.where(
-                sel, _dot(k, q * jnp.asarray(sm_scale, q.dtype), _ABT),
-                _NEG_INF)
+                sel, dot(k, q * jnp.asarray(sm_scale, q.dtype), ABT),
+                NEG_INF)
             p = jnp.exp(s - lse_ref[h])
-            ds = (p * (_dot(v, do, _ABT) - delta_scr[h])
+            ds = (p * (dot(v, do, ABT) - delta_scr[h])
                   * sm_scale).astype(k.dtype)
             p = p.astype(do.dtype)
-            dq_scr[h] += _dot(ds, k, _ATB)
-            dv_scr[kb] += _dot(p, do, _AB)
-            dk_scr[kb] += _dot(ds, q, _AB)
+            dq_scr[h] += dot(ds, k, ATB)
+            dv_scr[kb] += dot(p, do, AB)
+            dk_scr[kb] += dot(ds, q, AB)
             return carry
 
         jax.lax.fori_loop(0, group, head, 0)
@@ -345,7 +342,7 @@ def _bwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _blocks(seq: int, block_q: int, block_k: int):
-    return _fit_block(block_q, seq), _fit_block(block_k, seq)
+    return fit_block(block_q, seq), fit_block(block_k, seq)
 
 
 def _specs(group: int, kv: int, d: int, block_q: int, block_k: int):
@@ -370,7 +367,7 @@ def _params(blocks: str):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", blocks, "arbitrary"),
-        vmem_limit_bytes=_VMEM_BYTES)
+        vmem_limit_bytes=VMEM_BYTES)
 
 
 def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
@@ -395,7 +392,7 @@ def _masked_fwd(q, k, v, mask, sm_scale, block_q, block_k):
                         pltpu.VMEM((group, block_q, d), jnp.float32)],
         compiler_params=_params("parallel"),
         name=KERNEL_NAMES["fwd"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * s * s * d // 2,
             bytes_accessed=(2 * q.size + k.size + v.size) * q.dtype.itemsize
@@ -408,7 +405,7 @@ def _bwd_vmem(seq: int, block_q: int, block_k: int, group: int, d: int,
               itemsize: int) -> int:
     """Bytes of VMEM the backward kernel asks for: what it keeps of the
     whole sequence beside a block pair's tiles (``sparse_attention``
-    refuses a call over ``_VMEM_BYTES``). At S 16 384 in blocks of 1024 x
+    refuses a call over ``VMEM_BYTES``). At S 16 384 in blocks of 1024 x
     1024, 8 query heads of 128 to a key/value head, bf16: 16.8 MB of dk and
     dv + 16.8 MB of score tiles + 4.2 MB of dq + 21 MB of operands: 58.7
     of 67.1; S 24 576 is the longest that fits in these blocks, S 49 152 in
@@ -455,7 +452,7 @@ def _masked_bwd(q, k, v, mask, o, lse, g, sm_scale, block_q, block_k):
                         pltpu.VMEM((num_kb, block_k, d), jnp.float32)],
         compiler_params=_params("arbitrary"),
         name=KERNEL_NAMES["bwd"],
-        interpret=_use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=10 * b * h * s * s * d // 2,
             bytes_accessed=(4 * q.size + 2 * k.size + 2 * v.size)
@@ -520,19 +517,19 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"{h} query heads over {kv} key/value heads")
     if sm_scale is None:
         sm_scale = d ** -0.5
-    kernels = s % _LANES == 0 and d % _LANES == 0
+    kernels = s % LANES == 0 and d % LANES == 0
     route = "causal_flash" if s <= topk else \
         "masked_flash" if kernels else "masked_reference"
     fused = route == "masked_flash"
     if fused:
         need = _bwd_vmem(s, *_blocks(s, block_q, block_k), h // kv, d,
                          q.dtype.itemsize)
-        if need > _VMEM_BYTES:
+        if need > VMEM_BYTES:
             # the plain form would make S x S floats a head: no route to
             # fall to
             raise ValueError(
                 f"sparse_attention at S={s}: the backward keeps {need} bytes "
-                f"in VMEM, the limit is {_VMEM_BYTES}; smaller blocks than "
+                f"in VMEM, the limit is {VMEM_BYTES}; smaller blocks than "
                 f"{block_q} x {block_k} keep less")
     CALL_COUNTS[route] += 1
     _record("rtpu.ops.sparse_attention", "selected", {

@@ -79,19 +79,14 @@ from __future__ import annotations
 
 import collections
 import functools
-import importlib
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..perf.recorder import record as _record
-from .flash_attention import _AB, _ABT, _ATB, _LANES, _dot
-
-# The module, not the function of its name that the package exports: the
-# kernels here run interpreted where the flash kernels do, by the one
-# switch (``_use_interpret``) a described-chip compile steers.
-_flash = importlib.import_module(__package__ + ".flash_attention")
+from . import kernel_common
+from .kernel_common import (AB, ABT, ATB, LANES, VMEM_BYTES, dot, lane_sum,
+                            pad_tokens, record_path, spread)
 
 # The names of the two kernels, as a device trace and the compiled HLO
 # show them (``name=`` on ``pl.pallas_call``). Part of the measurement:
@@ -107,16 +102,15 @@ KERNEL_NAMES = {
 PATH_COUNTS: collections.Counter = collections.Counter()
 
 _MAX_HEADS_PER_BLOCK = 16     # 8 tiles of two heads unrolled in a program
-_VMEM_BYTES = 64 * 1024 * 1024
 
 
 def _heads_per_block(heads: int, p: int) -> int:
     """Heads a program works, or 0 where the merged layout cannot be cut:
     whole 128-lane tiles of [.., H*P], and rows of the [H, T] arrays of dt
     that tile the sublanes (a multiple of 8, or all of them)."""
-    if p not in (64, _LANES) or (heads * p) % _LANES:
+    if p not in (64, LANES) or (heads * p) % LANES:
         return 0
-    per_tile = _LANES // p
+    per_tile = LANES // p
     for hpb in range(min(heads, _MAX_HEADS_PER_BLOCK), 0, -1):
         if heads % hpb == 0 and hpb % per_tile == 0 \
                 and (hpb % 8 == 0 or hpb == heads):
@@ -133,7 +127,7 @@ _F32 = jnp.float32
 
 def _group_scores(c, b):
     """C B^T of one chunk [Q, Q] in f32, zero above the diagonal."""
-    g = _dot(c, b, _ABT)
+    g = dot(c, b, ABT)
     rows = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
     return jnp.where(rows >= cols, g, 0.0)
@@ -145,20 +139,9 @@ def _group_scores(c, b):
 _mul, _add, _sub = jax.lax.mul, jax.lax.add, jax.lax.sub
 
 
-def _spread(v, shape):
-    """[rows, 1] along the lanes, [1, lanes] down the rows, or [1, 1] over
-    both, of ``shape``."""
-    return jax.lax.broadcast_in_dim(v, shape, (0, 1))
-
-
 def _tile(v, i: int):
     """Lanes [128 i, 128 (i + 1)) of v."""
-    return jax.lax.slice(v, (0, i * _LANES), (v.shape[0], (i + 1) * _LANES))
-
-
-def _sum(v, axis: int):
-    """Sum over one axis of a 2-d v, the axis kept."""
-    return jax.lax.expand_dims(jax.lax.reduce_sum(v, (axis,)), (axis,))
+    return jax.lax.slice(v, (0, i * LANES), (v.shape[0], (i + 1) * LANES))
 
 
 def _row_is(sub, k: int):
@@ -182,8 +165,8 @@ def _decay(cc, cr):
     holding it) and along the lanes [1, Q]; the exponent is <= 0 wherever
     t >= s, and the rest is thrown away by the zeros of ``_group_scores``."""
     q = cr.shape[1]
-    d = _sub(jax.lax.concatenate([cc] * (q // _LANES), 1),
-             _spread(cr, (q, q)))
+    d = _sub(jax.lax.concatenate([cc] * (q // LANES), 1),
+             spread(cr, (q, q)))
     return jax.lax.exp(jax.lax.min(d, jax.lax.full_like(d, 0)))
 
 
@@ -195,8 +178,8 @@ def _down(rows, heads: int):
     pass) and the [128, Q] float32 tile turned by the XLU, aligned."""
     hpb, q = rows.shape
     return jax.lax.concatenate([jax.lax.transpose(jax.lax.concatenate(
-        [_spread(jax.lax.slice(rows, (k, 0), (k + 1, q)),
-                 (_LANES // heads, q)) for k in range(k0, k0 + heads)], 0),
+        [spread(jax.lax.slice(rows, (k, 0), (k + 1, q)),
+                (LANES // heads, q)) for k in range(k0, k0 + heads)], 0),
         (1, 0)) for k0 in range(0, hpb, heads)], 1)
 
 
@@ -213,7 +196,7 @@ def _last_rows(last, k0: int, per_tile: int, p: int, n: int):
     """exp(c_Q) of the block's heads [hpb, 1] -> [128, n], each head's P
     rows of tile k0's state holding its value."""
     return jax.lax.concatenate(
-        [_spread(jax.lax.slice(last, (k, 0), (k + 1, 1)), (p, n))
+        [spread(jax.lax.slice(last, (k, 0), (k + 1, 1)), (p, n))
          for k in range(k0, k0 + per_tile)], 0)
 
 
@@ -228,7 +211,7 @@ def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
     [head blocks, W, N] f32 is every head's state, carried over the
     chunks; ``g_scr`` the chunk's C B^T."""
     ci, hb = pl.program_id(1), pl.program_id(2)
-    per_tile = _LANES // p
+    per_tile = LANES // p
     dtype = x_ref.dtype
     hpb, q = cum_ref.shape
     n = b_ref.shape[1]
@@ -251,9 +234,9 @@ def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
         jnp.exp(cum), jnp.exp(cq - cum) * dt, dt))
     last = jnp.exp(cq)
     first = None if per_tile == 1 else \
-        jax.lax.broadcasted_iota(jnp.int32, (q, _LANES), 1) < p
-    for i in range(x_ref.shape[1] // _LANES):
-        lanes = pl.ds(i * _LANES, _LANES)
+        jax.lax.broadcasted_iota(jnp.int32, (q, LANES), 1) < p
+    for i in range(x_ref.shape[1] // LANES):
+        lanes = pl.ds(i * LANES, LANES)
         xf = x_ref[:, lanes].astype(_F32)
         h0 = h_scr[hb, lanes, :]
         xd = _mul(xf, _tile(step, i)).astype(dtype)
@@ -262,14 +245,14 @@ def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref,
             k = i * per_tile + j
             decay = _decay(_tile(cc, k), jax.lax.slice(cum, (k, 0),
                                                        (k + 1, q)))
-            part = _dot(_mul(g, decay).astype(dtype), _only(xd, j, p, first),
-                        _AB)
+            part = dot(_mul(g, decay).astype(dtype), _only(xd, j, p, first),
+                       AB)
             y = part if y is None else _add(y, part)
-        y = _add(y, _mul(_tile(into, i), _dot(cm, h0.astype(dtype), _ABT)))
+        y = _add(y, _mul(_tile(into, i), dot(cm, h0.astype(dtype), ABT)))
         xw = _mul(xf, _tile(carry, i)).astype(dtype)
         h_scr[hb, lanes, :] = _add(
             _mul(_last_rows(last, i * per_tile, per_tile, p, n), h0),
-            _dot(xw, bm, _ATB))
+            dot(xw, bm, ATB))
         y_ref[:, lanes] = y.astype(y_ref.dtype)
 
 
@@ -294,7 +277,7 @@ def _params():
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_BYTES)
+        vmem_limit_bytes=VMEM_BYTES)
 
 
 def _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
@@ -317,7 +300,7 @@ def _ssd_fwd(x, dt_t, cum_t, bm, cm, p, chunk, hpb):
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["fwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * t * (h * p * (chunk + 2 * n) + n * chunk),
             bytes_accessed=2 * x.size * x.dtype.itemsize + 4 * b * nc * hp * n,
@@ -339,7 +322,7 @@ def _head_rows(vals, k0: int, hpb: int, p: int):
     The tile is turned once by the XLU; a head's lanes are then sublanes,
     their sum is plain adds of registers, and it comes out with the tokens
     along the lanes, which is how d(dt) and d(cumulative sum) leave."""
-    per_tile = _LANES // p
+    per_tile = LANES // p
     q = vals[0][0].shape[0]
     sub = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 0)
     out = jnp.zeros((hpb, q), _F32)
@@ -347,9 +330,10 @@ def _head_rows(vals, k0: int, hpb: int, p: int):
         vt = jax.lax.transpose(v, (1, 0))
         for j in range(per_tile):
             jj = per_tile - 1 - j if swapped else j
-            row = _sum(jax.lax.slice(vt, (jj * p, 0), ((jj + 1) * p, q)), 0)
+            row = lane_sum(
+                jax.lax.slice(vt, (jj * p, 0), ((jj + 1) * p, q)), 0)
             out = _add(out, jax.lax.select(
-                _row_is(sub, k0 + j), _spread(row, (hpb, q)),
+                _row_is(sub, k0 + j), spread(row, (hpb, q)),
                 jax.lax.full_like(out, 0)))
     return out
 
@@ -364,7 +348,7 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
     head blocks. d(dt) here is through dt's own uses only; what reaches dt
     and A through the cumulative sum leaves as d(cumulative sum)."""
     ci, hb = pl.program_id(1), pl.program_id(2)
-    per_tile = _LANES // p
+    per_tile = LANES // p
     dtype = x_ref.dtype
     hpb, q = cum_ref.shape
     n = b_ref.shape[1]
@@ -391,13 +375,13 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
     sub = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 0)
     end = jax.lax.broadcasted_iota(jnp.int32, (hpb, q), 1) == q - 1
     first = None if per_tile == 1 else \
-        jax.lax.broadcasted_iota(jnp.int32, (q, _LANES), 1) < p
+        jax.lax.broadcasted_iota(jnp.int32, (q, LANES), 1) < p
     zeros = jnp.zeros((hpb, q), _F32)
     # d(cumulative sum) and d(dt) of the block, the tokens along the lanes
     dcum = ddt = zeros
     dg = None
-    for i in range(x_ref.shape[1] // _LANES):
-        lanes = pl.ds(i * _LANES, _LANES)
+    for i in range(x_ref.shape[1] // LANES):
+        lanes = pl.ds(i * LANES, LANES)
         dyt = dy_ref[:, lanes]
         xf, dyf = x_ref[:, lanes].astype(_F32), dyt.astype(_F32)
         h0, dh = st_ref[lanes, :], dh_scr[hb, lanes, :]
@@ -410,33 +394,33 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
                                                        (k + 1, q)))
             dyj = _only(dyt, j, p, first)
             # d(C B^T) of this head, unmasked
-            u = _mul(_dot(dyj, _only(xd, j, p, first), _ABT), decay)
+            u = _mul(dot(dyj, _only(xd, j, p, first), ABT), decay)
             dg = u if dg is None else _add(dg, u)
             w = _mul(u, g)                               # d(exponent) [t, s]
             # what row t gains is summed over the lanes below, with the
             # tile's other sums; what column s loses is a row already
             gain = _tile(w, 0)
-            for m in range(1, q // _LANES):
+            for m in range(1, q // LANES):
                 gain = _add(gain, _tile(w, m))
             across.append(gain)
             dcum = _sub(dcum, jax.lax.select(
-                _row_is(sub, k), _spread(_sum(w, 0), (hpb, q)), zeros))
-            part = _dot(_mul(g, decay).astype(dtype), dyj, _ATB)
+                _row_is(sub, k), spread(lane_sum(w, 0), (hpb, q)), zeros))
+            part = dot(_mul(g, decay).astype(dtype), dyj, ATB)
             dxd = part if dxd is None else _add(dxd, part)
         eq = _last_rows(last, i * per_tile, per_tile, p, n)
         h0m, dhm = h0.astype(dtype), dh.astype(dtype)
         e = _mul(dyf, ec)
         em = e.astype(dtype)
         xw = _mul(_mul(xf, sw), dcl)
-        dc_ref[...] += _dot(em, h0m, _AB)
-        db_ref[...] += _dot(xw.astype(dtype), dhm, _AB)
-        dxw = _dot(bm, dhm, _ABT)
-        dh_scr[hb, lanes, :] = _add(_dot(em, cm, _ATB), _mul(eq, dh))
+        dc_ref[...] += dot(em, h0m, AB)
+        db_ref[...] += dot(xw.astype(dtype), dhm, AB)
+        dxw = dot(bm, dhm, ABT)
+        dh_scr[hb, lanes, :] = _add(dot(em, cm, ATB), _mul(eq, dh))
         dxs = _add(dxd, _mul(dxw, sw))
         dx_ref[:, lanes] = _mul(dxs, dcl).astype(dx_ref.dtype)
         written = _mul(dxw, xw)               # d(h_end) . (what s wrote)
         # dy . (what the state gave y) less what s wrote: d(c_t) of both
-        local = _sub(_mul(e, _dot(cm, h0m, _ABT)), written)
+        local = _sub(_mul(e, dot(cm, h0m, ABT)), written)
         kept = _mul(_mul(dh, eq), h0)         # d(h_end) . (what was kept)
         if per_tile == 1:
             sums = [(_add(local, across[0]), False)]
@@ -446,16 +430,16 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
         dcum = _add(dcum, _head_rows(sums, i * per_tile, hpb, p))
         ddt = _add(ddt, _head_rows([(_mul(dxs, xf), False)], i * per_tile,
                                    hpb, p))
-        wrote = _sum(written, 0)                                   # [1, 128]
+        wrote = lane_sum(written, 0)                               # [1, 128]
         for j in range(per_tile):
             k = i * per_tile + j
             dcq = _add(
-                _sum(_only(wrote, j, p, None if first is None else
-                           jax.lax.slice(first, (0, 0), (1, _LANES))), 1),
-                _sum(_sum(jax.lax.slice(kept, (j * p, 0), ((j + 1) * p, n)),
-                          0), 1))
+                lane_sum(_only(wrote, j, p, None if first is None else
+                               jax.lax.slice(first, (0, 0), (1, LANES))), 1),
+                lane_sum(lane_sum(jax.lax.slice(
+                    kept, (j * p, 0), ((j + 1) * p, n)), 0), 1))
             dcum = _add(dcum, jax.lax.select(
-                _row_is(sub, k) & end, _spread(dcq, (hpb, q)), zeros))
+                _row_is(sub, k) & end, spread(dcq, (hpb, q)), zeros))
     dg_scr[...] += dg
     dcum_ref[...] = dcum
     ddt_ref[...] = ddt
@@ -465,8 +449,8 @@ def _bwd_kernel(x_ref, dy_ref, dt_ref, cum_ref, b_ref, c_ref, st_ref,
         rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
         dgm = jnp.where(rows >= cols, dg_scr[...], 0.0).astype(dtype)
-        dc_ref[...] += _dot(dgm, bm, _AB)
-        db_ref[...] += _dot(dgm, cm, _ATB)
+        dc_ref[...] += dot(dgm, bm, AB)
+        db_ref[...] += dot(dgm, cm, ATB)
 
 
 def _ssd_bwd(x, dy, dt_t, cum_t, bm, cm, states, p, chunk, hpb):
@@ -492,7 +476,7 @@ def _ssd_bwd(x, dy, dt_t, cum_t, bm, cm, states, p, chunk, hpb):
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
         compiler_params=_params(),
         name=KERNEL_NAMES["bwd"],
-        interpret=_flash._use_interpret(),
+        interpret=kernel_common.use_interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * t * (h * p * (2 * chunk + 5 * n) + 3 * n * chunk),
             bytes_accessed=3 * x.size * x.dtype.itemsize + 4 * b * nc * hp * n,
@@ -531,11 +515,7 @@ def _ssd_chunked(x, dt, a, bm, cm, chunk: int):
     Holds [B, chunks, Q, Q, H] arrays: small shapes only."""
     b, t, h, p = x.shape
     g, n = bm.shape[2:]
-    pad = -t % chunk
-    if pad:
-        x, dt, a, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
-                                    * (v.ndim - 2))
-                            for v in (x, dt, a, bm, cm))
+    (x, dt, a, bm, cm), pad = pad_tokens((x, dt, a, bm, cm), chunk)
     nc = (t + pad) // chunk
     cut = lambda v: v.reshape((b, nc, chunk) + v.shape[2:])  # noqa: E731
     x, dt, a, bm, cm = map(cut, (x, dt, a, bm, cm))
@@ -585,13 +565,12 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     dt = dt.astype(jnp.float32)
     a = dt * A.astype(jnp.float32)
     hpb = _heads_per_block(h, p)
-    kernel = bool(hpb) and g == 1 and n % _LANES == 0 \
-        and chunk % _LANES == 0 and t % chunk == 0
+    kernel = bool(hpb) and g == 1 and n % LANES == 0 \
+        and chunk % LANES == 0 and t % chunk == 0
     route = "kernel" if kernel else "reference"
-    PATH_COUNTS[route] += 1
-    _record("rtpu.ops.ssd.path", route,
-            {"route": route, "chunk": chunk, "heads": h, "head_dim": p,
-             "state": n, "groups": g, "chunks": -(-t // chunk)})
+    record_path("rtpu.ops.ssd.path", PATH_COUNTS, route,
+                {"chunk": chunk, "heads": h, "head_dim": p, "state": n,
+                 "groups": g, "chunks": -(-t // chunk)})
     if kernel:
         cum = jnp.cumsum(a.reshape(b, t // chunk, chunk, h), axis=2)
         rows = lambda v: jnp.swapaxes(v.reshape(b, t, h), 1, 2)  # noqa: E731

@@ -130,7 +130,7 @@ def compiler_remat(text: str) -> int:
 def compile_step(cell: str, sharding):
     """``cell``'s train step compiled for the device of ``sharding`` from
     shapes alone; ``benchmark`` and ``ray_tpu`` are whichever ``sys.path``
-    finds, the kernels whichever path ``_use_interpret`` says."""
+    finds, the kernels whichever path ``kernel_common.use_interpret`` says."""
     import jax
     import jax.numpy as jnp
 
@@ -154,6 +154,14 @@ def compile_step(cell: str, sharding):
         shaped(params), shaped(opt), toks).compile()
 
 
+def steer_kernels() -> None:
+    """Every kernel file picks interpret mode by the one switch of
+    ``ray_tpu.ops.kernel_common``, which reads ``jax.default_backend()``,
+    the CPU here: take the compiled path, as the chip does."""
+    importlib.import_module(
+        "ray_tpu.ops.kernel_common").use_interpret = lambda: False
+
+
 def dump(tree: str, out: str, cell: str, census: bool = False) -> None:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -162,14 +170,7 @@ def dump(tree: str, out: str, cell: str, census: bool = False) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    # the kernels pick interpret mode from jax.default_backend(), the CPU
-    # here: take the compiled path, as the chip does
-    # (``ray_tpu.ops.flash_attention`` the attribute is the function; the
-    # expert layer's grouped products have a switch of their own, and
-    # interpreted their row buffers alone refuse kimilinear's step)
-    for module in ("flash_attention", "expert_layer"):
-        importlib.import_module(
-            "ray_tpu.ops." + module)._use_interpret = lambda: False
+    steer_kernels()
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

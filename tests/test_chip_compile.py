@@ -3,63 +3,31 @@ attached one (on-chip-measurement guide, section 2). Nothing runs, so these
 say nothing about results or speed; they catch what the chip's compiler
 refuses (tiling, VMEM, HBM) before a chip run has to.
 
-The topology is described only inside the module-scoped fixture below, so
-importing this file loads nothing, and only the xdist worker that runs it
+The topology is described only inside the module-scoped fixture ``one_chip``
+(``tests/_chip_compile.py``, shared with ``test_cell_step_compile.py``, where
+a cell's WHOLE train step is compiled, marked ``slow``), so importing this
+file loads nothing, and only the xdist worker that runs it
 takes libtpu. The shapes are chip_smoke.py's: GPT-2 small at full width,
 S=1024, the train batch chosen there and the serve engine's programs.
 """
+import ast
 import importlib
 import math
+import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
-HBM_BYTES = 15.75e9  # what the v5e compiler reports as its HBM capacity
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a described-chip compile is written to the persistent cache but can
-    # never be read back without a chip: keep it out
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture
-def compiled_kernels(monkeypatch):
-    """The kernels decide interpret mode from jax.default_backend(), which
-    is the CPU here: steer them to the compiled path for these tests."""
-    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.flash_attention"),
-                        "_use_interpret", lambda: False)
+from _chip_compile import (compiled_kernels, fits, hlo_tool,  # noqa: F401
+                           one_chip)
 
 
 def _on(sharding, tree):
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         tree)
-
-
-def _fits(compiled) -> float:
-    m = compiled.memory_analysis()
-    resident = (m.argument_size_in_bytes + m.output_size_in_bytes
-                - m.alias_size_in_bytes)
-    assert resident < HBM_BYTES
-    return resident
 
 
 def test_flash_attention_fwd_bwd_gpt2_small(one_chip, compiled_kernels):
@@ -203,7 +171,7 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     ids=["kanana2_train_s8192", "xing4_train_s4096",
          "nemotron3super_train_s8192"])
 def test_held_expert_layer_at_the_benchmark_cells_shape(
-        one_chip, monkeypatch, t, d, f, held, of, shared, top_k, scale,
+        one_chip, compiled_kernels, t, d, f, held, of, shared, top_k, scale,
         expert, latent):
     """ISSUE 33: 16 384 tokens, 16 of 128 experts of 2048 x 768 held, top
     6: forward and backward of the layer, the two grouped-product kernels
@@ -214,7 +182,6 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     pair domain is 131 072 and no array of the program is sized by the
     360 448 (token, choice) pairs."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
-    monkeypatch.setattr(el, "_use_interpret", lambda: False)
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     width = latent or d
@@ -358,59 +325,6 @@ def test_conv_silu_backward_is_two_fusions_and_one_array(one_chip, shape,
     assert fusions == 3 and temp >= 3.9 * array_bytes
 
 
-def _hlo_tool():
-    """scripts/train_step_hlo.py as a module."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "train_step_hlo", os.path.join(os.path.dirname(__file__), "..",
-                                       "scripts", "train_step_hlo.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-    return tool
-
-
-def test_granite4h_train_step_keeps_its_room(one_chip, compiled_kernels,
-                                             monkeypatch):
-    """ISSUE 37: granite4h_train_s4096's own train step (the harness's
-    ``make_train_step``, the cell's configuration, optimizer, batch 2 of
-    4096, parameters and optimizer state donated) for the described v5e,
-    the runs after the first keeping the gated MLP's two input products:
-    8 652 767 744 bytes of temporaries beside 9.27 GB of arguments
-    (8 635 408 896 since ISSUE 55's hand-written gradient of the
-    convolution; 8 591 307 776 with nothing kept: what later runs keep is
-    freed before the peak, which is in the first run's backward), and the
-    compiler rematerialises NOTHING on its own. It does as soon as the first
-    run keeps a product too (``.remat`` instructions: the head's logits made
-    again, then the mixers' products), and with every layer keeping both
-    the program holds more matmul operations than with nothing kept. A
-    change that eats the room fails here, not as a slower step on the
-    chip."""
-    import os
-
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    tool = _hlo_tool()
-    compiled = tool.compile_step("granite4h_train_s4096", one_chip)
-    # 772 M parameters and two adam moments in float32, donated
-    assert 9.2e9 < _fits(compiled) < 9.3e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 9.0e9
-    text = compiled.as_text()
-    assert "s32[2,4096]" in text            # the cell's batch, not another
-    assert tool.compiler_remat(text) == 0
-    # 47.42 T with nothing kept, 44.67 T as kept here, 49.80 T with every
-    # layer keeping both (scripts/train_step_hlo.py --census)
-    census = tool.matmul_census(text)
-    assert sum(census.values()) < 44.8e12
-    assert census["mlp"] < 27.6e12          # 30.24 T with nothing kept
-    # the kept stacks of the runs of 1 and 4 are in the program, written
-    # by the product's own fusion; the run of 5 has none
-    assert "bf16[4,2,4096,8192]" in text
-    assert "bf16[5,2,4096,8192]" not in text
-    assert _conv_fusions_write_one_array_each(text, "bf16[2,4096,4352]")
-
-
 def test_selective_scan_at_the_benchmark_cells_shape(one_chip,
                                                      compiled_kernels):
     """ISSUE 43: phi4flash_train_s8192's Mamba-1 scan, B=1, S=8192, 5120
@@ -498,119 +412,6 @@ def test_paired_flash_at_the_benchmark_cells_shape(one_chip, compiled_kernels,
     assert "bf16[1,8192,5120]" in text and "[1,8192,80," not in text
 
 
-def test_phi4flash_train_step_keeps_its_room(one_chip, compiled_kernels,
-                                             monkeypatch):
-    """ISSUE 43: phi4flash_train_s8192's own train step (the harness's
-    ``make_train_step``, the cell's configuration, optimizer, batch 1 of
-    8192, parameters and optimizer state donated) for the described v5e:
-    697 M parameters at 12 B as arguments (8.37 GB), 5.454 GB of
-    temporaries with every layer keeping the kernels' outputs, the MLP's
-    two products and the mixers' input projections (5.230 GB with nothing
-    kept), the compiler rematerialising nothing on its own, 35.3 T matmul
-    operations a step (42.0 T with nothing kept; ``scripts/
-    train_step_hlo.py --census``), and each kernel in the program as often
-    as the six layers need it: no forward kernel a second time."""
-    import os
-
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    tool = _hlo_tool()
-    compiled = tool.compile_step("phi4flash_train_s8192", one_chip)
-    assert 8.3e9 < _fits(compiled) < 8.45e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 5.6e9
-    text = compiled.as_text()
-    assert "s32[1,8192]" in text            # the cell's batch, not another
-    assert tool.compiler_remat(text) == 0
-    assert sum(tool.matmul_census(text).values()) < 35.5e12
-    calls = [line.split(" = ")[0] for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    count = lambda name: sum(                                # noqa: E731
-        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
-    # two Mamba-1 layers; a window, a full and a cross layer
-    assert count("selscan_chunk_fwd") == count("selscan_chunk_bwd") == 2
-    assert count("flash_fwd") == count("flash_bwd_dq") \
-        == count("flash_bwd_dkv") == 3
-
-
-def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
-                                         monkeypatch):
-    """ISSUE 45: xing4_train_s4096's own train step (the harness's
-    ``make_train_step``, the cell's configuration, optimizer, batch 2 of
-    4096, parameters and optimizer state donated) for the described v5e:
-    759.5 M parameters at 12 B as arguments (9.11 GB), 10.43 GB of
-    temporaries (they overlap the donated state) with a layer keeping its
-    four streams, the latent kernels' output and row statistics and NOT q
-    (``_REMAT_SAVE_BOTTLENECK``: with q kept too the compiler refused the
-    step by 1.73 MB). The room is gone: the compiler makes instructions
-    again on its own to fit (``scripts/train_step_hlo.py --census``: 17
-    before ISSUE 47, mixed streams and the logits once; 9 and 10.58 GB of
-    temporaries with the mixings as kernels), which is what a change that
-    needs more memory would turn into a refusal here and not on the chip.
-    The latent kernels stand once a layer and direction, the mixings'
-    backward kernels once a sublayer of the dense layer and of the scanned
-    body (ISSUE 47), and no stream is laid out [tokens, 4, d] (4 rows
-    padded to 16)."""
-    import os
-
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    tool = _hlo_tool()
-    compiled = tool.compile_step("xing4_train_s4096", one_chip)
-    assert 9.05e9 < _fits(compiled) < 9.2e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 10.6e9
-    text = compiled.as_text()
-    assert "s32[2,4096]" in text            # the cell's batch, not another
-    assert tool.compiler_remat(text) <= 17
-    calls = [line.split(" = ")[0] for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    count = lambda name: sum(                                # noqa: E731
-        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
-    # the dense layer by itself and the scanned expert layers' one body
-    assert count("flash_latent_fwd") == count("flash_latent_bwd_dkv") == 2
-    assert count("mhc_post_bwd") == count("mhc_pre_bwd") == 4
-    assert count("mhc_pre_fwd") >= 4 and count("mhc_post_fwd") >= 4
-    assert not re.findall(r"\w+\[2,4096,4,3584\]", text)
-    assert not re.findall(r"f32\[8192,4,4\]|f32\[2,4096,4,4\]", text)
-
-
-def _unfused_under(text: str, scope: str):
-    """The instructions of an optimised program under the model's scope
-    ``scope`` (by their ``op_name``): of the entry and the loops' bodies,
-    not those inside a fusion (they live in registers and VMEM)."""
-    fused = set(re.findall(r" fusion\(.*?calls=%?([\w.\-]+)", text))
-    inside = None
-    for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
-        if head:
-            inside = head.group(1)
-        elif inside not in fused:
-            op = re.search(r'op_name="([^"]*)"', line)
-            if op and re.search(r"[/(]" + scope + r"[/)]", op.group(1)):
-                yield line
-
-
-def _buffers_under(text: str, shape: str, scope: str):
-    """The instructions of an optimised program that PRODUCE an array of
-    ``shape`` (``f32[2,8192,4096]``) under the model's scope ``scope``."""
-    return [line.split(" = ")[0].strip()
-            for line in _unfused_under(text, scope)
-            if re.match(r"\s*(?:ROOT )?%?\S+ = " + re.escape(shape) + r"[{ ]",
-                        line)]
-
-
-def _conv_fusions_write_one_array_each(text: str, shape: str) -> bool:
-    """ISSUE 55: inside the rematerialised, scanned layers too, every fusion
-    under the scope ``conv`` writes at most ONE array of ``shape`` (the
-    forward y; the backward dpre beside the sums for dw and db; dx).
-    Autodiff's backward had fusions there with two (the rematerialised
-    forward wrote its pre-activation for the backward to read), three and
-    four (one shifted product a tap)."""
-    written = [line.split(" fusion(")[0].partition(" = ")[2].count(shape)
-               for line in _unfused_under(text, "conv") if " fusion(" in line]
-    # forward, rematerialised forward, dpre, dx
-    return max(written) == 1 and sum(written) >= 4
-
-
 @pytest.mark.parametrize("entry", ["scan", "gated"])
 def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels,
                                                entry):
@@ -675,61 +476,6 @@ def test_kda_scan_at_the_benchmark_cells_shape(one_chip, compiled_kernels,
         3.0e9 if entry == "scan" else 0.8e9)
 
 
-# 48 s alone since the delta rule is a kernel pair (85 s with its loops of
-# 128 turns x 4 layers x 3 passes); beside five other workers it can still
-# pass the default 180 s
-@pytest.mark.time_limit(480)
-def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
-                                              monkeypatch):
-    """ISSUES 49, 50, 51: kimilinear_train_s8192's own train step (the
-    harness's ``make_train_step``, the cell's configuration, optimizer,
-    batch 2 of 8192, parameters and optimizer state donated) for the
-    described v5e, the expert layer's kernels on their compiled path as on
-    the chip (in interpret mode its row buffers are refused by 1.30 GB):
-    602.4 M parameters at 12 B as arguments (7.23 GB), 7.55 GB of
-    temporaries (they overlap the donated state; 7.95 GB while autodiff made
-    the convolutions' backward with four full-size arrays each, ISSUE 55;
-    8.76 GB while the plain code made the norms of q and k and wrote the
-    gate g in float32, ISSUE 51) with a KDA layer keeping its input alone
-    and the latent layer its kernels' output, row statistics and q; the
-    compiler makes NO instruction again on its own (2 before ISSUE 51; 20
-    before the delta rule's kernels freed the turns' stacked inputs; 20
-    again with one KDA layer's o and chunk states kept, 45 with all four:
-    why they are not). Under the scope ``scan`` no float32 [2, 8192, 4096]
-    array is produced any more (the parent's step held 21 such producers
-    there: the gate, its broadcast factor, the norms' squares, dg and its
-    products): the kernels read what the convolutions and the gate
-    projection made. The one latent layer's two kernels stand once each; the
-    delta rule's forward kernel stands twice a run of KDA layers (the
-    forward sweep and the rematerialised layer) and its backward once."""
-    import os
-
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.expert_layer"),
-                        "_use_interpret", lambda: False)
-    tool = _hlo_tool()
-    compiled = tool.compile_step("kimilinear_train_s8192", one_chip)
-    assert 7.2e9 < _fits(compiled) < 7.3e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 7.7e9
-    text = compiled.as_text()
-    assert "s32[2,8192]" in text            # the cell's batch, not another
-    assert tool.compiler_remat(text) <= 6
-    assert _buffers_under(text, "f32[2,8192,4096]", "scan") == []
-    assert _buffers_under(text, "f32[2,8192,4096]", "mixer")  # it can see
-    calls = [line.split(" = ")[0] for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    count = lambda name: sum(                                # noqa: E731
-        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
-    assert count("flash_latent_fwd") == count("flash_latent_bwd_dkv") == 1
-    assert count("kda_chunk_fwd") == 6 and count("kda_chunk_bwd") == 3
-    for line in text.splitlines():          # all nine under the scope
-        if re.match(r"\s*%?kda_chunk_(fwd|bwd)(\.\d+)? = ", line):
-            assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
-    assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 9
-    assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,4096]")
-
-
 def test_gdn_gated_scan_at_the_benchmark_cells_shape(one_chip,
                                                      compiled_kernels):
     """ISSUE 53: qwen3next_train_s8192's Gated DeltaNet scan, B=2, S=8192,
@@ -781,61 +527,8 @@ def test_gdn_gated_scan_at_the_benchmark_cells_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
-# 70 s alone (the compile of four layers' kernels and the sort of 163 840
-# pairs a layer); beside five other workers it can pass the default 180 s
-@pytest.mark.time_limit(480)
-def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
-                                             monkeypatch):
-    """ISSUE 52: qwen3next_train_s8192's own train step (the harness's
-    ``make_train_step``, the cell's configuration, optimizer, batch 2 of
-    8192, parameters and optimizer state donated) for the described v5e, the
-    expert layer's kernels on their compiled path as on the chip: 626.0 M
-    parameters at 12 B as arguments (7.51 GB), 9.50 GB of temporaries since
-    ISSUE 53 (9.77 while q and k were repeated to the value heads; they
-    overlap the donated state) with a Gated DeltaNet layer keeping its input
-    alone and the attention layer its kernels' output and row statistics
-    (nothing kept in the attention layer reads 9.7677 against 9.7679 GB; a
-    Gated DeltaNet layer keeping ``kda_out`` and ``kda_states`` is refused,
-    "Used 16.80G of 15.75G hbm"); the compiler makes 3 instructions again on
-    its own (4 until ISSUE 55: the convolution's forward a third time, for
-    autodiff's backward). Under the scope ``scan`` no float32 [2, 8192,
-    4096] array is produced: the kernels make the norms and the gate from
-    what the convolution and ``W_ba`` left. The one attention layer's two
-    one-part flash kernels stand once each; the delta rule's forward kernel
-    (ISSUE 53: ``gdn_chunk_fwd``, the body for one decay a head; KDA's is
-    not in the program) twice in the scanned run's loops (the forward sweep
-    and the rematerialised layer) and its backward once."""
-    import os
-
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.expert_layer"),
-                        "_use_interpret", lambda: False)
-    tool = _hlo_tool()
-    compiled = tool.compile_step("qwen3next_train_s8192", one_chip)
-    assert 7.5e9 < _fits(compiled) < 7.6e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 9.6e9
-    text = compiled.as_text()
-    assert "s32[2,8192]" in text            # the cell's batch, not another
-    assert tool.compiler_remat(text) <= 8
-    assert _buffers_under(text, "f32[2,8192,4096]", "scan") == []
-    calls = [line.split(" = ")[0] for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    count = lambda name: sum(                                # noqa: E731
-        1 for c in calls if re.match(r"\s*%?" + name + r"(\.\d+)?$", c))
-    assert count("flash_fwd") == 1          # kept: not run again
-    assert count("flash_bwd_dq") + count("flash_bwd_fused") == 1
-    assert count("gdn_chunk_fwd") == 2 and count("gdn_chunk_bwd") == 1
-    assert count("kda_chunk_fwd") == 0 and count("kda_chunk_bwd") == 0
-    for line in text.splitlines():          # all three under the scope
-        if re.match(r"\s*%?gdn_chunk_(fwd|bwd)(\.\d+)? = ", line):
-            assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
-    assert count("grouped_matmul") >= 6 and count("grouped_matmul_dw") >= 6
-    assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,8192]")
-
-
-def test_sparse_attention_layer_at_the_benchmark_cells_shape(one_chip,
-                                                             monkeypatch):
+def test_sparse_attention_layer_at_the_benchmark_cells_shape(
+        one_chip, compiled_kernels):
     """ISSUE 59: keyevl2_train_s16384's attention sublayer (norm, q / k / v
     with their norms and rotation, the indexer, the exact top 2048 a query,
     the two kernels over the selection, the output projection) at ONE row
@@ -848,7 +541,6 @@ def test_sparse_attention_layer_at_the_benchmark_cells_shape(one_chip,
     from ray_tpu.models import KeyeVL2, KeyeVL2Config
 
     sa = importlib.import_module("ray_tpu.ops.sparse_attention")
-    monkeypatch.setattr(sa, "_use_interpret", lambda: False)
     model = KeyeVL2(KeyeVL2Config.keye_vl2_30b_a3b(
         n_layer=1, experts_held=16, vocab_size=18992, max_seq=16384))
     c = model.config
@@ -904,7 +596,7 @@ def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
         _on(one_chip, params), _on(one_chip, opt), tok, tok).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # params f32 + two adam moments, nothing else resident across steps
-    assert 1.4e9 < _fits(compiled) < 1.6e9
+    assert 1.4e9 < fits(compiled) < 1.6e9
 
 
 def test_gpt2_small_engine_programs(one_chip):
@@ -923,11 +615,11 @@ def test_gpt2_small_engine_programs(one_chip):
     prefill = jax.jit(model.paged_prefill).lower(
         params, cache, i32(1, 512), i32(), i32(40)).compile()
     assert "tpu_custom_call" not in prefill.as_text()
-    _fits(prefill)
+    fits(prefill)
     decode = jax.jit(model.paged_decode_step).lower(
         params, cache, i32(8), i32(8), i32(8, 40),
         jax.ShapeDtypeStruct((8,), jnp.bool_, sharding=one_chip)).compile()
-    _fits(decode)
+    fits(decode)
 
 
 def test_hlo_comparison_ignores_where_code_stands(one_chip,
@@ -939,7 +631,7 @@ def test_hlo_comparison_ignores_where_code_stands(one_chip,
     and are equal once stripped; another program stays different."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    tool = _hlo_tool()
+    tool = hlo_tool()
 
     def here(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
@@ -964,3 +656,135 @@ def test_hlo_comparison_ignores_where_code_stands(one_chip,
     assert tool.strip_locations(a) == tool.strip_locations(b)
     assert "tpu_custom_call" in tool.strip_locations(a)
     assert tool.strip_locations(a) != tool.strip_locations(c)
+
+
+def _ops(name: str):
+    # the module, not the function of its name that the package exports
+    return importlib.import_module("ray_tpu.ops." + name)
+
+
+def _small_flash(sd):
+    return (lambda q, k, v: _ops("flash_attention").flash_attention(
+        q, k, v, causal=True)), [sd((1, 256, 2, 64), jnp.bfloat16)] * 3
+
+
+def _small_expert_layer(sd):
+    p = {"w_router": sd((128, 4)), "router_bias": sd((4,))}
+    p.update({n: sd((2, 128, 128)) for n in ("e_gate", "e_up", "e_down")})
+    return (lambda x, p: _ops("expert_layer").held_expert_layer(
+        x, p, experts_held=2, expert_offset=0, top_k=2,
+        routed_scale=1.0)[0]), [sd((256, 128), jnp.bfloat16), p]
+
+
+def _small_sparse(sd):
+    bf = jnp.bfloat16
+    return (lambda *a: _ops("sparse_attention").sparse_attention(
+        *a, topk=64, q_chunk=128, block_q=128, block_k=128)), [
+        sd((1, 256, 2, 128), bf), sd((1, 256, 1, 128), bf),
+        sd((1, 256, 1, 128), bf), sd((1, 256, 2, 64), bf),
+        sd((1, 256, 64), bf), sd((1, 256, 2))]
+
+
+def _small_ssd(sd):
+    bc = sd((1, 256, 1, 128), jnp.bfloat16)
+    return (lambda *a: _ops("ssd_scan").ssd_scan(*a, chunk=128)), [
+        sd((1, 256, 2, 64), jnp.bfloat16), sd((1, 256, 2)), sd((2,)), bc, bc,
+        sd((2,))]
+
+
+def _small_selective(sd):
+    return _ops("selective_scan").selective_scan, [
+        sd((1, 32, 128), jnp.bfloat16), sd((1, 32, 128)), sd((128, 8)),
+        sd((1, 32, 8)), sd((1, 32, 8)), sd((128,))]
+
+
+def _small_hyper_connection(sd):
+    hc = _ops("hyper_connection")
+    p = {k: sd(s) for k, s in hc.hc_param_shapes(4, 128).items()}
+    return (lambda x, p: hc.hc_mix(
+        x, p, lambda z: (jnp.tanh(z), None), iters=2, eps=1e-6,
+        clamp=(-30.0, 30.0), rms_eps=1e-6)[0]), [
+        tuple(sd((128, 128)) for _ in range(4)), p]
+
+
+def _small_kda(sd):
+    return (lambda *a: _ops("kda_scan").kda_scan(*a, scale=1.0)), \
+        [sd((1, 128, 128), jnp.bfloat16)] * 3 + [sd((1, 128, 128)),
+                                                 sd((1, 128, 1))]
+
+
+# the kernel files of ``ray_tpu/ops`` and one small call of each that
+# takes its kernel route
+KERNEL_FILES = {
+    "flash_attention": _small_flash, "expert_layer": _small_expert_layer,
+    "sparse_attention": _small_sparse, "ssd_scan": _small_ssd,
+    "selective_scan": _small_selective,
+    "hyper_connection": _small_hyper_connection, "kda_scan": _small_kda}
+
+
+@pytest.mark.parametrize("module", sorted(KERNEL_FILES))
+def test_every_kernel_file_asks_the_one_switch(one_chip, monkeypatch,
+                                               module):
+    """ISSUE 61: a small call of each kernel file, forward and backward,
+    LOWERED for the described v5e (nothing is compiled): interpreted as the
+    backend here says, it holds no ``tpu_custom_call``; with
+    ``kernel_common.use_interpret`` alone steered, it holds its forward
+    and its backward kernel. A file with a switch of its own, which a tool
+    that steers the one would describe interpreted, fails the second."""
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    f, args = KERNEL_FILES[module](sd)
+
+    def kernels() -> int:
+        loss = lambda *a: sum(jnp.sum(o.astype(jnp.float32))  # noqa: E731
+                              for o in jax.tree.leaves(f(*a)))
+        return jax.jit(jax.grad(loss)).lower(*args).as_text().count(
+            "tpu_custom_call")
+
+    assert kernels() == 0
+    monkeypatch.setattr(_ops("kernel_common"), "use_interpret", lambda: False)
+    assert kernels() >= 2
+
+
+def test_the_hlo_tool_steers_every_kernel(monkeypatch):
+    """ISSUE 61: after ``scripts/train_step_hlo.py``'s own steering no
+    kernel file answers "interpreted": a census or a comparison made with
+    the tool describes the program the chip runs (before, the tool steered
+    two of three switches, and ``keyevl2_train_s16384``'s sparse kernels
+    were compiled interpreted)."""
+    common = _ops("kernel_common")
+    assert common.use_interpret()                       # the CPU, here
+    monkeypatch.setattr(common, "use_interpret", common.use_interpret)
+    hlo_tool().steer_kernels()
+    for module in KERNEL_FILES:
+        assert _ops(module).kernel_common.use_interpret() is False, module
+
+
+def test_no_kernel_file_imports_anothers_private_name():
+    """ISSUE 61: no file of ``ray_tpu/ops`` takes a name that starts with
+    ``_`` from another file of the package, by ``from .x import _y`` or as
+    ``x._y`` of a module it imported: what several kernel files need is
+    public in ``kernel_common.py``, and a rename inside one file breaks no
+    other."""
+    ops = os.path.dirname(_ops("kernel_common").__file__)
+    taken = []
+    for name in sorted(os.listdir(ops)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(ops, name)) as f:
+            tree = ast.parse(f.read())
+        siblings = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and node.level == 1:
+                for a in node.names:
+                    if node.module is None:         # from . import x
+                        siblings.add(a.asname or a.name)
+                    elif a.name.startswith("_"):
+                        taken.append(f"{name}: {node.module}.{a.name}")
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Attribute) and node.attr.startswith("_") \
+                    and not node.attr.startswith("__") \
+                    and isinstance(node.value, ast.Name) \
+                    and node.value.id in siblings:
+                taken.append(f"{name}: {node.value.id}.{node.attr}")
+    assert not taken, taken

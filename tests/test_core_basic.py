@@ -92,18 +92,24 @@ def test_nested_tasks(ray_start_regular):
     assert ray_tpu.get(outer.remote(1), timeout=60) == 12
 
 
-def test_wait(ray_start_regular):
+def test_wait(ray_start_regular, machine_load):
+    """``fast`` is ready within the wait and ``slow`` is not. How long a
+    worker takes to start and return is the machine's at that moment: the
+    wait and the sleep it must end before grow with the load (4 s beside
+    5 s on an idle machine); the wait returns as soon as ``fast`` has."""
+    scale = 1.0 + machine_load
+
     @ray_tpu.remote
     def fast():
         return "fast"
 
     @ray_tpu.remote
-    def slow():
-        time.sleep(5)
+    def slow(seconds):
+        time.sleep(seconds)
         return "slow"
 
-    f, s = fast.remote(), slow.remote()
-    ready, pending = ray_tpu.wait([f, s], num_returns=1, timeout=4)
+    f, s = fast.remote(), slow.remote(5 * scale)
+    ready, pending = ray_tpu.wait([f, s], num_returns=1, timeout=4 * scale)
     assert ready == [f]
     assert pending == [s]
 

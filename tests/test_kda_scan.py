@@ -37,39 +37,19 @@ be, not of the sum that is left (against float64 the plain route's own
 ``A_log`` is off by 7e-5 of its largest entry here, the kernels' by 1.5e-4;
 of what is added both read under 1e-6).
 
-ISSUE 52 (the file's last section): the delta rule with ONE decay a head
-and several value heads to a key head (Gated DeltaNet).
-``gated_delta_scan`` is held to the same recurrence (the decay spread over
-a head's channels, q and k repeated to the value heads) and to ``kda_scan``
-handed that spread gate; ``gdn_gated_scan``, a layer's entry, to the
-recurrence on both routes, o and seven gradients, by the same limits.
+ISSUE 52, the delta rule with ONE decay a head (Gated DeltaNet):
+``test_gdn_scan.py``; what both files share (the recurrence, the route's
+fixture) is in ``_delta_rule.py``.
 """
-import importlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-kda = importlib.import_module("ray_tpu.ops.kda_scan")
+from _delta_rule import (D, F32_TOL, GATED, NAMES, kda,  # noqa: F401
+                         recurrence, route, took, unit_heads, value_and_grads)
 
-NAMES = ("q", "k", "v", "g", "beta")
-GATED = ("q", "k", "v", "step", "a_log", "dt_bias", "beta")
-F32_TOL = 1e-5
-D = 128
-EPS = 1e-6      # ``layers.l2norm``'s
-ROUTES = ("chunked_jnp", "kernel")
 ENTRIES = ("scan", "gated")
-
-
-@pytest.fixture(params=ROUTES)
-def route(request, monkeypatch):
-    """The route the module's calls take in this test: the plain form for
-    every shape, or what the shape gives (the kernel pair at d 128 and a
-    chunk of 64)."""
-    if request.param == "chunked_jnp":
-        monkeypatch.setattr(kda, "_route", lambda *shape: "chunked_jnp")
-    return request.param
 
 
 @pytest.fixture(params=ENTRIES)
@@ -77,50 +57,6 @@ def entry(request):
     """``scan``: ``kda_scan`` (q and k normalised, g ready); ``gated``:
     ``kda_gated_scan`` from the raw q, k and the gate projection's step."""
     return request.param
-
-
-def took(route, before, chunk=64):
-    """The one route counted since ``before`` (a copy of PATH_COUNTS) is
-    the one the test asked for, or the plain one for a chunk the kernels do
-    not take."""
-    want = route if chunk == 64 else "chunked_jnp"
-    gained = {k: n - before[k] for k, n in kda.PATH_COUNTS.items()
-              if n != before[k]}
-    assert set(gained) == {want}, (gained, want)
-
-
-def recurrence(q, k, v, g, beta, *, scale, heads, state_dtype=jnp.float32,
-               head_decay=False, delta=True):
-    """The definition: one token at a time, float32, no chunk. The three
-    switches make the WRONG scans the tolerances must catch."""
-    b, t, _ = q.shape
-    per_head = lambda x: x.astype(jnp.float32).reshape(     # noqa: E731
-        b, t, heads, -1)
-    q, k, v, g = map(per_head, (q, k, v, g))
-    if head_decay:
-        g = jnp.broadcast_to(g.mean(-1, keepdims=True), g.shape)
-    beta = beta.astype(jnp.float32)
-
-    def step(s, tok):
-        qt, kt, vt, gt, bt = tok
-        s = jnp.exp(gt)[..., None] * s
-        held = jnp.einsum("bhkv,bhk->bhv", s, kt) if delta else 0.0
-        s = s + (bt[..., None] * kt)[..., None] * (vt - held)[..., None, :]
-        s = s.astype(state_dtype).astype(jnp.float32)
-        return s, jnp.einsum("bhkv,bhk->bhv", s, qt * scale)
-
-    with jax.default_matmul_precision("highest"):
-        _, o = jax.lax.scan(
-            step, jnp.zeros((b, heads, q.shape[-1], v.shape[-1])),
-            tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
-    return jnp.moveaxis(o, 0, 1).reshape(b, t, -1)
-
-
-def unit_heads(x, heads, eps=EPS):
-    """x [B, T, heads * d] float32 / sqrt(sum of a head's squares + eps)."""
-    xh = x.astype(jnp.float32).reshape(*x.shape[:2], heads, -1)
-    return (xh / jnp.sqrt(jnp.sum(xh * xh, -1, keepdims=True) + eps)
-            ).reshape(x.shape)
 
 
 def gate_of(step, a_log, dt_bias):
@@ -195,19 +131,6 @@ def gated(args, dtype=None, gate=None):
     return out
 
 
-def value_and_grads(fn, args, do):
-    names = GATED if "step" in args else NAMES
-
-    def scalar(*a):
-        o = fn(*a)
-        return jnp.sum(o.astype(jnp.float32) * do), o
-
-    (_, o), grads = jax.jit(jax.value_and_grad(
-        scalar, argnums=tuple(range(len(names))), has_aux=True))(
-        *(args[n] for n in names))
-    return dict(zip(("o",) + names, (o,) + grads))
-
-
 def added(args, want):
     """What the gradients of ``a_log`` (dg g, a head) and ``dt_bias``
     (dstep, a channel) ADD UP in magnitude, from ``want``'s dstep = dg g
@@ -252,12 +175,15 @@ def test_kda_scan_is_the_recurrence(t, chunk, route, entry):
     multiple of the chunk or not (150 = 2 chunks and 22 tokens: padded; 40
     tokens: the kernels pad them to one chunk of 64), decays as strong as
     the assumed initialisation makes them. A chunk of 16 is the plain
-    form's on either route."""
-    args, do = arguments(0, t)
+    form's on either route. One row of one head: the smallest call the
+    kernels take (a program's heads are unrolled, and sixteen cases
+    compile them; two rows of two heads a program are every other test's
+    shape)."""
+    args, do = arguments(0, t, heads=1, batch=1)
     if entry == "gated":
         args = gated(args)
     before = kda.PATH_COUNTS.copy()
-    got, want = both(args, do, chunk=chunk)
+    got, want = both(args, do, heads=1, chunk=chunk)
     took(route, before, chunk)
     for name, err in worst(got, want, args).items():
         assert err < F32_TOL, (name, err)
@@ -509,402 +435,3 @@ def test_other_shapes_fall_back_to_the_plain_route(entry):
         took(want, before)
     assert [kda._heads_per_block(n) for n in (1, 2, 3, 6, 32)] == [
         1, 2, 3, 2, 4]
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 52: one decay a head, value heads in groups over the key heads
-# ---------------------------------------------------------------------------
-
-GDN = ("q", "k", "v", "g", "beta")
-GDN_GATED = ("q", "k", "v", "a", "a_log", "dt_bias", "beta")
-
-
-def gdn_arguments(seed, t, key_heads=2, value_heads=4, batch=2, gate=None,
-                  raw=False):
-    """What a Gated DeltaNet layer hands its scan: q, k [B, T, Hk * 128] of
-    unit length a head (``raw``: of any length, 0.01 to 10), v [B, T, Hv *
-    128], one decay a VALUE head and token from 0.999 down to 0.2 (A in
-    [1, 16] x a step log-uniform in [0.001, 0.1]) or ``gate``, beta; and,
-    with ``raw``, the layer's own a, A_log and dt_bias in place of g."""
-    r = jax.random.split(jax.random.PRNGKey(seed), 10)
-    kshape, vshape = (batch, t, key_heads * D), (batch, t, value_heads * D)
-
-    def keys(key, length):
-        x = jax.random.normal(key, (batch, t, key_heads, D))
-        x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-        if raw:
-            x = x * jnp.exp(jax.random.uniform(
-                length, (batch, t, key_heads, 1), minval=np.log(1e-2),
-                maxval=np.log(10.0)))
-        return x.reshape(kshape)
-
-    out = {"q": keys(r[0], r[6]), "k": keys(r[1], r[7]),
-           "v": jax.random.normal(r[2], vshape),
-           "beta": jax.nn.sigmoid(jax.random.normal(
-               r[5], (batch, t, value_heads)))}
-    a_log = jnp.log(jax.random.uniform(r[3], (value_heads,), minval=1.0,
-                                       maxval=16.0))
-    if raw:
-        dt = jnp.exp(jax.random.uniform(r[4], (value_heads,),
-                                        minval=np.log(1e-3),
-                                        maxval=np.log(0.1)))
-        out.update(a=0.5 * jax.random.normal(r[8], (batch, t, value_heads)),
-                   a_log=a_log, dt_bias=dt + jnp.log(-jnp.expm1(-dt)))
-    else:
-        step = jnp.exp(jax.random.uniform(
-            r[4], (batch, t, value_heads), minval=np.log(1e-3),
-            maxval=np.log(0.1)))
-        out["g"] = -jnp.exp(a_log) * step if gate is None \
-            else jnp.full((batch, t, value_heads), gate, jnp.float32)
-    return out, jax.random.normal(r[9], vshape)
-
-
-def to_value_heads(x, key_heads, value_heads):
-    """[B, T, Hk * 128] -> [B, T, Hv * 128]: value head j reads key head
-    j // (Hv / Hk)."""
-    b, t, _ = x.shape
-    return jnp.repeat(x.reshape(b, t, key_heads, D),
-                      value_heads // key_heads, 2).reshape(b, t, -1)
-
-
-def gdn_recurrence(q, k, v, g, beta, *, scale):
-    """The definition, token by token: the file's ``recurrence`` with the
-    head's one decay on all of its key channels."""
-    hv = beta.shape[-1]
-    hk = k.shape[-1] // D
-    return recurrence(to_value_heads(q, hk, hv), to_value_heads(k, hk, hv), v,
-                      jnp.repeat(g, D, -1), beta, scale=scale, heads=hv)
-
-
-def gdn_gated_recurrence(q, k, v, a, a_log, dt_bias, beta, *, scale):
-    hk = k.shape[-1] // D
-    g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
-    return gdn_recurrence(unit_heads(q, hk), unit_heads(k, hk), v, g, beta,
-                          scale=scale)
-
-
-def gdn_both(args, do, **kw):
-    names = GDN_GATED if "a" in args else GDN
-    ref, fn = (gdn_gated_recurrence, kda.gdn_gated_scan) if "a" in args \
-        else (gdn_recurrence, kda.gated_delta_scan)
-    scale = D ** -0.5
-
-    def grads(f):
-        def scalar(*a):
-            o = f(*a)
-            return jnp.sum(o.astype(jnp.float32) * do), o
-
-        (_, o), g = jax.jit(jax.value_and_grad(
-            scalar, argnums=tuple(range(len(names))), has_aux=True))(
-            *(args[n] for n in names))
-        return dict(zip(("o",) + names, (o,) + g))
-
-    return (grads(lambda *a: fn(*a, scale=scale, **kw)),
-            grads(lambda *a: ref(*a, scale=scale)))
-
-
-def gdn_worst(got, want, args):
-    """``worst`` with the sums of the layer's entry held to what they add
-    up: dA_log = sum dg g and ddt_bias = sum da, over every token."""
-    of = {n: jnp.max(jnp.abs(want[n])) for n in want}
-    if "a" in args:
-        x = args["a"] + args["dt_bias"]
-        da = jnp.abs(want["a"])
-        of["a_log"] = jnp.max((da * jax.nn.softplus(x) / jax.nn.sigmoid(x)
-                               ).sum((0, 1)))
-        of["dt_bias"] = jnp.max(da.sum((0, 1)))
-    return {n: float(jnp.max(jnp.abs(got[n] - want[n])) / (of[n] + 1e-30))
-            for n in want}
-
-
-@pytest.mark.parametrize("t,chunk,heads", [
-    (150, 64, (2, 2)), (150, 64, (2, 4)), (150, 64, (1, 4)),
-    (64, 64, (2, 4)), (40, 64, (2, 4)), (96, 16, (2, 4))],
-    ids=["ragged-one_to_one", "ragged-two_to_one", "ragged-four_to_one",
-         "one_chunk", "short", "chunk16"])
-def test_gated_delta_scan_is_the_recurrence(t, chunk, heads):
-    """o and all five gradients (``jax.vjp`` through the chunks' scan), T a
-    whole number of chunks or not, one, two and four value heads to a key
-    head: dq and dk are sums over a key head's value heads."""
-    args, do = gdn_arguments(0, t, *heads)
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do, chunk=chunk)
-    took("chunked_jnp", before)
-    assert got["o"].shape == (2, t, heads[1] * D)
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-
-
-def test_gated_delta_scan_is_kda_scan_under_a_channel_constant_gate(route):
-    """``kda_scan`` (either route) handed the head's decay on all 128 key
-    channels and q and k repeated to the value heads computes the same
-    thing: a decay that is constant over a head's lanes is a case of the
-    one it computes. o and the gradients of v and beta element by element,
-    dq and dk summed over a key head's value heads, dg over the lanes."""
-    args, do = gdn_arguments(3, 150)
-    got, _ = gdn_both(args, do)
-    wide = {"q": to_value_heads(args["q"], 2, 4),
-            "k": to_value_heads(args["k"], 2, 4), "v": args["v"],
-            "g": jnp.repeat(args["g"], D, -1), "beta": args["beta"]}
-    before = kda.PATH_COUNTS.copy()
-    theirs = value_and_grads(
-        lambda *a: kda.kda_scan(*a, scale=D ** -0.5), wide, do)
-    took(route, before)
-    group = lambda x: x.reshape(2, 150, 2, 2, D).sum(3).reshape(  # noqa: E731
-        2, 150, 2 * D)
-    want = {"o": theirs["o"], "q": group(theirs["q"]),
-            "k": group(theirs["k"]), "v": theirs["v"],
-            "g": theirs["g"].reshape(2, 150, 4, D).sum(-1),
-            "beta": theirs["beta"]}
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-
-
-def test_gated_delta_scan_under_the_strongest_decay():
-    """g = -20 a token: the state is forgotten between tokens, o_t = scale
-    beta_t (q_t . k_t) v_t, and every gradient is finite (every exponent
-    is a later cumulative sum less an earlier one)."""
-    args, do = gdn_arguments(1, 150, gate=-20.0)
-    got, want = gdn_both(args, do)
-    for name, v in got.items():
-        assert bool(jnp.all(jnp.isfinite(v))), name
-    assert float(jnp.max(jnp.abs(got["g"] - want["g"]))) < 1e-6
-    for name, err in gdn_worst(got, want, args).items():
-        assert name == "g" or err < F32_TOL, (name, err)
-    q, k = (to_value_heads(args[n], 2, 4).reshape(2, 150, 4, D) for n in "qk")
-    alone = (D ** -0.5 * args["beta"] * (q * k).sum(-1))[..., None] \
-        * args["v"].reshape(2, 150, 4, D)
-    np.testing.assert_allclose(got["o"], alone.reshape(2, 150, -1),
-                               atol=1e-6)
-
-
-def test_gated_delta_scan_refuses_heads_that_do_not_group():
-    args, _ = gdn_arguments(0, 64, key_heads=2, value_heads=3)
-    with pytest.raises(ValueError, match="3 value heads over 2 key heads"):
-        kda.gated_delta_scan(*(args[n] for n in GDN), scale=1.0)
-
-
-def in_dtype(args, dtype):
-    """A layer's arguments with what the model computes in its own dtype
-    (q, k, v and ``a``) cast to it; A_log, dt_bias and beta stay float32."""
-    return {n: x.astype(dtype) if n in ("q", "k", "v", "a") else x
-            for n, x in args.items()}
-
-
-def gdn_layer_worst(got, want, args):
-    """``gdn_worst`` for arguments of any dtype."""
-    f32 = lambda tree: {n: x.astype(jnp.float32)             # noqa: E731
-                        for n, x in tree.items()}
-    return gdn_worst(f32(got), f32(want), f32(args))
-
-
-@pytest.mark.parametrize("t,heads", [
-    (150, (2, 2)), (150, (2, 4)), (150, (1, 4)), (150, (1, 3)),
-    (150, (3, 3)), (64, (2, 4)), (40, (2, 4))],
-    ids=["ragged-one_to_one", "ragged-two_to_one", "ragged-four_to_one",
-         "ragged-three_to_one", "ragged-odd_heads", "one_chunk", "short"])
-def test_a_gated_deltanet_layers_scan_is_the_recurrence(route, t, heads):
-    """``gdn_gated_scan`` from what a layer's convolution and b | a
-    projection leave, on the kernel route (ISSUE 53: the pair's body for
-    one decay a head; q and k read once a key head, the norms, the gate and
-    its cumulative sums made in the kernels) and on the plain one
-    (``l2norm``, the softplus, ``gated_delta_scan``): o and seven gradients
-    against the recurrence. One, two, three and four value heads to a key
-    head (a program works four value heads over four, two and one key
-    heads, or three over one; three key heads of one value head each are
-    solved one by one), T ragged, one chunk, shorter than a chunk."""
-    args, do = gdn_arguments(2, t, *heads, raw=True)
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do)
-    took(route, before)
-    assert got["o"].shape == (2, t, heads[1] * D)
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-
-
-def test_value_heads_no_block_holds_take_the_plain_route():
-    """Eight value heads to a key head: a program works at most four, and
-    dq and dk leave the backward kernel summed over a key head's value
-    heads, so a block holds whole key heads or the call is the plain
-    route's. It falls back, and the event says so."""
-    assert [kda._gdn_heads_per_block(h, g) for h, g in (
-        (32, 2), (4, 1), (4, 4), (6, 1), (6, 3), (3, 1), (8, 8), (10, 5))
-    ] == [4, 4, 4, 2, 3, 3, None, None]
-    args, do = gdn_arguments(2, 64, 1, 8, raw=True)
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do)
-    took("chunked_jnp", before)
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-
-
-def test_a_gated_deltanet_layers_scan_under_the_strongest_decay(route):
-    """g = -20 a token through the layer's entry (A_log = log 20, softplus(
-    a + dt_bias) = 1): a chunk's cumulative gate reaches -1280 and the
-    table's exponents above the diagonal +1280, which are never taken (a
-    table factored as exp(G_i) exp(-G_j) would be inf x 0). The state is
-    forgotten between tokens, o_t = scale beta_t (q_t . k_t) v_t with q and
-    k of unit length, every gradient finite."""
-    args, do = gdn_arguments(1, 150, raw=True)
-    args.update(a=jnp.zeros_like(args["a"]),
-                a_log=jnp.full((4,), np.log(20.0), jnp.float32),
-                dt_bias=jnp.full((4,), np.log(np.e - 1), jnp.float32))
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do)
-    took(route, before)
-    for name, v in got.items():
-        assert bool(jnp.all(jnp.isfinite(v))), name
-    # the gate's gradients are of the order exp(-20) (KDA's test): held to
-    # zero, not to a share of themselves
-    zero = {"a": 1e-6, "dt_bias": 1e-4, "a_log": 1e-4}
-    for name, limit in zero.items():
-        assert float(jnp.max(jnp.abs(got[name] - want[name]))) < limit, name
-    for name, err in gdn_worst(got, want, args).items():
-        assert name in zero or err < F32_TOL, (name, err)
-    q, k = (to_value_heads(unit_heads(args[n], 2), 2, 4).reshape(
-        2, 150, 4, D) for n in "qk")
-    alone = (D ** -0.5 * args["beta"] * (q * k).sum(-1))[..., None] \
-        * args["v"].reshape(2, 150, 4, D)
-    np.testing.assert_allclose(got["o"], alone.reshape(2, 150, -1),
-                               atol=1e-6)
-
-
-def test_a_gated_deltanet_layers_keys_alike_are_solved_in_blocks(
-        monkeypatch, route):
-    """``test_keys_alike_are_solved_in_blocks`` for the body with one decay
-    a head: neighbouring keys alike in direction, beta 0.9, a weak decay.
-    The solve in blocks of 8, merged, is the one in use on both routes:
-    with one block of 64 (the Neumann product over the whole chunk) o is
-    wrong by more than its own size."""
-    args, do = gdn_arguments(5, 150, raw=True)
-    r = jax.random.split(jax.random.PRNGKey(105), 2)
-    k = jax.random.normal(r[0], (2, 1, 2, D)) \
-        + 0.5 * jax.random.normal(r[1], (2, 150, 2, D))
-    args.update(k=k.reshape(2, 150, 2 * D), beta=jnp.full((2, 150, 4), 0.9),
-                a_log=args["a_log"] + np.log(0.05))
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do)
-    took(route, before)
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-    monkeypatch.setattr(kda, "_SUB", 64)        # one block: the whole chunk
-    got, _ = gdn_both(args, do)
-    assert not gdn_worst({"o": got["o"]}, {"o": want["o"]}, {})["o"] < 1.0
-
-
-def test_a_gated_deltanet_layers_bf16_arguments(route):
-    """The model's call: bf16 q, k, v and ``a``; A_log, dt_bias and beta
-    float32. Products on bf16 operands, everything a gate touches float32:
-    3e-2 of the largest entry, as KDA's."""
-    args, do = gdn_arguments(3, 150, raw=True)
-    args = in_dtype(args, jnp.bfloat16)
-    before = kda.PATH_COUNTS.copy()
-    got, want = gdn_both(args, do)
-    took(route, before)
-    assert got["o"].dtype == jnp.bfloat16
-    for name, err in gdn_layer_worst(got, want, args).items():
-        assert err < 3e-2, (name, err)
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32_TOL),
-                                       (jnp.bfloat16, 3e-2)],
-                         ids=["float32", "bfloat16"])
-def test_the_head_decay_kernels_gradients_are_the_plain_routes(
-        dtype, tol, monkeypatch):
-    """ISSUE 53: o and the SEVEN gradients of ``gdn_gated_scan`` on the
-    kernel pair against ``jax.vjp`` of its plain route (``l2norm``, the
-    softplus, ``gated_delta_scan``), 3 chunks of a block of 4 value heads
-    over 2 key heads. dq and dk leave the backward kernel summed over a key
-    head's value heads, in q's dtype and shape; A_log's and dt_bias's as
-    partial sums a batch row, head and token of the chunk, held to what
-    they sum. With bf16 arguments o differs by a rounding of bf16 (the
-    kernels keep the normalised q and k and e^G (k S_0) in float32 where
-    the plain form rounds them first)."""
-    args, do = gdn_arguments(6, 192, raw=True)
-    args = in_dtype(args, dtype)
-    before = kda.PATH_COUNTS.copy()
-    got, _ = gdn_both(args, do)
-    took("kernel", before)
-    monkeypatch.setattr(kda, "_route", lambda *shape: "chunked_jnp")
-    before = kda.PATH_COUNTS.copy()
-    want, _ = gdn_both(args, do)
-    took("chunked_jnp", before)
-    for name, err in gdn_layer_worst(got, want, args).items():
-        assert err < (8e-3 if name == "o" and tol > 1e-3 else tol), (name, err)
-    for name in got:
-        assert got[name].shape == want[name].shape
-        assert got[name].dtype == (
-            jnp.float32 if name in ("a_log", "dt_bias", "beta") else dtype)
-
-
-def _traced_again():
-    """The pair's pure bodies are traced once a process (``jax.jit``): a
-    fault planted in what they call shows only to a fresh trace, and must
-    not outlive the test."""
-    kda._gdn_forward_of.clear_cache()
-    kda._gdn_backward_of.clear_cache()
-
-
-def _the_decay_table_dropped(monkeypatch):
-    """The value heads' tables exp(G_i - G_j) left off the shared scores:
-    every earlier token weighs as the newest."""
-    monkeypatch.setattr(kda, "_decay", lambda d: jnp.ones_like(d))
-    return ("o", "q", "k", "v", "a"), 1e-2
-
-
-def _dq_of_one_value_head(monkeypatch):
-    """dq and dk taken from the key head's LAST value head alone, not
-    summed over the pair: o and what belongs to a value head are right."""
-    monkeypatch.setattr(kda, "_summed",
-                        lambda parts, onto=None: parts[-1] if onto is None
-                        else onto + parts[-1])
-    return ("q", "k"), 1e-2
-
-
-@pytest.mark.parametrize("fault", ["table_dropped", "dq_of_one_value_head"])
-def test_a_wrong_head_decay_body_would_fail(fault, monkeypatch):
-    """What the limits of the kernel route are for: two faults planted in
-    the new body, each reads a thousand times over them."""
-    args, do = gdn_arguments(2, 150, raw=True)
-    plant = {"table_dropped": _the_decay_table_dropped,
-             "dq_of_one_value_head": _dq_of_one_value_head}[fault]
-    _traced_again()
-    try:
-        with monkeypatch.context() as m:
-            wrong, at_least = plant(m)
-            before = kda.PATH_COUNTS.copy()
-            got, want = gdn_both(args, do)
-            took("kernel", before)
-    finally:
-        _traced_again()
-    err = gdn_worst(got, want, args)
-    assert min(err[n] for n in wrong) > at_least > 10 * F32_TOL, err
-    right = set(err) - set(wrong) if fault == "dq_of_one_value_head" else ()
-    assert all(err[n] < F32_TOL for n in right), err
-    # and the body as it is, traced afresh, is right again
-    got, want = gdn_both(args, do)
-    for name, err in gdn_worst(got, want, args).items():
-        assert err < F32_TOL, (name, err)
-
-
-def test_a_gated_deltanet_scans_path_event(route):
-    """The facts of ``rtpu.ops.kda.path`` for a Gated DeltaNet layer's
-    call: ``decay`` (``head``: one a head, or ``channel``: KDA's),
-    ``key_heads`` (ISSUE 52) and ``body`` (ISSUE 53: ``head_decay``, the
-    program with the decay factored out of the scores, on both routes;
-    PR 52's kernel route said ``decay: head`` and ran ``channel_decay``);
-    on the kernel route four value heads a program, whole key heads."""
-    from ray_tpu.perf import recorder
-
-    args, _ = gdn_arguments(4, 150, raw=True)
-    jax.eval_shape(lambda *a: kda.gdn_gated_scan(*a, scale=1.0),
-                   *(args[n] for n in GDN_GATED))
-    data = [e["data"] for e in recorder.get_recorder().snapshot()
-            if e["kind"] == "rtpu.ops.kda.path"][-1]
-    facts = {"route": route, "chunk": 64, "tokens": 150, "padded_tokens": 42,
-             "heads": 4, "d_k": D, "d_v": D, "chunks": 3, "decay": "head",
-             "key_heads": 2, "body": "head_decay",
-             "prologue": "in_kernel" if route == "kernel" else "jnp"}
-    if route == "kernel":
-        facts["heads_per_block"] = 4
-    assert data == facts

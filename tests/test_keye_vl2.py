@@ -283,13 +283,13 @@ def test_a_row_too_long_for_the_backwards_accumulators_is_refused():
         (1, s, h, 128), (1, s, kv, 128), (1, s, kv, 128), (1, s, 16, 64),
         (1, s, 64), (1, s, 16))]
     need = sa._bwd_vmem(s, 1024, 1024, h // kv, 128, 2)
-    assert need > sa._VMEM_BYTES
+    assert need > sa.VMEM_BYTES
     with pytest.raises(ValueError, match=rf"sparse_attention at S={s}: the "
                        rf"backward keeps {need} bytes in VMEM"):
         jax.eval_shape(functools.partial(sa.sparse_attention, topk=2048),
                        *shapes)
     # the benchmark cell's row fits, and so does this one in smaller blocks
-    assert sa._bwd_vmem(16384, 1024, 1024, 8, 128, 2) < sa._VMEM_BYTES
+    assert sa._bwd_vmem(16384, 1024, 1024, 8, 128, 2) < sa.VMEM_BYTES
     out = jax.eval_shape(functools.partial(
         sa.sparse_attention, topk=2048, block_q=512, block_k=512), *shapes)
     assert out.shape == (1, s, h, 128)

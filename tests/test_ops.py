@@ -6,6 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import kernel_common
 from ray_tpu.ops import (apply_rope, cross_entropy_loss, flash_attention,
                          layernorm, mha_reference, ring_attention, rmsnorm,
                          rope_cache)
@@ -416,3 +417,35 @@ class TestLayers:
         loss = cross_entropy_loss(logits, labels)
         expected = -np.log(np.exp(2.0) / (np.exp(2.0) + 2.0))
         np.testing.assert_allclose(float(loss), expected, rtol=1e-5)
+
+
+class TestKernelCommon:
+    """ISSUE 61: what the kernel files share (``ops/kernel_common.py``)."""
+
+    @pytest.mark.parametrize("block,seq,want", [
+        (1024, 1024, 1024),     # the block divides
+        (1024, 768, 768),       # a sequence shorter than the block
+        (512, 768, 384),        # the largest 128-multiple that divides
+        (1024, 8320, 640),      # 65 tiles: 5 of them
+        (256, 128, 128)])       # never under a tile
+    def test_fit_block_is_the_largest_tile_multiple_that_divides(
+            self, block, seq, want):
+        got = kernel_common.fit_block(block, seq)
+        assert got == want
+        assert seq % got == 0 and got % kernel_common.LANES == 0
+
+    @pytest.mark.parametrize("tokens,added", [(150, 42), (128, 0)])
+    def test_pad_tokens_appends_zeros_to_whole_chunks(self, tokens, added):
+        """Every array along its SECOND axis, whatever its rank; a whole
+        number of chunks comes back as it went in."""
+        x = jnp.ones((2, tokens, 3, 4))
+        beta = jnp.ones((2, tokens))
+        (xp, bp), pad = kernel_common.pad_tokens((x, beta), 64)
+        assert pad == added
+        assert xp.shape == (2, tokens + added, 3, 4)
+        assert bp.shape == (2, tokens + added)
+        assert float(xp[:, tokens:].sum()) == 0.0
+        assert float(bp[:, tokens:].sum()) == 0.0
+        assert float(xp.sum()) == x.size and float(bp.sum()) == beta.size
+        if not added:
+            assert xp is x and bp is beta

@@ -85,10 +85,12 @@ class TestFlightRecorder:
         finally:
             rec.enabled = was
 
-    def test_record_cost_stays_micro(self):
+    def test_record_cost_stays_micro(self, machine_load):
         """The hot path is an attribute test + deque append. The bar is
         deliberately loose (loaded CI boxes) — it exists to catch an
-        accidental lock/IO/alloc regression, not to bench."""
+        accidental lock/IO/alloc regression, not to bench — and takes its
+        scale from the machine at that moment: with L runnable processes
+        a core this loop gets a share of one."""
         rec = FlightRecorder(capacity=1024, enabled=True)
         n = 20000
         t0 = time.perf_counter()
@@ -101,7 +103,7 @@ class TestFlightRecorder:
             rec.record("test.ev", "hot", None)
         per_off = (time.perf_counter() - t0) / n
         ncpu = os.cpu_count() or 1
-        bar_on = 50e-6 if ncpu >= 4 else 200e-6
+        bar_on = (50e-6 if ncpu >= 4 else 200e-6) * (1.0 + machine_load)
         assert per_on < bar_on, f"record() cost {per_on * 1e6:.2f}us"
         assert per_off < per_on, \
             (f"disabled path ({per_off * 1e6:.2f}us) should be cheaper "

@@ -80,7 +80,11 @@ def _ref_loss(model, params, tokens):
         logits, targets[..., None], -1)[..., 0]), logits
 
 
-@pytest.fixture(scope="module", params=sorted(CUTS))
+# the whole pattern is ``slow`` in every case that asks for it (its programs
+# take 150 s to build): it compares the six kinds of layer the two cuts compare
+@pytest.fixture(scope="module", params=[
+    pytest.param(cut, marks=pytest.mark.slow if "32" in cut else ())
+    for cut in sorted(CUTS)])
 def run(request):
     """One cut on one seeded batch: the model, its parameters and tokens,
     the program's jitted loss-and-gradients (``step``) with what it gave,

@@ -88,23 +88,33 @@ def test_stop_criteria_metric(rt):
 
 
 def test_asha_stops_bad_trials_early(rt):
+    """ASHA judges a trial against those that reached the rung BEFORE it,
+    and the first to reach a rung always goes on. A trial here reports
+    twenty times with nothing between two reports, so on a loaded machine
+    the trials reach every rung one after the other, in the order they
+    were started. Started best first, each later trial finds the rung's
+    cutoff above it, whatever the machine does; started worst first (as
+    this test did), each was the best so far and NONE was stopped."""
     def train_fn(config):
         for i in range(20):
-            tune.report(score=config["q"] * (i + 1))
+            tune.report(score=config["q"] * (i + 1), q=config["q"])
 
+    asha = ASHAScheduler(grace_period=2, reduction_factor=2, max_t=20)
     results = Tuner(
         train_fn,
-        param_space={"q": tune.grid_search([1, 2, 3, 4, 5, 6, 7, 8])},
+        param_space={"q": tune.grid_search([8, 7, 6, 5, 4, 3, 2, 1])},
         tune_config=TuneConfig(
             metric="score", mode="max", max_concurrent_trials=8,
-            scheduler=ASHAScheduler(grace_period=2, reduction_factor=2,
-                                    max_t=20)),
+            scheduler=asha),
     ).fit()
     assert len(results) == 8
-    iters = [r.metrics.get("training_iteration", 0) for r in results]
-    # bad trials got cut before max_t; at least one survivor went deep
-    assert min(iters) < 20
-    assert max(iters) >= 10
+    iters = {r.metrics["q"]: r.metrics["training_iteration"]
+             for r in results}
+    # every trial was judged at the first rung; bad trials got cut before
+    # max_t; the best is in the top half of every rung it reaches
+    assert len(asha._rungs[2]) == 8
+    assert min(iters.values()) < 20
+    assert iters[8] == 20
 
 
 def test_pbt_exploit_and_explore(rt):
