@@ -23,6 +23,19 @@ there while the grid does not. Rows move by gathers in both directions
 a scatter; they are made for the whole buffer, so that only the grouped
 product's time follows the routing.
 
+A padding row of the buffer (the ragged end of an expert's last tile) is
+NOT zeros: it holds whatever the gather's clamped index brought, a copy of
+a real token's row, because a select over [rows, D] is a pass of its own.
+The zero of a padding row is its WEIGHT, one scalar (``pairs_to_rows``),
+and every product over the buffer carries it (``_mlp``'s ``row_weight``):
+the row's hidden activation is finite x 0 = 0, so it adds 0 to ``dW_down``
+(h^T dy), its hidden cotangent is again times 0, so it adds 0 to
+``dW_gate`` and ``dW_up`` (x^T d) whatever x holds and its own cotangent is
+0; its output row and that cotangent are never read (``rows_to_tokens``,
+forward and as ``tokens_to_rows``' vjp, gathers held pairs' rows alone),
+and ``pairs_to_rows``' vjp drops its weight's gradient. The sums gain
+exact zeros where they gained exact zeros.
+
 A token's k choices are k DIFFERENT experts, so a token holds at most
 min(k, held) rows; the pair domain is that, the buffer already was. Where
 ``top_k > experts_held`` (two static arguments of the call) a token's held
@@ -64,7 +77,7 @@ KERNEL_NAMES = {
 
 # Rows of a tile. An expert's rows are padded to whole tiles (at least
 # one), so a tile multiplies one expert's weights: up to ROW_TILE - 1
-# rows of zeros an expert are the price.
+# padding rows an expert are the price.
 ROW_TILE = 256
 
 # Traced calls of the layer by (experts held, experts in all).
@@ -218,17 +231,17 @@ grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 def _rows_of(values, at, pairs_each: int):
     """values [n, *width], each standing for ``pairs_each`` pairs in a
     row (a token's k choices, or one pair) -> [rows, *width]: each row
-    its pair's value, zeros where the row is padding. Every row of the
-    buffer is made, used or not: made only as far as the used tiles
-    reach (a sixteenth of the buffer at a time, the rest skipped by
-    predicate) the step was 2.9 % shorter, and its length followed the
-    routing: six seeds then spread by 0.5 % where they spread by 0.15 %
-    so (PERF.md, PR 33)."""
+    its pair's value AS GATHERED. A padding row (``row_pair`` past the
+    last pair) holds a copy of the last value, not zeros: a select over
+    the gathered array is a pass of its own over [rows, D], three a layer
+    (PERF.md, PR 62), so the zero of a padding row is kept where it is one
+    scalar a row, in ``pairs_to_rows``. Every row of the buffer is made,
+    used or not: made only as far as the used tiles reach (a sixteenth of
+    the buffer at a time, the rest skipped by predicate) the step was
+    2.9 % shorter, and its length followed the routing: six seeds then
+    spread by 0.5 % where they spread by 0.15 % so (PERF.md, PR 33)."""
     pairs = values.shape[0] * pairs_each
-    pair = at["row_pair"]
-    filled = (pair < pairs).reshape((-1,) + (1,) * (values.ndim - 1))
-    return jnp.where(filled,
-                     values[jnp.minimum(pair, pairs - 1) // pairs_each], 0)
+    return values[jnp.minimum(at["row_pair"], pairs - 1) // pairs_each]
 
 
 def _tokens_to_rows_impl(x, at):
@@ -241,17 +254,27 @@ def _rows_to_tokens_impl(y, at):
     return jnp.sum(picked.astype(jnp.float32), axis=1).astype(y.dtype)
 
 
+def _pairs_to_rows_impl(w, at):
+    flat = w.reshape(-1)
+    return jnp.where(at["row_pair"] < flat.shape[0], _rows_of(flat, at, 1), 0)
+
+
 @jax.custom_vjp
 def tokens_to_rows(x, at):
-    """x [T, D] -> the buffer [rows, D]: each row its pair's token, zeros
-    where the row is padding."""
+    """x [T, D] -> the buffer [rows, D]: each row its pair's token; a
+    padding row holds a copy of the last token's row, which nothing reads:
+    ``pairs_to_rows`` gives the row the weight 0, so its hidden activation
+    and with it its share of every weight gradient and its own cotangent
+    are 0 (``_mlp``), and ``rows_to_tokens`` gathers the rows of held
+    pairs alone, in the forward and (as this function's vjp) backward."""
     return _tokens_to_rows_impl(x, at)
 
 
 @jax.custom_vjp
 def rows_to_tokens(y, at):
     """The buffer y [rows, D] -> [T, D]: each token the sum (in f32) of
-    the rows of its held pairs."""
+    the rows of its held pairs. A pair that is not held reads row 0, which
+    is real data: it is masked here, over the pair domain."""
     return _rows_to_tokens_impl(y, at)
 
 
@@ -265,12 +288,13 @@ rows_to_tokens.defvjp(
 
 @jax.custom_vjp
 def pairs_to_rows(w, at):
-    """A value a pair, w [T, k] -> a value a row [rows] (0 for padding)."""
-    return _rows_of(w.reshape(-1), at, 1)
+    """A value a pair, w [T, k] -> a value a row [rows], 0 for padding:
+    THE zero of a padding row (``tokens_to_rows``)."""
+    return _pairs_to_rows_impl(w, at)
 
 
 pairs_to_rows.defvjp(
-    lambda w, at: (_rows_of(w.reshape(-1), at, 1), at),
+    lambda w, at: (_pairs_to_rows_impl(w, at), at),
     lambda at, g: (jnp.where(at["pair_held"], g[at["pair_row"]], 0.0), None))
 
 
@@ -403,7 +427,9 @@ def compact_held(weights, chosen, experts_held: int, expert_offset: int):
 
 def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
     """The gated SiLU MLP; ``row_weight`` [rows] (f32) scales a row's
-    hidden activation, i.e. its output."""
+    hidden activation, i.e. its output. A call over the row buffer MUST
+    carry it: its 0 is all that keeps a padding row (a copy of a real
+    token, ``tokens_to_rows``) out of the weights' gradients."""
     h = jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up)
     if row_weight is not None:
         h = (h.astype(jnp.float32) * row_weight[:, None]).astype(h.dtype)
@@ -412,7 +438,8 @@ def _gated(x, w_gate, w_up, w_down, matmul, row_weight=None):
 
 def _relu2(x, w_up, w_down, matmul, row_weight=None):
     """The squared-ReLU MLP of two matrices, ``W_down relu(x W_up)^2``, the
-    square (and ``row_weight``, as in ``_gated``) in f32."""
+    square (and ``row_weight``, as in ``_gated``: a call over the row
+    buffer MUST carry it) in f32."""
     h = jax.nn.relu(matmul(x, w_up))
     hf = jnp.square(h.astype(jnp.float32))
     if row_weight is not None:
@@ -422,7 +449,12 @@ def _relu2(x, w_up, w_down, matmul, row_weight=None):
 
 def _mlp(expert: str, x, p, prefix: str, matmul, row_weight=None):
     """The MLP of kind ``expert`` over ``p``'s ``<prefix>_gate`` (a gated
-    one alone), ``<prefix>_up`` and ``<prefix>_down`` in x's dtype."""
+    one alone), ``<prefix>_up`` and ``<prefix>_down`` in x's dtype. Over
+    the row buffer (``matmul`` the grouped product) ``row_weight`` MUST be
+    ``pairs_to_rows``' weights: the buffer's padding rows are not zeros,
+    and the 0 of their weight on the hidden activation is what makes them
+    add nothing to any gradient (module docstring). Over the tokens
+    themselves (the shared expert) there is no padding and none is given."""
     w = lambda name: p[f"{prefix}_{name}"].astype(x.dtype)    # noqa: E731
     if expert == "swiglu":
         return _gated(x, w("gate"), w("up"), w("down"), matmul, row_weight)

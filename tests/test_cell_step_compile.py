@@ -149,8 +149,10 @@ def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
     step by 1.73 MB). The room is gone: the compiler makes instructions
     again on its own to fit (``scripts/train_step_hlo.py --census``: 17
     before ISSUE 47, mixed streams and the logits once; 9 and 10.58 GB of
-    temporaries with the mixings as kernels), which is what a change that
-    needs more memory would turn into a refusal here and not on the chip.
+    temporaries with the mixings as kernels; 3 and 9.80 GB since ISSUE 62,
+    the expert layer's selects over its row buffer gone), which is what a
+    change that needs more memory would turn into a refusal here and not on
+    the chip.
     The latent kernels stand once a layer and direction, the mixings'
     backward kernels once a sublayer of the dense layer and of the scanned
     body (ISSUE 47), and no stream is laid out [tokens, 4, d] (4 rows
@@ -230,10 +232,14 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
     alone and the attention layer its kernels' output and row statistics
     (nothing kept in the attention layer reads 9.7677 against 9.7679 GB; a
     Gated DeltaNet layer keeping ``kda_out`` and ``kda_states`` is refused,
-    "Used 16.80G of 15.75G hbm"); the compiler makes 3 instructions again on
+    "Used 16.80G of 15.75G hbm"); the compiler made 3 instructions again on
     its own (4 until ISSUE 55: the convolution's forward a third time, for
-    autodiff's backward). Under the scope ``scan`` no float32 [2, 8192,
-    4096] array is produced: the kernels make the norms and the gate from
+    autodiff's backward). Since ISSUE 62 (the expert layer writes no zeros
+    over its row buffer: three selects over [172 032, 2048] fewer a layer)
+    it makes NONE again and holds 9.92 GB of temporaries: what it made again
+    (2.47 T operations of ``mixer`` products, ``--census``) it now keeps.
+    Under the scope ``scan`` no float32 [2, 8192, 4096] array is produced:
+    the kernels make the norms and the gate from
     what the convolution and ``W_ba`` left. The one attention layer's two
     one-part flash kernels stand once each; the delta rule's forward kernel
     (ISSUE 53: ``gdn_chunk_fwd``, the body for one decay a head; KDA's is
@@ -241,7 +247,7 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
     and the rematerialised layer) and its backward once."""
     compiled = tool.compile_step("qwen3next_train_s8192", one_chip)
     assert 7.5e9 < fits(compiled) < 7.6e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 9.6e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 10.0e9
     text = compiled.as_text()
     assert "s32[2,8192]" in text            # the cell's batch, not another
     assert tool.compiler_remat(text) <= 8

@@ -180,7 +180,9 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     4096, 8 of 512 squared-ReLU experts of 1024 x 2688 in a latent of 1024,
     a shared expert of 5376, top 22: a token holds at most 8 rows, so the
     pair domain is 131 072 and no array of the program is sized by the
-    360 448 (token, choice) pairs."""
+    360 448 (token, choice) pairs. ISSUE 62: at all three, no select with a
+    [rows, width] result: the buffer's padding rows are not written as
+    zeros."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
@@ -208,6 +210,16 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     if top_k > held:
         assert re.search(r"\[%d[,\]]" % (t * held), text)
         assert not re.search(r"\[%d[,\]]" % (t * top_k), text)
+    # ISSUE 62: a padding row's zero is its weight. No select writes zeros
+    # over the buffer (PR 61's text holds two, ``select(filled, gathered,
+    # 0)`` forward and backward; a step that makes the forward again three):
+    # the scalar one a row and the one over the pair domain are all
+    rows = el.buffer_rows(t, top_k, held)
+    selects = lambda shape: re.findall(  # noqa: E731
+        r"= \w+\[%s\]\S* select\(" % shape, text)
+    assert not selects("%d,%d" % (rows, width))
+    assert selects("%d" % rows)
+    assert selects("%d,%d,%d" % (t, min(top_k, held), width))
 
 
 def test_hyper_connection_pair_at_the_benchmark_cells_shape(

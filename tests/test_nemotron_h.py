@@ -508,7 +508,7 @@ def _rows_by_hand(x, p, *, top_k, held, offset, scale, compact):
 def _uncompacted(x, p, *, expert, **kw):
     """``held_expert_layer`` composed by hand on the [T, k] pair domain, as
     every layer was before ISSUE 57 -> (output, held rows, the row buffer,
-    ``n_used``)."""
+    ``n_used``, ``row_pair``)."""
     at, weights, _, buf = _rows_by_hand(x, p, compact=False, **kw)
     y = el._mlp(expert, buf, p, "e",
                 lambda a, w: el.grouped_matmul(a, w, at["tile_expert"],
@@ -518,14 +518,14 @@ def _uncompacted(x, p, *, expert, **kw):
     if "w_fc2" in p:
         routed = jnp.dot(routed, p["w_fc2"].astype(x.dtype))
     return (el._mlp(expert, x, p, "s", jnp.dot) + routed, at["held_rows"],
-            buf, at["n_used"])
+            buf, at["n_used"], at["row_pair"])
 
 
 def _compacted_buffer(x, p, **kw):
-    """The row buffer and ``n_used`` of the compacted path and the slots
-    each token fills."""
+    """The row buffer, ``n_used`` and ``row_pair`` of the compacted path and
+    the slots each token fills."""
     at, _, chosen, buf = _rows_by_hand(x, p, compact=True, **kw)
-    return buf, at["n_used"], jnp.sum(chosen >= 0, axis=1)
+    return buf, at["n_used"], at["row_pair"], jnp.sum(chosen >= 0, axis=1)
 
 
 @pytest.mark.parametrize("top_k,held,offset,expert,latent,routing", [
@@ -536,11 +536,12 @@ def _compacted_buffer(x, p, **kw):
 def test_the_compacted_pair_domain_is_the_layer_it_was(
         top_k, held, offset, expert, latent, routing):
     """ISSUE 57: with a token's held choices compacted to ``held`` slots the
-    row buffer and the held rows EQUAL the [T, k] path's, and the output and
-    every gradient equal it in float32 (the stable sort orders an expert's
-    rows by token either way). ``every`` / ``none``: the selection bias makes
-    every token choose every held expert (all slots full, the buffer full: no
-    pair dropped) or none (all slots empty, one empty tile an expert)."""
+    row buffer's filled rows and the held rows EQUAL the [T, k] path's, and
+    the output and every gradient equal it in float32 (the stable sort orders
+    an expert's rows by token either way). ``every`` / ``none``: the selection
+    bias makes every token choose every held expert (all slots full, the
+    buffer full: no pair dropped) or none (all slots empty, one empty tile an
+    expert)."""
     t, d, e = PAIR_TOKENS, 32, 32 if top_k > 5 else 8
     x, p = _layer_inputs(expert, latent, t, d, 16, 24, e, held)
     if routing != "drawn":
@@ -558,28 +559,34 @@ def test_the_compacted_pair_domain_is_the_layer_it_was(
         return jnp.sum(y * cot), (y, rows)
 
     def before(x, p):
-        y, rows, buf, n_used = _uncompacted(x, p, expert=expert, **kw)
-        return jnp.sum(y * cot), (y, rows, buf, n_used)
+        y, *rest = _uncompacted(x, p, expert=expert, **kw)
+        return jnp.sum(y * cot), (y, *rest)
 
     with jax.default_matmul_precision("highest"):
         (_, (y, rows)), g = jax.jit(jax.value_and_grad(
             program, (0, 1), has_aux=True))(x, p)
-        (_, (want, want_rows, want_buf, want_used)), gw = jax.jit(
-            jax.value_and_grad(before, (0, 1), has_aux=True))(x, p)
-        buf, n_used, full = jax.jit(
+        (_, (want, want_rows, want_buf, want_used, want_row_pair)), gw = (
+            jax.jit(jax.value_and_grad(before, (0, 1), has_aux=True))(x, p))
+        buf, n_used, row_pair, full = jax.jit(
             lambda x, p: _compacted_buffer(x, p, **kw))(x, p)
-    np.testing.assert_array_equal(buf, want_buf)
+    # the rows that hold a pair (ISSUE 62: a padding row holds a copy of a
+    # token's row, not zeros; tests/test_expert_layer_padding.py)
+    filled, want_filled = (np.asarray(pair) < n for pair, n in (
+        (row_pair, t * held), (want_row_pair, t * top_k)))
+    np.testing.assert_array_equal(filled, want_filled)
+    np.testing.assert_array_equal(np.asarray(buf)[filled],
+                                  np.asarray(want_buf)[filled])
     assert int(n_used[0]) == int(want_used[0])
-    assert int(rows) == int(want_rows) == int(full.sum())
+    assert int(rows) == int(want_rows) == int(full.sum()) == filled.sum()
     if routing == "drawn":
-        assert 0 < int(rows) < t * held and np.asarray(buf).any()
+        assert 0 < int(rows) < t * held and np.asarray(buf)[filled].any()
         assert held >= int(full.max()) > int(full.min())
     elif routing == "every":
         assert int(rows) == t * held and (np.asarray(full) == held).all()
         assert int(n_used[0]) * PAIR_TILE == t * held
     else:
         assert int(rows) == 0 and not np.asarray(full).any()
-        assert int(n_used[0]) == held and not np.asarray(buf).any()
+        assert int(n_used[0]) == held
     # a token's rows summed over 8 slots or over 22 pairs, 14 of them zero:
     # the same terms in another tree, read 1.8e-7 of the largest entry apart
     # at most (one float32 step) at top 22 and 0 at top 9 and top 5
