@@ -21,6 +21,7 @@ from .kimi_linear import KimiLinear, KimiLinearConfig
 from .qwen3_next import Qwen3Next, Qwen3NextConfig
 from .nemotron_h import NemotronH, NemotronHConfig
 from .keye_vl2 import KeyeVL2, KeyeVL2Config
+from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
@@ -28,5 +29,5 @@ __all__ = [
     "DeepseekV3", "DeepseekV3Config", "GraniteHybrid", "GraniteHybridConfig",
     "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
     "Qwen3Next", "Qwen3NextConfig", "NemotronH", "NemotronHConfig",
-    "KeyeVL2", "KeyeVL2Config",
+    "KeyeVL2", "KeyeVL2Config", "Lfm2Moe", "Lfm2MoeConfig",
 ]

@@ -33,6 +33,10 @@ legend); for a TPU-native framework the hot ops are first-party:
   iterations (mHC); a Pallas kernel pair with a backward of its own, the
   plain jnp form for shapes its tile cannot take; every coefficient
   tokens-minor.
+- short_conv: a double-gated causal convolution of a few taps as a token
+  mixer of its own, ``c * conv(b * x)``: a Pallas kernel pair that reads
+  b, c, x (and dy) once a block with a halo of rows, the backward written
+  by hand, and the plain route beside it.
 - layers: rmsnorm/layernorm/gelu/rope (plain and YaRN)/cross-entropy,
   the causal depthwise convolution, the gated norms (the gate before the
   norm, or after it under sigmoid or SiLU) and a head's l2 norm
@@ -55,6 +59,7 @@ from .ssd_scan import ssd_scan
 from .kda_scan import (gated_delta_scan, gdn_gated_scan, kda_gated_scan,
                        kda_scan)
 from .selective_scan import selective_scan
+from .short_conv import in_proj_short_conv
 from .hyper_connection import hc_coefficients, hc_mix, hc_post, hc_pre
 from .paged_attention import (paged_attention_decode,
                               paged_attention_prefill, paged_gather_kv,
@@ -67,6 +72,7 @@ __all__ = [
     "gated_rmsnorm", "l2norm",
     "rmsnorm_then_gate", "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan",
     "kda_gated_scan", "gated_delta_scan", "gdn_gated_scan", "selective_scan",
+    "in_proj_short_conv",
     "hc_coefficients", "hc_pre", "hc_post", "hc_mix",
     "paged_attention_decode", "paged_attention_prefill",
     "paged_gather_kv", "paged_write_prefill",

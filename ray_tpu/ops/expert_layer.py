@@ -45,9 +45,10 @@ are sized by tokens x slots; where it is not, the compaction is not
 traced. The trace-time event ``rtpu.ops.expert_layer`` / ``held`` says
 which (``pair_slots`` beside ``top_k``).
 
-``balance_term`` is a softmax router's load-balancing term, for a model
-that adds it to its loss: it reads the router alone (every chip holds it
-whole), so a share states it as the uncut layer does.
+``balance_term`` is a router's load-balancing term (a softmax router's or,
+``score="sigmoid"``, a sigmoid router's with its selection bias), for a
+model that adds it to its loss: it reads the router alone (every chip holds
+it whole), so a share states it as the uncut layer does.
 
 Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
 ``router`` (scores, top-k, the sort, the gather into the buffer and the
@@ -334,20 +335,34 @@ def route(x, w_router, bias, *, top_k: int, routed_scale: float,
     return s / jnp.sum(s, axis=1, keepdims=True) * routed_scale, chosen
 
 
-def balance_term(x, w_router, *, top_k: int, groups: int = 1):
-    """The load-balancing term of a ``softmax`` router (Switch Transformer,
-    eq. 4 to 6, with a token's k choices counted as GShard counts them),
-    a sequence at a time: E sum_e f_e P_e, f_e the share of the sequence's
-    (token, choice) pairs that name expert e (a count: no gradient), P_e
-    the sequence's mean of p_e = softmax(x W)_e in f32. 1 under a level
-    router, E / k where every token makes the same k choices with
-    certainty. The scores and the top k are ``route``'s own expressions on
-    the same operands, so XLA makes them once. x [T, D], the rows of
-    ``groups`` sequences one after another -> [groups] f32."""
+def balance_term(x, w_router, *, top_k: int, groups: int = 1,
+                 score: str = "softmax", bias=None):
+    """A router's load-balancing term, a sequence at a time: E sum_e f_e
+    P_e, f_e the share of the sequence's (token, choice) pairs that name
+    expert e (a count: no gradient), P_e the sequence's mean of a token's
+    share p_e of the scores, in f32. 1 under a level router, E / k times
+    the chosen experts' mean share where every token makes the same k
+    choices. ``softmax``: Switch Transformer's (eq. 4 to 6, with a token's
+    k choices counted as GShard counts them), p = softmax(x W).
+    ``sigmoid``: DeepSeek-V3's sequence-wise term (arXiv:2412.19437, eq. 17
+    to 20), the form stated for a sigmoid router with a selection bias: the
+    k choices are the top k of s + ``bias`` (``route``'s own), p_e = s_e /
+    sum_j s_j over ALL experts, s = sigmoid(x W). The scores and the top k
+    are ``route``'s own expressions on the same operands, so XLA makes them
+    once. x [T, D], the rows of ``groups`` sequences one after another ->
+    [groups] f32."""
     logits = jnp.dot(x, w_router.astype(x.dtype),
                      preferred_element_type=jnp.float32)
-    scores = jax.nn.softmax(logits, axis=-1)
-    _, chosen = jax.lax.top_k(scores, top_k)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, top_k)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        scores = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"score is sigmoid or softmax, got {score!r}")
     e = scores.shape[1]
     named = jnp.sum(chosen[:, :, None] == jnp.arange(e)[None, None, :],
                     axis=1, dtype=jnp.float32)                 # [T, E]

@@ -262,3 +262,40 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
     assert count("grouped_matmul") >= 6 and count("grouped_matmul_dw") >= 6
     assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,8192]")
+
+
+# 60 s alone; beside five other workers it can pass the default 180 s
+@pytest.mark.time_limit(480)
+def test_lfm2moe_train_step_keeps_its_room(one_chip, compiled_kernels, tool):
+    """ISSUE 64: lfm2moe_train_s8192's own train step (the harness's
+    ``make_train_step``, the cell's configuration, optimizer, batch 2 of
+    8192, parameters and optimizer state donated; the model's ``loss``
+    holds the routers' balancing term) for the described v5e: 507.8 M
+    parameters at 12 B as arguments (6.09 GB) and 7.2 GB of temporaries
+    (they overlap the donated state), the compiler making nothing again on
+    its own. The convolution's forward kernel stands four times (the dense
+    layer and the scanned run of three, each in the forward sweep and in
+    the rematerialised layer: a conv layer keeps its input alone) and its
+    backward twice, all six under the scope ``conv``, where nothing else
+    stands that makes an array of the batch: the kernels' operand is W_in's
+    one output. The ONE attention layer's flash kernels stand once each
+    (its output and row statistics are kept)."""
+    compiled = tool.compile_step("lfm2moe_train_s8192", one_chip)
+    assert 6.0e9 < fits(compiled) < 6.2e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
+    text = compiled.as_text()
+    assert "s32[2,8192]" in text            # the cell's batch, not another
+    assert tool.compiler_remat(text) <= 4
+    count = _kernel_count(text)
+    assert count("flash_fwd") == 1          # kept: not run again
+    assert count("flash_bwd_dq") == 1 and count("flash_bwd_dkv") == 1
+    assert count("short_conv_fwd") == 4 and count("short_conv_bwd") == 2
+    for line in text.splitlines():          # all six under the scope
+        if re.match(r"\s*%?short_conv_(fwd|bwd)(\.\d+)? = ", line):
+            assert re.search(r'op_name="[^"]*[/(]conv[/)]', line), line[:200]
+    made = [line.split(" = ")[0].strip() for line in _unfused_under(
+        text, "conv") if re.search(r" = \(?\w+\[2,8192,", line)
+        and " get-tuple-element(" not in line]
+    assert all(re.match(r"%?short_conv_(fwd|bwd)(\.\d+)?$", m)
+               for m in made), made
+    assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 6

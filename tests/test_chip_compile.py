@@ -337,6 +337,56 @@ def test_conv_silu_backward_is_two_fusions_and_one_array(one_chip, shape,
     assert fusions == 3 and temp >= 3.9 * array_bytes
 
 
+def test_gated_short_conv_at_the_benchmark_cells_shape(one_chip,
+                                                       compiled_kernels):
+    """ISSUE 64: lfm2moe_train_s8192's conv operator (norm, W_in, the gated
+    3-tap convolution, W_out) at the cell's batch, 2 rows of 8192 at width
+    2048, forward and backward, for the described v5e: the two kernels by
+    name, once each (a bare grad keeps nothing, so the forward's output is
+    dead and its kernel with it: the backward makes z and the taps again
+    from b, c, x), blocks with a halo of 16 rows which only the chip's
+    compiler checks. The kernels' operand is W_in's ONE output and the
+    backward's result the ONE cotangent W_in's backward reads: no chunk
+    [2, 8192, 2048] is copied out or padded back under the scope ``conv``,
+    nothing stands there but the two calls, and the taps' gradient is a
+    [3, 2048] float32 output of the backward call."""
+    from ray_tpu.models import Lfm2Moe, Lfm2MoeConfig
+
+    sc = importlib.import_module("ray_tpu.ops.short_conv")
+    model = Lfm2Moe(Lfm2MoeConfig.lfm2_8b_a1b(
+        layer_types=("conv",), num_dense_layers=1, experts_held=8,
+        vocab_size=16384, max_seq=8192))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    lp = {n.split(".", 2)[2]: jax.ShapeDtypeStruct(
+        v.shape[1:], v.dtype, sharding=one_chip)
+        for n, v in shapes.items() if n.startswith("0.")
+        and n.split(".")[-1] in ("norm", "w_in", "conv_w", "w_out")}
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(x, lp):
+        return model._conv_operator(x, lp).astype(jnp.float32).sum()
+
+    before = sc.PATH_COUNTS["kernel"]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, lp).compile().as_text()
+    assert sc.PATH_COUNTS["kernel"] == before + 1
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.sub(r"^%|\.\d+$", "", c) for c in calls) == \
+        ["short_conv_bwd", "short_conv_fwd"], calls
+    bwd = [line for line in text.splitlines()
+           if re.match(r"\s*%?short_conv_bwd", line)][0]
+    assert "f32[3,2048]" in bwd.split(" custom-call(")[0]
+    assert "bf16[2,8192,6144]" in bwd.split(" custom-call(")[0]
+    under_conv = [line.split(" = ")[0].strip() for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*[/(]conv[/)]', line)
+                  and re.search(r" = \(?\w+\[2,8192,", line)
+                  and " get-tuple-element(" not in line]
+    assert sorted(re.sub(r"^%|\.\d+$", "", c) for c in under_conv) == \
+        ["short_conv_bwd", "short_conv_fwd"], under_conv
+
+
 def test_selective_scan_at_the_benchmark_cells_shape(one_chip,
                                                      compiled_kernels):
     """ISSUE 43: phi4flash_train_s8192's Mamba-1 scan, B=1, S=8192, 5120
@@ -719,6 +769,14 @@ def _small_hyper_connection(sd):
         tuple(sd((128, 128)) for _ in range(4)), p]
 
 
+def _small_short_conv(sd):
+    # squared: the forward keeps nothing for the backward, so a loss that
+    # is linear in its output would leave the forward kernel dead
+    return (lambda bcx, w: jnp.square(
+        _ops("short_conv").in_proj_short_conv(bcx, w))), \
+        [sd((1, 64, 384), jnp.bfloat16), sd((3, 128))]
+
+
 def _small_kda(sd):
     return (lambda *a: _ops("kda_scan").kda_scan(*a, scale=1.0)), \
         [sd((1, 128, 128), jnp.bfloat16)] * 3 + [sd((1, 128, 128)),
@@ -731,7 +789,8 @@ KERNEL_FILES = {
     "flash_attention": _small_flash, "expert_layer": _small_expert_layer,
     "sparse_attention": _small_sparse, "ssd_scan": _small_ssd,
     "selective_scan": _small_selective,
-    "hyper_connection": _small_hyper_connection, "kda_scan": _small_kda}
+    "hyper_connection": _small_hyper_connection, "kda_scan": _small_kda,
+    "short_conv": _small_short_conv}
 
 
 @pytest.mark.parametrize("module", sorted(KERNEL_FILES))

@@ -14,7 +14,8 @@ import pytest
 from ray_tpu.models import (DeepseekV3, DeepseekV3Config, GPT, GPTConfig,
                             GraniteHybrid, GraniteHybridConfig, KeyeVL2,
                             KeyeVL2Config, KimiLinear, KimiLinearConfig,
-                            Llama, LlamaConfig, NemotronH, NemotronHConfig,
+                            Lfm2Moe, Lfm2MoeConfig, Llama, LlamaConfig,
+                            NemotronH, NemotronHConfig,
                             Qwen3Next, Qwen3NextConfig, SambaY, SambaYConfig)
 import importlib
 
@@ -25,6 +26,7 @@ sel = importlib.import_module("ray_tpu.ops.selective_scan")
 kda = importlib.import_module("ray_tpu.ops.kda_scan")
 hc = importlib.import_module("ray_tpu.ops.hyper_connection")
 sa = importlib.import_module("ray_tpu.ops.sparse_attention")
+sc = importlib.import_module("ray_tpu.ops.short_conv")
 from ray_tpu.serve.llm import EngineConfig, LLMEngine, build_model
 
 PROGRAMS = ("_decode", "_prefill", "_extend", "_cow")
@@ -491,6 +493,75 @@ def test_the_indexer_and_the_selection_have_scopes_and_no_backward(
     assert not [n for n in mine if "transpose(" in n], scope
 
 
+@pytest.fixture(scope="module")
+def conv_stack():
+    """An LFM2-MoE shaped loss with its balancing term lowered (forward and
+    backward) at S 128 -> (text, events)."""
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = Lfm2Moe(Lfm2MoeConfig.tiny(experts_held=2, expert_offset=4,
+                                   router_aux_coef=0.001))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    return text, events
+
+
+def test_a_gated_convolution_stack_leaves_its_events(conv_stack):
+    """ISSUE 64: what an LFM2-MoE shaped loss leaves at trace time.
+    ``rtpu.ops.short_conv`` (beside ``rtpu.ops.conv``'s), once a traced
+    call: tokens, channels, taps and the route, ``kernel`` here;
+    ``rtpu.models.lfm2_moe.share``: the experts and vocabulary rows held
+    and the layers' kinds; ``rtpu.ops.expert_layer``: a sigmoid router
+    without a shared expert; ``rtpu.models.stack.runs``: three runs, the
+    attention layer alone keeping its kernels' output and statistics."""
+    _, events = conv_stack
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    conv = last("rtpu.ops.short_conv")
+    assert conv["label"] == "kernel" and conv["data"] == {
+        "route": "kernel", "tokens": 256, "channels": 128, "taps": 3}
+    share = last("rtpu.models.lfm2_moe.share")
+    assert share["label"] == "held" and share["data"] == {
+        "experts": [2, 8], "expert_offset": 4, "vocab_rows": 512,
+        "kinds": ["conv_mlp", "attn_moe", "conv_moe", "conv_moe"]}
+    assert last("rtpu.ops.expert_layer")["data"] == {
+        "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
+        "pair_slots": 2, "tokens": 256,
+        "row_buffer": el.buffer_rows(256, 3, 2), "row_tile": el.ROW_TILE,
+        "score": "sigmoid", "shared": False, "shared_gate": False,
+        "expert": "swiglu", "latent": 0}
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "lfm2_moe"][-1]
+    assert runs["data"]["runs"] == [["conv_mlp", 1], ["attn_moe", 1],
+                                    ["conv_moe", 2]]
+    assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"], []]
+    assert runs["data"]["side_state_bytes"] == 0
+
+
+@pytest.mark.parametrize("key,name", [("fwd", "short_conv_fwd"),
+                                      ("bwd", "short_conv_bwd")])
+def test_short_conv_kernel_names_are_pinned(conv_stack, key, name):
+    """ISSUE 64: ``short_conv_roofline`` finds its kernels by these, and
+    they stand under the scope ``conv`` (``train_conv_ms`` reads it): the
+    two gates and the taps are inside them, so nothing else does."""
+    text, _ = conv_stack
+    assert sc.KERNEL_NAMES[key] == name
+    assert sorted(sc.KERNEL_NAMES.values()) == ["short_conv_bwd",
+                                                "short_conv_fwd"]
+    assert re.search(r"conv/[^\n\"]*" + name + r"[/\")]", text), name
+    calls = re.findall(r"(short_conv_\w+)/pallas_call\"", text)
+    # two runs of conv layers, each: the forward, the rematerialised
+    # forward's is the same call site traced again, and one backward
+    assert set(calls) == {"short_conv_fwd", "short_conv_bwd"}, calls
+
+
 HC_KERNELS = [("pre_fwd", "mhc_pre_fwd"), ("post_fwd", "mhc_post_fwd"),
               ("post_bwd", "mhc_post_bwd"), ("pre_bwd", "mhc_pre_bwd")]
 
@@ -611,6 +682,7 @@ MODELS = {
     "nemotron_h": lambda: NemotronH(NemotronHConfig.tiny(
         experts_held=4, mamba_groups_held=1, heads_held=2)),
     "keye_vl2": lambda: KeyeVL2(KeyeVL2Config.tiny(experts_held=4)),
+    "lfm2_moe": lambda: Lfm2Moe(Lfm2MoeConfig.tiny(experts_held=4)),
     "gpt-unrolled": lambda: GPT(GPTConfig.tiny(scan_layers=False)),
     "llama": lambda: Llama(LlamaConfig.tiny()),
 }
@@ -652,7 +724,7 @@ def lowered_losses():
        if m.startswith("deepseek_v3")
        or m in ("kimi_linear", "qwen3_next", "nemotron_h") else ())
     # ISSUE 59: an expert layer WITHOUT a shared expert
-    + (("router", "experts") if m == "keye_vl2" else ())
+    + (("router", "experts") if m in ("keye_vl2", "lfm2_moe") else ())
     # ISSUE 56: both projections of the experts' latent, under one name
     + (("latent_proj",) if m == "nemotron_h" else ())
     # ISSUE 45: everything ops/hyper_connection.py does, under one name
@@ -661,6 +733,9 @@ def lowered_losses():
     + (("mixer", "conv", "scan")
        if m in ("granite_hybrid", "sambay", "kimi_linear", "qwen3_next",
                 "nemotron_h") else ())
+    # ISSUE 64: a conv operator's projections and its gated convolution,
+    # under granite_hybrid's names; no scan follows them
+    + (("mixer", "conv") if m == "lfm2_moe" else ())
     + (("gmu", "cross_attn") if m == "sambay" else ())])
 def test_a_lowered_loss_carries_the_models_scopes(lowered_losses, model,
                                                   scope):
