@@ -45,6 +45,24 @@ are sized by tokens x slots; where it is not, the compaction is not
 traced. The trace-time event ``rtpu.ops.expert_layer`` / ``held`` says
 which (``pair_slots`` beside ``top_k``).
 
+Where a token's slots do not fill whole tiles of the chip's second-minor
+dimension (``slot_axis``: slots that are not a multiple of ``SLOT_TILE``, 8)
+the pair domain is laid out CHOICE-MAJOR: a pair's id is ``choice * T +
+token`` and ``sort_rows``' tables over the pairs are [k, T], a token's k
+slots down the LEADING axis. The rows gathered back for the sum over a
+token's slots are then [k, T, D], a bitcast of the gather's [k * T, D], and
+the sum adds k whole [T, D] slices; with the slots in the second-minor
+dimension ([T, k, D]) the chip's tiling of 8 rows makes that a full copy of
+the layer's largest array, twice a layer (PERF.md, PR 65: four cells paid
+it, at 4, 6 and 10 slots). Only the NAMES of the pairs change: the sort runs
+over the token-major keys either way, so a row of the buffer holds the token
+it held. Where the slots ARE whole tiles (8 in three cells) [T, k, D] costs
+no copy and the pairs stay token-major, ``token * k + choice`` and [T, k]:
+the program those cells had, kept because choice-major there moved what the
+compiler schedules beside the gathers and read 0.3 to 2.9 % slower
+(PERF.md, PR 65). The choice is one static fact of the call, as the
+compaction's is, and the event's ``slot_axis`` states it.
+
 ``balance_term`` is a router's load-balancing term (a softmax router's or,
 ``score="sigmoid"``, a sigmoid router's with its selection bias), for a
 model that adds it to its loss: it reads the router alone (every chip holds
@@ -222,17 +240,49 @@ grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 # ---------------------------------------------------------------------------
 # rows in and out of the buffer: gathers both ways
 # ---------------------------------------------------------------------------
-# ``at`` (``sort_rows``) knows pair -> row and row -> pair (a pair is
-# token * k + choice), so tokens go to rows and rows come back to tokens
-# by gathers, and each is the other's transpose: no scatter in either
-# direction, and nothing but copies of bf16 rows (the routing weights are
-# applied to the rows inside the buffer, ``held_expert_layer``).
+# ``at`` (``sort_rows``) knows pair -> row and row -> pair, so tokens go to
+# rows and rows come back to tokens by gathers, and each is the other's
+# transpose: no scatter in either direction, and nothing but copies of bf16
+# rows (the routing weights are applied to the rows inside the buffer,
+# ``held_expert_layer``). A pair is ``choice * T + token`` and the tables
+# over the pairs [k, T] where ``at.slot_axis`` is 0, ``token * k + choice``
+# and [T, k] where it is 1 (``slot_axis``).
+
+# Rows of a tile of the chip's second-minor dimension.
+SLOT_TILE = 8
 
 
-def _rows_of(values, at, pairs_each: int):
-    """values [n, *width], each standing for ``pairs_each`` pairs in a
-    row (a token's k choices, or one pair) -> [rows, *width]: each row
-    its pair's value AS GATHERED. A padding row (``row_pair`` past the
+def slot_axis(slots: int) -> int:
+    """The axis of the pairs' tables a token's ``slots`` lie along: 1
+    ([T, k], token-major) where they are whole tiles of the second-minor
+    dimension, so that the rows gathered back as [T, k, D] tile as they
+    are; else 0 ([k, T], choice-major), where [T, k, D] would be a padded
+    copy of [T * k, D] and [k, T, D] is a bitcast of it."""
+    return 1 if slots % SLOT_TILE == 0 else 0
+
+
+class PairTables(dict):
+    """``sort_rows``' tables, and with them the one static fact their
+    readers need: ``slot_axis``, the axis of ``pair_row`` / ``pair_held``
+    a token's slots lie along. It rides in the pytree's structure, so it
+    is a Python int inside ``jit`` and ``custom_vjp`` too."""
+
+    def __init__(self, tables, slot_axis: int):
+        super().__init__(tables)
+        self.slot_axis = slot_axis
+
+
+jax.tree_util.register_pytree_node(
+    PairTables,
+    lambda at: (tuple(at.values()), (tuple(at), at.slot_axis)),
+    lambda aux, leaves: PairTables(zip(aux[0], leaves), aux[1]))
+
+
+def _rows_of(values, at):
+    """values [n, *width], one a token (n = T) or one a pair (n = pairs,
+    in the order of the pairs' ids) -> [rows, *width]: each row its pair's
+    value AS GATHERED (a pair's token is its id modulo T choice-major, its
+    id over k token-major). A padding row (``row_pair`` past the
     last pair) holds a copy of the last value, not zeros: a select over
     the gathered array is a pass of its own over [rows, D], three a layer
     (PERF.md, PR 62), so the zero of a padding row is kept where it is one
@@ -241,23 +291,30 @@ def _rows_of(values, at, pairs_each: int):
     the buffer at a time, the rest skipped by predicate) the step was
     2.9 % shorter, and its length followed the routing: six seeds then
     spread by 0.5 % where they spread by 0.15 % so (PERF.md, PR 33)."""
-    pairs = values.shape[0] * pairs_each
-    return values[jnp.minimum(at["row_pair"], pairs - 1) // pairs_each]
-
-
-def _tokens_to_rows_impl(x, at):
-    return _rows_of(x, at, at["pair_row"].shape[1])
+    pairs = at["pair_row"].size
+    pair = jnp.minimum(at["row_pair"], pairs - 1)
+    n = values.shape[0]
+    return values[pair % n if at.slot_axis == 0 else pair // (pairs // n)]
 
 
 def _rows_to_tokens_impl(y, at):
+    # choice-major [k, T, D]: k whole [T, D] slices added, no relayout;
+    # token-major [T, k, D], where k rows are whole tiles
     picked = jnp.where(at["pair_held"][..., None], y[at["pair_row"]],
                        jnp.zeros((), y.dtype))
-    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(y.dtype)
+    return jnp.sum(picked.astype(jnp.float32),
+                   axis=at.slot_axis).astype(y.dtype)
 
 
 def _pairs_to_rows_impl(w, at):
-    flat = w.reshape(-1)
-    return jnp.where(at["row_pair"] < flat.shape[0], _rows_of(flat, at, 1), 0)
+    # [T, k] scalars in the order of the pairs' ids
+    flat = (w.T if at.slot_axis == 0 else w).reshape(-1)
+    return jnp.where(at["row_pair"] < flat.shape[0], _rows_of(flat, at), 0)
+
+
+def _pairs_to_rows_bwd(at, g):
+    dw = jnp.where(at["pair_held"], g[at["pair_row"]], 0.0)
+    return (dw.T if at.slot_axis == 0 else dw), None
 
 
 @jax.custom_vjp
@@ -268,7 +325,7 @@ def tokens_to_rows(x, at):
     and with it its share of every weight gradient and its own cotangent
     are 0 (``_mlp``), and ``rows_to_tokens`` gathers the rows of held
     pairs alone, in the forward and (as this function's vjp) backward."""
-    return _tokens_to_rows_impl(x, at)
+    return _rows_of(x, at)
 
 
 @jax.custom_vjp
@@ -280,23 +337,25 @@ def rows_to_tokens(y, at):
 
 
 tokens_to_rows.defvjp(
-    lambda x, at: (_tokens_to_rows_impl(x, at), at),
+    lambda x, at: (_rows_of(x, at), at),
     lambda at, g: (_rows_to_tokens_impl(g, at), None))
 rows_to_tokens.defvjp(
     lambda y, at: (_rows_to_tokens_impl(y, at), at),
-    lambda at, g: (_tokens_to_rows_impl(g, at), None))
+    lambda at, g: (_rows_of(g, at), None))
 
 
 @jax.custom_vjp
 def pairs_to_rows(w, at):
     """A value a pair, w [T, k] -> a value a row [rows], 0 for padding:
-    THE zero of a padding row (``tokens_to_rows``)."""
+    THE zero of a padding row (``tokens_to_rows``). The weights come and
+    their gradient goes back [T, k], as ``route`` makes them: where the
+    pairs' tables are [k, T] the transposition is of scalars, and is made
+    here."""
     return _pairs_to_rows_impl(w, at)
 
 
-pairs_to_rows.defvjp(
-    lambda w, at: (_pairs_to_rows_impl(w, at), at),
-    lambda at, g: (jnp.where(at["pair_held"], g[at["pair_row"]], 0.0), None))
+pairs_to_rows.defvjp(lambda w, at: (_pairs_to_rows_impl(w, at), at),
+                     _pairs_to_rows_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -377,11 +436,20 @@ def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
     [T, k] int32 over all experts (k the choices, or the slots of
     ``compact_held``, an empty one -1) -> dict of
 
-    ``pair_held`` [T, k] bool: the pair's expert lives here;
-    ``pair_row`` [T, k] int32: its row (0 where not held);
+    ``pair_held`` bool: the pair's expert lives here;
+    ``pair_row`` int32: its row (0 where not held);
     ``row_pair`` [rows] int32: the pair of each row, T * k for padding;
     ``tile_expert`` [rows // tile], ``n_used`` [1]: ``grouped_matmul``'s;
-    ``held_rows``: pairs held, a scalar (the counter of the layer)."""
+    ``held_rows``: pairs held, a scalar (the counter of the layer),
+
+    a ``PairTables`` whose ``slot_axis`` (of k: ``slot_axis``) says how the
+    pairs are named and their two tables laid out: 1, ``token * k +
+    choice`` and [T, k]; 0, ``choice * T + token`` and [k, T] (module
+    docstring). The SORT runs over the keys token-major either way, as
+    ``chosen`` lies, so that an expert's rows stand in the order of their
+    tokens and the row buffer is the same buffer; choice-major its pairs
+    are renamed after it (scalars: [T, k] int32 transposed, the sorted
+    [T * k] int32 renumbered)."""
     t, k = chosen.shape
     pairs = t * k
     n_tiles = rows // tile
@@ -407,6 +475,10 @@ def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
     rank = jnp.argsort(order).astype(jnp.int32) - of_expert(first_pair, key)
     pair_row = jnp.where(held.reshape(pairs),
                          of_expert(first_row, key) + rank, 0).reshape(t, k)
+    axis = slot_axis(k)
+    if axis == 0:
+        order = order % k * t + order // k  # token * k + choice, renamed
+        held, pair_row = held.T, pair_row.T
     # row -> pair: the row's tile names its expert
     n_used = last_tile[-1:]
     tile_expert = jnp.minimum(
@@ -418,9 +490,10 @@ def sort_rows(chosen, experts_held: int, expert_offset: int, rows: int,
     row_pair = jnp.where(
         r < of_expert(counts, e),
         order[jnp.clip(of_expert(first_pair, e) + r, 0, pairs - 1)], pairs)
-    return {"pair_held": held, "pair_row": pair_row, "row_pair": row_pair,
-            "tile_expert": tile_expert, "n_used": n_used,
-            "held_rows": jnp.sum(counts)}
+    return PairTables(
+        {"pair_held": held, "pair_row": pair_row, "row_pair": row_pair,
+         "tile_expert": tile_expert, "n_used": n_used,
+         "held_rows": jnp.sum(counts)}, axis)
 
 
 def compact_held(weights, chosen, experts_held: int, expert_offset: int):
@@ -515,6 +588,8 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
     _record("rtpu.ops.expert_layer", "held",
             {"experts_held": experts_held, "of": n_experts, "top_k": top_k,
              "pair_slots": min(top_k, experts_held),
+             # the axis of the pairs' tables a token's slots lie along
+             "slot_axis": slot_axis(min(top_k, experts_held)),
              "expert_offset": expert_offset, "tokens": t, "row_buffer": rows,
              "row_tile": tile, "score": score,
              "shared": "s_up" in p, "shared_gate": "s_gate_w" in p,

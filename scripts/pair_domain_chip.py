@@ -29,8 +29,19 @@ PR 62, ``--other <tree>``: ``held_expert_layer`` of another tree (its
 512 of 2048 x 512, softmax top 10, a gated shared expert;
 ``kanana2_train_s8192``'s: 16 of 128 of 2048 x 768, sigmoid top 6, two shared
 experts; ``xing4_train_s4096``'s: 8192 tokens, 8 of 64 of 3584 x 1024, sigmoid
-top 4, a shared expert): whether output and every gradient are EQUAL, and forward + backward of either, this, other,
-other, this. A script, not a metric."""
+top 4, a shared expert; ``lfm2moe_train_s8192``'s: 8 of 32 of 2048 x 1792,
+sigmoid top 4, no shared expert): whether output and every gradient are EQUAL,
+and forward + backward of either, this, other, other, this.
+
+PR 65: where a token's slots are not whole tiles of 8 the pairs are named
+choice-major (``choice * T + token``, ``sort_rows``' tables [k, T],
+``at.slot_axis`` 0); ``--other <parent tree>`` also says whether the way INTO the
+buffer is the other tree's element by element (the row buffer, the rows'
+weights, ``tile_expert``, ``n_used``: each tree's own ``route``, ``sort_rows``,
+``tokens_to_rows`` and ``pairs_to_rows`` on the same inputs), which is what
+makes the change a renaming, and how far output and gradients are apart where
+they are not EQUAL (the sum over a token's slots adds the same f32 terms,
+over another axis). A script, not a metric."""
 import argparse
 import importlib.util
 import os
@@ -61,6 +72,9 @@ SHAPES = {
     "xing4": dict(t=8192, d=3584, latent=0, e=64, held=8, f=1024, fs=1024,
                   top_k=4, scale=2.0, tile=el.ROW_TILE, expert="swiglu",
                   score="sigmoid"),
+    "lfm2moe": dict(t=16384, d=2048, latent=0, e=32, held=8, f=1792, fs=0,
+                    top_k=4, scale=1.0, tile=el.ROW_TILE, expert="swiglu",
+                    score="sigmoid"),
 }
 TINY = dict(t=256, d=64, latent=32, e=32, held=8, f=48, fs=64, top_k=22,
             scale=5.0, tile=8, expert="relu2", score="sigmoid")
@@ -97,7 +111,8 @@ def by_hand(x, p, s, compact):
                 el.pairs_to_rows(weights, at))
     routed = jnp.dot(el.rows_to_tokens(y, at), p["w_fc2"].astype(dt))
     return (el._mlp("relu2", x, p, "s", jnp.dot) + routed, buf,
-            jnp.sum(at["pair_held"], axis=1), at["row_pair"] < chosen.size)
+            jnp.sum(at["pair_held"], axis=at.slot_axis),
+            at["row_pair"] < chosen.size)
 
 
 def the_layer(x, p, s, module=el):
@@ -105,6 +120,23 @@ def the_layer(x, p, s, module=el):
         x, p, experts_held=s["held"], expert_offset=0, top_k=s["top_k"],
         routed_scale=s["scale"], expert=s["expert"], score=s["score"],
         tile=s["tile"])[0]
+
+
+def way_in(x, p, s, module):
+    """``module``'s way into the row buffer, as its ``held_expert_layer``
+    goes: (the buffer, the rows' weights, ``tile_expert``, ``n_used``)."""
+    weights, chosen = module.route(
+        x, p["w_router"], p.get("router_bias"), top_k=s["top_k"],
+        routed_scale=s["scale"], score=s["score"])
+    if s["top_k"] > s["held"]:
+        weights, chosen = module.compact_held(weights, chosen, s["held"], 0)
+    at = module.sort_rows(
+        chosen, s["held"], 0,
+        module.buffer_rows(s["t"], s["top_k"], s["held"], s["tile"]),
+        s["tile"])
+    u = jnp.dot(x, p["w_fc1"].astype(x.dtype)) if s["latent"] else x
+    return (module.tokens_to_rows(u, at), module.pairs_to_rows(weights, at),
+            at["tile_expert"], at["n_used"])
 
 
 def draw_layer(s, seed):
@@ -196,6 +228,28 @@ def two_trees(x, p, s, other):
     rows = el.buffer_rows(s["t"], s["top_k"], s["held"], s["tile"])
     print(f"  this tree against {other.__file__}: output and gradients EQUAL "
           f"{all(same.values())} {same}, all finite {finite}")
+
+    def apart(a, b):
+        """(largest difference over the other's largest entry, share of
+        elements that differ)."""
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        return [float(np.abs(a - b).max() / np.abs(b).max()),
+                float((a != b).mean())]
+
+    print("  where not EQUAL, [largest difference of the other's largest "
+          "entry, share of elements]:", {
+              n: apart(a, b) for n, a, b in [
+                  ("y", outs["this"], outs["other"]),
+                  ("x", got["this"][0], got["other"][0])] + [
+                  (n, got["this"][1][n], got["other"][1][n]) for n in p]
+              if not same[n]})
+    ways = [jax.jit(lambda x, p, m=m: way_in(x, p, s, m))(x, p)
+            for m in (el, other)]
+    print("  the way into the buffer EQUAL the other tree's, element by "
+          "element:", {n: bool((a == b).all()) for n, a, b in zip(
+              ("buffer", "row_weight", "tile_expert", "n_used"), *ways)},
+          f"({int(ways[0][3][0])} tiles used of {rows // s['tile']}, "
+          f"{int((ways[0][1] != 0).sum())} rows weighted)")
     ms = [(k, timed(grads[k], x, p)) for k in ("this", "other", "other",
                                                "this")]
     this, that = (min(v for k, v in ms if k == name)

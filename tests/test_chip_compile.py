@@ -167,9 +167,10 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     "t,d,f,held,of,shared,top_k,scale,expert,latent",
     [(16384, 2048, 768, 16, 128, 2, 6, 2.448, "swiglu", 0),
      (8192, 3584, 1024, 8, 64, 1, 4, 2.0, "swiglu", 0),
-     (16384, 4096, 2688, 8, 512, 2, 22, 5.0, "relu2", 1024)],
+     (16384, 4096, 2688, 8, 512, 2, 22, 5.0, "relu2", 1024),
+     (16384, 2048, 512, 32, 512, 1, 10, 1.0, "swiglu", 0)],
     ids=["kanana2_train_s8192", "xing4_train_s4096",
-         "nemotron3super_train_s8192"])
+         "nemotron3super_train_s8192", "qwen3next_train_s8192"])
 def test_held_expert_layer_at_the_benchmark_cells_shape(
         one_chip, compiled_kernels, t, d, f, held, of, shared, top_k, scale,
         expert, latent):
@@ -182,7 +183,14 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     pair domain is 131 072 and no array of the program is sized by the
     360 448 (token, choice) pairs. ISSUE 62: at all three, no select with a
     [rows, width] result: the buffer's padding rows are not written as
-    zeros."""
+    zeros. ISSUE 65: 16 384 tokens, 32 of 512 experts of 2048 x 512, top
+    10, and at all four: the rows gathered back are summed as a BITCAST of
+    the gather's [slots * T, width], to [slots, T, width] where the slots
+    are not whole tiles of 8 rows (6, 4 and 10: in the second-minor
+    dimension they were one padded copy a gather back) and to [T, slots,
+    width] where they are (nemotron3super's 8, the program it had), and no
+    ``reshape``, ``copy`` or ``transpose`` outside a fusion makes or reads
+    an array of that size."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
@@ -219,7 +227,19 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
         r"= \w+\[%s\]\S* select\(" % shape, text)
     assert not selects("%d,%d" % (rows, width))
     assert selects("%d" % rows)
-    assert selects("%d,%d,%d" % (t, min(top_k, held), width))
+    slots = min(top_k, held)
+    summed = (t, slots, width) if el.slot_axis(slots) else (slots, t, width)
+    assert selects("%d,%d,%d" % summed)
+    # ISSUE 65: between the gather back and the sum over a token's slots
+    # nothing moves the rows. An instruction of the ENTRY computation (a
+    # fusion's own lines carry no ``backend_config``) over one of the
+    # gathered array's three shapes is a bitcast or a fusion, never a copy
+    gathered = r"\[(%d,%d,%d|%d,%d,%d|%d,%d)\]" % (
+        slots, t, width, t, slots, width, slots * t, width)
+    moves = re.findall(r"= bf16%s\S* (reshape|copy|transpose)\([^\n]*"
+                       r"backend_config" % gathered, text)
+    assert not moves, moves
+    assert re.search(r"= bf16\[%d,%d,%d\]\S* bitcast\(" % summed, text)
 
 
 def test_hyper_connection_pair_at_the_benchmark_cells_shape(

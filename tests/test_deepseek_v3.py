@@ -481,9 +481,117 @@ def test_the_row_buffer_is_static_and_covers_the_worst_case():
     chosen = jnp.zeros((64, 3), jnp.int32)
     at = sort_rows(chosen, 4, 0, buffer_rows(64, 3, 4, 8), 8)
     assert int(at["n_used"][0]) == 24 + 3
+    # ISSUE 65: the pairs' tables are [k, T], a pair is choice * T + token
+    assert at["pair_row"].shape == at["pair_held"].shape == (3, 64)
     assert sorted(np.asarray(at["pair_row"]).ravel()) == list(range(192))
     assert list(np.asarray(at["tile_expert"])[:27]) == [0] * 24 + [1, 2, 3]
     assert (np.asarray(at["row_pair"])[192:] == 192).all()
+    # one expert's rows stand in the order of their tokens, a token's by
+    # choice: row 3 * token + choice holds the pair choice * 64 + token
+    np.testing.assert_array_equal(
+        np.asarray(at["pair_row"]),
+        3 * np.arange(64)[None, :] + np.arange(3)[:, None])
+    np.testing.assert_array_equal(
+        np.asarray(at["row_pair"])[:192],
+        (np.arange(3)[None, :] * 64 + np.arange(64)[:, None]).ravel())
+
+
+def _token_major_buffer(x, weights, chosen, held, offset, rows, tile):
+    """The row buffer as every layer built it before ISSUE 65, in numpy and
+    with nothing of the module: a pair is ``token * k + choice``, the pairs
+    are sorted (stably) by held expert, an expert's rows start on a tile
+    (one tile at least) -> (buffer, a row's weight, ``tile_expert``,
+    ``n_used``); a padding row holds the last token's row and weighs 0."""
+    k = chosen.shape[1]
+    local = chosen - offset
+    key = np.where((local >= 0) & (local < held), local, held).ravel()
+    order = np.argsort(key, kind="stable")
+    counts = np.bincount(key, minlength=held + 1)[:held]
+    last_tile = np.cumsum(np.maximum(-(-counts // tile), 1))
+    first_row = np.concatenate([[0], last_tile[:-1]]) * tile
+    first_pair = np.cumsum(counts) - counts
+    buf = np.broadcast_to(x[-1], (rows, x.shape[1])).copy()
+    row_weight = np.zeros((rows,), np.float32)
+    for e in range(held):
+        pairs = order[first_pair[e]:first_pair[e] + counts[e]]
+        at_row = first_row[e] + np.arange(counts[e])
+        buf[at_row] = x[pairs // k]
+        row_weight[at_row] = weights.ravel()[pairs]
+    tile_expert = np.minimum(np.searchsorted(
+        last_tile, np.arange(rows // tile), side="right"), held - 1)
+    return buf, row_weight, tile_expert, last_tile[-1]
+
+
+@pytest.mark.parametrize("top_k,held", [(4, 8), (6, 16), (8, 16), (10, 32),
+                                        (22, 8)])
+def test_the_choice_major_pairs_fill_the_token_major_buffer(top_k, held):
+    """ISSUE 65: where a token's slots are not whole tiles the pairs are
+    NAMED choice-major (``choice * T + token``, the tables [k, T]: xing4's
+    and lfm2moe's top 4, kanana2's 6, qwen3next's 10), where they are (two
+    cells' top 8, nemotron3super's 22 compacted to 8 slots) token-major as
+    they were, and either way nothing else moved: the row buffer, the rows'
+    weights, ``tile_expert`` and ``n_used`` EQUAL the token-major
+    construction's element by element, and the layer's output and every
+    gradient are the dense per-token layer's."""
+    from ray_tpu.ops import expert_layer as el
+
+    t, d, f, e, offset, tile = 64, 32, 16, 64, 8, 8
+    keys = iter(jax.random.split(jax.random.PRNGKey(65), 12))
+    draw = lambda *s: 0.3 * jax.random.normal(next(keys), s)    # noqa: E731
+    lp = {"w_router": 3.0 * draw(d, e), "s_gate_w": draw(d, 1),
+          "s_gate": draw(d, f), "s_up": draw(d, f), "s_down": draw(f, d),
+          "e_gate": draw(held, d, f), "e_up": draw(held, d, f),
+          "e_down": draw(held, f, d)}
+    x = jax.random.normal(next(keys), (t, d))
+    rows = buffer_rows(t, top_k, held, tile)
+    weights, chosen = el.route(x, lp["w_router"], None, top_k=top_k,
+                               routed_scale=1.0, score="softmax")
+    want = _token_major_buffer(np.asarray(x), np.asarray(weights),
+                               np.asarray(chosen), held, offset, rows, tile)
+    if top_k > held:
+        weights, chosen = el.compact_held(weights, chosen, held, offset)
+    at = sort_rows(chosen, held, offset, rows, tile)
+    slots = min(top_k, held)
+    # 8 slots are whole tiles and stay token-major, [T, k]; the others [k, T]
+    assert at.slot_axis == el.slot_axis(slots) == (0 if slots % 8 else 1)
+    assert at["pair_row"].shape == at["pair_held"].shape == (
+        (slots, t) if slots % 8 else (t, slots))
+    got = (el.tokens_to_rows(x, at), el.pairs_to_rows(weights, at),
+           at["tile_expert"], at["n_used"][0])
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    filled = np.asarray(at["row_pair"]) < slots * t
+    assert 0 < filled.sum() == int(at["held_rows"]) < slots * t
+    assert (want[1] > 0).sum() == filled.sum()
+    # a row's pair names the row's token and is sent back to the row
+    pair = np.asarray(at["row_pair"])[filled]
+    np.testing.assert_array_equal(
+        np.asarray(at["pair_row"]).ravel()[pair], np.flatnonzero(filled))
+    np.testing.assert_array_equal(
+        np.asarray(x)[pair % t if slots % 8 else pair // slots],
+        want[0][filled])
+    assert np.asarray(at["pair_held"]).ravel()[pair].all()
+
+    kw = dict(experts_held=held, expert_offset=offset, top_k=top_k,
+              routed_scale=1.0, score="softmax", tile=tile)
+    cot = jnp.cos(7.0 * x[:, ::-1])
+
+    def under_cot(layer):
+        """(output, gradients of x and ``lp``) under one cotangent."""
+        def fn(x, lp):
+            y = layer(x, lp)
+            return (y * cot).sum(), y
+        (_, y), g = jax.jit(jax.value_and_grad(
+            fn, argnums=(0, 1), has_aux=True))(x, lp)
+        return [y, g[0]] + [g[1][n] for n in lp]
+
+    mine = under_cot(lambda x, lp: held_expert_layer(x, lp, **kw)[0])
+    theirs = under_cot(
+        lambda x, lp: sum(_softmax_layer(x, lp, top_k, offset)))
+    for got, ref, limit in zip(mine, theirs, [2e-6] + [2e-5] * (1 + len(lp))):
+        assert float(jnp.abs(ref).max()) > 0
+        assert float(jnp.abs(got - ref).max()) < limit * float(
+            jnp.abs(ref).max())
 
 
 def test_routing_stats_counts_the_held_rows_of_each_expert_layer():

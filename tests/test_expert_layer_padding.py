@@ -45,38 +45,49 @@ def _layer_inputs(expert, latent, e, held, *, shared=True, t=TOKENS, d=32,
 # -- the plain reference: the gathers of PR 61, padding rows written as zeros
 
 
-def _masked_rows_of(values, at, pairs_each):
-    """``ops/expert_layer.py``'s ``_rows_of`` as PR 61 had it."""
-    pairs = values.shape[0] * pairs_each
+def _masked_rows_of(values, at):
+    """``ops/expert_layer.py``'s ``_rows_of`` as PR 61 had it, on the pair
+    ids of ISSUE 65 (``choice * T + token`` where ``at.slot_axis`` is 0,
+    ``token * k + choice`` where it is 1; values [T, ...] a token or
+    [pairs] a pair)."""
+    pairs = at["pair_row"].size
     pair = at["row_pair"]
     filled = (pair < pairs).reshape((-1,) + (1,) * (values.ndim - 1))
-    return jnp.where(filled,
-                     values[jnp.minimum(pair, pairs - 1) // pairs_each], 0)
-
-
-def _masked_tokens_to_rows_impl(x, at):
-    return _masked_rows_of(x, at, at["pair_row"].shape[1])
+    pair = jnp.minimum(pair, pairs - 1)
+    n = values.shape[0]
+    return jnp.where(filled, values[
+        pair % n if at.slot_axis == 0 else pair // (pairs // n)], 0)
 
 
 def _sum_of_held_rows(y, at):
     picked = jnp.where(at["pair_held"][..., None], y[at["pair_row"]],
-                       jnp.zeros((), y.dtype))
-    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(y.dtype)
+                       jnp.zeros((), y.dtype))      # [k, T, D] or [T, k, D]
+    return jnp.sum(picked.astype(jnp.float32),
+                   axis=at.slot_axis).astype(y.dtype)
 
 
-masked_tokens_to_rows = jax.custom_vjp(_masked_tokens_to_rows_impl)
+def _masked_pairs_to_rows_impl(w, at):
+    return _masked_rows_of(
+        (w.T if at.slot_axis == 0 else w).reshape(-1), at)
+
+
+def _masked_pairs_to_rows_bwd(at, g):
+    dw = jnp.where(at["pair_held"], g[at["pair_row"]], 0.0)
+    return (dw.T if at.slot_axis == 0 else dw), None
+
+
+masked_tokens_to_rows = jax.custom_vjp(_masked_rows_of)
 masked_rows_to_tokens = jax.custom_vjp(_sum_of_held_rows)
-masked_pairs_to_rows = jax.custom_vjp(
-    lambda w, at: _masked_rows_of(w.reshape(-1), at, 1))
+masked_pairs_to_rows = jax.custom_vjp(_masked_pairs_to_rows_impl)
 masked_tokens_to_rows.defvjp(
-    lambda x, at: (_masked_tokens_to_rows_impl(x, at), at),
+    lambda x, at: (_masked_rows_of(x, at), at),
     lambda at, g: (_sum_of_held_rows(g, at), None))
 masked_rows_to_tokens.defvjp(
     lambda y, at: (_sum_of_held_rows(y, at), at),
-    lambda at, g: (_masked_tokens_to_rows_impl(g, at), None))
+    lambda at, g: (_masked_rows_of(g, at), None))
 masked_pairs_to_rows.defvjp(
-    lambda w, at: (_masked_rows_of(w.reshape(-1), at, 1), at),
-    lambda at, g: (jnp.where(at["pair_held"], g[at["pair_row"]], 0.0), None))
+    lambda w, at: (_masked_pairs_to_rows_impl(w, at), at),
+    _masked_pairs_to_rows_bwd)
 
 MASKED = (masked_tokens_to_rows, masked_pairs_to_rows, masked_rows_to_tokens)
 
@@ -260,3 +271,62 @@ def test_nothing_reads_a_padding_row(expert, latent, top_k, held, offset,
     rows = int(layer[0][1][1])
     assert 0 < rows < el.buffer_rows(TOKENS, top_k, held, TILE) - TILE
     _assert_equal(overwritten, layer)
+
+
+# -- ISSUE 65: a token's slots lie down the LEADING axis ----------------------
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations
+    (``custom_vjp_call``, ``pjit``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("k", [4, 6, 10, 8, 16])
+@pytest.mark.parametrize("move", ["rows_to_tokens", "tokens_to_rows_bwd"])
+def test_the_slots_are_summed_where_the_gather_lands(move, k):
+    """The guard of the layout on the CPU, where the chip's tiling cannot
+    be read. At 4, 6 and 10 slots (xing4's and lfm2moe's, kanana2's,
+    qwen3next's) the rows gathered back are [k, T, D] and the sum over a
+    token's slots is over axis 0, in float32; no ``transpose`` and no
+    ``reshape`` touches an array of T * k * D elements but the gather's own
+    [k * T, D] -> [k, T, D] (a bitcast on the chip: T rows tile by 8 where
+    k rows do not), forward and as ``tokens_to_rows``' backward. At 8 and
+    16, whole tiles, they are [T, k, D] summed over axis 1: the program
+    the three cells of 8 slots had."""
+    t, d, held = 24, 16, 20
+    axis = el.slot_axis(k)
+    assert axis == (0 if k % 8 else 1)
+    rows = el.buffer_rows(t, k, held, TILE)
+    chosen = jnp.argsort(jax.random.normal(jax.random.PRNGKey(65), (t, 32)),
+                         axis=1)[:, :k].astype(jnp.int32)
+    at = el.sort_rows(chosen, held, 4, rows, TILE)
+    at.pop("held_rows")
+    gathered = (k, t, d) if axis == 0 else (t, k, d)
+    assert at.slot_axis == axis and at["pair_row"].shape == gathered[:2]
+    y = jnp.ones((rows, d), jnp.bfloat16)
+    if move == "rows_to_tokens":
+        jaxpr = jax.make_jaxpr(el.rows_to_tokens)(y, at)
+    else:
+        jaxpr = jax.make_jaxpr(lambda g, at: jax.vjp(
+            lambda x: el.tokens_to_rows(x, at),
+            jnp.ones((t, d), jnp.bfloat16))[1](g)[0])(y, at)
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(t, d)]
+    eqns = list(_equations(jaxpr.jaxpr))
+    sums = [e for e in eqns if e.primitive.name == "reduce_sum"]
+    assert [(e.invars[0].aval.shape, e.invars[0].aval.dtype,
+             tuple(e.params["axes"])) for e in sums] == [
+        (gathered, jnp.float32, (axis,))]
+    gathers = [e for e in eqns if e.primitive.name == "gather"
+               and e.invars[0].aval.shape == (rows, d)]
+    assert [e.outvars[0].aval.shape for e in gathers] in (
+        [gathered], [(k * t, d)])
+    moved = [(e.primitive.name, e.invars[0].aval.shape,
+              e.outvars[0].aval.shape) for e in eqns
+             if e.primitive.name in ("transpose", "reshape", "copy",
+                                     "broadcast_in_dim", "squeeze")
+             and e.invars[0].aval.size == t * k * d]
+    assert all(m == ("reshape", (k * t, d), gathered) for m in moved), moved

@@ -628,6 +628,8 @@ def test_the_compaction_is_traced_only_where_more_are_chosen_than_held(
         rec.enabled = was
     assert event["top_k"] == top_k
     assert event["pair_slots"] == (held if compacts else top_k)
+    # ISSUE 65: 8 slots, whole tiles, stay token-major; kanana2's 6 do not
+    assert event["slot_axis"] == (0 if event["pair_slots"] % 8 else 1)
     assert bool(entered) == compacts
     # ``sort_rows``' own cumsums run over its [held] tables: axis 0
     assert "cumsum[axis=0" in text

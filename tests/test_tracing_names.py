@@ -199,6 +199,8 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
         "pair_slots": 2,
+        # ISSUE 65: the pairs choice-major, a token's slots down axis 0
+        "slot_axis": 0,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE,
         # ISSUE 52: how the router scores, whether the shared expert is gated
@@ -332,6 +334,8 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
         "pair_slots": 2,
+        # ISSUE 65: the pairs choice-major, a token's slots down axis 0
+        "slot_axis": 0,
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
         "row_tile": el.ROW_TILE, "score": "softmax", "shared": True,
         "shared_gate": True,
@@ -384,6 +388,8 @@ def test_a_share_of_a_latent_expert_stack_leaves_its_events():
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
         "pair_slots": 2,
+        # ISSUE 65: the pairs choice-major, a token's slots down axis 0
+        "slot_axis": 0,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE, "score": "sigmoid", "shared": True, "shared_gate": False,
         "expert": "relu2", "latent": 32}
@@ -446,7 +452,7 @@ def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
         "layers": 2}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
-        "pair_slots": 2, "tokens": 512,
+        "pair_slots": 2, "slot_axis": 0, "tokens": 512,
         "row_buffer": el.buffer_rows(512, 3, 2), "row_tile": el.ROW_TILE,
         "score": "softmax", "shared": False, "shared_gate": False,
         "expert": "swiglu", "latent": 0}
@@ -533,7 +539,7 @@ def test_a_gated_convolution_stack_leaves_its_events(conv_stack):
         "kinds": ["conv_mlp", "attn_moe", "conv_moe", "conv_moe"]}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
-        "pair_slots": 2, "tokens": 256,
+        "pair_slots": 2, "slot_axis": 0, "tokens": 256,
         "row_buffer": el.buffer_rows(256, 3, 2), "row_tile": el.ROW_TILE,
         "score": "sigmoid", "shared": False, "shared_gate": False,
         "expert": "swiglu", "latent": 0}
