@@ -154,15 +154,26 @@ more).
   - once a program: the gate (the prologue) and the cumulative gates of
     the whole block (the triangle of ones times g, g in three bfloat16
     pieces: three exact passes);
-  - once a PAIR of heads (``_forward_of`` / ``_backward_of``: pure
-    functions of the pair's tiles under ``jax.jit``, so that the nine
-    kernel instances of a train step and the pairs of a program all get
+  - once a program, for its whole block of heads (``_forward_of`` /
+    ``_backward_of``: pure functions of the block's tiles under
+    ``jax.jit``, so that the nine kernel instances of a train step all get
     the body traced ONCE; ``setup_s`` pays for every equation traced): the
-    solve, the two heads' A side by side [C, 2 C] against block-diagonal
+    solve, two heads' A side by side [C, 2 C] against block-diagonal
     right-hand sides (``_solve``: the MXU's time is the rows that stream
     through it, so two heads cost one); its ten products at
     ``Precision.HIGHEST``, which Mosaic honours (on the chip o is the plain
-    route's to a rounding of bf16, PERF.md PR 50);
+    route's to a rounding of bf16, PERF.md PR 50), are a chain in which
+    each waits for the last, so the block's two pairs are solved in lock
+    step (``_solve_heads``) and every later stage of the heads' chunks is
+    issued for all of them before the next (``_staged``): the same products
+    on the same numbers, side by side in the MXU (on a v5e at the cell's
+    shape a layer's forward kernel 11.0 -> 6.8 ms and its backward 19.0 ->
+    12.9 by the order of issue alone, every output equal to the last bit:
+    PERF.md PR 66; Gated DeltaNet's body below had it from PR 53). A head's
+    scores and their backward, two thirds of a head's equations and no part
+    of those chains, are jit's too (``_scores``, ``_scores_bwd``): traced
+    once for the four heads, so the body of four costs the step's trace what
+    the body of two did;
   - once a head: beta as a [C, 128] column by a product with ones (beta
     comes in head-major, dense [heads, C] rows: a [C, 1] column costs a
     register a sublane whatever is done to it, ISSUE 40), the block rows
@@ -636,6 +647,13 @@ def _diagonal_bwd(qf, kf, cum, dsq, dsk, r: int):
     return tuple(jax.lax.reshape(x, (c, d)) for x in (dq, dk, dc))
 
 
+# A head's scores and their backward are two thirds of the equations of a
+# head's chunk and are no part of the chain the heads' stages overlap in:
+# under ``jax.jit`` each is traced ONCE for all of a block's heads and for
+# both kernels' bodies (they are lowered inline, where they are called).
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def _scores(qf, kf, cum, dt, r: int):
     """q, k [C, d] float32, cum [C, d] -> (Q, K) [C, C] float32 as
     ``_decayed_scores`` makes them: Q_ij for i >= j, K_ij for i > j."""
@@ -656,6 +674,7 @@ def _scores(qf, kf, cum, dt, r: int):
             jax.lax.select(_gt(row, col), sk, _zeros_like(sk)))
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6))
 def _scores_bwd(qf, kf, cum, dsq, dsk, dt, r: int):
     """The gradients of ``_scores``: dsq, dsk [C, C] (zero where the score
     is masked) -> (dq, dk as a row's key, dk as a column's key) [C, d].
@@ -752,28 +771,42 @@ def _solve_heads(a, r: int):
     return out + [_solve(x, r) for x in a[len(out):]]
 
 
-def _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt):
-    """One head's chunk from its scores and solve up to what it writes. q,
-    k, v [C, d] float32, cum [C, d], bcol [C, d] (beta_i in every lane), st
-    [d_v, d_k] float32 the state the chunk starts from, transposed, sq and
-    t [C, C] -> a dict of what the forward and the backward both use."""
-    c, d = qf.shape
-    kept = jax.lax.exp(cum)
-    bkv = jax.lax.concatenate([_mul(_mul(kf, bcol), kept), _mul(vf, bcol)],
-                              1).astype(dt)                   # [C, 2d]
-    wu = dot(t.astype(dt), bkv, AB)
-    w, u = _cols(wu, 0, d).astype(dt), _cols(wu, d, d)
-    q_in = _mul(qf, kept).astype(dt)
-    held = st.astype(dt)
-    from_state = dot(jax.lax.concatenate([w, q_in], 0), held, ABT)
-    wrote = _minus(u, _rows(from_state, 0, c)).astype(dt)
-    last = spread(_rows(cum, c - 1, 1), (c, d))
-    to_end = jax.lax.exp(_minus(last, cum))
-    return dict(kept=kept, bkv=bkv, w=w, q_in=q_in,
-                held=held, wrote=wrote, to_end=to_end,
-                k_end=_mul(kf, to_end).astype(dt),
-                q_state=_rows(from_state, c, c),
-                at_end=jax.lax.exp(_rows(cum, c - 1, 1)))
+def _staged(heads, **stages):
+    """``f[name] = stage(f)`` for every head's dict f, a stage at a time:
+    a head's chunk is a chain of products each waiting for the last, the
+    heads' chains are independent, and side by side in the program they
+    overlap in the MXU (as the pairs' solves do, ``_solve``)."""
+    for name, stage in stages.items():
+        for f in heads:
+            f[name] = stage(f)
+
+
+def _chunk_forward(heads, dt):
+    """A block's heads from their scores and solves up to what they write,
+    a stage at a time for all of them (``_staged``). Each head a dict: qf,
+    kf, vf [C, d] float32, cum [C, d], bcol [C, d] (beta_i in every lane),
+    st [d_v, d_k] float32 the state the chunk starts from, transposed, sq
+    and t [C, C]; it gains what the forward and the backward both use."""
+    c, d = heads[0]["qf"].shape
+    _staged(
+        heads,
+        kept=lambda f: jax.lax.exp(f["cum"]),
+        bkv=lambda f: jax.lax.concatenate(
+            [_mul(_mul(f["kf"], f["bcol"]), f["kept"]),
+             _mul(f["vf"], f["bcol"])], 1).astype(dt),           # [C, 2d]
+        wu=lambda f: dot(f["t"].astype(dt), f["bkv"], AB),
+        w=lambda f: _cols(f["wu"], 0, d).astype(dt),
+        q_in=lambda f: _mul(f["qf"], f["kept"]).astype(dt),
+        held=lambda f: f["st"].astype(dt),
+        from_state=lambda f: dot(
+            jax.lax.concatenate([f["w"], f["q_in"]], 0), f["held"], ABT),
+        wrote=lambda f: _minus(_cols(f["wu"], d, d),
+                               _rows(f["from_state"], 0, c)).astype(dt),
+        to_end=lambda f: jax.lax.exp(_minus(
+            spread(_rows(f["cum"], c - 1, 1), (c, d)), f["cum"])),
+        k_end=lambda f: _mul(f["kf"], f["to_end"]).astype(dt),
+        q_state=lambda f: _rows(f["from_state"], c, c),
+        at_end=lambda f: jax.lax.exp(_rows(f["cum"], c - 1, 1)))
 
 
 def _three(x):
@@ -811,17 +844,23 @@ def _beta_column(row):
 
 
 def _heads_scores(heads, dt, r: int):
-    """What the solve needs of some heads, each (q, k, v [C, 128] in the
-    model's dtype, its cumulative gates, its row of beta, ...) -> per head
-    (q, k, v float32, the gates, beta's column, Q, K), and the heads' T."""
+    """What the solve needs of a block's heads, each (q, k, v [C, 128] in
+    the model's dtype, its cumulative gates, its row of beta, the start
+    state, ...) -> a dict a head: q, k, v in float32, the gates, beta's
+    column, the state, Q, K and T, the heads' solves in lock step
+    (``_solve_heads``)."""
     out = []
-    for q, k, v, cum, beta, *_ in heads:
+    for q, k, v, cum, beta, st, *_ in heads:
         qf, kf = q.astype(_F32), k.astype(_F32)
-        out.append((qf, kf, v.astype(_F32), cum, _beta_column(beta))
-                   + _scores(qf, kf, cum, dt, r))
+        sq, sk = _scores(qf, kf, cum, dt, r)
+        out.append(dict(qf=qf, kf=kf, vf=v.astype(_F32), cum=cum,
+                        bcol=_beta_column(beta), st=st, sq=sq, sk=sk))
     c = cum.shape[0]
-    return out, _solve_heads(
-        [_mul(_cols(bcol, 0, c), sk) for *_, bcol, _, sk in out], r)
+    solved = _solve_heads(
+        [_mul(_cols(f["bcol"], 0, c), f["sk"]) for f in out], r)
+    for f, t in zip(out, solved):
+        f["t"] = t
+    return out
 
 
 # The prologue: what ``kda_gated_scan``'s caller left to the kernels. Pure
@@ -886,43 +925,39 @@ def _tiles(refs, h: int):
     return tuple(x[:, pl.ds(h * LANES, LANES)] for x in refs)
 
 
-# Two heads' share of a program as a PURE function of values under
+# A program's whole block of heads as a PURE function of values under
 # ``jax.jit``: a train step holds nine instances of these kernels (three
-# runs of the walker x forward sweep, rematerialised forward, backward) and
-# each program two or more pairs of heads; jit's cache hands every one of
-# them the body traced ONCE, and ``setup_s`` pays for every equation traced.
+# runs of the walker x forward sweep, rematerialised forward, backward);
+# jit's cache hands every one of them the body traced ONCE, and ``setup_s``
+# pays for every equation traced. All of the block's heads in one body, and
+# not a pair at a time: a head's chunk is a chain of products each waiting
+# for the last (the solve's ten at ``Precision.HIGHEST`` first of all), and
+# the body issues every stage for all the heads before the next, the two
+# pairs' solves in lock step (``_solve_heads``, ``_staged``).
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
 def _forward_of(heads, *, scale: float, r: int, eps):
-    """``heads``: one or two of (q, k, v, cumulative gates, beta's row, the
+    """``heads``: a block's (q, k, v, cumulative gates, beta's row, the
     start state [d_v, d_k]) -> per head (o scaled in q's dtype, the state
     the chunk ends in). ``eps``: the l2 norm's, where q and k come as the
     convolutions left them; None where the caller normalised them."""
     dt, d = heads[0][0].dtype, LANES
-    heads, _ = _normed(heads, eps)
-    scored, solved = _heads_scores(heads, dt, r)
-    out = []
-    for (*_, st), (qf, kf, vf, cum, bcol, sq, _), t in zip(
-            heads, scored, solved):
-        f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
-        o = _add(f["q_state"], dot(sq.astype(dt), f["wrote"], AB))
-        out.append((_mul(o, jax.lax.full_like(o, scale)).astype(dt),
-                    _add(_mul(spread(f["at_end"], (d, d)), st),
-                         dot(f["wrote"], f["k_end"], ATB))))
-    return out
+    heads = _heads_scores(_normed(heads, eps)[0], dt, r)
+    _chunk_forward(heads, dt)
+    _staged(
+        heads,
+        o=lambda f: _add(f["q_state"],
+                         dot(f["sq"].astype(dt), f["wrote"], AB)),
+        ended=lambda f: _add(_mul(spread(f["at_end"], (d, d)), f["st"]),
+                             dot(f["wrote"], f["k_end"], ATB)))
+    return [(_mul(f["o"], jax.lax.full_like(f["o"], scale)).astype(dt),
+             f["ended"]) for f in heads]
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-
-
-def _pairs(hpb: int):
-    """The block's heads as the solve takes them: in pairs, or one by one
-    where their number is odd."""
-    per = 2 - hpb % 2
-    return [range(i, i + per) for i in range(0, hpb, per)]
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
@@ -939,15 +974,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
 
     st_ref[...] = s_scr[...]
     cum_all = _triangle_sums(_gate(*gate)[0], AB)
-    for hs in _pairs(q_ref.shape[1] // d):
-        done = _forward_of(tuple(
-            _tiles((q_ref, k_ref, v_ref), h)
-            + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
-               s_scr[pl.ds(h * d, d), :]) for h in hs),
-            scale=scale, r=r, eps=eps)
-        for h, (o, st) in zip(hs, done):
-            o_ref[:, pl.ds(h * d, d)] = o
-            s_scr[pl.ds(h * d, d), :] = st
+    done = _forward_of(tuple(
+        _tiles((q_ref, k_ref, v_ref), h)
+        + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
+           s_scr[pl.ds(h * d, d), :]) for h in range(q_ref.shape[1] // d)),
+        scale=scale, r=r, eps=eps)
+    for h, (o, st) in enumerate(done):
+        o_ref[:, pl.ds(h * d, d)] = o
+        s_scr[pl.ds(h * d, d), :] = st
 
 
 def _specs(t: int, chunk: int, hpb: int, reverse: bool, key_heads: int = 0):
@@ -1039,73 +1073,89 @@ def _kda_fwd(q, k, v, gate, beta_t, scale, hpb, eps):
 
 @functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
 def _backward_of(heads, *, scale: float, r: int, eps):
-    """``heads``: one or two of (q, k, v, dO, cumulative gates, beta's row,
-    the start state, the END state's gradient) -> per head (dq, dk, dv in
+    """``heads``: a block's (q, k, v, cumulative gates, beta's row, the
+    start state, dO, the END state's gradient) -> per head (dq, dk, dv in
     q's dtype, d(cumulative gates) [C, 128], dbeta's row [1, C], the START
     state's gradient). The chunk's matrices are made again first; with
     ``eps`` (``_forward_of``) dq and dk are the RAW q's and k's, through
-    the norm."""
+    the norm. A stage at a time for all the heads, as the forward."""
     dt, d = heads[0][0].dtype, LANES
     c = heads[0][0].shape[0]
-    heads, norms = _normed(heads, eps)
-    scored, solved = _heads_scores(
-        [(q, k, v, cum, beta) for q, k, v, _, cum, beta, *_ in heads], dt, r)
+    given, norms = _normed(heads, eps)
+    heads = _heads_scores(given, dt, r)
+    _chunk_forward(heads, dt)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
+    seen, earlier = _ge(row, col), _gt(row, col)
+    zero = _full((c, c), 0)
     ones = _full((8, d + c), 1)
     last_row = _is(_iota((c, d), 0), c - 1)
-    out = []
-    for (*_, do, _, _, st, dst), (qf, kf, vf, cum, bcol, sq, sk), t, inv \
-            in zip(heads, scored, solved, norms):
-        f = _chunk_forward(qf, kf, vf, cum, bcol, st, sq, t, dt)
-        held, wrote, w = f["held"], f["wrote"], f["w"]
+    for f, (*_, do, dst) in zip(heads, given):
         do = do.astype(_F32)
-        do = _mul(do, jax.lax.full_like(do, scale)).astype(dt)
-        dsd = dst.astype(dt)
+        f.update(do=_mul(do, jax.lax.full_like(do, scale)).astype(dt),
+                 dst=dst, dsd=dst.astype(dt), bc=_cols(f["bcol"], 0, c))
+    _staged(
+        heads,
         # what each token wrote: through o and through the end state
-        dwrote = _add(dot(sq.astype(dt), do, ATB),
-                      dot(f["k_end"], dsd, ABT))               # [C, d_v]
-        dwd = dwrote.astype(dt)
-        dsq = dot(do, wrote, ABT)
-        dsq = jax.lax.select(_ge(row, col), dsq, _zeros_like(dsq))
-        dq_in = dot(do, held, AB)                              # [C, d_k]
-        dk_end = dot(wrote, dsd, AB)
-        dw = jax.lax.neg(dot(dwd, held, AB))
-        dwu = jax.lax.concatenate([dw, dwrote], 1).astype(dt)
-        d_t = dot(dwu, f["bkv"], ABT)                          # [C, C]
-        dbkv = dot(t.astype(dt), dwu, ATB)                     # [C, 2d]
-        dbk, dbv = _cols(dbkv, 0, d), _cols(dbkv, d, d)
+        dwrote=lambda f: _add(dot(f["sq"].astype(dt), f["do"], ATB),
+                              dot(f["k_end"], f["dsd"], ABT)),    # [C, d_v]
+        dwd=lambda f: f["dwrote"].astype(dt),
+        dsq=lambda f: jax.lax.select(
+            seen, dot(f["do"], f["wrote"], ABT), zero),
+        dq_in=lambda f: dot(f["do"], f["held"], AB),            # [C, d_k]
+        dk_end=lambda f: dot(f["wrote"], f["dsd"], AB),
+        dwu=lambda f: jax.lax.concatenate(
+            [jax.lax.neg(dot(f["dwd"], f["held"], AB)), f["dwrote"]],
+            1).astype(dt),
+        d_t=lambda f: dot(f["dwu"], f["bkv"], ABT),             # [C, C]
+        dbkv=lambda f: dot(f["t"].astype(dt), f["dwu"], ATB),   # [C, 2d]
+        dbk=lambda f: _cols(f["dbkv"], 0, d),
+        dbv=lambda f: _cols(f["dbkv"], d, d),
         # the solve's own backward, dA = -T^T dT T^T
-        da = jax.lax.neg(_exact_dot(_exact_dot(t, d_t, ATB), t, ABT))
-        da = jax.lax.select(_gt(row, col), da, _zeros_like(da))
-        dsk = _mul(da, _cols(bcol, 0, c))
+        half=lambda f: _exact_dot(f["t"], f["d_t"], ATB),
+        da=lambda f: jax.lax.select(
+            earlier, jax.lax.neg(_exact_dot(f["half"], f["t"], ABT)), zero),
+        dsk=lambda f: _mul(f["da"], f["bc"]),
         # the state the chunk starts from
-        decayed = _mul(dst, spread(f["at_end"], (d, d)))
-        dst_start = _minus(
-            _add(decayed, dot(do, f["q_in"], ATB)), dot(dwd, w, ATB))
-        d_last = lane_sum(_mul(decayed, st), 0)                      # [1, d]
+        decayed=lambda f: _mul(f["dst"], spread(f["at_end"], (d, d))),
+        dst_start=lambda f: _minus(
+            _add(f["decayed"], dot(f["do"], f["q_in"], ATB)),
+            dot(f["dwd"], f["w"], ATB)),
+        d_last=lambda f: lane_sum(_mul(f["decayed"], f["st"]), 0),  # [1, d]
         # beta: through A's rows, beta k exp(G) and beta v; a row of sums
         # over the lanes by a product with ones, the tokens along the lanes
-        k_kept = _mul(kf, f["kept"])
-        sums = jax.lax.concatenate(
-            [_add(_mul(dbk, k_kept), _mul(dbv, vf)), _mul(da, sk)], 1)
-        dbeta = _rows(_exact_dot(ones, sums, ABT), 0, 1)        # [1, C]
-        # the scores
-        dqs, dk_row, dk_col = _scores_bwd(qf, kf, cum, dsq, dsk, dt, r)
-        ended = _mul(dk_end, _mul(kf, f["to_end"]))
-        dq = _add(_mul(dq_in, f["kept"]), dqs)
-        dk = _add(_add(_mul(_mul(dbk, bcol), f["kept"]),
-                       _mul(dk_end, f["to_end"])), _add(dk_row, dk_col))
-        dc = _add(
-            _minus(_add(_mul(dbk, _mul(k_kept, bcol)),
-                        _mul(dq_in, _mul(qf, f["kept"]))), ended),
-            _add(_mul(qf, dqs), _mul(kf, _minus(dk_row, dk_col))))
-        at_last = _add(d_last, lane_sum(ended, 0))
-        dc = _add(dc, jax.lax.select(
-            last_row, spread(at_last, (c, d)), _zeros_like(dc)))
+        k_kept=lambda f: _mul(f["kf"], f["kept"]),
+        sums=lambda f: jax.lax.concatenate(
+            [_add(_mul(f["dbk"], f["k_kept"]), _mul(f["dbv"], f["vf"])),
+             _mul(f["da"], f["sk"])], 1),
+        dbeta=lambda f: _rows(_exact_dot(ones, f["sums"], ABT), 0, 1),
+        # the scores: (dq, dk as a row's key, dk as a column's key)
+        dscores=lambda f: _scores_bwd(f["qf"], f["kf"], f["cum"], f["dsq"],
+                                      f["dsk"], dt, r),
+        ended=lambda f: _mul(f["dk_end"], _mul(f["kf"], f["to_end"])),
+        dq=lambda f: _add(_mul(f["dq_in"], f["kept"]), f["dscores"][0]),
+        dk=lambda f: _add(
+            _add(_mul(_mul(f["dbk"], f["bcol"]), f["kept"]),
+                 _mul(f["dk_end"], f["to_end"])),
+            _add(f["dscores"][1], f["dscores"][2])),
+        at_last=lambda f: _add(f["d_last"], lane_sum(f["ended"], 0)),
+        dc=lambda f: _add(
+            _add(_minus(_add(_mul(f["dbk"], _mul(f["k_kept"], f["bcol"])),
+                             _mul(f["dq_in"], _mul(f["qf"], f["kept"]))),
+                        f["ended"]),
+                 _add(_mul(f["qf"], f["dscores"][0]),
+                      _mul(f["kf"], _minus(f["dscores"][1],
+                                           f["dscores"][2])))),
+            jax.lax.select(last_row, spread(f["at_last"], (c, d)),
+                           _full((c, d), 0))))
+    out = []
+    for f, inv in zip(heads, norms):
+        dq, dk = f["dq"], f["dk"]
         if inv is not None:
-            dq, dk = _unit_bwd(dq, qf, inv[0]), _unit_bwd(dk, kf, inv[1])
+            dq = _unit_bwd(dq, f["qf"], inv[0])
+            dk = _unit_bwd(dk, f["kf"], inv[1])
         out.append((dq.astype(dt), dk.astype(dt),
-                    _mul(dbv, bcol).astype(dt), dc, dbeta, dst_start))
+                    _mul(f["dbv"], f["bcol"]).astype(dt), f["dc"],
+                    f["dbeta"], f["dst_start"]))
     return out
 
 
@@ -1132,21 +1182,20 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, r: int, eps):
 
     g, slope = _gate(*gate, slope=True)
     cum_all = _triangle_sums(g, AB)
-    dcum = []
-    for hs in _pairs(q_ref.shape[1] // d):
-        done = _backward_of(tuple(
-            _tiles((q_ref, k_ref, v_ref, do_ref), h)
-            + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
-               st_ref[pl.ds(h * d, d), :], ds_scr[pl.ds(h * d, d), :])
-            for h in hs), scale=scale, r=r, eps=eps)
-        for h, (dq, dk, dv, dc, dbeta, dst) in zip(hs, done):
-            lanes = pl.ds(h * d, d)
-            dq_ref[:, lanes], dk_ref[:, lanes], dv_ref[:, lanes] = dq, dk, dv
-            dbeta_ref[pl.ds(h, 1), :] = dbeta
-            ds_scr[pl.ds(h * d, d), :] = dst
-            dcum.append(dc)
+    done = _backward_of(tuple(
+        _tiles((q_ref, k_ref, v_ref), h)
+        + (_cols(cum_all, h * d, d), beta_ref[pl.ds(h, 1), :],
+           st_ref[pl.ds(h * d, d), :])
+        + _tiles((do_ref,), h) + (ds_scr[pl.ds(h * d, d), :],)
+        for h in range(q_ref.shape[1] // d)), scale=scale, r=r, eps=eps)
+    for h, (dq, dk, dv, _, dbeta, dst) in enumerate(done):
+        lanes = pl.ds(h * d, d)
+        dq_ref[:, lanes], dk_ref[:, lanes], dv_ref[:, lanes] = dq, dk, dv
+        dbeta_ref[pl.ds(h, 1), :] = dbeta
+        ds_scr[pl.ds(h * d, d), :] = dst
     # dg from d(cumulative sum): the triangle's product, reversed
-    dg = _triangle_sums(jax.lax.concatenate(dcum, 1), ATB)
+    dg = _triangle_sums(
+        jax.lax.concatenate([dc for _, _, _, dc, _, _ in done], 1), ATB)
     if slope is None:
         dgate_ref[...] = dg
         return
@@ -1373,16 +1422,6 @@ def _gdn_chunk(keys, values, dt, r: int, eps: float):
         rhs=lambda f: _mul(f["bcol"], f["inner"]).astype(dt),
         wrote=lambda f: dot(f["t"].astype(dt), f["rhs"], AB).astype(dt))
     return keyed, heads
-
-
-def _staged(heads, **stages):
-    """``f[name] = stage(f)`` for every head's dict f, a stage at a time:
-    a head's chunk is a chain of products each waiting for the last, the
-    heads' chains are independent, and side by side in the program they
-    overlap in the MXU (as the pairs' solves do, ``_solve``)."""
-    for name, stage in stages.items():
-        for f in heads:
-            f[name] = stage(f)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "r", "eps"))
@@ -1664,7 +1703,7 @@ def _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta, *, key_heads: int,
     (q, k, v, a, beta), pad = pad_tokens(
         (q, k, v, a.astype(_F32), beta.astype(_F32)), chunk)
     facts = _path_facts(chunk, t, pad, heads, d, d, eps, key_heads)
-    facts["heads_per_block"] = hpb
+    facts.update(_block_facts(hpb))
     record_path("rtpu.ops.kda.path", PATH_COUNTS, "kernel", facts)
     rows = jnp.pad(jnp.stack([a_log, dt_bias]).reshape(2, heads // hpb, hpb),
                    ((0, 0), (0, 0), (0, _HEAD_ROWS - hpb)))
@@ -1698,6 +1737,14 @@ def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):
             "key_heads": key_heads or heads}
 
 
+def _block_facts(hpb: int):
+    """The kernel route's facts of a program's block of heads:
+    ``pairs_in_step`` is how many pairs' solves a program issues side by
+    side (``_solve_heads``: 2 at four heads, one pair alone at two or three,
+    none at one head)."""
+    return {"heads_per_block": hpb, "pairs_in_step": hpb // 2}
+
+
 def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
     """What KDA's two entries share: the route by what the call shows,
     whole chunks, the path event. ``gate``: (g,) with ``eps`` None, or the
@@ -1713,7 +1760,8 @@ def _scan(q, k, v, gate, beta, *, scale: float, chunk: int, eps):
     gate = (lead,) + tuple(gate[1:])
     facts = _path_facts(chunk, t, pad, heads, d_k, d_v, eps, None)
     if route == "kernel":
-        hpb = facts["heads_per_block"] = _heads_per_block(heads)
+        hpb = _heads_per_block(heads)
+        facts.update(_block_facts(hpb))
     record_path("rtpu.ops.kda.path", PATH_COUNTS, route, facts)
     if route == "kernel":
         o = _kernel_route(q, k, v, gate, beta, heads, chunk, float(scale),

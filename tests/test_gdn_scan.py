@@ -407,6 +407,6 @@ def test_a_gated_deltanet_scans_path_event(route):
              "heads": 4, "d_k": D, "d_v": D, "chunks": 3, "decay": "head",
              "key_heads": 2, "body": "head_decay",
              "prologue": "in_kernel" if route == "kernel" else "jnp"}
-    if route == "kernel":
-        facts["heads_per_block"] = 4
+    if route == "kernel":      # ISSUE 66: two pairs' solves in lock step
+        facts.update(heads_per_block=4, pairs_in_step=2)
     assert data == facts

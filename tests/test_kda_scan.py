@@ -365,8 +365,8 @@ def test_path_event_and_padding(route, entry):
              "prologue": "in_kernel" if (entry, route) == ("gated", "kernel")
              else "jnp", "decay": "channel", "key_heads": 2,
              "body": "channel_decay"}     # ISSUE 53: which body a run measured
-    if route == "kernel":
-        facts["heads_per_block"] = 2
+    if route == "kernel":      # ISSUE 66: one pair, solved alone
+        facts.update(heads_per_block=2, pairs_in_step=1)
     assert events and events[-1]["data"] == facts
 
 
@@ -409,6 +409,84 @@ def test_the_kernels_gradients_are_the_plain_routes(dtype, tol, entry):
     gates = ("g", "beta") if entry == "scan" else ("a_log", "dt_bias", "beta")
     for name in got:
         assert got[name].dtype == (jnp.float32 if name in gates else dtype)
+
+
+def _traced_again():
+    """The pair's pure bodies are traced once a process (``jax.jit``): what
+    a test plants in what they call, or in how many heads make a block,
+    shows only to a fresh trace and must not outlive the test."""
+    kda._forward_of.clear_cache()
+    kda._backward_of.clear_cache()
+
+
+@pytest.mark.parametrize("heads", [4, 3], ids=["four_heads", "three_heads"])
+def test_a_block_worked_whole_changes_no_number(heads, entry, monkeypatch):
+    """ISSUE 66: a program works its whole block of heads in one body, the
+    pairs' solves in lock step and every later stage for all the heads
+    before the next. That is an order of issue: o and every gradient are
+    EQUAL TO THE LAST BIT to the same call at two heads a program (a pair
+    alone, the program before; three heads then go one by one) and at one
+    (no pair at all: a [C, C] solve a head)."""
+    args, do = arguments(8, 128, heads=heads, batch=1)
+    if entry == "gated":
+        args = gated(args)
+    fn = kda.kda_gated_scan if entry == "gated" else kda.kda_scan
+    blocks, got = [], []
+    try:
+        for most in (4, 2, 1):
+            monkeypatch.setattr(kda, "_MAX_HEADS_PER_BLOCK", most)
+            if most == 1:     # an even number of heads is never cut to ones
+                monkeypatch.setattr(kda, "_heads_per_block", lambda h: 1)
+            _traced_again()
+            blocks.append(kda._heads_per_block(heads))
+            got.append(value_and_grads(
+                lambda *a: fn(*a, scale=D ** -0.5), args, do))
+    finally:
+        _traced_again()
+    assert blocks == ([4, 2, 1] if heads == 4 else [3, 1, 1])
+    for other in got[1:]:
+        for name, x in got[0].items():
+            np.testing.assert_array_equal(
+                np.asarray(x.astype(jnp.float32)),
+                np.asarray(other[name].astype(jnp.float32)), err_msg=name)
+
+
+def test_four_heads_a_program_solve_their_pairs_in_lock_step(monkeypatch):
+    """ISSUE 66: at four heads a program ``_solve`` is handed a LIST of two
+    [C, 2C] matrices (two pairs, their chains of products side by side),
+    once in the forward body and once in the backward's, and never a pair
+    alone; and the path event says how many pairs a program solves side by
+    side: 2 at four heads, 1 at two, 0 at one."""
+    from ray_tpu.perf import recorder
+
+    whole, calls = kda._solve, []
+
+    def seen(a, r):
+        calls.append([x.shape for x in a] if isinstance(a, (list, tuple))
+                     else a.shape)
+        return whole(a, r)
+
+    monkeypatch.setattr(kda, "_solve", seen)
+    facts = {}
+    try:
+        for heads in (4, 2, 1):
+            args, do = arguments(9, 64, heads=heads, batch=1)
+            _traced_again()
+            del calls[:]
+            jax.eval_shape(jax.grad(lambda *a: jnp.sum(kda.kda_scan(
+                *a, scale=1.0) * do), argnums=(0, 1, 2, 3, 4)),
+                *(args[n] for n in NAMES))
+            top = [c for c in calls if isinstance(c, list)]
+            facts[heads] = (top, [e["data"] for e in
+                                  recorder.get_recorder().snapshot()
+                                  if e["kind"] == "rtpu.ops.kda.path"][-1])
+    finally:
+        _traced_again()
+    pair = (64, 128)
+    assert facts[4][0] == [[pair, pair]] * 2       # forward, then backward
+    assert facts[2][0] == [[pair]] * 2 and facts[1][0] == [[(64, 64)]] * 2
+    assert [(facts[h][1]["heads_per_block"], facts[h][1]["pairs_in_step"])
+            for h in (4, 2, 1)] == [(4, 2), (2, 1), (1, 0)]
 
 
 def test_other_shapes_fall_back_to_the_plain_route(entry):

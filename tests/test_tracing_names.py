@@ -275,6 +275,8 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
         "route": "kernel", "chunk": 64, "tokens": 150,
         "padded_tokens": 42, "heads": 2, "d_k": 128, "d_v": 128,
         "chunks": 3, "heads_per_block": 2, "prologue": "in_kernel",
+        # ISSUE 66: the pairs' solves a program issues side by side
+        "pairs_in_step": 1,
         # ISSUE 52: KDA's decay is one a key channel, a key head a value head
         "decay": "channel", "key_heads": 2,
         # ISSUE 53: the body that makes the decayed scores in sub-blocks
@@ -329,7 +331,7 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "route": "kernel", "chunk": 64, "tokens": 256, "padded_tokens": 0,
         "heads": 4, "d_k": 128, "d_v": 128, "chunks": 4,
         "heads_per_block": 4, "prologue": "in_kernel", "decay": "head",
-        "key_heads": 2, "body": "head_decay"}
+        "key_heads": 2, "body": "head_decay", "pairs_in_step": 2}
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
