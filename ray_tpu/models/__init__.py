@@ -22,6 +22,7 @@ from .qwen3_next import Qwen3Next, Qwen3NextConfig
 from .nemotron_h import NemotronH, NemotronHConfig
 from .keye_vl2 import KeyeVL2, KeyeVL2Config
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
@@ -30,4 +31,5 @@ __all__ = [
     "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
     "Qwen3Next", "Qwen3NextConfig", "NemotronH", "NemotronHConfig",
     "KeyeVL2", "KeyeVL2Config", "Lfm2Moe", "Lfm2MoeConfig",
+    "OlmoHybrid", "OlmoHybridConfig",
 ]

@@ -296,9 +296,22 @@ _ein = functools.partial(jnp.einsum, preferred_element_type=_F32)
 _exact = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
-def _inverse_by_blocks(a):
+def _solve_block(beta_max: float) -> int:
+    """Rows of the diagonal blocks the solve inverts by the Neumann product,
+    by how large the caller says beta gets. A block's terms grow as C(r - 1,
+    n) a^n before they cancel, a an entry of A = beta k_i . k_j: with
+    neighbouring keys alike (k_i . k_j near 0.8) blocks of 8 are off by
+    9e-7 of T's largest entry at beta 0.9 and by 3.3e-5 at beta 2, where the
+    correction I - beta k k^T has the eigenvalue 1 - beta < 0 (Gated
+    DeltaNet with ``allow_neg_eigval``); blocks of 4 read 2.9e-6 there, and
+    blocks of 2 no less (``tests/test_kda_scan.py``). As many products
+    either way: one doubling less in the block, one merge more."""
+    return _SUB if beta_max <= 1.0 else _SUB // 2
+
+
+def _inverse_by_blocks(a, r: int):
     n = a.shape[-1]
-    if n <= _SUB or n % 2:
+    if n <= r or n % 2:
         eye = jnp.eye(n, dtype=a.dtype)
         t, p, m = eye - a, a, 2
         while m < n:                  # p = a^(m/2) -> a^m; a^n = 0
@@ -308,27 +321,28 @@ def _inverse_by_blocks(a):
         return t
     h = n // 2
     t11, t22 = _inverse_by_blocks(
-        jnp.stack([a[..., :h, :h], a[..., h:, h:]]))
+        jnp.stack([a[..., :h, :h], a[..., h:, h:]]), r)
     t21 = -_exact(_exact(t22, a[..., h:, :h]), t11)
     return jnp.concatenate([
         jnp.concatenate([t11, jnp.zeros_like(t11)], -1),
         jnp.concatenate([t21, t22], -1)], -2)
 
 
-@jax.custom_vjp
-def _unit_lower_inverse(a):
-    """T = (I + a)^-1 for a [..., n, n] strictly lower triangular, float32.
-    Its backward is the inverse's own, dA = -T^T dT T^T from T alone: two
-    products, where autodiff through the blocks' products makes twenty."""
-    return _inverse_by_blocks(a)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, r):
+    """T = (I + a)^-1 for a [..., n, n] strictly lower triangular, float32,
+    the diagonal blocks of ``r`` rows by the Neumann product. Its backward
+    is the inverse's own, dA = -T^T dT T^T from T alone: two products, where
+    autodiff through the blocks' products makes twenty."""
+    return _inverse_by_blocks(a, r)
 
 
-def _inverse_fwd(a):
-    t = _inverse_by_blocks(a)
+def _inverse_fwd(a, r):
+    t = _inverse_by_blocks(a, r)
     return t, t
 
 
-def _inverse_bwd(t, dt):
+def _inverse_bwd(r, t, dt):
     tt = jnp.swapaxes(t, -1, -2)
     return (-_exact(_exact(tt, dt), tt),)
 
@@ -390,7 +404,7 @@ def _chunk_body(state, xs, *, scale: float):
     sq, sk = _decayed_scores(q, k, cum)                      # [b, h, C, C]
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
     t = _unit_lower_inverse(
-        jnp.where(strict, sk * beta[..., None], 0.0)).astype(dt)
+        jnp.where(strict, sk * beta[..., None], 0.0), _SUB).astype(dt)
     last = cum[:, :, -1:, :]                                 # [b, h, 1, d]
     return _chunk_tail(state, t, sq, q, k, v, beta[..., None], jnp.exp(cum),
                        jnp.exp(last - cum), jnp.exp(last)[:, :, 0, :, None],
@@ -438,7 +452,7 @@ def _chunked(q, k, v, g, beta, heads: int, chunk: int, scale: float):
     return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, t, heads * dv)
 
 
-def _head_decay_chunk_body(state, xs, *, scale: float):
+def _head_decay_chunk_body(state, xs, *, scale: float, r: int):
     """One chunk of the delta rule with ONE decay a value head and token.
     state [B, Hk, R, d_k, d_v] f32 (R value heads to a key head); xs = (q,
     k [B, Hk, C, d_k], v [B, Hk, R, C, d_v], g, beta [B, Hk, R, C] f32) ->
@@ -456,7 +470,7 @@ def _head_decay_chunk_body(state, xs, *, scale: float):
     sk = _ein("bhid,bhjd->bhij", k, k)[:, :, None] * decay   # [b, h, r, C, C]
     sq = _ein("bhid,bhjd->bhij", q, k)[:, :, None] * decay
     t = _unit_lower_inverse(jnp.where(
-        jnp.tril(lower, -1), sk * beta[..., None], 0.0)).astype(dt)
+        jnp.tril(lower, -1), sk * beta[..., None], 0.0), r).astype(dt)
     last = cum[..., -1:]                                     # [b, h, r, 1]
     return _chunk_tail(state, t, sq, q[:, :, None], k[:, :, None], v,
                        beta[..., None], jnp.exp(cum)[..., None],
@@ -465,7 +479,7 @@ def _head_decay_chunk_body(state, xs, *, scale: float):
 
 
 def _head_decay_chunked(q, k, v, g, beta, key_heads: int, chunk: int,
-                        scale: float):
+                        scale: float, r: int):
     """Whole chunks of merged arrays, q, k [B, T, Hk*d_k], v [B, T,
     Hv*d_v], g, beta [B, T, Hv] -> o [B, T, Hv*d_v]; value head j reads
     key head j // (Hv / Hk)."""
@@ -484,7 +498,7 @@ def _head_decay_chunked(q, k, v, g, beta, key_heads: int, chunk: int,
           chunks_first(beta, key_heads, group)[..., 0])
     dk, dv = xs[1].shape[-1], xs[2].shape[-1]
     body = jax.checkpoint(functools.partial(_head_decay_chunk_body,
-                                            scale=scale))
+                                            scale=scale, r=r))
     _, o = jax.lax.scan(
         body, jnp.zeros((b, key_heads, group, dk, dv), _F32), xs)
     # [chunks, B, Hk, R, C, d_v] -> [B, T, Hv * d_v]
@@ -1466,7 +1480,7 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, o_ref,
         s_scr[pl.ds(h * d, d), :] = st
 
 
-def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
+def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps, r):
     """q, k [B, T, Hk*128], v [B, T, Hv*128], a_t and beta_t [B, Hv/hpb,
     T/C, hpb, C] float32, rows [Hv/hpb, 16, C] (``_head_gate``) -> (o [B,
     T, Hv*128], states [B, T/C, Hv*128, 128] f32: the state each chunk
@@ -1479,7 +1493,7 @@ def _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
     keys = hpb * q.shape[-1] // hd
     s = _specs(t, chunk, hpb, reverse=False, key_heads=keys)
     return pl.pallas_call(
-        functools.partial(_gdn_fwd_kernel, scale=scale, r=_SUB, eps=eps),
+        functools.partial(_gdn_fwd_kernel, scale=scale, r=r, eps=eps),
         grid=(b, h // hpb, nc),
         in_specs=[s["keys"]] * 2 + [s["x"], s["beta"], s["head_rows"],
                                     s["beta"]],
@@ -1634,7 +1648,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, a_ref, rows_ref, beta_ref, st_ref,
                           jax.lax.concatenate([_mul(dg, g), da], 0))
 
 
-def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
+def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps, r):
     """-> [dq, dk [B, T, Hk*128], dv [B, T, Hv*128] (q's dtype), da_t f32,
     drows [B, Hv/hpb, 16, C] f32, dbeta_t f32]."""
     from jax.experimental.pallas import tpu as pltpu
@@ -1646,7 +1660,7 @@ def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
     s = _specs(t, chunk, hpb, reverse=True, key_heads=keys)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_gdn_bwd_kernel, scale=scale, r=_SUB, eps=eps),
+        functools.partial(_gdn_bwd_kernel, scale=scale, r=r, eps=eps),
         grid=(b, h // hpb, nc),
         in_specs=[s["keys"]] * 2 + [s["x"], s["beta"], s["head_rows"],
                                     s["beta"], s["state"], s["x"]],
@@ -1668,22 +1682,22 @@ def _gdn_bwd(q, k, v, a_t, rows, beta_t, states, do, scale, eps):
     )(q, k, v, a_t, rows, beta_t, states, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _gdn_kernels(q, k, v, a_t, rows, beta_t, scale, eps):
-    return _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _gdn_kernels(q, k, v, a_t, rows, beta_t, scale, eps, r):
+    return _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps, r)[0]
 
 
-def _gdn_vjp_fwd(q, k, v, a_t, rows, beta_t, scale, eps):
+def _gdn_vjp_fwd(q, k, v, a_t, rows, beta_t, scale, eps, r):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, states = _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps)
+    o, states = _gdn_fwd(q, k, v, a_t, rows, beta_t, scale, eps, r)
     o = checkpoint_name(o, "kda_out")             # as ``_kda_vjp_fwd``
     states = checkpoint_name(states, "kda_states")
     return o, (q, k, v, a_t, rows, beta_t, states)
 
 
-def _gdn_vjp_bwd(scale, eps, res, do):
-    dq, dk, dv, da, drows, dbeta = _gdn_bwd(*res, do, scale, eps)
+def _gdn_vjp_bwd(scale, eps, r, res, do):
+    dq, dk, dv, da, drows, dbeta = _gdn_bwd(*res, do, scale, eps, r)
     # the rows' partial sums a batch row -> the rows' gradient (a token of
     # the chunk still: autodiff adds those, the rows being a broadcast)
     return dq, dk, dv, da, drows.sum(0), dbeta
@@ -1692,27 +1706,70 @@ def _gdn_vjp_bwd(scale, eps, res, do):
 _gdn_kernels.defvjp(_gdn_vjp_fwd, _gdn_vjp_bwd)
 
 
+def _gdn_lanes(d_k: int, d_v: int):
+    """(the lanes a head's keys occupy, the lanes its values occupy) in
+    what the Gated DeltaNet kernels read. The delta rule is separable over
+    the value channels and a zero key channel changes no product, so a head
+    whose sizes are not whole 128-lane tiles takes the pair's body as it
+    is: its keys zero-padded to one tile, its values to whole tiles, each
+    tile a value head of 128 on the one key head with the head's own decay
+    and beta (a [96, 192] state is two [128, 128] ones side by side, a
+    quarter of each zero). Taken where more than half of what is read is
+    the model's; None else: the plain route's."""
+    lanes_v = -(-d_v // LANES) * LANES
+    if d_k <= LANES < 2 * d_k and lanes_v < 2 * d_v:
+        return LANES, lanes_v
+    return None
+
+
+def _lane_padded(x, heads: int, lanes: int):
+    """[B, T, heads * d] -> [B, T, heads * lanes], zeros after a head's d
+    channels; as it is where d is ``lanes``."""
+    b, t, hd = x.shape
+    if hd == heads * lanes:
+        return x
+    return jnp.pad(x.reshape(b, t, heads, hd // heads), (
+        (0, 0), (0, 0), (0, 0), (0, lanes - hd // heads))).reshape(
+        b, t, heads * lanes)
+
+
 def _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta, *, key_heads: int,
-                      hpb: int, scale: float, eps: float, chunk: int):
+                      hpb: int, scale: float, eps: float, chunk: int,
+                      lanes: tuple, r: int):
     """A Gated DeltaNet layer's call through the pair with the body for one
-    decay a head: q and k as they are, [B, T, Hk*128]; ``a`` and beta go in
-    head-major (``_head_major``) in float32, A_log and dt_bias as rows a
-    head block."""
+    decay a head: q and k [B, T, Hk*128] as they are (heads of fewer
+    channels zero-padded to the tile, ``_gdn_lanes``; zeros change neither
+    the l2 norm nor a product); v in tiles of 128, a head of more value
+    channels as that many value heads on its key head, each with the
+    head's ``a``, beta, A_log and dt_bias; ``a`` and beta go in head-major
+    (``_head_major``) in float32, A_log and dt_bias as rows a head block."""
     t, heads = q.shape[1], beta.shape[-1]
-    d = v.shape[-1] // heads
+    d_k, d_v = k.shape[-1] // key_heads, v.shape[-1] // heads
+    tiles = lanes[1] // LANES
+    q, k = (_lane_padded(x, key_heads, lanes[0]) for x in (q, k))
+    v = _lane_padded(v, heads, lanes[1])
+    if tiles > 1:
+        a, beta = (jnp.repeat(x, tiles, -1) for x in (a, beta))
+        a_log, dt_bias = (jnp.repeat(x, tiles) for x in (a_log, dt_bias))
     (q, k, v, a, beta), pad = pad_tokens(
         (q, k, v, a.astype(_F32), beta.astype(_F32)), chunk)
-    facts = _path_facts(chunk, t, pad, heads, d, d, eps, key_heads)
+    facts = _path_facts(chunk, t, pad, heads, d_k, d_v, eps, key_heads,
+                        lanes=lanes, r=r)
     facts.update(_block_facts(hpb))
     record_path("rtpu.ops.kda.path", PATH_COUNTS, "kernel", facts)
-    rows = jnp.pad(jnp.stack([a_log, dt_bias]).reshape(2, heads // hpb, hpb),
+    blocks = heads * tiles // hpb
+    rows = jnp.pad(jnp.stack([a_log, dt_bias]).reshape(2, blocks, hpb),
                    ((0, 0), (0, 0), (0, _HEAD_ROWS - hpb)))
     rows = jnp.broadcast_to(
-        rows.transpose(1, 0, 2).reshape(heads // hpb, 2 * _HEAD_ROWS, 1),
-        (heads // hpb, 2 * _HEAD_ROWS, chunk))
-    return _gdn_kernels(q, k, v, _head_major(a, chunk, hpb), rows,
-                        _head_major(beta, chunk, hpb), float(scale),
-                        float(eps))[:, :t]
+        rows.transpose(1, 0, 2).reshape(blocks, 2 * _HEAD_ROWS, 1),
+        (blocks, 2 * _HEAD_ROWS, chunk))
+    o = _gdn_kernels(q, k, v, _head_major(a, chunk, hpb), rows,
+                     _head_major(beta, chunk, hpb), float(scale),
+                     float(eps), r)[:, :t]
+    if lanes[1] == d_v:
+        return o
+    return o.reshape(*o.shape[:2], heads, lanes[1])[..., :d_v].reshape(
+        *o.shape[:2], heads * d_v)
 
 
 def _route(d_k: int, d_v: int, chunk: int) -> str:
@@ -1721,8 +1778,15 @@ def _route(d_k: int, d_v: int, chunk: int) -> str:
     return "kernel" if d_k == d_v == LANES and chunk == 64 else "chunked_jnp"
 
 
-def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):
-    """The facts of ``rtpu.ops.kda.path`` that every entry states.
+def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads,
+                lanes=None, r=None):
+    """The facts of ``rtpu.ops.kda.path`` that every entry states. ``d_k``
+    and ``d_v`` are the MODEL's head sizes; ``lanes_k`` and ``lanes_v`` the
+    lanes a head's keys and values occupy in what the route reads (the head
+    sizes themselves but on the kernel route of heads that are not whole
+    tiles, ``_gdn_lanes``: what the padding costs is (d_k + d_v) / (lanes_k
+    + lanes_v)); ``solve_block`` the rows of the solve's Neumann blocks
+    (``_solve_block``).
     ``key_heads`` None: a decay a key channel, a key head a value head, and
     the body that makes the decayed scores in sub-blocks; else the body
     with the head's one decay factored out of them (``body``: which
@@ -1730,7 +1794,9 @@ def _path_facts(chunk, tokens, pad, heads, d_k, d_v, eps, key_heads):
     ``channel_decay``)."""
     return {"chunk": chunk, "tokens": tokens, "padded_tokens": pad,
             "heads": heads, "d_k": d_k, "d_v": d_v,
-            "chunks": (tokens + pad) // chunk,
+            "lanes_k": (lanes or (d_k, d_v))[0],
+            "lanes_v": (lanes or (d_k, d_v))[1],
+            "chunks": (tokens + pad) // chunk, "solve_block": r or _SUB,
             "prologue": "jnp" if eps is None else "in_kernel",
             "decay": "channel" if key_heads is None else "head",
             "body": "channel_decay" if key_heads is None else "head_decay",
@@ -1812,40 +1878,56 @@ def kda_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     return kda_scan(unit(q), unit(k), v, g, beta, scale=scale, chunk=chunk)
 
 
+def _gdn_head_sizes(q, k, v, beta, key_heads):
+    """(value heads, key heads, d_k, d_v) of merged arrays; the key heads as
+    stated or, where keys and values share one head size, known from it."""
+    heads = beta.shape[-1]
+    d_v = v.shape[-1] // heads
+    key_heads = key_heads or k.shape[-1] // d_v
+    if heads % key_heads or q.shape[-1] != k.shape[-1] \
+            or k.shape[-1] % key_heads:
+        raise ValueError(f"{heads} value heads over {key_heads} key heads "
+                         f"of {d_v}: q {q.shape}, k {k.shape}, v {v.shape}")
+    return heads, key_heads, k.shape[-1] // key_heads, d_v
+
+
 def gated_delta_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, *, scale: float,
-                     chunk: int = 64) -> jax.Array:
+                     beta: jax.Array, *, scale: float, chunk: int = 64,
+                     key_heads: int = None,
+                     beta_max: float = 1.0) -> jax.Array:
     """The gated delta rule with ONE decay a head (Gated DeltaNet): the
     plain definition, in the chunked form with the decay factored out
-    (the module's docstring). q, k [batch, seq, key_heads * d] as the
-    caller normalised them, v [batch, seq, value_heads * d], g (<= 0, the
-    log decay, float32) and beta [batch, seq, value_heads]; keys and
-    values share the head size d (which is how the key heads are known
-    from merged arrays), value_heads is a multiple of key_heads and value
-    head j reads key head j // (value_heads / key_heads) -> o [batch, seq,
-    value_heads * d] in q's dtype. Differentiable in all five. Always the
+    (the module's docstring). q, k [batch, seq, key_heads * d_k] as the
+    caller normalised them, v [batch, seq, value_heads * d_v], g (<= 0, the
+    log decay, float32) and beta [batch, seq, value_heads], beta as the
+    caller made it (a sigmoid, or twice one where the correction may turn
+    a key's component round: say so with ``beta_max``, which sizes the
+    solve's blocks, ``_solve_block``, and changes nothing else). Keys and
+    values may differ in head size where ``key_heads`` is stated; left out,
+    they share one size d, which is how the key heads are then known from
+    merged arrays. value_heads is a multiple of key_heads and value head j
+    reads key head j // (value_heads / key_heads) -> o [batch, seq,
+    value_heads * d_v] in q's dtype. Differentiable in all five. Always the
     route ``chunked_jnp``; ``chunk`` is how the work is cut, not what is
     computed."""
     t = q.shape[1]
-    heads = beta.shape[-1]
-    d = v.shape[-1] // heads
-    key_heads = k.shape[-1] // d
-    if heads % key_heads or q.shape[-1] != k.shape[-1]:
-        raise ValueError(f"{heads} value heads over {key_heads} key heads "
-                         f"of {d}: q {q.shape}, k {k.shape}, v {v.shape}")
+    heads, key_heads, d_k, d_v = _gdn_head_sizes(q, k, v, beta, key_heads)
     chunk = min(chunk, t)
+    r = _solve_block(beta_max)
     (q, k, v, g, beta), pad = pad_tokens(
         (q, k, v, g.astype(_F32), beta.astype(_F32)), chunk)
     record_path("rtpu.ops.kda.path", PATH_COUNTS, "chunked_jnp",
-                _path_facts(chunk, t, pad, heads, d, d, None, key_heads))
+                _path_facts(chunk, t, pad, heads, d_k, d_v, None, key_heads,
+                            r=r))
     return _head_decay_chunked(q, k, v, g, beta, key_heads, chunk,
-                               float(scale))[:, :t]
+                               float(scale), r)[:, :t]
 
 
 def gdn_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
                    a_log: jax.Array, dt_bias: jax.Array, beta: jax.Array, *,
-                   scale: float, eps: float = 1e-6,
-                   chunk: int = 64) -> jax.Array:
+                   scale: float, eps: float = 1e-6, chunk: int = 64,
+                   key_heads: int = None,
+                   beta_max: float = 1.0) -> jax.Array:
     """A Gated DeltaNet layer's scan from what its convolution and its
     ``b | a`` projection made: ``gated_delta_scan`` of q and k each
     normalised to unit length a key head (``layers.l2norm`` with ``eps``)
@@ -1853,30 +1935,35 @@ def gdn_gated_scan(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
 
         g = -exp(a_log) * softplus(a + dt_bias)      float32, a value head
 
-    q, k [batch, seq, key_heads * d], v [batch, seq, value_heads * d], a
-    and beta [batch, seq, value_heads], a_log and dt_bias [value_heads].
+    q, k [batch, seq, key_heads * d_k], v [batch, seq, value_heads * d_v],
+    a and beta [batch, seq, value_heads], a_log and dt_bias [value_heads];
+    ``key_heads`` and ``beta_max`` as ``gated_delta_scan`` takes them.
     Differentiable in all seven arrays. That sentence is this function on
-    the plain route, literally. On the kernel route (heads of 128, a chunk
-    of 64, a block of at most four value heads that holds whole key heads)
-    it is the kernel pair with the body for one decay a head (the module's
-    docstring): a program reads q and k ONCE a key head from these arrays
-    as they are, makes the norms, the gate and its cumulative sums itself
-    (a number a value head and token: no float32 g, no copy of ``a`` over a
-    head's lanes is written), one product of scores a key head and a [C,
-    C] table of decays a value head over it; dq and dk come back summed
-    over a key head's value heads."""
-    heads = beta.shape[-1]
-    d = v.shape[-1] // heads
-    key_heads = k.shape[-1] // d
+    the plain route, literally. On the kernel route (a chunk of 64, heads
+    whose keys fill more than half of one 128-lane tile and whose values
+    more than half of whole tiles, ``_gdn_lanes``, a block of at most four
+    value tiles that holds whole key heads) it is the kernel pair with the
+    body for one decay a head (the module's docstring): a program reads q
+    and k ONCE a key head from these arrays as they are, makes the norms,
+    the gate and its cumulative sums itself (a number a value head and
+    token: no float32 g, no copy of ``a`` over a head's lanes is written),
+    one product of scores a key head and a [C, C] table of decays a value
+    head over it; dq and dk come back summed over a key head's value
+    heads."""
+    heads, key_heads, d_k, d_v = _gdn_head_sizes(q, k, v, beta, key_heads)
     a_log, dt_bias = a_log.astype(_F32), dt_bias.astype(_F32)
-    hpb = _gdn_heads_per_block(heads, heads // key_heads) \
-        if heads % key_heads == 0 and q.shape[-1] == k.shape[-1] else None
-    if _route(d, d, chunk) == "kernel" and hpb:
+    lanes = _gdn_lanes(d_k, d_v)
+    tiles = lanes[1] // LANES if lanes else 0
+    hpb = lanes and _gdn_heads_per_block(heads * tiles,
+                                         heads // key_heads * tiles)
+    if hpb and _route(lanes[0], LANES, chunk) == "kernel":
         return _gdn_kernel_route(q, k, v, a, a_log, dt_bias, beta,
                                  key_heads=key_heads, hpb=hpb, scale=scale,
-                                 eps=eps, chunk=chunk)
+                                 eps=eps, chunk=chunk, lanes=lanes,
+                                 r=_solve_block(beta_max))
     unit = lambda x: l2norm(                                 # noqa: E731
-        x.reshape(*x.shape[:2], key_heads, d), eps).reshape(x.shape)
+        x.reshape(*x.shape[:2], key_heads, d_k), eps).reshape(x.shape)
     g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(_F32) + dt_bias)
     return gated_delta_scan(unit(q), unit(k), v, g, beta, scale=scale,
-                            chunk=chunk)
+                            chunk=chunk, key_heads=key_heads,
+                            beta_max=beta_max)

@@ -299,3 +299,37 @@ def test_lfm2moe_train_step_keeps_its_room(one_chip, compiled_kernels, tool):
     assert all(re.match(r"%?short_conv_(fwd|bwd)(\.\d+)?$", m)
                for m in made), made
     assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 6
+
+
+# 25 s alone; beside five other workers it can pass the default 180 s
+@pytest.mark.time_limit(480)
+def test_olmohybrid_train_step_keeps_its_room(one_chip, compiled_kernels,
+                                              tool):
+    """ISSUE 67: olmohybrid_train_s8192's own train step (the harness's
+    ``make_train_step``, the cell's configuration, optimizer, batch 1 of
+    8192, parameters and optimizer state donated) for the described v5e:
+    766.2 M parameters at 12 B as arguments (9.20 GB) and 7.81 GB of
+    temporaries (they overlap the donated state; 9.92 at batch 2, accepted
+    too) with a Gated DeltaNet layer keeping its input alone and the
+    attention layer its kernels' output and row statistics. The delta rule
+    runs the KERNEL route on padded lanes: Gated DeltaNet's own pair
+    (``gdn_chunk_fwd`` twice in the scanned run's loops, the forward sweep
+    and the rematerialised layer, ``gdn_chunk_bwd`` once; KDA's not in the
+    program), all three under the scope ``scan``, q and k read as [1, 8192,
+    15 x 128] and v as [1, 8192, 30 x 128]; the ONE attention layer's flash
+    kernels stand once each."""
+    compiled = tool.compile_step("olmohybrid_train_s8192", one_chip)
+    assert 9.1e9 < fits(compiled) < 9.3e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 8.2e9
+    text = compiled.as_text()
+    assert "s32[1,8192]" in text            # the cell's batch, not another
+    assert tool.compiler_remat(text) <= 4
+    count = _kernel_count(text)
+    assert count("flash_fwd") == 1          # kept: not run again
+    assert count("flash_bwd_dq") + count("flash_bwd_fused") == 1
+    assert count("gdn_chunk_fwd") == 2 and count("gdn_chunk_bwd") == 1
+    assert count("kda_chunk_fwd") == 0 and count("kda_chunk_bwd") == 0
+    for line in text.splitlines():          # all three under the scope
+        if re.match(r"\s*%?gdn_chunk_(fwd|bwd)(\.\d+)? = ", line):
+            assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
+            assert "bf16[1,8192,1920]" in line and "bf16[1,8192,3840]" in line

@@ -609,6 +609,52 @@ def test_gdn_gated_scan_at_the_benchmark_cells_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
+def test_gdn_gated_scan_on_padded_lanes_at_the_benchmark_cells_shape(
+        one_chip, compiled_kernels):
+    """ISSUE 67: olmohybrid_train_s8192's Gated DeltaNet scan, B=1, S=8192,
+    15 heads of 96 key and 192 value channels, fed as the model feeds it (q
+    and k merged [B, S, 15 x 96], v [B, S, 15 x 192], ``a`` bf16 and beta
+    float32 [B, S, 15], the key heads stated, ``beta_max`` 2): the call
+    takes the KERNEL route on padded lanes (a head's keys on one 128-lane
+    tile, its values on two; the solve in blocks of 4), forward and
+    backward are ONE ``gdn_chunk_fwd`` and ONE ``gdn_chunk_bwd`` and both
+    compile for the chip. The kernels read q and k at 15 x 128 columns and
+    v at 30 x 128; what the padding adds to HBM is those copies, their
+    gradients' and the padded o, no float32 array of the batch, no score
+    matrix and no decay table."""
+    kda = importlib.import_module("ray_tpu.ops.kda_scan")
+    b, t, h, dk, dv = 1, 8192, 15, 96, 192
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    args = (sd((b, t, h * dk)), sd((b, t, h * dk)), sd((b, t, h * dv)),
+            sd((b, t, h)), sd((h,), jnp.float32), sd((h,), jnp.float32),
+            sd((b, t, h), jnp.float32))
+
+    def loss(*a):
+        return kda.gdn_gated_scan(*a, scale=dk ** -0.5, key_heads=h,
+                                  beta_max=2.0).astype(jnp.float32).sum()
+
+    before = kda.PATH_COUNTS.copy()
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(7)))
+                       ).lower(*args).compile()
+    assert kda.PATH_COUNTS - before == {"kernel": 1}
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    for part in ("gdn_fwd", "gdn_bwd"):
+        name = kda.KERNEL_NAMES[part]
+        found = [c for c in calls if name in c.split(" = ")[0]]
+        assert len(found) == 1, (name, calls)
+        assert "bf16[1,8192,1920]" in found[0] and "bf16[1,8192,3840]" in \
+            found[0]
+    assert not re.findall(r"\w+\[[\d,]*64,64\]", text)
+    assert "f32[1,8192,3840]" not in text and "f32[1,8192,1920]" not in text
+    assert "f32[1,128,3840,128]" in text      # the chunk-start states
+    # q, k, v padded, their gradients, o padded and the states (252 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_sparse_attention_layer_at_the_benchmark_cells_shape(
         one_chip, compiled_kernels):
     """ISSUE 59: keyevl2_train_s16384's attention sublayer (norm, q / k / v

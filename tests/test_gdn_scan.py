@@ -83,24 +83,27 @@ def gdn_gated_recurrence(q, k, v, a, a_log, dt_bias, beta, *, scale):
                           scale=scale)
 
 
-def gdn_both(args, do, **kw):
+def o_and_grads(f, args, do):
+    """{o and the gradient of every argument of ``f``} under the cotangent
+    ``do``; the arguments the five of a scan or a layer's seven."""
     names = GDN_GATED if "a" in args else GDN
+
+    def scalar(*a):
+        o = f(*a)
+        return jnp.sum(o.astype(jnp.float32) * do), o
+
+    (_, o), g = jax.jit(jax.value_and_grad(
+        scalar, argnums=tuple(range(len(names))), has_aux=True))(
+        *(args[n] for n in names))
+    return dict(zip(("o",) + names, (o,) + g))
+
+
+def gdn_both(args, do, **kw):
     ref, fn = (gdn_gated_recurrence, kda.gdn_gated_scan) if "a" in args \
         else (gdn_recurrence, kda.gated_delta_scan)
     scale = D ** -0.5
-
-    def grads(f):
-        def scalar(*a):
-            o = f(*a)
-            return jnp.sum(o.astype(jnp.float32) * do), o
-
-        (_, o), g = jax.jit(jax.value_and_grad(
-            scalar, argnums=tuple(range(len(names))), has_aux=True))(
-            *(args[n] for n in names))
-        return dict(zip(("o",) + names, (o,) + g))
-
-    return (grads(lambda *a: fn(*a, scale=scale, **kw)),
-            grads(lambda *a: ref(*a, scale=scale)))
+    return (o_and_grads(lambda *a: fn(*a, scale=scale, **kw), args, do),
+            o_and_grads(lambda *a: ref(*a, scale=scale), args, do))
 
 
 def gdn_worst(got, want, args):
@@ -406,7 +409,191 @@ def test_a_gated_deltanet_scans_path_event(route):
     facts = {"route": route, "chunk": 64, "tokens": 150, "padded_tokens": 42,
              "heads": 4, "d_k": D, "d_v": D, "chunks": 3, "decay": "head",
              "key_heads": 2, "body": "head_decay",
+             # ISSUE 67: heads of 128 are whole tiles; the solve's blocks
+             "lanes_k": D, "lanes_v": D, "solve_block": 8,
              "prologue": "in_kernel" if route == "kernel" else "jnp"}
     if route == "kernel":      # ISSUE 66: two pairs' solves in lock step
         facts.update(heads_per_block=4, pairs_in_step=2)
     assert data == facts
+
+
+# -- ISSUE 67: key and value heads of unlike sizes, beta up to 2 --------------
+
+DK, DV = 96, 192      # Olmo-Hybrid's head: a state [96, 192]
+
+
+def unlike_arguments(seed, t, key_heads, value_heads, batch=2, alike=None,
+                     beta=None):
+    """``gdn_arguments(raw=True)`` at key heads of 96 and value heads of
+    192, beta drawn in (0, 2) (``2 sigmoid``, what a layer with
+    ``allow_neg_eigval`` hands its scan) or ``beta`` everywhere; ``alike``:
+    neighbouring keys alike in direction (k_i . k_j near 0.8) and a weak
+    decay, the solve's hard case."""
+    r = jax.random.split(jax.random.PRNGKey(seed), 10)
+
+    def keys(key, length, around=None):
+        x = jax.random.normal(key, (batch, t, key_heads, DK))
+        if around is not None:
+            x = jax.random.normal(r[6], (batch, 1, key_heads, DK)) + around * x
+        x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        x = x * jnp.exp(jax.random.uniform(
+            length, (batch, t, key_heads, 1), minval=np.log(1e-2),
+            maxval=np.log(10.0)))
+        return x.reshape(batch, t, key_heads * DK)
+
+    dt = jnp.exp(jax.random.uniform(r[4], (value_heads,), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    a_log = jnp.log(jax.random.uniform(r[3], (value_heads,), minval=1.0,
+                                       maxval=16.0))
+    shape = (batch, t, value_heads)
+    args = {"q": keys(r[0], r[7]), "k": keys(r[1], r[8], alike),
+            "v": jax.random.normal(r[2], (batch, t, value_heads * DV)),
+            "a": 0.5 * jax.random.normal(r[5], shape),
+            "a_log": a_log if alike is None else a_log + np.log(0.05),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "beta": 2.0 * jax.nn.sigmoid(jax.random.normal(r[9], shape))
+            if beta is None else jnp.full(shape, beta)}
+    return args, jax.random.normal(r[6], (batch, t, value_heads * DV))
+
+
+def unlike_recurrence(q, k, v, a, a_log, dt_bias, beta, *, key_heads, scale,
+                      **wrong):
+    """The definition at unlike head sizes, token by token: q and k of unit
+    length a key head, repeated to the value heads; the head's one decay on
+    all of its key channels; a state [96, 192] a value head."""
+    b, t, hv = beta.shape
+    wide = lambda x: jnp.repeat(                             # noqa: E731
+        unit_heads(x, key_heads).reshape(b, t, key_heads, DK),
+        hv // key_heads, 2).reshape(b, t, hv * DK)
+    g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+    return recurrence(wide(q), wide(k), v, jnp.repeat(g, DK, -1), beta,
+                      scale=scale, heads=hv, **wrong)
+
+
+def unlike_both(args, do, key_heads, beta_max=2.0, **wrong):
+    scale = DK ** -0.5
+    return (o_and_grads(lambda *a: kda.gdn_gated_scan(
+        *a, scale=scale, key_heads=key_heads, beta_max=beta_max), args, do),
+        o_and_grads(lambda *a: unlike_recurrence(
+            *a, key_heads=key_heads, scale=scale, **wrong), args, do))
+
+
+@pytest.mark.parametrize("t,heads", [(150, (3, 3)), (150, (2, 4)),
+                                     (64, (1, 1)), (40, (2, 2))],
+                         ids=["ragged-odd_heads", "ragged-two_to_one",
+                              "one_chunk", "short"])
+def test_heads_of_unlike_sizes_are_the_recurrence(route, t, heads):
+    """ISSUE 67: ``gdn_gated_scan`` with the key heads STATED, keys of 96
+    and values of 192, beta drawn in (0, 2), on both routes: o and the
+    seven gradients against the recurrence on a [96, 192] state. On the
+    kernel route a head's keys are zero-padded to one 128-lane tile and its
+    values to two tiles, each a value head of 128 on the one key head (a
+    program: one key head and its two tiles, or two key heads and their
+    four where the heads are even); the solve in blocks of 4."""
+    args, do = unlike_arguments(2, t, *heads)
+    before = kda.PATH_COUNTS.copy()
+    got, want = unlike_both(args, do, heads[0])
+    took(route, before)
+    assert got["o"].shape == (2, t, heads[1] * DV)
+    for name, err in gdn_worst(got, want, args).items():
+        assert err < F32_TOL, (name, err)
+        assert got[name].shape == args.get(name, got["o"]).shape
+
+
+@pytest.mark.parametrize("fault,least", [
+    ({"state_dtype": jnp.bfloat16}, 1e-3), ("beta_left_at_sigmoid", 1e-2)],
+    ids=["bf16_state", "beta_left_at_sigmoid"])
+def test_a_wrong_scan_of_unlike_heads_would_fail(route, fault, least):
+    """What ``F32_TOL`` is for at these shapes: a state rounded to bf16
+    after every token (o off by 1.2e-2 of its largest entry), and beta left
+    at the sigmoid where the layer hands twice that (0.54): a thousand
+    times over it and more."""
+    args, do = unlike_arguments(2, 150, 3, 3)
+    if fault == "beta_left_at_sigmoid":
+        got, _ = unlike_both(dict(args, beta=0.5 * args["beta"]), do, 3)
+        _, want = unlike_both(args, do, 3)
+    else:
+        got, want = unlike_both(args, do, 3, **fault)
+    assert gdn_worst(got, want, args)["o"] > least >= 100 * F32_TOL
+
+
+def test_the_plain_definition_takes_unlike_head_sizes():
+    """``gated_delta_scan`` with ``key_heads`` stated: q and k [B, T, Hk *
+    96] as the caller normalised them, v [B, T, Hv * 192], against the
+    recurrence, o and five gradients. (Left unstated, these merged arrays
+    would read as ONE key head of 192 under four value heads, a shape like
+    any other: nothing to refuse, which is why the layer states it.)"""
+    args, do = unlike_arguments(3, 150, 2, 4)
+    g = -jnp.exp(args["a_log"]) * jax.nn.softplus(args["a"] + args["dt_bias"])
+    plain = {"q": unit_heads(args["q"], 2), "k": unit_heads(args["k"], 2),
+             "v": args["v"], "g": g, "beta": args["beta"]}
+    scale = DK ** -0.5
+    wide = lambda x: jnp.repeat(x.reshape(2, 150, 2, DK), 2, 2).reshape(  # noqa: E731
+        2, 150, 4 * DK)
+    before = kda.PATH_COUNTS.copy()
+    got = value_and_grads(lambda *a: kda.gated_delta_scan(
+        *a, scale=scale, key_heads=2, beta_max=2.0), plain, do)
+    took("chunked_jnp", before)
+    want = value_and_grads(lambda q, k, v, g, beta: recurrence(
+        wide(q), wide(k), v, jnp.repeat(g, DK, -1), beta, scale=scale,
+        heads=4), plain, do)
+    for name, err in gdn_worst(got, want, plain).items():
+        assert err < F32_TOL, (name, err)
+    with pytest.raises(ValueError, match="4 value heads over 3 key heads"):
+        kda.gated_delta_scan(*(plain[n] for n in GDN), scale=scale,
+                             key_heads=3)
+
+
+def test_heads_of_128_are_untouched_by_the_new_arguments(route):
+    """Qwen3-Next's call: the key heads stated or known from the one head
+    size, it is the same jaxpr and the same numbers to the bit; the solve
+    in blocks of 8 and the lanes the head sizes. (That the cell's whole
+    train step is the program it was: ``scripts/train_step_hlo.py
+    --compare``.)"""
+    from ray_tpu.perf import recorder
+
+    args, _ = gdn_arguments(4, 150, raw=True)
+    xs = [args[n] for n in GDN_GATED]
+    as_was = lambda *a: kda.gdn_gated_scan(*a, scale=D ** -0.5)  # noqa: E731
+    stated = lambda *a: kda.gdn_gated_scan(                      # noqa: E731
+        *a, scale=D ** -0.5, key_heads=2, beta_max=1.0)
+    assert str(jax.make_jaxpr(as_was)(*xs)) == str(jax.make_jaxpr(stated)(*xs))
+    np.testing.assert_array_equal(jax.jit(as_was)(*xs), jax.jit(stated)(*xs))
+    data = [e["data"] for e in recorder.get_recorder().snapshot()
+            if e["kind"] == "rtpu.ops.kda.path"][-1]
+    assert (data["lanes_k"], data["lanes_v"], data["solve_block"]) == (D, D, 8)
+
+
+def test_which_heads_take_the_padded_kernel_route():
+    """More than half of what the kernels read of a head is the model's, or
+    the plain route runs: 96 and 192 take the pair on 128 and 256 lanes (75
+    %), heads of 64 stay the plain form's (two to a tile: a packed layout's
+    to win, ROADMAP), heads of 128 are whole tiles."""
+    assert [kda._gdn_lanes(*d) for d in (
+        (96, 192), (128, 128), (64, 64), (96, 64), (64, 128), (128, 256),
+        (192, 192), (100, 130))] == [
+        (128, 256), (128, 128), None, None, None, (128, 256), None,
+        (128, 256)]
+    args, _ = unlike_arguments(0, 64, 1, 8)     # 16 tiles on one key head
+    before = kda.PATH_COUNTS.copy()
+    jax.eval_shape(lambda *a: kda.gdn_gated_scan(
+        *a, scale=1.0, key_heads=1), *(args[n] for n in GDN_GATED))
+    took("chunked_jnp", before)
+
+
+def test_keys_alike_at_beta_near_two_need_blocks_of_four(route):
+    """The beta-to-2 study end to end (``test_kda_scan.py`` has the solve
+    alone): neighbouring keys alike, a weak decay, beta 1.9 everywhere. With
+    ``beta_max`` 2 (blocks of 4) o and every gradient hold ``F32_TOL``; the
+    same call with the solve left at blocks of 8 reads two to three times
+    worse and misses it."""
+    args, do = unlike_arguments(5, 150, 2, 2, alike=0.5, beta=1.9)
+    before = kda.PATH_COUNTS.copy()
+    got, want = unlike_both(args, do, 2)
+    took(route, before)
+    fine = gdn_worst(got, want, args)
+    for name, err in fine.items():
+        assert err < F32_TOL, (name, err)
+    got, _ = unlike_both(args, do, 2, beta_max=1.0)
+    coarse = gdn_worst(got, want, args)
+    assert max(coarse.values()) > F32_TOL > max(fine.values()), (coarse, fine)

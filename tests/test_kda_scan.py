@@ -364,7 +364,9 @@ def test_path_event_and_padding(route, entry):
              "padded_tokens": 42, "heads": 2, "d_k": D, "d_v": D, "chunks": 3,
              "prologue": "in_kernel" if (entry, route) == ("gated", "kernel")
              else "jnp", "decay": "channel", "key_heads": 2,
-             "body": "channel_decay"}     # ISSUE 53: which body a run measured
+             "body": "channel_decay",     # ISSUE 53: which body a run measured
+             # ISSUE 67: the lanes a head occupies, the solve's blocks
+             "lanes_k": D, "lanes_v": D, "solve_block": 8}
     if route == "kernel":      # ISSUE 66: one pair, solved alone
         facts.update(heads_per_block=2, pairs_in_step=1)
     assert events and events[-1]["data"] == facts
@@ -513,3 +515,51 @@ def test_other_shapes_fall_back_to_the_plain_route(entry):
         took(want, before)
     assert [kda._heads_per_block(n) for n in (1, 2, 3, 6, 32)] == [
         1, 2, 3, 2, 4]
+
+
+# -- ISSUE 67: the solve where beta reaches 2 ---------------------------------
+
+def _alike_a(beta, n=64, heads=8, seed=105):
+    """A = beta tril(K K^T e^(G_i - G_j), -1) [heads, n, n] float32 of keys
+    alike in direction (k_i . k_j near 0.8) under a weak decay."""
+    r = jax.random.split(jax.random.PRNGKey(seed), 2)
+    k = jax.random.normal(r[0], (heads, 1, D)) \
+        + 0.5 * jax.random.normal(r[1], (heads, n, D))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -0.002 * jnp.arange(n)
+    a = jnp.einsum("hid,hjd->hij", k, k) * jnp.exp(g[:, None] - g[None]) * beta
+    return jnp.tril(a, -1).astype(jnp.float32)
+
+
+def _off(t, a):
+    """T's largest error against float64's (I + A)^-1, as a share of that
+    inverse's largest entry."""
+    want = np.linalg.inv(np.eye(a.shape[-1]) + np.asarray(a, np.float64))
+    return float(np.max(np.abs(np.asarray(t, np.float64) - want))
+                 / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_the_solve_holds_float32_where_beta_reaches_two(form):
+    """The accuracy study of ``test_keys_alike_are_solved_in_blocks`` taken
+    to beta in (0, 2) (Gated DeltaNet with ``allow_neg_eigval``: beta = 2
+    sigmoid), the solve alone against float64, neighbouring keys alike. A
+    diagonal block's Neumann terms grow as C(r - 1, n) a^n before they
+    cancel and a = beta k_i . k_j doubles with beta. Read here (plain form;
+    the kernels' ``_solve`` the same to the digit shown): blocks of 8 are
+    off by 9.4e-7 at beta 0.9, 1.5e-6 at 1, 7.7e-6 at 1.5 and 3.3e-5 at 2;
+    blocks of 4 by 3.0e-7, 3.1e-7, 7.7e-7 and 2.9e-6; blocks of 2 no better
+    than 4 (2.5e-6 at 2: what is left is the merges' and the problem's
+    own). So a call that says its beta reaches past 1 is solved in blocks
+    of 4 (``_solve_block``: as many products, one doubling less, one merge
+    more) and every other call as before: at beta 2 it holds 5e-6 where
+    blocks of 8 miss 2e-5."""
+    solve = {"plain": lambda a, r: kda._inverse_by_blocks(a, r),
+             "kernel": lambda a, r: jnp.stack(
+                 [kda._solve(x, r) for x in a])}[form]
+    assert (kda._solve_block(1.0), kda._solve_block(2.0)) == (8, 4)
+    off = {(beta, r): _off(solve(_alike_a(beta), r), _alike_a(beta))
+           for beta in (0.9, 2.0) for r in (8, 4)}
+    assert off[0.9, 8] < 2e-6 and off[0.9, 4] < 2e-6, off
+    assert off[2.0, 8] > 2e-5, off          # sixteen times what it was
+    assert off[2.0, kda._solve_block(2.0)] < 5e-6, off

@@ -280,7 +280,10 @@ def test_the_delta_rule_scan_and_its_stack_leave_their_events():
         # ISSUE 52: KDA's decay is one a key channel, a key head a value head
         "decay": "channel", "key_heads": 2,
         # ISSUE 53: the body that makes the decayed scores in sub-blocks
-        "body": "channel_decay"}
+        "body": "channel_decay",
+        # ISSUE 67: the lanes a head occupies in what the route reads (the
+        # head sizes: whole tiles), the rows of the solve's Neumann blocks
+        "lanes_k": 128, "lanes_v": 128, "solve_block": 8}
     # both kernels stand under the scope the roofline reads, and the other
     # rule's are not in this program
     for part in ("fwd", "bwd"):
@@ -331,7 +334,8 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "route": "kernel", "chunk": 64, "tokens": 256, "padded_tokens": 0,
         "heads": 4, "d_k": 128, "d_v": 128, "chunks": 4,
         "heads_per_block": 4, "prologue": "in_kernel", "decay": "head",
-        "key_heads": 2, "body": "head_decay", "pairs_in_step": 2}
+        "key_heads": 2, "body": "head_decay", "pairs_in_step": 2,
+        "lanes_k": 128, "lanes_v": 128, "solve_block": 8}    # ISSUE 67
     assert last("rtpu.ops.expert_layer")["data"] == {
         "experts_held": 2, "of": 8, "top_k": 3, "expert_offset": 4,
         # ISSUE 57: the slots of a token's pairs, min(top k, experts held)
@@ -353,6 +357,59 @@ def test_a_gated_deltanet_stack_leaves_its_events():
     assert runs["data"]["runs"] == [["gdn_moe", 3], ["attn_moe", 1]]
     assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
     assert runs["data"]["side_state_bytes"] == 0
+
+
+def test_a_share_of_an_olmo_hybrid_stack_leaves_its_events():
+    """ISSUE 67: what an Olmo-Hybrid shaped loss leaves at trace time.
+    ``rtpu.ops.kda.path``: the kernel route with ``decay`` ``head`` at the
+    MODEL's head sizes, ``d_k`` 96 and ``d_v`` 192, and the two facts this
+    PR adds, ``lanes_k`` 128 and ``lanes_v`` 256 (the lanes a head's keys
+    and values occupy in what the kernels read: ``gdn_lane_fill_pct`` is
+    100 x 288 / 384), the solve in blocks of 4 (beta reaches 2), one key
+    head and its two value tiles a program; ``rtpu.ops.flash.path``: heads
+    of 128 read from the merged arrays with no copy; ``rtpu.models.stack.
+    runs``: 3 scanned Gated DeltaNet layers that keep their inputs alone,
+    then the attention layer with the flash kernels' output and row
+    statistics, and the share of the heads held. No new kernel name: the
+    pair is Gated DeltaNet's (``gdn_chunk_fwd`` / ``gdn_chunk_bwd``), under
+    the scope ``scan``, and the one-part flash kernels under ``attn``."""
+    from ray_tpu.models import OlmoHybrid, OlmoHybridConfig
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = OlmoHybrid(OlmoHybridConfig.tiny(n_head=4, heads_held=3,
+                                         head_offset=1))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    before = kda.PATH_COUNTS.copy()
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    assert set(kda.PATH_COUNTS - before) == {"kernel"}
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    assert last("rtpu.ops.kda.path")["data"] == {
+        "route": "kernel", "chunk": 64, "tokens": 256, "padded_tokens": 0,
+        "heads": 3, "d_k": 96, "d_v": 192, "lanes_k": 128, "lanes_v": 256,
+        "chunks": 4, "solve_block": 4, "heads_per_block": 2,
+        "pairs_in_step": 1, "prologue": "in_kernel", "decay": "head",
+        "key_heads": 3, "body": "head_decay"}
+    flash = last("rtpu.ops.flash.path")
+    assert flash["label"] != "relayout" and flash["data"]["hd"] == 128
+    for part in ("fwd", "bwd"):
+        name = kda.KERNEL_NAMES["gdn_" + part]
+        assert re.search(r"scan/[^\n]*" + name, text), name
+        assert kda.KERNEL_NAMES[part] not in text
+    assert re.search(r"attn/[^\n]*flash", text)
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "olmo_hybrid"][-1]
+    assert runs["data"]["runs"] == [["gdn", 3], ["attn", 1]]
+    assert runs["data"]["kept"] == [[], ["flash_out", "flash_lse"]]
+    assert runs["data"]["heads"] == [3, 4]
+    assert runs["data"]["head_offset"] == 1
 
 
 def test_a_share_of_a_latent_expert_stack_leaves_its_events():
