@@ -20,8 +20,9 @@ expert as a prefetched scalar; tiles past the last used one are skipped
 by predicate and fetch nothing, so the time follows the rows that are
 there while the grid does not. Rows move by gathers in both directions
 (forward and backward each know token -> row and row -> token), never by
-a scatter; they are made for the whole buffer, so that only the grouped
-product's time follows the routing.
+a scatter; they are made for the whole buffer, so that only the time of
+``experts`` (the grouped products and, since PR 68, the kernel pair of the
+first half) follows the routing.
 
 A padding row of the buffer (the ragged end of an expert's last tile) is
 NOT zeros: it holds whatever the gather's clamped index brought, a copy of
@@ -63,6 +64,33 @@ compiler schedules beside the gathers and read 0.3 to 2.9 % slower
 (PERF.md, PR 65). The choice is one static fact of the call, as the
 compaction's is, and the event's ``slot_axis`` states it.
 
+The routed experts' first half is a kernel PAIR (``expert_hidden``, PR 68):
+``h = silu(x W_gate) * (x W_up) * row_weight`` (``swiglu``) or ``relu(x
+W_up)^2 * row_weight`` (``relu2``) over the buffer's tiles in ONE call, on
+the grouped product's pattern (grid over the row tiles, ``tile_expert`` and
+``n_used`` prefetched). The forward kernel KEEPS in VMEM the f32 sums of a
+tile's product(s) with its expert's matrices, rounds them where the grouped
+product rounds, applies the activation and the row's weight there in f32 and
+WRITES h alone, in the buffer's dtype: no pre-activation of the worst-case
+buffer goes to HBM, comes back, is widened or weighted by a pass of its own.
+The backward kernel takes x, the rows' weights and h's cotangent (``e_down``'s
+transposed grouped product), makes the pre-activations AGAIN in VMEM (two
+products a tile) and WRITES their cotangents in the buffer's dtype (what
+``grouped_matmul_dw`` reads for ``dW_gate`` / ``dW_up``), the rows' weights'
+cotangent and ``dx = dg W_gate^T + du W_up^T`` summed in f32 in VMEM and
+rounded once: no add over [rows, D]. Both SKIP a tile past ``n_used`` as the
+grouped product does (its step names the block that is already there:
+nothing fetched, computed or written), so the whole of ``experts`` follows
+the rows that are there. Where an expert's matrices do not fit the kernels'
+fast memory beside their second buffers (``hidden_block``, from the widths
+and ``VMEM_BYTES``) the grid takes a second axis over blocks of F: outside
+the tiles forward (the activation is elementwise in F; a block of weights
+stays put over its expert's tiles), inside them backward (dx and the rows'
+weights' cotangent are sums over F, made in scratch). ``e_down``'s product,
+``grouped_matmul_dw`` and the shared expert (``_gated`` / ``_relu2`` over
+``jnp.dot``: the tokens themselves, no padding) are as they were. The event
+says ``mlp_in`` ``kernel`` and the block of F (``mlp_in_block``).
+
 ``balance_term`` is a router's load-balancing term (a softmax router's or,
 ``score="sigmoid"``, a sigmoid router's with its selection bias), for a
 model that adds it to its loss: it reads the router alone (every chip holds
@@ -70,7 +98,8 @@ it whole), so a share states it as the uncut layer does.
 
 Scopes (``jax.named_scope``, pinned in tests/test_tracing_names.py):
 ``router`` (scores, top-k, the sort, the gather into the buffer and the
-weighted sum back), ``experts`` (the grouped products), ``shared_expert``
+weighted sum back), ``experts`` (the kernel pair of the first half and the
+grouped products), ``shared_expert``
 (where the layer has one), ``latent_proj`` (the projections into and out
 of the experts' latent, where the layer has one).
 """
@@ -87,11 +116,15 @@ from ..perf.recorder import record as _record
 from . import kernel_common
 from .kernel_common import VMEM_BYTES
 
-# Names of the two Pallas calls as a device trace shows them; part of the
+# Names of the Pallas calls as a device trace shows them; part of the
 # measurement (tests/test_tracing_names.py).
 KERNEL_NAMES = {
     "rows": "grouped_matmul",        # y[tile] = x[tile] @ w[expert(tile)]
     "weights": "grouped_matmul_dw",  # dw[e] = sum over e's tiles x^T dy
+    # h[tile] = act(x[tile] @ w_gate[e], x[tile] @ w_up[e]) * row_weight
+    "hidden": "expert_hidden_fwd",
+    # its cotangents: of the pre-activations, of the rows' weights, of x
+    "hidden_bwd": "expert_hidden_bwd",
 }
 
 # Rows of a tile. An expert's rows are padded to whole tiles (at least
@@ -235,6 +268,236 @@ def _grouped_bwd(tile, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the experts' first half: products, activation and the rows' weights, a tile
+# where the tile is (module docstring)
+# ---------------------------------------------------------------------------
+
+
+def _hidden(expert: str, weight, *pre):
+    """The hidden activation of a tile in f32 from the rows' weights
+    [tile, 1] f32 and its pre-activations (the gate's and the up product
+    ``swiglu``, the up product ``relu2``; in the buffer's dtype, as the
+    products are rounded): ``_gated`` / ``_relu2``'s own expressions."""
+    if expert == "swiglu":
+        gate, up = (v.astype(jnp.float32) for v in pre)
+        return jax.nn.silu(gate) * up * weight
+    # (the chip compares in f32: relu after the widening, the same values)
+    return jnp.square(jax.nn.relu(pre[0].astype(jnp.float32))) * weight
+
+
+def _column(row):
+    """The rows' weights of a tile as the kernels hold them, [1, tile] along
+    the lanes, as a column [tile, 1] (a value a row of the tile). The weights
+    travel lane-major because a [rows, 1] f32 array is tiled (8, 128) on the
+    chip: 512 bytes a row, a pass of 50 to 90 MB for every operation that
+    makes or reads it."""
+    return jnp.transpose(jnp.broadcast_to(row, (SLOT_TILE, row.shape[1])))[
+        :, :1]
+
+
+def _lanes(column):
+    """``_column``'s inverse: [tile, 1] -> [1, tile]."""
+    return jnp.transpose(jnp.broadcast_to(
+        column, (column.shape[0], SLOT_TILE)))[:1, :]
+
+
+def _pre(x, w_refs, dtype):
+    """x [tile, K] times each of the expert's matrices [K, block of F],
+    summed in f32 and rounded where the grouped product rounds."""
+    return [kernel_common.dot(x, w[...], kernel_common.AB).astype(dtype)
+            for w in w_refs]
+
+
+def _hidden_kernel(tile_expert, n_used, x_ref, weight_ref, *refs, expert):
+    *w_refs, h_ref = refs
+
+    @pl.when(pl.program_id(1) < n_used[0])
+    def _():
+        pre = _pre(x_ref[...], w_refs, h_ref.dtype)
+        h_ref[...] = _hidden(expert, _column(weight_ref[...]), *pre).astype(
+            h_ref.dtype)
+
+
+def _hidden_bwd_kernel(tile_expert, n_used, x_ref, weight_ref, dh_ref, *refs,
+                       expert, mats, f_blocks):
+    w_refs, d_refs = refs[:mats], refs[mats:2 * mats]
+    dx_ref, dweight_ref = refs[2 * mats:2 * mats + 2]
+    j = pl.program_id(1)    # read here: interpret mode has none in a branch
+
+    @pl.when(pl.program_id(0) < n_used[0])
+    def _():
+        pre = _pre(x_ref[...], w_refs, dx_ref.dtype)
+        _, pull = jax.vjp(functools.partial(_hidden, expert),
+                          _column(weight_ref[...]), *pre)
+        d_weight, *d_pre = pull(dh_ref[...].astype(jnp.float32))
+        for d_ref, d in zip(d_refs, d_pre):
+            d_ref[...] = d
+        # dx = dg W_gate^T + du W_up^T, summed here: no pass over [rows, K]
+        dx = sum(kernel_common.dot(d, w[...], kernel_common.ABT)
+                 for d, w in zip(d_pre, w_refs))
+        if f_blocks == 1:
+            dx_ref[...] = dx.astype(dx_ref.dtype)
+            dweight_ref[...] = _lanes(d_weight)
+            return
+        dx_acc, dweight_acc = refs[2 * mats + 2:]
+
+        @pl.when(j == 0)
+        def _first_block():
+            dx_acc[...] = jnp.zeros_like(dx_acc)
+            dweight_acc[...] = jnp.zeros_like(dweight_acc)
+
+        dx_acc[...] += dx
+        dweight_acc[...] += d_weight
+
+        @pl.when(j == f_blocks - 1)
+        def _last_block():
+            dx_ref[...] = dx_acc[...].astype(dx_ref.dtype)
+            dweight_ref[...] = _lanes(dweight_acc[...])
+
+
+def hidden_block(k: int, f: int, mats: int, itemsize: int,
+                 tile: int = ROW_TILE) -> int:
+    """The block of F the kernel pair works at a time: all of F where the
+    backward kernel (the larger of the two) fits its fast memory, else the
+    largest whole share of F in whole lanes that does. Counted: the
+    expert's ``mats`` matrices [K, block] and their second buffers, the
+    tile's rows in and out twice ([tile, K] x and dx, [tile, block] the
+    hidden cotangent and the pre-activations'), and in f32 dx and what the
+    activation's cotangents take, [tile, block] a value; an eighth of the
+    memory is left to the compiler. By that count every cell of the
+    benchmark works all of F (``lfm2moe``'s 2048 x 1792 and ``xing4``'s
+    3584 x 1024 at 52 and 56 MB of 64; timed in halves, both were 2 to 4 %
+    slower forward + backward: PERF.md, PR 68)."""
+    def fits(block):
+        weights = 2 * mats * k * block * itemsize
+        rows = 2 * tile * (2 * k + (1 + mats) * block) * itemsize
+        values = 4 * tile * (2 * k + (2 + 3 * mats) * block)
+        return weights + rows + values <= VMEM_BYTES * 7 // 8
+
+    for n in range(1, f // kernel_common.LANES + 1):
+        if f % (n * kernel_common.LANES) == 0 and fits(f // n):
+            return f // n
+    return f if f % kernel_common.LANES else kernel_common.LANES
+
+
+def _hidden_call(x, w, weight, tile_expert, n_used, expert, tile):
+    """The forward kernel. Its grid walks the blocks of F OUTSIDE the tiles,
+    so that an expert's block of weights stays where it is over the expert's
+    tiles (x [tile, K], a matrix's 256th, is what comes again)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    f = w[0].shape[2]
+    block = hidden_block(k, f, len(w), x.dtype.itemsize, tile)
+    row = lambda j, i, te, nu: (_last_used(i, nu), 0)        # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_hidden_kernel, expert=expert),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(f // block, rows // tile),
+            in_specs=[pl.BlockSpec((tile, k), row),
+                      pl.BlockSpec((None, 1, tile), lambda j, i, te, nu: (
+                          _last_used(i, nu), 0, 0))] + [
+                pl.BlockSpec(
+                    (None, k, block),
+                    lambda j, i, te, nu: (te[_last_used(i, nu)], 0, j))
+                for _ in w],
+            out_specs=pl.BlockSpec(
+                (tile, block),
+                lambda j, i, te, nu: (_last_used(i, nu), j))),
+        out_shape=jax.ShapeDtypeStruct((rows, f), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_BYTES),
+        name=KERNEL_NAMES["hidden"],
+        interpret=kernel_common.use_interpret(),
+    )(tile_expert, n_used, x, weight.reshape(-1, 1, tile), *w)
+
+
+def _hidden_bwd_call(x, w, weight, dh, tile_expert, n_used, expert, tile):
+    """The backward kernel -> (the pre-activations' cotangents [rows, F]
+    each, dx [rows, K], the rows' weights' cotangent [rows] f32). dx and the
+    weights' cotangent are sums over F, so here the blocks of F are the
+    INNER axis and the two are summed in scratch."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    f = w[0].shape[2]
+    block = hidden_block(k, f, len(w), x.dtype.itemsize, tile)
+    f_blocks = f // block
+    row = lambda i, j, te, nu: (_last_used(i, nu), 0)        # noqa: E731
+    lanes = pl.BlockSpec((None, 1, tile),
+                         lambda i, j, te, nu: (_last_used(i, nu), 0, 0))
+    # a skipped step names the last block of the last used tile
+    last_block = lambda i, j, nu: jnp.where(  # noqa: E731
+        i < nu[0], j, f_blocks - 1)
+    wide = pl.BlockSpec(
+        (tile, block), lambda i, j, te, nu: (
+            _last_used(i, nu), last_block(i, j, nu)))
+    out = pl.pallas_call(
+        functools.partial(_hidden_bwd_kernel, expert=expert, mats=len(w),
+                          f_blocks=f_blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(rows // tile, f_blocks),
+            in_specs=[pl.BlockSpec((tile, k), row), lanes, wide] + [
+                pl.BlockSpec(
+                    (None, k, block), lambda i, j, te, nu: (
+                        te[_last_used(i, nu)], 0, last_block(i, j, nu)))
+                for _ in w],
+            out_specs=[wide for _ in w] + [
+                pl.BlockSpec((tile, k), row), lanes],
+            scratch_shapes=[] if f_blocks == 1 else [
+                pltpu.VMEM((tile, k), jnp.float32),
+                pltpu.VMEM((tile, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, f), x.dtype) for _ in w] + [
+            jax.ShapeDtypeStruct((rows, k), x.dtype),
+            jax.ShapeDtypeStruct((rows // tile, 1, tile), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_BYTES),
+        name=KERNEL_NAMES["hidden_bwd"],
+        interpret=kernel_common.use_interpret(),
+    )(tile_expert, n_used, x, weight.reshape(-1, 1, tile), dh, *w)
+    return out[:len(w)], out[-2], out[-1].reshape(rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def expert_hidden(x, w, row_weight, tile_expert, n_used, expert: str,
+                  tile=ROW_TILE):
+    """The hidden activation of the row buffer x [rows, K] under each tile's
+    expert -> [rows, F] in x's dtype: ``_gated`` / ``_relu2``'s first half
+    over ``grouped_matmul``, made a tile at a time where the tile is. ``w``:
+    the held experts' (``e_gate``, ``e_up``) ``swiglu``, (``e_up``,)
+    ``relu2``, each [held, K, F] in x's dtype; ``row_weight`` [rows] f32
+    (``pairs_to_rows``: the 0 of a padding row); ``tile_expert``, ``n_used``
+    as ``grouped_matmul``'s, and as there rows of tiles past ``n_used`` are
+    not written, forward (h) or backward (dx, the weights' cotangent).
+    The products are summed in f32 and rounded to x's dtype, the activation,
+    the square and the weight are applied in f32 and h is rounded once, as
+    the unfused route rounds; backward the pre-activations are made again
+    from x (two products a tile), their cotangents kept in f32 until they
+    are written, and dx is the sum of the two transposed products in f32,
+    rounded once (unfused: each rounded, then added and rounded)."""
+    return _hidden_call(x, w, row_weight, tile_expert, n_used, expert, tile)
+
+
+def _expert_hidden_fwd(x, w, row_weight, tile_expert, n_used, expert, tile):
+    return (_hidden_call(x, w, row_weight, tile_expert, n_used, expert, tile),
+            (x, w, row_weight, tile_expert, n_used))
+
+
+def _expert_hidden_bwd(expert, tile, res, dh):
+    x, w, row_weight, tile_expert, n_used = res
+    d_pre, dx, d_weight = _hidden_bwd_call(x, w, row_weight, dh, tile_expert,
+                                           n_used, expert, tile)
+    dw = tuple(_weights_call(x, d, tile_expert, n_used, m.shape[0], m.dtype,
+                             tile) for d, m in zip(d_pre, w))
+    return dx, dw, d_weight, None, None
+
+
+expert_hidden.defvjp(_expert_hidden_fwd, _expert_hidden_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -549,6 +812,19 @@ def _mlp(expert: str, x, p, prefix: str, matmul, row_weight=None):
     return _relu2(x, w("up"), w("down"), matmul, row_weight)
 
 
+def _held_mlp(expert: str, buf, p, row_weight, at, tile: int):
+    """``_mlp`` over the row buffer under ``p``'s ``e_*``: the first half
+    (both products, the activation, ``row_weight``: ``pairs_to_rows``',
+    whose 0 keeps a padding row out of every gradient) in the kernel pair
+    ``expert_hidden``, then ``e_down``'s grouped product."""
+    w = lambda name: p[f"e_{name}"].astype(buf.dtype)         # noqa: E731
+    first = (w("gate"), w("up")) if expert == "swiglu" else (w("up"),)
+    h = expert_hidden(buf, first, row_weight, at["tile_expert"],
+                      at["n_used"], expert, tile)
+    return grouped_matmul(h, w("down"), at["tile_expert"], at["n_used"],
+                          tile)
+
+
 def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
                       top_k: int, routed_scale: float, tile: int = ROW_TILE,
                       score: str = "sigmoid", expert: str = "swiglu"):
@@ -594,7 +870,13 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
              "row_tile": tile, "score": score,
              "shared": "s_up" in p, "shared_gate": "s_gate_w" in p,
              "expert": expert,
-             "latent": latent})
+             "latent": latent,
+             # the experts' first half is the kernel pair ``expert_hidden``,
+             # worked this block of F at a time (``hidden_block``)
+             "mlp_in": "kernel",
+             "mlp_in_block": hidden_block(
+                 latent or d, p["e_up"].shape[2], 1 + (expert == "swiglu"),
+                 dt.itemsize, tile)})
     shared = None
     if "s_up" in p:
         with jax.named_scope("shared_expert"):
@@ -620,10 +902,7 @@ def held_expert_layer(x, p, *, experts_held: int, expert_offset: int,
         buf = tokens_to_rows(x, at)
         row_weight = pairs_to_rows(weights, at)
     with jax.named_scope("experts"):
-        y = _mlp(expert, buf, p, "e",
-                 lambda a, w: grouped_matmul(a, w, at["tile_expert"],
-                                             at["n_used"], tile),
-                 row_weight)
+        y = _held_mlp(expert, buf, p, row_weight, at, tile)
     with jax.named_scope("router"):
         routed = rows_to_tokens(y, at)
     if latent:

@@ -30,8 +30,9 @@ PR 62, ``--other <tree>``: ``held_expert_layer`` of another tree (its
 ``kanana2_train_s8192``'s: 16 of 128 of 2048 x 768, sigmoid top 6, two shared
 experts; ``xing4_train_s4096``'s: 8192 tokens, 8 of 64 of 3584 x 1024, sigmoid
 top 4, a shared expert; ``lfm2moe_train_s8192``'s: 8 of 32 of 2048 x 1792,
-sigmoid top 4, no shared expert): whether output and every gradient are EQUAL,
-and forward + backward of either, this, other, other, this.
+sigmoid top 4, no shared expert; PR 68, ``kimilinear_train_s8192``'s: 8 of 256
+of 2304 x 1024, sigmoid top 8, a shared expert): whether output and every
+gradient are EQUAL, and forward + backward of either, this, other, other, this.
 
 PR 65: where a token's slots are not whole tiles of 8 the pairs are named
 choice-major (``choice * T + token``, ``sort_rows``' tables [k, T],
@@ -75,6 +76,9 @@ SHAPES = {
     "lfm2moe": dict(t=16384, d=2048, latent=0, e=32, held=8, f=1792, fs=0,
                     top_k=4, scale=1.0, tile=el.ROW_TILE, expert="swiglu",
                     score="sigmoid"),
+    "kimilinear": dict(t=16384, d=2304, latent=0, e=256, held=8, f=1024,
+                       fs=1024, top_k=8, scale=2.446, tile=el.ROW_TILE,
+                       expert="swiglu", score="sigmoid"),
 }
 TINY = dict(t=256, d=64, latent=32, e=32, held=8, f=48, fs=64, top_k=22,
             scale=5.0, tile=8, expert="relu2", score="sigmoid")

@@ -54,6 +54,23 @@ def _unfused_under(text: str, scope: str):
                 yield line
 
 
+def _experts_first_half_is_the_kernel_pair(text: str, runs: int) -> bool:
+    """ISSUE 68: a run of expert layers (a loop's body, or a layer by
+    itself) holds ``expert_hidden_fwd`` twice (the forward sweep and the
+    rematerialised layer) and ``expert_hidden_bwd`` once, all under the scope
+    ``experts``; of the grouped product what is left is ``e_down``'s (forward,
+    at most again, and transposed for h's cotangent: 2 or 3 a run where the
+    unfused first half made 8 or 9)."""
+    count = _kernel_count(text)
+    for line in text.splitlines():
+        if re.match(r"\s*%?expert_hidden_(fwd|bwd)(\.\d+)? = ", line):
+            if not re.search(r'op_name="[^"]*[/(]experts[/)]', line):
+                return False
+    return (count("expert_hidden_fwd") == 2 * runs
+            and count("expert_hidden_bwd") == runs
+            and 2 * runs <= count("grouped_matmul") <= 3 * runs)
+
+
 def _buffers_under(text: str, shape: str, scope: str):
     """The instructions of an optimised program that PRODUCE an array of
     ``shape`` (``f32[2,8192,4096]``) under the model's scope ``scope``."""
@@ -170,6 +187,7 @@ def test_xing4_train_step_keeps_its_room(one_chip, compiled_kernels,
     assert count("mhc_pre_fwd") >= 4 and count("mhc_post_fwd") >= 4
     assert not re.findall(r"\w+\[2,4096,4,3584\]", text)
     assert not re.findall(r"f32\[8192,4,4\]|f32\[2,4096,4,4\]", text)
+    assert _experts_first_half_is_the_kernel_pair(text, runs=1)
 
 
 # 48 s alone since the delta rule is a kernel pair (85 s with its loops of
@@ -213,7 +231,9 @@ def test_kimilinear_train_step_keeps_its_room(one_chip, compiled_kernels,
     for line in text.splitlines():          # all nine under the scope
         if re.match(r"\s*%?kda_chunk_(fwd|bwd)(\.\d+)? = ", line):
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
-    assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 9
+    # ISSUE 68: three runs of expert layers (7.68 GB of temporaries)
+    assert _experts_first_half_is_the_kernel_pair(text, runs=3)
+    assert count("grouped_matmul_dw") >= 9
     assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,4096]")
 
 
@@ -247,7 +267,10 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
     and the rematerialised layer) and its backward once."""
     compiled = tool.compile_step("qwen3next_train_s8192", one_chip)
     assert 7.5e9 < fits(compiled) < 7.6e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 10.0e9
+    # ISSUE 68: 10.22 GB with the experts' first half a kernel pair (the
+    # backward kernel's five outputs and two inputs sized by the buffer are
+    # live together where the unfused passes' were not)
+    assert compiled.memory_analysis().temp_size_in_bytes < 10.3e9
     text = compiled.as_text()
     assert "s32[2,8192]" in text            # the cell's batch, not another
     assert tool.compiler_remat(text) <= 8
@@ -260,7 +283,8 @@ def test_qwen3next_train_step_keeps_its_room(one_chip, compiled_kernels,
     for line in text.splitlines():          # all three under the scope
         if re.match(r"\s*%?gdn_chunk_(fwd|bwd)(\.\d+)? = ", line):
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
-    assert count("grouped_matmul") >= 6 and count("grouped_matmul_dw") >= 6
+    assert _experts_first_half_is_the_kernel_pair(text, runs=2)
+    assert count("grouped_matmul_dw") >= 6
     assert _conv_fusions_write_one_array_each(text, "bf16[2,8192,8192]")
 
 
@@ -298,7 +322,37 @@ def test_lfm2moe_train_step_keeps_its_room(one_chip, compiled_kernels, tool):
         and " get-tuple-element(" not in line]
     assert all(re.match(r"%?short_conv_(fwd|bwd)(\.\d+)?$", m)
                for m in made), made
-    assert count("grouped_matmul") >= 12 and count("grouped_matmul_dw") >= 6
+    assert _experts_first_half_is_the_kernel_pair(text, runs=2)
+    assert count("grouped_matmul_dw") >= 6
+
+
+# 50 to 80 s each alone
+@pytest.mark.time_limit(480)
+@pytest.mark.parametrize("cell,arguments,temporaries,wide", [
+    ("nemotron3super_train_s8192", (8.3e9, 8.6e9), 9.9e9, "133120,2688"),
+    ("keyevl2_train_s16384", (0.0, 16e9), 13.2e9, "135168,768")])
+def test_a_claimed_cells_train_step_keeps_its_room(
+        one_chip, compiled_kernels, tool, cell, arguments, temporaries, wide):
+    """ISSUE 68: the two claimed cells' own train steps for the described
+    v5e with the experts' first half a kernel pair: 9.76 GB of temporaries
+    in ``nemotron3super_train_s8192`` (10.53 GB unfused, 8.41 GB of
+    arguments) and 13.10 GB in ``keyevl2_train_s16384`` (13.09 unfused), the
+    compiler making nothing again on its own, ONE run of expert layers each,
+    and under the scope ``experts`` nothing but a kernel makes an array of
+    [buffer rows, F]."""
+    compiled = tool.compile_step(cell, one_chip)
+    assert arguments[0] < fits(compiled) < arguments[1]
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+    text = compiled.as_text()
+    assert tool.compiler_remat(text) == 0
+    assert _experts_first_half_is_the_kernel_pair(text, runs=1)
+    made = [line.split(" = ")[0].strip()
+            for line in _unfused_under(text, "experts")
+            if re.search(r" = \(?\w+\[%s\]" % wide, line)
+            and " get-tuple-element(" not in line]
+    assert made and all(re.match(
+        r"%?(expert_hidden_(fwd|bwd)|grouped_matmul)(\.\d+)?$", m)
+        for m in made), made
 
 
 # 25 s alone; beside five other workers it can pass the default 180 s

@@ -168,9 +168,14 @@ def test_latent_attention_at_the_benchmark_cells_shape(one_chip,
     [(16384, 2048, 768, 16, 128, 2, 6, 2.448, "swiglu", 0),
      (8192, 3584, 1024, 8, 64, 1, 4, 2.0, "swiglu", 0),
      (16384, 4096, 2688, 8, 512, 2, 22, 5.0, "relu2", 1024),
-     (16384, 2048, 512, 32, 512, 1, 10, 1.0, "swiglu", 0)],
+     (16384, 2048, 512, 32, 512, 1, 10, 1.0, "swiglu", 0),
+     (16384, 2048, 768, 16, 128, 0, 8, 1.0, "swiglu", 0),
+     (16384, 2048, 1792, 8, 32, 0, 4, 1.0, "swiglu", 0),
+     (16384, 2304, 1024, 8, 256, 1, 8, 2.446, "swiglu", 0)],
     ids=["kanana2_train_s8192", "xing4_train_s4096",
-         "nemotron3super_train_s8192", "qwen3next_train_s8192"])
+         "nemotron3super_train_s8192", "qwen3next_train_s8192",
+         "keyevl2_train_s16384", "lfm2moe_train_s8192",
+         "kimilinear_train_s8192"])
 def test_held_expert_layer_at_the_benchmark_cells_shape(
         one_chip, compiled_kernels, t, d, f, held, of, shared, top_k, scale,
         expert, latent):
@@ -190,16 +195,28 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     dimension they were one padded copy a gather back) and to [T, slots,
     width] where they are (nemotron3super's 8, the program it had), and no
     ``reshape``, ``copy`` or ``transpose`` outside a fusion makes or reads
-    an array of that size."""
+    an array of that size. ISSUE 68: at all seven router cells' shapes
+    (keyevl2's 16 of 128 of 2048 x 768, top 8, and lfm2moe's 8 of 32 of
+    2048 x 1792, top 4, neither with a shared expert; kimilinear's 8 of 256
+    of 2304 x 1024, top 8) the experts' first half is the kernel pair
+    ``expert_hidden_fwd`` / ``expert_hidden_bwd``, one call of each, with
+    all of F a block (``hidden_block``: lfm2moe's two 2048 x 1792 matrices
+    and xing4's two of 3584 x 1024 beside their second buffers are what the
+    64 MB of fast memory must take), and outside the kernels nothing elementwise is left that is
+    sized [buffer rows, F]: no fusion, convert, select or add makes such an
+    array (the unfused first half made eight to twelve a layer)."""
     el = importlib.import_module("ray_tpu.ops.expert_layer")
     sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     width = latent or d
     p = {"w_router": sd((d, of)), "router_bias": sd((of,)),
-         "s_up": sd((d, shared * f)), "s_down": sd((shared * f, d)),
          "e_up": sd((held, width, f)), "e_down": sd((held, f, width))}
+    if shared:
+        p.update(s_up=sd((d, shared * f)), s_down=sd((shared * f, d)))
     if expert == "swiglu":
-        p.update(s_gate=sd((d, shared * f)), e_gate=sd((held, width, f)))
+        p["e_gate"] = sd((held, width, f))
+        if shared:
+            p["s_gate"] = sd((d, shared * f))
     if latent:
         p.update(w_fc1=sd((d, latent)), w_fc2=sd((latent, d)))
 
@@ -214,6 +231,8 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
              if 'custom_call_target="tpu_custom_call"' in line]
     assert any("grouped_matmul_dw" in c for c in calls)
     assert any("grouped_matmul" in c and "_dw" not in c for c in calls)
+    for name in ("expert_hidden_fwd", "expert_hidden_bwd"):
+        assert sum(1 for c in calls if name in c) == 1, (name, calls)
     assert " scatter(" not in text
     if top_k > held:
         assert re.search(r"\[%d[,\]]" % (t * held), text)
@@ -226,7 +245,14 @@ def test_held_expert_layer_at_the_benchmark_cells_shape(
     selects = lambda shape: re.findall(  # noqa: E731
         r"= \w+\[%s\]\S* select\(" % shape, text)
     assert not selects("%d,%d" % (rows, width))
-    assert selects("%d" % rows)
+    # (ISSUE 68: one scalar a row still, a column now: the kernel pair
+    # takes the rows' weights [rows, 1])
+    assert selects("%d(?:,1)?" % rows)
+    # ISSUE 68: what is [rows, F] is made by a kernel (h, the cotangents of
+    # h and of the pre-activations) and by nothing else
+    wide = re.findall(r"= \w+\[%d,%d\]\S* ([\w-]+)\(" % (rows, f), text)
+    assert wide and set(wide) <= {"custom-call", "get-tuple-element",
+                                  "bitcast", "parameter"}, wide
     slots = min(top_k, held)
     summed = (t, slots, width) if el.slot_axis(slots) else (slots, t, width)
     assert selects("%d,%d,%d" % summed)

@@ -155,6 +155,46 @@ def test_grouped_matmul_kernel_names_are_pinned(key, name):
     assert re.search(r"[/\"(]" + name + r"[/\")]", text)
 
 
+def _pallas_calls(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("expert", ["swiglu", "relu2"])
+@pytest.mark.parametrize("key,name", [("hidden", "expert_hidden_fwd"),
+                                      ("hidden_bwd", "expert_hidden_bwd")])
+def test_expert_hidden_kernel_names_are_pinned(key, name, expert):
+    """ISSUE 68: the experts' first half is a kernel pair of these names,
+    beside the grouped product's two, under the layer's ``experts`` scope
+    (what ``train_experts_ms`` charges them to), for either kind of expert.
+    The layer's unfused first half (a grouped product a matrix and passes
+    over the row buffer) is gone: forward and backward of a layer hold ONE
+    call of each of the pair, ``e_down``'s grouped product and its
+    transpose, and a ``grouped_matmul_dw`` a matrix."""
+    import collections
+
+    assert el.KERNEL_NAMES[key] == name
+    d, f, held = 16, 8, 2
+    p = {"w_router": jnp.ones((d, 4)), "router_bias": jnp.zeros((4,)),
+         "e_up": jnp.ones((held, d, f)), "e_down": jnp.ones((held, f, d))}
+    if expert == "swiglu":
+        p["e_gate"] = jnp.ones((held, d, f))
+    grad = jax.grad(lambda x, p: el.held_expert_layer(
+        x, p, experts_held=held, expert_offset=0, top_k=2, routed_scale=1.0,
+        expert=expert, tile=8)[0].astype(jnp.float32).sum(), argnums=(0, 1))
+    x = jnp.ones((8, d), jnp.bfloat16)
+    text = jax.jit(grad).lower(x, p).as_text(debug_info=True)
+    assert re.search(r"\(experts\)+/" + name + "/pallas_call", text)
+    assert _pallas_calls(jax.make_jaxpr(grad)(x, p).jaxpr,
+                         collections.Counter()) == {
+        "expert_hidden_fwd": 1, "expert_hidden_bwd": 1, "grouped_matmul": 2,
+        "grouped_matmul_dw": 3 if expert == "swiglu" else 2}
+
+
 @pytest.mark.parametrize("key,name", [("fwd", "ssd_chunk_fwd"),
                                       ("bwd", "ssd_chunk_bwd")])
 def test_ssd_scan_kernel_names_are_pinned(key, name):
@@ -206,7 +246,9 @@ def test_the_expert_layer_and_the_latent_route_leave_their_events():
         # ISSUE 52: how the router scores, whether the shared expert is gated
         "score": "sigmoid", "shared": True, "shared_gate": False,
         # ISSUE 56: the kind of every MLP of the layer, the latent's width
-        "expert": "swiglu", "latent": 0}
+        "expert": "swiglu", "latent": 0,
+        # ISSUE 68: the first half is the kernel pair, all of F a block
+        "mlp_in": "kernel", "mlp_in_block": 32}
     path = [e for e in events if e["kind"] == "rtpu.ops.flash.path"
             and e["label"] == "latent"][-1]
     assert path["data"]["hd_qk"] == 192 and path["data"]["hd_v"] == 128
@@ -345,7 +387,9 @@ def test_a_gated_deltanet_stack_leaves_its_events():
         "tokens": 512, "row_buffer": el.buffer_rows(512, 3, 2),
         "row_tile": el.ROW_TILE, "score": "softmax", "shared": True,
         "shared_gate": True,
-        "expert": "swiglu", "latent": 0}
+        "expert": "swiglu", "latent": 0,
+        # ISSUE 68: the first half is the kernel pair, all of F a block
+        "mlp_in": "kernel", "mlp_in_block": 32}
     flash = last("rtpu.ops.flash.path")
     assert flash["label"] == "relayout" and flash["data"]["hd"] == 256
     for part in ("fwd", "bwd"):
@@ -451,7 +495,9 @@ def test_a_share_of_a_latent_expert_stack_leaves_its_events():
         "slot_axis": 0,
         "tokens": 256, "row_buffer": el.buffer_rows(256, 3, 2),
         "row_tile": el.ROW_TILE, "score": "sigmoid", "shared": True, "shared_gate": False,
-        "expert": "relu2", "latent": 32}
+        "expert": "relu2", "latent": 32,
+        # ISSUE 68: the first half is the kernel pair, all of F a block
+        "mlp_in": "kernel", "mlp_in_block": 48}
     path = last("rtpu.ops.ssd.path")
     assert path["label"] == "kernel" and path["data"]["groups"] == 1 \
         and path["data"]["heads"] == 2
@@ -514,7 +560,9 @@ def test_a_sparse_attention_stack_leaves_its_events(sparse_stack):
         "pair_slots": 2, "slot_axis": 0, "tokens": 512,
         "row_buffer": el.buffer_rows(512, 3, 2), "row_tile": el.ROW_TILE,
         "score": "softmax", "shared": False, "shared_gate": False,
-        "expert": "swiglu", "latent": 0}
+        "expert": "swiglu", "latent": 0,
+        # ISSUE 68: the first half is the kernel pair, all of F a block
+        "mlp_in": "kernel", "mlp_in_block": 32}
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "keye_vl2"][-1]
     assert runs["data"]["runs"] == [["attn_moe", 2]]
@@ -601,7 +649,9 @@ def test_a_gated_convolution_stack_leaves_its_events(conv_stack):
         "pair_slots": 2, "slot_axis": 0, "tokens": 256,
         "row_buffer": el.buffer_rows(256, 3, 2), "row_tile": el.ROW_TILE,
         "score": "sigmoid", "shared": False, "shared_gate": False,
-        "expert": "swiglu", "latent": 0}
+        "expert": "swiglu", "latent": 0,
+        # ISSUE 68: the first half is the kernel pair, all of F a block
+        "mlp_in": "kernel", "mlp_in_block": 64}
     runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
             and e["label"] == "lfm2_moe"][-1]
     assert runs["data"]["runs"] == [["conv_mlp", 1], ["attn_moe", 1],
