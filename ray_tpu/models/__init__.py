@@ -23,6 +23,7 @@ from .nemotron_h import NemotronH, NemotronHConfig
 from .keye_vl2 import KeyeVL2, KeyeVL2Config
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+from .minicpm_sala import MiniCPMSALA, MiniCPMSALAConfig
 
 __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "ResNet", "ResNetConfig",
@@ -31,5 +32,5 @@ __all__ = [
     "SambaY", "SambaYConfig", "KimiLinear", "KimiLinearConfig",
     "Qwen3Next", "Qwen3NextConfig", "NemotronH", "NemotronHConfig",
     "KeyeVL2", "KeyeVL2Config", "Lfm2Moe", "Lfm2MoeConfig",
-    "OlmoHybrid", "OlmoHybridConfig",
+    "OlmoHybrid", "OlmoHybridConfig", "MiniCPMSALA", "MiniCPMSALAConfig",
 ]

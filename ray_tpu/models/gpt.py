@@ -24,7 +24,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import cross_entropy_loss, flash_attention, gelu, layernorm
+from ..ops import (chunked_head_nll, cross_entropy_loss, flash_attention,
+                   gelu, layernorm)
 from ..ops.ring_attention import ring_attention
 
 
@@ -284,30 +285,10 @@ class GPT:
                           targets: jax.Array, num_chunks: int) -> jax.Array:
         """Head + token-mean NLL per chunk under jax.checkpoint — shared by
         loss_chunked and loss_pp so the no-full-logits property holds on
-        every path."""
-        wte = wte.astype(self.config.dtype)
-        T = targets.size
-        xt = x.reshape(T, -1)
-        tg = targets.reshape(T)
-        assert T % num_chunks == 0
-        xt = xt.reshape(num_chunks, T // num_chunks, -1)
-        tg = tg.reshape(num_chunks, T // num_chunks)
-
-        @functools.partial(jax.checkpoint,
-                           policy=jax.checkpoint_policies.nothing_saveable)
-        def chunk_nll(carry, xt_tg):
-            xc, tc = xt_tg
-            with jax.named_scope("lm_head"):
-                logits = jnp.einsum("td,vd->tv", xc, wte,
-                                    preferred_element_type=jnp.float32)
-            with jax.named_scope("loss"):
-                lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(
-                    logits, tc[:, None], axis=-1)[:, 0]
-                return carry + jnp.sum(lse - gold), None
-
-        total, _ = jax.lax.scan(chunk_nll, jnp.float32(0.0), (xt, tg))
-        return total / T
+        every path (``ops.chunked_head_nll``, which other families reach
+        too)."""
+        return chunked_head_nll(wte.astype(self.config.dtype), x, targets,
+                                num_chunks)
 
     def loss_pp(self, params: Dict[str, jax.Array], tokens: jax.Array,
                 targets: jax.Array, mesh, num_microbatches: int = 0,

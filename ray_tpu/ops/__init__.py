@@ -11,6 +11,13 @@ legend); for a TPU-native framework the hot ops are first-party:
   exact top k with no sort, masked flash kernels of their own (one
   key/value head and its group of query heads a program) with a backward
   written by hand (Pallas).
+  block_sparse_attention: the same kernel pair over the BLOCKS of keys a
+  key/value group selects for each query by its own heads' scores on
+  mean-pooled keys (no indexer, no parameter).
+- lightning_attention: linear attention whose every head has its own q and
+  k and a constant decay (groups == heads), a chunked scan with the decay's
+  powers as tables: forward and backward kernels, a head's state in VMEM
+  (Pallas).
 - ring_attention: context-parallel attention over the `sp` mesh axis —
   K/V blocks rotate the ring via ppermute while compute overlaps.
 - ssd_scan: Mamba-2's state-space recurrence as a chunked scan, forward
@@ -50,8 +57,9 @@ on TPU.
 from .attention import mha_reference
 from .flash_attention import flash_attention
 from .ring_attention import ring_attention
-from .sparse_attention import sparse_attention
-from .layers import (cross_entropy_loss, gelu, layernorm, rmsnorm,
+from .sparse_attention import block_sparse_attention, sparse_attention
+from .lightning_attention import lightning_attention
+from .layers import (chunked_head_nll, cross_entropy_loss, gelu, layernorm, rmsnorm,
                      rope_cache, apply_rope, causal_conv1d,
                      causal_conv1d_silu, gated_rmsnorm, l2norm,
                      rmsnorm_then_gate, sigmoid_gated_rmsnorm)
@@ -67,8 +75,9 @@ from .paged_attention import (paged_attention_decode,
 
 __all__ = [
     "flash_attention", "ring_attention", "mha_reference", "sparse_attention",
+    "block_sparse_attention", "lightning_attention",
     "rmsnorm", "layernorm", "gelu", "rope_cache", "apply_rope",
-    "cross_entropy_loss", "causal_conv1d", "causal_conv1d_silu",
+    "cross_entropy_loss", "chunked_head_nll", "causal_conv1d", "causal_conv1d_silu",
     "gated_rmsnorm", "l2norm",
     "rmsnorm_then_gate", "sigmoid_gated_rmsnorm", "ssd_scan", "kda_scan",
     "kda_gated_scan", "gated_delta_scan", "gdn_gated_scan", "selective_scan",

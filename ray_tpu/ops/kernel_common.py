@@ -3,7 +3,8 @@ the switch that says whether a kernel is compiled or interpreted, the
 chip's tile and memory sizes, the block products and the two helpers of
 the chunked scans. ``flash_attention``, ``expert_layer``,
 ``sparse_attention``, ``ssd_scan``, ``selective_scan``,
-``hyper_connection`` and ``kda_scan`` are built on it; a new kernel file
+``hyper_connection``, ``kda_scan`` and ``lightning_attention`` are built on
+it; a new kernel file
 asks here and imports no private name of another kernel file
 (``tests/test_chip_compile.py`` holds both).
 """

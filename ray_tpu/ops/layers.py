@@ -7,6 +7,7 @@ in f32 even under bf16 params — the TPU mixed-precision recipe.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -139,6 +140,40 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
         nll = nll + z_loss * jnp.square(lse)
     mask = (labels != ignore_index).astype(jnp.float32)
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def chunked_head_nll(head: jax.Array, x: jax.Array, targets: jax.Array,
+                     num_chunks: int) -> jax.Array:
+    """The head and the token-mean next-token loss walked in ``num_chunks``
+    chunks of tokens, each under ``jax.checkpoint``: head [V, D] (already in
+    the compute dtype), x [..., D], targets [...] -> the mean of logsumexp -
+    gold over all tokens, float32. The float32 logits of one chunk exist at
+    a time, forward and backward (a whole row's are tokens x V x 4 bytes and
+    as much again for their cotangent). Scopes ``lm_head`` and ``loss``
+    inside."""
+    T = targets.size
+    xt = x.reshape(T, -1)
+    tg = targets.reshape(T)
+    if T % num_chunks:
+        raise ValueError(f"{T} tokens are not {num_chunks} whole chunks")
+    xt = xt.reshape(num_chunks, T // num_chunks, -1)
+    tg = tg.reshape(num_chunks, T // num_chunks)
+
+    @functools.partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.nothing_saveable)
+    def chunk_nll(carry, xt_tg):
+        xc, tc = xt_tg
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("td,vd->tv", xc, head,
+                                preferred_element_type=jnp.float32)
+        with jax.named_scope("loss"):
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logits, tc[:, None], axis=-1)[:, 0]
+            return carry + jnp.sum(lse - gold), None
+
+    total, _ = jax.lax.scan(chunk_nll, jnp.float32(0.0), (xt, tg))
+    return total / T
 
 
 def causal_conv1d(x: jax.Array, weight: jax.Array,

@@ -66,6 +66,14 @@ holds [S, S] floats and is for small shapes alone.
 
 What a call did is the trace-time event ``rtpu.ops.sparse_attention`` /
 ``selected`` and ``CALL_COUNTS``.
+
+**A second selector** (``block_sparse_attention``, PR 69): BLOCKS of keys,
+not keys, and no indexer: the layer's own query heads score mean-pooled
+keys, a softmax a head, summed over a key/value group, max-pooled to blocks
+of keys; the first block and a local window are forced; ONE set a group and
+query. It leaves as one byte a (query, key block) and goes through the same
+masked kernel pair, the group's query heads a program, in blocks the
+backward's accumulators admit at the row's length.
 """
 from __future__ import annotations
 
@@ -170,6 +178,26 @@ def select(scores, topk: int, row0=0):
     return (above | tie).astype(jnp.int8)
 
 
+def _top_set(keys, allowed, topk: int):
+    """``select``'s set from any keys: keys [C, S] uint32, 0 where a column
+    is not ``allowed`` -> [C, S] int8: the ``topk`` largest keys of a row,
+    ties to the lower column; all that are allowed where a row has no more
+    than ``topk``. (``select`` keeps its own lines: the names of its
+    closures stand in the metadata of a cell's compiled program, which
+    ``scripts/train_step_hlo.py --compare`` holds to the letter.)"""
+    thr = _kth_largest(keys, topk)[:, None]
+    above = keys > thr
+    tie = (keys == thr) & allowed
+    need = topk - jnp.sum(above, axis=1, dtype=jnp.int32)
+    # more ties than places: the first ``need`` of them by position
+    tie = jax.lax.cond(
+        jnp.any(jnp.sum(tie, axis=1, dtype=jnp.int32) > need),
+        lambda: tie & (jnp.cumsum(tie, axis=1, dtype=jnp.int32)
+                       <= need[:, None]),
+        lambda: tie)
+    return (above | tie).astype(jnp.int8)
+
+
 def selection_mask(q_idx, k_idx, w_idx, *, topk: int, q_chunk: int = 512):
     """q_idx [B, S, Hi, Di], k_idx [B, S, Di], w_idx [B, S, Hi] (scaled)
     -> [B, S, S] int8, 1 where query t selects key s. A block of
@@ -214,6 +242,138 @@ def _chunk_of(seq: int, q_chunk: int) -> int:
     while seq % chunk:
         chunk -= 1
     return chunk
+
+
+# ---------------------------------------------------------------------------
+# the second selector: BLOCKS of keys, chosen by the main heads' own scores
+# on mean-pooled keys, one set a key/value group and query (InfLLM-v2)
+# ---------------------------------------------------------------------------
+
+
+def block_selected_pairs(seq: int, block: int, blocks: int) -> int:
+    """(query, key) pairs a row of ``seq`` tokens selects where a query
+    takes ``blocks`` blocks of ``block`` keys, its own among them and of
+    that the causal part; every causal key where it sees no more blocks."""
+    total = 0
+    for own in range(-(-seq // block)):
+        rows = min(block, seq - own * block)
+        total += rows * min(own, blocks - 1) * block + rows * (rows + 1) // 2
+    return total
+
+
+def pooled_keys(k, size: int, stride: int):
+    """k [B, S, Hkv, D] -> [B, S / stride, Hkv, D] in k's dtype: entry j the
+    float32 mean of keys stride j .. stride j + size - 1 (size = 2 stride);
+    the last entry, whose window would pass the row's end, is zero and no
+    query ever sees it."""
+    b, s, kv, d = k.shape
+    halves = jnp.sum(k.astype(jnp.float32).reshape(
+        b, s // stride, stride, kv, d), axis=2)
+    means = (halves[:, :-1] + halves[:, 1:]) / size
+    return jnp.pad(means, ((0, 0), (0, 1), (0, 0), (0, 0))).astype(k.dtype)
+
+
+def pooled_scores(q, kc, row0, *, size: int, stride: int, sm_scale: float):
+    """q [C, G, D] of the queries ``row0`` .. and ONE group's pooled keys kc
+    [J, D] -> P [C, J] float32: each head's softmax over the pooled keys
+    whose window ends at or before its query (stride j + size - 1 <= t;
+    zero elsewhere, and a zero row where there is none), summed over the
+    group's heads in their order."""
+    c, j = q.shape[0], kc.shape[0]
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (c, j), 0)
+    seen = jax.lax.broadcasted_iota(jnp.int32, (c, j), 1) * stride \
+        + (size - 1) <= rows
+
+    def head(acc, qh):
+        s = jax.lax.dot_general(qh, kc, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(seen, s, NEG_INF)
+        e = jnp.where(seen, jnp.exp(s - jnp.max(s, axis=1, keepdims=True)),
+                      0.0)
+        return acc + e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True),
+                                     1e-30), None
+
+    return jax.lax.scan(head, jnp.zeros((c, j), jnp.float32),
+                        jnp.swapaxes(q, 0, 1))[0]
+
+
+def block_scores(p, per_block: int):
+    """P [C, J] -> [C, J / per_block]: block b's score the largest P of the
+    pooled windows that overlap it, j = per_block b - 1 .. per_block b +
+    per_block - 1 (a max-pool of per_block + 1, stride per_block, padding
+    1)."""
+    c, j = p.shape
+    cut = p.reshape(c, j // per_block, per_block)
+    before = jnp.pad(cut[:, :-1, -1], ((0, 0), (1, 0)))
+    return jnp.maximum(jnp.max(cut, axis=2), before)
+
+
+def select_blocks(scores, *, blocks: int, block: int, init_blocks: int,
+                  local_blocks: int, row0=0):
+    """scores [C, S / block] float32 of the queries ``row0`` .. -> [C,
+    S / block] int8, 1 where the query selects the block: the first
+    ``init_blocks`` and the ``local_blocks`` that end with the query's own
+    are FORCED (they stand above every score), with them the blocks of
+    largest score up to ``blocks`` in all, ties to the lower block, never a
+    block after the query's own; all it sees where that is no more than
+    ``blocks``."""
+    c, n = scores.shape
+    own = (row0 + jax.lax.broadcasted_iota(jnp.int32, (c, n), 0)) // block
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, n), 1)
+    seen = cols <= own
+    forced = (cols < init_blocks) | (cols > own - local_blocks)
+    keys = jnp.where(seen, jnp.where(forced, jnp.uint32(0xFFFFFFFF),
+                                     _sortable(scores)), jnp.uint32(0))
+    return _top_set(keys, seen, blocks)
+
+
+def block_selection(q, k, *, block: int, blocks: int, init_blocks: int,
+                    local_blocks: int, pool, sm_scale: float,
+                    q_chunk: int = 512):
+    """q [B, S, H, D], k [B, S, Hkv, D] -> [B, Hkv, S, S / block] int8, 1
+    where a query of key/value group g selects key block b. A block of
+    ``q_chunk`` queries and one group at a time (``pooled_scores`` under
+    the scope ``indexer``, then ``block_scores`` and ``select_blocks`` under
+    ``select``); the float32 scores of a block never leave it."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    size, stride = pool
+    chunk = _chunk_of(s, q_chunk)
+    n = s // chunk
+    with jax.named_scope("indexer"):
+        kc = jnp.moveaxis(pooled_keys(k, size, stride), 2, 1)  # [B,Hkv,J,D]
+        kc = kc.reshape(b * kv, s // stride, d)
+        # [B * Hkv * n, chunk, G, D]: a group's block of queries an entry
+        qg = jnp.moveaxis(q.reshape(b, n, chunk, kv, h // kv, d), 3, 1)
+        qg = qg.reshape(b * kv * n, chunk, h // kv, d)
+
+    def one(args):
+        qc, row, at = args
+        with jax.named_scope("indexer"):
+            p = pooled_scores(qc, kc[at], row * chunk, size=size,
+                              stride=stride, sm_scale=sm_scale)
+        with jax.named_scope("select"):
+            return select_blocks(
+                block_scores(p, block // stride), blocks=blocks, block=block,
+                init_blocks=init_blocks, local_blocks=local_blocks,
+                row0=row * chunk)
+
+    picked = jax.lax.map(one, (
+        qg, jnp.tile(jnp.arange(n, dtype=jnp.int32), b * kv),
+        jnp.repeat(jnp.arange(b * kv, dtype=jnp.int32), n)))
+    with jax.named_scope("select"):
+        return picked.reshape(b, kv, s, s // block)
+
+
+def expand_blocks(picked, block: int):
+    """[B, S, S / block] int8 -> [B, S, S] int8: one byte a (query, key)
+    pair, what the masked kernels read: a selected block's keys, of the
+    query's own block those up to the query."""
+    b, s, _ = picked.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    return jnp.where(cols <= rows, jnp.repeat(picked, block, axis=2),
+                     jnp.int8(0))
 
 
 # ---------------------------------------------------------------------------
@@ -556,3 +716,95 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     major = lambda x: jnp.swapaxes(x, 1, 2)                   # noqa: E731
     return major(_masked_gqa(major(q), major(k), major(v), mask, sm_scale,
                              block_q, block_k))
+
+
+def _fitting_blocks(seq: int, group: int, d: int, itemsize: int):
+    """The largest square blocks whose backward ``_bwd_vmem`` admits at this
+    length, or None where none of 128 or more does."""
+    for side in (1024, 512, 256, 128):
+        fitted = _blocks(seq, side, side)
+        if _bwd_vmem(seq, *fitted, group, d, itemsize) <= VMEM_BYTES:
+            return fitted
+    return None
+
+
+def block_sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           block: int = 64, blocks: int = 96,
+                           init_blocks: int = 1, local_blocks: int = 32,
+                           pool=(32, 16), dense_len: int = 8192,
+                           sm_scale: Optional[float] = None,
+                           q_chunk: int = 512) -> jax.Array:
+    """q [B, S, H, D], k and v [B, S, Hkv, D] -> [B, S, H, D]: grouped-query
+    attention over the ``blocks`` blocks of ``block`` keys that each query's
+    key/value GROUP selects (InfLLM-v2's mechanism: no parameter; the
+    group's own query heads score mean-pooled keys, ``pooled_scores``,
+    ``block_scores``, ``select_blocks``), causal inside the query's own
+    block. A row of at most ``dense_len`` tokens attends causally over
+    everything. No gradient passes the selection. The selection leaves as
+    one byte a (query, key block), [B, Hkv, S, S / block] int8 under the
+    ``checkpoint_name`` ``sparse_mask``, and is expanded to the byte a pair
+    the masked kernels read one key/value head at a time (``expand_blocks``:
+    made again in a rematerialised layer's backward, never kept); the kernel
+    pair's blocks are the largest its backward's accumulators admit at the
+    length (``_fitting_blocks``)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    if h % kv:
+        raise ValueError(f"{h} query heads over {kv} key/value heads")
+    size, stride = pool
+    if size != 2 * stride or block % stride:
+        raise ValueError(f"pooling {pool} over blocks of {block}: windows of "
+                         "two strides and whole strides a block are built")
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    dense = s <= dense_len or s <= blocks * block
+    if not dense and s % block:
+        raise ValueError(f"a row of {s} is not whole blocks of {block}")
+    tiled = not dense and s % LANES == 0 and d % LANES == 0
+    fits = _fitting_blocks(s, h // kv, d, q.dtype.itemsize) if tiled else None
+    if tiled and not fits:
+        # the plain form would make S x S floats a head: no route to fall to
+        raise ValueError(
+            f"block_sparse_attention at S={s}: the backward keeps "
+            f"{_bwd_vmem(s, LANES, LANES, h // kv, d, q.dtype.itemsize)} "
+            f"bytes in VMEM in its smallest blocks, the limit is "
+            f"{VMEM_BYTES}")
+    route = "causal_flash" if dense else \
+        "masked_flash" if fits else "masked_reference"
+    CALL_COUNTS[route] += 1
+    _record("rtpu.ops.sparse_attention", "selected", {
+        "seq": s, "select_by": "block", "block": block, "blocks": blocks,
+        "init_blocks": init_blocks, "local_blocks": local_blocks,
+        "pool": list(pool), "dense_len": dense_len, "heads": h,
+        "kv_heads": kv, "head_dim": d, "route": route,
+        "kernel_blocks": list(fits) if fits else None,
+        "backward": "fused" if fits else "none",
+        "bwd_products": 5 if fits else 0,
+        "saved": "none" if dense else "block_mask_int8",
+        "q_chunk": _chunk_of(s, q_chunk),
+        "selected_pairs": s * (s + 1) // 2 if dense
+        else block_selected_pairs(s, block, blocks),
+        "causal_pairs": s * (s + 1) // 2})
+    if dense:
+        return flash_attention(q, jnp.repeat(k, h // kv, axis=2),
+                               jnp.repeat(v, h // kv, axis=2), causal=True,
+                               sm_scale=sm_scale)
+    picked = checkpoint_name(block_selection(
+        *jax.lax.stop_gradient((q, k)), block=block, blocks=blocks,
+        init_blocks=init_blocks, local_blocks=local_blocks, pool=pool,
+        sm_scale=sm_scale, q_chunk=q_chunk), "sparse_mask")
+    group = h // kv
+    major = lambda x: jnp.swapaxes(x, 1, 2)                   # noqa: E731
+    outs = []
+    for g in range(kv):        # a group's selection is its own: a call each
+        with jax.named_scope("select"):
+            mask = expand_blocks(picked[:, g], block)
+        qg = q[:, :, g * group:(g + 1) * group]
+        kg, vg = k[:, :, g:g + 1], v[:, :, g:g + 1]
+        outs.append(
+            major(_masked_gqa(major(qg), major(kg), major(vg), mask, sm_scale,
+                              *fits)) if fits else
+            masked_attention_reference(qg, kg, vg, mask, sm_scale))
+    return outs[0] if kv == 1 else jnp.concatenate(outs, axis=2)

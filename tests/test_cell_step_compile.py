@@ -387,3 +387,42 @@ def test_olmohybrid_train_step_keeps_its_room(one_chip, compiled_kernels,
         if re.match(r"\s*%?gdn_chunk_(fwd|bwd)(\.\d+)? = ", line):
             assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
             assert "bf16[1,8192,1920]" in line and "bf16[1,8192,3840]" in line
+
+
+# 45 s alone; beside five other workers it can pass the default 180 s
+@pytest.mark.time_limit(480)
+def test_minicpmsala_train_step_keeps_its_room(one_chip, compiled_kernels,
+                                               tool):
+    """ISSUE 69: minicpmsala_train_s32768's own train step (the harness's
+    ``make_train_step``, the cell's configuration, optimizer, batch 1 of
+    32 768, parameters and optimizer state donated) for the described v5e:
+    630.2 M parameters at 12 B as arguments (7.56 GB) and 11.5 GB of
+    temporaries (they overlap the donated state) with the head and loss in 8
+    chunks of 4096 tokens, the attention layer keeping its input, the
+    selection as one byte a (query, key block) and the masked kernels'
+    output and row statistics, a Lightning layer its input alone. The
+    recurrence runs ITS OWN kernel pair (``lightning_chunk_fwd`` twice in
+    the scanned run's loops, the forward sweep and the rematerialised
+    layer, ``lightning_chunk_bwd`` once; ``ssd_scan``'s pair is not in the
+    program) under the scope ``scan`` on the merged [1, 32768, 16 x 128]
+    arrays; the ONE attention layer's masked kernels stand once each (kept:
+    not run again); no float array of 32768 x 32768, and never the float32
+    logits of the whole row."""
+    compiled = tool.compile_step("minicpmsala_train_s32768", one_chip)
+    assert 7.5e9 < fits(compiled) < 7.65e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 12.0e9
+    text = compiled.as_text()
+    assert "s32[1,32768]" in text           # the cell's batch, not another
+    count = _kernel_count(text)
+    assert count("sparse_attn_fwd") == 1 and count("sparse_attn_bwd_dkv") == 1
+    assert count("lightning_chunk_fwd") == 2
+    assert count("lightning_chunk_bwd") == 1
+    assert count("ssd_chunk_fwd") == 0 and count("ssd_chunk_bwd") == 0
+    for line in text.splitlines():          # all three under the scope
+        if re.match(r"\s*%?lightning_chunk_(fwd|bwd)(\.\d+)? = ", line):
+            assert re.search(r'op_name="[^"]*[/(]scan[/)]', line), line[:200]
+            assert "bf16[1,32768,2048]" in line
+    assert "s8[1,1,32768,512]" in text      # the selection, a byte a block
+    assert not re.findall(r"\b(?:f32|bf16)\[[\d,]*32768,32768\]", text)
+    assert not re.findall(r"\bf32\[(?:1,)?32768,9216\]", text)
+    assert re.search(r"f32\[4096,9216\]", text)     # a chunk's logits

@@ -728,6 +728,78 @@ def test_sparse_attention_layer_at_the_benchmark_cells_shape(
     assert not re.findall(r"\b(?:f32|bf16)\[[\d,]*16384,\d+,16384\]", text)
 
 
+def test_lightning_attention_at_the_benchmark_cells_shape(one_chip,
+                                                          compiled_kernels):
+    """ISSUE 69: minicpmsala_train_s32768's recurrence, B=1, S=32768, 16
+    heads of 128 on a state of 128 x 128, every head its own q and k
+    (groups == heads), a constant decay a head handed in TRACED (inside the
+    model it is a scanned layer's entry), chunks of 256, fed as the model
+    feeds it: forward and backward are the two kernels by name, the states
+    a chunk starts from are float32 [1, 16, 128, 128, 128], and NO array
+    with two chunk-long dimensions beside a head and chunk axis (a [..,
+    chunks, heads, 256, 256] score matrix) is in the compiled program; the
+    decays' powers are [16, 256, 256], a table of the head."""
+    la = importlib.import_module("ray_tpu.ops.lightning_attention")
+    b, t, h = 1, 32768, 16
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def loss(q, k, v, a):
+        heads = lambda x: x.reshape(b, t, h, 128)  # noqa: E731
+        return la.lightning_attention(
+            heads(q), heads(k), heads(v), a,
+            scale=128 ** -0.5).astype(jnp.float32).sum()
+
+    before = la.PATH_COUNTS["kernel"]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sd((b, t, h * 128)), sd((b, t, h * 128)), sd((b, t, h * 128)),
+        sd((h,), jnp.float32)).compile().as_text()
+    assert la.PATH_COUNTS["kernel"] == before + 1
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert any(la.KERNEL_NAMES["fwd"] in c for c in calls)
+    assert any(la.KERNEL_NAMES["bwd"] in c for c in calls)
+    assert "f32[1,16,128,128,128]" in text
+    assert not re.findall(r"\w+\[[\d,]*128,16,256,256\]", text)
+    assert not re.findall(r"\w+\[[\d,]*16,128,256,256\]", text)
+
+
+def test_block_sparse_attention_at_the_benchmark_cells_shape(
+        one_chip, compiled_kernels):
+    """ISSUE 69: minicpmsala_train_s32768's attention over a block
+    selection at ONE row of 32 768, a group of 16 query heads of 128 on one
+    key/value head, forward and backward, for the described v5e: the masked
+    kernel pair by name in blocks of 512 x 512, which the backward's
+    accumulators of a whole row admit under the module's VMEM limit
+    (ROADMAP B25(h): 1024 x 1024 is refused by ``_bwd_vmem``); the selection
+    as ONE byte a (query, key block), expanded to a byte a pair for the
+    kernels; the pooled scores a block of 512 queries and one head at a
+    time; and no float array of 32768 x 32768 or of 32768 x 2048 a head."""
+    sa = importlib.import_module("ray_tpu.ops.sparse_attention")
+    s, h = 32768, 16
+    sd = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, s, heads, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return sa.block_sparse_attention(q, k, v).astype(jnp.float32).sum()
+
+    before = sa.CALL_COUNTS["masked_flash"]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sd(h), sd(1), sd(1)).compile().as_text()
+    assert sa.CALL_COUNTS["masked_flash"] == before + 1
+    calls = " ".join(line.split(" = ")[0] for line in text.splitlines()
+                     if 'custom_call_target="tpu_custom_call"' in line)
+    for name in sa.KERNEL_NAMES.values():
+        assert name in calls, (name, calls)
+    assert "s8[1,1,32768,512]" in text or "s8[64,512,512]" in text
+    assert "s8[1,32768,32768]" in text               # what the kernels read
+    assert re.search(r"f32\[512,2048\]", text)       # a block's pooled scores
+    assert not re.findall(r"\b(?:f32|bf16|f16|f64)\[[\d,]*32768,32768\]",
+                          text)
+    assert not re.findall(r"\bf32\[[\d,]*32768,2048\]", text)
+
+
 def test_gpt2_small_train_step_at_smoke_batch(one_chip, compiled_kernels):
     """chip_smoke.py's trainer step: adamw on GPTConfig.small(bf16, flash),
     B=8, S=1024, params and optimizer state donated."""
@@ -869,6 +941,12 @@ def _small_short_conv(sd):
         [sd((1, 64, 384), jnp.bfloat16), sd((3, 128))]
 
 
+def _small_lightning(sd):
+    return (lambda q, k, v, a: _ops("lightning_attention").lightning_attention(
+        q, k, v, a, scale=1.0, chunk=128)), \
+        [sd((1, 256, 2, 128), jnp.bfloat16)] * 3 + [sd((2,))]
+
+
 def _small_kda(sd):
     return (lambda *a: _ops("kda_scan").kda_scan(*a, scale=1.0)), \
         [sd((1, 128, 128), jnp.bfloat16)] * 3 + [sd((1, 128, 128)),
@@ -882,6 +960,7 @@ KERNEL_FILES = {
     "sparse_attention": _small_sparse, "ssd_scan": _small_ssd,
     "selective_scan": _small_selective,
     "hyper_connection": _small_hyper_connection, "kda_scan": _small_kda,
+    "lightning_attention": _small_lightning,
     "short_conv": _small_short_conv}
 
 

@@ -985,3 +985,129 @@ def test_a_replicas_start_is_one_pinned_span():
               and ev["ts"] + ev["dur"]
               <= start[0]["ts"] + start[0]["dur"] + 1e-3]
     assert "rtpu.jax.compile" in {ev["kind"] for ev in inside}
+
+
+# -- ISSUE 69: a MiniCPM-SALA shaped stack -----------------------------------
+
+la = importlib.import_module("ray_tpu.ops.lightning_attention")
+
+
+@pytest.fixture(scope="module")
+def sala_stack():
+    """A MiniCPM-SALA shaped loss lowered (forward and backward) at S 256,
+    longer than the preset's ``dense_len`` 64 -> (text, events)."""
+    from ray_tpu.models import MiniCPMSALA, MiniCPMSALAConfig
+    from ray_tpu.perf.recorder import get_recorder
+
+    m = MiniCPMSALA(MiniCPMSALAConfig.tiny(heads_held=2, head_offset=2,
+                                           ff_held=64, ff_offset=64))
+    p = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    rec = get_recorder()
+    was, rec.enabled = rec.enabled, True
+    try:
+        text = jax.jit(jax.grad(m.loss)).lower(p, toks, toks).as_text(
+            debug_info=True)
+        events = rec.snapshot()
+    finally:
+        rec.enabled = was
+    return text, events
+
+
+def test_a_share_of_a_minicpm_sala_stack_leaves_its_events(sala_stack):
+    """ISSUE 69: what a MiniCPM-SALA shaped loss leaves at trace time.
+    ``rtpu.ops.lightning.path``: the kernel route with as many ``groups``
+    as ``heads``, heads of 128 on a ``state`` of 128, ``decay``
+    ``constant``, the ``chunk``; ``rtpu.ops.sparse_attention`` /
+    ``selected`` with the second selector's facts: ``select_by`` ``block``,
+    the ``block``, the ``blocks`` a query takes, ``init_blocks``,
+    ``local_blocks``, the ``pool``, the kernel pair's blocks as the length
+    chose them, what is ``saved`` and the selected beside the causal pairs;
+    ``rtpu.models.stack.runs``: the attention layer with Keye's keep-set,
+    then 3 scanned Lightning layers that keep their inputs alone, and the
+    shares held."""
+    _, events = sala_stack
+    last = lambda kind: [e for e in events if e["kind"] == kind][-1]  # noqa: E731
+    assert last("rtpu.ops.lightning.path")["data"] == {
+        "route": "kernel", "chunk": 128, "heads": 2, "groups": 2,
+        "head_dim": 128, "state": 128, "decay": "constant", "chunks": 2}
+    sel = last("rtpu.ops.sparse_attention")
+    assert sel["label"] == "selected" and sel["data"] == {
+        "seq": 256, "select_by": "block", "block": 16, "blocks": 6,
+        "init_blocks": 1, "local_blocks": 2, "pool": [8, 4],
+        "dense_len": 64, "heads": 2, "kv_heads": 1, "head_dim": 128,
+        "route": "masked_flash", "kernel_blocks": [256, 256],
+        "backward": "fused", "bwd_products": 5,
+        "saved": "block_mask_int8", "q_chunk": 256,
+        "selected_pairs": sa.block_selected_pairs(256, 16, 6),
+        "causal_pairs": 256 * 257 // 2}
+    # 16 own blocks' causal halves; 0 .. 4 earlier blocks for the first five
+    # blocks of queries, 5 for the other eleven
+    assert sel["data"]["selected_pairs"] == 16 * (16 * 17 // 2) \
+        + 16 * 16 * (0 + 1 + 2 + 3 + 4 + 5 * 11)
+    runs = [e for e in events if e["kind"] == "rtpu.models.stack.runs"
+            and e["label"] == "minicpm_sala"][-1]
+    assert runs["data"]["runs"] == [["attn", 1], ["lightning", 3]]
+    assert runs["data"]["kept"] == [["sparse_mask", "sparse_out",
+                                     "sparse_lse", "flash_out",
+                                     "flash_lse"], []]
+    assert (runs["data"]["heads"], runs["data"]["kv_heads"],
+            runs["data"]["head_offset"], runs["data"]["ff"],
+            runs["data"]["ff_offset"]) == ([2, 4], [1, 2], 2, [64, 128], 64)
+
+
+def _in_scope(names, scope):
+    """The operation names that stand under ``scope``: ``scope/`` inside a
+    scanned body, ``jvp(scope)`` where the layer is a run of one."""
+    return [n for n in names
+            if re.search(r"(^|/|\()" + scope + r"(/|\))", n)]
+
+
+@pytest.mark.parametrize("key,name", [("fwd", "lightning_chunk_fwd"),
+                                      ("bwd", "lightning_chunk_bwd")])
+def test_lightning_kernel_names_are_pinned(sala_stack, key, name):
+    """ISSUE 69: ``lightning_scan_roofline`` finds its kernels by these;
+    they stand under the scope ``scan``, the masked pair (whose names
+    ``block_sparse_attention_roofline`` reads, unchanged) under ``attn``,
+    and no state-space kernel of ``ssd_scan`` is in the program."""
+    text, _ = sala_stack
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    assert la.KERNEL_NAMES[key] == name
+    assert sorted(la.KERNEL_NAMES.values()) == ["lightning_chunk_bwd",
+                                                "lightning_chunk_fwd"]
+    assert [n for n in _in_scope(names, "scan") if "/" + name + "/" in n]
+    for other in ssd.KERNEL_NAMES.values():
+        assert other not in text
+    for masked in sa.KERNEL_NAMES.values():
+        assert [n for n in _in_scope(names, "attn")
+                if "/" + masked + "/" in n], masked
+    assert "sparse_attn_bwd_dq" not in text
+
+
+@pytest.mark.parametrize("scope", ["indexer", "select"])
+def test_the_block_selection_has_scopes_and_no_backward(sala_stack, scope):
+    """ISSUE 69: ``train_indexer_ms`` and ``train_select_ms`` read these
+    scopes in this family too (the innermost on an operation's name stack,
+    under ``attn``). No gradient passes the selection: nothing of it stands
+    under a transpose but what a rematerialised layer makes AGAIN there
+    (the bytes a pair, expanded from the kept blocks), and the pooled
+    scores are not among that."""
+    text, _ = sala_stack
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    mine = _in_scope(names, scope)
+    assert mine, scope
+    assert [n for n in mine if _in_scope([n], "attn")]
+    again = [n for n in mine if "transpose(" in n]
+    assert all("rematted_computation" in n for n in again), scope
+    if scope == "indexer":
+        assert not again
+
+
+def test_the_chunked_head_keeps_its_scopes(sala_stack):
+    """ISSUE 69: the head and the loss walked in token chunks
+    (``ops.chunked_head_nll``, which ``models/gpt.py`` reaches too) stand
+    under ``lm_head`` and ``loss``, which ``train_head_loss_ms`` reads."""
+    text, _ = sala_stack
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    for scope in ("lm_head", "loss", "embed", "mixer", "scan", "mlp", "attn"):
+        assert _in_scope(names, scope), scope
